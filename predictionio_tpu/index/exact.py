@@ -1,18 +1,19 @@
-"""Exact on-device retrieval: fused Pallas dot+top-k, XLA fallback.
+"""Exact on-device retrieval: fused Pallas dot+top-k, XLA reference.
 
 The hot path is ``ops/pallas/topk_dot.py`` — the item table streamed
 through VMEM in tiles, MXU partial dots, a running [B, k] top-k merged
 per tile; the full [B, I] logits matrix never exists in HBM. The XLA
 brute-force scorer (``ops.topk.TopKScorer``) remains the numerical
-reference and the fallback everywhere the kernel is ineligible or its
-Mosaic probe fails — the ``ops/pallas`` design contract, applied to
-serving instead of training.
+reference and the path for shapes the kernel is not eligible for (and
+for the CPU backend). An engaged kernel that the chip's compiler
+refuses raises from ``search`` — the ``ops/pallas`` design contract,
+applied to serving instead of training.
 
 Kernel selection mirrors ``flash_ce_kernel`` exactly: a per-index
 ``kernel`` flag ("auto"/"on"/"off", wired from the model params'
 ``index_kernel``), the ``PIO_INDEX_KERNEL`` env override, ``auto``
-engaging only on a real TPU backend, probe-guarded with per-shape
-smoke compiles, and interpret mode for CPU tier-1 equivalence tests.
+engaging only on a real TPU backend, and interpret mode for CPU
+tier-1 equivalence tests.
 """
 
 from __future__ import annotations
@@ -58,6 +59,11 @@ class ExactIndex(AnnIndex):
                                                "reason": "no build yet"}
         self.build_seconds = 0.0
         self.searches = 0
+        #: which side answered each search: the Pallas kernel, or the
+        #: XLA scorer on the device / its host scan (ops/topk.py).
+        #: Shared with every TopKScorer this index builds, so the
+        #: counts survive the scorer being dropped on build/upsert
+        self.routes = {"kernel": 0, "device": 0, "host": 0}
 
     # -- build / upsert -------------------------------------------------------
     def build(self, item_vectors: np.ndarray) -> None:
@@ -149,16 +155,9 @@ class ExactIndex(AnnIndex):
         fn = self._fns.get(key)
         if fn is None:
             n, d = self._vectors.shape
-            interpret = bool(self.kernel_plan.get("interpret"))
-            if not interpret and not plk.probe(
-                    f"topk_dot:{n}x{d}:B{B}E{E}k{k}",
-                    lambda: tkd.smoke_at(n, d, B, k, E,
-                                         block_items=self.block_items)):
-                self._fns[key] = False   # this shape degraded to XLA
-                return False
-            fn = tkd.make_topk_dot(n, d, B, k, E,
-                                   block_items=self.block_items,
-                                   interpret=interpret)
+            fn = tkd.make_topk_dot(
+                n, d, B, k, E, block_items=self.block_items,
+                interpret=bool(self.kernel_plan.get("interpret")))
             self._fns[key] = fn
         return fn
 
@@ -187,6 +186,7 @@ class ExactIndex(AnnIndex):
             scorer = TopKScorer(self._vectors,
                                 max_exclude=self.max_exclude,
                                 placement=self._placement)
+            scorer.routed = self.routes
             self._scorer = scorer  # graftlint: disable=JT18 — lock-free lazy init by design: racing fills build equivalent scorers over the same read-only vectors; last write wins, readers hold their local ref
         return scorer
 
@@ -207,9 +207,8 @@ class ExactIndex(AnnIndex):
         if not self._kernel_eligible(q2.shape[0], excl.shape[1], k_bucket):
             return self._fallback().score(query_vecs, k, exclude)
         fn = self._fn(q2.shape[0], excl.shape[1], k_bucket)
-        if fn is False:   # probe failed for this shape — XLA fallback
-            return self._fallback().score(query_vecs, k, exclude)
         scores, idx = fn(q2, self._device_items(), excl)
+        self.routes["kernel"] += 1
         return (np.asarray(scores)[:B, :k_eff],
                 np.asarray(idx)[:B, :k_eff])
 
@@ -219,6 +218,9 @@ class ExactIndex(AnnIndex):
             "kernel": dict(self.kernel_plan),
             "build_seconds": round(self.build_seconds, 4),
             "searches": self.searches,
+            "routes": {"kernel": self.routes["kernel"],
+                       "xla_device": self.routes["device"],
+                       "host": self.routes["host"]},
             "max_exclude": self.max_exclude,
         })
         return out

@@ -51,9 +51,13 @@ def build_library(name: str, extra_flags: Optional[list] = None) -> str:
         if os.path.exists(out) and os.path.getmtime(out) >= dep_mtime:
             return out
         os.makedirs(_BUILD_DIR, exist_ok=True)
+        # a temporary name of this process's own: several processes may
+        # build from a fresh checkout at once (test workers, fleet
+        # replicas), and each must rename a file it wrote itself
+        tmp = f"{out}.{os.getpid()}.tmp"
         cmd = [
             _CXX, "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-            *(extra_flags or []), src, "-o", out + ".tmp",
+            *(extra_flags or []), src, "-o", tmp,
         ]
         try:
             proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
@@ -65,7 +69,7 @@ def build_library(name: str, extra_flags: Optional[list] = None) -> str:
             raise NativeBuildError(
                 f"compiling {name} failed:\n{proc.stderr[-2000:]}"
             )
-        os.replace(out + ".tmp", out)
+        os.replace(tmp, out)
         return out
 
 

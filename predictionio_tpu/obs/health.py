@@ -213,13 +213,24 @@ def storage_probe(storage) -> ProbeResult:
     return ok(f"{len(results)} repositories in {elapsed_ms:.1f} ms")
 
 
-def _devices_probe() -> ProbeResult:
-    try:
-        import jax
+def jax_backend_initialized() -> bool:
+    """True when THIS process has already initialised a jax backend
+    for its own work (engine server, trainer). Never imports jax and
+    never touches the backend: a chip belongs to one process, and a
+    health check must not be what takes it."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
 
-        devices = jax.local_devices()
-    except Exception as e:  # noqa: BLE001 — event-tier servers run without jax
-        return degraded(f"jax devices unavailable: {type(e).__name__}: {e}")
+    return xla_bridge.backends_are_initialized()
+
+
+def _devices_probe() -> ProbeResult:
+    if not jax_backend_initialized():
+        return ok("no device in this process")
+    import jax
+
+    devices = jax.local_devices()
     if not devices:
         return failed("no local devices")
     return ok(f"{len(devices)} {devices[0].platform} device(s)")

@@ -17,13 +17,14 @@ the obs registry so they show up on every server's ``/metrics``:
                                                     (owned by obs/memacct.py)
   pio_pallas_kernel_enabled{kernel=}                Pallas vs XLA path choice
 
-``install()`` never imports jax at module import time and never raises:
-observability must not change whether training runs.
+The module never imports jax at import time (event-tier servers import
+it for the registry only).
 """
 
 from __future__ import annotations
 
 import logging
+import os
 from typing import Optional
 
 from predictionio_tpu.obs import metrics
@@ -75,9 +76,7 @@ PALLAS_KERNEL_ENABLED = metrics.gauge(
     ("kernel",),
 )
 
-#: jax.monitoring event keys -> our series (jax 0.4.x names; unknown
-#: keys are ignored so a jax upgrade degrades to missing points, never
-#: an error)
+#: jax.monitoring event keys -> our series (jax 0.9.0 names)
 _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "hit",
     "/jax/compilation_cache/cache_misses": "miss",
@@ -104,19 +103,13 @@ def _on_event_duration(event: str, duration_secs: float, **kwargs) -> None:
 
 
 def install() -> bool:
-    """Register the jax.monitoring bridge once per process.
-
-    Returns True when listening (idempotent), False when jax (or its
-    monitoring module) is unavailable — the metrics then simply stay at
-    zero."""
+    """Register the jax.monitoring bridge once per process
+    (idempotent)."""
     global _installed
     if _installed:
         return True
-    try:
-        from jax import monitoring
-    except Exception as e:  # noqa: BLE001 — observability is optional
-        log.warning("jax.monitoring unavailable, compile metrics off: %s", e)
-        return False
+    from jax import monitoring
+
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_event_duration)
     _installed = True
@@ -131,6 +124,46 @@ def record_kernel_plan(plan: dict) -> None:
     for kernel in ("flash_ce", "embed_update"):
         if kernel in plan:
             PALLAS_KERNEL_ENABLED.labels(kernel).set(float(bool(plan[kernel])))
+
+
+#: what each trainer of this process last reported (``pio train``
+#: prints them): kernel plan, step time, losses, placement
+TRAINER_REPORTS: dict = {}
+
+
+def record_trainer_report(trainer: str, report: dict) -> None:
+    """Merge ``report`` into the trainer's entry (a trainer reports
+    from more than one seam: placement, then each dispatch)."""
+    TRAINER_REPORTS.setdefault(trainer, {}).update(report)
+
+
+def compile_cache_counts() -> dict:
+    """{"hit": n, "miss": n} of this process's persistent-cache
+    lookups so far (``pio_jax_compile_cache_total``)."""
+    return {result: int(COMPILE_CACHE_TOTAL.labels(result).value)
+            for result in ("hit", "miss")}
+
+
+def device_report() -> dict:
+    """What this process runs on, as jax reports it — the block
+    ``pio train`` prints and the engine server's ``GET /`` carries, so
+    a result always names its device. Only for processes that own a
+    backend (it initialises one)."""
+    import jax
+
+    local = jax.local_devices()
+    stats = [d.memory_stats() or {} for d in local]
+    return {
+        "platform": local[0].platform,
+        "device_kind": local[0].device_kind,
+        "device_count": jax.device_count(),
+        "device_ids": [d.id for d in local],
+        # the chip the fleet gave this process (serving/fleet.py)
+        "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+        "bytes_in_use": [s.get("bytes_in_use") for s in stats],
+        "peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
+        "compile_cache": compile_cache_counts(),
+    }
 
 
 def record_transfer(nbytes: Optional[int], direction: str) -> None:

@@ -33,7 +33,7 @@ Design constraints:
     (the event joins the flight recorder / span ring), and the
     emitting server/replica name when the caller knows it
 
-Event kinds (the taxonomy the anomaly sentinel and ``pio journal``
+Event kinds (the vocabulary the anomaly sentinel and ``pio journal``
 filter on): ``reload``, ``patch``, ``fold``, ``resync``,
 ``canary_start``, ``canary_verdict``, ``canary_promote``,
 ``canary_rollback``, ``swap``, ``replica_state``, ``breaker``,

@@ -25,9 +25,10 @@ FLOPs/bytes-moved basis:
       pio_device_headroom_bytes                 capacity - in-use
 
     Capacity comes from ``memory_stats()['bytes_limit']`` where the
-    backend reports it (TPU); on CPU the ``PIO_PEAK_HBM_BYTES``
-    accounting peak (obs/perfacct.py) stands in and in-use falls back
-    to the ledger total, so tier-1 exercises the full plane. The
+    backend reports it (TPU); the CPU backend's arrays live in host
+    memory, so there the host's physical memory stands in and in-use
+    falls back to the ledger total, so tier-1 exercises the full
+    plane. The
     ``device_memory`` health probe goes DEGRADED below the
     ``PIO_MEM_HEADROOM_FLOOR`` fraction of capacity.
 
@@ -57,8 +58,6 @@ it on the flight-recorder snapshot cadence, so serving processes
 report continuously — not only post-train.
 
 Env knobs:
-  PIO_PEAK_HBM_BYTES       accounting capacity on backends that report
-                           no bytes_limit (shared with perfacct)
   PIO_MEM_HEADROOM_FLOOR   headroom fraction of capacity below which
                            the device_memory probe is DEGRADED
                            (default 0.05)
@@ -76,12 +75,13 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import os
 import sys
 import threading
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
-from predictionio_tpu.obs import flight, health, metrics, perfacct
+from predictionio_tpu.obs import flight, health, metrics
 
 log = logging.getLogger(__name__)
 
@@ -96,7 +96,7 @@ DEVICE_HEADROOM_BYTES = metrics.gauge(
     "pio_device_headroom_bytes",
     "Device-memory capacity minus in-use bytes (worst device): "
     "memory_stats bytes_limit/bytes_in_use where the backend reports "
-    "them, else the PIO_PEAK_HBM_BYTES accounting peak minus the "
+    "them, else the host's physical memory minus the "
     "ledger total",
 )
 TRAIN_PEAK_BYTES = metrics.gauge(
@@ -280,10 +280,11 @@ def release_model(model: Any) -> int:
 
 def _jax_device_stats(import_jax: bool = False) -> List[Dict[str, Any]]:
     """Per-device ``memory_stats()`` where the backend reports them.
-    Without ``import_jax`` this only LOOKS at an already-imported jax —
-    the snapshot-cadence refresh must never make an event-tier server
-    pay the jax import for its gauges. Never raises."""
-    if not import_jax and "jax" not in sys.modules:
+    Without ``import_jax`` this only LOOKS at a backend the process has
+    already initialised for its own work — the snapshot-cadence refresh
+    must never make an event-tier server import jax, nor make a process
+    that imported jax for another reason take the chip. Never raises."""
+    if not import_jax and not health.jax_backend_initialized():
         return []
     try:
         import jax
@@ -321,12 +322,18 @@ def update_device_memory_gauges(import_jax: bool = True) -> int:
     return len(devices)
 
 
+def host_memory_bytes() -> int:
+    """Physical memory of this host: the capacity the CPU backend's
+    "device" arrays actually draw on."""
+    return os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+
+
 def capacity_report(import_jax: bool = False) -> Dict[str, Any]:
     """Capacity / in-use / headroom with their basis, refreshing
     ``pio_device_headroom_bytes``. Basis ``memory_stats`` when some
     device reports a ``bytes_limit`` (headroom = the WORST device);
-    else the ``PIO_PEAK_HBM_BYTES`` accounting peak with the ledger
-    total as in-use — the CPU tier-1 contract."""
+    else ``host_memory`` — the CPU backend keeps arrays in host RAM —
+    with the ledger total as in-use (the CPU tier-1 contract)."""
     devices = _jax_device_stats(import_jax=import_jax)
     limited = [d for d in devices if "bytes_limit" in d]
     if limited:
@@ -336,9 +343,9 @@ def capacity_report(import_jax: bool = False) -> Dict[str, Any]:
         in_use = int(worst.get("bytes_in_use", 0))
         basis = "memory_stats"
     else:
-        capacity = int(perfacct.peak_hbm_bytes())
+        capacity = host_memory_bytes()
         in_use = LEDGER.total_bytes()
-        basis = "env"
+        basis = "host_memory"
     headroom = capacity - in_use
     DEVICE_HEADROOM_BYTES.set(float(headroom))
     return {
@@ -428,18 +435,6 @@ def peak_from_compiled(compiled: Any) -> Optional[int]:
     if total <= 0:
         return None
     return int(total)
-
-
-def peak_from_jitted(fn: Any, *args: Any) -> Optional[int]:
-    """AOT-lower an already-jitted callable at ``args``' shapes and
-    read its memory_analysis. Call AFTER the first dispatch so the
-    persistent compile cache absorbs the second backend compile.
-    Returns None on any failure — analytic fallback territory."""
-    try:
-        return peak_from_compiled(fn.lower(*args).compile())
-    except Exception as e:  # noqa: BLE001 — strictly best-effort
-        log.debug("jitted memory analysis failed: %s", e)
-        return None
 
 
 def note_train_peak(model: str, peak_bytes: int,

@@ -10,7 +10,7 @@ them continuous:
     Every instrumented trainer builds a :class:`StepAccountant`: the
     FLOP/byte cost of its compiled step comes from
     ``jax.stages.Compiled.cost_analysis()`` when the backend reports it
-    (:func:`costs_from_compiled` / :func:`costs_from_jitted`), falling
+    (:func:`costs_from_compiled`), falling
     back to the analytic formulas this repo already trusts — the
     two-tower matmul count that used to live in bench.py
     (:func:`twotower_matmul_flops`, now the ONE copy bench imports) and
@@ -53,16 +53,18 @@ them continuous:
     obs/flight.py clamps the unattributed remainder at 0 (and counts
     the clamps in ``pio_flight_negative_remainder_total``).
 
-Chip peaks default to the public TPU v5e numbers (bench.py imports
-them from here); override with ``PIO_PEAK_FLOPS`` / ``PIO_PEAK_HBM_BYTES``
-when accounting against other hardware. jax is only imported inside
-the cost-analysis helpers — the module stays importable by the bench
-orchestrator and the pure-CPU servers.
+Chip peaks live in ONE table keyed by jax's ``device_kind``
+(:data:`DEVICE_PEAKS`; bench.py imports the v5e row from here). A TPU
+whose kind has no row is an error, not a default, and on the CPU
+backend no utilisation is computed or exported at all. jax is only
+imported inside the cost-analysis and peak helpers — the module stays
+importable by the bench orchestrator and the pure-CPU servers.
 """
 
 from __future__ import annotations
 
 import collections
+import dataclasses
 import logging
 import threading
 import time
@@ -72,30 +74,50 @@ from predictionio_tpu.obs import flight, metrics
 
 log = logging.getLogger(__name__)
 
-# public TPU v5e per-chip peaks (cloud.google.com/tpu/docs/v5e):
-# 197 TFLOP/s bf16, 819 GB/s HBM bandwidth — the one copy; bench.py
-# and the live gauges divide by the SAME denominators by construction
-PEAK_BF16_FLOPS = 197e12
-PEAK_HBM_BYTES = 819e9
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float          # FLOP/s
+    hbm_bytes_per_sec: float
 
 
-def peak_flops() -> float:
-    """The accounting FLOP/s peak (PIO_PEAK_FLOPS overrides the v5e
-    default for other chips; the gauge is a fraction of THIS)."""
-    return metrics.env_float("PIO_PEAK_FLOPS", PEAK_BF16_FLOPS)
+#: per-chip peaks keyed by ``jax.devices()[0].device_kind`` — the one
+#: copy; bench.py and the live gauges divide by the SAME denominators.
+#: Source: Google Cloud documentation, "TPU v5e"
+#: (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 819 GB/s HBM.
+DEVICE_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, hbm_bytes_per_sec=819e9),
+}
 
 
-def peak_hbm_bytes() -> float:
-    return metrics.env_float("PIO_PEAK_HBM_BYTES", PEAK_HBM_BYTES)
+def device_peaks() -> Optional[ChipPeaks]:
+    """Peaks of this process's default device: None on the CPU backend
+    (a utilisation against a chip that is not there means nothing);
+    ``LookupError`` for an accelerator whose kind has no row."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    peaks = DEVICE_PEAKS.get(dev.device_kind)
+    if peaks is None:
+        raise LookupError(
+            f"no peak FLOP/s / HBM row for device kind "
+            f"{dev.device_kind!r} in obs/perfacct.py DEVICE_PEAKS "
+            f"(known: {sorted(DEVICE_PEAKS)}) — add the chip's published "
+            "peaks with their source before reporting a utilisation")
+    return peaks
 
 
-def mfu(flops: float, seconds: float) -> float:
+def mfu(flops: float, seconds: float) -> Optional[float]:
     """Model FLOPs utilization: achieved FLOP/s over the chip peak —
     the one formula the live gauge and bench.py's driver-captured
-    ``twotower_mfu`` share."""
+    ``twotower_mfu`` share. None on the CPU backend."""
+    peaks = device_peaks()
+    if peaks is None:
+        return None
     if seconds <= 0.0:
         return 0.0
-    return flops / seconds / peak_flops()
+    return flops / seconds / peaks.bf16_flops
 
 
 def twotower_matmul_flops(batch: int, dim: int,
@@ -118,39 +140,20 @@ def twotower_matmul_flops(batch: int, dim: int,
 def costs_from_compiled(compiled: Any) -> Optional[Tuple[float, float]]:
     """(flops, bytes accessed) per execution from a
     ``jax.stages.Compiled``'s ``cost_analysis()``, or None when the
-    backend reports nothing usable (CPU builds without the cost model,
-    older jax returning empty dicts) — the caller then falls back to
-    its analytic formula. Never raises: accounting must not change
-    whether training runs."""
+    backend reports nothing usable (no flop count in its cost model)
+    — the caller then falls back to its analytic formula. Never
+    raises: accounting must not change whether training runs."""
     try:
         analysis = compiled.cost_analysis()
     except Exception as e:  # noqa: BLE001 — backend-dependent surface
         log.debug("cost_analysis unavailable: %s", e)
         return None
-    # jax has returned both a bare dict and a per-device list of dicts
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else None
     if not isinstance(analysis, dict):
         return None
     flops = float(analysis.get("flops") or 0.0)
     if flops <= 0.0:
         return None
-    bytes_accessed = float(analysis.get("bytes accessed")
-                           or analysis.get("bytes_accessed") or 0.0)
-    return flops, bytes_accessed
-
-
-def costs_from_jitted(fn: Any, *args: Any) -> Optional[Tuple[float, float]]:
-    """Cost-analyze an already-jitted callable by AOT-lowering it at
-    ``args``' shapes. Call AFTER the first dispatch so the persistent
-    compile cache (when enabled) absorbs the second backend compile;
-    donated-argument metadata is harmless under ``lower``. Returns None
-    on any failure — analytic fallback territory, never an error."""
-    try:
-        return costs_from_compiled(fn.lower(*args).compile())
-    except Exception as e:  # noqa: BLE001 — strictly best-effort
-        log.debug("jitted cost analysis failed: %s", e)
-        return None
+    return flops, float(analysis.get("bytes accessed") or 0.0)
 
 
 # -- gauges -------------------------------------------------------------------
@@ -158,8 +161,8 @@ def costs_from_jitted(fn: Any, *args: Any) -> Optional[Tuple[float, float]]:
 _TRAIN_MFU = metrics.gauge(
     "pio_train_mfu",
     "Model FLOPs utilization of the last observed training step: "
-    "achieved FLOP/s over the chip peak (PIO_PEAK_FLOPS, default TPU "
-    "v5e bf16)",
+    "achieved FLOP/s over the chip's bf16 peak (obs/perfacct.py "
+    "DEVICE_PEAKS by device_kind; not exported on the CPU backend)",
     ("model",),
 )
 _STEP_FLOPS = metrics.gauge(
@@ -214,12 +217,13 @@ class StepAccountant:
         self.flops_per_step = float(flops_per_step)
         self.bytes_per_step = float(bytes_per_step)
         self.source = source
-        self.last_mfu = 0.0
+        self.last_mfu: Optional[float] = None
         _STEP_FLOPS.labels(model).set(self.flops_per_step)
         _STEP_BYTES.labels(model).set(self.bytes_per_step)
-        if self.bytes_per_step > 0.0:
+        peaks = device_peaks()
+        if peaks is not None and self.bytes_per_step > 0.0:
             intensity = self.flops_per_step / self.bytes_per_step
-            ridge = peak_flops() / peak_hbm_bytes()
+            ridge = peaks.bf16_flops / peaks.hbm_bytes_per_sec
             _ROOFLINE_POSITION.labels(model).set(intensity / ridge)
 
     @classmethod
@@ -233,20 +237,13 @@ class StepAccountant:
             return cls(model, costs[0], costs[1], source="cost_analysis")
         return cls(model, fallback_flops, fallback_bytes, source="analytic")
 
-    @classmethod
-    def from_jitted(cls, model: str, fn: Any, args: Sequence[Any],
-                    fallback_flops: float,
-                    fallback_bytes: float = 0.0) -> "StepAccountant":
-        costs = costs_from_jitted(fn, *args)
-        if costs is not None:
-            return cls(model, costs[0], costs[1], source="cost_analysis")
-        return cls(model, fallback_flops, fallback_bytes, source="analytic")
-
-    def observe(self, seconds: float, steps: int = 1) -> float:
+    def observe(self, seconds: float, steps: int = 1) -> Optional[float]:
         """Record one timed dispatch covering ``steps`` steps; returns
-        (and gauges) the resulting MFU."""
+        (and gauges) the resulting MFU — None, and no gauge, on the
+        CPU backend."""
         self.last_mfu = mfu(self.flops_per_step * steps, seconds)
-        _TRAIN_MFU.labels(self.model).set(self.last_mfu)
+        if self.last_mfu is not None:
+            _TRAIN_MFU.labels(self.model).set(self.last_mfu)
         return self.last_mfu
 
 

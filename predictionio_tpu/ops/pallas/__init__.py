@@ -11,18 +11,20 @@ the table update run as one VMEM-resident gather→update→write pass
 
 Design contract shared by every kernel here:
 
-  - the XLA implementation REMAINS the reference and the fallback; a
-    kernel is selected per-trainer by :func:`decide` (config flag +
-    env override + eligibility), never unconditionally;
-  - kernels run under Pallas interpret mode on CPU, so tier-1
-    exercises fwd/bwd numerics with no TPU in the loop
-    (``PIO_PALLAS_INTERPRET=1`` forces it; a ``cpu`` jax backend
-    implies it);
-  - on a real TPU a kernel must pass a one-time :func:`probe` (tiny
-    compiled smoke call) before it is engaged — a Mosaic regression
-    degrades to the XLA path with a warning, never a failed train;
+  - the XLA implementation REMAINS the reference; a kernel is selected
+    per-trainer by :func:`decide` (config flag + env override +
+    eligibility). ``auto`` is a choice by backend — on a TPU the
+    compiled kernel, on the CPU the XLA path — never a fallback: an
+    engaged kernel that fails to compile or run raises the compiler's
+    own error in the trainer / the index, so a chip run can never be
+    measured on a path nobody chose;
+  - kernels run under Pallas interpret mode only for the tests, on the
+    CPU backend (flag ``on``; ``PIO_PALLAS_INTERPRET=1``), so tier-1
+    exercises fwd/bwd numerics with no TPU in the loop. Interpret mode
+    is never chosen on a TPU backend;
   - equivalence tests pin each kernel to its XLA reference at <=1e-5
-    in f32 (tests/test_pallas_kernels.py).
+    in f32 (tests/test_pallas_kernels.py), and tests/test_tpu_compile.py
+    compiles each for a described v5e at the advertised shapes.
 
 The same contract covers serving: ``topk_dot`` (fused dot + streaming
 top-k over a tiled item table — the exact retrieval index's hot path,
@@ -38,7 +40,7 @@ from __future__ import annotations
 
 import logging
 import os
-from typing import Callable, Dict, Tuple
+from typing import Tuple
 
 log = logging.getLogger(__name__)
 
@@ -47,17 +49,18 @@ _FALSY = {"0", "false", "no", "off"}
 
 
 def interpret_mode() -> bool:
-    """Whether kernels should run under the Pallas interpreter.
+    """Whether kernels should run under the Pallas interpreter: never
+    on a TPU backend; on any other backend ``PIO_PALLAS_INTERPRET``
+    wins when set, else interpret (there is no Mosaic compiler to
+    target)."""
+    import jax
 
-    ``PIO_PALLAS_INTERPRET`` wins when set; otherwise a non-TPU jax
-    backend implies interpret (there is no Mosaic compiler to target).
-    """
+    if jax.default_backend() == "tpu":
+        return False
     env = os.environ.get("PIO_PALLAS_INTERPRET")
     if env is not None:
         return env.strip().lower() in _TRUTHY
-    import jax
-
-    return jax.default_backend() != "tpu"
+    return True
 
 
 def resolve_flag(config_value: str, env_name: str) -> str:
@@ -107,24 +110,3 @@ def decide(
         return True, "auto (tpu backend)"
     return False, "auto defaults off on non-TPU backends (set the flag " \
                   "to 'on' to run under the interpreter)"
-
-
-_probe_cache: Dict[str, bool] = {}
-
-
-def probe(name: str, smoke: Callable[[], None]) -> bool:
-    """Run a kernel's tiny smoke call once per process; a failure
-    (Mosaic lowering, API drift, OOM) disables the kernel with a
-    warning instead of failing the train that wanted it."""
-    cached = _probe_cache.get(name)
-    if cached is not None:
-        return cached
-    try:
-        smoke()
-        ok = True
-    except Exception as e:  # noqa: BLE001 — any failure means "use the XLA fallback", logged below
-        log.warning("pallas kernel %r failed its smoke probe; falling "
-                    "back to the XLA path: %s: %s", name, type(e).__name__, e)
-        ok = False
-    _probe_cache[name] = ok
-    return ok

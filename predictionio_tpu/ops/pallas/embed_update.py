@@ -86,7 +86,10 @@ def _apply_kernel(idx_sref, idxr_ref, idxc_ref, grad_ref, scale_ref,
     # commute (see module docstring, step 3)
     adj = (idxr_ref[...] == idxc_ref[...]).astype(jnp.float32)   # [T, T]
     delta = -(scale_ref[...] * grad_ref[...])                    # [T, E] f32
-    rows[...] += jnp.dot(adj, delta, preferred_element_type=jnp.float32)
+    # HIGHEST: the TPU's default matmul precision rounds delta to bf16,
+    # which the f32 XLA scatter this kernel stands in for does not
+    rows[...] += jnp.dot(adj, delta, preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)
 
     for k in range(T):
         write_copy(t, k).start()
@@ -112,7 +115,9 @@ def _scatter_apply(table, idx, grad, scale, *, tile, interpret):
         scale = jnp.pad(scale, (0, pad))
     grad = grad.astype(jnp.float32)
     idxr = idx32.reshape(Bp, 1)
-    idxc = idx32.reshape(1, Bp)
+    # one [1, T] row per grid step, the step axis squeezed: a (1, T)
+    # block of a [1, Bp] array is not a legal TPU tile
+    idxc = idx32.reshape(Bp // T, 1, T)
     scale2 = scale.astype(jnp.float32).reshape(Bp, 1)
     vm = pltpu.VMEM
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -120,12 +125,13 @@ def _scatter_apply(table, idx, grad, scale, *, tile, interpret):
         grid=(Bp // T,),
         in_specs=[
             pl.BlockSpec((T, 1), lambda t, idx_s: (t, 0), memory_space=vm),
-            pl.BlockSpec((1, T), lambda t, idx_s: (0, t), memory_space=vm),
+            pl.BlockSpec((None, 1, T), lambda t, idx_s: (t, 0, 0),
+                         memory_space=vm),
             pl.BlockSpec((T, E), lambda t, idx_s: (t, 0), memory_space=vm),
             pl.BlockSpec((T, 1), lambda t, idx_s: (t, 0), memory_space=vm),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # table: DMA'd by row
+            pl.BlockSpec(memory_space=pl.ANY),     # table: DMA'd by row
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((T, E), jnp.float32),
             pltpu.SemaphoreType.DMA((T,)),
@@ -155,18 +161,3 @@ def pallas_rowwise_adagrad(table, acc, idx, grad, lr, eps=1e-8,
     table = _scatter_apply(table, idx, grad, scale,
                            tile=tile, interpret=interpret)
     return table, acc
-
-
-def smoke_at(B=24, E=16):
-    """Compiled end-to-end call for :func:`probe` at the caller's
-    (batch, row-width) — the row-DMA width E and the batch's tile
-    count are what a shape-dependent lowering failure keys on; the
-    table height only scales untouched HBM, so a small N suffices."""
-    N = 64
-    table = jnp.zeros((N, E), jnp.float32)
-    acc = jnp.zeros((N,), jnp.float32)
-    idx = jnp.zeros((B,), jnp.int32)
-    grad = jnp.ones((B, E), jnp.float32)
-    out, acc2 = pallas_rowwise_adagrad(table, acc, idx, grad, 0.01,
-                                       interpret=False)
-    jax.block_until_ready((out, acc2))

@@ -82,8 +82,10 @@ def _tile_logits(u_ref, v_ref, temp, cdt):
     cdt matmul output (f32 MXU accumulation), cdt divide, THEN f32."""
     ut = u_ref[...].astype(cdt)
     vt = v_ref[...].astype(cdt)
+    # the TPU matmul accumulates in 32 bits (Mosaic refuses any other
+    # accumulator); rounding its result to cdt is the reference's output
     L = jax.lax.dot_general(ut, vt, (((1,), (1,)), ((), ())),
-                            preferred_element_type=cdt)
+                            preferred_element_type=jnp.float32).astype(cdt)
     return (L / temp).astype(jnp.float32)
 
 
@@ -119,7 +121,7 @@ def _fwd_kernel(u_ref, v_ref, uir_ref, uic_ref, iir_ref, iic_ref,
                              keepdims=True)
     # column exp-sums cannot accumulate in VMEM (their block revisits
     # non-consecutively under a row-major grid): write one [1, bc]
-    # partial per row-tile; the wrapper reduces the [Sr, Bp] partials
+    # partial per row-tile; the wrapper reduces the [Sr, 1, Bp] partials
     iu_part_ref[...] = jnp.sum(jnp.where(ban_iu, 0.0, e), axis=0,
                                keepdims=True)
 
@@ -232,12 +234,15 @@ def make_flash_ce(u_idx, i_idx, weight, temp, cdt, B,
             ],
             out_specs=[
                 _row_spec(br), _row_spec(br),
-                pl.BlockSpec((1, bc), lambda i, j: (i, j), memory_space=vm),
+                # [Sr, 1, Bp] with the row-tile axis squeezed: a (1, bc)
+                # block of an [Sr, Bp] array is not a legal TPU tile
+                pl.BlockSpec((None, 1, bc), lambda i, j: (i, 0, j),
+                             memory_space=vm),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((Bp, 1), f32),
                 jax.ShapeDtypeStruct((Bp, 1), f32),
-                jax.ShapeDtypeStruct((Sr, Bp), f32),
+                jax.ShapeDtypeStruct((Sr, 1, Bp), f32),
             ],
             interpret=interpret,
         )(up, vp, uir, uic, iir, iic, wr, wc)
@@ -245,7 +250,7 @@ def make_flash_ce(u_idx, i_idx, weight, temp, cdt, B,
         # _DIRECT_EXP_MAX_INV_TEMP): log of the global exp-sums; the
         # never-banned diagonal keeps every sum >= exp(L[b,b]) > 0
         lse_ui = jnp.log(sum_ui[:, 0])
-        lse_iu = jnp.log(jnp.sum(iu_parts, axis=0))
+        lse_iu = jnp.log(jnp.sum(iu_parts, axis=(0, 1)))
         d = diag[:, 0]
         loss = 0.5 * (jnp.sum((lse_ui - d) * w_pad)
                       + jnp.sum((lse_iu - d) * w_pad)) / wsum
@@ -311,20 +316,3 @@ def pallas_blockwise_ce(u, v, u_idx, i_idx, weight, temp, cdt,
     fn = make_flash_ce(u_idx, i_idx, weight, temp, cdt, u.shape[0],
                        interpret=interpret, block=block)
     return fn(u, v)
-
-
-def smoke_at(B=MIN_BATCH, D=8, temp=0.07, cdt=jnp.bfloat16):
-    """Compiled end-to-end call (fwd + bwd) for :func:`probe` AT THE
-    CALLER'S SHAPES: a tiny fixed-shape probe would pass while the
-    real (B, D, block) tiles hit a shape-dependent Mosaic/VMEM failure
-    inside the first jitted train step — the probe must compile the
-    exact kernels the trainer is about to trust. Zero inputs suffice
-    (the never-banned diagonal keeps every LSE finite at L == 0)."""
-    u = jnp.zeros((B, D), jnp.float32)
-    v = jnp.zeros((B, D), jnp.float32)
-    u_idx = jnp.zeros((B,), jnp.int32)
-    i_idx = jnp.zeros((B,), jnp.int32)
-    w = jnp.ones((B,), jnp.float32)
-    fn = make_flash_ce(u_idx, i_idx, w, temp, cdt, B, interpret=False)
-    loss, (du, dv) = jax.value_and_grad(fn, argnums=(0, 1))(u, v)
-    jax.block_until_ready((loss, du, dv))

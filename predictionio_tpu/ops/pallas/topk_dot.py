@@ -26,10 +26,10 @@ iota — one unrolled ``where`` per exclusion column, so the kernel
 never needs a scatter.
 
 Selection contract (ops/pallas/__init__.py): the XLA scorer REMAINS
-the reference and the fallback; ``index/exact.py`` engages this kernel
-per-index via :func:`predictionio_tpu.ops.pallas.decide`
-(``index_kernel="auto"`` + ``PIO_INDEX_KERNEL``), probe-guarded on
-real TPUs, interpret-mode on CPU for tier-1.
+the reference; ``index/exact.py`` engages this kernel per-index via
+:func:`predictionio_tpu.ops.pallas.decide` (``index_kernel="auto"`` +
+``PIO_INDEX_KERNEL``): compiled on a TPU, interpret-mode on CPU for
+tier-1.
 """
 
 from __future__ import annotations
@@ -167,16 +167,3 @@ def topk_dot(q, items, exclude_idx, k, *, block_items=BLOCK_ITEMS,
                        excl.shape[1], block_items=block_items,
                        interpret=interpret)
     return fn(q, pad_items(items, block_items), excl)
-
-
-def smoke_at(n_items, D, B, k, n_excl, *, block_items=BLOCK_ITEMS):
-    """Compiled end-to-end call for :func:`ops.pallas.probe` AT THE
-    CALLER'S SHAPES (same stance as ``flash_ce.smoke_at``: a tiny fixed
-    probe would pass while the real tile shapes hit a shape-dependent
-    Mosaic failure on the first live query). Zero inputs suffice."""
-    fn = make_topk_dot(n_items, D, B, k, n_excl,
-                       block_items=block_items, interpret=False)
-    q = jnp.zeros((B, D), jnp.float32)
-    items = pad_items(jnp.zeros((n_items, D), jnp.float32), block_items)
-    excl = jnp.full((B, n_excl), -1, jnp.int32)
-    jax.block_until_ready(fn(q, items, excl))

@@ -14,10 +14,14 @@ job-startup tax, as JVM spin-up + jar shipping is Spark's
 (SURVEY.md §3.1 runtime notes).
 
 Config:
-  PIO_COMPILE_CACHE_DIR  cache directory (default
-                         $PIO_FS_BASEDIR/compile_cache, i.e. the same
-                         home the localfs storage tier uses)
-  PIO_COMPILE_CACHE=0    disable
+  JAX_COMPILATION_CACHE_DIR  where set, JAX itself keeps the cache
+                             there and this module sets no directory
+                             in code; where not, the cache lives at
+                             ``<checkout>/.pio_run/compile_cache`` — a
+                             fixed path, because the path is part of
+                             the cache key and a directory that moves
+                             never hits
+  PIO_COMPILE_CACHE=0        disable
 
 Multi-process safe: JAX writes entries atomically (temp + rename), so
 N trainers sharing one cache dir (e.g. over NFS) only ever read
@@ -36,18 +40,22 @@ log = logging.getLogger(__name__)
 
 _enabled_dir: Optional[str] = None
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
 
 def cache_dir_default() -> str:
-    base = os.environ.get("PIO_FS_BASEDIR", os.path.expanduser("~/.pio_store"))
-    return os.path.join(base, "compile_cache")
+    return os.path.join(_CHECKOUT, ".pio_run", "compile_cache")
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at the PIO home.
+def enable_persistent_cache() -> Optional[str]:
+    """Turn JAX's persistent compilation cache on, at the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names or else at the checkout's own.
 
     Idempotent; returns the active cache directory (None when disabled
-    via PIO_COMPILE_CACHE=0 or on failure — the framework must run
-    without a writable home, just slower).
+    via PIO_COMPILE_CACHE=0 or when the default directory cannot be
+    created — the framework must run from a read-only checkout, just
+    slower).
     """
     global _enabled_dir
     # hit/miss counters + compile-time histograms (obs/jaxmon.py) come
@@ -59,21 +67,22 @@ def enable_persistent_cache(cache_dir: Optional[str] = None) -> Optional[str]:
         return None
     if _enabled_dir is not None:
         return _enabled_dir
-    path = (cache_dir or os.environ.get("PIO_COMPILE_CACHE_DIR")
-            or cache_dir_default())
-    try:
-        os.makedirs(path, exist_ok=True)
-        import jax
+    import jax
 
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = cache_dir_default()
+        try:
+            os.makedirs(path, exist_ok=True)
+        except OSError as e:
+            log.warning("persistent compilation cache unavailable: %s", e)
+            return None
         jax.config.update("jax_compilation_cache_dir", path)
-        # the default 1s floor skips small serving/eval programs whose
-        # recompiles still dominate /reload latency; cache everything
-        # that took meaningful compile time
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # noqa: BLE001 — cache is an optimization
-        log.warning("persistent compilation cache unavailable: %s", e)
-        return None
+    # the default 1s floor skips small serving/eval programs whose
+    # recompiles still dominate /reload latency; cache everything
+    # that took meaningful compile time
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _enabled_dir = path
     log.info("persistent compilation cache at %s", path)
     return path

@@ -86,7 +86,7 @@ class CircuitOpenError(ConnectionError):
 class RetryBudgetExceeded(ConnectionError):
     """Marker mixin-style error: ``Policy.run`` re-raises the LAST
     underlying failure on exhaustion (callers keep their error
-    taxonomy); this type exists for callers that pass
+    classes); this type exists for callers that pass
     ``raise_exhausted=True`` and want the budget itself named."""
 
     def __init__(self, target: str, attempts: int, last: BaseException):

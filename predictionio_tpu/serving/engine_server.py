@@ -40,8 +40,8 @@ from urllib.parse import urlparse
 
 from predictionio_tpu.core.engine import Engine
 from predictionio_tpu.data.storage import Storage, get_storage
-from predictionio_tpu.obs import (dataobs, flight, health, journal, metrics,
-                                  slo as slo_mod, trace)
+from predictionio_tpu.obs import (dataobs, flight, health, jaxmon, journal,
+                                  metrics, slo as slo_mod, trace)
 from predictionio_tpu.parallel.mesh import MeshContext
 from predictionio_tpu.resilience import chaos
 from predictionio_tpu.resilience.admission import AdmissionController
@@ -897,6 +897,8 @@ class EngineServer(HTTPServerBase):
             m.retrieval_stats() if hasattr(m, "retrieval_stats") else None
             for m in models
         ]
+        from predictionio_tpu.ops.topk import measured_dispatch_latency
+
         return {
             "status": "alive",
             "engineId": self.engine_id,
@@ -916,6 +918,14 @@ class EngineServer(HTTPServerBase):
             "degraded": self.degraded_reason(),
             "storageCircuit": self._storage_breaker.snapshot(),
             "retrieval": retrieval,
+            # which device answers (and the compile-cache outcome of
+            # this process): a 200 alone never shows the chip was used
+            "device": {
+                **jaxmon.device_report(),
+                # the floor ops/topk.py's placement policy routes by
+                # (measured once per process, then cached)
+                "dispatch_latency_sec": measured_dispatch_latency(),
+            },
         }
 
 

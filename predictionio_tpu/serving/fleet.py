@@ -60,6 +60,7 @@ window per replica, shared with the SIGTERM handler).
 
 from __future__ import annotations
 
+import glob
 import json
 import logging
 import os
@@ -340,10 +341,43 @@ def threaded_fleet(n: int, factory: Callable[[str], Any],
     return [ThreadedReplica(f"{prefix}{i}", factory) for i in range(n)]
 
 
+def local_chip_count() -> int:
+    """TPU chips of this host, counted from their device nodes — the
+    parent (router + supervisor) must learn it WITHOUT initialising a
+    jax backend, or it would hold the chips its replicas need. 0 when
+    there are none, or when the process is held to the CPU backend."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return 0
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def chip_env(i: int) -> Dict[str, str]:
+    """The environment that shows a child chip ``i`` and only that
+    chip (libtpu reads it at start-up; both spellings of the bounds,
+    because the host's own environment may carry either)."""
+    one = "1,1,1"
+    return {"TPU_VISIBLE_CHIPS": str(i),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": one, "TPU_PROCESS_BOUNDS": one,
+            "TPU_CHIPS_PER_HOST_BOUNDS": one, "TPU_HOST_BOUNDS": one}
+
+
 def subprocess_fleet(n: int, argv: List[str],
                      env: Optional[Dict[str, str]] = None,
                      prefix: str = "r") -> List[SubprocessReplica]:
-    return [SubprocessReplica(f"{prefix}{i}", argv, env)
+    """``n`` child replicas; on a host with TPU chips replica *i* gets
+    chip *i* and only that chip (a chip belongs to one process — with
+    the parent's environment every child would try to take them all).
+    Fewer chips than replicas is an error here, at start, not a
+    restart loop later. On the CPU backend nothing is assigned."""
+    chips = local_chip_count()
+    if chips and n > chips:
+        raise ValueError(
+            f"{n} subprocess replicas need {n} TPU chips (one each), "
+            f"but this host has {chips}: lower --replicas to {chips} "
+            "or fewer")
+    return [SubprocessReplica(f"{prefix}{i}", argv,
+                              {**(env or {}), **(chip_env(i) if chips else {})})
             for i in range(n)]
 
 

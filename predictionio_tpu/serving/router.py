@@ -271,7 +271,7 @@ class _ReplicaClient:
                 headers: Dict[str, str], timeout: float,
                 replay_safe: bool = True):
         """(status, body bytes, headers dict); transport problems raise
-        ReplicaTransportError so the policy/breaker taxonomy applies.
+        ReplicaTransportError so the policy/breaker error classes apply.
 
         A POOLED connection that dies before yielding any response is
         retried ONCE on a fresh connection silently: the replica's

@@ -235,6 +235,17 @@ def cmd_train(args) -> int:
         workflow_params=wp,
     )
     _p(f"Training completed: engine instance {instance.id} ({instance.status})")
+    # one machine-readable line: a train result always names the device
+    # it ran on, where the seconds went and which kernels were engaged
+    from predictionio_tpu.obs import jaxmon, perfacct
+
+    runs = perfacct.LEDGER.snapshot().get("runs") or []
+    _p(json.dumps({"train_report": {
+        "instance": instance.id,
+        **jaxmon.device_report(),
+        "stages_sec": runs[-1].get("stages") if runs else None,
+        "trainers": jaxmon.TRAINER_REPORTS,
+    }}))
     return 0 if instance.status == "COMPLETED" else 1
 
 
@@ -275,8 +286,6 @@ def cmd_eval(args) -> int:
 
 def cmd_deploy(args) -> int:
     from predictionio_tpu.obs import metrics
-    from predictionio_tpu.serving.engine_server import EngineServer
-    from predictionio_tpu.serving.http import install_drain_handler
 
     replicas = (args.replicas if args.replicas is not None
                 else metrics.env_int("PIO_REPLICAS", 1))
@@ -286,6 +295,12 @@ def cmd_deploy(args) -> int:
                            "while the rest serve the baseline")
     if replicas > 1:
         return _deploy_fleet(args, replicas)
+    # imported only on the single-server lane: the engine server pulls
+    # in jax, and a fleet's router process must stay off it (a parent
+    # that touched jax could take the chip its replicas need)
+    from predictionio_tpu.serving.engine_server import EngineServer
+    from predictionio_tpu.serving.http import install_drain_handler
+
     variant = _load_variant(args.engine_json)
     engine = variant.create_engine()
     engine_id = args.engine_id or variant.raw.get("engineId") or variant.engine_factory
@@ -320,7 +335,6 @@ def _deploy_fleet(args, replicas: int) -> int:
     from predictionio_tpu.serving.http import (drain_timeout,
                                                install_drain_handler)
     from predictionio_tpu.serving.router import QueryRouter
-    from predictionio_tpu.workflow.deploy import latest_completed_instance_id
 
     variant = _load_variant(args.engine_json)
     engine_id = (args.engine_id or variant.raw.get("engineId")
@@ -356,15 +370,21 @@ def _deploy_fleet(args, replicas: int) -> int:
             argv += ["--accesskey", args.accesskey]
         if args.log_url:
             argv += ["--log-url", args.log_url]
-        members = subprocess_fleet(replicas, argv)
+        try:
+            members = subprocess_fleet(replicas, argv)
+        except ValueError as e:   # fewer chips than replicas
+            raise CommandError(str(e)) from e
 
     from predictionio_tpu.data.storage import get_storage
 
     storage = get_storage()
     fleet = FleetSupervisor(
         members,
-        version_source=lambda: latest_completed_instance_id(
-            storage, engine_id, args.engine_version, variant.id),
+        # workflow.deploy.latest_completed_instance_id, spelled out:
+        # importing that module would pull jax into the router process
+        version_source=lambda: getattr(
+            storage.engine_instances().get_latest_completed(
+                engine_id, args.engine_version, variant.id), "id", None),
         canary_mode=True if getattr(args, "canary", False) else None,
     ).start()
     router = QueryRouter(fleet, host=args.ip, port=args.port)

@@ -27,7 +27,7 @@ from predictionio_tpu.data.metadata import EngineInstance, Model
 from predictionio_tpu.data.storage import Storage, get_storage
 from predictionio_tpu.obs import (dataobs, health, jaxmon, memacct, perfacct,
                                   profiler)
-from predictionio_tpu.parallel.mesh import MeshContext
+from predictionio_tpu.parallel.mesh import MeshContext, create_mesh
 from predictionio_tpu.workflow.config import WorkflowParams
 
 log = logging.getLogger(__name__)
@@ -176,7 +176,14 @@ def run_train(
     distributed = mh.initialize_from_env()
     enable_persistent_cache()
     storage = storage or get_storage()
-    ctx = ctx or MeshContext()
+    if ctx is None:
+        # the default mesh: every visible device on ``data`` (a lone
+        # device needs none) — without it a `pio train` on a four-chip
+        # host used one chip and left three idle
+        import jax
+
+        ctx = MeshContext(
+            mesh=create_mesh() if jax.device_count() > 1 else None)
     wp = workflow_params or WorkflowParams()
     writer = not distributed or mh.process_index() == 0
     instance_id = mh.broadcast_string(uuid.uuid4().hex)

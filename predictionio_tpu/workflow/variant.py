@@ -23,10 +23,14 @@ from __future__ import annotations
 import hashlib
 import json
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from typing import TYPE_CHECKING, Any, Dict, Optional, Tuple
 
-from predictionio_tpu.core.engine import Engine, resolve_engine_factory
-from predictionio_tpu.core.params import EngineParams
+# predictionio_tpu.core pulls in jax; a variant is also read by the
+# fleet's router process, which must stay off it (serving/fleet.py) —
+# so the engine machinery is imported where an engine is made
+if TYPE_CHECKING:
+    from predictionio_tpu.core.engine import Engine
+    from predictionio_tpu.core.params import EngineParams
 
 
 def _load_project_module(path: str):
@@ -116,6 +120,8 @@ class EngineVariant:
                 return factory_from_object(
                     getattr(module, attr), self.engine_factory
                 )()
+        from predictionio_tpu.core.engine import resolve_engine_factory
+
         return resolve_engine_factory(self.engine_factory)()
 
     def engine_params(self, engine: Optional[Engine] = None) -> EngineParams:

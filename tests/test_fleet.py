@@ -824,10 +824,11 @@ def test_fleet_chaos_anomaly_journal_e2e(memory_storage, monkeypatch,
     monkeypatch.setenv("PIO_JOURNAL_PATH", str(sink))
     # a fresh timeline focused on the serving p99 (the rate/staleness
     # collectors would add unrelated series whose test-paced samples
-    # could alarm on their own); capacity 24 so the post-recovery ring
-    # turns over inside the test
+    # could alarm on their own); capacity 40 so the calm baseline
+    # survives the chaos phase and the post-recovery ring still turns
+    # over inside the test
     tl = timeline_mod.Timeline(
-        interval=0.0, capacity=24,
+        interval=0.0, capacity=40,
         collectors=[timeline_mod.quantile_collector(
             "pio_serving_request_seconds", 0.99, "serve_p99_ms",
             scale=1e3)])
@@ -856,6 +857,21 @@ def test_fleet_chaos_anomaly_journal_e2e(memory_storage, monkeypatch,
     with running_fleet(memory_storage, engine, n=2,
                        engine_name="journal_e2e") as (fleet, router,
                                                       base):
+        # a baseline the test controls: the series is the p99 of a
+        # CUMULATIVE histogram, so with only the 16 real queries below
+        # it is their maximum — and one start-up straggler (first
+        # dispatch, thread spin-up, a loaded CI host) landing past the
+        # sentinel's 12-point baseline window reads as a step up before
+        # any chaos exists. 250 observations of 20 ms pin the calm p99
+        # inside their bucket (a straggler or two is < 1% of the mass),
+        # while three injected 250 ms queries below are > 1% and still
+        # move it.
+        from predictionio_tpu.obs import metrics as metrics_mod
+
+        calm = metrics_mod.REGISTRY.get(
+            "pio_serving_request_seconds").labels("journal_e2e")
+        for _ in range(250):
+            calm.observe(0.020)
         for _ in range(16):
             status, body, _ = post(base + "/queries.json")
             assert status == 200, body
@@ -864,9 +880,21 @@ def test_fleet_chaos_anomaly_journal_e2e(memory_storage, monkeypatch,
         assert series not in report["active"], report  # calm baseline
 
         chaos.configure("batcher@r1:latency:250ms")  # journals "chaos"
-        for _ in range(8):
+        # the router picks between the two replicas at random: send
+        # until three queries have met the fault on r1
+        slow = 0
+        for _ in range(24):
+            t0 = time.perf_counter()
             status, body, _ = post(base + "/queries.json", timeout=30)
             assert status == 200, body
+            slow += time.perf_counter() - t0 >= 0.25
+            tl.sample(now=time.time())
+            if slow >= 3:
+                break
+        assert slow >= 3
+        # the sentinel wants the shift sustained, not one point: the
+        # cumulative p99 stays where the third slow query put it
+        for _ in range(5):
             tl.sample(now=time.time())
         report = anomaly.SENTINEL.scan(now=time.time())
         assert series in report["active"], report
@@ -879,7 +907,7 @@ def test_fleet_chaos_anomaly_journal_e2e(memory_storage, monkeypatch,
         assert series in out and "chaos" in out
 
         chaos.clear()
-        for _ in range(30):
+        for _ in range(48):
             status, body, _ = post(base + "/queries.json")
             assert status == 200, body
             tl.sample(now=time.time())

@@ -4,7 +4,7 @@ surfaces (/admin/memory, pio mem, dashboard /memory, timeline,
 benchcmp keys).
 
 Acceptance pinned here:
-  - on CPU with PIO_PEAK_HBM_BYTES set, GET /admin/memory attribution
+  - on CPU with the host-memory capacity steered by the test, GET /admin/memory attribution
     sums to within 1% of the ledger's registered nbytes for every
     loaded model;
   - a fleet serving a baseline REFUSES an oversized candidate at
@@ -131,7 +131,7 @@ def test_unpickle_registers_the_load_seam():
 # -- capacity / headroom / probe ----------------------------------------------
 
 def test_env_basis_headroom_and_probe(monkeypatch):
-    monkeypatch.setenv("PIO_PEAK_HBM_BYTES", "10000")
+    monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 10000)
 
     class Owner:
         pass
@@ -139,7 +139,7 @@ def test_env_basis_headroom_and_probe(monkeypatch):
     o = Owner()
     memacct.LEDGER.register(o, "m", "factors", 4000)
     report = memacct.capacity_report()
-    assert report["basis"] == "env"
+    assert report["basis"] == "host_memory"
     assert report["capacity_bytes"] == 10000
     assert report["in_use_bytes"] == 4000
     assert report["headroom_bytes"] == 6000
@@ -202,13 +202,12 @@ def test_peak_from_compiled_fallback_contract():
     assert memacct.peak_from_compiled(Raises()) is None
 
 
-def test_peak_from_jitted_on_cpu_and_note():
+def test_peak_from_compiled_on_cpu_and_note():
     import jax
 
     fn = jax.jit(lambda x: x * 2.0)
     x = np.ones((16, 16), np.float32)
-    fn(x)
-    peak = memacct.peak_from_jitted(fn, x)
+    peak = memacct.peak_from_compiled(fn.lower(x).compile())
     # CPU jax reports CompiledMemoryStats here; either way the
     # contract holds: an int or the analytic-fallback None
     assert peak is None or peak >= 2 * x.nbytes
@@ -248,7 +247,7 @@ def _store_blob(storage, instance_id: str, nbytes: int) -> None:
 
 def test_preflight_refuses_forces_and_disables(memory_storage,
                                                monkeypatch):
-    monkeypatch.setenv("PIO_PEAK_HBM_BYTES", "1000")
+    monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 1000)
     _store_blob(memory_storage, "fat", 900)   # estimate 1800 > 1000
     with pytest.raises(memacct.PreflightRefused) as exc:
         memacct.preflight_check("fat", memory_storage)
@@ -281,7 +280,7 @@ def test_engine_server_reload_answers_507_then_force(memory_storage,
     base = f"http://127.0.0.1:{server.port}"
     try:
         train_const(memory_storage)  # the candidate
-        monkeypatch.setenv("PIO_PEAK_HBM_BYTES", "8")
+        monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 8)
         status, body = get_json(base + "/reload")
         assert status == 507, body
         assert body["preflight"]["result"] == "refused"
@@ -343,7 +342,7 @@ def test_fleet_refuses_oversized_candidate_then_force(memory_storage,
         _, candidate = train_const(memory_storage)
         assert candidate.id != baseline.id
         # every const-model blob estimates far beyond 8 bytes
-        monkeypatch.setenv("PIO_PEAK_HBM_BYTES", "8")
+        monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 8)
         failures, results = [], []
         with _load(base, failures, results):
             # rolling swap through the router: starts, then every
@@ -402,7 +401,7 @@ def test_force_started_canary_promotes_with_force(memory_storage,
     engine, baseline = train_const(memory_storage)
     with canary_fleet(memory_storage, engine, n=2) as (fleet, _r, _b):
         _, candidate = train_const(memory_storage)
-        monkeypatch.setenv("PIO_PEAK_HBM_BYTES", "8")
+        monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 8)
         assert fleet.start_canary(force=True)
         _await(lambda: fleet.canary().get("active"),
                message="forced canary active")
@@ -417,8 +416,8 @@ def test_force_started_canary_promotes_with_force(memory_storage,
 def test_admin_memory_sums_match_ledger_within_1pct(memory_storage,
                                                     monkeypatch):
     """Acceptance: /admin/memory attribution vs the ledger's registered
-    nbytes, per loaded model, on CPU with PIO_PEAK_HBM_BYTES set."""
-    monkeypatch.setenv("PIO_PEAK_HBM_BYTES", str(1 << 30))
+    nbytes, per loaded model, on CPU with the host-memory capacity steered by the test."""
+    monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 1 << 30)
     model = _als_model()
     model.retrieval_index()
     engine, _ = train_const(memory_storage)
@@ -437,7 +436,7 @@ def test_admin_memory_sums_match_ledger_within_1pct(memory_storage,
                 ledger[name], rel=0.01)
             assert block["total_bytes"] == sum(
                 block["components"].values())
-        assert served["basis"] == "env"
+        assert served["basis"] == "host_memory"
         assert served["capacity_bytes"] == (1 << 30)
         assert served["headroom_bytes"] == (
             served["capacity_bytes"] - served["in_use_bytes"])
@@ -449,7 +448,7 @@ def test_pio_mem_cli_renders_both_modes(memory_storage, monkeypatch,
                                         capsys):
     from predictionio_tpu.tools import cli
 
-    monkeypatch.setenv("PIO_PEAK_HBM_BYTES", str(1 << 30))
+    monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 1 << 30)
     model = _als_model()  # kept referenced: the ledger holds weakrefs
     memacct.note_train_peak("als", 4096, source="analytic")
     # in-process
@@ -475,7 +474,7 @@ def test_pio_mem_cli_renders_both_modes(memory_storage, monkeypatch,
 def test_dashboard_memory_panel(memory_storage, monkeypatch):
     from predictionio_tpu.tools.dashboard import DashboardServer
 
-    monkeypatch.setenv("PIO_PEAK_HBM_BYTES", str(1 << 30))
+    monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 1 << 30)
     model = _als_model()  # kept referenced: the ledger holds weakrefs
     server = DashboardServer(storage=memory_storage, host="127.0.0.1",
                              port=0).start()
@@ -498,14 +497,14 @@ def test_dashboard_memory_panel(memory_storage, monkeypatch):
 def test_timeline_mem_series(monkeypatch):
     from predictionio_tpu.obs.timeline import Timeline
 
-    monkeypatch.setenv("PIO_PEAK_HBM_BYTES", str(1 << 20))
+    monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 1 << 20)
     model = _als_model()  # kept referenced: the ledger holds weakrefs
     tl = Timeline(interval=0.0)
     assert tl.sample(force=True)
     series = tl.series()["series"]
     assert "mem.headroom" in series
     assert "mem.model_bytes.als" in series
-    # the headroom sample is capacity - ledger total (env basis; the
+    # the headroom sample is capacity - ledger total (host_memory basis; the
     # ring stores 6 significant figures, hence the loose tolerance)
     assert series["mem.headroom"][-1][1] == pytest.approx(
         (1 << 20) - memacct.LEDGER.total_bytes(), rel=1e-4)
@@ -518,7 +517,7 @@ def test_snapshot_cadence_refreshes_gauges(monkeypatch):
     only post-train."""
     from predictionio_tpu.obs import flight
 
-    monkeypatch.setenv("PIO_PEAK_HBM_BYTES", "5000")
+    monkeypatch.setattr(memacct, "host_memory_bytes", lambda: 5000)
 
     class Owner:
         pass

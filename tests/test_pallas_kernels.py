@@ -254,18 +254,26 @@ def test_trainer_kernels_end_to_end_match_xla():
                                rtol=1e-3, atol=1e-4)
 
 
-def test_probe_failure_disables_kernel(monkeypatch):
-    """A smoke-probe crash must mean 'XLA fallback', never a failed
-    train (the Mosaic-regression safety net)."""
-    monkeypatch.setattr(plk, "_probe_cache", {})
+def test_engaged_kernel_compile_failure_raises(monkeypatch):
+    """An engaged kernel that the compiler refuses must fail the train
+    with the compiler's message — there is no XLA fallback that could
+    let a chip run 'pass' on a path nobody chose."""
+    import predictionio_tpu.ops.twotower as tt
 
-    def boom():
-        raise RuntimeError("mosaic said no")
+    def refuse(*args, **kwargs):
+        raise ValueError("mosaic said no: block shape (1, 512)")
 
-    assert plk.probe("boom_kernel", boom) is False
-    # memoized: the second call doesn't re-run the probe
-    assert plk.probe("boom_kernel", boom) is False
-    assert plk._probe_cache["boom_kernel"] is False
+    monkeypatch.setattr(tt._pl_flash, "pallas_blockwise_ce", refuse)
+    u, i, n_users, n_items = _positives()
+    cfg = TwoTowerConfig(dim=8, epochs=1, batch_size=128, seed=3,
+                         flash_ce_kernel="on")
+    tr = tt.TwoTowerTrainer((u, i, None), n_users, n_items, cfg)
+    assert tr.kernel_plan["flash_ce"] is True
+    with pytest.raises(ValueError, match="mosaic said no"):
+        tr.run()
+    # and the ops/pallas package offers nothing that turns a failure
+    # into False
+    assert not hasattr(plk, "probe")
 
 
 def test_flash_ce_weight_grad_raises_not_zero():
@@ -285,21 +293,18 @@ def test_flash_ce_weight_grad_raises_not_zero():
         jax.grad(loss_of_w)(w)
 
 
-def test_pallas_import_failure_degrades_to_xla(monkeypatch):
-    """An import-time break in jax.experimental.pallas (API churn)
-    must leave every two-tower train on the XLA paths with the reason
-    recorded — even with the kernels requested 'on' — not raise."""
+def test_missing_pallas_with_flag_on_raises(monkeypatch):
+    """A two-tower module that lost its Pallas kernels must not train
+    on the XLA paths when a kernel was asked for: the import is no
+    longer guarded (no ``_PALLAS_IMPORT_ERROR`` to degrade on), and a
+    trainer without the module raises instead of planning a fallback."""
     import predictionio_tpu.ops.twotower as tt
 
+    assert not hasattr(tt, "_PALLAS_IMPORT_ERROR")
     monkeypatch.setattr(tt, "_pl_flash", None)
     monkeypatch.setattr(tt, "_pl_embed", None)
-    monkeypatch.setattr(tt, "_PALLAS_IMPORT_ERROR",
-                        "ImportError: no pallas today")
     u, i, n_users, n_items = _positives()
     cfg = TwoTowerConfig(dim=8, epochs=1, batch_size=128, seed=3,
                          flash_ce_kernel="on", embed_update_kernel="on")
-    tr = tt.TwoTowerTrainer((u, i, None), n_users, n_items, cfg)
-    assert tr.kernel_plan["flash_ce"] is False
-    assert "unavailable" in tr.kernel_plan["flash_ce_reason"]
-    assert tr.kernel_plan["embed_update"] is False
-    assert tr.run() and len(tr.run()) == 1   # trains on the XLA path
+    with pytest.raises(AttributeError):
+        tt.TwoTowerTrainer((u, i, None), n_users, n_items, cfg)

@@ -62,6 +62,39 @@ def _reset_perfacct():
 # MFU: cost_analysis path + analytic fallback
 # ---------------------------------------------------------------------------
 
+@pytest.fixture
+def v5e_peaks(monkeypatch):
+    """Steer the accounting onto the v5e row: on the CPU backend no
+    utilisation is computed, so tests of the MFU arithmetic and its
+    surfaces hand it the chip's peaks themselves."""
+    monkeypatch.setattr(perfacct, "device_peaks",
+                        lambda: perfacct.DEVICE_PEAKS["TPU v5 lite"])
+
+
+def test_no_utilisation_on_cpu_and_unknown_tpu_kind_raises(monkeypatch):
+    """CPU backend: no MFU, no roofline position, no gauge child — not
+    one against a v5e peak. A TPU whose kind has no row is an error."""
+    import jax
+
+    assert perfacct.device_peaks() is None
+    assert perfacct.mfu(1e9, 0.01) is None
+    acct = StepAccountant("cpu-only-model", 1e9, 1e6)
+    assert acct.observe(0.01) is None and acct.last_mfu is None
+    exported = metrics.REGISTRY.render()
+    assert 'pio_train_mfu{model="cpu-only-model"}' not in exported
+    assert 'pio_roofline_position{model="cpu-only-model"}' not in exported
+
+    class FakeTpu:
+        platform = "tpu"
+        device_kind = "TPU v9 imaginary"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu()])
+    with pytest.raises(LookupError, match="TPU v9 imaginary"):
+        perfacct.device_peaks()
+    FakeTpu.device_kind = "TPU v5 lite"
+    assert perfacct.device_peaks().bf16_flops == 197e12
+
+
 def test_costs_from_compiled_real_cpu_executable():
     """A real CPU-compiled step: cost_analysis either reports flops
     (the primary path) or the helper declines with None — it must
@@ -76,7 +109,7 @@ def test_costs_from_compiled_real_cpu_executable():
         assert flops > 0 and bytes_accessed >= 0
 
 
-def test_accountant_falls_back_when_cost_analysis_fails():
+def test_accountant_falls_back_when_cost_analysis_fails(v5e_peaks):
     class Boom:
         def cost_analysis(self):
             raise RuntimeError("no cost model on this backend")
@@ -124,7 +157,7 @@ def test_twotower_matmul_flops_matches_trainer_method():
         trainer.batch, cfg.dim, _tail_widths(cfg))
 
 
-def test_twotower_run_populates_live_mfu_gauge():
+def test_twotower_run_populates_live_mfu_gauge(v5e_peaks):
     """Acceptance: a CPU train run sets pio_train_mfu > 0 via either
     the cost-analysis or the analytic fallback path."""
     from predictionio_tpu.ops.twotower import TwoTowerConfig, TwoTowerTrainer
@@ -389,7 +422,7 @@ def test_timeline_broken_collector_isolated():
     assert t.series()["series"]["ok"] == [[1.0, 7.0]]
 
 
-def test_default_collectors_pick_up_mfu_and_staleness():
+def test_default_collectors_pick_up_mfu_and_staleness(v5e_peaks):
     StepAccountant("twotower", 1e9).observe(0.01)
     perfacct.LEDGER.note_ingest()
     t = Timeline(interval=0.0, capacity=8)
@@ -438,8 +471,7 @@ def dash_server(memory_storage):
     flight.RECORDER.clear()
 
 
-def test_admin_timeline_collects_samples_at_test_cadence(
-        dash_server, monkeypatch):
+def test_admin_timeline_collects_samples_at_test_cadence(dash_server, monkeypatch, v5e_peaks):
     """Acceptance: GET /admin/timeline returns >= 2 samples for a
     tracked gauge at the test cadence (interval 0 -> every read
     samples)."""
@@ -495,7 +527,7 @@ def test_admin_timeline_and_tail_auth_matrix(dash_server, monkeypatch):
     assert status == 200
 
 
-def test_dashboard_timeline_panel_renders(dash_server):
+def test_dashboard_timeline_panel_renders(dash_server, v5e_peaks):
     StepAccountant("twotower", 1e9).observe(0.01)
     base = f"http://127.0.0.1:{dash_server.port}"
     status, _, body = http("GET", f"{base}/timeline")
@@ -507,7 +539,7 @@ def test_dashboard_timeline_panel_renders(dash_server):
 # pio top
 # ---------------------------------------------------------------------------
 
-def test_pio_top_once_json_shape(capsys, monkeypatch):
+def test_pio_top_once_json_shape(capsys, monkeypatch, v5e_peaks):
     monkeypatch.setenv("PIO_TIMELINE_INTERVAL_SEC", "0")
     StepAccountant("twotower", 1e9).observe(0.01)
     from predictionio_tpu.tools.cli import main
@@ -522,7 +554,7 @@ def test_pio_top_once_json_shape(capsys, monkeypatch):
     assert point[1] > 0
 
 
-def test_pio_top_once_text_frame(capsys, monkeypatch):
+def test_pio_top_once_text_frame(capsys, monkeypatch, v5e_peaks):
     monkeypatch.setenv("PIO_TIMELINE_INTERVAL_SEC", "0")
     StepAccountant("twotower", 1e9).observe(0.01)
     perfacct.LEDGER.start_run("frame-run")

@@ -69,7 +69,7 @@ def test_host_respects_max_exclude_cap(factors):
 
 def test_auto_routing_crossover(factors, monkeypatch):
     scorer = T.TopKScorer(factors, placement="auto")
-    # a slow (tunneled) backend: lone queries must go host-side
+    # a backend with a slow dispatch: lone queries must go host-side
     monkeypatch.setattr(T, "_dispatch_latency", 0.1)
     assert scorer._route(1) == "host"
     # ...but a big batch amortizes the dispatch floor

@@ -1,0 +1,98 @@
+"""The main path's Pallas kernels, compiled for a described v5e chip.
+
+Interpret mode (tests/test_pallas_kernels.py) cannot show what the
+chip's compiler refuses — a block that is not a legal TPU tile, a
+matmul accumulator that is not 32-bit — so each kernel is also compiled
+here at the shapes the repo advertises, for a ``v5e:2x2`` topology that
+is described, not attached. Nothing runs; a compile that passes says
+nothing about results or times.
+
+All of it lives in this ONE file and behind fixtures: only one process
+may load the TPU's library, so the topology is described inside a
+fixture of the worker that got this file, never at import.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip (the next one warns
+    and compiles again): turn the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _flash_ce(B, D):
+    from predictionio_tpu.ops.pallas import flash_ce
+
+    def fwd_bwd(u, v, u_idx, i_idx, w):
+        ce = flash_ce.make_flash_ce(u_idx, i_idx, w, 0.07, jnp.bfloat16, B)
+        return jax.value_and_grad(ce, argnums=(0, 1))(u, v)
+
+    f32, i32 = jnp.float32, jnp.int32
+    return fwd_bwd, [((B, D), f32), ((B, D), f32), ((B,), i32), ((B,), i32),
+                     ((B,), f32)], 3
+
+
+def _topk_dot(n_items, D, B, k=16, n_excl=8):
+    from predictionio_tpu.ops.pallas import topk_dot
+
+    fn = topk_dot.make_topk_dot(n_items, D, B, k, n_excl)
+    padded = -(-n_items // topk_dot.BLOCK_ITEMS) * topk_dot.BLOCK_ITEMS
+    return fn, [((B, D), jnp.float32), ((padded, D), jnp.float32),
+                ((B, n_excl), jnp.int32)], 1
+
+
+def _embed_update(N, B, E):
+    from predictionio_tpu.ops.pallas import embed_update
+
+    fn = functools.partial(embed_update.pallas_rowwise_adagrad, lr=0.01)
+    f32 = jnp.float32
+    return fn, [((N, E), f32), ((N,), f32), ((B,), jnp.int32),
+                ((B, E), f32)], 1
+
+
+@pytest.mark.parametrize("build,args", [
+    (_flash_ce, (8192, 128)),
+    (_flash_ce, (4096, 64)),
+    (_topk_dot, (26_744, 64, 1)),
+    (_topk_dot, (26_744, 64, 32)),
+    (_topk_dot, (1_000_000, 128, 1)),
+    (_embed_update, (1_000_000, 8192, 128)),
+], ids=["flash_ce-8192x128", "flash_ce-4096x64", "topk_dot-26744x64-B1",
+        "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
+        "embed_update-1M-8192x128"])
+def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
+    fn, shapes, n_kernels = build(*args)
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= n_kernels
