@@ -26,6 +26,7 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from predictionio_tpu.index import AnnIndex, MEASURED_RECALL
+from predictionio_tpu.obs import trace
 from predictionio_tpu.ops import pallas as plk
 
 log = logging.getLogger(__name__)
@@ -202,15 +203,24 @@ class ExactIndex(AnnIndex):
                     np.zeros((B, 0), np.int32))
         from predictionio_tpu.ops.topk import _prepare_score_inputs
 
-        q2, excl, k_eff, k_bucket, B = _prepare_score_inputs(
-            query_vecs, k, exclude, len(self), self.max_exclude)
-        if not self._kernel_eligible(q2.shape[0], excl.shape[1], k_bucket):
-            return self._fallback().score(query_vecs, k, exclude)
-        fn = self._fn(q2.shape[0], excl.shape[1], k_bucket)
-        scores, idx = fn(q2, self._device_items(), excl)
-        self.routes["kernel"] += 1
-        return (np.asarray(scores)[:B, :k_eff],
-                np.asarray(idx)[:B, :k_eff])
+        # enqueue: pad, transfer, the jitted call returning; fetch: the
+        # wait for the device and the copy back
+        with trace.device_span("index.search"):
+            with trace.device_span("index.enqueue"):
+                q2, excl, k_eff, k_bucket, B = _prepare_score_inputs(
+                    query_vecs, k, exclude, len(self), self.max_exclude)
+                eligible = self._kernel_eligible(
+                    q2.shape[0], excl.shape[1], k_bucket)
+                if eligible:
+                    fn = self._fn(q2.shape[0], excl.shape[1], k_bucket)
+                    scores, idx = fn(q2, self._device_items(), excl)
+            if not eligible:
+                return self._fallback().score_unspanned(
+                    query_vecs, k, exclude)
+            self.routes["kernel"] += 1
+            with trace.device_span("index.fetch"):
+                return (np.asarray(scores)[:B, :k_eff],
+                        np.asarray(idx)[:B, :k_eff])
 
     def stats(self) -> Dict[str, object]:
         out = super().stats()

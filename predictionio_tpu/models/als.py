@@ -23,6 +23,7 @@ import numpy as np
 from predictionio_tpu.core import Algorithm, SanityCheck
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.data.bimap import BiMap
+from predictionio_tpu.obs import trace
 from predictionio_tpu.ops.als import ALSConfig, ALSFactors, als_train
 from predictionio_tpu.ops.topk import TopKScorer
 from predictionio_tpu.parallel.mesh import MeshContext
@@ -283,10 +284,12 @@ class ALSModel:
         exclude_items: Sequence[str] = (),
         candidate_items: Optional[Sequence[str]] = None,
     ) -> List[Tuple[str, float]]:
-        row = self.user_ids.get(user_id)
-        if row is None:
-            return []
-        exclude = {self.item_ids[i] for i in exclude_items if i in self.item_ids}
+        with trace.device_span("engine.prepare"):
+            row = self.user_ids.get(user_id)
+            if row is None:
+                return []
+            exclude = {self.item_ids[i] for i in exclude_items
+                       if i in self.item_ids}
         if candidate_items is not None:
             cand = np.array(
                 sorted(
@@ -313,12 +316,13 @@ class ALSModel:
         else:
             scores, idx = self.retrieval_index().search(
                 self.user_factors[row], num, excl)
-        inv = self.item_ids.inverse()
-        return [
-            (inv[int(i)], float(s))
-            for s, i in zip(scores[0], idx[0])
-            if s > -1e29 and int(i) >= 0
-        ]
+        with trace.device_span("engine.decode"):
+            inv = self.item_ids.inverse()
+            return [
+                (inv[int(i)], float(s))
+                for s, i in zip(scores[0], idx[0])
+                if s > -1e29 and int(i) >= 0
+            ]
 
     def similar_items(
         self,
@@ -331,18 +335,20 @@ class ALSModel:
         item table, the query item excluded. Cosine similarity when the
         table is row-normalized (two-tower towers are; raw ALS factors
         score dot-similarity, popularity-weighted)."""
-        row = self.item_ids.get(item_id)
-        if row is None:
-            return []
-        exclude = {self.item_ids[i] for i in exclude_items
-                   if i in self.item_ids} - {row}
-        # self-exclusion goes LAST: the exact backend caps exclusion
-        # lists at max_exclude keeping the NEWEST (rightmost) entries,
-        # so an oversize blacklist may drop itself but never the query
-        # item — and the result filter below backstops even that
-        excl = np.fromiter(
-            list(exclude) + [row], dtype=np.int32,
-            count=len(exclude) + 1)
+        with trace.device_span("engine.prepare"):
+            row = self.item_ids.get(item_id)
+            if row is None:
+                return []
+            exclude = {self.item_ids[i] for i in exclude_items
+                       if i in self.item_ids} - {row}
+            # self-exclusion goes LAST: the exact backend caps exclusion
+            # lists at max_exclude keeping the NEWEST (rightmost)
+            # entries, so an oversize blacklist may drop itself but
+            # never the query item — and the result filter below
+            # backstops even that
+            excl = np.fromiter(
+                list(exclude) + [row], dtype=np.int32,
+                count=len(exclude) + 1)
         if self.sharded_axis is not None:
             # sharded serving keeps the mesh scorer (same stance as
             # recommend: no single-device index over a sharded catalog)
@@ -351,12 +357,13 @@ class ALSModel:
         else:
             scores, idx = self.retrieval_index().search(
                 self.item_factors[row], num, excl)
-        inv = self.item_ids.inverse()
-        return [
-            (inv[int(i)], float(s))
-            for s, i in zip(scores[0], idx[0])
-            if s > -1e29 and int(i) >= 0 and int(i) != row
-        ]
+        with trace.device_span("engine.decode"):
+            inv = self.item_ids.inverse()
+            return [
+                (inv[int(i)], float(s))
+                for s, i in zip(scores[0], idx[0])
+                if s > -1e29 and int(i) >= 0 and int(i) != row
+            ]
 
 
 def apply_rows_patch(model: ALSModel, patch: dict) -> bool:
@@ -675,27 +682,33 @@ class ALSAlgorithm(Algorithm):
         Queries for known users are scored as one batched matmul+top-k;
         unknown users fall back to empty results.
         """
-        known = [(i, q) for i, q in queries if str(q["user"]) in model.user_ids]
-        unknown = [(i, q) for i, q in queries if str(q["user"]) not in model.user_ids]
-        out = [(i, {"itemScores": []}) for i, q in unknown]
+        with trace.device_span("engine.prepare"):
+            known = [(i, q) for i, q in queries
+                     if str(q["user"]) in model.user_ids]
+            unknown = [(i, q) for i, q in queries
+                       if str(q["user"]) not in model.user_ids]
+            out = [(i, {"itemScores": []}) for i, q in unknown]
+            if known:
+                rows = np.array(
+                    [model.user_ids[str(q["user"])] for _, q in known],
+                    dtype=np.int64)
+                num = max(int(q.get("num", 10)) for _, q in known)
+                vecs = model.user_factors[rows]
         if known:
-            rows = np.array(
-                [model.user_ids[str(q["user"])] for _, q in known], dtype=np.int64
-            )
-            num = max(int(q.get("num", 10)) for _, q in known)
-            scores, idx = model.scorer().score(model.user_factors[rows], num)
-            inv = model.item_ids.inverse()
-            for (qi, q), s_row, i_row in zip(known, scores, idx):
-                n = int(q.get("num", 10))
-                out.append(
-                    (
-                        qi,
-                        {
-                            "itemScores": [
-                                {"item": inv[int(i)], "score": float(s)}
-                                for s, i in zip(s_row[:n], i_row[:n])
-                            ]
-                        },
+            scores, idx = model.scorer().score(vecs, num)
+            with trace.device_span("engine.decode"):
+                inv = model.item_ids.inverse()
+                for (qi, q), s_row, i_row in zip(known, scores, idx):
+                    n = int(q.get("num", 10))
+                    out.append(
+                        (
+                            qi,
+                            {
+                                "itemScores": [
+                                    {"item": inv[int(i)], "score": float(s)}
+                                    for s, i in zip(s_row[:n], i_row[:n])
+                                ]
+                            },
+                        )
                     )
-                )
         return out

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import logging
 import os
+import re
 from typing import Optional
 
 from predictionio_tpu.obs import metrics
@@ -135,6 +136,51 @@ def record_trainer_report(trainer: str, report: dict) -> None:
     """Merge ``report`` into the trainer's entry (a trainer reports
     from more than one seam: placement, then each dispatch)."""
     TRAINER_REPORTS.setdefault(trainer, {}).update(report)
+
+
+#: per compiled program (HLO module name, as a device trace's "XLA
+#: Modules" line gives it): instruction name -> innermost named scope.
+#: A Pallas kernel's instruction carries its ``name=``; XLA's own
+#: fusions stay ``%fusion.N`` and are told apart only through this map
+SCOPE_MAPS: dict = {}
+
+_HLO_MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+_HLO_OP_NAME_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?\bop_name=\"([^\"]*)\"", re.M)
+#: what a named scope of this program looks like as one element of an
+#: op_name path: lower-case and dotted (``twotower.adagrad_user``).
+#: JAX's own elements are not (``jit(epoch)``, ``while``, ``body``,
+#: ``TwoTowerTrainer._make_epoch.<locals>.epoch``); under autodiff a
+#: scope arrives wrapped (``transpose(jvp(twotower.flash_ce))``)
+_SCOPE_RE = re.compile(r"[a-z][a-z0-9_]*(?:\.[a-z][a-z0-9_]*)+")
+_WRAPPERS_RE = re.compile(r"^(?:\w+\()+|\)+$")
+
+
+def scope_map_of(hlo_text: str) -> dict:
+    """instruction name -> innermost program scope, for every
+    instruction of an optimised HLO module whose ``op_name`` metadata
+    lies under one (``jax.named_scope("twotower.step")``)."""
+    out = {}
+    for instr, op_name in _HLO_OP_NAME_RE.findall(hlo_text):
+        # the last path element is the primitive, not a scope
+        for element in reversed(op_name.split("/")[:-1]):
+            element = _WRAPPERS_RE.sub("", element)
+            if _SCOPE_RE.fullmatch(element):
+                out[instr] = element
+                break
+    return out
+
+
+def record_scope_map(compiled) -> None:
+    """Keep a compiled program's instruction -> scope map, read once
+    from its text, under its HLO module's name. On a device trace an
+    event's name is the instruction's text and carries no scope: the
+    trace's readers (obs/profiler.py, the benchmark) resolve
+    ``%fusion.108`` through this map."""
+    text = compiled.as_text()
+    module = _HLO_MODULE_RE.search(text)
+    if module is not None:
+        SCOPE_MAPS[module.group(1)] = scope_map_of(text)
 
 
 def compile_cache_counts() -> dict:

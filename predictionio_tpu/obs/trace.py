@@ -30,6 +30,16 @@ This module is a deliberately small tracer:
 Spans only record while a trace is active — background work that no
 request asked about stays silent, so the ring buffer and trace log hold
 request-shaped evidence, not noise.
+
+``device_span("batch.dispatch", size=31)`` is the same boundary on the
+DEVICE trace's clock: a ``jax.profiler.TraceAnnotation`` named
+``pio:batch.dispatch`` on the profiler's host plane, so a device event
+or an idle gap of a captured trace can be held against what the host
+was doing. It needs no active trace (the batcher worker's loop has
+none), costs well under a microsecond while no profiler session runs,
+and is a null context in a process that never imported JAX (the event
+and storage servers). ``span()`` opens one too, so a boundary that
+already records is annotated from the same call site.
 """
 
 from __future__ import annotations
@@ -41,6 +51,7 @@ import json
 import logging
 import os
 import re
+import sys
 import threading
 import time
 import uuid
@@ -315,29 +326,65 @@ def traced_headers(headers: Optional[Dict[str, str]] = None
     return out
 
 
+#: every annotation this module writes into a profiler trace starts so
+DEVICE_SPAN_PREFIX = "pio:"
+
+_NO_SPAN = contextlib.nullcontext()
+#: ``jax.profiler.TraceAnnotation``, once this process is seen to hold JAX
+_annotation = None
+
+
+def device_span(name: str, **attrs: Any):
+    """``with device_span("index.fetch"):`` — the block as a
+    ``pio:index.fetch`` span of a profiler capture, on the device
+    trace's clock (module docstring). Attributes are scalars; the
+    active request's trace id rides along as ``trace``, which is how
+    the spans of one request are told from another's."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    ctx = _ctx.get()
+    if ctx is not None:
+        attrs["trace"] = ctx.trace_id
+    return _annotation(DEVICE_SPAN_PREFIX + name, **attrs)
+
+
 @contextlib.contextmanager
-def span(name: str, **attrs: Any):
+def span(name: str, device: Optional[str] = None, **attrs: Any):
     """Record one unit of work under the active trace.
 
-    No active trace -> no-op (zero allocation beyond the context var
-    read), so library code can span unconditionally. Attributes must be
-    JSON-serializable scalars; the span record is emitted on exit even
-    when the body raises (the error is noted, then propagates)."""
+    No active trace -> nothing is recorded, so library code can span
+    unconditionally. Attributes must be JSON-serializable scalars; the
+    span record is emitted on exit even when the body raises (the error
+    is noted, then propagates). With or without a trace the block is
+    also a :func:`device_span`, named ``device`` where the boundary has
+    another name on the device trace than in the record (its scalar
+    attributes ride along)."""
+    annotation = device_span(device or name, **{
+        k: v for k, v in attrs.items()
+        if isinstance(v, (str, int, float, bool))})
     parent = _ctx.get()
     if parent is None:
-        yield None
+        with annotation:
+            yield None
         return
     span_id = _new_span_id()
     token = _ctx.set(SpanContext(trace_id=parent.trace_id, span_id=span_id))
     start_unix = time.time()
     t0 = time.perf_counter()
     error: Optional[str] = None
+    annotation.__enter__()
     try:
         yield span_id
     except BaseException as e:
         error = f"{type(e).__name__}: {e}"
         raise
     finally:
+        annotation.__exit__(None, None, None)
         _ctx.reset(token)
         record: Dict[str, Any] = {
             "trace": parent.trace_id,

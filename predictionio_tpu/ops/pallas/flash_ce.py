@@ -245,6 +245,7 @@ def make_flash_ce(u_idx, i_idx, weight, temp, cdt, B,
                 jax.ShapeDtypeStruct((Sr, 1, Bp), f32),
             ],
             interpret=interpret,
+            name="flash_ce_fwd",
         )(up, vp, uir, uic, iir, iic, wr, wc)
         # direct-exp combine (selection guarantees |L| <= 1/temp <=
         # _DIRECT_EXP_MAX_INV_TEMP): log of the global exp-sums; the
@@ -283,6 +284,7 @@ def make_flash_ce(u_idx, i_idx, weight, temp, cdt, B,
             out_specs=pl.BlockSpec((out_len, D), out_map, memory_space=vm),
             out_shape=jax.ShapeDtypeStruct((Bp, D), f32),
             interpret=interpret,
+            name="flash_ce_bwd_du" if rowmajor else "flash_ce_bwd_dv",
         )(scale, up, vp, uir, uic, iir, iic, wr, wc, lse_ui2, lse_iu2)
 
     @jax.custom_vjp

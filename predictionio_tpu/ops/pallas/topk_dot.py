@@ -142,6 +142,9 @@ def make_topk_dot(n_items, D, B, k, n_excl, *, block_items=BLOCK_ITEMS,
             jax.ShapeDtypeStruct((B, k), jnp.int32),
         ],
         interpret=interpret,
+        # the compiled instruction's name (%topk_dot.N): how a device
+        # trace's reader finds this kernel's events
+        name="topk_dot",
     )
     return jax.jit(fn)
 

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from predictionio_tpu.obs import trace
+
 # plain numpy scalar, NOT jnp: a module-level jnp constant would
 # materialize a device array at import time, initializing the XLA
 # backend — which forbids a later jax.distributed.initialize() and
@@ -250,16 +252,27 @@ class TopKScorer:
         first) — callers needing exact long blacklists should filter
         host-side on the returned ranking.
         """
+        with trace.device_span("index.search"):
+            return self.score_unspanned(user_vecs, k, exclude_idx)
+
+    def score_unspanned(self, user_vecs, k, exclude_idx=None):
+        """:meth:`score` for a caller that holds ``pio:index.search``
+        open itself (``index/exact.py`` when its kernel is not
+        eligible)."""
         B_in = np.atleast_2d(np.asarray(user_vecs)).shape[0]
         if self._route(B_in) == "host":
             return self._score_host(user_vecs, k, exclude_idx)
-        user_vecs, exclude_idx, k, k_bucket, B = _prepare_score_inputs(
-            user_vecs, k, exclude_idx, self.item_factors.shape[0],
-            self.max_exclude)
-        scores, idx = _topk_scores(
-            user_vecs, self.item_factors, exclude_idx, k_bucket
-        )
-        return np.asarray(scores)[:B, :k], np.asarray(idx)[:B, :k]
+        # enqueue: pad, transfer, the jitted call returning; fetch: the
+        # wait for the device and the copy back
+        with trace.device_span("index.enqueue"):
+            user_vecs, exclude_idx, k, k_bucket, B = _prepare_score_inputs(
+                user_vecs, k, exclude_idx, self.item_factors.shape[0],
+                self.max_exclude)
+            scores, idx = _topk_scores(
+                user_vecs, self.item_factors, exclude_idx, k_bucket
+            )
+        with trace.device_span("index.fetch"):
+            return np.asarray(scores)[:B, :k], np.asarray(idx)[:B, :k]
 
     def score_masked(
         self,

@@ -630,10 +630,11 @@ class TwoTowerTrainer:
         v = _apply_tail(dense["item"], ve, cfg)
         B = u.shape[0]
         if self.kernel_plan["flash_ce"]:
-            return _pl_flash.pallas_blockwise_ce(
-                u, v, u_idx, i_idx, weight, cfg.temperature,
-                jnp.dtype(cfg.compute_dtype),
-                interpret=self.kernel_plan["interpret"])
+            with jax.named_scope("twotower.flash_ce"):
+                return _pl_flash.pallas_blockwise_ce(
+                    u, v, u_idx, i_idx, weight, cfg.temperature,
+                    jnp.dtype(cfg.compute_dtype),
+                    interpret=self.kernel_plan["interpret"])
         chunk = cfg.loss_chunk
         if chunk and B >= 2 * chunk and B % chunk == 0:
             return _blockwise_softmax_ce(
@@ -664,22 +665,29 @@ class TwoTowerTrainer:
         else:
             row_update = _rowwise_adagrad
 
+        # the scopes name the step's parts for whoever reads a device
+        # trace (obs/jaxmon.record_scope_map keeps instruction -> scope
+        # of the compiled program); they add metadata, not operations
+        @jax.named_scope("twotower.step")
         def step(carry, idx):
             tables, acc, dense, opt_state = carry
-            u_idx = self._u[idx]
-            i_idx = self._i[idx]
-            w = self._w[idx]
-            ue = tables["user"][u_idx]                  # [B, E] gather
-            ve = tables["item"][i_idx]
+            with jax.named_scope("twotower.gather"):
+                u_idx = self._u[idx]
+                i_idx = self._i[idx]
+                w = self._w[idx]
+                ue = tables["user"][u_idx]              # [B, E] gather
+                ve = tables["item"][i_idx]
             loss, (gu, gv, gd) = jax.value_and_grad(
                 loss_from_rows, argnums=(0, 1, 2),
             )(ue, ve, dense, u_idx, i_idx, w)
             tables = dict(tables)
             acc = dict(acc)
-            tables["user"], acc["user"] = row_update(
-                tables["user"], acc["user"], u_idx, gu, table_lr)
-            tables["item"], acc["item"] = row_update(
-                tables["item"], acc["item"], i_idx, gv, table_lr)
+            with jax.named_scope("twotower.adagrad_user"):
+                tables["user"], acc["user"] = row_update(
+                    tables["user"], acc["user"], u_idx, gu, table_lr)
+            with jax.named_scope("twotower.adagrad_item"):
+                tables["item"], acc["item"] = row_update(
+                    tables["item"], acc["item"], i_idx, gv, table_lr)
             if any(len(v) for v in dense.values()):
                 updates, opt_state = tx.update(gd, opt_state, dense)
                 dense = optax.apply_updates(dense, updates)
@@ -709,7 +717,7 @@ class TwoTowerTrainer:
         (seed, epoch index) so a resumed run replays the same order."""
         import time as _time
 
-        from predictionio_tpu.obs import jaxmon
+        from predictionio_tpu.obs import jaxmon, trace
 
         target = epochs if epochs is not None else self.cfg.epochs
         base = jax.random.PRNGKey(self.cfg.seed + 1)
@@ -717,36 +725,42 @@ class TwoTowerTrainer:
             key = jax.random.fold_in(base, self._epochs_done)
             if self._compiled is None:
                 self._compile_epoch(key)
-            t_step = _time.perf_counter()
-            *state, stats = self._compiled(*self._state, key)
-            mean_loss, first, last = np.asarray(jax.block_until_ready(stats))
-            epoch_sec = _time.perf_counter() - t_step
-            self._state = tuple(state)
-            self._losses.append(float(mean_loss))
-            # per-dispatch wall time onto pio_train_step_seconds; also
-            # beats the train-step stall watchdog (obs/health.py)
-            jaxmon.observe_train_step(epoch_sec)
-            self._acct.observe(epoch_sec)
-            jaxmon.record_trainer_report("twotower", {
-                "kernel_plan": self.kernel_plan,
-                "steps_per_epoch": self.steps_per_epoch,
-                "batch": self.batch,
-                "compile_sec": round(self.compile_sec, 3),
-                # host clock around one epoch dispatch, ended by
-                # block_until_ready — compile excluded (AOT above)
-                "epoch_sec": epoch_sec,
-                "step_ms": epoch_sec / self.steps_per_epoch * 1e3,
-                "first_step_loss": float(first),
-                "last_step_loss": float(last),
-                "epoch_losses": list(self._losses),
-            })
-            self._epochs_done += 1
-            if self._ckpt is not None:
-                tables, acc, dense, opt_state = self._state
-                self._ckpt.maybe_save(self._epochs_done, {
-                    "tables": tables, "acc": acc, "dense": dense,
-                    "opt_state": opt_state, "losses": list(self._losses),
+            with trace.device_span("train.epoch",
+                                   epoch=self._epochs_done):
+                t_step = _time.perf_counter()
+                *state, stats = self._compiled(*self._state, key)
+                mean_loss, first, last = np.asarray(
+                    jax.block_until_ready(stats))
+                epoch_sec = _time.perf_counter() - t_step
+            with trace.device_span("train.report"):
+                self._state = tuple(state)
+                self._losses.append(float(mean_loss))
+                # per-dispatch wall time onto pio_train_step_seconds;
+                # also beats the train-step stall watchdog
+                # (obs/health.py)
+                jaxmon.observe_train_step(epoch_sec)
+                self._acct.observe(epoch_sec)
+                jaxmon.record_trainer_report("twotower", {
+                    "kernel_plan": self.kernel_plan,
+                    "steps_per_epoch": self.steps_per_epoch,
+                    "batch": self.batch,
+                    "compile_sec": round(self.compile_sec, 3),
+                    # host clock around one epoch dispatch, ended by
+                    # block_until_ready — compile excluded (AOT above)
+                    "epoch_sec": epoch_sec,
+                    "step_ms": epoch_sec / self.steps_per_epoch * 1e3,
+                    "first_step_loss": float(first),
+                    "last_step_loss": float(last),
+                    "epoch_losses": list(self._losses),
                 })
+                self._epochs_done += 1
+                if self._ckpt is not None:
+                    tables, acc, dense, opt_state = self._state
+                    self._ckpt.maybe_save(self._epochs_done, {
+                        "tables": tables, "acc": acc, "dense": dense,
+                        "opt_state": opt_state,
+                        "losses": list(self._losses),
+                    })
         return list(self._losses)
 
     def _compile_epoch(self, key) -> None:
@@ -756,11 +770,12 @@ class TwoTowerTrainer:
         and the first epoch's timing carries no compile."""
         import time as _time
 
-        from predictionio_tpu.obs import memacct, perfacct
+        from predictionio_tpu.obs import jaxmon, memacct, perfacct
 
         t0 = _time.perf_counter()
         self._compiled = self._epoch_fn.lower(*self._state, key).compile()
         self.compile_sec = _time.perf_counter() - t0
+        jaxmon.record_scope_map(self._compiled)
         # one dispatch = one epoch (the jitted lax.scan), so the cost
         # basis is per-EPOCH: cost_analysis of the compiled epoch when
         # the backend reports one, else the shared analytic matmul

@@ -258,6 +258,9 @@ class MicroBatcher:
         # — folding them in would skew the bench's srv_queue /
         # srv_dispatch percentiles with numbers no served request saw
         self._abandoned = 0
+        # dispatches so far; only the worker writes it. Rides on each
+        # pio:batch.dispatch span so a trace can tell them apart
+        self._seq = 0
         self._stop = False
         # orders submit()'s stop-check+enqueue against stop()'s flag+wake,
         # so nothing can be enqueued after the worker's shutdown drain
@@ -307,11 +310,12 @@ class MicroBatcher:
                 break
             batch = [first]
             try:
-                while len(batch) < self._max_batch:
-                    try:
-                        batch.append(self._queue.get_nowait())
-                    except _queue.Empty:
-                        break
+                with trace.device_span("batch.collect"):
+                    while len(batch) < self._max_batch:
+                        try:
+                            batch.append(self._queue.get_nowait())
+                        except _queue.Empty:
+                            break
                 with _DISPATCH_WATCHDOG.watch():
                     # chaos seam: injected latency/hangs land INSIDE the
                     # dispatch watchdog's watch window (a chaos hang is
@@ -369,21 +373,23 @@ class MicroBatcher:
             return
         with self._hist_lock:
             self._hist[len(batch)] = self._hist.get(len(batch), 0) + 1
+        self._seq += 1
         t_start = time.perf_counter()
         if len(batch) == 1:
             p = batch[0]
             token = (trace.activate_context(p.trace_ctx)
                      if p.trace_ctx is not None else None)
             try:
-                with trace.span("serve.dispatch", batch_size=1):
+                with trace.span("serve.dispatch", device="batch.dispatch",
+                                batch_size=1, seq=self._seq, size=1,
+                                path="lone"):
                     p.result = self._run_one(p.payload)
             except BaseException as e:  # noqa: BLE001 — relayed to caller
                 p.error = e
             finally:
                 if token is not None:
                     trace.deactivate(token)
-            self._record_splits(batch, t_start)
-            p.event.set()
+            self._deliver(batch, t_start)
             return
         # the multi-query dispatch gets its OWN span: one record, under
         # a batch-minted trace id, carrying every member's trace id —
@@ -397,8 +403,10 @@ class MicroBatcher:
         try:
             batch_token = trace.activate(trace.new_trace_id())
             try:
-                with trace.span("serve.batch", batch_size=len(batch),
-                                members=members):
+                with trace.span("serve.batch", device="batch.dispatch",
+                                batch_size=len(batch), members=members,
+                                seq=self._seq, size=len(batch),
+                                path="batched"):
                     results = self._run_batch([p.payload for p in batch])
             finally:
                 trace.deactivate(batch_token)
@@ -414,17 +422,25 @@ class MicroBatcher:
                 token = (trace.activate_context(p.trace_ctx)
                          if p.trace_ctx is not None else None)
                 try:
-                    with trace.span("serve.dispatch", batch_size=1,
-                                    fallback=True):
+                    with trace.span("serve.dispatch",
+                                    device="batch.dispatch", batch_size=1,
+                                    fallback=True, seq=self._seq, size=1,
+                                    path="lone"):
                         p.result = self._run_one(p.payload)
                 except BaseException as e:  # noqa: BLE001
                     p.error = e
                 finally:
                     if token is not None:
                         trace.deactivate(token)
-        self._record_splits(batch, t_start)
-        for p in batch:
-            p.event.set()
+        self._deliver(batch, t_start)
+
+    def _deliver(self, batch, t_start: float) -> None:
+        """Hand a finished dispatch back: its time splits, then every
+        waiter's wake-up."""
+        with trace.device_span("batch.deliver", size=len(batch)):
+            self._record_splits(batch, t_start)
+            for p in batch:
+                p.event.set()
 
     def _record_splits(self, batch, t_start: float) -> None:
         t_done = time.perf_counter()
@@ -757,7 +773,11 @@ class EngineServer(HTTPServerBase):
 
     def query(self, payload: Any) -> Any:
         t0 = time.perf_counter()
-        with trace.span("serve.query", engine=self.engine_id):
+        # pio:serve.wait on the device trace's clock: what this handler
+        # thread waits for (the batcher's queue and the dispatch; the
+        # dispatch itself on a server that runs without a batcher)
+        with trace.span("serve.query", device="serve.wait",
+                        engine=self.engine_id):
             if self._batcher is not None:
                 result = self._batcher.submit(payload)
             else:
@@ -1025,7 +1045,8 @@ class _EngineRequestHandler(JSONRequestHandler):
             # any queue time: an overloaded server's cheapest work is
             # saying no (429 + Retry-After), and the shed must be
             # reconstructable (counter + flight record)
-            decision = self.server_ref.admission.check()
+            with trace.device_span("serve.admit"):
+                decision = self.server_ref.admission.check()
             if decision is not None:
                 flight.note_field("shed", decision.reason)
                 self._send(

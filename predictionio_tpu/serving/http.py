@@ -791,9 +791,11 @@ def _instrument(fn):
             # server= stamps the owning process on the edge span: the
             # trace collector attributes every descendant span to the
             # nearest ancestor edge's server (a shared-ring threaded
-            # fleet cannot attribute by which member answered)
-            with trace.span(f"http.{name}", method=self.command,
-                            route=route, server=name):
+            # fleet cannot attribute by which member answered). On the
+            # device trace's clock the same span is pio:http.request:
+            # request line and headers read -> response written
+            with trace.span(f"http.{name}", device="http.request",
+                            method=self.command, route=route, server=name):
                 fn(self)
         except BaseException as e:
             # an exception ESCAPING a handler (their own except blocks
@@ -802,27 +804,31 @@ def _instrument(fn):
             error = f"{type(e).__name__}: {e}"
             raise
         finally:
-            inflight.dec()
-            status = getattr(self, "_metrics_status", None)
-            # the dominant host frame the sampler observed during this
-            # request's window stamps the record BEFORE it seals, so a
-            # slow record names code, not just stages
-            dominant = contprof.request_end()
-            if dominant is not None:
-                flight.note_field("dominant_frame", dominant)
-            # seal the flight record while the trace is still active so
-            # the slow-request log line carries the trace id
-            flight.finish(fkey, status, error)
-            trace.deactivate(token)
-            if status is not None:
-                _REQUESTS_TOTAL.labels(server, self.command, route,
-                                       str(status)).inc()
-                # the trace id rides along as an OpenMetrics exemplar:
-                # a collector can jump from a latency bucket straight
-                # to this request's trace
-                _REQUEST_SECONDS.labels(server, self.command, route).observe(
-                    time.perf_counter() - t0,
-                    exemplar={"trace_id": trace_id})
+            # the request's bookkeeping after its answer is written: the
+            # connection's next request waits behind it
+            with trace.device_span("http.finish"):
+                inflight.dec()
+                status = getattr(self, "_metrics_status", None)
+                # the dominant host frame the sampler observed during this
+                # request's window stamps the record BEFORE it seals, so a
+                # slow record names code, not just stages
+                dominant = contprof.request_end()
+                if dominant is not None:
+                    flight.note_field("dominant_frame", dominant)
+                # seal the flight record while the trace is still active so
+                # the slow-request log line carries the trace id
+                flight.finish(fkey, status, error)
+                trace.deactivate(token)
+                if status is not None:
+                    _REQUESTS_TOTAL.labels(server, self.command, route,
+                                           str(status)).inc()
+                    # the trace id rides along as an OpenMetrics exemplar:
+                    # a collector can jump from a latency bucket straight
+                    # to this request's trace
+                    _REQUEST_SECONDS.labels(
+                        server, self.command, route).observe(
+                            time.perf_counter() - t0,
+                            exemplar={"trace_id": trace_id})
 
     wrapper._pio_instrumented = True
     return wrapper
@@ -880,49 +886,50 @@ class JSONRequestHandler(BaseHTTPRequestHandler):
     def _send(self, status: int, body: Any,
               content_type: str = "application/json; charset=UTF-8",
               extra_headers: Optional[dict] = None) -> None:
-        t_ser = time.perf_counter()
-        if isinstance(body, bytes):
-            data = body
-        elif isinstance(body, str):
-            data = body.encode()
-        else:
-            data = json.dumps(body).encode()
-        # Consume any unread request body before responding: under
-        # HTTP/1.1 keep-alive an unread body desynchronizes the
-        # connection — the next request would be parsed from leftover
-        # body bytes (matters for short-circuit responses: auth denial,
-        # unknown route). Cheap no-op when the handler already read it.
-        # Oversized undrained bodies (> 1 MB — only short-circuit paths
-        # leave bodies unread) and chunked request bodies (no length to
-        # drain by) close the connection instead.
-        try:
-            unread = int(self.headers.get("Content-Length") or 0)
-        except (TypeError, ValueError):
-            unread = 0
-        if not getattr(self, "_body_consumed", False):
-            if self.headers.get("Transfer-Encoding"):
-                self.close_connection = True
-            elif unread > (1 << 20):
-                self.close_connection = True
-            elif unread:
-                self.rfile.read(unread)
-        self._body_consumed = True  # this request's body is settled
-        self.send_response(status)
-        self.send_header("Content-Type", content_type)
-        self.send_header("Content-Length", str(len(data)))
-        trace_id = trace.current_trace_id()
-        if trace_id:
-            # echo the request's trace id so clients can join their logs
-            self.send_header(trace.TRACE_HEADER, trace_id)
-        if self.close_connection:
-            self.send_header("Connection", "close")
-        for name, value in (extra_headers or {}).items():
-            self.send_header(name, value)
-        self.end_headers()
-        self.wfile.write(data)
-        # response encode+write billed to the request's flight record
-        # (no-op when no record is open, e.g. the shared /metrics route)
-        flight.note_stage("serialize", time.perf_counter() - t_ser)
+        with trace.device_span("http.respond"):
+            t_ser = time.perf_counter()
+            if isinstance(body, bytes):
+                data = body
+            elif isinstance(body, str):
+                data = body.encode()
+            else:
+                data = json.dumps(body).encode()
+            # Consume any unread request body before responding: under
+            # HTTP/1.1 keep-alive an unread body desynchronizes the
+            # connection — the next request would be parsed from leftover
+            # body bytes (matters for short-circuit responses: auth denial,
+            # unknown route). Cheap no-op when the handler already read it.
+            # Oversized undrained bodies (> 1 MB — only short-circuit paths
+            # leave bodies unread) and chunked request bodies (no length to
+            # drain by) close the connection instead.
+            try:
+                unread = int(self.headers.get("Content-Length") or 0)
+            except (TypeError, ValueError):
+                unread = 0
+            if not getattr(self, "_body_consumed", False):
+                if self.headers.get("Transfer-Encoding"):
+                    self.close_connection = True
+                elif unread > (1 << 20):
+                    self.close_connection = True
+                elif unread:
+                    self.rfile.read(unread)
+            self._body_consumed = True  # this request's body is settled
+            self.send_response(status)
+            self.send_header("Content-Type", content_type)
+            self.send_header("Content-Length", str(len(data)))
+            trace_id = trace.current_trace_id()
+            if trace_id:
+                # echo the request's trace id so clients can join their logs
+                self.send_header(trace.TRACE_HEADER, trace_id)
+            if self.close_connection:
+                self.send_header("Connection", "close")
+            for name, value in (extra_headers or {}).items():
+                self.send_header(name, value)
+            self.end_headers()
+            self.wfile.write(data)
+            # response encode+write billed to the request's flight record
+            # (no-op when no record is open, e.g. the shared /metrics route)
+            flight.note_stage("serialize", time.perf_counter() - t_ser)
 
     def _read_body(self) -> bytes:
         t0 = time.perf_counter()
@@ -934,7 +941,8 @@ class JSONRequestHandler(BaseHTTPRequestHandler):
 
     def _read_json(self) -> Any:
         """Parsed JSON body; raises json.JSONDecodeError."""
-        return json.loads(self._read_body() or b"{}")
+        with trace.device_span("http.parse"):
+            return json.loads(self._read_body() or b"{}")
 
     def _do_get_fallback(self):
         self._send(404, {"message": "Not Found"})

@@ -25,6 +25,7 @@ from predictionio_tpu.core.persistent_model import (
 )
 from predictionio_tpu.data.metadata import EngineInstance
 from predictionio_tpu.data.storage import Storage, get_storage
+from predictionio_tpu.obs import trace
 from predictionio_tpu.parallel.mesh import MeshContext
 
 
@@ -73,7 +74,8 @@ class Deployment:
         predictions = [
             algo.predict(model, q) for algo, model in zip(self.algorithms, self.models)
         ]
-        return self.serving.serve(q, predictions)
+        with trace.device_span("engine.decode"):
+            return self.serving.serve(q, predictions)
 
     def query_batch(self, payloads: List[Any]) -> List[Any]:
         """Many queries through each algorithm's vectorized
@@ -85,10 +87,11 @@ class Deployment:
             dict(algo.batch_predict(model, indexed))
             for algo, model in zip(self.algorithms, self.models)
         ]
-        return [
-            self.serving.serve(q, [preds[i] for preds in per_algo])
-            for i, q in indexed
-        ]
+        with trace.device_span("engine.decode"):
+            return [
+                self.serving.serve(q, [preds[i] for preds in per_algo])
+                for i, q in indexed
+            ]
 
 
 def latest_completed_instance_id(

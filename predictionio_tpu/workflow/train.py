@@ -70,10 +70,10 @@ def _maybe_profile(instance_id: str):
     train into ``<dir>/<instance_id>`` (open with TensorBoard or
     xprof; obs/profiler.py owns the capture machinery). After a
     successful capture the PER-STEP device-time breakdown is computed
-    in a subprocess (the xplane parser's tensorflow proto stack must
-    not share this process) and logged as a structured record plus a
-    ``breakdown.json`` beside the trace. Profiling failures never fail
-    training."""
+    (obs/profiler.parse_xplane: busy as a union, self time by the
+    program's scopes and kernel names, idle by ``pio:`` span) and
+    logged as a structured record plus a ``breakdown.json`` beside the
+    trace. Profiling failures never fail training."""
     profile_dir = os.environ.get("PIO_PROFILE_DIR")
     if not profile_dir:
         yield
@@ -88,25 +88,15 @@ def _maybe_profile(instance_id: str):
 
 
 def _log_step_breakdown(profile_dir: str, steps: int) -> None:
-    """Parse the captured trace into device ms/step by HLO category
+    """Parse the captured trace into device ms/step by scope and kernel
     (best effort: on CPU tier-1 or without the parser deps this logs
     the parse error and moves on). A train whose loop never feeds
     ``pio_train_step_seconds`` has ``steps == 0``: the TOTAL device
     time is logged instead — a whole-train number must never be
     presented as a per-step one."""
-    import subprocess
-    import sys as _sys
-
-    cmd = [_sys.executable, "-m", "predictionio_tpu.obs.profiler",
-           profile_dir]
-    if steps > 0:
-        cmd += ["--steps", str(steps)]
     try:
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=600)
-        lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-        breakdown = json.loads(lines[-1]) if lines else {
-            "error": f"parse rc={proc.returncode}: {proc.stderr[-300:]}"}
+        breakdown = (profiler.step_breakdown(profile_dir, steps)
+                     if steps > 0 else profiler.parse_xplane(profile_dir))
     except Exception as e:  # noqa: BLE001 — observability must not break train
         breakdown = {"error": str(e)}
     if "error" in breakdown:

@@ -373,39 +373,35 @@ def test_single_spike_costs_at_most_one_halving(monkeypatch):
 
 
 def test_gil_contention_does_not_downshift(monkeypatch):
-    """The governor meters CPU time, not wall time: pure-Python spinner
-    threads hold the GIL so a sampling pass takes large WALL time
-    waiting its turn, but the sampler's own CPU cost stays tiny — a
-    loaded server must keep its full sampling rate (wall-based metering
-    downshifted to the floor exactly under load)."""
+    """The governor meters CPU time, not wall time: on a loaded server a
+    sampling pass takes large WALL time waiting its turn at the GIL, but
+    the sampler's own CPU cost stays tiny — it must keep its full
+    sampling rate (wall-based metering downshifted to the floor exactly
+    under load). Driven tick by tick on injected clocks: a pass that
+    "takes" 30 ms of wall time (75% of the 40 ms interval) and 10 us of
+    the sampler thread's CPU time (0.025%, under the 1% budget)."""
     monkeypatch.setenv("PIO_PROF_HZ", "25")
-    # a few warm-up ticks absorb the genuine first-pass cold cost; the
-    # sustained spin period after them is what must stay ungoverned
-    monkeypatch.setenv("PIO_PROF_WARMUP_TICKS", "5")
-    p = contprof.ContProfiler()
-    stop = threading.Event()
-
-    def spin():
-        while not stop.is_set():
-            sum(i * i for i in range(5000))
-
-    workers = [threading.Thread(target=spin, daemon=True)
-               for _ in range(3)]
-    for w in workers:
-        w.start()
+    monkeypatch.setenv("PIO_PROF_MAX_OVERHEAD", "0.01")
+    monkeypatch.setenv("PIO_PROF_WARMUP_TICKS", "0")
+    # what the sampler thread meters itself with when nothing is
+    # injected: its own CPU clock, not the wall clock
+    assert contprof.ContProfiler()._cpu_clock is time.thread_time
     before = metrics.REGISTRY.get("pio_prof_downshifts_total").value
-    p.retain("gil")
-    try:
-        time.sleep(0.8)
-        assert p.snapshot()["total_samples"] > 0
-        assert p.effective_hz() == 25.0
-        assert metrics.REGISTRY.get(
-            "pio_prof_downshifts_total").value == before
-    finally:
-        stop.set()
-        p.release("gil")
-        for w in workers:
-            w.join(timeout=2.0)
+    p = contprof.ContProfiler(clock=ScriptedClock(0.030),
+                              cpu_clock=ScriptedClock(1e-5))
+    for _ in range(10 * contprof.EMA_SEED_TICKS):
+        p._tick()
+    assert p.snapshot()["total_samples"] > 0
+    assert p.overhead_ratio() < 0.01
+    assert p.effective_hz() == 25.0
+    assert metrics.REGISTRY.get("pio_prof_downshifts_total").value == before
+    # the same wall cost billed as CPU time is over the budget: the
+    # clocks are what decides, not the pass
+    q = contprof.ContProfiler(clock=ScriptedClock(1e-5),
+                              cpu_clock=ScriptedClock(0.030))
+    for _ in range(10 * contprof.EMA_SEED_TICKS):
+        q._tick()
+    assert q.effective_hz() < 25.0
 
 
 # ---------------------------------------------------------------------------

@@ -96,3 +96,53 @@ def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
              for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*specs).compile()
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+
+
+@pytest.mark.parametrize("build,args,names", [
+    (_topk_dot, (26_744, 64, 1), ["topk_dot"]),
+    (_flash_ce, (4096, 64),
+     ["flash_ce_fwd", "flash_ce_bwd_du", "flash_ce_bwd_dv"]),
+], ids=["topk_dot", "flash_ce"])
+def test_a_kernels_instruction_carries_its_name(one_chip, no_compile_cache,
+                                                build, args, names):
+    """A device trace's events are named by the instruction's text: the
+    readers find a kernel by ``pallas_call(name=...)``, which must reach the
+    compiled instruction (unnamed, it took an accidental one:
+    ``tpu_custom_call.1``, ``jvp__.6``)."""
+    import re
+
+    fn, shapes, _ = build(*args)
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                         r"\"tpu_custom_call\"", text)
+    assert len(kernels) == len(names)
+    # XLA appends .N; under autodiff with no scope around the call JAX
+    # wraps the name (jvp_flash_ce_fwd_.1), inside the trainer's
+    # twotower.flash_ce scope it does not (flash_ce_fwd.6)
+    for name in names:
+        assert sum(name in k for k in kernels) == 1, (name, kernels)
+
+
+def test_a_scope_reaches_xlas_own_fusions_through_the_scope_map(
+        one_chip, no_compile_cache):
+    """A scatter under ``named_scope`` stays ``%fusion.N`` on the chip; the
+    scope is in the compiled text's metadata alone, which is what
+    ``jaxmon.scope_map_of`` reads."""
+    from predictionio_tpu.obs import jaxmon
+    from predictionio_tpu.ops.twotower import _rowwise_adagrad
+
+    def step(table, acc, idx, grad):
+        with jax.named_scope("twotower.step"):
+            with jax.named_scope("twotower.adagrad_user"):
+                return _rowwise_adagrad(table, acc, idx, grad, 0.01)
+
+    f32 = jnp.float32
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in [((100_000, 128), f32), ((100_000,), f32),
+                                  ((8192,), jnp.int32), ((8192, 128), f32)]]
+    text = jax.jit(step).lower(*specs).compile().as_text()
+    scopes = jaxmon.scope_map_of(text)
+    fusions = {k: v for k, v in scopes.items() if k.startswith("fusion")}
+    assert fusions and set(fusions.values()) == {"twotower.adagrad_user"}
