@@ -539,6 +539,10 @@ def phase_als(run: Run) -> dict:
     if not kernel.get("engaged") or kernel.get("interpret"):
         raise PhaseFailed(f"topk_dot not engaged compiled: {kernel}",
                           run.stderr_of(proc))
+    # the kernel's own count of the tiles it merged, as GET / shows it
+    if not 1 <= kernel.get("merged_tiles", 0) <= kernel.get("tiles", 0):
+        raise PhaseFailed(f"topk_dot reported no merged-tile count: {kernel}",
+                          run.stderr_of(proc))
     # the counters count dispatches (queries in flight together share
     # one): every search the index served must have gone through the
     # kernel, none to the XLA scorer or its host scan, and the queries

@@ -12,10 +12,10 @@ reference's MLlib ancestry never had (ALS serving ends at
                         / ``stats``.
   ``index/exact.py``    exact on-device retrieval: a fused Pallas
                         dot+top-k kernel (``ops/pallas/topk_dot.py`` —
-                        item table streamed through VMEM in tiles,
-                        never a [B, I] logits matrix in HBM) with the
-                        XLA brute-force scorer (``ops.topk``) as the
-                        reference and fallback.
+                        item table read once per search in the layout
+                        it is stored in, never a [B, I] logits matrix
+                        in HBM) with the XLA brute-force scorer
+                        (``ops.topk``) as the reference and fallback.
   ``index/ivf.py``      approximate CPU fallback: k-means coarse
                         quantizer + ``nprobe`` inverted-list search,
                         optional int8 per-dim quantization — gated at

@@ -1,13 +1,15 @@
 """Exact on-device retrieval: fused Pallas dot+top-k, XLA reference.
 
-The hot path is ``ops/pallas/topk_dot.py`` — the item table streamed
-through VMEM in tiles, MXU partial dots, a running [B, k] top-k merged
-per tile; the full [B, I] logits matrix never exists in HBM. The XLA
-brute-force scorer (``ops.topk.TopKScorer``) remains the numerical
-reference and the path for shapes the kernel is not eligible for (and
-for the CPU backend). An engaged kernel that the chip's compiler
-refuses raises from ``search`` — the ``ops/pallas`` design contract,
-applied to serving instead of training.
+The hot path is ``ops/pallas/topk_dot.py`` — the item table kept on the
+device ONCE, in the ``[D, Ip]`` layout the kernel reads (items on the
+lanes), streamed through VMEM in tiles of thousands of items; a tile
+is merged into the running [B, k] top-k only if it can change it, and
+the full [B, I] logits matrix never exists in HBM. The XLA brute-force
+scorer (``ops.topk.TopKScorer``) remains the numerical reference and
+the path for shapes the kernel is not eligible for (and for the CPU
+backend). An engaged kernel that the chip's compiler refuses raises
+from ``search`` — the ``ops/pallas`` design contract, applied to
+serving instead of training.
 
 Kernel selection mirrors ``flash_ce_kernel`` exactly: a per-index
 ``kernel`` flag ("auto"/"on"/"off", wired from the model params'
@@ -45,16 +47,18 @@ class ExactIndex(AnnIndex):
     def __init__(self, kernel: str = "auto", max_exclude: int = 64,
                  block_items: Optional[int] = None,
                  placement: Optional[str] = None):
-        from predictionio_tpu.ops.pallas import topk_dot as tkd
-
         self.kernel_flag = kernel
         self.max_exclude = int(max_exclude)
-        self.block_items = int(block_items or tkd.BLOCK_ITEMS)
+        #: items per kernel tile; None = ``topk_dot.tile_items``' rule
+        self.block_items = block_items
         self._placement = placement
         self._scorer = None          # lazy TopKScorer fallback
         self._vectors = np.zeros((0, 1), np.float32)
-        self._device_padded = None   # device copy padded to the tile
+        self._device_table = None    # device copy in the kernel's layout
         self._fns: Dict[Tuple[int, int, int], object] = {}
+        #: (tiles, merged-tile count still on the device) of the newest
+        #: kernel search; fetched by ``stats()`` alone
+        self._last_merge = None
         self._lock = threading.Lock()
         self.kernel_plan: Dict[str, object] = {"engaged": False,
                                                "reason": "no build yet"}
@@ -73,7 +77,7 @@ class ExactIndex(AnnIndex):
             self._vectors = np.ascontiguousarray(item_vectors,
                                                  dtype=np.float32)
             self._scorer = None
-            self._device_padded = None
+            self._device_table = None
             self._fns.clear()
             self._plan_kernel()
         self.build_seconds = time.perf_counter() - t0
@@ -106,7 +110,7 @@ class ExactIndex(AnnIndex):
             # table; drop them — a same-shape re-put hits the compile
             # cache, only appends change shapes
             self._scorer = None
-            self._device_padded = None
+            self._device_table = None
             if grow > 0:
                 self._fns.clear()   # n_items is a static kernel arg
             self._note_build(self.build_seconds)
@@ -117,10 +121,10 @@ class ExactIndex(AnnIndex):
 
     def _mem_nbytes(self) -> int:
         """Resident bytes this index owns: the host table plus, once
-        materialized, the tile-padded device copy the kernel streams."""
-        padded = self._device_padded
+        materialized, the device copy the kernel streams."""
+        table = self._device_table
         return int(self._vectors.nbytes
-                   + (padded.nbytes if padded is not None else 0))
+                   + (table.nbytes if table is not None else 0))
 
     @property
     def vectors(self) -> np.ndarray:
@@ -143,6 +147,11 @@ class ExactIndex(AnnIndex):
                             "interpret": interpret}
 
     def _kernel_eligible(self, B: int, E: int, k: int) -> bool:
+        """Whether the kernel answers this (bucketed) shape. The rule is
+        the measured one: on the chip the kernel took less device time
+        than the XLA scorer at every batch, ``k`` and exclusion bucket
+        inside its caps, at D = 64 and 128 (``topk_dot.MAX_BATCH``), so
+        the caps are the whole rule and ``D`` does not enter it."""
         from predictionio_tpu.ops.pallas import topk_dot as tkd
 
         return (bool(self.kernel_plan.get("engaged"))
@@ -164,20 +173,18 @@ class ExactIndex(AnnIndex):
 
     def _device_items(self):
         from predictionio_tpu.ops.pallas import topk_dot as tkd
-        import jax.numpy as jnp
 
         # read-once: a concurrent upsert nulls the cache mid-call (the
         # patch lane runs while queries are in flight); the local ref
         # keeps this search on a consistent (old-or-new) table
-        padded = self._device_padded
-        if padded is None:
-            padded = tkd.pad_items(jnp.asarray(self._vectors),
-                                   self.block_items)
-            self._device_padded = padded  # graftlint: disable=JT18 — lock-free lazy init by design: the store is atomic, racing fills compute identical tables and the last write wins; readers above took one local ref
+        table = self._device_table
+        if table is None:
+            table = tkd.to_kernel_layout(self._vectors, self.block_items)
+            self._device_table = table  # graftlint: disable=JT18 — lock-free lazy init by design: the store is atomic, racing fills compute identical tables and the last write wins; readers above took one local ref
             # a NEW long-lived device allocation: re-price the ledger
-            # footprint with the padded copy included (JT16 contract)
+            # footprint with the device copy included (JT16 contract)
             self._register_mem(self._mem_nbytes())
-        return padded
+        return table
 
     def _fallback(self):
         from predictionio_tpu.ops.topk import TopKScorer
@@ -213,7 +220,9 @@ class ExactIndex(AnnIndex):
                     q2.shape[0], excl.shape[1], k_bucket)
                 if eligible:
                     fn = self._fn(q2.shape[0], excl.shape[1], k_bucket)
-                    scores, idx = fn(q2, self._device_items(), excl)
+                    table = self._device_items()
+                    scores, idx, merged = fn(q2, table, excl)
+                    self._last_merge = (fn.tiles, merged)
             if not eligible:
                 return self._fallback().score_unspanned(
                     query_vecs, k, exclude)
@@ -224,8 +233,14 @@ class ExactIndex(AnnIndex):
 
     def stats(self) -> Dict[str, object]:
         out = super().stats()
+        kernel = dict(self.kernel_plan)
+        last = self._last_merge
+        if last is not None:
+            # the one place the merged-tile count leaves the device
+            kernel["tiles"] = last[0]
+            kernel["merged_tiles"] = int(np.asarray(last[1])[0, 0])
         out.update({
-            "kernel": dict(self.kernel_plan),
+            "kernel": kernel,
             "build_seconds": round(self.build_seconds, 4),
             "searches": self.searches,
             "routes": {"kernel": self.routes["kernel"],

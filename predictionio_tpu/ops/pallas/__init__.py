@@ -27,8 +27,10 @@ Design contract shared by every kernel here:
     compiles each for a described v5e at the advertised shapes.
 
 The same contract covers serving: ``topk_dot`` (fused dot + streaming
-top-k over a tiled item table — the exact retrieval index's hot path,
-selected per-index via ``index_kernel`` / ``PIO_INDEX_KERNEL``).
+top-k over the item table in ``[D, Ip]`` tiles of thousands of items,
+merging only a tile that can change the top-k — the exact retrieval
+index's hot path, selected per-index via ``index_kernel`` /
+``PIO_INDEX_KERNEL``).
 
 Env overrides (each beats the config flag, for bench A/B without code
 changes): ``PIO_TT_FLASH_CE``, ``PIO_TT_EMBED_UPDATE``,

@@ -38,7 +38,7 @@ from predictionio_tpu.index.ivf import IVFIndex
 from predictionio_tpu.index.recall import brute_force_topk, recall_at_k
 from predictionio_tpu.models.als import ALSAlgorithm, ALSModel, ALSParams
 from predictionio_tpu.ops.als import ALSFactors
-from predictionio_tpu.ops.pallas.topk_dot import topk_dot
+from predictionio_tpu.ops.pallas.topk_dot import tile_items, topk_dot
 from predictionio_tpu.ops.topk import NEG_INF, TopKScorer
 
 RNG = np.random.default_rng(42)
@@ -89,7 +89,8 @@ class TestTopkDotKernel:
         excl = np.full((B, E), -1, np.int32)
         # valid + out-of-tile + -1 pads
         excl[:, 0] = rng.integers(0, I, size=B)
-        s, i = topk_dot(q, items, excl, k, interpret=True)
+        s, i, _ = topk_dot(q, items, excl, k, block_items=512,
+                           interpret=True)
         bs, bi = _brute_masked(items, q, k, excl)
         np.testing.assert_allclose(np.asarray(s), bs, rtol=1e-5, atol=1e-5)
         assert np.array_equal(np.asarray(i), bi)
@@ -103,8 +104,8 @@ class TestTopkDotKernel:
         q = rng.normal(size=(2, D)).astype(np.float32)
         winner = 65_777   # > 2^16, inside the ragged tail region
         items[winner] = 100.0 * q[0] / np.linalg.norm(q[0])
-        s, i = topk_dot(q, items, np.full((2, 1), -1, np.int32), 8,
-                        interpret=True)
+        s, i, _ = topk_dot(q, items, np.full((2, 1), -1, np.int32), 8,
+                           interpret=True)
         assert int(np.asarray(i)[0, 0]) == winner
 
     def test_ties_identical_scores_valid_indices(self):
@@ -117,8 +118,8 @@ class TestTopkDotKernel:
         items = np.vstack([base, base[:200]])   # 200 exact-tie pairs
         q = rng.normal(size=(3, D)).astype(np.float32)
         k = 16
-        s, i = topk_dot(q, items, np.full((3, 1), -1, np.int32), k,
-                        interpret=True)
+        s, i, _ = topk_dot(q, items, np.full((3, 1), -1, np.int32), k,
+                           block_items=256, interpret=True)
         bs, _ = _brute_masked(items, q, k)
         np.testing.assert_allclose(np.asarray(s), bs, rtol=1e-5, atol=1e-5)
         # every returned index's true score matches the returned score
@@ -136,10 +137,102 @@ class TestTopkDotKernel:
         q = rng.normal(size=(1, 8)).astype(np.float32)
         _, top = _brute_masked(items, q, 16)
         excl = top[:, :16].astype(np.int32)       # ban the true top-16
-        s, i = topk_dot(q, items, excl, 8, interpret=True)
+        s, i, _ = topk_dot(q, items, excl, 8, block_items=512,
+                           interpret=True)
         bs, bi = _brute_masked(items, q, 8, excl)
         np.testing.assert_allclose(np.asarray(s), bs, rtol=1e-5, atol=1e-5)
         assert np.array_equal(np.asarray(i), bi)
+
+    # -- the tiling: big tiles, a merge only where the top-k can change ------
+    @pytest.mark.parametrize("B", [1, 8, 32])
+    @pytest.mark.parametrize("D", [64, 128])
+    @pytest.mark.parametrize("E,k", [(1, 8), (64, 16), (1, 128)])
+    def test_tiled_matches_brute_force(self, B, D, E, k):
+        """The module's own tile rule (no ``block_items``) over a table
+        of several tiles and a ragged tail; every row excludes the item
+        that is its best tile's maximum."""
+        bi = tile_items(D, 1 << 30, B)
+        I = 2 * bi + 777
+        rng = np.random.default_rng(B * 1000 + D + k)
+        q = rng.normal(size=(B, D)).astype(np.float32)
+        items = rng.normal(size=(I, D)).astype(np.float32)
+        excl = np.full((B, E), -1, np.int32)
+        excl[:, 0] = np.argmax(q @ items.T, axis=1)
+        if E > 1:
+            excl[:, 1:E // 2] = rng.integers(0, I, size=(B, E // 2 - 1))
+        s, i, merged = topk_dot(q, items, excl, k, interpret=True)
+        bs, bidx = _brute_masked(items, q, k, excl)
+        np.testing.assert_allclose(np.asarray(s), bs, rtol=1e-5, atol=1e-5)
+        assert np.array_equal(np.asarray(i), bidx)
+        assert 1 <= merged <= 3
+        assert not (np.asarray(i) == excl[:, :1]).any()
+
+    @pytest.mark.parametrize("B", [1, 8])
+    def test_below_one_tile(self, B):
+        """Fewer items than one tile of the rule: the tile shrinks to
+        the table (a power of two of lanes), one grid step."""
+        I, D, k = 300, 64, 16
+        assert tile_items(D, I, B) == 512
+        rng = np.random.default_rng(B)
+        q = rng.normal(size=(B, D)).astype(np.float32)
+        items = rng.normal(size=(I, D)).astype(np.float32)
+        s, i, merged = topk_dot(q, items, np.full((B, 1), -1, np.int32), k,
+                                interpret=True)
+        bs, bidx = _brute_masked(items, q, k)
+        np.testing.assert_allclose(np.asarray(s), bs, rtol=1e-5, atol=1e-5)
+        assert np.array_equal(np.asarray(i), bidx)
+        assert merged == 1
+
+    @pytest.mark.parametrize("B", [1, 8, 32])
+    def test_padded_tail_never_wins_against_negative_scores(self, B):
+        """Every real score is negative, the zero-padded tail scores 0:
+        nothing of the tail may enter, in any tile that holds padding."""
+        I, D, k = 2 * 512 + 3, 16, 8
+        rng = np.random.default_rng(3)
+        items = np.abs(rng.normal(size=(I, D))).astype(np.float32)
+        q = -np.abs(rng.normal(size=(B, D))).astype(np.float32)
+        s, i, _ = topk_dot(q, items, np.full((B, 1), -1, np.int32), k,
+                           block_items=512, interpret=True)
+        bs, bidx = _brute_masked(items, q, k)
+        assert (bs < 0).all()
+        np.testing.assert_allclose(np.asarray(s), bs, rtol=1e-5, atol=1e-5)
+        assert np.array_equal(np.asarray(i), bidx)
+
+    @pytest.mark.parametrize("order,merged_tiles", [("ascending", 8),
+                                                    ("descending", 1)])
+    @pytest.mark.parametrize("B", [1, 8])
+    def test_merged_tiles_counts_the_tiles_that_could_change_the_topk(
+            self, order, merged_tiles, B):
+        """Scores ascending along the table: every tile beats the
+        running k-th best and merges. Descending: none after the first."""
+        bi, D, k = 256, 8, 8
+        I = 8 * bi
+        ramp = np.linspace(1.0, 2.0, I, dtype=np.float32)
+        if order == "descending":
+            ramp = ramp[::-1]
+        items = np.zeros((I, D), np.float32)
+        items[:, 0] = ramp
+        q = np.zeros((B, D), np.float32)
+        q[:, 0] = np.arange(1, B + 1)
+        s, i, merged = topk_dot(q, items, np.full((B, 1), -1, np.int32), k,
+                                block_items=bi, interpret=True)
+        bs, bidx = _brute_masked(items, q, k)
+        np.testing.assert_allclose(np.asarray(s), bs, rtol=1e-6)
+        assert np.array_equal(np.asarray(i), bidx)
+        assert merged == merged_tiles
+
+    def test_fewer_candidates_than_k_leaves_unfilled_slots_masked(self):
+        """Exclusions leave fewer than k candidates: the slots nothing
+        filled read NEG_INF / -1, as the index contract has it."""
+        items = np.eye(8, dtype=np.float32) + 1.0
+        q = np.ones((1, 8), np.float32)
+        excl = np.arange(5, dtype=np.int32)[None, :]
+        s, i, _ = topk_dot(q, items, np.concatenate(
+            [excl, np.full((1, 3), -1, np.int32)], axis=1), 8,
+            interpret=True)
+        s, i = np.asarray(s), np.asarray(i)
+        assert sorted(i[0, :3].tolist()) == [5, 6, 7]
+        assert (s[0, 3:] <= float(NEG_INF)).all() and (i[0, 3:] == -1).all()
 
 
 # ---------------------------------------------------------------------------
@@ -183,6 +276,48 @@ class TestExactIndex:
         assert len(index) == 901
         s, i = index.search(probe, 2)
         assert int(i[0, 0]) == 900 and int(i[0, 1]) == 5
+
+    def test_upsert_rebuilds_the_device_table_in_the_kernels_layout(self):
+        """The device copy is ``[D, Ip]``, items on the lanes; an
+        overwrite rebuilds it at the same shape, an append past the last
+        tile grows it by a tile (and drops the compiled searches)."""
+        vecs = RNG.normal(size=(1024, 12)).astype(np.float32)
+        index = ExactIndex(kernel="on", block_items=256)
+        index.build(vecs)
+        probe = np.zeros(12, np.float32)
+        probe[3] = 1.0
+        index.search(probe, 4)
+        assert index._device_table.shape == (16, 1024)
+        np.testing.assert_array_equal(
+            np.asarray(index._device_table)[:12, :1024], vecs.T)
+        index.upsert(np.array([1023]), 70.0 * probe)
+        assert index._device_table is None      # dropped, not patched
+        s, i = index.search(probe, 4)
+        assert int(i[0, 0]) == 1023 and index._device_table.shape == (16, 1024)
+        index.upsert(np.array([1024]), 90.0 * probe)
+        assert not index._fns
+        s, i = index.search(probe, 4)
+        assert i[0, :2].tolist() == [1024, 1023]
+        assert index._device_table.shape == (16, 1280)
+        assert index.stats()["kernel"]["tiles"] == 5
+
+    def test_stats_fetches_the_merged_tile_count_and_search_does_not(self):
+        import jax
+
+        index = ExactIndex(kernel="on", block_items=256)
+        index.build(self.VECS)
+        assert "tiles" not in index.stats()["kernel"]
+        index.search(RNG.normal(size=(2, 12)).astype(np.float32), 10)
+        tiles, merged = index._last_merge
+        # still on the device: a query never waits for it
+        assert isinstance(merged, jax.Array)
+        kernel = index.stats()["kernel"]
+        assert kernel["tiles"] == tiles == 4
+        assert 1 <= kernel["merged_tiles"] <= 4
+        assert isinstance(kernel["merged_tiles"], int)
+        # a fallback search leaves the last kernel search's count alone
+        index.search(RNG.normal(size=(1, 12)).astype(np.float32), 5000)
+        assert index.stats()["kernel"]["tiles"] == 4
 
     def test_empty_index_search(self):
         index = ExactIndex()
@@ -425,6 +560,21 @@ class TestServingEndToEnd:
         # user -> top-k retrieval sees the full (grown) catalog too
         user_q = self._query(server, {"user": "u1", "num": 21})
         assert len(user_q["itemScores"]) == 21   # 20 trained + patched
+
+    def test_status_page_shows_the_kernels_merged_tile_count(
+            self, monkeypatch, request):
+        """``GET /`` -> ``retrieval[].kernel``: how many tiles the last
+        kernel search had and how many it merged."""
+        import urllib.request
+
+        monkeypatch.setenv("PIO_INDEX_KERNEL", "on")   # interpret on CPU
+        _, _, server = request.getfixturevalue("served_world")
+        self._query(server, {"user": "u1", "num": 5})
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{server.port}/", timeout=30) as resp:
+            kernel = json.loads(resp.read())["retrieval"][0]["kernel"]
+        assert kernel["engaged"] and kernel["tiles"] == 1
+        assert kernel["merged_tiles"] == 1
 
     def test_index_survives_reload_hot_swap(self, served_world):
         storage, engine, server = served_world

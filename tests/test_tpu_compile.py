@@ -13,6 +13,8 @@ fixture of the worker that got this file, never at import.
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,8 +68,9 @@ def _topk_dot(n_items, D, B, k=16, n_excl=8):
     from predictionio_tpu.ops.pallas import topk_dot
 
     fn = topk_dot.make_topk_dot(n_items, D, B, k, n_excl)
-    padded = -(-n_items // topk_dot.BLOCK_ITEMS) * topk_dot.BLOCK_ITEMS
-    return fn, [((B, D), jnp.float32), ((padded, D), jnp.float32),
+    # the table as the index keeps it: [D, Ip], items on the lanes
+    return fn, [((B, D), jnp.float32),
+                (topk_dot.table_shape(D, n_items), jnp.float32),
                 ((B, n_excl), jnp.int32)], 1
 
 
@@ -92,10 +95,20 @@ def _embed_update(N, B, E):
         "embed_update-1M-8192x128"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
+    text = _compiled_text(fn, shapes, one_chip)
+    assert text.count("tpu_custom_call") >= n_kernels
+
+
+def _compiled_text(fn, shapes, one_chip):
     specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
              for shape, dtype in shapes]
-    compiled = jax.jit(fn).lower(*specs).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _kernel_instructions(text):
+    """Names of the compiled text's Pallas kernel instructions."""
+    return re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                      r"\"tpu_custom_call\"", text)
 
 
 @pytest.mark.parametrize("build,args,names", [
@@ -109,20 +122,33 @@ def test_a_kernels_instruction_carries_its_name(one_chip, no_compile_cache,
     readers find a kernel by ``pallas_call(name=...)``, which must reach the
     compiled instruction (unnamed, it took an accidental one:
     ``tpu_custom_call.1``, ``jvp__.6``)."""
-    import re
-
     fn, shapes, _ = build(*args)
-    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-             for shape, dtype in shapes]
-    text = jax.jit(fn).lower(*specs).compile().as_text()
-    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
-                         r"\"tpu_custom_call\"", text)
+    kernels = _kernel_instructions(_compiled_text(fn, shapes, one_chip))
     assert len(kernels) == len(names)
     # XLA appends .N; under autodiff with no scope around the call JAX
     # wraps the name (jvp_flash_ce_fwd_.1), inside the trainer's
     # twotower.flash_ce scope it does not (flash_ce_fwd.6)
     for name in names:
         assert sum(name in k for k in kernels) == 1, (name, kernels)
+
+
+def test_a_lone_search_at_the_cells_shape_reads_the_table_as_it_is_stored(
+        one_chip, no_compile_cache):
+    """``als-amazon14``'s lone query (9,400,000 x 64, B = 1, k bucket 16,
+    one exclusion column): ONE kernel, and nothing in front of it that
+    re-tiles, transposes or pads the table: no ``copy``/``transpose``/
+    ``pad`` instruction (fused or not) with a table-sized result."""
+    n_items, D = 9_400_000, 64
+    fn, shapes, _ = _topk_dot(n_items, D, 1, k=16, n_excl=1)
+    text = _compiled_text(fn, shapes, one_chip)
+    kernels = _kernel_instructions(text)
+    assert len(kernels) == 1 and "topk_dot" in kernels[0], kernels
+    table_sized = [
+        m.group(0) for m in re.finditer(
+            r"= \w+\[([\d,]*)\]\S* (copy|transpose|pad)\(", text)
+        if math.prod(int(d) for d in m.group(1).split(",") if d)
+        >= n_items * D]
+    assert not table_sized, table_sized
 
 
 def test_a_scope_reaches_xlas_own_fusions_through_the_scope_map(
