@@ -1,0 +1,10 @@
+"""Share of the device's busy time over the traced stretch spent in
+the dense FFNs (``seq.layer<i>.ffn_a|ffn_b``), in %: the self time of the operations traced under those
+``jax.named_scope``s, through the program's ``obs/jaxmon.SCOPE_MAPS``
+(``seq_counts.scope_share_pct``)."""
+
+PARTS = ("ffn_",)
+
+
+def read(ctx):
+    return ctx["bench"].lib("seq_counts").scope_share_pct(ctx, PARTS)

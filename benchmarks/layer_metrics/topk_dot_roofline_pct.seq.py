@@ -1,0 +1,27 @@
+"""The head's ``topk_dot`` kernel's share of its roofline over the traced
+stretch, in %: the least time the chip could take for one search (the
+float32 output embedding, ``vocab_size`` x ``hidden_size``, read once at the
+peak memory rate: ``seq_counts.head_bytes``; the product over at most 8
+hidden states needs far less) over the median device time of the kernel's
+own events (instruction ``topk_dot.N``: the one-row search after a prefill
+and the eight-row search after an extension batch alike)."""
+
+import statistics
+
+
+def read(ctx):
+    bench = ctx["bench"]
+    spans = bench.lib("program_spans")
+    trace = spans.trace_of(ctx)
+    if trace is None:
+        return None
+    calls = spans.ops_named(trace, "topk_dot")
+    if not calls:
+        return None
+    counts, kernel = bench.lib("seq_counts"), bench.lib("kernel_counts")
+    peaks = bench.lib("peaks").peaks_for(bench.devices[0].device_kind)
+    least_s = kernel.least_seconds(
+        peaks, flops=counts.head_flops(bench.config),
+        nbytes=counts.head_bytes(bench.config))
+    measured_s = statistics.median(o.end - o.start for o in calls) / 1e9
+    return kernel.roofline_pct(least_s, measured_s)

@@ -112,6 +112,15 @@ class Algorithm(Doer, Generic[PD, M, Q, P]):
     uses in P2LAlgorithm.scala:63).
     """
 
+    #: an algorithm that answers IN STEPS sets this and adds
+    #: ``begin(model, query) -> ticket | None`` (None: no room yet, ask again
+    #: after a step), ``step(model, tickets, done)`` (a bounded amount of
+    #: work over the pending tickets; ``done(ticket, prediction)`` the moment
+    #: one is answered, ``prediction`` as ``predict`` gives it) and
+    #: ``cancel(model, ticket)``. The engine server then drives it with its
+    #: step worker instead of the batcher (``serving/engine_server.py``).
+    stepwise = False
+
     @abc.abstractmethod
     def train(self, ctx: MeshContext, prepared_data: PD) -> M:
         ...

@@ -135,6 +135,21 @@ def twotower_matmul_flops(batch: int, dim: int,
     return flops
 
 
+def active_param_flops(tokens: float, dense_params: float,
+                       expert_params: float, expert_picks: float) -> float:
+    """Forward FLOPs of an expert model on the ACTIVE-parameter basis: two
+    operations a parameter a token over the parameters every token passes
+    (``dense_params``: attention, dense FFNs, router), and two a parameter
+    for each (token, pick) that reached a computing expert
+    (``expert_picks`` x ``expert_params`` of one expert). With zero-compute
+    experts, or with only a share of the experts held here, the compute a
+    token costs varies with where its picks went, so the basis takes the
+    picks as COUNTED (``SeqStackModel.counters``), not ``top_k`` a token.
+    Attention's own products are the caller's to add."""
+    return 2.0 * (float(tokens) * float(dense_params)
+                  + float(expert_picks) * float(expert_params))
+
+
 # -- cost analysis of compiled steps ------------------------------------------
 
 def costs_from_compiled(compiled: Any) -> Optional[Tuple[float, float]]:

@@ -71,21 +71,30 @@ def _accum_block(
     q_pos: jax.Array,    # [Lq] global positions
     k_pos: jax.Array,    # [Lk] global positions
     causal: bool,
+    scale: Optional[float] = None,   # None: q's head width ** -0.5
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One online-softmax update: fold the (q, k/v-block) partial into
     the (m, l, o) accumulators. The rescaling trick is the standard
     flash-attention recurrence."""
-    scale = q.shape[-1] ** -0.5
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale          # MXU
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    # products accumulate in float32 whatever the inputs' type (bfloat16
+    # keys beside float32 accumulators: the MLA prefill); the values'
+    # head width is its own (192-wide keys beside 128-wide values)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale   # MXU
     if causal:
-        mask = q_pos[:, None] >= k_pos[None, :]              # [Lq, Lk]
-        s = jnp.where(mask[None, None], s, _NEG)
+        # ``q_pos``/``k_pos`` are [L] (one set of positions for the batch)
+        # or [B, L] (each row its own: sessions of different lengths)
+        mask = q_pos[..., :, None] >= k_pos[..., None, :]
+        s = jnp.where(mask[None, None] if mask.ndim == 2 else mask[:, None],
+                      s, _NEG)
     m_new = jnp.maximum(m, s.max(axis=-1))                   # [B, H, Lq]
     alpha = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new[..., None])                        # [B, H, Lq, Lk]
     l_new = l * alpha + p.sum(axis=-1)
     o_new = o * alpha.transpose(0, 2, 1)[..., None] + jnp.einsum(
-        "bhqk,bkhd->bqhd", p, v
+        "bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
     )
     return m_new, l_new, o_new
 
@@ -113,12 +122,13 @@ def blockwise_attention(
     dtype = q.dtype
     qf = q.astype(jnp.float32)
     kb = k.astype(jnp.float32).reshape(B, n_blocks, block_size, H, D)
-    vb = v.astype(jnp.float32).reshape(B, n_blocks, block_size, H, D)
+    vb = v.astype(jnp.float32).reshape(B, n_blocks, block_size, H,
+                                       v.shape[-1])
     q_pos = jnp.arange(L)
 
     m0 = jnp.full((B, H, L), _NEG, jnp.float32)
     l0 = jnp.zeros((B, H, L), jnp.float32)
-    o0 = jnp.zeros((B, L, H, D), jnp.float32)
+    o0 = jnp.zeros((B, L, H, v.shape[-1]), jnp.float32)
 
     def body(carry, blk):
         m, l, o = carry
@@ -134,6 +144,30 @@ def blockwise_attention(
          jnp.arange(n_blocks)),
     )
     return _finish(m, l, o, dtype)
+
+
+def attend_over_blocks(q, q_pos, kv_block, n_blocks, block_size: int,
+                       v_dim: int, dtype=None,
+                       scale: Optional[float] = None) -> jax.Array:
+    """Causal attention of ``q`` [B, Lq, H, Dk] (positions ``q_pos``, [Lq]
+    or [B, Lq]) over keys and values that ``kv_block(j)`` produces one block
+    at a time — ``(k [B, block, H, Dk], v [B, block, H, Dv])`` for the key
+    positions ``j * block_size + arange(block_size)`` — so the caller can
+    read them from a cache, or expand them from latents, only as far as the
+    history reaches. ``n_blocks`` may be traced: the loop runs that many
+    times in ONE compiled program for every history length."""
+    B, Lq, H, _ = q.shape
+    carry = (jnp.full((B, H, Lq), _NEG, jnp.float32),
+             jnp.zeros((B, H, Lq), jnp.float32),
+             jnp.zeros((B, Lq, H, v_dim), jnp.float32))
+
+    def body(j, carry):
+        k, v = kv_block(j)
+        k_pos = j * block_size + jnp.arange(block_size)
+        return _accum_block(q, k, v, *carry, q_pos, k_pos, True, scale)
+
+    m, l, o = jax.lax.fori_loop(0, n_blocks, body, carry)
+    return _finish(m, l, o, dtype or q.dtype)
 
 
 def ring_attention(

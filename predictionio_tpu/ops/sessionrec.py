@@ -28,18 +28,21 @@ import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from predictionio_tpu.ops import mla as mla_ops
+from predictionio_tpu.ops import moe as moe_ops
 from predictionio_tpu.ops.attention import (
     blockwise_attention,
     mha_reference,
     ring_attention_sharded,
 )
+from predictionio_tpu.ops.mla import MLADims
+from predictionio_tpu.ops.moe import MoEDims
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,20 +63,223 @@ class SessionRecConfig:
     checkpoint_dir: Optional[str] = None  # mid-training checkpoint/resume
     checkpoint_every: int = 1             # epochs between checkpoints
 
+    def stack(self) -> "StackSpec":
+        """This model as a configuration of the block stack."""
+        return StackSpec(
+            dim=self.dim, ffn_dim=self.dim * self.ffn_mult,
+            blocks=(BlockSpec(),) * self.layers, heads=self.heads,
+            positions="learned", max_len=self.max_len,
+            embed_scale=self.dim ** 0.5)
 
-class _Block(nn.Module):
-    """Pre-LN transformer block; attention path selected by config."""
 
-    cfg: SessionRecConfig
-    mesh: Optional[Mesh]
+# ---------------------------------------------------------------------------
+# The block stack, built from a configuration
+# ---------------------------------------------------------------------------
 
-    @nn.compact
-    def __call__(self, x: jax.Array, *, deterministic: bool) -> jax.Array:
+@dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """One block of the stack, by the kinds of its parts."""
+
+    mixer: str = "mha"          # "mha" (q/k/v heads) | "mla" (ops/mla.py)
+    ffn: str = "gelu_mlp"       # "gelu_mlp" | "swiglu"
+    norm: str = "layernorm"     # "layernorm" | "rmsnorm"
+    #: "pre_ln": x + mix(norm x), then + ffn(norm .);
+    #: "scmoe": the shortcut-connected double-layer — two mixers, two dense
+    #: FFNs and ONE expert layer computed from the first half's input and
+    #: added at the double-layer's end (so nothing after it in the
+    #: double-layer waits for it)
+    topology: str = "pre_ln"
+
+
+@dataclasses.dataclass(frozen=True)
+class StackSpec:
+    """A stack of blocks over item embeddings. The toy next-item model is
+    one such configuration (:meth:`SessionRecConfig.stack`), a latent-
+    attention expert model another; both run through :func:`apply_block`."""
+
+    dim: int
+    ffn_dim: int
+    blocks: Tuple[BlockSpec, ...]
+    heads: int = 0                       # "mha" mixers
+    positions: str = "learned"           # "learned" (added) | "rope" (mixer's)
+    max_len: int = 0                     # learned positions
+    embed_scale: float = 1.0
+    eps: float = 1e-6
+    tied_head: bool = True               # scores against the item embedding
+    mla: Optional[MLADims] = None
+    moe: Optional[MoEDims] = None
+
+
+def _layernorm(p, x, eps):
+    x = x.astype(jnp.float32)
+    mean = x.mean(axis=-1, keepdims=True)
+    var = jnp.square(x - mean).mean(axis=-1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+
+
+def _gelu_mlp(p, x):
+    h = jax.nn.gelu(x @ p["w1"] + p["b1"], approximate=True)
+    return h @ p["w2"] + p["b2"]
+
+
+NORMS = {"layernorm": _layernorm,
+         "rmsnorm": lambda p, x, eps: mla_ops.rms_norm(x, p, eps)}
+FFNS = {"gelu_mlp": _gelu_mlp,
+        "swiglu": lambda p, x: moe_ops.swiglu(x, p["w_g"], p["w_u"],
+                                              p["w_d"])}
+
+
+def apply_block(spec: StackSpec, block: BlockSpec, p, x, mix, *,
+                moe=None, drop=lambda h: h, scope: str = "seq.layer0"):
+    """One block. ``mix(which, params, h)`` is the caller's mixer ("a" and,
+    in a double-layer, "b"): it carries what differs between training,
+    chunked prefill and extension (positions, the cache). ``moe(params, h)``
+    likewise, where the topology has an expert layer."""
+    norm = functools.partial(NORMS[block.norm], eps=spec.eps)
+    ffn = FFNS[block.ffn]
+
+    def scoped(name, fn, *args):
+        with jax.named_scope(f"{scope}.{name}"):
+            return fn(*args)
+
+    mixer = block.mixer
+    h1 = x + drop(scoped(f"{mixer}_a", mix, "a", p["mixer_a"],
+                         norm(p["norm_a"], x)))
+    u = norm(p["norm_ffn_a"], h1)
+    if block.topology == "pre_ln":
+        return h1 + drop(scoped("ffn_a", ffn, p["ffn_a"], u))
+    if block.topology != "scmoe":
+        raise ValueError(f"unknown block topology {block.topology!r}")
+    m = moe(p["moe"], u)
+    h2 = h1 + scoped("ffn_a", ffn, p["ffn_a"], u)
+    h3 = h2 + scoped(f"{mixer}_b", mix, "b", p["mixer_b"],
+                     norm(p["norm_b"], h2))
+    return h3 + scoped("ffn_b", ffn, p["ffn_b"],
+                       norm(p["norm_ffn_b"], h3)) + m
+
+
+def _init_norm(kind: str, width: int, dtype):
+    if kind == "layernorm":
+        return {"scale": jnp.ones((width,), dtype),
+                "bias": jnp.zeros((width,), dtype)}
+    return jnp.ones((width,), dtype)
+
+
+def _init_mixer(spec: StackSpec, block: BlockSpec, key, dtype):
+    if block.mixer == "mla":
+        return mla_ops.init(key, spec.mla, dtype)
+    k1, k2 = jax.random.split(key)
+    head_dim = spec.dim // spec.heads
+    lecun = jax.nn.initializers.lecun_normal
+    return {
+        "wqkv": lecun(in_axis=0, out_axis=(1, 2, 3))(
+            k1, (spec.dim, 3, spec.heads, head_dim), dtype),
+        "bqkv": jnp.zeros((3, spec.heads, head_dim), dtype),
+        "wo": lecun(in_axis=(0, 1), out_axis=2)(
+            k2, (spec.heads, head_dim, spec.dim), dtype),
+        "bo": jnp.zeros((spec.dim,), dtype),
+    }
+
+
+def _init_ffn(spec: StackSpec, block: BlockSpec, key, dtype):
+    k1, k2, k3 = jax.random.split(key, 3)
+    lecun = jax.nn.initializers.lecun_normal()
+    if block.ffn == "gelu_mlp":
+        return {"w1": lecun(k1, (spec.dim, spec.ffn_dim), dtype),
+                "b1": jnp.zeros((spec.ffn_dim,), dtype),
+                "w2": lecun(k2, (spec.ffn_dim, spec.dim), dtype),
+                "b2": jnp.zeros((spec.dim,), dtype)}
+    return {"w_g": lecun(k1, (spec.dim, spec.ffn_dim), dtype),
+            "w_u": lecun(k2, (spec.dim, spec.ffn_dim), dtype),
+            "w_d": lecun(k3, (spec.ffn_dim, spec.dim), dtype)}
+
+
+def init_stack(spec: StackSpec, key, n_rows: int, dtype=jnp.float32) -> Dict:
+    """Parameters of a stack over ``n_rows`` embedding rows."""
+    keys = iter(jax.random.split(key, 3 + 5 * len(spec.blocks)))
+    embed = jax.nn.initializers.variance_scaling(
+        1.0, "fan_in", "normal", out_axis=0)
+    params: Dict = {"item_embed": {
+        "embedding": embed(next(keys), (n_rows, spec.dim), dtype)}}
+    if spec.positions == "learned":
+        params["pos_embed"] = 0.02 * jax.random.normal(
+            next(keys), (spec.max_len, spec.dim), dtype)
+    if not spec.tied_head:
+        params["head"] = embed(next(keys), (n_rows, spec.dim), dtype)
+    blocks = []
+    for block in spec.blocks:
+        p = {"norm_a": _init_norm(block.norm, spec.dim, dtype),
+             "mixer_a": _init_mixer(spec, block, next(keys), dtype),
+             "norm_ffn_a": _init_norm(block.norm, spec.dim, dtype),
+             "ffn_a": _init_ffn(spec, block, next(keys), dtype)}
+        if block.topology == "scmoe":
+            p.update(
+                norm_b=_init_norm(block.norm, spec.dim, dtype),
+                mixer_b=_init_mixer(spec, block, next(keys), dtype),
+                norm_ffn_b=_init_norm(block.norm, spec.dim, dtype),
+                ffn_b=_init_ffn(spec, block, next(keys), dtype),
+                moe=moe_ops.init(next(keys), spec.moe, dtype))
+        blocks.append(p)
+    params["blocks"] = blocks
+    params["final_norm"] = _init_norm(spec.blocks[-1].norm, spec.dim, dtype)
+    return params
+
+
+def stack_tree_from_flax(tree):
+    """A parameter tree of the flax module that the toy configuration was
+    before it ran through the block stack (``block_<i>/LayerNorm_0,
+    DenseGeneral_0, DenseGeneral_1, LayerNorm_1, Dense_0, Dense_1``), in the
+    stack's layout (``blocks[i]/norm_a, mixer_a, norm_ffn_a, ffn_a``): the
+    same arrays under their new names. Stored models and mid-training
+    checkpoints from before carry the old tree; any other tree is returned
+    as it is."""
+    if not isinstance(tree, dict) or "block_0" not in tree:
+        return tree
+
+    def block(b):
+        qkv, out = b["DenseGeneral_0"], b["DenseGeneral_1"]
+        up, down = b["Dense_0"], b["Dense_1"]
+        return {"norm_a": b["LayerNorm_0"],
+                "mixer_a": {"wqkv": qkv["kernel"], "bqkv": qkv["bias"],
+                            "wo": out["kernel"], "bo": out["bias"]},
+                "norm_ffn_a": b["LayerNorm_1"],
+                "ffn_a": {"w1": up["kernel"], "b1": up["bias"],
+                          "w2": down["kernel"], "b2": down["bias"]}}
+
+    new = {k: v for k, v in tree.items() if not k.startswith("block_")}
+    new["blocks"] = [block(tree[f"block_{i}"])
+                     for i in range(len(tree) - len(new))]
+    return new
+
+
+def stack_trees_from_flax(state):
+    """:func:`stack_tree_from_flax` over every such tree inside ``state``:
+    ``{"params": tree}``, or an optimiser state whose moments mirror it."""
+    return jax.tree_util.tree_map(
+        stack_tree_from_flax, state,
+        is_leaf=lambda x: isinstance(x, dict) and "block_0" in x)
+
+
+class SessionEncoder:
+    """Item+position embedding -> causal blocks -> hidden states: the toy
+    configuration of the block stack, with the ``init`` / ``apply`` surface
+    of the module it once was (``params`` is ``{"params": tree}``).
+
+    Vocabulary is n_items + 1: index 0 is the padding token; real items
+    are 1-shifted by the caller.
+    """
+
+    def __init__(self, n_items: int, cfg: SessionRecConfig,
+                 mesh: Optional[Mesh] = None):
+        self.n_items, self.cfg, self.mesh = n_items, cfg, mesh
+        self.spec = cfg.stack()
+
+    def init(self, key, seq=None, *, deterministic: bool = True) -> Dict:
+        return {"params": init_stack(self.spec, key, self.n_items + 1)}
+
+    def _mix(self, which, p, h):
         cfg = self.cfg
-        h = nn.LayerNorm()(x)
-        B, L, _ = h.shape
-        head_dim = cfg.dim // cfg.heads
-        qkv = nn.DenseGeneral((3, cfg.heads, head_dim), axis=-1)(h)
+        qkv = jnp.einsum("bld,dthe->blthe", h, p["wqkv"]) + p["bqkv"]
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]   # [B, L, H, Dh]
         if cfg.seq_axis is not None and self.mesh is not None:
             attn = ring_attention_sharded(
@@ -83,43 +289,27 @@ class _Block(nn.Module):
             attn = blockwise_attention(q, k, v, block_size=cfg.attn_block)
         else:
             attn = mha_reference(q, k, v, causal=True)
-        attn = nn.DenseGeneral(cfg.dim, axis=(-2, -1))(attn)
-        attn = nn.Dropout(cfg.dropout)(attn, deterministic=deterministic)
-        x = x + attn
-        h = nn.LayerNorm()(x)
-        h = nn.Dense(cfg.dim * cfg.ffn_mult)(h)
-        h = nn.gelu(h)
-        h = nn.Dense(cfg.dim)(h)
-        h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
-        return x + h
+        return jnp.einsum("blhe,hed->bld", attn, p["wo"]) + p["bo"]
 
+    def apply(self, params, seq: jax.Array, *, deterministic: bool = True,
+              rngs: Optional[Dict] = None) -> jax.Array:
+        cfg, spec, p = self.cfg, self.spec, params["params"]
+        rate = 0.0 if deterministic else cfg.dropout
+        sites = iter(range(1 << 30))
 
-class SessionEncoder(nn.Module):
-    """Item+position embedding -> causal blocks -> hidden states.
+        def drop(h):
+            if rate == 0.0:
+                return h
+            key = jax.random.fold_in(rngs["dropout"], next(sites))
+            keep = jax.random.bernoulli(key, 1.0 - rate, h.shape)
+            return jnp.where(keep, h / (1.0 - rate), 0.0)
 
-    Vocabulary is n_items + 1: index 0 is the padding token; real items
-    are 1-shifted by the caller.
-    """
-
-    n_items: int
-    cfg: SessionRecConfig
-    mesh: Optional[Mesh] = None
-
-    @nn.compact
-    def __call__(self, seq: jax.Array, *, deterministic: bool = True) -> jax.Array:
-        cfg = self.cfg
-        emb = nn.Embed(self.n_items + 1, cfg.dim, name="item_embed")
-        x = emb(seq) * (cfg.dim ** 0.5)
-        pos = self.param(
-            "pos_embed", nn.initializers.normal(0.02), (cfg.max_len, cfg.dim)
-        )
-        x = x + pos[None, : seq.shape[1]]
-        x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
-        for i in range(cfg.layers):
-            x = _Block(cfg, self.mesh, name=f"block_{i}")(
-                x, deterministic=deterministic
-            )
-        x = nn.LayerNorm(name="final_norm")(x)
+        x = p["item_embed"]["embedding"][seq] * spec.embed_scale
+        x = drop(x + p["pos_embed"][None, : seq.shape[1]])
+        for i, block in enumerate(spec.blocks):
+            x = apply_block(spec, block, p["blocks"][i], x, self._mix,
+                            drop=drop, scope=f"seq.layer{i}")
+        x = NORMS[spec.blocks[-1].norm](p["final_norm"], x, spec.eps)
         # padding positions carry no signal downstream
         return x * (seq > 0)[..., None]
 
@@ -166,6 +356,10 @@ class SessionRecModelState:
     n_items: int
     cfg: SessionRecConfig
     losses: List[float]
+
+    def __setstate__(self, d):
+        self.__dict__.update(d)
+        self.params = stack_trees_from_flax(self.params)
 
 
 class SessionRecTrainer:
@@ -234,7 +428,8 @@ class SessionRecTrainer:
             restored = self._ckpt.restore()
             if restored is not None:
                 epoch, state = restored
-                params, opt_state = state["params"], state["opt_state"]
+                params, opt_state = stack_trees_from_flax(
+                    (state["params"], state["opt_state"]))
                 if mesh is not None:
                     rep = NamedSharding(mesh, P())
                     params = jax.device_put(params, rep)
@@ -397,3 +592,161 @@ class SessionScorer:
         # is always -inf, so it must never count toward (or appear in) k
         scores, idx = jax.lax.top_k(logits, min(k, logits.shape[1] - 1))
         return np.asarray(scores)[:B], np.asarray(idx)[:B] - 1  # unshift pad
+
+
+# ---------------------------------------------------------------------------
+# Serving a latent-attention stack in steps, over a per-session latent cache
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class ServeShape:
+    """The fixed shapes of the two serve programs (everything a window can
+    ask for runs in these two, compiled once)."""
+
+    n_slots: int = 32            # sessions whose latents are kept
+    capacity: int = 8192         # positions a slot holds
+    #: positions of one prefill chunk, and the cached positions attention
+    #: takes per round of its block loop
+    chunk: int = 512
+    extend_len: int = 8          # new positions of one extension, at most
+    extend_batch: int = 8        # extensions of one step, at most
+
+
+class StackPrograms:
+    """The compiled serve path of a stack whose mixers are latent attention:
+    ``prefill`` (one chunk of one session against its slot) and ``extend``
+    (a few new positions of several sessions, absorbed form), over a cache of
+    ``[n_slots + 1, capacity + chunk, latent]`` per mixer. The extra slot is
+    scratch for the padding rows of an extension batch; the extra chunk of
+    positions lets the last chunk of a full slot be written whole.
+
+    Both programs take the parameters as arguments (nothing is baked in),
+    donate the cache, and return ``(cache, h_last, counters)``: the final-
+    normed hidden state of each session's last real position, and what the
+    expert layers counted. Which slot holds which session is the caller's
+    business (``models/sessionrec.LatentCache``)."""
+
+    def __init__(self, spec: StackSpec, params: Dict, shape: ServeShape):
+        if any(b.mixer != "mla" for b in spec.blocks):
+            raise ValueError("stepwise serving needs latent-attention mixers")
+        from predictionio_tpu.obs import jaxmon
+
+        self.spec, self.shape, self.params = spec, shape, params
+        dtype = params["item_embed"]["embedding"].dtype
+        n_mixers = sum(2 if b.topology == "scmoe" else 1
+                       for b in spec.blocks)
+        self.cache = [jnp.zeros((shape.n_slots + 1,
+                                 shape.capacity + shape.chunk,
+                                 mla_ops.cache_width(spec.mla)), dtype)
+                      for _ in range(n_mixers)]
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+
+        def struct(*dims):
+            return jax.ShapeDtypeStruct(dims, jnp.int32)
+
+        B, S = shape.extend_batch, shape.extend_len
+        self._prefill = jax.jit(self._prefill_fn, donate_argnums=1).lower(
+            params, self.cache, struct(shape.chunk), i32, i32, i32).compile()
+        self._extend = jax.jit(self._extend_fn, donate_argnums=1).lower(
+            params, self.cache, struct(B, S), struct(B), struct(B),
+            struct(B), i32).compile()
+        for compiled in (self._prefill, self._extend):
+            jaxmon.record_scope_map(compiled)
+
+    # -- the two programs -----------------------------------------------------
+    def _run(self, params, x, valid, mix_with):
+        """The blocks over tokens ``x`` [T, dim] (float32 residual stream);
+        ``mix_with(i_mixer)`` gives block code its mixer."""
+        spec = self.spec
+        loads, zeros, i_mixer = [], [], 0
+        for i, block in enumerate(spec.blocks):
+            mixers = {"a": i_mixer, "b": i_mixer + 1}
+
+            def mix(which, p, h, _m=mixers):
+                return mix_with(_m[which], p, h)
+
+            def moe(p, h, _i=i):
+                y, counted = moe_ops.moe(p, spec.moe, h, valid,
+                                         scope=f"seq.layer{_i}.moe")
+                loads.append(counted["expert_load"])
+                zeros.append(counted["zero_picks"])
+                return y
+
+            x = apply_block(spec, block, params["blocks"][i], x, mix,
+                            moe=moe, scope=f"seq.layer{i}")
+            i_mixer += 2 if block.topology == "scmoe" else 1
+        counters = {"tokens": valid.sum().astype(jnp.int32)}
+        if loads:
+            counters.update(expert_load=jnp.stack(loads),
+                            zero_picks=jnp.stack(zeros))
+        return x, counters
+
+    def _final(self, params, h):
+        return NORMS[self.spec.blocks[-1].norm](
+            params["final_norm"], h, self.spec.eps)
+
+    def _embed(self, params, ids):
+        return (params["item_embed"]["embedding"][ids].astype(jnp.float32)
+                * self.spec.embed_scale)
+
+    def _prefill_fn(self, params, cache, ids, n_valid, slot, offset):
+        cache = list(cache)
+        valid = jnp.arange(ids.shape[0]) < n_valid
+
+        def mix_with(m, p, h):
+            out, cache[m] = mla_ops.prefill_chunk(
+                p, self.spec.mla, h, offset, cache[m], slot,
+                self.shape.chunk)
+            return out
+
+        x, counters = self._run(params, self._embed(params, ids), valid,
+                                mix_with)
+        return cache, self._final(params, x[n_valid - 1])[None], counters
+
+    def _extend_fn(self, params, cache, ids, n_new, slots, pos0, n_blocks):
+        cache = list(cache)
+        B, S = ids.shape
+        pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+        valid = (jnp.arange(S)[None] < n_new[:, None]).reshape(-1)
+
+        def mix_with(m, p, h):
+            out, cache[m] = mla_ops.extend(
+                p, self.spec.mla, h.reshape(B, S, -1), pos, cache[m], slots,
+                n_blocks, self.shape.chunk)
+            return out.reshape(B * S, -1)
+
+        x, counters = self._run(
+            params, self._embed(params, ids).reshape(B * S, -1), valid,
+            mix_with)
+        last = x.reshape(B, S, -1)[jnp.arange(B), jnp.maximum(n_new - 1, 0)]
+        return cache, self._final(params, last), counters
+
+    # -- calls ----------------------------------------------------------------
+    def prefill(self, ids: np.ndarray, slot: int, offset: int):
+        """One chunk (``len(ids) <= chunk`` real positions) of the session in
+        ``slot``, from position ``offset`` on. ``(h_last [1, dim],
+        counters)``, still on the device."""
+        padded = np.zeros(self.shape.chunk, np.int32)
+        padded[:len(ids)] = ids
+        self.cache, h_last, counters = self._prefill(
+            self.params, self.cache, padded, np.int32(len(ids)),
+            np.int32(slot), np.int32(offset))
+        return h_last, counters
+
+    def extend(self, rows):
+        """``rows``: [(ids, slot, position of ids[0])], at most
+        ``extend_batch`` of at most ``extend_len`` ids each.
+        ``(h_last [extend_batch, dim], counters)``, still on the device."""
+        sh = self.shape
+        ids = np.zeros((sh.extend_batch, sh.extend_len), np.int32)
+        n_new = np.zeros(sh.extend_batch, np.int32)
+        slots = np.full(sh.extend_batch, sh.n_slots, np.int32)   # scratch
+        pos0 = np.zeros(sh.extend_batch, np.int32)
+        for b, (new, slot, at) in enumerate(rows):
+            ids[b, :len(new)] = new
+            n_new[b], slots[b], pos0[b] = len(new), slot, at
+        reach = int((pos0 + sh.extend_len).max())
+        self.cache, h_last, counters = self._extend(
+            self.params, self.cache, ids, n_new, slots, pos0,
+            np.int32(-(-reach // sh.chunk)))
+        return h_last, counters

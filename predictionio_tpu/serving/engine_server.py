@@ -476,6 +476,195 @@ class MicroBatcher:
         return self._queue.qsize()
 
 
+class StepWorker:
+    """The worker of a deployment whose algorithm answers IN STEPS
+    (``core.Algorithm.stepwise``: ``begin``, ``step``, ``cancel``; a sequence
+    model over a per-session cache, ``models/sessionrec.SeqStackAlgorithm``).
+
+    :class:`MicroBatcher` answers a batch as a whole, so a query that needs
+    a few milliseconds would wait behind a batchmate that needs a second.
+    Here the worker keeps every admitted query as a ticket and calls the
+    algorithm's ``step`` over all of them, again and again: a step does a
+    bounded amount of work (every pending short extension, one chunk of one
+    long history), hands back what it finished, and new arrivals join at the
+    next step. A query therefore waits for the step in flight and for its
+    own steps, never for another query's whole answer.
+
+    The submit / stop / queue_depth / histogram / recent_splits surface is
+    the batcher's, so the server around it is the same."""
+
+    def __init__(self, deployment_of, chaos_tag: Optional[str] = None):
+        import queue as _queue
+        from collections import deque
+
+        self._deployment_of = deployment_of
+        self._chaos_tag = chaos_tag
+        self._queue: "_queue.Queue[_Pending]" = _queue.Queue()
+        self._hist_lock = threading.Lock()
+        self._hist: dict = {}               # finished per step -> steps
+        self._splits = deque(maxlen=50_000)
+        self._abandoned = 0
+        self._stop = False
+        self._stop_lock = threading.Lock()
+        self._worker = threading.Thread(target=self._loop, daemon=True,
+                                        name="pio-batcher")
+        self._worker.start()
+
+    def submit(self, payload, timeout: float = 30.0):
+        pending = _Pending(payload)
+        with self._stop_lock:
+            if self._stop:
+                raise RuntimeError("serving step worker is stopped")
+            self._queue.put(pending)
+        if not pending.event.wait(timeout):
+            pending.abandoned = True
+            raise TimeoutError("query timed out in the serving step worker")
+        if pending.error is not None:
+            raise pending.error
+        return pending.result
+
+    def stop(self) -> None:
+        with self._stop_lock:
+            if self._stop:
+                return
+            self._stop = True
+            self._queue.put(_Pending(None))  # wake the worker
+        self._worker.join(timeout=60)
+
+    def queue_depth(self) -> int:
+        return self._queue.qsize()
+
+    def histogram(self) -> dict:
+        with self._hist_lock:
+            hist = {str(k): v for k, v in sorted(self._hist.items())}
+            abandoned = self._abandoned
+        return {"stepwise": True, "dispatches": sum(hist.values()),
+                "batchSizeHistogram": hist,
+                "answered": sum(int(k) * v for k, v in hist.items()),
+                "abandonedRequests": abandoned}
+
+    def recent_splits(self, n: int):
+        """Last ``n`` answered requests' (seconds from submit to admission —
+        the wait for the step in flight —, seconds from there to the answer,
+        whether the model called the query an extension), oldest first."""
+        with self._hist_lock:
+            return list(self._splits)[-n:]
+
+    def _fail(self, pending, error) -> None:
+        pending.error = error
+        pending.event.set()
+
+    def _arrivals(self, block: bool) -> List[_Pending]:
+        """Whatever is queued; with nothing in hand, wait for the first."""
+        import queue as _queue
+
+        got = [self._queue.get()] if block else []
+        try:
+            for _ in range(self._queue.qsize() + 1):
+                got.append(self._queue.get_nowait())
+        except _queue.Empty:
+            pass
+        return got
+
+    def _loop(self) -> None:
+        waiting: List[_Pending] = []     # admitted, no ticket yet
+        # (pending, deployment, ticket, t_first): a ticket is stepped by
+        # the deployment that began it, through a reload too
+        active: List[tuple] = []
+        while True:
+            try:
+                waiting += self._arrivals(block=not (waiting or active))
+                if self._stop:
+                    break
+                with _DISPATCH_WATCHDOG.watch():
+                    chaos.inject("batcher", tag=self._chaos_tag)
+                    waiting, active = self._advance(waiting, active)
+            except Exception as e:  # noqa: BLE001 — a dead worker starves
+                # every later submitter: fail what was in hand, go on
+                log.exception("step worker iteration failed")
+                for p in waiting + [a[0] for a in active]:
+                    if not p.event.is_set():
+                        self._fail(p, e)
+                for _, deployment, ticket, _ in active:
+                    try:
+                        deployment.algorithms[0].cancel(
+                            deployment.models[0], ticket)
+                    except Exception:  # noqa: BLE001
+                        log.exception("ticket cancel failed")
+                waiting, active = [], []
+        # shutdown: a submitter must not block out its timeout on a
+        # stopped server, and a failure here must leave a trace
+        try:
+            for p in waiting + [a[0] for a in active]:
+                if p.payload is not None and not p.event.is_set():
+                    self._fail(p, RuntimeError("serving step worker stopped"))
+        except Exception:  # noqa: BLE001 — see above
+            log.exception("step worker shutdown drain failed")
+
+    def _advance(self, waiting, active):
+        """Admit what can be admitted, run one step, deliver. The format of
+        an answer is the algorithm's (``done(ticket, prediction)``) and
+        Serving's; nothing of it is known here."""
+        deployment = self._deployment_of()
+        algorithm, model = deployment.algorithms[0], deployment.models[0]
+        still_waiting = []
+        with trace.device_span("batch.collect"):
+            for p in waiting:
+                if p.abandoned:
+                    with self._hist_lock:
+                        self._abandoned += 1
+                    continue
+                try:
+                    ticket = algorithm.begin(model, p.payload)
+                except Exception as e:  # noqa: BLE001 — this query's own
+                    self._fail(p, e)
+                    continue
+                if ticket is None:
+                    still_waiting.append(p)
+                else:
+                    active.append((p, deployment, ticket,
+                                   time.perf_counter()))
+        by_deployment: dict = {}
+        for entry in active:
+            by_deployment.setdefault(id(entry[1]), []).append(entry)
+        still_active = []
+        for entries in by_deployment.values():
+            stepping = entries[0][1]
+            algorithm, model = stepping.algorithms[0], stepping.models[0]
+            live = []
+            for entry in entries:
+                if entry[0].abandoned:
+                    algorithm.cancel(model, entry[2])
+                    with self._hist_lock:
+                        self._abandoned += 1
+                else:
+                    live.append(entry)
+            entry_of = {id(e[2]): e for e in live}
+            delivered = []
+
+            def deliver(ticket, prediction, _entry_of=entry_of,
+                        _out=delivered, _serving=stepping.serving):
+                # called the moment a ticket is answered: an extension's
+                # answer does not wait for the step's chunk
+                p, _, _, t_first = _entry_of.pop(id(ticket))
+                t_done = time.perf_counter()
+                with trace.device_span("batch.deliver", size=1):
+                    with trace.device_span("engine.decode"):
+                        p.result = _serving.serve(p.payload, [prediction])
+                    p.event.set()
+                _out.append((t_first - p.t_submit, t_done - t_first,
+                             getattr(ticket, "extension", None)))
+
+            if live:
+                algorithm.step(model, [e[2] for e in live], deliver)
+            still_active += entry_of.values()
+            with self._hist_lock:
+                self._hist[len(delivered)] = self._hist.get(
+                    len(delivered), 0) + 1
+                self._splits.extend(delivered)
+        return still_waiting, still_active
+
+
 class EngineServer(HTTPServerBase):
     """One deployed engine behind HTTP (ref: CreateServer.scala:100,106)."""
 
@@ -521,11 +710,16 @@ class EngineServer(HTTPServerBase):
         # (subprocess replicas via PIO_CHAOS_TAG) so operators can fault
         # ONE replica of a fleet; a standalone server stays untagged
         self.chaos_tag = chaos_tag or os.environ.get("PIO_CHAOS_TAG") or None
-        self._batcher: Optional[MicroBatcher] = (
-            MicroBatcher(self._query_batch_now, self._query_now,
-                         max_batch=max_batch, chaos_tag=self.chaos_tag)
-            if micro_batch else None
-        )
+        self._batcher = None
+        if micro_batch and self.deployment.stepwise:
+            # an algorithm that answers in steps gets the step worker; every
+            # other engine keeps the batcher as it was
+            self._batcher = StepWorker(self._current_deployment,
+                                       chaos_tag=self.chaos_tag)
+        elif micro_batch:
+            self._batcher = MicroBatcher(
+                self._query_batch_now, self._query_now,
+                max_batch=max_batch, chaos_tag=self.chaos_tag)
 
         # admission control (resilience tentpole): shed with 429 +
         # Retry-After from queue depth / in-flight / SLO burn signals
@@ -650,6 +844,16 @@ class EngineServer(HTTPServerBase):
         except (StorageError, ConnectionError):
             self._storage_breaker.record_failure()
             raise
+        if self._batcher is not None and deployment.stepwise != isinstance(
+                self._batcher, StepWorker):
+            # the worker's kind was chosen at start, for the deployment
+            # there was: a batcher would run a stepwise algorithm's whole
+            # answer inside a batch, a step worker cannot drive any other
+            raise RuntimeError(
+                f"reload refused: instance {instance.id} "
+                f"{'answers' if deployment.stepwise else 'does not answer'} "
+                "in steps and this server's worker was started for the "
+                "other kind; deploy it on a new server")
         self._warmup(deployment)
         self._storage_breaker.record_success()
         with self._deployment_lock:
@@ -761,6 +965,10 @@ class EngineServer(HTTPServerBase):
         return result
 
     # -- query path ---------------------------------------------------------
+    def _current_deployment(self) -> Deployment:
+        with self._deployment_lock:
+            return self.deployment
+
     def _query_now(self, payload: Any) -> Any:
         with self._deployment_lock:
             deployment = self.deployment

@@ -26,6 +26,7 @@ from predictionio_tpu.data import store
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.models.sessionrec import (
     PreparedSequences,
+    SeqStackAlgorithm,
     SessionRecAlgorithm,
 )
 from predictionio_tpu.parallel.mesh import MeshContext
@@ -207,6 +208,9 @@ def sessionrec_engine() -> Engine:
     return Engine(
         data_source_classes=SeqDataSource,
         preparator_classes=SeqPreparator,
-        algorithm_classes={"sessionrec": SessionRecAlgorithm},
+        algorithm_classes={"sessionrec": SessionRecAlgorithm,
+                           # a latent-attention stack served in steps over
+                           # a per-session cache (serve only)
+                           "seqstack": SeqStackAlgorithm},
         serving_classes=FirstServing,
     )

@@ -68,6 +68,12 @@ class Deployment:
     models: List[Any]
     serving: Serving
 
+    @property
+    def stepwise(self) -> bool:
+        """Whether this deployment answers in steps (``Algorithm.stepwise``):
+        one algorithm, and it says so."""
+        return len(self.algorithms) == 1 and bool(self.algorithms[0].stepwise)
+
     def query(self, q: Any) -> Any:
         """One query through all algorithms + serving
         (ref: CreateServer.scala:472-475)."""

@@ -1,0 +1,410 @@
+"""The latent-attention expert stack against the plain reference
+(benchmarks/reference/longcat_forward.py, which imports nothing of the
+program), at a small size on the CPU: hidden 64, 4 heads, 2 double-layers,
+16 routed + 8 zero-compute experts, top-4, seeded float32 weights."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from predictionio_tpu.ops import mla as mla_ops
+from predictionio_tpu.ops import moe as moe_ops
+from predictionio_tpu.ops.sessionrec import (
+    BlockSpec, ServeShape, StackPrograms, StackSpec, apply_block, init_stack)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def ref():
+    path = os.path.join(REPO, "benchmarks", "reference", "longcat_forward.py")
+    spec = importlib.util.spec_from_file_location("longcat_forward_ref", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+MLA = mla_ops.MLADims(dim=64, heads=4, d_nope=16, d_rope=8, d_v=16,
+                      q_rank=32, kv_rank=24, rope_theta=1e4)
+MOE = moe_ops.MoEDims(dim=64, expert_dim=32, n_routed=16, n_zero=8, top_k=4,
+                      scale=3.0, held=(4, 4))
+N_ITEMS = 50
+
+
+def small_spec(moe=MOE, layers=2):
+    return StackSpec(
+        dim=64, ffn_dim=128, heads=4, positions="rope", eps=1e-5,
+        tied_head=False, mla=MLA, moe=moe,
+        blocks=(BlockSpec(mixer="mla", ffn="swiglu", norm="rmsnorm",
+                          topology="scmoe"),) * layers)
+
+
+def seeded_params(spec, seed=0):
+    """init_stack's weights with the norms and the selection bias made
+    non-trivial, so that a part that skipped them would show."""
+    params = init_stack(spec, jax.random.PRNGKey(seed), N_ITEMS)
+    rng = np.random.default_rng(seed)
+
+    def jitter(tree):
+        if isinstance(tree, dict):
+            return {k: (jnp.asarray(1 + 0.2 * rng.standard_normal(v.shape),
+                                    jnp.float32)
+                        if "norm" in k and not isinstance(v, dict)
+                        else jitter(v)) for k, v in tree.items()}
+        if isinstance(tree, list):
+            return [jitter(v) for v in tree]
+        return tree
+
+    params = jitter(params)
+    for block in params["blocks"]:
+        block["moe"]["bias"] = jnp.asarray(
+            2e-3 * rng.standard_normal(spec.moe.n_router), jnp.float32)
+    params["item_embed"]["embedding"] = jnp.asarray(
+        rng.standard_normal((N_ITEMS, spec.dim)), jnp.float32)
+    return params
+
+
+def as_reference(params):
+    """The same arrays under the reference's names."""
+    return {"embed": params["item_embed"]["embedding"],
+            "head": params["head"], "final_norm": params["final_norm"],
+            "layers": params["blocks"]}
+
+
+def ref_dims(spec):
+    m, e = spec.mla, spec.moe
+    return {"D": spec.dim, "H": m.heads, "dn": m.d_nope, "dr": m.d_rope,
+            "dv": m.d_v, "rq": m.q_rank, "rkv": m.kv_rank,
+            "theta": m.rope_theta, "eps": spec.eps, "n_routed": e.n_routed,
+            "n_zero": e.n_zero, "top_k": e.top_k, "scale": e.scale,
+            "held": e.held, "scale_q": m.scale_q, "scale_kv": m.scale_kv}
+
+
+def close(a, b, tol=2e-4):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    scale = max(float(np.abs(b).max()), 1e-6)
+    assert float(np.abs(a - b).max()) <= tol * scale, (
+        float(np.abs(a - b).max()), scale)
+
+
+def test_mla_full_and_chunked_prefill_match_the_reference(ref):
+    p = seeded_params(small_spec())["blocks"][0]["mixer_a"]
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((40, 64)),
+                    jnp.float32)
+    pos = jnp.arange(40, dtype=jnp.int32)
+    want = ref.mla(p, x, pos, ref_dims(small_spec()))
+    close(mla_ops.attend_full(p, MLA, x[None], pos[None])[0], want)
+    # chunks of 16 against a slot's cache, blocks of 8, starting mid-block
+    cache = jnp.zeros((2, 64, MLA.latent), jnp.float32)
+    outs, at = [], 0
+    for n in (12, 16, 12):
+        chunk = jnp.zeros((16, 64), jnp.float32).at[:n].set(x[at:at + n])
+        out, cache = mla_ops.prefill_chunk(p, MLA, chunk, at, cache, 1, 8)
+        outs.append(out[:n])
+        at += n
+    close(jnp.concatenate(outs), want)
+
+
+def test_mla_extension_over_cached_latents_matches_the_reference(ref):
+    p = seeded_params(small_spec())["blocks"][0]["mixer_b"]
+    rng = np.random.default_rng(2)
+    xs = [jnp.asarray(rng.standard_normal((n, 64)), jnp.float32)
+          for n in (21, 9)]
+    dm = ref_dims(small_spec())
+    want = [ref.mla(p, x, jnp.arange(len(x)), dm) for x in xs]
+    cache = jnp.zeros((3, 40, MLA.latent), jnp.float32)
+    # all but the last 3 / 2 positions prefilled, the rest extended together
+    new = (3, 2)
+    for slot, (x, n) in enumerate(zip(xs, new)):
+        head = len(x) - n
+        chunk = jnp.zeros((24, 64), jnp.float32).at[:head].set(x[:head])
+        _, cache = mla_ops.prefill_chunk(p, MLA, chunk, 0, cache, slot, 8)
+    batch = jnp.stack([jnp.zeros((4, 64)).at[:n].set(x[len(x) - n:])
+                       for x, n in zip(xs, new)])
+    pos0 = jnp.array([len(x) - n for x, n in zip(xs, new)], jnp.int32)
+    pos = pos0[:, None] + jnp.arange(4)[None]
+    out, _ = mla_ops.extend(p, MLA, batch, pos, cache, jnp.array([0, 1]),
+                            jnp.int32(4), 8)
+    for b, (w, n) in enumerate(zip(want, new)):
+        close(out[b, :n], w[len(w) - n:])
+
+
+@pytest.mark.parametrize("skew", ["uniform", "one_expert"])
+def test_expert_layer_matches_the_reference_and_drops_no_token(ref, skew):
+    spec = small_spec()
+    p = dict(seeded_params(spec)["blocks"][0]["moe"])
+    if skew == "one_expert":        # every token picks held expert 5
+        p["bias"] = p["bias"].at[5].set(10.0)
+    # 150 tokens: a crowded expert's group runs over several tiles
+    x = jnp.asarray(np.random.default_rng(3).standard_normal((150, 64)),
+                    jnp.float32)
+    valid = jnp.ones(150, bool)
+    y, counted = moe_ops.moe(p, MOE, x, valid)
+    routed, zero, _ = ref.moe_parts(p, x, ref_dims(spec), MOE.held)
+    close(y, routed + zero)
+    if skew == "one_expert":
+        assert int(counted["expert_load"][1]) == 150 > 2 * moe_ops.TILE
+    # a padding token reaches no expert and counts nowhere
+    y2, counted2 = moe_ops.moe(p, MOE, x, valid.at[-5:].set(False))
+    close(y2[:-5], (routed + zero)[:-5])
+    assert int(counted2["expert_load"].sum()) < int(
+        counted["expert_load"].sum()) or skew == "uniform"
+
+
+def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(ref):
+    """Four shares of the 16 routed experts, the zero-compute part counted
+    once: the whole layer, in the program and in the reference alike."""
+    whole = dataclasses.replace(MOE, held=(0, 16))
+    p = moe_ops.init(jax.random.PRNGKey(4), whole, bias_std=2e-3)
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((29, 64)),
+                    jnp.float32)
+    valid = jnp.ones(29, bool)
+    dm = ref_dims(small_spec())
+    uncut_r, uncut_z, _ = ref.moe_parts(p, x, dm, (0, 16))
+    idx, gates = moe_ops.route(p, whole, x)
+    total = 0.0
+    for e0 in range(0, 16, 4):
+        share = dataclasses.replace(MOE, held=(e0, 4))
+        ps = dict(p, **{k: p[k][e0:e0 + 4] for k in ("w_g", "w_u", "w_d")})
+        routed, _ = moe_ops.experts_sorted(ps, share, x, idx, gates, valid)
+        ref_routed, ref_zero, _ = ref.moe_parts(ps, x, dm, (e0, 4))
+        close(routed, ref_routed)
+        close(ref_zero, uncut_z)
+        total = total + routed
+    close(total, uncut_r)
+    y, _ = moe_ops.moe(p, whole, x, valid)
+    close(y, uncut_r + uncut_z)
+
+
+def test_double_layer_topology_matches_the_reference(ref):
+    spec = small_spec()
+    p = seeded_params(spec)["blocks"][1]
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((23, 64)),
+                    jnp.float32)
+    pos = jnp.arange(23, dtype=jnp.int32)
+    want, _ = ref.double_layer(p, x, pos, ref_dims(spec), MOE.held)
+
+    def mix(which, mp, h):
+        return mla_ops.attend_full(mp, MLA, h[None], pos[None])[0]
+
+    def moe(mp, h):
+        return moe_ops.moe(mp, MOE, h, jnp.ones(23, bool))[0]
+
+    got = apply_block(spec, spec.blocks[1], p, x, mix, moe=moe)
+    close(got, want)
+
+
+SHAPE = ServeShape(n_slots=3, capacity=64, chunk=16, extend_len=4,
+                   extend_batch=2)
+
+
+def test_chunked_prefill_then_extensions_give_the_reference_scores(ref):
+    """Scores, not ranks: a history prefilled in chunks and grown by
+    extensions through the cache, against the full forward over it."""
+    spec = small_spec()
+    params = seeded_params(spec)
+    programs = StackPrograms(spec, params, SHAPE)
+    weights, dm = as_reference(params), ref_dims(spec)
+    rng = np.random.default_rng(6)
+    hist = rng.integers(0, N_ITEMS, size=45).tolist()
+
+    def scores(h_last):
+        return np.asarray(h_last) @ np.asarray(params["head"]).T
+
+    at = 0
+    for n in (16, 16, 5):                 # 37 positions in three chunks
+        h, counted = programs.prefill(np.array(hist[at:at + n]), 1, at)
+        at += n
+    close(scores(h[0]), ref.forward(weights, hist[:37], dm)[0], 5e-4)
+    assert int(counted["tokens"]) == 5
+    other = rng.integers(0, N_ITEMS, size=9).tolist()
+    programs.prefill(np.array(other[:7]), 0, 0)
+    # two sessions extended in one step
+    h, counted = programs.extend([(hist[37:40], 1, 37), (other[7:9], 0, 7)])
+    close(scores(h[0]), ref.forward(weights, hist[:40], dm)[0], 5e-4)
+    close(scores(h[1]), ref.forward(weights, other, dm)[0], 5e-4)
+    assert int(counted["tokens"]) == 5
+    assert counted["expert_load"].shape == (2, 4)
+    h, _ = programs.extend([(hist[40:44], 1, 40)])
+    close(scores(h[0]), ref.forward(weights, hist[:44], dm)[0], 5e-4)
+
+
+# -- through the engine server ------------------------------------------------
+
+from predictionio_tpu.core.persistent_model import (  # noqa: E402
+    PersistentModel, PersistentModelManifest)
+
+_HANDOVER = {}
+
+
+class HandedOverStack(PersistentModel):
+    """The model reaches the server as a deployment's own loader would hand
+    it over: the stored blob is a manifest, ``load`` builds the model."""
+
+    def save(self, instance_id, params, ctx):
+        return True
+
+    @classmethod
+    def load(cls, instance_id, params, ctx):
+        from predictionio_tpu.data.bimap import BiMap
+        from predictionio_tpu.models.sessionrec import SeqStackModel
+
+        spec, weights = _HANDOVER[instance_id]
+        items = BiMap.from_vocab([f"i{r}" for r in range(N_ITEMS)])
+        return SeqStackModel(spec, weights, items, params.shape())
+
+
+def deploy_small(n_slots=2, capacity=128):
+    import datetime as dt
+    import json
+    import pickle
+    import uuid
+
+    from predictionio_tpu.core.params import EngineParams
+    from predictionio_tpu.data.metadata import EngineInstance, Model
+    from predictionio_tpu.data.storage import Storage
+    from predictionio_tpu.models.sessionrec import SeqStackParams
+    from predictionio_tpu.serving.engine_server import EngineServer
+    from predictionio_tpu.templates.sessionrec import (
+        SeqDataSourceParams, sessionrec_engine)
+
+    spec = small_spec()
+    params = seeded_params(spec)
+    storage = Storage.from_env({
+        "PIO_STORAGE_SOURCES_MEM_TYPE": "memory",
+        **{f"PIO_STORAGE_REPOSITORIES_{r}_{k}": v
+           for r in ("METADATA", "EVENTDATA", "MODELDATA")
+           for k, v in (("NAME", r.lower()), ("SOURCE", "MEM"))}})
+    ep = EngineParams(
+        data_source_params=("", SeqDataSourceParams(app_name="t")),
+        preparator_params=("", None),
+        algorithm_params_list=[("seqstack", SeqStackParams(
+            n_slots=n_slots, capacity=capacity, chunk=16, extend_len=4,
+            extend_batch=2))],
+        serving_params=("", None)).to_json_dict()
+    now = dt.datetime.now(tz=dt.timezone.utc)
+    instance = EngineInstance(
+        id=uuid.uuid4().hex, status="COMPLETED", start_time=now,
+        end_time=now, engine_id="seq_t", engine_version="0",
+        engine_variant="default", engine_factory="t", batch="t",
+        data_source_params=json.dumps(ep["dataSourceParams"]),
+        preparator_params=json.dumps(ep["preparatorParams"]),
+        algorithms_params=json.dumps(ep["algorithmParamsList"]),
+        serving_params=json.dumps(ep["servingParams"]))
+    storage.engine_instances().insert(instance)
+    _HANDOVER[instance.id] = (spec, params)
+    manifest = PersistentModelManifest(
+        class_name="HandedOverStack", module_name=__name__)
+    storage.models().insert(Model(id=instance.id,
+                                  models=pickle.dumps([manifest])))
+    server = EngineServer(sessionrec_engine(), "seq_t", host="127.0.0.1",
+                          port=0, storage=storage,
+                          slo_conf={"latency_ms": 60000.0}).start()
+    return server, spec, params
+
+
+def post(server, items, num=5):
+    import json
+    import urllib.request
+
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{server.port}/queries.json",
+        data=json.dumps({"items": [f"i{r}" for r in items],
+                         "num": num}).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as resp:
+        return json.loads(resp.read())["itemScores"]
+
+
+def test_cached_partly_cached_evicted_and_never_cached_answer_alike(ref):
+    server, spec, params = deploy_small()
+    try:
+        weights, dm = as_reference(params), ref_dims(spec)
+        model = server.deployment.models[0]
+        assert server._batcher.histogram()["stepwise"] is True
+        rng = np.random.default_rng(7)
+        a = rng.integers(0, N_ITEMS, size=30).tolist()
+        b = rng.integers(0, N_ITEMS, size=25).tolist()
+        c = rng.integers(0, N_ITEMS, size=41).tolist()
+
+        def check(hist, got):
+            logits = ref.forward(weights, hist, dm)[0]
+            want = ref.top_k_answer(logits, 5)
+            assert [int(s["item"][1:]) for s in got] == [i for i, _ in want]
+            close([s["score"] for s in got], [v for _, v in want], 1e-3)
+
+        # no known item: answered at admission, in the algorithm's format
+        assert post(server, [10 ** 6]) == []
+        check(a, post(server, a))                     # never cached
+        assert (model.cache.hit_tokens, model.cache.miss_tokens) == (0, 30)
+        check(a, post(server, a))                     # wholly cached
+        assert model.cache.hit_tokens == 29
+        grown = a + [3, 4]
+        check(grown, post(server, grown))             # an extension
+        assert model.cache.hit_tokens == 29 + 30
+        forked = a[:20] + [7, 8, 9, 10, 11, 12]
+        check(forked, post(server, forked))           # partly cached
+        assert model.cache.hit_tokens == 29 + 30 + 20
+        check(b, post(server, b))
+        check(c, post(server, c))                     # two slots: a's is gone
+        assert model.cache.evictions >= 1
+        hits = model.cache.hit_tokens
+        check(grown, post(server, grown))             # prefilled again
+        assert model.cache.hit_tokens == hits
+    finally:
+        server.stop()
+
+
+def test_an_extension_is_served_within_one_step_of_a_long_prefill():
+    server, spec, params = deploy_small(n_slots=3)
+    try:
+        model = server.deployment.models[0]
+        rng = np.random.default_rng(8)
+        short = rng.integers(0, N_ITEMS, size=10).tolist()
+        post(server, short)                           # its slot is warm
+        chunks0 = model.counters["prefill_runs"]
+        long = rng.integers(0, N_ITEMS, size=80).tolist()   # 5 chunks of 16
+        t_long = model.begin({"items": [f"i{r}" for r in long], "num": 5})
+        model.step([t_long])                          # chunk 1 of 5
+        t_ext = model.begin({"items": [f"i{r}" for r in short + [1, 2]],
+                             "num": 5})
+        assert t_ext.extension and t_ext.remaining == 2
+        done = model.step([t_long, t_ext])            # its first step
+        assert done == [t_ext] and len(t_ext.result) == 5
+        assert t_long.done == 32 and t_long.result is None
+        assert model.counters["prefill_runs"] - chunks0 == 2
+        assert model.counters["extensions_waited"] == 0
+        while t_long.result is None:
+            model.step([t_long])
+        assert model.counters["prefill_runs"] - chunks0 == 5
+    finally:
+        server.stop()
+
+
+def test_a_reload_onto_the_other_kind_of_worker_is_refused(monkeypatch):
+    """The worker's kind is chosen at start; a reload whose deployment
+    needs the other kind must not fall back to it silently."""
+    from predictionio_tpu.models.sessionrec import (
+        SessionRecAlgorithm, SessionRecParams)
+    from predictionio_tpu.serving import engine_server
+
+    server, _, _ = deploy_small()
+    try:
+        live = server.deployment
+        assert live.stepwise
+        other = dataclasses.replace(
+            live, algorithms=[SessionRecAlgorithm(SessionRecParams())])
+        assert not other.stepwise
+        monkeypatch.setattr(engine_server, "prepare_deploy",
+                            lambda *a, **k: other)
+        with pytest.raises(RuntimeError, match="reload refused"):
+            server.reload()
+        assert server.deployment is live
+        assert len(post(server, [1, 2, 3])) == 5
+    finally:
+        server.stop()

@@ -90,9 +90,14 @@ def _embed_update(N, B, E):
     (_topk_dot, (26_744, 64, 32)),
     (_topk_dot, (1_000_000, 128, 1)),
     (_embed_update, (1_000_000, 8192, 128)),
+    # the sequence model's head: 16,384 item rows of width 6144, the tile
+    # rule at its floor of one 128-lane row of items (a 3 MB tile)
+    (_topk_dot, (16_384, 6144, 1, 16, 1)),
+    (_topk_dot, (16_384, 6144, 8, 16, 1)),
 ], ids=["flash_ce-8192x128", "flash_ce-4096x64", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
-        "embed_update-1M-8192x128"])
+        "embed_update-1M-8192x128", "topk_dot-16384x6144-B1",
+        "topk_dot-16384x6144-B8"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
     text = _compiled_text(fn, shapes, one_chip)
