@@ -4,12 +4,17 @@ The hot path is ``ops/pallas/topk_dot.py`` — the item table kept on the
 device ONCE, in the ``[D, Ip]`` layout the kernel reads (items on the
 lanes), streamed through VMEM in tiles of thousands of items; a tile
 is merged into the running [B, k] top-k only if it can change it, and
-the full [B, I] logits matrix never exists in HBM. The XLA brute-force
-scorer (``ops.topk.TopKScorer``) remains the numerical reference and
-the path for shapes the kernel is not eligible for (and for the CPU
-backend). An engaged kernel that the chip's compiler refuses raises
-from ``search`` — the ``ops/pallas`` design contract, applied to
-serving instead of training.
+the full [B, I] logits matrix never exists in HBM. Every search a
+factor model makes comes here, a lone query and a micro-batch alike
+(``models/als.py ALSModel.retrieve``), and on a TPU every one inside the
+kernel's caps (``_kernel_eligible``: 128 rows, k 128, 64 exclusions)
+is the kernel's. The XLA brute-force scorer (``ops.topk.TopKScorer``)
+remains the numerical reference and this index's own fallback: the CPU
+backend, and the shapes beyond the caps. An engaged kernel that the
+chip's compiler refuses raises from ``search`` — the ``ops/pallas``
+design contract, applied to serving instead of training. The route
+each search took is counted (``routes``) and written into the trace
+(``pio:index.route``, a marker inside ``pio:index.search``).
 
 Kernel selection mirrors ``flash_ce_kernel`` exactly: a per-index
 ``kernel`` flag ("auto"/"on"/"off", wired from the model params'
@@ -227,6 +232,11 @@ class ExactIndex(AnnIndex):
                 return self._fallback().score_unspanned(
                     query_vecs, k, exclude)
             self.routes["kernel"] += 1
+            # a span's attributes are set as it opens, and the route is
+            # known only once the inputs are bucketed: it rides on a
+            # marker (the fallback writes its own, ops/topk.py)
+            with trace.device_span("index.route", route="kernel", rows=B):
+                pass
             with trace.device_span("index.fetch"):
                 return (np.asarray(scores)[:B, :k_eff],
                         np.asarray(idx)[:B, :k_eff])

@@ -16,7 +16,7 @@ deployed engine (`POST /queries.json {"user": "1", "num": 4}` ->
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -101,8 +101,20 @@ class ALSParams(Params):
     index_kernel: str = "auto"
 
 
+class QueryPlan(NamedTuple):
+    """One query against the item table, lone or a row of a batch."""
+
+    vec: np.ndarray          # [D] query vector
+    exclude: List[int]       # item rows it excludes, in the order given
+    skip: Optional[int]      # the row the answer must not repeat
+
+
 class ALSModel:
-    """Factor matrices + id maps; scorer compiled lazily and kept on device."""
+    """Factor matrices + id maps, and ONE retriever over the item table
+    (:meth:`retrieve`): the retrieval index with its one device copy,
+    for a lone query and for a batch alike; on a mesh
+    (:meth:`enable_sharded_serving`) the row-sharded scorer in its
+    place."""
 
     #: ledger attribution label (obs/memacct.py); TwoTowerModel
     #: overrides — the same per-model key perfacct's MFU gauges use
@@ -114,7 +126,9 @@ class ALSModel:
         self.item_factors = factors.item_factors
         self.user_ids = user_ids
         self.item_ids = item_ids
-        self._scorer: Optional[TopKScorer] = None
+        # the retriever's mesh form (enable_sharded_serving); an
+        # unsharded model never builds a scorer of its own
+        self._scorer = None
         # retrieval index (predictionio_tpu/index): built lazily /
         # by deploy warm-up, patched in place by the streaming lane
         self._index = None
@@ -158,10 +172,25 @@ class ALSModel:
             self, self.memacct_model, "id_maps",
             (len(self.user_ids) + len(self.item_ids)) * 24)
 
-    def scorer(self) -> TopKScorer:
-        if self._scorer is None:
-            self._scorer = TopKScorer(self.item_factors)
+    def scorer(self):
+        """The retriever's mesh form (``ShardedTopKScorer``), or None:
+        an unsharded model retrieves through :meth:`retrieval_index`
+        alone and keeps no second device copy of the item table."""
         return self._scorer
+
+    def retrieve(self, vecs: np.ndarray, num: int,
+                 exclude: Optional[np.ndarray] = None,
+                 ) -> Tuple[np.ndarray, np.ndarray]:
+        """(scores [B, k], item rows [B, k]) of the best ``num`` items
+        by dot product for each row of ``vecs`` ([D] or [B, D]);
+        ``exclude`` is [E] or [B, E] item rows, -1 padded. Every
+        retrieval of the model, lone or batched, goes through here. The
+        index picks its path from the shape and the backend
+        (``ExactIndex._kernel_eligible``); a sharded catalogue keeps the
+        mesh scorer (no single-device index over it)."""
+        if self._scorer is not None:
+            return self._scorer.score(vecs, num, exclude)
+        return self.retrieval_index().search(vecs, num, exclude)
 
     def retrieval_index(self):
         """The model's ANN candidate-generation index over the item
@@ -190,7 +219,7 @@ class ALSModel:
         """Swap in a ShardedTopKScorer: item factors row-sharded over
         ``mesh[axis]``, per-shard top-k merged over ICI — serving for
         catalogs larger than one chip's HBM (ops.topk.make_sharded_topk).
-        Same results as the single-device scorer."""
+        Same results as the single-device index."""
         from predictionio_tpu.ops.topk import ShardedTopKScorer
 
         self._scorer = ShardedTopKScorer(self.item_factors, mesh, axis=axis)
@@ -205,16 +234,17 @@ class ALSModel:
         named factor rows. COPY-ON-WRITE — new arrays are built and the
         attribute references swapped last, so a concurrent ``predict``
         reading ``self.user_factors`` once sees either the old or the
-        new table, never a torn row. Any item change invalidates the
-        cached scorer (it holds a device copy of the item table); a
-        same-shape re-put hits the compile cache, only NEW items change
-        shapes. Returns (n_new_users, n_new_items)."""
+        new table, never a torn row. Item rows land in the built
+        retrieval index as an in-place upsert (it drops its device copy
+        of the old table; a same-shape re-put hits the compile cache,
+        only NEW items change shapes). Returns (n_new_users,
+        n_new_items)."""
         rank = self.user_factors.shape[1] if self.user_factors.size else (
             self.item_factors.shape[1])
         if item_rows and self.sharded_axis is not None:
             # the sharded scorer's row placement can't be patched from
             # here (no mesh at hand) — silently downgrading to the
-            # single-device scorer would change serving capacity; the
+            # single-device index would change serving capacity; the
             # rolling /reload lane is the supported swap for these
             raise ValueError(
                 "item-row patches are not supported on a sharded-serving "
@@ -262,8 +292,6 @@ class ALSModel:
                 factors[ids[iid]] = vec
             self.item_factors = factors  # graftlint: disable=JT18 — copy-on-write commit: store is atomic, readers take one local ref (old-or-new, never torn)
             self.item_ids = ids  # graftlint: disable=JT18 — paired with the factors swap; same ordering rule
-            # the scorer holds a DEVICE copy of the old item table
-            self._scorer = None
             # the retrieval index takes the SAME rows as an in-place
             # upsert (no rebuild): streamed items become retrievable
             # without a /reload
@@ -277,6 +305,70 @@ class ALSModel:
             self._register_memory()
         return new_users, new_items
 
+    # -- queries ---------------------------------------------------------------
+    # ``user_plan`` / ``item_plan`` turn a query into a QueryPlan,
+    # ``answer`` runs any number of plans as ONE retrieval — so a query
+    # sent alone and the same query inside a batch are the same code
+    # on the same inputs.
+
+    def _exclusion_rows(self, exclude_items: Sequence[str]) -> List[int]:
+        """Rows of the known ids of a blacklist, each once, in the
+        order given: the retrievers cap an exclusion list keeping its
+        NEWEST (rightmost) entries."""
+        ids = self.item_ids
+        return list(dict.fromkeys(
+            ids[i] for i in exclude_items if i in ids))
+
+    def user_plan(self, user_id: str, exclude_items: Sequence[str] = ()
+                  ) -> Optional[QueryPlan]:
+        """user -> top items: the user's factor, the blacklist's rows.
+        None for an unknown user."""
+        row = self.user_ids.get(user_id)
+        if row is None:
+            return None
+        return QueryPlan(self.user_factors[row],
+                         self._exclusion_rows(exclude_items), None)
+
+    def item_plan(self, item_id: str, exclude_items: Sequence[str] = ()
+                  ) -> Optional[QueryPlan]:
+        """item -> similar items: the item's factor against the item
+        table, itself excluded. None for an unknown item. The
+        self-exclusion goes LAST: an oversize blacklist may then drop
+        its own oldest entries but never the query item — and
+        ``answer``'s filter backstops even that."""
+        row = self.item_ids.get(item_id)
+        if row is None:
+            return None
+        excl = [r for r in self._exclusion_rows(exclude_items) if r != row]
+        return QueryPlan(self.item_factors[row], excl + [row], row)
+
+    def answer(self, plans: Sequence[QueryPlan], nums: Sequence[int]
+               ) -> List[List[Tuple[str, float]]]:
+        """[(item id, score), ...] best first for each plan, ``nums[b]``
+        of them at most: one retrieval of ``max(nums)`` for all."""
+        if not plans:
+            return []
+        with trace.device_span("engine.prepare"):
+            vecs = np.stack([p.vec for p in plans])
+            width = max(len(p.exclude) for p in plans)
+            excl = None
+            if width:
+                # right-aligned, -1 padded on the LEFT: a retriever that
+                # caps the width keeps the rightmost columns, which must
+                # be every row's own newest entries
+                excl = np.full((len(plans), width), -1, np.int32)
+                for b, p in enumerate(plans):
+                    if p.exclude:
+                        excl[b, width - len(p.exclude):] = p.exclude
+        scores, idx = self.retrieve(vecs, max(nums), excl)
+        with trace.device_span("engine.decode"):
+            inv = self.item_ids.inverse()
+            return [
+                [(inv[int(i)], float(s))
+                 for s, i in zip(s_row[:n], i_row[:n])
+                 if s > -1e29 and int(i) >= 0 and int(i) != p.skip]
+                for p, n, s_row, i_row in zip(plans, nums, scores, idx)]
+
     def recommend(
         self,
         user_id: str,
@@ -285,44 +377,29 @@ class ALSModel:
         candidate_items: Optional[Sequence[str]] = None,
     ) -> List[Tuple[str, float]]:
         with trace.device_span("engine.prepare"):
-            row = self.user_ids.get(user_id)
-            if row is None:
-                return []
-            exclude = {self.item_ids[i] for i in exclude_items
-                       if i in self.item_ids}
+            plan = self.user_plan(user_id, exclude_items)
+        if plan is None:
+            return []
         if candidate_items is not None:
+            # a whitelist is scored on the host against its candidates
+            # alone: the one query shape with no batched form
             cand = np.array(
                 sorted(
                     {self.item_ids[i] for i in candidate_items if i in self.item_ids}
-                    - exclude
+                    - set(plan.exclude)
                 ),
                 dtype=np.int64,
             )
             if len(cand) == 0:
                 return []
-            scores = self.item_factors[cand] @ self.user_factors[row]
+            scores = self.item_factors[cand] @ plan.vec
             # partial sort: the whitelist can be the whole catalog
             # (JT14 — argsort(...)[:k] full-sorts it per query)
             top_s, top_j = TopKScorer._host_topk(scores[None, :], num)
             inv = self.item_ids.inverse()
             return [(inv[int(cand[j])], float(s))
                     for s, j in zip(top_s[0], top_j[0])]
-        excl = np.fromiter(exclude, dtype=np.int32) if exclude else None
-        if self.sharded_axis is not None:
-            # sharded serving keeps the mesh scorer (a model-axis
-            # sharded index is the ROADMAP item A follow-up)
-            scores, idx = self.scorer().score(
-                self.user_factors[row], num, excl)
-        else:
-            scores, idx = self.retrieval_index().search(
-                self.user_factors[row], num, excl)
-        with trace.device_span("engine.decode"):
-            inv = self.item_ids.inverse()
-            return [
-                (inv[int(i)], float(s))
-                for s, i in zip(scores[0], idx[0])
-                if s > -1e29 and int(i) >= 0
-            ]
+        return self.answer([plan], [num])[0]
 
     def similar_items(
         self,
@@ -330,40 +407,14 @@ class ALSModel:
         num: int,
         exclude_items: Sequence[str] = (),
     ) -> List[Tuple[str, float]]:
-        """item -> top-``num`` similar items through the retrieval
-        index: top-k by dot product of the item's factor against the
-        item table, the query item excluded. Cosine similarity when the
-        table is row-normalized (two-tower towers are; raw ALS factors
-        score dot-similarity, popularity-weighted)."""
+        """item -> top-``num`` similar items: top-k by dot product of
+        the item's factor against the item table, the query item
+        excluded. Cosine similarity when the table is row-normalized
+        (two-tower towers are; raw ALS factors score dot-similarity,
+        popularity-weighted)."""
         with trace.device_span("engine.prepare"):
-            row = self.item_ids.get(item_id)
-            if row is None:
-                return []
-            exclude = {self.item_ids[i] for i in exclude_items
-                       if i in self.item_ids} - {row}
-            # self-exclusion goes LAST: the exact backend caps exclusion
-            # lists at max_exclude keeping the NEWEST (rightmost)
-            # entries, so an oversize blacklist may drop itself but
-            # never the query item — and the result filter below
-            # backstops even that
-            excl = np.fromiter(
-                list(exclude) + [row], dtype=np.int32,
-                count=len(exclude) + 1)
-        if self.sharded_axis is not None:
-            # sharded serving keeps the mesh scorer (same stance as
-            # recommend: no single-device index over a sharded catalog)
-            scores, idx = self.scorer().score(
-                self.item_factors[row], num, excl)
-        else:
-            scores, idx = self.retrieval_index().search(
-                self.item_factors[row], num, excl)
-        with trace.device_span("engine.decode"):
-            inv = self.item_ids.inverse()
-            return [
-                (inv[int(i)], float(s))
-                for s, i in zip(scores[0], idx[0])
-                if s > -1e29 and int(i) >= 0 and int(i) != row
-            ]
+            plan = self.item_plan(item_id, exclude_items)
+        return [] if plan is None else self.answer([plan], [num])[0]
 
 
 def apply_rows_patch(model: ALSModel, patch: dict) -> bool:
@@ -371,7 +422,7 @@ def apply_rows_patch(model: ALSModel, patch: dict) -> bool:
     shares (ALS and two-tower models both serve from ALSModel factor
     tables): ``patch`` carries ``userRows`` / ``itemRows`` as
     ``[[id, [floats...]], ...]`` and lands via
-    :meth:`ALSModel.upsert_rows` (copy-on-write, scorer invalidation).
+    :meth:`ALSModel.upsert_rows` (copy-on-write, index upsert).
     Malformed rows raise ValueError — the engine server maps that to
     400 with nothing partially applied for the failing side."""
 
@@ -618,19 +669,22 @@ class ALSAlgorithm(Algorithm):
     def warmup(self, model: ALSModel, ctx: MeshContext) -> None:
         """Pre-warm the serve path so the first queries after
         deploy/reload answer at steady-state latency (SURVEY.md §7.5
-        hard part #2): k buckets 8 and 16 at B=1, then the BATCH-size
-        buckets the micro-batched server dispatches under load (8/32)
-        — covering first-touch costs on both scorer routes (XLA
-        compiles on the device route, BLAS/thread-pool init on the
-        host route) before live traffic pays them."""
+        hard part #2). The retrieval index is BUILT here, at model load
+        (``pio_index_build_seconds`` prices it, never a live query),
+        and the model's one retriever is run at every (B, k) bucket the
+        server can dispatch: B buckets 1...64 (the default micro-batch
+        cap; a lone query is B = 1), k buckets 8 and 16. An item ->
+        similar query and a user query without a blacklist are the same
+        (B, E = 1, k) shape. On the chip each bucket is one ``topk_dot``
+        compile that would otherwise block a LIVE batch; on the CPU
+        backend the index's XLA fallback takes them, as a lone search
+        does. Deploy/reload warm BEFORE the swap, so this cost never
+        blocks traffic. A sharded model warms its mesh scorer at the
+        same buckets and builds no index: that would device-put the
+        FULL item table onto one chip, the exact thing the sharded
+        catalogue can't hold."""
         if len(model.user_ids) == 0 or len(model.item_ids) == 0:
             return
-        # every (B, k) bucket the server can dispatch (B buckets up to
-        # the default micro-batch cap of 64, k buckets 8 and 16): on
-        # the device route each distinct bucket is an XLA compile that
-        # would otherwise block a LIVE batch (code-review regression);
-        # on the host route these are millisecond no-ops. Deploy/reload
-        # warm BEFORE the swap, so this cost never blocks traffic.
         for b in (1, 2, 4, 8, 16, 32, 64):
             # batch size is bounded by CONCURRENT QUERIES (max_batch),
             # not distinct users — duplicate-user queries coalesce into
@@ -638,24 +692,7 @@ class ALSAlgorithm(Algorithm):
             # warm (tile rows instead of capping at the user count)
             rows = model.user_factors[np.arange(b) % len(model.user_ids)]
             for k in (5, 10):
-                model.scorer().score(rows, k)
-        if model.sharded_axis is not None:
-            # sharded serving never consults the single-device index —
-            # building one would device-put the FULL item table onto
-            # one chip, the exact thing the sharded catalog can't hold
-            return
-        # retrieval index: BUILD at model load (pio_index_build_seconds
-        # prices it here, never on a live query) and warm the search
-        # buckets both retrieval query shapes dispatch — user -> top-k
-        # (no exclusions) and item -> similar (one self-exclusion)
-        index = model.retrieval_index()
-        for b in (1, 8):
-            rows = model.user_factors[np.arange(b) % len(model.user_ids)]
-            for k in (5, 10):
-                index.search(rows, k)
-        index.search(model.item_factors[:1],
-                     min(10, len(model.item_ids)),
-                     exclude=np.array([[0]], np.int32))
+                model.retrieve(rows, k)
 
     def predict(self, model: ALSModel, query: Dict[str, Any]) -> Dict[str, Any]:
         num = int(query.get("num", 10))
@@ -663,52 +700,49 @@ class ALSAlgorithm(Algorithm):
             # item -> top-num similar items: candidate generation
             # through the retrieval index (the similarproduct-style
             # query surface on the factor templates)
-            sims = model.similar_items(
+            ranked = model.similar_items(
                 str(query["item"]), num,
                 exclude_items=query.get("blacklist") or ())
-            return {"itemScores": [{"item": i, "score": s}
-                                   for i, s in sims]}
-        recs = model.recommend(
-            str(query["user"]),
-            num,
-            exclude_items=query.get("blacklist") or (),
-            candidate_items=query.get("whitelist"),
-        )
-        return {"itemScores": [{"item": i, "score": s} for i, s in recs]}
+        else:
+            ranked = model.recommend(
+                str(query["user"]),
+                num,
+                exclude_items=query.get("blacklist") or (),
+                candidate_items=query.get("whitelist"),
+            )
+        return _item_scores(ranked)
 
     def batch_predict(self, model: ALSModel, queries):
-        """Vector-scored evaluation path (ref: batchPredict for eval).
-
-        Queries for known users are scored as one batched matmul+top-k;
-        unknown users fall back to empty results.
-        """
+        """Many queries as ONE retrieval (micro-batched serving, ref:
+        batchPredict for eval): user and item queries, each with its
+        own ``num`` and ``blacklist``, become the rows of one search of
+        the model's retriever (``ALSModel.answer``), so a query gets
+        the answer it would get alone. Unknown users and items get
+        empty results; a ``whitelist`` query has no batched form and is
+        answered through ``predict``."""
+        out, batched, alone = [], [], []
         with trace.device_span("engine.prepare"):
-            known = [(i, q) for i, q in queries
-                     if str(q["user"]) in model.user_ids]
-            unknown = [(i, q) for i, q in queries
-                       if str(q["user"]) not in model.user_ids]
-            out = [(i, {"itemScores": []}) for i, q in unknown]
-            if known:
-                rows = np.array(
-                    [model.user_ids[str(q["user"])] for _, q in known],
-                    dtype=np.int64)
-                num = max(int(q.get("num", 10)) for _, q in known)
-                vecs = model.user_factors[rows]
-        if known:
-            scores, idx = model.scorer().score(vecs, num)
-            with trace.device_span("engine.decode"):
-                inv = model.item_ids.inverse()
-                for (qi, q), s_row, i_row in zip(known, scores, idx):
-                    n = int(q.get("num", 10))
-                    out.append(
-                        (
-                            qi,
-                            {
-                                "itemScores": [
-                                    {"item": inv[int(i)], "score": float(s)}
-                                    for s, i in zip(s_row[:n], i_row[:n])
-                                ]
-                            },
-                        )
-                    )
+            for i, q in queries:
+                if q.get("whitelist") is not None:
+                    alone.append((i, q))
+                    continue
+                blacklist = q.get("blacklist") or ()
+                if "user" not in q and "item" in q:
+                    plan = model.item_plan(str(q["item"]), blacklist)
+                else:
+                    plan = model.user_plan(str(q["user"]), blacklist)
+                if plan is None:
+                    out.append((i, _item_scores(())))
+                else:
+                    batched.append((i, plan, int(q.get("num", 10))))
+        ranked = model.answer([plan for _, plan, _ in batched],
+                              [num for _, _, num in batched])
+        out.extend((i, _item_scores(r))
+                   for (i, _, _), r in zip(batched, ranked))
+        out.extend((i, self.predict(model, q)) for i, q in alone)
         return out
+
+
+def _item_scores(ranked) -> Dict[str, Any]:
+    """The recommendation templates' result shape."""
+    return {"itemScores": [{"item": i, "score": s} for i, s in ranked]}

@@ -56,10 +56,10 @@ class TwoTowerParams(Params):
 
 
 class TwoTowerModel(ALSModel):
-    """Same container as ALSModel: (user_vecs, item_vecs, id maps) +
-    TopKScorer serve path + the shared retrieval index; vectors here
-    are L2-normalized so scores — including the index's item -> similar
-    answers — are cosine similarities."""
+    """Same container as ALSModel: (user_vecs, item_vecs, id maps) and
+    its one retriever over the retrieval index; vectors here are
+    L2-normalized so scores — including the item -> similar answers —
+    are cosine similarities."""
 
     #: device-memory ledger attribution (obs/memacct.py)
     memacct_model = "twotower"
@@ -116,9 +116,9 @@ class TwoTowerAlgorithm(Algorithm):
         model.train_losses = emb.losses
         return model
 
-    # identical model/query surface -> share ALS's serve and batched
-    # (matmul + top-k) evaluation paths, its deploy-time warmup, and
-    # the streaming model-patch lane (same factor-table container)
+    # identical model/query surface -> share ALS's lone and batched
+    # serve paths (one retrieval each), its deploy-time warmup, and the
+    # streaming model-patch lane (same factor-table container)
     predict = ALSAlgorithm.predict
     batch_predict = ALSAlgorithm.batch_predict
     warmup = ALSAlgorithm.warmup

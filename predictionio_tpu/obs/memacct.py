@@ -63,8 +63,9 @@ Env knobs:
                            (default 0.05)
   PIO_MEM_PREFLIGHT        0 disables the deploy preflight (default on)
   PIO_MEM_ESTIMATE_SCALE   blob-bytes -> resident-bytes factor for the
-                           preflight estimate (default 2.0: host table
-                           + device scorer/index copies)
+                           preflight estimate (default 2.0: the host
+                           tables + the retrieval index's one device
+                           copy of the item side, rounded up)
 
 jax is only consulted lazily — and the snapshot-cadence refresh only
 touches it when some other subsystem already imported it, so a pure
@@ -133,8 +134,9 @@ def preflight_enabled() -> bool:
 
 def estimate_scale() -> float:
     """Stored-blob bytes -> resident bytes: the pickled factor tables
-    land on host ~1:1, and serving adds device copies (scorer + index)
-    of the item side (``PIO_MEM_ESTIMATE_SCALE``)."""
+    land on host ~1:1, and serving adds the retrieval index's device
+    copy of the item side (a factor model keeps ONE; the default of 2.0
+    is that bound rounded up — ``PIO_MEM_ESTIMATE_SCALE``)."""
     return max(1.0, metrics.env_float("PIO_MEM_ESTIMATE_SCALE", 2.0))
 
 
@@ -265,7 +267,7 @@ LEDGER = MemLedger()
 def release_model(model: Any) -> int:
     """Retire a served model AND the satellite objects it owns that
     registered under their own identity (the built retrieval index,
-    the cached scorer) — the ``/reload`` hot-swap, replica-stop and
+    a template's cached scorer) — the ``/reload`` hot-swap, replica-stop and
     stream-rebind seams call this so every component's gauge drops
     with the swap; the weakref sweep remains the backstop."""
     released = LEDGER.release(model)

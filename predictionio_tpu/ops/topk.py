@@ -1,28 +1,31 @@
-"""Top-k scoring for serving, with latency-aware placement.
+"""Top-k scoring by brute force: the XLA scorer and its mesh form.
 
-Serve-path design (SURVEY.md §7.5): model factors stay resident on the
-device; a query is one embedding-row lookup plus a [1, K] x [K, I]
-matmul and a fixed-shape ``lax.top_k`` — no per-request host<->device
-round trips beyond the scalar inputs/outputs. The reference's analogue
-is ALSModel.recommendProducts' driver-side dot-product scan
-(MLlib MatrixFactorizationModel, used by
-examples/scala-parallel-recommendation templates).
+One query is an embedding row, a [B, K] x [K, I] product and a
+fixed-shape ``lax.top_k`` over the whole catalogue (SURVEY.md §7.5; the
+reference's analogue is ALSModel.recommendProducts' driver-side
+dot-product scan, MLlib MatrixFactorizationModel). What stands here:
 
-Placement policy: a single-user query against a modest catalog is a
-few-MFLOP matvec — microseconds of compute — so its latency is pure
-dispatch overhead, and for a small enough catalog the HOST path (numpy
-matvec + partial sort, exactly the reference's driver-side scan) beats
-one device dispatch. ``TopKScorer`` measures the backend's per-dispatch
-latency once per process and routes EACH call by modeled cost (batch x
-catalog FLOPs vs dispatch floor): big batches and big catalogs go to
-the MXU, tiny lone queries go wherever they're actually fastest. The
-route each call took is counted (``routed``) and shown in the serving
-status, so a 200 never hides which side answered. Override with
-PIO_SERVE_PLACEMENT=device|host|auto. Catalogs beyond one chip's HBM
-use the sharded scorer (make_sharded_topk), device-only by nature.
+* ``TopKScorer``: the numerical reference of the retrieval subsystem
+  (``tests/test_index.py`` pins ``index/exact.py``'s kernel to it) and
+  that index's own fallback for what its kernel does not take: the CPU
+  backend (tier-1, ``pio eval`` on a host) and shapes beyond the
+  kernel's caps. The factor models (``models/als.py``) hold none of
+  their own: their every retrieval, lone or batched, is the index's.
+  ``score_masked`` serves the templates whose candidates are a
+  business-rule mask (``similarproduct``, ``ecommerce``).
+* ``ShardedTopKScorer`` / ``make_sharded_topk``: the item table
+  row-sharded over a mesh axis, for catalogues beyond one chip's HBM;
+  ``ALSModel.enable_sharded_serving`` puts it in the index's place.
 
-Batched variants score many users at once (evaluation batchPredict and
-micro-batched serving).
+A ``TopKScorer`` call answers on the device or on the host (numpy
+matvec + partial sort, the reference's scan): with a small catalogue a
+lone query's product is microseconds of compute and a device dispatch
+is all overhead, so each call is routed by a cost model (batch x
+catalogue FLOPs against the backend's measured dispatch round trip;
+``PIO_SERVE_PLACEMENT=device|host|auto`` overrides). The route is
+counted (``routed``, the index's ``routes``), written into the trace
+(``pio:index.route``) and shown in the serving status, so a 200 never
+hides which side answered.
 """
 
 from __future__ import annotations
@@ -260,7 +263,13 @@ class TopKScorer:
         open itself (``index/exact.py`` when its kernel is not
         eligible)."""
         B_in = np.atleast_2d(np.asarray(user_vecs)).shape[0]
-        if self._route(B_in) == "host":
+        route = self._route(B_in)
+        # the same marker ``index/exact.py`` writes for its kernel
+        with trace.device_span(
+                "index.route", rows=B_in,
+                route="host" if route == "host" else "xla_device"):
+            pass
+        if route == "host":
             return self._score_host(user_vecs, k, exclude_idx)
         # enqueue: pad, transfer, the jitted call returning; fetch: the
         # wait for the device and the copy back

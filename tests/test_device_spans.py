@@ -34,6 +34,7 @@ PARENTS = {
     "pio:index.search": "pio:batch.dispatch",
     "pio:index.enqueue": "pio:index.search",
     "pio:index.fetch": "pio:index.search",
+    "pio:index.route": "pio:index.search",
     "pio:engine.decode": "pio:batch.dispatch",
     "pio:train.epoch": None,
     "pio:train.report": None,
@@ -189,6 +190,12 @@ def test_every_serving_boundary_is_a_span_with_its_parent(
     assert max(sizes) > 1 and sum(sizes) == 5
     seqs = [s[4]["seq"] for s in spans if s[0] == "pio:batch.dispatch"]
     assert len(set(seqs)) == len(seqs)
+    # every search says which side answered it and how many rows it held:
+    # one marker a dispatch, the lone query's and the batches' alike
+    routes = [s[4] for s in spans if s[0] == "pio:index.route"]
+    assert len(routes) == len(sizes)
+    assert {r["route"] for r in routes} == {"xla_device"}
+    assert sorted(r["rows"] for r in routes) == sorted(sizes)
     # one request, one identifier: on the handler thread and, for a query
     # that was dispatched alone, on the worker's dispatch and below it
     mine = [s for s in spans if s[4].get("trace") == lone_id]

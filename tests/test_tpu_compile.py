@@ -94,10 +94,15 @@ def _embed_update(N, B, E):
     # rule at its floor of one 128-lane row of items (a 3 MB tile)
     (_topk_dot, (16_384, 6144, 1, 16, 1)),
     (_topk_dot, (16_384, 6144, 8, 16, 1)),
+    # the batched serve path's buckets at the ALS cell's catalogue
+    (_topk_dot, (9_400_000, 64, 16, 16, 1)),
+    (_topk_dot, (9_400_000, 64, 32, 16, 1)),
+    (_topk_dot, (9_400_000, 64, 64, 16, 1)),
 ], ids=["flash_ce-8192x128", "flash_ce-4096x64", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
         "embed_update-1M-8192x128", "topk_dot-16384x6144-B1",
-        "topk_dot-16384x6144-B8"])
+        "topk_dot-16384x6144-B8", "topk_dot-9400000x64-B16",
+        "topk_dot-9400000x64-B32", "topk_dot-9400000x64-B64"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
     text = _compiled_text(fn, shapes, one_chip)
