@@ -45,15 +45,15 @@ import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 PIO = os.path.join(ROOT, "bin", "pio")
-sys.path.insert(0, ROOT)    # bench.py's generator, the fleet's chip_env
+sys.path.insert(0, ROOT)    # the fleet's chip_env
 
-#: the recommendation engine at full width (bench.py DEFAULT_KNOBS: the
-#: ML-20M shape, rank 64, 5 iterations). 20M ratings: a cold run of the
+#: the recommendation engine at full width (the ML-20M shape, rank 64,
+#: 5 iterations). 20M ratings: a cold run of the
 #: whole script then took 253 s on the chip (PR 22), under a third of
 #: the 1200 s limit
 FULL = {"users": 138_493, "items": 26_744, "ratings": 20_000_000,
         "rank": 64, "iters": 5,
-        # two-tower stretch configuration (bench.py stage_twotower)
+        # two-tower stretch configuration
         "tt_ids": 1_000_000, "tt_pos": 4_000_000, "tt_dim": 128,
         "tt_batch": 8192}
 #: test-only size (--tiny): control flow on the CPU in seconds
@@ -62,7 +62,11 @@ TINY = {"users": 300, "items": 120, "ratings": 12_000, "rank": 8, "iters": 2,
 
 LIMIT_SEC = 1150                # the whole run, failing ones included
 EVENTS_OVER_HTTP = 300          # the rest goes through `pio import`
-HOLD_EVERY = 20                 # 5% held out, as bench.py splits it
+HOLD_EVERY = 20                 # every 20th rating is held out (5%)
+#: the band for held-out RMSE of ALS over `synthesize` at FULL: set
+#: around the 0.427 that CPU runs of this generator read before the
+#: chip, it catches a solve that got worse yet still beats the mean
+RMSE_BAND = (0.38, 0.48)
 
 
 class PhaseFailed(Exception):
@@ -255,10 +259,21 @@ def write_parquet(path: str, users, items, ratings, event: str) -> None:
         else col for name, col in cols.items()}), path)
 
 
-def als_data(run: Run):
-    """bench.py's generator at the run's seed, split 95/5."""
-    from bench import synthesize
+def synthesize(n_users, n_items, n_ratings, rng):
+    """Ratings with planted rank-8 structure: clip(3 + 1.2z + noise),
+    in half stars, over uniform users and Zipf-popular items."""
+    uu = rng.integers(0, n_users, size=n_ratings, dtype=np.int64)
+    ii = (rng.zipf(1.2, size=n_ratings) % n_items).astype(np.int64)
+    U = rng.normal(size=(n_users, 8)).astype(np.float32)
+    V = rng.normal(size=(n_items, 8)).astype(np.float32)
+    z = np.einsum("nk,nk->n", U[uu], V[ii]) / np.sqrt(8.0)
+    raw = 3.0 + 1.2 * z + rng.normal(0, 0.35, size=n_ratings).astype(np.float32)
+    vals = np.clip(np.round(raw * 2.0) / 2.0, 0.5, 5.0).astype(np.float64)
+    return uu, ii, vals
 
+
+def als_data(run: Run):
+    """`synthesize` at the run's seed, split 95/5."""
     s = run.size
     uu, ii, vals = synthesize(s["users"], s["items"], s["ratings"],
                               np.random.default_rng(run.seed))
@@ -438,6 +453,18 @@ def query_all(base: str, queries: list, in_flight: int):
     return [d[0] for d in done], [d[1] for d in done], [d[2] for d in done]
 
 
+def check_rmse(rmse: float, base_rmse: float, n_ratings: int) -> None:
+    """The band holds for the full data set only; a reduced one has to
+    beat the global mean by 15%."""
+    if n_ratings == FULL["ratings"]:
+        if not RMSE_BAND[0] <= rmse <= RMSE_BAND[1]:
+            raise PhaseFailed(f"held-out RMSE {rmse:.4f} outside the "
+                              f"band {RMSE_BAND}")
+    elif not rmse <= 0.85 * base_rmse:
+        raise PhaseFailed(f"held-out RMSE {rmse:.4f} not 15% better than the "
+                          f"global mean's {base_rmse:.4f}")
+
+
 def phase_als(run: Run) -> dict:
     require_tpu(run.found, 1)
     s = run.size
@@ -492,15 +519,7 @@ def phase_als(run: Run) -> dict:
     rmse, base_rmse = heldout_rmse(factors, held, float(tv.mean()))
     out.update({"rmse_heldout": rmse, "rmse_global_mean": base_rmse,
                 "readback_sec": round(time.time() - t0, 1)})
-    from bench import RMSE_BAND
-
-    if s["ratings"] == FULL["ratings"]:
-        if not RMSE_BAND[0] <= rmse <= RMSE_BAND[1]:
-            raise PhaseFailed(f"held-out RMSE {rmse:.4f} outside bench.py's "
-                              f"band {RMSE_BAND}")
-    elif not rmse <= 0.85 * base_rmse:
-        raise PhaseFailed(f"held-out RMSE {rmse:.4f} not 15% better than the "
-                          f"global mean's {base_rmse:.4f}")
+    check_rmse(rmse, base_rmse, s["ratings"])
 
     t0 = time.time()
     proc, base = run.serve("deploy", "--engine-json", variant_path,
@@ -566,9 +585,9 @@ def phase_twotower(run: Run) -> dict:
     s = run.size
     n, pos = s["tt_ids"], s["tt_pos"]
     t0 = time.time()
-    # bench.py stage_twotower's clustered generator; the first n rows
-    # are a permutation on each side so every id appears and the tables
-    # are exactly n rows wide
+    # clustered positives (64 clusters, 80% of a user's items from its
+    # own); the first n rows are a permutation on each side so every id
+    # appears and the tables are exactly n rows wide
     rng = np.random.default_rng(run.seed + 2)
     n_clusters = 64
     user_cluster = rng.integers(0, n_clusters, size=n)

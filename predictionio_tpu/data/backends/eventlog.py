@@ -644,7 +644,7 @@ class EventLogEventStore(S.EventStore):
         *,
         strict: bool = True,
     ):
-        """The native live lane (VERDICT r3 item 3): the API-format JSON
+        """The native live lane: the API-format JSON
         array the event server receives goes straight to C++ — parse,
         EventValidation, wire-record packing and the append happen in
         one call with the GIL released; no per-row Python objects exist
@@ -889,8 +889,8 @@ class EventLogEventStore(S.EventStore):
         ``overrides`` maps event names to constant ratings (the "buy
         means 4.0" rule); other rows take ``value_property`` with
         NaN -> 0.0. ``skip_mod``/``skip_rem`` hold out every row whose
-        kept-row ordinal % mod == rem as an evaluation COO (the bench's
-        5%% split). Rows without a target id are dropped
+        kept-row ordinal % mod == rem as an evaluation COO (a
+        5%% split at mod 20). Rows without a target id are dropped
         (read_interactions semantics). The layout is bit-identical to
         ``compress_side(build_segmented_groups(...))`` over the same
         COO — pinned by tests/test_bin_columnar.py."""
@@ -1150,7 +1150,7 @@ class EventLogEventStore(S.EventStore):
     def data_fingerprint(self, app_id, channel_id=None) -> str:
         """O(1) content fingerprint — changes whenever the app's event
         data does. The binned-layout cache keys on it so retraining on
-        unchanged events skips the 20M-row re-read (VERDICT r3 item 2).
+        unchanged events skips the bulk re-read.
         Backends without a cheap fingerprint simply lack this method.
 
         The key carries the LOG'S IDENTITY (a hash of the resolved log

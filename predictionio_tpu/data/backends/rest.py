@@ -1273,7 +1273,7 @@ class RestModelsRepo(S.ModelsRepo):
 
 
 # ---------------------------------------------------------------------------
-# Replicated METADATA / MODELDATA (VERDICT r3 item 1)
+# Replicated METADATA / MODELDATA
 # ---------------------------------------------------------------------------
 #
 # The reference's metadata tier is highly available because
@@ -1701,7 +1701,7 @@ class RestStorageClient(S.StorageClient):
         return self._meta_replicas > 1
 
     def health_tiers(self) -> Dict[str, Any]:
-        """Tier-resolved health (VERDICT r3 item 9): beyond the
+        """Tier-resolved health: beyond the
         conservative per-endpoint map, report whether each TIER can
         still ANSWER — metadata/models serve while ANY of their first R
         replicas lives; the event tier serves while EVERY shard has a

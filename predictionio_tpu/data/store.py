@@ -152,7 +152,7 @@ def data_fingerprint(
     the backend has no cheap one (only the native eventlog does —
     el_fingerprint). Changes whenever the data does; the binned-layout
     cache (ops.bincache) keys on it so retraining on unchanged events
-    skips the bulk re-read (VERDICT r3 item 2)."""
+    skips the bulk re-read."""
     storage = storage or get_storage()
     app_id, channel_id = resolve_app(app_name, channel_name, storage)
     fn = getattr(storage.events(), "data_fingerprint", None)

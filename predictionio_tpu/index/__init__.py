@@ -24,8 +24,8 @@ reference's MLlib ancestry never had (ALS serving ends at
                         0.95).
   ``index/recall.py``   recall@k measurement vs brute force — the
                         equivalence currency of the whole subsystem
-                        (bench gates, IVF build gate, the streaming
-                        drift probe in workflow/stream.py).
+                        (IVF build gate, the streaming drift
+                        probe in workflow/stream.py).
 
 Models expose ``retrieval_index()`` (ALS / two-tower / similarproduct
 share the factor-table container); the engine server builds and warms
@@ -35,7 +35,7 @@ retrieval, not just scoring.
 
 Backend selection: ``make_index(vectors, backend=...)`` with
 ``PIO_INDEX_BACKEND`` (``auto`` | ``exact`` | ``ivf``) overriding the
-argument for bench A/B. ``auto`` = exact: on an accelerator the fused
+argument. ``auto`` = exact: on an accelerator the fused
 kernel IS the fast path, and on CPU the exact fallback is still the
 correct default — IVF is the explicit opt-in for host-only serving of
 catalogs where brute force can't hold latency.
@@ -92,8 +92,8 @@ class AnnIndex(abc.ABC):
       - ``upsert`` lands streaming fold-in rows (overwrite existing
         rows, append brand-new ones) without a rebuild — the
         ``POST /model/patch`` freshness lane ends here;
-      - ``stats()`` is the operator surface (engine-server status page,
-        bench detail).
+      - ``stats()`` is the operator surface (engine-server status
+        page).
     """
 
     backend: str = "abstract"
@@ -145,8 +145,8 @@ class AnnIndex(abc.ABC):
 
 
 def resolve_backend(backend: Optional[str] = None) -> str:
-    """``PIO_INDEX_BACKEND`` beats the argument (bench A/B without code
-    changes, same stance as the kernel flags); ``auto`` -> exact."""
+    """``PIO_INDEX_BACKEND`` beats the argument (the deployment's
+    choice over the engine.json's); ``auto`` -> exact."""
     value = os.environ.get("PIO_INDEX_BACKEND") or backend or "auto"
     value = str(value).strip().lower()
     if value in ("auto", ""):
@@ -163,8 +163,8 @@ def make_index(item_vectors: Optional[np.ndarray] = None,
                **kwargs) -> AnnIndex:
     """Build an index over ``item_vectors`` (or an empty one to fill
     later). ``kernel`` is the exact backend's Pallas flag
-    (``index_kernel`` on the model params: on/off/auto, env
-    ``PIO_INDEX_KERNEL`` overrides — exactly like ``flash_ce_kernel``)."""
+    (``index_kernel`` on the model params: on/off/auto, exactly like
+    ``flash_ce_kernel``)."""
     name = resolve_backend(backend)
     if name == "exact":
         from predictionio_tpu.index.exact import ExactIndex

@@ -18,9 +18,9 @@ each search took is counted (``routes``) and written into the trace
 
 Kernel selection mirrors ``flash_ce_kernel`` exactly: a per-index
 ``kernel`` flag ("auto"/"on"/"off", wired from the model params'
-``index_kernel``), the ``PIO_INDEX_KERNEL`` env override, ``auto``
-engaging only on a real TPU backend, and interpret mode for CPU
-tier-1 equivalence tests.
+``index_kernel``), ``auto`` engaging only on a real TPU backend, and
+``on`` running interpret mode on the CPU for the tier-1 equivalence
+tests.
 """
 
 from __future__ import annotations
@@ -144,8 +144,7 @@ class ExactIndex(AnnIndex):
         eligible = n > 0
         reason = "empty table" if not eligible else ""
         engaged, why = plk.decide(
-            self.kernel_flag, "PIO_INDEX_KERNEL",
-            eligible=eligible, ineligible_reason=reason,
+            self.kernel_flag, eligible=eligible, ineligible_reason=reason,
             auto_default=jax.default_backend() == "tpu",
         )
         self.kernel_plan = {"engaged": engaged, "reason": why,

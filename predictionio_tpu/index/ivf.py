@@ -15,8 +15,7 @@ until the measured recall clears ``PIO_INDEX_RECALL_FLOOR`` (default
 0.95) or every list is probed (== brute force). The measured value is
 exported on the ``pio_index_recall{backend="ivf"}`` gauge and in
 ``stats()`` — an operator never has to take the approximation on
-faith, and the bench's ``retrieval_qps_recall95`` key only counts
-configurations that cleared the floor.
+faith.
 
 Everything here is numpy partial-sorts (``np.argpartition``) — the
 graftlint JT14 rule exists precisely because a stray ``argsort(...)[:k]``

@@ -2,10 +2,9 @@
 
 Every approximate (or patched) index in this package is judged by one
 number: of the true top-k items under exact dot-product scoring, what
-fraction did the index return? The IVF build gate, the bench's
-``retrieval_qps_recall95`` key and the streaming drift probe
-(``pio_stream_index_recall``) all call :func:`recall_at_k` so they can
-never disagree about what "recall" means.
+fraction did the index return? The IVF build gate and the streaming drift
+probe (``pio_stream_index_recall``) both call :func:`recall_at_k` so
+they can never disagree about what "recall" means.
 
 Ties are handled the only honest way: a retrieved item counts if its
 TRUE score is >= the k-th true score (minus a float epsilon), so an

@@ -95,8 +95,7 @@ class ALSParams(Params):
     # retrieval-index knobs (predictionio_tpu/index): backend
     # "auto"/"exact"/"ivf" (PIO_INDEX_BACKEND overrides), and the exact
     # backend's Pallas dot+top-k kernel flag "auto"/"on"/"off"
-    # (PIO_INDEX_KERNEL overrides — selection exactly like
-    # flash_ce_kernel)
+    # (selection exactly like flash_ce_kernel)
     index_backend: str = "auto"
     index_kernel: str = "auto"
 
@@ -590,7 +589,7 @@ class ALSAlgorithm(Algorithm):
     ) -> Optional[List[ALSModel]]:
         """Train EVERY candidate in ONE compiled dispatch when the
         candidates differ only in SHAPE-STABLE scalars — lambda_,
-        alpha, num_iterations, cg_iters (VERDICT r4 item 6; iteration
+        alpha, num_iterations, cg_iters (iteration
         counts ride as per-candidate step budgets: the program runs to
         the max and freezes finished candidates bit-identically to
         their sequential runs). The vmapped tuning path

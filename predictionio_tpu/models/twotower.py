@@ -46,13 +46,10 @@ class TwoTowerParams(Params):
     checkpoint_every: int = 1
     flash_ce_kernel: str = "auto"          # ops/pallas flash-CE kernel:
     embed_update_kernel: str = "off"       # "auto" | "on" | "off" (see
-                                           # TwoTowerConfig; env overrides
-                                           # PIO_TT_FLASH_CE /
-                                           # PIO_TT_EMBED_UPDATE)
+                                           # TwoTowerConfig)
     index_backend: str = "auto"            # retrieval index backend
                                            # (PIO_INDEX_BACKEND overrides)
     index_kernel: str = "auto"             # Pallas dot+top-k flag
-                                           # (PIO_INDEX_KERNEL overrides)
 
 
 class TwoTowerModel(ALSModel):

@@ -641,8 +641,8 @@ def format_flame(payload: Dict[str, Any], top: int = 10,
     return "\n".join(out) + "\n"
 
 
-#: serve-path interpreter-time buckets, by frame file basename — the
-#: bench profiling stage's parse/JSON/socket/dispatch breakdown
+#: serve-path interpreter-time buckets, by frame file basename: the
+#: parse/JSON/socket/dispatch breakdown
 _BREAKDOWN_FILES = {
     "encoder.py": "json", "decoder.py": "json", "scanner.py": "json",
     "socket.py": "socket", "selectors.py": "socket", "ssl.py": "socket",

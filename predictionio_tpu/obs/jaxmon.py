@@ -119,7 +119,7 @@ def install() -> bool:
 
 def record_kernel_plan(plan: dict) -> None:
     """Export a trainer's kernel-selection decision (ops/pallas/) so a
-    bench capture or dashboard always says which path produced its
+    capture or dashboard always says which path produced its
     numbers — a step-time comparison across runs is meaningless without
     it."""
     for kernel in ("flash_ce", "embed_update"):

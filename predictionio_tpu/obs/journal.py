@@ -18,8 +18,7 @@ Design constraints:
   - the emit path rides SERVING code (a breaker flip happens inside a
     request): it must cost microseconds — build the dict, append to
     the ring, enqueue for the writer; no syscall, no flush, no lock
-    shared with the file handle (the bench pins
-    ``key.journal_append_us``)
+    shared with the file handle
   - durability is the WRITER's job: a daemon thread drains the queue,
     appends, flushes; the file is size-capped with ONE ``.1`` roll
     (same discipline as PIO_TRACE_LOG — current + rolled bound the

@@ -38,7 +38,7 @@ FLOPs/bytes-moved basis:
     ``costs_from_compiled``; analytic-estimate fallback when the
     backend reports nothing) into ``pio_train_peak_bytes{model}`` —
     the peak a donation/HBM regression would move, continuously and
-    per model instead of once per bench run.
+    per model.
 
   OOM preflight
     :func:`estimate_instance_bytes` prices a COMPLETED instance from
@@ -442,7 +442,7 @@ def peak_from_compiled(compiled: Any) -> Optional[int]:
 def note_train_peak(model: str, peak_bytes: int,
                     source: str = "analytic") -> None:
     """Record a trainer's peak device bytes (gauge + the
-    ``/admin/memory`` / bench ``detail.memacct`` record)."""
+    ``/admin/memory`` record)."""
     peak = int(peak_bytes)
     TRAIN_PEAK_BYTES.labels(model).set(float(peak))
     with _peaks_lock:

@@ -1,10 +1,10 @@
 """Performance accounting: live MFU/roofline gauges, the data-path
 ledger, and tail-latency attribution.
 
-The three numbers the ROADMAP says the next PRs must move — data-path
-seconds (events->model), concurrent-tail p99, and two-tower MFU — were
-only observable through one-shot ``bench.py`` runs. This module makes
-them continuous:
+Three numbers a deployment watches — data-path seconds
+(events->model), concurrent-tail p99, and two-tower MFU — kept
+continuously, as live gauges (the benchmark under ``benchmarks/`` reads
+the chip's own trace instead):
 
   MFU / roofline gauges
     Every instrumented trainer builds a :class:`StepAccountant`: the
@@ -12,9 +12,8 @@ them continuous:
     ``jax.stages.Compiled.cost_analysis()`` when the backend reports it
     (:func:`costs_from_compiled`), falling
     back to the analytic formulas this repo already trusts — the
-    two-tower matmul count that used to live in bench.py
-    (:func:`twotower_matmul_flops`, now the ONE copy bench imports) and
-    ALS's ``work_model``. Each observed step sets:
+    two-tower matmul count (:func:`twotower_matmul_flops`) and ALS's
+    ``work_model``. Each observed step sets:
 
       pio_train_mfu{model=}           achieved FLOP/s over the chip peak
       pio_step_flops{model=}          FLOPs per step (cost basis)
@@ -54,11 +53,11 @@ them continuous:
     the clamps in ``pio_flight_negative_remainder_total``).
 
 Chip peaks live in ONE table keyed by jax's ``device_kind``
-(:data:`DEVICE_PEAKS`; bench.py imports the v5e row from here). A TPU
+(:data:`DEVICE_PEAKS`). A TPU
 whose kind has no row is an error, not a default, and on the CPU
 backend no utilisation is computed or exported at all. jax is only
 imported inside the cost-analysis and peak helpers — the module stays
-importable by the bench orchestrator and the pure-CPU servers.
+importable by parents that must stay off jax and the pure-CPU servers.
 """
 
 from __future__ import annotations
@@ -81,7 +80,7 @@ class ChipPeaks:
 
 
 #: per-chip peaks keyed by ``jax.devices()[0].device_kind`` — the one
-#: copy; bench.py and the live gauges divide by the SAME denominators.
+#: copy in the package: every live gauge divides by these.
 #: Source: Google Cloud documentation, "TPU v5e"
 #: (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 819 GB/s HBM.
 DEVICE_PEAKS: Dict[str, ChipPeaks] = {
@@ -109,9 +108,8 @@ def device_peaks() -> Optional[ChipPeaks]:
 
 
 def mfu(flops: float, seconds: float) -> Optional[float]:
-    """Model FLOPs utilization: achieved FLOP/s over the chip peak —
-    the one formula the live gauge and bench.py's driver-captured
-    ``twotower_mfu`` share. None on the CPU backend."""
+    """Model FLOPs utilization: achieved FLOP/s over the chip peak
+    (the live ``pio_train_mfu`` gauge). None on the CPU backend."""
     peaks = device_peaks()
     if peaks is None:
         return None
@@ -124,9 +122,8 @@ def twotower_matmul_flops(batch: int, dim: int,
                           tail_widths: Sequence[int]) -> float:
     """Analytic matmul FLOPs per two-tower training step (fwd + bwd):
     the [B, B] logits einsum and its two rank-D backward products, plus
-    the tail MLP matmuls — moved here from bench.py so the live MFU
-    gauge and the bench capture can never drift apart. The optimizer's
-    elementwise work deliberately does not count."""
+    the tail MLP matmuls. The optimizer's elementwise work
+    deliberately does not count."""
     B, D = float(batch), float(dim)
     flops = 3 * 2.0 * B * B * D          # logits fwd + dL/du + dL/dv
     per_row = sum(2.0 * a * b
@@ -272,7 +269,7 @@ class DataPathLedger:
     """Stage wall-times per training run + the model-freshness clock.
 
     SCOPE: the clock is **per process**. It is exact wherever ingest
-    and publish share a process (the bench, `pio train` after an
+    and publish share a process (`pio train` after an
     import, single-process deployments, tier-1) and is the substrate
     the streaming path (ROADMAP item C) will build on; a split
     deployment (event server here, trainer there) sees only its own

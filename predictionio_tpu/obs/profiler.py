@@ -430,9 +430,7 @@ def per_step(parsed: Dict[str, Any], steps: int) -> Optional[Dict[str, Any]]:
     """Per-STEP device-time breakdown from an already-parsed trace that
     covered ``steps`` train steps: device ms/step overall and per group
     (``group_of``) — the number a step-time regression investigation starts
-    from. The ONE implementation of this division: workflow/train.py's
-    post-train log and bench.py's detail.* both call it, so they can
-    never disagree on the same trace. None when the trace carries no
+    from. workflow/train.py's post-train log calls it. None when the trace carries no
     device time or ``steps`` is unknown (<= 0) — a whole-train total
     must never masquerade as a per-step number."""
     if not parsed.get("device_time_sec") or steps <= 0:

@@ -1,7 +1,7 @@
 """Metric timelines: a bounded in-process time-series ring.
 
-``GET /metrics`` answers "what is the value now"; a bench run answers
-"what was it that one time". Neither answers the operator question
+``GET /metrics`` answers "what is the value now"; a benchmark run
+answers "what was it that one time". Neither answers the operator question
 "what has the MFU / staleness / serving p99 done over the last hour?"
 without an external TSDB. This module keeps a small history in the
 process itself: on a configurable cadence, a fixed set of collectors

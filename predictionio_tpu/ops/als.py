@@ -109,7 +109,7 @@ def als_row_cost_slots(rank: int) -> float:
     per-slot gather cost. The ONE copy — this number shapes the
     PHYSICAL layout (it drives auto seg_len), and the binned-layout
     cache key covers it only through ``rank``, so every lane (trainer,
-    binned fit lane, bench) must derive it from rank the same way or
+    binned fit lane) must derive it from rank the same way or
     a shared cache entry would carry a different geometry than the
     requesting lane would build."""
     return max(8.0, rank * rank / 300.0)
@@ -179,8 +179,9 @@ def _batched_cg(A, b, iters: int, x0=None, matvec_dtype=jnp.float32,
     them), reaching the same residual in fewer steps — the knob that
     lets cg_iters drop below the unpreconditioned cliff.
 
-    r5 ON-CHIP MEASUREMENTS (ML-20M, K=64, integrated 5-iteration train,
-    min-of-2, /tmp-harness reproduced in ROUND5.md):
+    ON-CHIP MEASUREMENTS (ML-20M, K=64, integrated 5-iteration train,
+    min-of-2), taken before this repo's benchmark existed and not
+    repeated since (no cell trains ALS, PERF.md §7):
       scan-none-10 (r4 default)  1.477 s  rmse 0.4276
       unroll-none-10             1.468 s  rmse 0.4276
       unroll-jacobi-10           1.434 s  rmse 0.4276
@@ -478,7 +479,7 @@ class SideLayout:
     - the gather indexes cross the wire SPLIT as lo-uint16 (+ hi-uint8
       only when the opposing vocab exceeds 65535; vocabs are < 2^24 by
       assertion), recombined to int32 ONCE on device right after the
-      put (r5, VERDICT item 3). The r3-rejected int16 variant made the
+      put. The earlier, rejected int16 variant made the
       per-STEP gather pay an int16->s32 conversion (~12% step time);
       the one-time decode keeps the steady-state gather on int32 while
       the wire pays 2-3 B/slot instead of 4 — 9 -> 3-4 B/slot total
@@ -708,9 +709,9 @@ def layout_cache_key(cache_key: str, cfg: ALSConfig, n_shards: int,
                      max_ratings_per_user: Optional[int] = None,
                      max_ratings_per_item: Optional[int] = None) -> str:
     """The ONE bincache key derivation for ALS segmented layouts —
-    shared by ALSTrainer's internal COO-path cache, the zero-copy
-    binned lane (models/als._train_binned) and the bench's warm stage,
-    so an entry written by any lane serves the others (the layouts are
+    shared by ALSTrainer's internal COO-path cache and the zero-copy
+    binned lane (models/als._train_binned), so an entry written by
+    either lane serves the other (the layouts are
     bit-identical by construction)."""
     from predictionio_tpu.ops import bincache
 
@@ -740,7 +741,7 @@ class ALSTrainer:
     """Prepared ALS run: data binned + placed on device, steps compiled.
 
     Separates the one-time costs (host binning, sharding, XLA compile)
-    from the per-iteration device work so callers — and the benchmark —
+    from the per-iteration device work so callers
     can alternate without paying them again. The full pipeline replaces
     the reference's `ALS.train` call (examples/.../ALSAlgorithm.scala:56).
     """
@@ -757,7 +758,7 @@ class ALSTrainer:
         cache_key: Optional[str] = None,
     ):
         """``cache_key`` enables the persistent binned-layout cache
-        (ops.bincache, VERDICT r3 item 2): the compressed device layout
+        (ops.bincache): the compressed device layout
         is loaded by key when present — ``user_coo``/``n_users``/
         ``n_items`` may then be None, and retraining on unchanged
         events skips the whole read->bin pipeline — and saved after a
@@ -878,8 +879,6 @@ class ALSTrainer:
         self.transfer_bytes = (user_side.transfer_bytes
                                + item_side.transfer_bytes)
         self._slot_bytes = (user_side.slot_bytes, item_side.slot_bytes)
-        self._user_row_block = user_side.row_block
-        self._user_affine = user_side.affine  # measure_gather_roof
         self._host_refs = (user_side, item_side)
         self._transfer_lock = threading.Lock()
         self._transfer_noted = False
@@ -1047,8 +1046,8 @@ class ALSTrainer:
         })
 
     def compile(self) -> "ALSTrainer":
-        """Compile the default-iteration-count program ahead of time
-        (bench warm-up): ``.lower().compile()`` at the resident shapes,
+        """Compile the default-iteration-count program ahead of time:
+        ``.lower().compile()`` at the resident shapes,
         kept as the program ``step_n`` dispatches — no throwaway run."""
         n = self.cfg.iterations
         t0 = time.perf_counter()
@@ -1105,64 +1104,12 @@ class ALSTrainer:
             item_factors=_materialize(self._Y)[: self.n_items],
         )
 
-    def measure_gather_roof(self, reps: int = 3) -> dict:
-        """EMPIRICAL roof for the stage the train step is claimed to be
-        bound by (VERDICT r3 item 4): a jitted kernel that performs
-        ONLY the stage-1 gather + mask-multiply + reduce of the USER
-        side, at the real device shapes/dtypes/blocking — no Gramian
-        einsums, no segment-sum, no solve. Its slots/sec is what this
-        chip can actually issue for this access pattern, so
-        ``train slots/sec / roof slots/sec`` is a measured bound
-        fraction (the public specs publish no gather issue rate).
-        Returns {"roof_slots_per_sec", "slots_per_iteration"}."""
-        idx = self._ud[0]
-        val = self._ud[1]
-        R, L = idx.shape
-        row_block = min(self._user_row_block, R)
-        nrb = R // row_block
-        cdt = jnp.dtype(self.cfg.compute_dtype)
-        affine = self._user_affine
-
-        def kernel(Y, idx, val):
-            Yc = Y.astype(cdt)
-
-            def block(args):
-                idx_b, val_b = args
-                if affine is not None:
-                    mask_b = (val_b != PAD_CODE).astype(cdt)
-                else:
-                    mask_b = val_b  # uncoded: val doubles as a stream read
-                g = Yc[idx_b] * mask_b[..., None]
-                return jnp.sum(g, dtype=jnp.float32)
-
-            parts = jax.lax.map(
-                block, (idx.reshape(nrb, row_block, L),
-                        val.reshape(nrb, row_block, L)))
-            return jnp.sum(parts)
-
-        fn = jax.jit(kernel)
-        fn(self._Y, idx, val).item()   # compile + warm
-        import time as _time
-
-        t0 = _time.perf_counter()
-        for _ in range(reps):
-            fn(self._Y, idx, val).item()
-        dt = (_time.perf_counter() - t0) / reps
-        slots_user = float(R) * float(L)
-        slots_item = (float(self._it[0].shape[0])
-                      * float(self._it[0].shape[1]))
-        return {
-            "roof_slots_per_sec": slots_user / dt,
-            "slots_per_iteration": slots_user + slots_item,
-            "roof_kernel_sec": dt,
-        }
-
     def work_model(self) -> dict:
         """Analytic FLOP/byte counts per full alternation (both half
         steps), from the ACTUAL padded array shapes on device — the
-        basis for the benchmark's roofline accounting (achieved vs chip
-        peak). Padded slots count: they cost real gather issue slots,
-        MXU cycles and HBM beats.
+        basis for roofline accounting (achieved vs chip peak). Padded
+        slots count: they cost real gather issue slots, MXU cycles and
+        HBM beats.
 
         The byte model counts the dominant streams of `_solve_shard`:
         gather-read of the opposing factors, the materialized [B, L, K]
@@ -1238,8 +1185,8 @@ def als_grid_train(
     Single-device (the grid axis occupies the batch dimension; shard the
     DATA instead when one model alone saturates a chip).
 
-    Beyond ``regs``, candidates may differ in any SHAPE-STABLE scalar
-    (VERDICT r4 item 6): ``alphas`` (implicit confidence) rides the
+    Beyond ``regs``, candidates may differ in any SHAPE-STABLE scalar:
+    ``alphas`` (implicit confidence) rides the
     vmap like reg; ``iterations`` and ``cg_iters`` are per-candidate
     step BUDGETS — the program runs to the max and freezes a
     candidate's state once its budget is spent, so each grid member

@@ -1,4 +1,4 @@
-"""Persistent cache of binned device layouts (VERDICT r3 item 2).
+"""Persistent cache of binned device layouts.
 
 Retraining on unchanged events should not re-pay the host-side
 read -> bin pipeline: the segmented layouts the ALS trainer ships to

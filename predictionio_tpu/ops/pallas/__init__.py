@@ -1,10 +1,11 @@
 """Pallas TPU kernels for the framework's measured hot loops.
 
-Why a kernel subsystem exists (ROUND5.md §4): the two-tower stretch
-step is 90% NON-matmul device time — the blockwise-CE scan body's
+Why a kernel subsystem exists: a profile of the two-tower stretch step
+taken before this repo's chip runs (PERF.md §5 has today's) read 90%
+of its device time outside the matmuls — the blockwise-CE scan body's
 per-tile elementwise (56%) and the embedding scatter path (28+%) —
-while the matmul window itself already runs at ~45-57% of the v5e bf16
-peak. XLA fuses neither across its own loop/scatter boundaries; Pallas
+while the matmul window itself ran at ~45-57% of the v5e bf16 peak.
+XLA fuses neither across its own loop/scatter boundaries; Pallas
 lets the elementwise CE ride in the matmul's shadow (``flash_ce``) and
 the table update run as one VMEM-resident gather→update→write pass
 (``embed_update``).
@@ -12,8 +13,8 @@ the table update run as one VMEM-resident gather→update→write pass
 Design contract shared by every kernel here:
 
   - the XLA implementation REMAINS the reference; a kernel is selected
-    per-trainer by :func:`decide` (config flag + env override +
-    eligibility). ``auto`` is a choice by backend — on a TPU the
+    per-trainer by :func:`decide` (config flag + eligibility).
+    ``auto`` is a choice by backend — on a TPU the
     compiled kernel, on the CPU the XLA path — never a fallback: an
     engaged kernel that fails to compile or run raises the compiler's
     own error in the trainer / the index, so a chip run can never be
@@ -29,13 +30,11 @@ Design contract shared by every kernel here:
 The same contract covers serving: ``topk_dot`` (fused dot + streaming
 top-k over the item table in ``[D, Ip]`` tiles of thousands of items,
 merging only a tile that can change the top-k — the exact retrieval
-index's hot path, selected per-index via ``index_kernel`` /
-``PIO_INDEX_KERNEL``).
+index's hot path, selected per-index via ``index_kernel``).
 
-Env overrides (each beats the config flag, for bench A/B without code
-changes): ``PIO_TT_FLASH_CE``, ``PIO_TT_EMBED_UPDATE``,
-``PIO_INDEX_KERNEL`` = ``on`` / ``off`` / ``auto``;
-``PIO_PALLAS_INTERPRET=1`` forces interpret mode.
+Each flag (``flash_ce_kernel``, ``embed_update_kernel``,
+``index_kernel``) takes ``on`` / ``off`` / ``auto``;
+``PIO_PALLAS_INTERPRET=1`` forces interpret mode off the TPU.
 """
 
 from __future__ import annotations
@@ -65,28 +64,23 @@ def interpret_mode() -> bool:
     return True
 
 
-def resolve_flag(config_value: str, env_name: str) -> str:
-    """Normalize a kernel flag to ``on`` / ``off`` / ``auto``; the env
-    variable (bench A/B switch) overrides the config value. An
+def resolve_flag(config_value: str) -> str:
+    """Normalize a kernel flag to ``on`` / ``off`` / ``auto``. An
     unrecognized value falls back to ``auto`` WITH a warning — a typo'd
-    ``PIO_TT_EMBED_UPDATE=onn`` during an on-chip A/B must not silently
-    measure the fallback arm twice."""
-    value = os.environ.get(env_name, config_value)
-    value = str(value).strip().lower()
+    ``"onn"`` in an engine.json must not silently run the other path."""
+    value = str(config_value).strip().lower()
     if value in _TRUTHY:
         return "on"
     if value in _FALSY:
         return "off"
     if value != "auto":
-        log.warning("unrecognized kernel flag %r (config %r / env %s); "
-                    "treating as 'auto' — valid values: on/off/auto",
-                    value, config_value, env_name)
+        log.warning("unrecognized kernel flag %r; treating as 'auto' — "
+                    "valid values: on/off/auto", config_value)
     return "auto"
 
 
 def decide(
     config_value: str,
-    env_name: str,
     *,
     eligible: bool,
     ineligible_reason: str,
@@ -101,7 +95,7 @@ def decide(
              passes True only on a real TPU backend, so interpret mode
              is never silently slower for CPU users.
     """
-    flag = resolve_flag(config_value, env_name)
+    flag = resolve_flag(config_value)
     if flag == "off":
         return False, "disabled by flag"
     if not eligible:

@@ -30,14 +30,15 @@ differs for duplicates).
 
 DEFAULT OFF (``TwoTowerConfig.embed_update_kernel = "off"``), the
 repo's measured-rejection discipline applied prospectively: the XLA
-scatter's measured floor is its ~75 ns/row ISSUE RATE (ROUND5.md §4 —
-optimization_barrier, sorted-indices, and fused-accumulator-column
-forms all tried and rejected with numbers, ``_rowwise_adagrad``
-docstring), and this kernel's per-row DMA round-trips amortize only
-``tile``-wide, so the analytic projection at B=8192 is AT BEST parity
-(2 x 8192 row-DMAs/step vs 2 x 8192 scatter row-issues) — it must WIN
-on-chip before becoming default. Flip ``PIO_TT_EMBED_UPDATE=on`` for
-the A/B; record the numbers either way.
+scatter's floor is its ~75 ns/row ISSUE RATE (measured before this
+repo's chip runs; optimization_barrier, sorted-indices and
+fused-accumulator-column forms all tried and rejected with numbers,
+``_rowwise_adagrad`` docstring), and this kernel's per-row DMA
+round-trips amortize only ``tile``-wide, so the analytic projection at
+B=8192 is AT BEST parity (2 x 8192 row-DMAs/step vs 2 x 8192 scatter
+row-issues) — it must WIN on-chip before becoming default: set
+``embed_update_kernel: "on"`` in the algorithm's params for the
+comparison and record the numbers either way.
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ What it replaces: ``ops.twotower._make_blockwise_ce_vjp``'s
 [B, B] HBM materialization, but its per-tile elementwise (masks, exp,
 where, reductions) lowers as a separate fusion per scan step — the
 ``while`` envelope measured at 56% of the stretch step's device time
-(ROUND5.md §4). Here each (row-tile, col-tile) grid step computes the
+(a profile taken before this repo's chip runs). Here each (row-tile, col-tile) grid step computes the
 tile logits ON the MXU and does the masking/exp/reduction while the
 next tile's operands stream in — the elementwise rides in the matmul's
 shadow instead of owning the loop.

@@ -37,9 +37,9 @@ upper bound that can cost a needless merge, never an answer.
 
 Selection contract (ops/pallas/__init__.py): the XLA scorer REMAINS
 the reference; ``index/exact.py`` engages this kernel per-index via
-:func:`predictionio_tpu.ops.pallas.decide` (``index_kernel="auto"`` +
-``PIO_INDEX_KERNEL``): compiled on a TPU, interpret-mode on CPU for
-tier-1.
+:func:`predictionio_tpu.ops.pallas.decide` (``index_kernel``):
+compiled on a TPU under ``auto``, interpret-mode on the CPU under
+``on`` for tier-1.
 """
 
 from __future__ import annotations

@@ -9,8 +9,8 @@ only), so the behavior contract is the recommendation template's (same
 query/result surface as ALS); the training loop is what a TPU-native
 framework adds.
 
-r5 redesign — the loop is shaped by what actually binds at catalog
-scale (1M x 128 tables), measured for the BENCH twotower stage:
+The loop is shaped by what actually binds at catalog scale (1M x 128
+tables):
 
   - ROW-SPARSE table updates. A flax ``nn.Embed`` under
     ``value_and_grad`` materializes a DENSE [N, E] gradient and a dense
@@ -101,14 +101,12 @@ class TwoTowerConfig:
     checkpoint_every: int = 1             # epochs between checkpoints
     flash_ce_kernel: str = "auto"      # Pallas fused flash-CE loss kernel:
                                        # "auto" (on for single-device TPU
-                                       # runs, XLA elsewhere) | "on" | "off";
-                                       # env PIO_TT_FLASH_CE overrides
+                                       # runs, XLA elsewhere) | "on" | "off"
     embed_update_kernel: str = "off"   # Pallas fused table-update kernel:
                                        # default OFF pending an on-chip win
                                        # over the measured XLA scatter floor
                                        # (ops/pallas/embed_update.py
-                                       # docstring); env PIO_TT_EMBED_UPDATE
-                                       # overrides
+                                       # docstring)
 
 
 @dataclasses.dataclass
@@ -200,8 +198,8 @@ def _blockwise_softmax_ce_autodiff(u, v, u_idx, i_idx, weight, temp, chunk,
     trick applied to the retrieval loss): logits are computed in
     [B, chunk] column tiles inside ``jax.checkpoint``, so the full
     [B, B] matrix and its masks NEVER materialize in HBM — the step
-    stays matmul-bound instead of elementwise-HBM-bound (measured r5:
-    6.4 ms -> see bench twotower stage at B=8192, D=128).
+    stays matmul-bound instead of elementwise-HBM-bound (the dense
+    loss measured 6.4 ms a step at B=8192, D=128, before the chip).
 
     One pass over column tiles yields BOTH directions: each tile
     contributes a partial row-LSE for user->item (combined across tiles
@@ -590,8 +588,9 @@ class TwoTowerTrainer:
         multi-device mesh). An engaged kernel is used as is: if the
         chip's compiler refuses it, the first epoch raises that error —
         there is no XLA fallback to hide it. The decision dict
-        is exported (bench detail + ``pio_pallas_kernel_enabled``
-        metric) so a capture always says which path produced it."""
+        is exported (``pio train``'s report, ``kernel_plan`` and the
+        ``pio_pallas_kernel_enabled`` metric) so a capture always says
+        which path produced it."""
         from predictionio_tpu.obs import jaxmon
 
         cfg = self.cfg
@@ -607,13 +606,12 @@ class TwoTowerTrainer:
                   else "1/temp outside the direct-exp regime" if not direct
                   else f"batch {self.batch} < {_pl_flash.MIN_BATCH}")
         ce_on, ce_why = _plk.decide(
-            cfg.flash_ce_kernel, "PIO_TT_FLASH_CE",
-            eligible=elig_ce, ineligible_reason=why_ce,
+            cfg.flash_ce_kernel, eligible=elig_ce, ineligible_reason=why_ce,
             auto_default=on_tpu)
 
         emb_on, emb_why = _plk.decide(
-            cfg.embed_update_kernel, "PIO_TT_EMBED_UPDATE",
-            eligible=single, ineligible_reason="multi-device mesh",
+            cfg.embed_update_kernel, eligible=single,
+            ineligible_reason="multi-device mesh",
             auto_default=False)  # default-off: measured-rejection
         #                          discipline, ops/pallas/embed_update.py
 
@@ -778,9 +776,8 @@ class TwoTowerTrainer:
         jaxmon.record_scope_map(self._compiled)
         # one dispatch = one epoch (the jitted lax.scan), so the cost
         # basis is per-EPOCH: cost_analysis of the compiled epoch when
-        # the backend reports one, else the shared analytic matmul
-        # count x steps (obs/perfacct — the same formula bench.py's
-        # twotower_mfu divides by)
+        # the backend reports one, else the analytic matmul count x
+        # steps (obs/perfacct.twotower_matmul_flops)
         self._acct = perfacct.StepAccountant.from_compiled(
             "twotower", self._compiled,
             fallback_flops=(self.matmul_flops_per_step()
@@ -822,13 +819,10 @@ class TwoTowerTrainer:
             losses=losses or [],
         )
 
-    # -- bench hooks --------------------------------------------------------
-
     def matmul_flops_per_step(self) -> float:
-        """Analytic matmul FLOPs per training step (fwd + bwd) — the
-        ONE shared formula (obs/perfacct.twotower_matmul_flops), so the
-        live ``pio_train_mfu`` gauge and the bench's driver-captured
-        ``twotower_mfu`` can never drift apart."""
+        """Analytic matmul FLOPs per training step (fwd + bwd), by
+        obs/perfacct.twotower_matmul_flops: the live ``pio_train_mfu``
+        gauge's cost basis where the backend reports none."""
         from predictionio_tpu.obs import perfacct
 
         return perfacct.twotower_matmul_flops(

@@ -101,22 +101,16 @@ class ServingStats:
     percentiles on the status page and ``GET /metrics`` report from
     that one source of truth. Counts/totals are additionally tracked
     per ServingStats (per server — fleet replicas need per-replica
-    numbers), and a bounded window of raw per-request times is kept
-    alongside for ``recent()`` (bench.py reads exact server-side
-    samples; histogram buckets would quantize them)."""
-
-    WINDOW = 8192
+    numbers)."""
 
     def __init__(self, engine_id: str = "default"):
-        import collections
-
         self._lock = threading.Lock()
         # the registry child is process-global per engine: every live
         # server for this engine (N threaded fleet replicas included)
         # records into the SAME series, so /metrics, the serving-latency
         # SLO and burn-driven shedding see ALL traffic — a regression
         # confined to one replica must still move the shared histogram.
-        # Per-SERVER bookkeeping (status page counts, recent()) lives
+        # Per-SERVER bookkeeping (status page counts) lives
         # locally: a new server starts its own counts from zero while
         # the registry series stays cumulative, Prometheus-style.
         self._hist = _SERVING_SECONDS.labels(engine_id)
@@ -124,7 +118,6 @@ class ServingStats:
         self._sum = 0.0
         self.last_serving_sec = 0.0
         self.start_time = _dt.datetime.now(tz=UTC)
-        self._window: collections.deque = collections.deque(maxlen=self.WINDOW)
 
     @property
     def request_count(self) -> int:
@@ -145,13 +138,6 @@ class ServingStats:
             self._count += 1
             self._sum += seconds
             self.last_serving_sec = seconds
-            self._window.append(seconds)
-
-    def recent(self, n: Optional[int] = None) -> List[float]:
-        """The last ``n`` (default: all windowed) serving times."""
-        with self._lock:
-            out = list(self._window)
-        return out if n is None else out[-n:]
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -241,22 +227,22 @@ class MicroBatcher:
                             else f"serving_queue:{chaos_tag}")
         health.REGISTRY.register(self._probe_name, self._queue_probe)
         # batch-size histogram: the observable proof that amortization
-        # actually happens under load (VERDICT r3 item 6) — exposed in
+        # actually happens under load — exposed in
         # the server's status JSON
         self._hist_lock = threading.Lock()
         self._hist: dict = {}
         # rolling (queue_wait, dispatch) seconds per answered request:
         # separates time spent WAITING for the worker from time inside
         # the model dispatch — the split a concurrency sweep needs to
-        # tell queueing from device work (VERDICT r4 item 5)
+        # tell queueing from device work
         from collections import deque
 
         self._splits = deque(maxlen=50_000)
         # abandoned submitters (timed out waiting) are counted here and
         # EXCLUDED from the splits: their queue wait is the caller's
         # timeout and their dispatch time covers work the worker skipped
-        # — folding them in would skew the bench's srv_queue /
-        # srv_dispatch percentiles with numbers no served request saw
+        # — folding them in would skew the percentiles read from
+        # recent_splits() with numbers no served request saw
         self._abandoned = 0
         # dispatches so far; only the worker writes it. Rides on each
         # pio:batch.dispatch span so a trace can tell them apart

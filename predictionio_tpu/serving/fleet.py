@@ -452,7 +452,7 @@ class FleetSupervisor:
         for replica in self.replicas:
             replica.terminate()
             # retire this fleet's per-replica series: a later fleet in
-            # the same process (bench's 1/2/4 sweep) must not inherit
+            # the same process (a sweep over fleet sizes) must not inherit
             # phantom replicas still exported at 0 / on an old version
             _REPLICA_UP.remove(replica.name)
             if replica.version:

@@ -25,9 +25,6 @@ Console.scala:128-735 command surface; bin/pio:17-42 wrapper):
                          dump; an on-demand JAX profiler window)
   slo                   (obs: SLO burn-rate evaluation, in-process or
                          from a server's /admin/slo)
-  bench-compare         (per-metric deltas across the BENCH_r*.json
-                         trajectory; exit 1 on regressions beyond the
-                         tolerance band)
   top                   (live terminal view of the metric timelines:
                          MFU, staleness, serving p50/p99, request rate
                          — sparklines from a server's /admin/timeline
@@ -1793,18 +1790,6 @@ def cmd_top(args) -> int:
         return 0
 
 
-def cmd_bench_compare(args) -> int:
-    """Per-metric deltas across the bench trajectory (BENCH_r*.json):
-    newest round vs the previous (or --against first), REGRESSION/
-    IMPROVED verdicts beyond --tolerance percent, exit 1 on any
-    regression — perf drift becomes visible at review time."""
-    from predictionio_tpu.tools import benchcmp
-
-    files = args.files or benchcmp.default_files(args.dir)
-    return benchcmp.run(files, tolerance_pct=args.tolerance,
-                        against=args.against)
-
-
 def cmd_lint(args) -> int:
     """graftlint: the JAX/TPU-aware static analysis over the tree
     (rules JT01-JT17 + JT22-JT23 per file; --project adds the whole-program
@@ -2344,24 +2329,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--json", action="store_true",
                    help="raw data-plane report")
     p.set_defaults(func=cmd_data)
-
-    p = sub.add_parser(
-        "bench-compare",
-        help="compare the newest BENCH_r*.json round against a baseline; "
-             "print per-metric deltas, exit 1 on regressions beyond the "
-             "tolerance band",
-    )
-    p.add_argument("files", nargs="*", default=[],
-                   help="bench files in trajectory order (default: "
-                        "BENCH_r*.json in --dir)")
-    p.add_argument("--dir", default=".",
-                   help="directory holding BENCH_r*.json (default: cwd)")
-    p.add_argument("--tolerance", type=float, default=10.0,
-                   help="tolerance band in percent (default 10)")
-    p.add_argument("--against", choices=["prev", "first"], default="prev",
-                   help="baseline round: the previous one (default) or "
-                        "the first")
-    p.set_defaults(func=cmd_bench_compare)
 
     p = sub.add_parser("lint", help="run graftlint (JAX/TPU-aware static "
                                     "analysis, rules JT01-JT23) over the tree")

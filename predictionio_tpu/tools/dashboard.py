@@ -784,7 +784,7 @@ class DashboardServer(HTTPServerBase):
             f"{report['capacity_bytes']:,} B — headroom "
             f"<b>{report['headroom_bytes']:,} B</b> (floor "
             f"{report['headroom_floor_fraction']:.0%} of capacity; "
-            "PIO_PEAK_HBM_BYTES / PIO_MEM_HEADROOM_FLOOR).</p>"
+            "PIO_MEM_HEADROOM_FLOOR).</p>"
             f"<p>headroom <code>{esc(spark) or '(no samples yet)'}"
             "</code></p>"
             "<h2>Per-model ledger</h2>"

@@ -68,18 +68,6 @@ def http_target(base_url: str) -> Target:
     return query
 
 
-def server_target(server: Any) -> Target:
-    """A replay target over an in-process EngineServer (bench/tests):
-    same differ, no HTTP hop."""
-
-    def query(payload: Any) -> Tuple[Any, float]:
-        t0 = time.perf_counter()
-        answer = server.query(payload)
-        return answer, time.perf_counter() - t0
-
-    return query
-
-
 def fetch_payloads(flight_url: str, n: Optional[int] = None,
                    timeout: float = 10.0) -> List[Dict[str, Any]]:
     """Pull the captured payload ring off a server's flight dump.

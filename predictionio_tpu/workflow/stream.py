@@ -1,8 +1,10 @@
 """Streaming events→model: delta tailer + fold-in updates (ROADMAP C).
 
-The batch pipeline retrains the world on every event — cold
-events→model is ~80 s and warm ~35 s (BENCH_r03/r05) while the actual
-ALS train is ~1.5 s. This module is the incremental path that makes
+The batch pipeline retrains the world on every event, and most of a
+retrain is reading, binning and shipping data that did not change, not
+the ALS solve (before the chip, on ML-20M: ~80 s cold and ~35 s warm
+against a ~1.5 s train; no cell measures it yet, PERF.md §7). This
+module is the incremental path that makes
 ``pio_model_staleness_seconds`` small:
 
   tail     ``EventStore.find_columnar_since(cursor)`` (native
@@ -501,7 +503,7 @@ class StreamUpdater:
 
     ``patch_servers`` are in-process
     :class:`~predictionio_tpu.serving.engine_server.EngineServer`
-    objects (bench / tests / single-process deployments);
+    objects (tests / single-process deployments);
     ``patch_urls`` are remote engine-server base URLs (``pio stream
     --url``). With neither, the local model copy is still folded and
     the horizon still moves — the embedding caller owns serving.

@@ -266,7 +266,7 @@ def run_train(
         # one structured line with the events->model stage split (the
         # zero-copy lane's read/bin/transfer sub-stages land here, so
         # a `pio train` log answers "where did the minutes go" without
-        # a bench run; pio_datapath_stage_seconds carries it live)
+        # a measurement run; pio_datapath_stage_seconds carries it live)
         runs = perfacct.LEDGER.snapshot().get("runs") or []
         if runs:
             stages = runs[-1].get("stages") or {}

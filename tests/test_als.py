@@ -363,7 +363,7 @@ def test_grid_train_vmapped_matches_sequential():
 
 
 def test_grid_train_multi_scalar_matches_sequential():
-    """VERDICT r4 item 6: candidates differing in reg AND iteration
+    """Candidates differing in reg AND iteration
     budget AND cg budget ride ONE vmapped dispatch — each candidate's
     factors match its own dedicated sequential run (the run-to-max +
     freeze masking must be numerically faithful, not approximate)."""

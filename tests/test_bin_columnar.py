@@ -432,49 +432,6 @@ def test_bincache_save_is_atomic_and_prune_skips_fresh_temps(
     assert bincache.load("torn") is None
 
 
-# -- benchcmp gates -------------------------------------------------------------
-
-def test_benchcmp_gates_datapath_keys(tmp_path):
-    """key.bin_sec / key.transfer_sec regress UP (lower-better);
-    key.warm_transfer_mb_per_sec regresses DOWN."""
-    import io
-    import json
-
-    from predictionio_tpu.tools import benchcmp
-
-    assert benchcmp.lower_is_better("key.bin_sec")
-    assert benchcmp.lower_is_better("key.transfer_sec")
-    assert not benchcmp.lower_is_better("key.warm_transfer_mb_per_sec")
-
-    for n, (b, t) in ((1, (5.0, 10.0)), (2, (9.0, 22.0))):
-        (tmp_path / f"BENCH_r0{n}.json").write_text(json.dumps(
-            {"parsed": {"metric": "m", "value": 1.0,
-                        "key": {"bin_sec": b, "transfer_sec": t}}}))
-    out = io.StringIO()
-    rc = benchcmp.run([str(tmp_path / "BENCH_r01.json"),
-                       str(tmp_path / "BENCH_r02.json")],
-                      tolerance_pct=10.0, out=out)
-    assert rc == 1
-    assert "key.bin_sec" in out.getvalue()
-    assert "key.transfer_sec" in out.getvalue()
-
-
-def test_headline_carries_datapath_keys():
-    import bench as bench_mod
-
-    detail = {
-        "rmse_gate_passed": True, "rmse_band_passed": True,
-        "serve_gate_passed": True, "serve_32_gate_passed": True,
-        "row_lane_gate_passed": True, "updates_per_sec": 123.0,
-        "bin_sec": 2.5, "transfer_sec": 7.0,
-        "warm": {"events_to_model_sec": 9.0, "transfer_mb_per_sec": 88.0},
-    }
-    line = bench_mod.emit_headline(dict(detail), detail_path=os.devnull)
-    assert line["key"]["bin_sec"] == 2.5
-    assert line["key"]["transfer_sec"] == 7.0
-    assert line["key"]["warm_transfer_mb_per_sec"] == 88.0
-
-
 def test_rb_bin_compressed_nan_values_stay_uncoded(monkeypatch):
     """Review regression: a NaN among the raw values must force the
     f32+mask layout (np.unique keeps the NaN and the ladder check

@@ -1,4 +1,4 @@
-"""Binned-layout cache + transfer compression (VERDICT r3 item 2).
+"""Binned-layout cache + transfer compression.
 
 Retraining on unchanged events must not re-pay read->bin: the
 compressed device layout persists under the bin cache keyed by the

@@ -3,8 +3,10 @@
   - the script itself: on the CPU at the test-only size it fails, names
     the phase and exits non-zero (the platform is not ``tpu``); its
     child-line parser is unit-tested;
-  - parents stay off jax: chip_smoke, bench's orchestrator and the
-    fleet's router process import no jax, and ``GET /readyz`` /
+  - the script's own data: its generator is pinned by a checksum, the
+    split holds out every 20th row, the RMSE band binds at full size;
+  - parents stay off jax: chip_smoke and the fleet's router process
+    import no jax, and ``GET /readyz`` /
     ``GET /metrics`` on an event server, a storage server and a router
     leave it so (a process that imported jax could take the chip its
     children need) — checked in fresh interpreters, since this one has
@@ -113,18 +115,72 @@ def test_check_top10_against_numpy_reference():
         chip_smoke.check_top10(factors, 2, swapped)
 
 
+# -- the script's own data ---------------------------------------------------
+
+@pytest.mark.parametrize("seed,sizes,digest", [
+    (7, (300, 120, 12_000),
+     "7e108cfcb9066602a1a5485c0585e46168bbb86b1426a8157d09e5e863db623b"),
+    (22, (1000, 400, 50_000),
+     "b154e0f809b81da983fee3a633ffbdebf8699b13f222b14ea3314b64f47b3eaa"),
+])
+def test_synthesize_gives_the_arrays_it_always_gave(seed, sizes, digest):
+    """The digests were taken from the generator this one replaced
+    (PR 29's parent), so a smoke run's data, and with it the RMSE band,
+    mean what they meant."""
+    import hashlib
+
+    import numpy as np
+
+    arrays = chip_smoke.synthesize(*sizes, np.random.default_rng(seed))
+    assert [a.dtype for a in arrays] == [np.int64, np.int64, np.float64]
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.ascontiguousarray(a).tobytes())
+    assert h.hexdigest() == digest
+
+
+def test_als_data_holds_out_every_20th_row():
+    import types
+
+    import numpy as np
+
+    size = dict(chip_smoke.TINY)
+    run = types.SimpleNamespace(seed=5, size=size)
+    kept, held = chip_smoke.als_data(run)
+    whole = chip_smoke.synthesize(size["users"], size["items"],
+                                  size["ratings"], np.random.default_rng(5))
+    rows = np.arange(size["ratings"])
+    for k, h, w in zip(kept, held, whole):
+        assert np.array_equal(h, w[rows % 20 == 0])
+        assert np.array_equal(k, w[rows % 20 != 0])
+    assert len(held[0]) * 20 == size["ratings"]
+
+
+def test_rmse_band_binds_at_full_size_only():
+    full, tiny = chip_smoke.FULL["ratings"], chip_smoke.TINY["ratings"]
+    lo, hi = chip_smoke.RMSE_BAND
+    chip_smoke.check_rmse((lo + hi) / 2, 1.0, full)
+    for outside in (lo - 0.01, hi + 0.01):
+        with pytest.raises(chip_smoke.PhaseFailed, match="outside the band"):
+            chip_smoke.check_rmse(outside, 1.0, full)
+    # a reduced data set: the band says nothing, the global mean does
+    chip_smoke.check_rmse(hi + 0.2, 1.0, tiny)
+    chip_smoke.check_rmse(lo - 0.2, 1.0, tiny)
+    with pytest.raises(chip_smoke.PhaseFailed, match="not 15% better"):
+        chip_smoke.check_rmse(0.9, 1.0, tiny)
+
+
 # -- parents stay off jax ----------------------------------------------------
 
 def test_parent_modules_import_no_jax():
     proc = _python("""
         import sys
-        import bench, chip_smoke
+        import chip_smoke
         import predictionio_tpu.tools.cli
         import predictionio_tpu.serving.fleet, predictionio_tpu.serving.router
         import predictionio_tpu.serving.event_server
         import predictionio_tpu.serving.storage_server
         import predictionio_tpu.workflow.variant
-        assert callable(bench.orchestrate)
         assert "jax" not in sys.modules, sorted(
             m for m in sys.modules if m.startswith("jax"))[:5]
     """)

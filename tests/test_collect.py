@@ -665,34 +665,6 @@ def test_dashboard_trace_view(memory_storage):
 
 
 # ---------------------------------------------------------------------------
-# bench + CI gate: federation keys are benchcmp-gated lower-better
-# ---------------------------------------------------------------------------
-
-def _bench_round(tmp_path, name, scrape_ms, stitch_ms):
-    path = tmp_path / name
-    path.write_text(json.dumps({"parsed": {
-        "metric": "m", "value": 1.0,
-        "key": {"fleet_scrape_ms": scrape_ms,
-                "trace_stitch_ms": stitch_ms},
-    }}))
-    return str(path)
-
-
-def test_benchcmp_gates_federation_keys(tmp_path, capsys):
-    from predictionio_tpu.tools import benchcmp
-
-    assert benchcmp.lower_is_better("key.fleet_scrape_ms")
-    assert benchcmp.lower_is_better("key.trace_stitch_ms")
-    base = _bench_round(tmp_path, "BENCH_r01.json", 10.0, 5.0)
-    worse = _bench_round(tmp_path, "BENCH_r02.json", 25.0, 5.0)
-    assert benchcmp.run([base, worse]) == 1  # regression -> exit 1
-    out = capsys.readouterr().out
-    assert "key.fleet_scrape_ms" in out and "REGRESSION" in out
-    better = _bench_round(tmp_path, "BENCH_r03.json", 8.0, 2.0)
-    assert benchcmp.run([base, better]) == 0
-
-
-# ---------------------------------------------------------------------------
 # ops-journal + anomaly federation
 # ---------------------------------------------------------------------------
 
@@ -773,9 +745,3 @@ def test_federate_anomaly_all_quiet():
     assert report["active"] == []
     assert report["members"][0]["active"] == 0
 
-
-def test_benchcmp_gates_sentinel_keys():
-    from predictionio_tpu.tools import benchcmp
-
-    assert benchcmp.lower_is_better("key.journal_append_us")
-    assert benchcmp.lower_is_better("key.anomaly_scan_ms")

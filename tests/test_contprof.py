@@ -718,29 +718,3 @@ def test_acceptance_tail_flame_joins_flight_slow_ring(memory_storage,
         assert "slow-cohort trace ids" in out
     assert not contprof.PROFILER.running()  # fleet teardown released
 
-
-# ---------------------------------------------------------------------------
-# bench + CI gate: prof overhead is a first-class lower-better key
-# ---------------------------------------------------------------------------
-
-def _bench_round(tmp_path, name, overhead_pct):
-    path = tmp_path / name
-    path.write_text(json.dumps({"parsed": {
-        "metric": "m", "value": 1.0,
-        "key": {"prof_overhead_pct": overhead_pct},
-    }}))
-    return str(path)
-
-
-def test_benchcmp_gates_prof_overhead_lower_better(tmp_path, capsys):
-    from predictionio_tpu.tools import benchcmp
-
-    assert benchcmp.lower_is_better("key.prof_overhead_pct")
-    assert not benchcmp.is_config_key("key.prof_overhead_pct")
-    base = _bench_round(tmp_path, "BENCH_r01.json", 0.5)
-    worse = _bench_round(tmp_path, "BENCH_r02.json", 3.0)
-    assert benchcmp.run([base, worse]) == 1  # regression -> exit 1
-    out = capsys.readouterr().out
-    assert "key.prof_overhead_pct" in out and "REGRESSION" in out
-    better = _bench_round(tmp_path, "BENCH_r03.json", 0.3)
-    assert benchcmp.run([base, better]) == 0

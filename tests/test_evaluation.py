@@ -214,7 +214,7 @@ def test_nan_score_never_wins_lower_is_better():
 
 
 # ---------------------------------------------------------------------------
-# Vmapped grid tuning through `pio eval` (VERDICT r3 item 5): when the
+# Vmapped grid tuning through `pio eval`: when the
 # candidates differ only in ALS reg, MetricEvaluator's candidates train
 # in ONE compiled dispatch per fold (ALSAlgorithm.grid_train), with
 # leaderboard/ranking/best.json identical to the sequential path.
@@ -276,7 +276,7 @@ class _RatingMSE(AverageMetric):
 
 def test_als_reg_grid_single_dispatch_matches_sequential(memory_storage):
     """6-point reg grid: one vmapped train dispatch per fold, identical
-    ranking to the sequential path (VERDICT r3 item 5 done-criterion)."""
+    ranking to the sequential path."""
     from predictionio_tpu.core.fast_eval import FastEvalEngineWorkflow
     from predictionio_tpu.parallel.mesh import MeshContext
 

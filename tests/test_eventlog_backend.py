@@ -567,7 +567,7 @@ def test_concurrent_appends_scans_and_compact(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Native JSON ingest lane (VERDICT r3 item 3): the event server's live
+# Native JSON ingest lane: the event server's live
 # lane without per-row Python objects — API-format JSON array bytes go
 # straight to C++ (parse + EventValidation + wire packing + append, GIL
 # released). Reference role: EventAPI's request pipeline

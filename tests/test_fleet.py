@@ -1,7 +1,7 @@
 """Serving fleet: replica supervisor, health-routed query router and
 the rolling zero-downtime hot-swap (serving/fleet.py,
 serving/router.py), plus the shared SIGTERM drain handler
-(serving/http.py) and the fleet keys' bench-compare gating.
+(serving/http.py).
 
 Chaos comes through the PR-6 seams: ``ThreadedReplica.kill()`` dies
 like a crashed process (listening socket closed abruptly), and the
@@ -614,7 +614,7 @@ def test_drain_handler_finishes_inflight_requests(memory_storage):
         server.stop()
 
 
-# -- dashboard + bench-compare satellites --------------------------------------
+# -- dashboard -------------------------------------------------------------
 
 def test_dashboard_fleet_panel(memory_storage):
     from predictionio_tpu.tools.dashboard import DashboardServer
@@ -634,30 +634,6 @@ def test_dashboard_fleet_panel(memory_storage):
         assert 'href="/fleet"' in body
     finally:
         dash.stop()
-
-
-def test_benchcmp_gates_serve_and_fleet_keys(tmp_path):
-    """key.serve_p99_ms and the fleet sweep keys are direction-aware:
-    a p99 increase is a REGRESSION (exit 1), qps is higher-better."""
-    import io
-
-    from predictionio_tpu.tools import benchcmp
-
-    assert benchcmp.lower_is_better("key.serve_p99_ms")
-    assert benchcmp.lower_is_better("key.fleet_srv_p99_ms_128conn")
-    assert not benchcmp.lower_is_better("key.fleet_qps_128conn")
-
-    for n, p99 in ((1, 10.0), (2, 20.0)):
-        (tmp_path / f"BENCH_r0{n}.json").write_text(json.dumps(
-            {"parsed": {"metric": "m", "value": 1.0,
-                        "key": {"serve_p99_ms": p99}}}))
-    out = io.StringIO()
-    rc = benchcmp.run([str(tmp_path / "BENCH_r01.json"),
-                       str(tmp_path / "BENCH_r02.json")],
-                      tolerance_pct=10.0, out=out)
-    assert rc == 1
-    assert "key.serve_p99_ms" in out.getvalue()
-    assert "REGRESSION" in out.getvalue()
 
 
 # -- tagged chaos --------------------------------------------------------------

@@ -13,14 +13,10 @@ The contract under test, per ISSUE 12's acceptance criteria:
     ``/reload`` (the ``event_to_servable`` contract extended to
     retrieval), and the index survives a ``/reload`` hot-swap;
   - the streaming recall probe exports ``pio_stream_index_recall`` and
-    counts floor breaches;
-  - bench/benchcmp treat ``retrieval_qps_recall95`` (higher-better)
-    and ``index_build_sec`` (lower-better) direction-aware.
+    counts floor breaches.
 """
 
-import importlib.util
 import json
-import os
 import pickle
 
 import numpy as np
@@ -505,7 +501,9 @@ class TestModelWiring:
 # ---------------------------------------------------------------------------
 
 @pytest.fixture()
-def served_world(tmp_path):
+def served_world(tmp_path, request):
+    """A trained recommendation engine behind a live server; a test
+    may pass extra ALS params (``indirect=True``)."""
     from predictionio_tpu.data.storage import set_storage
     from predictionio_tpu.serving.engine_server import EngineServer
 
@@ -518,7 +516,8 @@ def served_world(tmp_path):
     storage.events().init(app.id)
     _seed_world(storage, app.id, n_users=30, n_items=20, n_events=600)
     engine, instance = _train_reco(storage, engine_id="idx_e2e",
-                                   iterations=6)
+                                   iterations=6,
+                                   **getattr(request, "param", {}))
     server = EngineServer(engine, "idx_e2e", host="127.0.0.1", port=0,
                           storage=storage, micro_batch=False).start()
     try:
@@ -561,14 +560,16 @@ class TestServingEndToEnd:
         user_q = self._query(server, {"user": "u1", "num": 21})
         assert len(user_q["itemScores"]) == 21   # 20 trained + patched
 
+    @pytest.mark.parametrize(
+        "served_world", [{"index_kernel": "on"}],   # interpret on CPU
+        indirect=True, ids=["index_kernel_on"])
     def test_status_page_shows_the_kernels_merged_tile_count(
-            self, monkeypatch, request):
+            self, served_world):
         """``GET /`` -> ``retrieval[].kernel``: how many tiles the last
         kernel search had and how many it merged."""
         import urllib.request
 
-        monkeypatch.setenv("PIO_INDEX_KERNEL", "on")   # interpret on CPU
-        _, _, server = request.getfixturevalue("served_world")
+        _, _, server = served_world
         self._query(server, {"user": "u1", "num": 5})
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{server.port}/", timeout=30) as resp:
@@ -654,58 +655,3 @@ class TestStreamRecallProbe:
             assert breaches.value == before + 1
         finally:
             set_storage(None)
-
-
-# ---------------------------------------------------------------------------
-# bench / benchcmp gates
-# ---------------------------------------------------------------------------
-
-class TestBenchGates:
-    def test_benchcmp_directions(self):
-        from predictionio_tpu.tools import benchcmp
-
-        assert benchcmp.lower_is_better("key.index_build_sec")
-        assert not benchcmp.lower_is_better("key.retrieval_qps_recall95")
-        assert not benchcmp.lower_is_better("key.stream_index_recall")
-
-    def test_benchcmp_gates_retrieval_regression(self, tmp_path):
-        from predictionio_tpu.tools import benchcmp
-
-        def round_file(name, qps, build):
-            doc = {"parsed": {
-                "metric": "m", "value": 1.0,
-                "key": {"retrieval_qps_recall95": qps,
-                        "index_build_sec": build}}}
-            path = tmp_path / name
-            path.write_text(json.dumps(doc))
-            return str(path)
-
-        files = [round_file("BENCH_r01.json", 1000.0, 2.0),
-                 round_file("BENCH_r02.json", 500.0, 2.0)]   # qps halved
-        import io
-
-        out = io.StringIO()
-        assert benchcmp.run(files, tolerance_pct=10.0, out=out) == 1
-        assert "retrieval_qps_recall95" in out.getvalue()
-        # build time doubling is a regression too (lower-better)
-        files = [round_file("BENCH_r03.json", 1000.0, 2.0),
-                 round_file("BENCH_r04.json", 1000.0, 5.0)]
-        assert benchcmp.run(files, tolerance_pct=10.0,
-                            out=io.StringIO()) == 1
-
-    def test_emit_headline_carries_retrieval_keys(self, tmp_path):
-        spec = importlib.util.spec_from_file_location(
-            "bench_mod", os.path.join(os.path.dirname(__file__), "..",
-                                      "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        detail = {
-            "rmse_gate_passed": True, "rmse_band_passed": True,
-            "serve_gate_passed": True, "serve_32_gate_passed": True,
-            "row_lane_gate_passed": True, "updates_per_sec": 1.0,
-            "retrieval_qps_recall95": 1234.5, "index_build_sec": 0.7,
-        }
-        line = bench.emit_headline(
-            detail, detail_path=str(tmp_path / "d.json"))
-        assert line["key"]["retrieval_qps_recall95"] == 1234.5
-        assert line["key"]["index_build_sec"] == 0.7

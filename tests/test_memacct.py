@@ -1,7 +1,6 @@
 """Device-memory accounting plane (obs/memacct.py): the per-model HBM
 ledger, train high-water tracking, the OOM preflight, and their
-surfaces (/admin/memory, pio mem, dashboard /memory, timeline,
-benchcmp keys).
+surfaces (/admin/memory, pio mem, dashboard /memory, timeline).
 
 Acceptance pinned here:
   - on CPU with the host-memory capacity steered by the test, GET /admin/memory attribution
@@ -540,49 +539,3 @@ def test_jaxmon_delegate_still_answers():
     from predictionio_tpu.obs import jaxmon
 
     assert jaxmon.update_device_memory_gauges() >= 0
-
-
-# -- benchcmp keys -------------------------------------------------------------
-
-class TestMemBenchKeys:
-    @staticmethod
-    def _round(tmp_path, name, hbm, peak):
-        doc = {"parsed": {
-            "metric": "als_ml20m_rating_updates_per_sec_per_chip",
-            "value": 6.0e7,
-            "key": {"model_hbm_bytes": hbm,
-                    "train_peak_bytes": peak}}}
-        p = tmp_path / name
-        p.write_text(json.dumps(doc))
-        return str(p)
-
-    def test_direction_inference(self):
-        from predictionio_tpu.tools import benchcmp
-
-        assert benchcmp.lower_is_better("key.model_hbm_bytes")
-        assert benchcmp.lower_is_better("key.train_peak_bytes")
-
-    def test_hbm_regression_exits_1(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 1.0e9, 2.0e9),
-                 self._round(tmp_path, "BENCH_r02.json", 1.6e9, 2.0e9)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 1
-        out = capsys.readouterr().out
-        assert "key.model_hbm_bytes" in out and "REGRESSION" in out
-
-    def test_train_peak_regression_exits_1(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 1.0e9, 2.0e9),
-                 self._round(tmp_path, "BENCH_r02.json", 1.0e9, 3.0e9)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 1
-        assert "key.train_peak_bytes" in capsys.readouterr().out
-
-    def test_shrinking_is_an_improvement(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 2.0e9, 3.0e9),
-                 self._round(tmp_path, "BENCH_r02.json", 1.0e9, 2.0e9)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 0
-        assert "IMPROVED" in capsys.readouterr().out

@@ -1,4 +1,4 @@
-"""Real 2-process jax.distributed exercise (VERDICT r1 item 4).
+"""Real 2-process jax.distributed exercise.
 
 Two CPU subprocesses (coordinator on localhost, 2 forced local devices
 each -> 4 global) run initialize_from_env, assemble a global array from

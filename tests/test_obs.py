@@ -559,7 +559,6 @@ def test_serving_stats_reports_from_shared_histogram():
     # bucket-interpolated percentiles from the SAME series /metrics shows
     assert 0.0005 <= snap["p50ServingSec"] <= 0.0025
     assert 0.1 <= snap["p99ServingSec"] <= 0.25
-    assert st.recent(3) == [0.2, 0.2, 0.2]
     child = _SERVING_SECONDS.labels("obs-hist-engine")
     assert child.count == 100
     # a second ServingStats for the same engine (a fleet replica, or a

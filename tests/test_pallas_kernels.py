@@ -198,18 +198,6 @@ def test_trainer_kernel_plan_forced_on_engages_interpret():
     assert tr.kernel_plan["embed_update"] is True
 
 
-def test_trainer_kernel_plan_env_overrides_config(monkeypatch):
-    """The bench A/B switch: env beats the config flag."""
-    monkeypatch.setenv("PIO_TT_FLASH_CE", "off")
-    monkeypatch.setenv("PIO_TT_EMBED_UPDATE", "off")
-    u, i, n_users, n_items = _positives()
-    cfg = TwoTowerConfig(dim=8, epochs=1, batch_size=256, seed=3,
-                         flash_ce_kernel="on", embed_update_kernel="on")
-    tr = TwoTowerTrainer((u, i, None), n_users, n_items, cfg)
-    assert tr.kernel_plan["flash_ce"] is False
-    assert tr.kernel_plan["embed_update"] is False
-
-
 def test_trainer_kernel_plan_ineligible_falls_back():
     """Multi-device mesh and small batches fall back with a reason —
     never an error (pallas_call does not partition under a mesh)."""

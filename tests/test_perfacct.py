@@ -141,8 +141,8 @@ def test_accountant_empty_cost_analysis_also_falls_back():
 
 
 def test_twotower_matmul_flops_matches_trainer_method():
-    """The one-formula contract: the trainer's bench hook delegates to
-    the shared perfacct formula (bench.py divides the same number)."""
+    """The one-formula contract: the trainer's method delegates to
+    the shared perfacct formula."""
     from predictionio_tpu.ops.twotower import (
         TwoTowerConfig,
         TwoTowerTrainer,
@@ -571,45 +571,3 @@ def test_pio_top_json_requires_once():
     from predictionio_tpu.tools.cli import main
 
     assert main(["top", "--json"]) == 1
-
-
-# ---------------------------------------------------------------------------
-# benchcmp: key.* metrics join the direction-aware gate set
-# ---------------------------------------------------------------------------
-
-def test_benchcmp_extracts_headline_key_block(tmp_path):
-    from predictionio_tpu.tools import benchcmp
-
-    doc = {"parsed": {"metric": "m", "value": 1.0,
-                      "key": {"twotower_mfu": 0.042,
-                              "serve_32_srv_p99_ms": 23.95,
-                              "rmse_heldout": 0.427,
-                              "detail_note": "not-a-number"}}}
-    path = tmp_path / "BENCH_r09.json"
-    path.write_text(json.dumps(doc))
-    got = benchcmp.load_metrics(str(path))
-    assert got["key.twotower_mfu"] == 0.042
-    assert got["key.serve_32_srv_p99_ms"] == 23.95
-    assert "key.detail_note" not in got
-    # direction awareness: mfu regresses DOWN, p99/rmse regress UP
-    assert not benchcmp.lower_is_better("key.twotower_mfu")
-    assert benchcmp.lower_is_better("key.serve_32_srv_p99_ms")
-    assert benchcmp.lower_is_better("key.rmse_heldout")
-
-
-def test_benchcmp_flags_mfu_regression(tmp_path):
-    import io
-
-    from predictionio_tpu.tools import benchcmp
-
-    for n, mfu_val in ((1, 0.10), (2, 0.04)):
-        (tmp_path / f"BENCH_r0{n}.json").write_text(json.dumps(
-            {"parsed": {"metric": "m", "value": 1.0,
-                        "key": {"twotower_mfu": mfu_val}}}))
-    out = io.StringIO()
-    rc = benchcmp.run([str(tmp_path / "BENCH_r01.json"),
-                       str(tmp_path / "BENCH_r02.json")],
-                      tolerance_pct=10.0, out=out)
-    assert rc == 1
-    assert "key.twotower_mfu" in out.getvalue()
-    assert "REGRESSION" in out.getvalue()

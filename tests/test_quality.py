@@ -527,57 +527,6 @@ class TestFoldQualityProbe:
 
 
 # ---------------------------------------------------------------------------
-# bench-compare: quality keys are direction-aware
-# ---------------------------------------------------------------------------
-
-class TestQualityBenchKeys:
-    @staticmethod
-    def _round(tmp_path, name, recall, verdict_ms):
-        doc = {"parsed": {
-            "metric": "als_ml20m_rating_updates_per_sec_per_chip",
-            "value": 6.0e7,
-            "key": {"quality_recall_vs_retrain": recall,
-                    "canary_verdict_ms": verdict_ms}}}
-        p = tmp_path / name
-        p.write_text(json.dumps(doc))
-        return str(p)
-
-    def test_direction_inference(self):
-        from predictionio_tpu.tools import benchcmp
-
-        assert not benchcmp.lower_is_better("key.quality_recall_vs_retrain")
-        assert benchcmp.lower_is_better("key.canary_verdict_ms")
-        assert benchcmp.lower_is_better("key.quality_rmse_drift")
-        assert not benchcmp.lower_is_better("key.replay_mean_overlap")
-
-    def test_quality_regression_exits_1(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 0.99, 2.0),
-                 self._round(tmp_path, "BENCH_r02.json", 0.70, 2.0)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 1
-        out = capsys.readouterr().out
-        assert "key.quality_recall_vs_retrain" in out
-        assert "REGRESSION" in out
-
-    def test_verdict_cost_regression_exits_1(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 0.99, 2.0),
-                 self._round(tmp_path, "BENCH_r02.json", 0.99, 9.0)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 1
-        assert "key.canary_verdict_ms" in capsys.readouterr().out
-
-    def test_improvement_passes(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 0.80, 9.0),
-                 self._round(tmp_path, "BENCH_r02.json", 0.99, 2.0)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 0
-        assert "IMPROVED" in capsys.readouterr().out
-
-
-# ---------------------------------------------------------------------------
 # dashboard /quality panel
 # ---------------------------------------------------------------------------
 

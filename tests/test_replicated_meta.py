@@ -1,4 +1,4 @@
-"""Replicated METADATA / MODELDATA (VERDICT r3 item 1) + tier-resolved
+"""Replicated METADATA / MODELDATA + tier-resolved
 `pio status` exit codes (item 9).
 
 The reference's metadata tier survives machine loss because
@@ -131,7 +131,7 @@ def test_reads_survive_metadata_home_death_writes_fail_loudly(
 def test_engine_server_reload_survives_metadata_home_death(three_replicated):
     """A serving host must be able to /reload after the metadata home
     dies: get_latest_completed + the model blob both answer from the
-    surviving replica (the done-criterion of VERDICT r3 item 1)."""
+    surviving replica."""
     from tests.test_servers import http, train_const
     from predictionio_tpu.serving.engine_server import EngineServer
 
@@ -246,7 +246,7 @@ def test_storagerepair_cli_covers_both_tiers(three_replicated, capsys):
 
 def test_status_exit_codes_distinguish_tiers(three_replicated):
     """0 = all endpoints up; 2 = degraded but every tier serving;
-    1 = some tier cannot answer (VERDICT r3 item 9)."""
+    1 = some tier cannot answer."""
     from predictionio_tpu.tools.cli import STATUS_DEGRADED, main as cli_main
 
     backends, servers, client = three_replicated

@@ -616,8 +616,9 @@ def test_webhook_fires_on_alert_transitions():
         assert _wait_for(lambda: len(mine()) >= 2)
         assert mine()[-1]["state"] == "resolved"
         assert len(mine()) == 2  # one per TRANSITION, not per tick
+        # the sink has the page before the sender counts its answer
         family = metrics.REGISTRY.get("pio_alert_webhook_total")
-        assert family.labels("ok").value >= 2
+        assert _wait_for(lambda: family.labels("ok").value >= 2)
     finally:
         slo.remove_alert_listener(hook.on_transition)
         hook.stop()

@@ -207,7 +207,7 @@ def test_status_verifies_rest_repos(rest_storage):
 
 
 # ---------------------------------------------------------------------------
-# Cross-host: train on host A, deploy on host B (VERDICT r1 item 3)
+# Cross-host: train on host A, deploy on host B
 # ---------------------------------------------------------------------------
 
 _TRAIN_A = """
@@ -329,8 +329,8 @@ def test_train_on_host_a_deploy_on_host_b(tmp_path):
 
 def test_two_writers_share_one_logical_eventdata(rest_storage):
     """Two rest clients (distinct client objects, same server) see one
-    consistent event store — the multi-host EVENTDATA story (VERDICT r1
-    item 5 option a; ref: HBEventsUtil.scala:47 shared HBase tables)."""
+    consistent event store — the multi-host EVENTDATA story (ref:
+    HBEventsUtil.scala:47 shared HBase tables)."""
     _, client_a = rest_storage
     server_port = client_a.client_for("EVENTDATA").config["PORTS"]
     client_b = _client_storage(int(server_port))
@@ -499,8 +499,8 @@ def test_compact_over_rest(tmp_path):
 
 def test_scan_fetch_resumes_after_connection_drop(rest_storage, monkeypatch):
     """A connection that dies mid-transfer of a bulk scan must resume
-    from the last received byte (offset fetch), not restart or fail —
-    VERDICT r2 item 5 (HBase client retry role)."""
+    from the last received byte (offset fetch), not restart or fail
+    (HBase client retry role)."""
     import urllib.request as _ur
 
     _, client = rest_storage
@@ -557,8 +557,7 @@ def test_scan_fetch_resumes_after_connection_drop(rest_storage, monkeypatch):
 def test_scan_survives_server_restart_mid_scan(tmp_path):
     """Kill the storage server after the scan was prepared but before
     the fetch, restart it (fresh scan registry), and the client must
-    complete correctly by re-preparing — VERDICT r2 item 5 'kill the
-    server mid-scan, restarts it, client completes correctly'."""
+    complete correctly by re-preparing."""
     from predictionio_tpu.data.backends.rest import RestEventStore
 
     server_storage = make_memory_storage()

@@ -626,7 +626,7 @@ def test_batch_events_malformed_body(event_server):
 
 
 def test_saturating_load_batches_form_and_p99_bounded(memory_storage):
-    """VERDICT r3 item 6: 32 concurrent keep-alive connections through
+    """32 concurrent keep-alive connections through
     /queries.json — no errors, bounded tail latency, and the
     MicroBatcher histogram (in / status JSON) proves batches > 1
     actually form under load."""
@@ -688,8 +688,8 @@ def test_saturating_load_batches_form_and_p99_bounded(memory_storage):
         assert not errs, errs[0]
         flat = sorted(x for ls in lat for x in ls)
         p99 = flat[int(len(flat) * 0.99)]
-        # generous absolute bound for CI boxes; the REAL perf claim is
-        # measured by bench.py on the bench host (p99 < 25 ms gate)
+        # generous absolute bound for CI boxes; latency on the chip is
+        # the benchmark's to measure (BENCHMARK.json, serve-c32)
         assert p99 < 2.0, f"p99 {p99 * 1e3:.1f} ms under 32-conn load"
 
         # the histogram is served in the status JSON and shows real
@@ -702,7 +702,7 @@ def test_saturating_load_batches_form_and_p99_bounded(memory_storage):
         batched = sum(v for k, v in hist.items() if int(k) > 1)
         assert batched > 0, hist
 
-        # the queue-wait vs dispatch split (VERDICT r4 item 5): every
+        # the queue-wait vs dispatch split: every
         # answered request leaves a (wait, dispatch) pair whose parts
         # are sane — dispatch covers the ~1.5ms sleep, and the recorded
         # count covers the full offered load

@@ -1,4 +1,4 @@
-"""Entity-hash sharded columnar reads (VERDICT r2 item 1/4 substrate).
+"""Entity-hash sharded columnar reads.
 
 The reference's bulk read path is region-parallel: each Spark executor
 scans only its HBase region slice (hbase/HBPEvents.scala:48), with

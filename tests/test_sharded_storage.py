@@ -1,4 +1,4 @@
-"""EVENTDATA sharded across N storage servers (VERDICT r2 item 4).
+"""EVENTDATA sharded across N storage servers.
 
 The reference's event store scales horizontally because HBase splits
 tables into regions by the MD5 rowkey prefix and spreads them across

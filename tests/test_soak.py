@@ -1,4 +1,4 @@
-"""Hours-shaped behavior in minutes form (VERDICT r4 item 8): the
+"""Hours-shaped behavior in minutes form: the
 event server, engine server and storage server under CONTINUOUS mixed
 load — ingest + queries + reads + periodic hot /reload + scan spools —
 asserting what only time surfaces: flat RSS (no leak), the scan-spool

@@ -144,7 +144,7 @@ class TestDeltaReads:
 # ALS fold-in equivalence + freshness
 # ---------------------------------------------------------------------------
 
-def _train_reco(storage, engine_id="stream_eq", iterations=15):
+def _train_reco(storage, engine_id="stream_eq", iterations=15, **als_params):
     from predictionio_tpu.templates.recommendation import (
         recommendation_engine)
     from predictionio_tpu.workflow.train import run_train
@@ -155,7 +155,7 @@ def _train_reco(storage, engine_id="stream_eq", iterations=15):
         "algorithms": [{"name": "als", "params": {
             "rank": 8, "num_iterations": iterations, "lambda_": 0.1,
             "compute_dtype": "float32", "cg_dtype": "float32",
-            "cg_iters": 12}}],
+            "cg_iters": 12, **als_params}}],
     })
     instance = run_train(engine, ep, engine_id=engine_id, storage=storage)
     assert instance.status == "COMPLETED"
@@ -638,51 +638,3 @@ class TestHedgeRescueCredit:
         latency = [s for s in slo.default_slos()
                    if s.name == "serving-latency"][0]
         assert latency.good_credit_metric == "pio_router_hedge_rescues_total"
-
-
-# ---------------------------------------------------------------------------
-# bench-compare: streaming keys are direction-aware
-# ---------------------------------------------------------------------------
-
-class TestStreamBenchKeys:
-    @staticmethod
-    def _round(tmp_path, name, e2s_ms, foldin_eps):
-        doc = {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "(fx)",
-               "parsed": {
-                   "metric": "als_ml20m_rating_updates_per_sec_per_chip",
-                   "value": 6.0e7, "unit": "ratings*iters/sec",
-                   "key": {"event_to_servable_ms": e2s_ms,
-                           "foldin_events_per_sec": foldin_eps}}}
-        p = tmp_path / name
-        p.write_text(json.dumps(doc))
-        return str(p)
-
-    def test_direction_inference(self):
-        from predictionio_tpu.tools import benchcmp
-
-        assert benchcmp.lower_is_better("key.event_to_servable_ms")
-        assert not benchcmp.lower_is_better("key.foldin_events_per_sec")
-
-    def test_freshness_regression_fails_compare(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 420.0, 5000.0),
-                 self._round(tmp_path, "BENCH_r02.json", 900.0, 5100.0)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 1
-        assert "key.event_to_servable_ms" in capsys.readouterr().out
-
-    def test_foldin_throughput_drop_fails_compare(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 420.0, 5000.0),
-                 self._round(tmp_path, "BENCH_r02.json", 410.0, 2000.0)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 1
-        assert "key.foldin_events_per_sec" in capsys.readouterr().out
-
-    def test_improvement_passes(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        files = [self._round(tmp_path, "BENCH_r01.json", 900.0, 2000.0),
-                 self._round(tmp_path, "BENCH_r02.json", 420.0, 5000.0)]
-        assert benchcmp.run(files, tolerance_pct=10.0) == 0
-        assert "IMPROVED" in capsys.readouterr().out

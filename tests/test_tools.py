@@ -9,7 +9,6 @@ import datetime as dt
 import json
 import urllib.error
 import urllib.request
-from pathlib import Path
 
 import pytest
 
@@ -478,160 +477,6 @@ class TestColumnarImport:
         assert got["u1"].target_entity_id == "i1"
         assert got["u2"].target_entity_id is None
         assert got["u2"].target_entity_type is None
-
-
-class TestBenchCompare:
-    """`pio bench-compare` over the checked-in fixture trajectory
-    (tests/data/bench): per-metric deltas, direction-aware verdicts,
-    exit codes."""
-
-    FIXTURES = sorted(
-        str(p) for p in
-        (Path(__file__).parent / "data" / "bench").glob("BENCH_r*.json"))
-
-    def test_load_metrics_extracts_headline_and_detail(self):
-        from predictionio_tpu.tools import benchcmp
-
-        got = benchcmp.load_metrics(self.FIXTURES[0])
-        assert got["als_ml20m_rating_updates_per_sec_per_chip"] == 60000000.0
-        assert got["detail.serve_p50_ms"] == 1.0
-        assert got["detail.n_users"] == 138000
-
-    def test_direction_inference(self):
-        from predictionio_tpu.tools import benchcmp
-
-        assert benchcmp.lower_is_better("detail.serve_p50_ms")
-        assert benchcmp.lower_is_better("detail.elapsed_sec")
-        assert not benchcmp.lower_is_better("detail.serve_qps")
-        assert not benchcmp.lower_is_better(
-            "als_ml20m_rating_updates_per_sec_per_chip")
-
-    def test_within_tolerance_passes(self, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        # r01 -> r02: every delta is under 10%
-        rc = benchcmp.run(self.FIXTURES[:2], tolerance_pct=10.0)
-        assert rc == 0
-        out = capsys.readouterr().out
-        assert "no regressions beyond tolerance" in out
-
-    def test_regression_beyond_tolerance_fails(self, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        # r02 -> r03: throughput -28%, latency +47%/+40%
-        rc = benchcmp.run(self.FIXTURES, tolerance_pct=10.0)
-        assert rc == 1
-        out = capsys.readouterr().out
-        assert "REGRESSION" in out
-        assert "als_ml20m_rating_updates_per_sec_per_chip" in out
-        assert "detail.serve_p50_ms" in out
-        # qps went UP 4%: within tolerance, not printed as a verdict
-        assert "detail.serve_qps:" not in out
-
-    def test_improvement_is_reported_not_failed(self, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        # reversed trajectory: r03 -> r02 is an improvement
-        rc = benchcmp.run([self.FIXTURES[2], self.FIXTURES[1]],
-                          tolerance_pct=10.0)
-        assert rc == 0
-        assert "IMPROVED" in capsys.readouterr().out
-
-    def test_config_change_is_flagged_but_not_a_regression(self, tmp_path,
-                                                           capsys):
-        import json as _json
-
-        from predictionio_tpu.tools import benchcmp
-
-        doc = _json.loads(Path(self.FIXTURES[1]).read_text())
-        doc["parsed"]["detail"]["rank"] = 128
-        changed = tmp_path / "BENCH_r99.json"
-        changed.write_text(_json.dumps(doc))
-        rc = benchcmp.run([self.FIXTURES[0], str(changed)],
-                          tolerance_pct=10.0)
-        assert rc == 0
-        assert "CONFIG-CHANGED" in capsys.readouterr().out
-
-    def test_needs_two_files(self, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        assert benchcmp.run(self.FIXTURES[:1]) == 2
-
-    def test_cli_entrypoint(self, capsys):
-        from predictionio_tpu.tools.cli import main
-
-        rc = main(["bench-compare", "--tolerance", "10",
-                   *self.FIXTURES[1:]])
-        assert rc == 1
-        assert "bench-compare:" in capsys.readouterr().out
-
-    def test_rounds_without_metrics_are_skipped(self, tmp_path, capsys):
-        # a round whose headline failed to parse (empty `parsed`, like
-        # the real BENCH_r04.json) must not become the baseline
-        import json as _json
-
-        from predictionio_tpu.tools import benchcmp
-
-        empty = tmp_path / "BENCH_r98.json"
-        empty.write_text(_json.dumps({"n": 98, "parsed": {}}))
-        rc = benchcmp.run([self.FIXTURES[0], str(empty),
-                           self.FIXTURES[1]], tolerance_pct=10.0)
-        assert rc == 0
-        out = capsys.readouterr().out
-        assert "BENCH_r98.json has no extractable metrics" in out
-        assert "BENCH_r02.json vs BENCH_r01.json" in out
-
-    @staticmethod
-    def _sentinel_round(tmp_path, name, append_us, scan_ms):
-        import json as _json
-
-        doc = {"n": 1, "cmd": "python bench.py", "rc": 0,
-               "tail": "(fixture)",
-               "parsed": {"metric": "m", "value": 1.0,
-                          "key": {"journal_append_us": append_us,
-                                  "anomaly_scan_ms": scan_ms}}}
-        path = tmp_path / name
-        path.write_text(_json.dumps(doc))
-        return str(path)
-
-    def test_sentinel_keys_gated_lower_better(self):
-        from predictionio_tpu.tools import benchcmp
-
-        assert benchcmp.lower_is_better("key.journal_append_us")
-        assert benchcmp.lower_is_better("key.anomaly_scan_ms")
-
-    def test_journal_append_regression_exits_1(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        base = self._sentinel_round(tmp_path, "BENCH_r01.json", 8.0, 14.0)
-        slow = self._sentinel_round(tmp_path, "BENCH_r02.json", 20.0, 14.5)
-        rc = benchcmp.run([base, slow], tolerance_pct=10.0)
-        assert rc == 1
-        out = capsys.readouterr().out
-        assert "key.journal_append_us" in out
-        assert "REGRESSION" in out
-        # scan drifted +3.6%: inside tolerance, no verdict printed
-        assert "key.anomaly_scan_ms:" not in out
-
-    def test_anomaly_scan_regression_exits_1(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        base = self._sentinel_round(tmp_path, "BENCH_r01.json", 8.0, 14.0)
-        slow = self._sentinel_round(tmp_path, "BENCH_r02.json", 8.1, 40.0)
-        rc = benchcmp.run([base, slow], tolerance_pct=10.0)
-        assert rc == 1
-        out = capsys.readouterr().out
-        assert "key.anomaly_scan_ms" in out
-        assert "REGRESSION" in out
-
-    def test_sentinel_keys_dropping_is_improvement(self, tmp_path, capsys):
-        from predictionio_tpu.tools import benchcmp
-
-        base = self._sentinel_round(tmp_path, "BENCH_r01.json", 8.0, 14.0)
-        fast = self._sentinel_round(tmp_path, "BENCH_r02.json", 4.0, 7.0)
-        rc = benchcmp.run([base, fast], tolerance_pct=10.0)
-        assert rc == 0
-        assert "IMPROVED" in capsys.readouterr().out
 
 
 class TestJournalCLI:
