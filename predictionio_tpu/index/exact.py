@@ -74,6 +74,10 @@ class ExactIndex(AnnIndex):
         #: Shared with every TopKScorer this index builds, so the
         #: counts survive the scorer being dropped on build/upsert
         self.routes = {"kernel": 0, "device": 0, "host": 0}
+        #: where each search's query vectors were when they arrived:
+        #: host data rides the one compiled call, a ``jax.Array`` (a
+        #: program's output) stays on the device
+        self.inputs = {"host": 0, "device": 0}
 
     # -- build / upsert -------------------------------------------------------
     def build(self, item_vectors: np.ndarray) -> None:
@@ -208,14 +212,19 @@ class ExactIndex(AnnIndex):
                ) -> Tuple[np.ndarray, np.ndarray]:
         self._note_query()
         self.searches += 1
+        from predictionio_tpu.ops.topk import (_batch_rows, _fetch,
+                                               _inputs_kind,
+                                               _prepare_score_inputs)
+
         if len(self) == 0:
-            B = np.atleast_2d(np.asarray(query_vecs)).shape[0]
+            B = _batch_rows(query_vecs)
             return (np.zeros((B, 0), np.float32),
                     np.zeros((B, 0), np.int32))
-        from predictionio_tpu.ops.topk import _prepare_score_inputs
-
-        # enqueue: pad, transfer, the jitted call returning; fetch: the
-        # wait for the device and the copy back
+        inputs = _inputs_kind(query_vecs)
+        self.inputs[inputs] += 1
+        # enqueue: pad in numpy, the jitted call (it carries the
+        # transfer of host inputs) returning; fetch: the one wait for
+        # the device and the copies back
         with trace.device_span("index.search"):
             with trace.device_span("index.enqueue"):
                 q2, excl, k_eff, k_bucket, B = _prepare_score_inputs(
@@ -234,11 +243,11 @@ class ExactIndex(AnnIndex):
             # a span's attributes are set as it opens, and the route is
             # known only once the inputs are bucketed: it rides on a
             # marker (the fallback writes its own, ops/topk.py)
-            with trace.device_span("index.route", route="kernel", rows=B):
+            with trace.device_span("index.route", route="kernel", rows=B,
+                                   inputs=inputs):
                 pass
             with trace.device_span("index.fetch"):
-                return (np.asarray(scores)[:B, :k_eff],
-                        np.asarray(idx)[:B, :k_eff])
+                return _fetch(scores, idx, B, k_eff)
 
     def stats(self) -> Dict[str, object]:
         out = super().stats()
@@ -255,6 +264,7 @@ class ExactIndex(AnnIndex):
             "routes": {"kernel": self.routes["kernel"],
                        "xla_device": self.routes["device"],
                        "host": self.routes["host"]},
+            "inputs": dict(self.inputs),
             "max_exclude": self.max_exclude,
         })
         return out

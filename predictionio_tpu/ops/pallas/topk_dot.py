@@ -225,7 +225,9 @@ def make_topk_dot(n_items, D, B, k, n_excl, *, block_items=None,
     and excluded entries never enter, a slot nothing filled comes back
     as ``NEG_INF`` score / -1 index like the XLA scorer's masked
     entries. ``merged`` counts the tiles (of ``fn.tiles``) that were
-    merged. ``k`` must be <= ``n_items`` (the caller buckets).
+    merged. ``k`` must be <= ``n_items`` (the caller buckets). ``q``
+    and ``excl`` may be host (numpy) arrays: their transfer is then part
+    of this one call, and the ``D -> Dp`` pad runs inside it.
     ``block_items`` overrides :func:`tile_items` for the tests."""
     bi = int(block_items or tile_items(D, n_items, B))
     Dp, Ip = table_shape(D, n_items, block_items)

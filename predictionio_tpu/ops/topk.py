@@ -116,6 +116,45 @@ def _pow2_bucket(n: int, lo: int, hi: int) -> int:
     return b
 
 
+def _batch_rows(vecs) -> int:
+    """Rows of a ``[D]`` or ``[B, D]`` batch, read from its shape alone
+    (a device array is not copied to the host to be counted)."""
+    shape = np.shape(vecs)
+    return shape[0] if len(shape) > 1 else 1
+
+
+def _inputs_kind(vecs) -> str:
+    """``device`` for a ``jax.Array`` (a program's output, which stays
+    where it is), ``host`` for everything else: what the
+    ``pio:index.route`` marker and ``ExactIndex.stats()`` report."""
+    return "device" if isinstance(vecs, jax.Array) else "host"
+
+
+def _pad_rows(vecs, b_bucket: int):
+    """``vecs`` as float32 ``[b_bucket, D]``, zero rows added. Host data
+    is padded in numpy and comes back numpy: it crosses to the device
+    inside the compiled call it is handed to, and no eager program runs
+    for it. A ``jax.Array`` stays on the device; a program's output
+    already has a bucketed shape, and one that does not is padded
+    there."""
+    if isinstance(vecs, jax.Array):
+        vecs = jnp.asarray(vecs, dtype=jnp.float32)
+        if vecs.ndim < 2:
+            vecs = vecs[None, :]
+        B = vecs.shape[0]
+        if B < b_bucket:
+            vecs = jnp.concatenate(
+                [vecs, jnp.zeros((b_bucket - B, vecs.shape[1]), vecs.dtype)])
+        return vecs
+    vecs = np.atleast_2d(np.asarray(vecs, dtype=np.float32))
+    B = vecs.shape[0]
+    if B < b_bucket:
+        padded = np.zeros((b_bucket, vecs.shape[1]), np.float32)
+        padded[:B] = vecs
+        vecs = padded
+    return vecs
+
+
 def _prepare_score_inputs(user_vecs, k: int, exclude_idx, n_items: int,
                           max_exclude: int):
     """Shared serve-path shape discipline for the scorers: bucket the
@@ -123,33 +162,37 @@ def _prepare_score_inputs(user_vecs, k: int, exclude_idx, n_items: int,
     produces arbitrary batch sizes, and every novel B would otherwise
     compile a fresh program), default/broadcast/bucket the exclusion
     lists (capped at ``max_exclude``, oldest dropped first), bucket k to
-    powers of two. Returns (user_vecs [B_bucket, K],
-    exclude [B_bucket, E_bucket], k, k_bucket, true_batch)."""
-    user_vecs = jnp.atleast_2d(jnp.asarray(user_vecs, dtype=jnp.float32))
-    B = user_vecs.shape[0]
-    if exclude_idx is None:
-        exclude_idx = np.full((B, 1), -1, dtype=np.int32)
-    exclude_idx = np.asarray(exclude_idx, dtype=np.int32)
-    if exclude_idx.ndim == 1:
-        exclude_idx = np.broadcast_to(exclude_idx, (B, exclude_idx.shape[0]))
-    exclude_idx = exclude_idx[:, -max_exclude:]
-    e_bucket = _pow2_bucket(exclude_idx.shape[1], 1, max_exclude)
-    if exclude_idx.shape[1] < e_bucket:
-        pad = np.full((B, e_bucket - exclude_idx.shape[1]), -1, dtype=np.int32)
-        exclude_idx = np.concatenate([exclude_idx, pad], axis=1)
+    powers of two. All of it is numpy: the exclusions always, the
+    vectors when they arrive as host data (:func:`_pad_rows`), so the
+    caller's one compiled call carries the transfer. Returns
+    (user_vecs [B_bucket, K], exclude [B_bucket, E_bucket] int32 numpy,
+    k, k_bucket, true_batch)."""
+    B = _batch_rows(user_vecs)
     b_bucket = _pow2_bucket(B, 1, 1 << 30)
-    if B < b_bucket:
-        user_vecs = jnp.concatenate(
-            [user_vecs,
-             jnp.zeros((b_bucket - B, user_vecs.shape[1]), user_vecs.dtype)]
-        )
-        exclude_idx = np.concatenate(
-            [exclude_idx,
-             np.full((b_bucket - B, exclude_idx.shape[1]), -1, np.int32)]
-        )
+    user_vecs = _pad_rows(user_vecs, b_bucket)
+    if exclude_idx is None:
+        width, e_bucket = 0, 1
+    else:
+        exclude_idx = np.asarray(exclude_idx, dtype=np.int32)
+        if exclude_idx.ndim == 1:
+            exclude_idx = exclude_idx[None, :]     # one list for every row
+        exclude_idx = exclude_idx[:, -max_exclude:]
+        width = exclude_idx.shape[1]
+        e_bucket = _pow2_bucket(width, 1, max_exclude)
+    excl = np.full((b_bucket, e_bucket), -1, dtype=np.int32)
+    if width:
+        excl[:B, :width] = exclude_idx
     k = min(k, n_items)
     k_bucket = min(_pow2_bucket(k, 8, 1 << 20), n_items)
-    return user_vecs, jnp.asarray(exclude_idx), k, k_bucket, B
+    return user_vecs, excl, k, k_bucket, B
+
+
+def _fetch(scores, idx, B: int, k: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Both results of a search on the host, the padding cut off, after
+    ONE wait: ``device_get`` starts the two copies together, so the
+    second is there when the first is."""
+    scores, idx = jax.device_get((scores, idx))
+    return scores[:B, :k], idx[:B, :k]
 
 
 class TopKScorer:
@@ -262,17 +305,18 @@ class TopKScorer:
         """:meth:`score` for a caller that holds ``pio:index.search``
         open itself (``index/exact.py`` when its kernel is not
         eligible)."""
-        B_in = np.atleast_2d(np.asarray(user_vecs)).shape[0]
+        B_in = _batch_rows(user_vecs)
         route = self._route(B_in)
         # the same marker ``index/exact.py`` writes for its kernel
         with trace.device_span(
-                "index.route", rows=B_in,
+                "index.route", rows=B_in, inputs=_inputs_kind(user_vecs),
                 route="host" if route == "host" else "xla_device"):
             pass
         if route == "host":
             return self._score_host(user_vecs, k, exclude_idx)
-        # enqueue: pad, transfer, the jitted call returning; fetch: the
-        # wait for the device and the copy back
+        # enqueue: pad in numpy, the jitted call (it carries the
+        # transfer) returning; fetch: the one wait for the device and
+        # the copies back
         with trace.device_span("index.enqueue"):
             user_vecs, exclude_idx, k, k_bucket, B = _prepare_score_inputs(
                 user_vecs, k, exclude_idx, self.item_factors.shape[0],
@@ -281,7 +325,7 @@ class TopKScorer:
                 user_vecs, self.item_factors, exclude_idx, k_bucket
             )
         with trace.device_span("index.fetch"):
-            return np.asarray(scores)[:B, :k], np.asarray(idx)[:B, :k]
+            return _fetch(scores, idx, B, k)
 
     def score_masked(
         self,
@@ -295,34 +339,26 @@ class TopKScorer:
         make the top-k (fewer candidates than k) come back with score
         <= NEG_INF — callers drop them by score threshold.
         """
-        B_in = np.atleast_2d(np.asarray(user_vecs)).shape[0]
-        if self._route(B_in) == "host":
+        B = _batch_rows(user_vecs)
+        if self._route(B) == "host":
             uv = np.atleast_2d(np.asarray(user_vecs, dtype=np.float32))
             scores = uv @ self._host_factors.T
             m = np.asarray(mask, dtype=bool)
             scores = np.where(m if m.ndim == 2 else m[None, :],
                               scores, float(NEG_INF))
             return self._host_topk(scores, k)
-        user_vecs = jnp.atleast_2d(jnp.asarray(user_vecs, dtype=jnp.float32))
-        B = user_vecs.shape[0]
         b_bucket = _pow2_bucket(B, 1, 1 << 30)
+        user_vecs = _pad_rows(user_vecs, b_bucket)
         mask = np.asarray(mask, dtype=bool)
-        if B < b_bucket:   # batch bucketing (see _prepare_score_inputs)
-            user_vecs = jnp.concatenate(
-                [user_vecs,
-                 jnp.zeros((b_bucket - B, user_vecs.shape[1]), user_vecs.dtype)]
-            )
-            if mask.ndim == 2:
-                mask = np.concatenate(
-                    [mask, np.zeros((b_bucket - B, mask.shape[1]), bool)]
-                )
+        if B < b_bucket and mask.ndim == 2:
+            mask = np.concatenate(
+                [mask, np.zeros((b_bucket - B, mask.shape[1]), bool)])
         n_items = self.item_factors.shape[0]
         k = min(k, n_items)
         k_bucket = min(_pow2_bucket(k, 8, 1 << 20), n_items)
         scores, idx = _topk_scores_masked(
-            user_vecs, self.item_factors, jnp.asarray(mask), k_bucket
-        )
-        return np.asarray(scores)[:B, :k], np.asarray(idx)[:B, :k]
+            user_vecs, self.item_factors, mask, k_bucket)
+        return _fetch(scores, idx, B, k)
 
 
 def make_sharded_topk(mesh, axis: str, n_items_global: int, k: int,
@@ -434,7 +470,7 @@ class ShardedTopKScorer:
             user_vecs, k, exclude_idx, self.n_items, self.max_exclude)
         scores, idx = self._fn(k_bucket)(
             user_vecs, self.item_factors, exclude_idx)
-        return np.asarray(scores)[:B, :k], np.asarray(idx)[:B, :k]
+        return _fetch(scores, idx, B, k)
 
 
 def cosine_normalize(m: np.ndarray, eps: float = 1e-8) -> np.ndarray:

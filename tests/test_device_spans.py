@@ -196,6 +196,8 @@ def test_every_serving_boundary_is_a_span_with_its_parent(
     assert len(routes) == len(sizes)
     assert {r["route"] for r in routes} == {"xla_device"}
     assert sorted(r["rows"] for r in routes) == sorted(sizes)
+    # the engine's query vectors are host data (rows of the user table)
+    assert {r["inputs"] for r in routes} == {"host"}
     # one request, one identifier: on the handler thread and, for a query
     # that was dispatched alone, on the worker's dispatch and below it
     mine = [s for s in spans if s[4].get("trace") == lone_id]
@@ -208,6 +210,43 @@ def test_every_serving_boundary_is_a_span_with_its_parent(
     others = [s for s in spans if s[0] == "pio:http.request"
               and s[4].get("trace") != lone_id]
     assert len({s[4]["trace"] for s in others}) == len(others) == 4
+
+
+@pytest.mark.parametrize("kernel,route", [("on", "kernel"),
+                                          ("off", "xla_device")])
+def test_the_route_marker_says_where_the_query_vectors_were(
+        tmp_path, kernel, route):
+    """``inputs`` = ``host`` for host data (it crosses inside the compiled
+    call), ``device`` for a ``jax.Array`` (a program's output stays where it
+    is: the sequence engine's head); ``ExactIndex.stats()`` counts both."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.index.exact import ExactIndex
+
+    rng = np.random.default_rng(30)
+    index = ExactIndex(kernel=kernel, block_items=256, placement="device")
+    index.build(rng.normal(size=(300, 8)).astype(np.float32))
+    queries = rng.normal(size=(4, 8)).astype(np.float32)
+    on_device = jnp.asarray(queries[:2])
+    index.search(queries, 5)                          # warm, untraced
+    index.search(on_device, 5)
+    start_profiler(tmp_path)
+    try:
+        index.search(queries, 5)
+        index.search(on_device, 5)
+        index.search(queries[0], 5)
+    finally:
+        jax.profiler.stop_trace()
+    spans = read_spans(tmp_path)
+    markers = [s for s in spans if s[0] == "pio:index.route"]
+    assert [(m[4]["inputs"], m[4]["rows"], m[4]["route"]) for m in markers] \
+        == [("host", 4, route), ("device", 2, route), ("host", 1, route)]
+    for m in markers:
+        assert parent_of(m, spans) == "pio:index.search"
+    stats = index.stats()
+    assert stats["inputs"] == {"host": 3, "device": 2}
+    assert stats["routes"][route] == stats["searches"] == 5
 
 
 def test_training_boundaries_are_spans(tmp_path):
