@@ -1,6 +1,7 @@
 """Median ``pio:batch.dispatch`` of the traced stretch with ``path=batched``,
-in ms: several queries as one batch through the XLA scorer, device included.
-A cell with one connection has none."""
+in ms: several queries as one batch through the model's one retriever (since
+PR 28 ``topk_dot`` at the batch's row bucket, over the index's one device copy
+of the table), device included. A cell with one connection has none."""
 
 
 def read(ctx):

@@ -3,7 +3,8 @@
 One general generator that reads a traffic mix (a data file under
 ``traffic/``) and drives ``POST /queries.json`` over raw keep-alive sockets:
 a minimal HTTP/1.1 client, so that the cycles it burns are not taken from the
-server it shares the machine with (``bench.py stage_loadgen``'s design).
+server it shares the machine with (the design of the pre-chip benchmark
+script's load stage; that script left the tree at PR 29).
 
 Protocol with the parent (which holds the chip): every connection runs ONE
 closed loop from its first request to its last — send, wait for the answer,

@@ -3,8 +3,9 @@
 No ``pio`` child, no parquet, no import and no event store: the factors are
 made from the seed, an ``ALSModel`` goes into an in-memory models repository,
 and the program's own ``EngineServer`` loads it (``prepare_deploy``: unpickle,
-index build, warm-up) and serves it on a local port, all in this process —
-the pattern of ``bench.py _serve_stage``.
+index build, warm-up) and serves it on a local port, all in this process
+(the pattern of the pre-chip benchmark script's serve stage; that script left
+the tree at PR 29).
 """
 
 from __future__ import annotations

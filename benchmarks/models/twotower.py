@@ -23,7 +23,8 @@ def program_seed(seed: int) -> int:
 
 
 def make_positives(bench):
-    """Clustered positives as ``bench.py stage_twotower``: every user has a
+    """Clustered positives as the pre-chip benchmark script's two-tower stage
+    made them (the script left the tree at PR 29): every user has a
     cluster, a share ``in_cluster`` of a user's items fall into it, so the
     loss has something to learn. Returns (u [n_pos], i [n_pos]) int32.
 

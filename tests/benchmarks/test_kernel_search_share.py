@@ -9,8 +9,9 @@ import shutil
 
 import pytest
 
+from tests.benchmarks import repo_spec
 from tests.benchmarks.test_program_spans import (BENCHMARKS, FIXTURE, HERE,
-                                                 REPO, SCOPES, load_file,
+                                                 SCOPES, load_file,
                                                  make_trace, read)
 
 METRIC = "kernel_search_share.serve"
@@ -55,19 +56,13 @@ def test_no_marker_is_nothing_to_read(ps):
     assert read(METRIC, recorded) is None
 
 
-def test_benchmark_json_names_the_reader_and_its_two_cells():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        spec = json.load(f)
-    entry = spec["per_layer"][-1]
-    assert entry == {
+@pytest.mark.parametrize("case", repo_spec.CASES)
+def test_benchmark_json_names_the_reader_and_its_two_cells(case):
+    repo_spec.assert_names_the_reader(repo_spec.load(case), {
         "name": METRIC, "unit": "%", "better": "higher",
         "source": "program_span", "layer": "retrieval",
         "moves": "query_p50_ms",
-        "workloads": ["als-amazon14.serve-c32", "als-amazon14.serve-c1"]}
-    moved = next(m for m in spec["end_to_end"] if m["name"] == entry["moves"])
-    assert set(entry["workloads"]) <= set(moved["workloads"])
-    assert os.path.isfile(os.path.join(BENCHMARKS, "layer_metrics",
-                                       METRIC + ".py"))
+        "workloads": ["als-amazon14.serve-c32", "als-amazon14.serve-c1"]})
 
 
 def test_a_traced_tiny_cell_reads_every_search_off_the_kernel_on_the_cpu(
@@ -77,12 +72,10 @@ def test_a_traced_tiny_cell_reads_every_search_off_the_kernel_on_the_cpu(
     ``host`` or ``xla_device`` and the share is a reading of 0, not None."""
     run = load_file(os.path.join(BENCHMARKS, "run.py"))
     shutil.copytree(os.path.join(HERE, "tiny"), tmp_path / "tiny")
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        added = [m for m in json.load(f)["per_layer"] if m["name"] == METRIC]
+    added = repo_spec.by_name(repo_spec.load()["per_layer"], METRIC)
     path = tmp_path / "tiny" / "BENCHMARK.json"
     spec = json.loads(path.read_text())
-    spec["per_layer"] += [dict(m, workloads=["als-tiny.serve-c4"])
-                          for m in added]
+    spec["per_layer"].append(dict(added, workloads=["als-tiny.serve-c4"]))
     path.write_text(json.dumps(spec))
     code = run.main(["--bench-root", str(tmp_path / "tiny"), "--rehearse-cpu",
                      "--workload", "als-tiny.serve-c4", "--seed",

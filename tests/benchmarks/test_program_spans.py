@@ -14,6 +14,8 @@ import types
 
 import pytest
 
+from tests.benchmarks import repo_spec
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 BENCHMARKS = os.path.join(REPO, "benchmarks")
@@ -385,9 +387,9 @@ def test_a_reader_returns_none_where_the_program_has_no_spans(
     assert "idle by pio: span" not in capsys.readouterr().out
 
 
-def test_benchmark_json_names_each_new_reader_with_its_cells():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        spec = json.load(f)
+@pytest.mark.parametrize("case", repo_spec.CASES)
+def test_benchmark_json_names_each_new_reader_with_its_cells(case):
+    spec = repo_spec.load(case)
     by_name = {m["name"]: m for m in spec["per_layer"]}
     assert NEW_METRICS <= set(by_name)
     cells = {w["name"] for w in spec["workloads"]}
@@ -412,9 +414,8 @@ def test_a_traced_tiny_cell_prints_the_span_metrics_and_the_idle_lines(
     the CPU (no device plane) the span readers still read."""
     run = load_file(os.path.join(BENCHMARKS, "run.py"))
     shutil.copytree(os.path.join(HERE, "tiny"), tmp_path / "tiny")
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        added = [m for m in json.load(f)["per_layer"]
-                 if m["name"] in NEW_METRICS and m["name"].endswith(".serve")]
+    added = [m for m in repo_spec.load()["per_layer"]
+             if m["name"] in NEW_METRICS and m["name"].endswith(".serve")]
     path = tmp_path / "tiny" / "BENCHMARK.json"
     spec = json.loads(path.read_text())
     spec["per_layer"] += [dict(m, workloads=["als-tiny.serve-c4"])

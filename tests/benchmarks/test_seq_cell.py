@@ -14,6 +14,8 @@ import types
 
 import pytest
 
+from tests.benchmarks import repo_spec
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 BENCHMARKS = os.path.join(REPO, "benchmarks")
@@ -160,10 +162,10 @@ def test_seq_counts_at_the_published_widths():
                        + 8 * 75_497_472 + 3000 * 576 * 2 * 8)) < 1
 
 
-def test_benchmark_json_names_the_cell_and_each_reader():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        spec = json.load(f)
-    cell = next(w for w in spec["workloads"] if w["name"] == REAL_CELL)
+@pytest.mark.parametrize("case", repo_spec.CASES)
+def test_benchmark_json_names_the_cell_and_each_reader(case):
+    spec = repo_spec.load(case)
+    cell = repo_spec.by_name(spec["workloads"], REAL_CELL)
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     by_name = {m["name"]: m for m in spec["per_layer"]}
     for name in NEW_METRICS:
@@ -184,8 +186,7 @@ def test_benchmark_json_names_the_cell_and_each_reader():
     assert by_name["extend_wait_ms.seq"]["source"] == "program_counter"
     assert by_name["front_self_ms.seq"]["source"] == "program_span"
     assert by_name["topk_dot_roofline_pct.seq"]["source"] == "device_trace"
-    config = next(c for c in spec["configs"]
-                  if c["name"] == "longcat-flash-chat")
+    config = repo_spec.by_name(spec["configs"], "longcat-flash-chat")
     assert config["reduced"] == ["num_layers", "n_routed_experts",
                                  "vocab_size"]
 
