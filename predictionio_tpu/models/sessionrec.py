@@ -206,14 +206,14 @@ class SessionRecAlgorithm(Algorithm):
 
 
 # ---------------------------------------------------------------------------
-# A latent-attention stack served in steps over a per-session latent cache
+# A stack served in steps over a per-session cache
 # ---------------------------------------------------------------------------
 
 class LatentCache:
-    """Which slot holds which session's latents. Host bookkeeping only: the
-    latents themselves are the device arrays of ``ops.sessionrec
-    .StackPrograms``; a slot here is the list of item rows whose latents it
-    holds, in order.
+    """Which slot holds which session's cached positions (latents, or keys
+    and values). Host bookkeeping only: the values themselves are the device
+    arrays of ``ops.sessionrec.StackPrograms``; a slot here is the list of
+    item rows whose positions it holds, in order.
 
     A query's rows are matched against the free slots by LONGEST COMMON
     PREFIX. A slot is a hit when the shared prefix is at least half of what
@@ -221,12 +221,17 @@ class LatentCache:
     the query then extends from the end of the prefix, and what the slot held
     beyond it is simply written over. Anything less is a miss: the least
     recently used free slot is taken and the history prefilled from its
-    start. A slot is busy while a query is being answered from it."""
+    start. A slot is busy while a query is being answered from it.
 
-    def __init__(self, n_slots: int):
+    ``block``: under a block-causal mask a position's cached values depend
+    on every position of its block, so only whole shared blocks are
+    reusable: the match rounds DOWN to a block boundary."""
+
+    def __init__(self, n_slots: int, block: int = 1):
         self.rows = [np.zeros(0, np.int32) for _ in range(n_slots)]
         self.busy = [False] * n_slots
         self.used = [0] * n_slots
+        self.block = block
         self._clock = 0
         self.hit_tokens = self.miss_tokens = self.evictions = 0
 
@@ -247,6 +252,7 @@ class LatentCache:
             shared = [self.common_prefix(self.rows[s], rows) for s in free]
             best = max(range(len(free)), key=shared.__getitem__)
             slot, cached = free[best], min(shared[best], len(rows) - 1)
+            cached -= cached % self.block
             if cached < 1 or 2 * shared[best] < len(self.rows[slot]):
                 cached = 0
                 slot = min(free, key=self.used.__getitem__)
@@ -266,7 +272,7 @@ class LatentCache:
         return slot, cached
 
     def release(self, slot: int, rows: np.ndarray) -> None:
-        """The slot now holds exactly ``rows``' latents."""
+        """The slot now holds exactly ``rows``' positions."""
         self._clock += 1
         self.rows[slot] = rows
         self.used[slot] = self._clock
@@ -274,10 +280,15 @@ class LatentCache:
 
 
 class SeqTicket:
-    """One query on its way through the steps."""
+    """One query on its way through the steps. A query that asks a block-
+    diffusion stack for ``generate`` items lives through many: its history's
+    whole blocks are encoded (``done`` of ``known`` positions), then block
+    after block is denoised and committed, ``forwards`` block forwards in
+    all, until ``done`` reaches ``end``."""
 
     __slots__ = ("rows", "num", "slot", "done", "born", "result",
-                 "extension")
+                 "extension", "generate", "known", "end", "block",
+                 "denoised", "forwards", "found", "tail")
 
     def __init__(self, rows, num, slot, done, born):
         self.rows, self.num, self.slot = rows, num, slot
@@ -285,48 +296,81 @@ class SeqTicket:
         #: whether the query arrived as an extension (a few new positions
         #: beyond what its slot held), as opposed to a history to prefill
         self.extension = False
-        self.result: Optional[List[Tuple[str, float]]] = None
+        self.result: Optional[list] = None
+        self.generate = 0
+        #: positions of the history's whole blocks; where the last block to
+        #: generate ends
+        self.known = self.end = len(rows)
+        #: the block being denoised (mask rows where masked), how many
+        #: denoise forwards it has had, and the slate's forwards so far
+        self.block: Optional[np.ndarray] = None
+        self.denoised = self.forwards = 0
+        #: position -> (item row, score, confidence, step)
+        self.found: Dict[int, tuple] = {}
+        self.tail: list = []
 
     @property
     def remaining(self) -> int:
-        return len(self.rows) - self.done
+        """Positions of the query's own rows still to encode."""
+        return self.known - self.done
+
+    def held(self) -> np.ndarray:
+        """The rows whose cached positions are final: ``done`` of them,
+        the history's and then the generated blocks that were committed."""
+        beyond = [self.found[p][0] for p in range(len(self.rows), self.done)]
+        return np.concatenate(
+            [self.rows, np.asarray(beyond, np.int32)])[:self.done]
 
 
 class SeqStackModel:
-    """A block stack with latent-attention mixers behind the ``items`` query,
-    served in STEPS: each step runs every pending extension (a few new
-    positions each) as one batch, and at most one prefill chunk of the
-    oldest session that still has a history to encode. The engine server's
-    step worker drives :meth:`begin` / :meth:`step` through
-    :class:`SeqStackAlgorithm`; :meth:`recommend` drives them to the end for
-    one query (``predict``).
+    """A block stack with cached mixers behind the ``items`` query, served in
+    STEPS: each step runs every pending extension (a few new positions each)
+    as one batch, one block forward over the tickets that generate, and at
+    most one prefill chunk of the oldest session that still has a history to
+    encode. The engine server's step worker drives :meth:`begin` /
+    :meth:`step` through :class:`SeqStackAlgorithm`; :meth:`recommend` drives
+    them to the end for one query (``predict``).
+
+    A latent-attention stack answers ``{"items", "num"}`` once, from the last
+    position's hidden state through the head (the exact retrieval index over
+    the output embedding, as for every other model's final projection). A
+    block-diffusion stack (``StackSpec.generation``) answers ``{"items",
+    "generate": n}`` with ``n`` items in position order, each with the logit
+    and the confidence of the forward that unmasked it and that forward's
+    index (``step``); what the last block holds beyond ``n`` comes as
+    ``blockTail``, so that every forward can be rebuilt from an answer.
 
     ``params`` are device arrays in the stack's layout (``ops.sessionrec
-    .init_stack``); the head is the exact retrieval index over the output
-    embedding, as for every other model's final projection."""
+    .init_stack``)."""
 
     def __init__(self, spec, params, item_ids: BiMap, shape=None):
         from predictionio_tpu.ops.sessionrec import ServeShape
 
         self.spec, self.params, self.item_ids = spec, params, item_ids
         self.shape = shape or ServeShape()
-        self.cache = LatentCache(self.shape.n_slots)
+        self.gen = spec.generation
+        self.cache = LatentCache(self.shape.n_slots,
+                                 self.gen.block_len if self.gen else 1)
         self._programs = None
         self._index = None
         self._inverse = None
         self.steps = 0
         # what the steps did, summed since deploy. Per program kind
-        # ("extend" / "prefill"): runs, real tokens, (token, pick) pairs
-        # that reached a held expert, held experts that got any token (per
-        # layer, summed), zero-compute picks; extensions: cached positions
-        # their attention read
+        # ("extend" / "prefill" / "block"): runs, real tokens, (token, pick)
+        # pairs that reached a held expert, held experts that got any token
+        # (per layer, summed), zero-compute picks; the cached positions the
+        # rows' attention read (extensions: latents; blocks: keys and values)
         self.counters = {
-            f"{kind}_{what}": 0 for kind in ("extend", "prefill")
+            f"{kind}_{what}": 0 for kind in ("extend", "prefill", "block")
             for what in ("runs", "tokens", "held_picks", "experts_touched",
                          "zero_picks")}
         self.counters.update({
             "extend_rows": 0, "extend_latent_positions": 0,
             "extensions_waited": 0,
+            # block forwards: rows by kind (a known block of a history is a
+            # commit row), positions the rule unmasked, queries answered
+            "denoise_rows": 0, "commit_rows": 0, "positions_unmasked": 0,
+            "slates_done": 0, "block_kv_positions": 0,
             # per program run and layer: the fullest held expert's tokens,
             # and the held experts' mean, summed
             "load_max_sum": 0.0, "load_mean_sum": 0.0})
@@ -344,12 +388,13 @@ class SeqStackModel:
             from predictionio_tpu.ops.sessionrec import StackPrograms
 
             self._programs = StackPrograms(self.spec, self.params, self.shape)
-            head = self.params["item_embed" if self.spec.tied_head
-                               else "head"]
-            head = head["embedding"] if isinstance(head, dict) else head
-            self._index = ExactIndex()
-            self._index.build(np.asarray(head, np.float32))
             self._inverse = self.item_ids.inverse()
+            if self.gen is None:    # a generating stack's head is in its
+                head = self.params["item_embed" if self.spec.tied_head
+                                   else "head"]          # block program
+                head = head["embedding"] if isinstance(head, dict) else head
+                self._index = ExactIndex()
+                self._index.build(np.asarray(head, np.float32))
         return self._programs
 
     def stats(self) -> Dict[str, Any]:
@@ -360,16 +405,31 @@ class SeqStackModel:
                 "index": self._index.stats() if self._index else None}
 
     # -- one query, in steps --------------------------------------------------
-    def resolve(self, query: Dict[str, Any]) -> np.ndarray:
+    def resolve(self, query: Dict[str, Any], room: Optional[int] = None
+                ) -> np.ndarray:
         ids = self.item_ids
         rows = [ids[i] for i in map(str, query.get("items") or ()) if i in ids]
-        return np.asarray(rows[-self.shape.capacity:], np.int32)
+        if self.gen is not None:        # a mask is never part of a history
+            rows = [r for r in rows if r != self.gen.mask_row]
+        return np.asarray(rows[-(room or self.shape.capacity):], np.int32)
 
     def begin(self, query: Dict[str, Any]) -> Optional[SeqTicket]:
         """A ticket for the query, its slot taken; None while every slot is
         busy (ask again after a step). A query that names no known item is
         finished at once, with nothing."""
-        rows = self.resolve(query)
+        generate = int(query.get("generate", 0))
+        room = None
+        if self.gen is None and generate:
+            raise ValueError("this stack generates nothing: ask it for "
+                             '{"items", "num"}')
+        if self.gen is not None:
+            # the last block is denoised whole
+            room = self.shape.capacity - generate - self.gen.block_len
+            if generate < 1 or room < 1:
+                raise ValueError(
+                    'a block-diffusion stack answers {"items", "generate": '
+                    f"n}} with 1 <= n < {self.shape.capacity}")
+        rows = self.resolve(query, room)
         num = int(query.get("num", 10))
         if len(rows) == 0:
             ticket = SeqTicket(rows, num, None, 0, self.steps)
@@ -379,20 +439,27 @@ class SeqStackModel:
         if got is None:
             return None
         ticket = SeqTicket(rows, num, got[0], got[1], self.steps)
+        if self.gen is not None:
+            B = self.gen.block_len
+            ticket.generate = generate
+            ticket.known = len(rows) - len(rows) % B
+            ticket.end = -(-(len(rows) + generate) // B) * B
         ticket.extension = ticket.remaining <= self.shape.extend_len
         return ticket
 
     def cancel(self, ticket: SeqTicket) -> None:
         if ticket.slot is not None:
-            self.cache.release(ticket.slot, ticket.rows[:ticket.done])
+            self.cache.release(ticket.slot, ticket.held())
 
     def step(self, tickets: List[SeqTicket],
              done=lambda ticket: None) -> List[SeqTicket]:
         """One step over the pending ``tickets`` (oldest first): every
-        extension (at most ``extend_batch``), then one prefill chunk.
-        Returns the tickets it finished, their ``result`` set (a ticket that
-        was answered at admission among them); ``done`` is called with each
-        as soon as it is, so that an extension's answer leaves before the
+        extension (at most ``extend_batch``) or, in a block-diffusion stack,
+        one block forward over the tickets that generate (``gen_batch`` rows
+        at most); then one prefill chunk. Returns the tickets it finished,
+        their ``result`` set (a ticket that was answered at admission among
+        them), possibly none: a slate takes many steps. ``done`` is called
+        with each as soon as it is, so that an answer leaves before the
         step's prefill chunk begins."""
         programs, sh = self.programs(), self.shape
         finished = [t for t in tickets if t.result is not None]
@@ -401,12 +468,14 @@ class SeqStackModel:
         pending = [t for t in tickets if t.result is None]
         if not pending:
             return finished
-        ext = [t for t in pending if t.remaining <= sh.extend_len]
-        ext = ext[:sh.extend_batch]
+        short = [t for t in pending if t.remaining <= sh.extend_len]
+        ext = [] if self.gen else short[:sh.extend_batch]
+        rows = self._block_rows(short) if self.gen else []
         pre = next((t for t in pending if t.remaining > sh.extend_len), None)
         self.steps += 1
         with trace.device_span(
-                "seq.step", n_extend=len(ext), seq=self.steps,
+                "seq.step", n_extend=len(ext), n_block=len(rows),
+                seq=self.steps,
                 prefill_tokens=(min(pre.remaining, sh.chunk) if pre else 0)):
             if ext:
                 with trace.device_span("seq.extend", rows=len(ext)):
@@ -424,6 +493,8 @@ class SeqStackModel:
                     t.done = len(t.rows)
                 self._answer(ext, h, done)
                 finished += ext
+            if rows:
+                finished += self._block_forward(rows, done)
             if pre is not None:
                 n = min(pre.remaining, sh.chunk)
                 with trace.device_span("seq.prefill_chunk", slot=pre.slot,
@@ -433,10 +504,82 @@ class SeqStackModel:
                     h.block_until_ready()
                 self._count("prefill", counted)
                 pre.done += n
-                if pre.remaining == 0:
+                if pre.remaining == 0 and not self.gen:
                     self._answer([pre], h, done)
                     finished.append(pre)
         return finished
+
+    # -- block diffusion ------------------------------------------------------
+    def _block_rows(self, tickets: List[SeqTicket]) -> list:
+        """The rows of this step's block forward, oldest ticket first:
+        ``(ticket, kind, block ids, position, n_unmask)``. A ticket gives
+        the whole blocks its history still lacks (``"known"``: committed as
+        they are) and, behind them, the block it generates: ``"denoise"``
+        while it holds a mask, ``"commit"`` once."""
+        gen, B = self.gen, self.gen.block_len
+        rows = []
+        for t in tickets:
+            at = t.done
+            while at < t.known and len(rows) < self.shape.gen_batch:
+                rows.append((t, "known", t.rows[at:at + B], at, 0))
+                at += B
+            if at < t.known or len(rows) == self.shape.gen_batch:
+                continue
+            if t.block is None:     # a new block: the history's left-over
+                t.block = np.full(B, gen.mask_row, np.int32)    # items open
+                if at == t.known:                               # the first
+                    t.block[:len(t.rows) - at] = t.rows[at:]
+                t.denoised = 0
+            masked = bool((t.block == gen.mask_row).any())
+            rows.append((t, "denoise" if masked else "commit", t.block, at,
+                         gen.unmask_at_least(t.denoised) if masked else 0))
+        return rows
+
+    def _block_forward(self, rows: list, done) -> List[SeqTicket]:
+        import jax
+
+        gen, B = self.gen, self.gen.block_len
+        n_denoise = sum(1 for r in rows if r[1] == "denoise")
+        with trace.device_span("seq.block_step", rows=len(rows),
+                               denoise_rows=n_denoise,
+                               commit_rows=len(rows) - n_denoise):
+            decided, counted = jax.device_get(self._programs.block(
+                [(ids, t.slot, at, kind == "denoise", n)
+                 for t, kind, ids, at, n in rows]))
+        self._count("block", counted)
+        c = self.counters
+        c["denoise_rows"] += n_denoise
+        c["commit_rows"] += len(rows) - n_denoise
+        c["block_kv_positions"] += sum(at + B for _, _, _, at, _ in rows)
+        finished = []
+        for b, (t, kind, _, at, _) in enumerate(rows):
+            if kind == "known":
+                t.done = at + B
+                continue
+            if kind == "denoise":
+                t.block = decided["ids"][b]
+                for i in np.flatnonzero(decided["picked"][b]):
+                    t.found[at + int(i)] = (
+                        int(t.block[i]), float(decided["score"][b, i]),
+                        float(decided["confidence"][b, i]), t.forwards)
+                    c["positions_unmasked"] += 1
+                t.denoised += 1
+            else:
+                t.done, t.block = at + B, None
+            t.forwards += 1
+            if t.done == t.end:
+                self._finish_slate(t, done)
+                finished.append(t)
+        return finished
+
+    def _finish_slate(self, t: SeqTicket, done) -> None:
+        inv, H = self._inverse, len(t.rows)
+        items = [(inv[row], score, conf, step) for row, score, conf, step
+                 in (t.found[p] for p in range(H, t.end))]
+        t.result, t.tail = items[:t.generate], items[t.generate:]
+        self.counters["slates_done"] += 1
+        self.cache.release(t.slot, t.held())
+        done(t)
 
     def _count(self, kind: str, counted) -> None:
         c = self.counters
@@ -465,13 +608,17 @@ class SeqStackModel:
             self.cache.release(t.slot, t.rows)
             done(t)
 
-    def recommend(self, query: Dict[str, Any]) -> List[Tuple[str, float]]:
+    def answer(self, query: Dict[str, Any]) -> SeqTicket:
+        """The query's ticket, driven through its steps to the end."""
         ticket = self.begin(query)
         if ticket is None:
             raise RuntimeError("every cache slot is busy")
         while ticket.result is None:
             self.step([ticket])
-        return ticket.result
+        return ticket
+
+    def recommend(self, query: Dict[str, Any]) -> list:
+        return self.answer(query).result
 
 
 @dataclass
@@ -484,6 +631,7 @@ class SeqStackParams(Params):
     chunk: int = 512
     extend_len: int = 8
     extend_batch: int = 8
+    gen_batch: int = 8
 
     def shape(self):
         import dataclasses
@@ -494,42 +642,58 @@ class SeqStackParams(Params):
 
 
 class SeqStackAlgorithm(Algorithm):
-    """Serves a :class:`SeqStackModel`. Training such a stack is not this
-    system's yet (``ROADMAP.md`` §2): its model arrives through
-    ``core.persistent_model`` from whoever holds its weights."""
+    """Serves a :class:`SeqStackModel`: a stack whose mixers keep a per-
+    session cache (latent attention, or grouped-query attention under a
+    block-causal mask). Training such a stack is not this system's yet
+    (``ROADMAP.md`` §2): its model arrives through ``core.persistent_model``
+    from whoever holds its weights."""
 
     stepwise = True
+    #: an answer's entries: ``(item, score)``, and from a stack that
+    #: generates ``(item, score, confidence, step)``
+    ENTRY = ("item", "score", "confidence", "step")
 
     def __init__(self, params: SeqStackParams):
         super().__init__(params)
 
     def train(self, ctx: MeshContext, pd) -> SeqStackModel:
         raise NotImplementedError(
-            "training a latent-attention expert stack is not supported; "
+            "training a stepwise-served expert stack is not supported; "
             "deploy one through a PersistentModel")
 
     def warmup(self, model: SeqStackModel, ctx: MeshContext) -> None:
-        """Compile both serve programs and every head batch, then run each
+        """Compile the serve programs and every head batch, then run each
         once, so that no query compiles anything."""
         programs, sh = model.programs(), model.shape
+        if model.gen is not None:
+            import jax
+
+            B = model.gen.block_len
+            programs.prefill(np.zeros(B, np.int32), sh.n_slots, 0)
+            jax.block_until_ready(programs.block(
+                [(np.zeros(B, np.int32), sh.n_slots, 0, True, 1)]))
+            return
         h, _ = programs.prefill(np.zeros(1, np.int32), sh.n_slots, 0)
         hs, _ = programs.extend([(np.zeros(1, np.int32), sh.n_slots, 1)])
         for h_last in (h, hs):
             model._index.search(h_last, 10)
 
-    @staticmethod
-    def _prediction(recs) -> Dict[str, Any]:
-        return {"itemScores": [{"item": i, "score": s} for i, s in recs]}
+    @classmethod
+    def _prediction(cls, ticket: SeqTicket) -> Dict[str, Any]:
+        out = {"itemScores": [dict(zip(cls.ENTRY, r)) for r in ticket.result]}
+        if ticket.tail:
+            out["blockTail"] = [dict(zip(cls.ENTRY, r)) for r in ticket.tail]
+        return out
 
     def predict(self, model: SeqStackModel, query: Dict[str, Any]):
-        return self._prediction(model.recommend(query))
+        return self._prediction(model.answer(query))
 
     # -- in steps (``core.Algorithm.stepwise``) --------------------------------
     def begin(self, model: SeqStackModel, query: Dict[str, Any]):
         return model.begin(query)
 
     def step(self, model: SeqStackModel, tickets, done) -> None:
-        model.step(tickets, lambda t: done(t, self._prediction(t.result)))
+        model.step(tickets, lambda t: done(t, self._prediction(t)))
 
     def cancel(self, model: SeqStackModel, ticket) -> None:
         model.cancel(ticket)

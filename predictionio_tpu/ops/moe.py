@@ -10,7 +10,8 @@ the exchange would bring, and nothing here stands in for it.
 
 Routing: ``p = softmax(x W_r)`` in float32 at the highest matmul precision,
 ``S = top_k(p + bias)``, gate ``g_i = scale * p_i`` (the selected expert's own
-probability, not renormalised over the picks).
+probability), or, where the model says so (``norm_topk``), renormalised over
+the picks: ``g_i = scale * p_i / sum_{j in S} p_j``.
 
 The grouped product (:func:`experts_sorted`) runs over the (token, pick)
 pairs SORTED by expert. Each held expert's group is cut into tiles of
@@ -49,6 +50,7 @@ class MoEDims:
     top_k: int
     scale: float                     # routed_scaling_factor
     held: Tuple[int, int]            # (first routed expert held, how many)
+    norm_topk: bool = False          # gates renormalised over the picks
 
     @property
     def n_router(self) -> int:
@@ -77,8 +79,10 @@ def route(p, dims: MoEDims, x):
                      precision=jax.lax.Precision.HIGHEST)
     prob = jax.nn.softmax(logits, axis=-1)
     _, idx = jax.lax.top_k(prob + p["bias"].astype(jnp.float32), dims.top_k)
-    return idx.astype(jnp.int32), dims.scale * jnp.take_along_axis(
-        prob, idx, axis=-1)
+    gates = jnp.take_along_axis(prob, idx, axis=-1)
+    if dims.norm_topk:
+        gates = gates / gates.sum(axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), dims.scale * gates
 
 
 def swiglu(x, w_g, w_u, w_d):
@@ -127,6 +131,8 @@ def moe(p, dims: MoEDims, x, valid, scope: str = "moe"):
         idx, gates = route(p, dims, x)
     with jax.named_scope(scope + ".experts"):
         routed, load = experts_sorted(p, dims, x, idx, gates, valid)
+    if not dims.n_zero:
+        return routed, {"expert_load": load, "zero_picks": jnp.int32(0)}
     with jax.named_scope(scope + ".zero"):
         is_zero = idx >= dims.n_routed
         zero_gate = jnp.where(is_zero, gates, 0.0).sum(axis=-1)

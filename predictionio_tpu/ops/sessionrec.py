@@ -34,6 +34,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from predictionio_tpu.ops import gqa as gqa_ops
 from predictionio_tpu.ops import mla as mla_ops
 from predictionio_tpu.ops import moe as moe_ops
 from predictionio_tpu.ops.attention import (
@@ -41,6 +42,7 @@ from predictionio_tpu.ops.attention import (
     mha_reference,
     ring_attention_sharded,
 )
+from predictionio_tpu.ops.gqa import GQADims
 from predictionio_tpu.ops.mla import MLADims
 from predictionio_tpu.ops.moe import MoEDims
 
@@ -80,8 +82,11 @@ class SessionRecConfig:
 class BlockSpec:
     """One block of the stack, by the kinds of its parts."""
 
-    mixer: str = "mha"          # "mha" (q/k/v heads) | "mla" (ops/mla.py)
-    ffn: str = "gelu_mlp"       # "gelu_mlp" | "swiglu"
+    #: "mha" (q/k/v heads) | "mla" (ops/mla.py) | "gqa" (ops/gqa.py)
+    mixer: str = "mha"
+    #: "gelu_mlp" | "swiglu" | "moe" (the expert layer, ops/moe.py, as the
+    #: block's whole FFN: "pre_ln" only)
+    ffn: str = "gelu_mlp"
     norm: str = "layernorm"     # "layernorm" | "rmsnorm"
     #: "pre_ln": x + mix(norm x), then + ffn(norm .);
     #: "scmoe": the shortcut-connected double-layer — two mixers, two dense
@@ -92,10 +97,60 @@ class BlockSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class Generation:
+    """Block-diffusion generation (``StackPrograms``' block program, ``models/
+    sessionrec.SeqStackModel``): a block of ``block_len`` positions starts as
+    mask rows; each denoise forward takes, at every position still masked,
+    the best item and its probability, and unmasks by ``rule``; the forward
+    over the finished block commits its keys and values.
+
+    ``low_confidence_static``: the ``n_s`` most confident masked positions
+    of the block's ``s``-th denoise forward, ``n_s`` from ``block_len /
+    denoising_steps`` (the remainder goes to the first forwards), all of
+    them where fewer are left. ``low_confidence_dynamic``: every masked
+    position whose confidence exceeds ``threshold``, and at least ``n_s``."""
+
+    mask_row: int                        # the item row that stands for a mask
+    block_len: int = 4
+    denoising_steps: int = 4
+    rule: str = "low_confidence_dynamic"
+    threshold: float = 0.9
+
+    def __post_init__(self):
+        if self.rule not in ("low_confidence_static",
+                             "low_confidence_dynamic"):
+            raise ValueError(f"unknown unmasking rule {self.rule!r}")
+
+    def unmask_at_least(self, s: int) -> int:
+        """``n_s`` of a block's ``s``-th denoise forward (from 0)."""
+        base, more = divmod(self.block_len, self.denoising_steps)
+        s = min(s, self.denoising_steps - 1)
+        return base + (1 if s < more else 0)
+
+    @property
+    def over(self) -> float:
+        """The confidence above which a position is unmasked whatever
+        its rank: the static rule knows none."""
+        return (self.threshold if self.rule == "low_confidence_dynamic"
+                else float("inf"))
+
+
+def unmask_by_rule(gen: Generation, masked, confidence, n_unmask):
+    """The positions of ``masked`` [B, block_len] one denoise forward
+    unmasks: the ``n_unmask`` [B] most confident of each block (ties: the
+    earlier position; all of them where fewer are left), and whatever lies
+    over the rule's threshold."""
+    c = jnp.where(masked, confidence, -jnp.inf)
+    rank = jnp.argsort(jnp.argsort(-c, axis=1, stable=True), axis=1)
+    return masked & ((rank < n_unmask[:, None]) | (c > gen.over))
+
+
+@dataclasses.dataclass(frozen=True)
 class StackSpec:
     """A stack of blocks over item embeddings. The toy next-item model is
     one such configuration (:meth:`SessionRecConfig.stack`), a latent-
-    attention expert model another; both run through :func:`apply_block`."""
+    attention expert model another, a block-diffusion expert model with
+    grouped-query attention a third; all run through :func:`apply_block`."""
 
     dim: int
     ffn_dim: int
@@ -107,7 +162,11 @@ class StackSpec:
     eps: float = 1e-6
     tied_head: bool = True               # scores against the item embedding
     mla: Optional[MLADims] = None
+    gqa: Optional[GQADims] = None
     moe: Optional[MoEDims] = None
+    #: how the stack generates, where it does (a "gqa" stack under the
+    #: block-causal mask); None: a query is answered once, from the head
+    generation: Optional[Generation] = None
 
 
 def _layernorm(p, x, eps):
@@ -136,7 +195,6 @@ def apply_block(spec: StackSpec, block: BlockSpec, p, x, mix, *,
     chunked prefill and extension (positions, the cache). ``moe(params, h)``
     likewise, where the topology has an expert layer."""
     norm = functools.partial(NORMS[block.norm], eps=spec.eps)
-    ffn = FFNS[block.ffn]
 
     def scoped(name, fn, *args):
         with jax.named_scope(f"{scope}.{name}"):
@@ -146,6 +204,9 @@ def apply_block(spec: StackSpec, block: BlockSpec, p, x, mix, *,
     h1 = x + drop(scoped(f"{mixer}_a", mix, "a", p["mixer_a"],
                          norm(p["norm_a"], x)))
     u = norm(p["norm_ffn_a"], h1)
+    if block.topology == "pre_ln" and block.ffn == "moe":
+        return h1 + moe(p["moe"], u)
+    ffn = FFNS[block.ffn]
     if block.topology == "pre_ln":
         return h1 + drop(scoped("ffn_a", ffn, p["ffn_a"], u))
     if block.topology != "scmoe":
@@ -168,6 +229,8 @@ def _init_norm(kind: str, width: int, dtype):
 def _init_mixer(spec: StackSpec, block: BlockSpec, key, dtype):
     if block.mixer == "mla":
         return mla_ops.init(key, spec.mla, dtype)
+    if block.mixer == "gqa":
+        return gqa_ops.init(key, spec.gqa, dtype)
     k1, k2 = jax.random.split(key)
     head_dim = spec.dim // spec.heads
     lecun = jax.nn.initializers.lecun_normal
@@ -210,8 +273,11 @@ def init_stack(spec: StackSpec, key, n_rows: int, dtype=jnp.float32) -> Dict:
     for block in spec.blocks:
         p = {"norm_a": _init_norm(block.norm, spec.dim, dtype),
              "mixer_a": _init_mixer(spec, block, next(keys), dtype),
-             "norm_ffn_a": _init_norm(block.norm, spec.dim, dtype),
-             "ffn_a": _init_ffn(spec, block, next(keys), dtype)}
+             "norm_ffn_a": _init_norm(block.norm, spec.dim, dtype)}
+        if block.ffn == "moe":
+            p["moe"] = moe_ops.init(next(keys), spec.moe, dtype)
+        else:
+            p["ffn_a"] = _init_ffn(spec, block, next(keys), dtype)
         if block.topology == "scmoe":
             p.update(
                 norm_b=_init_norm(block.norm, spec.dim, dtype),
@@ -595,65 +661,103 @@ class SessionScorer:
 
 
 # ---------------------------------------------------------------------------
-# Serving a latent-attention stack in steps, over a per-session latent cache
+# Serving a stack in steps, over a per-session cache
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
 class ServeShape:
-    """The fixed shapes of the two serve programs (everything a window can
-    ask for runs in these two, compiled once)."""
+    """The fixed shapes of the serve programs (everything a window can ask
+    for runs in these, compiled once)."""
 
-    n_slots: int = 32            # sessions whose latents are kept
+    n_slots: int = 32            # sessions whose cached positions are kept
     capacity: int = 8192         # positions a slot holds
     #: positions of one prefill chunk, and the cached positions attention
     #: takes per round of its block loop
     chunk: int = 512
     extend_len: int = 8          # new positions of one extension, at most
     extend_batch: int = 8        # extensions of one step, at most
+    gen_batch: int = 8           # blocks of one block forward, at most
 
 
 class StackPrograms:
-    """The compiled serve path of a stack whose mixers are latent attention:
-    ``prefill`` (one chunk of one session against its slot) and ``extend``
-    (a few new positions of several sessions, absorbed form), over a cache of
-    ``[n_slots + 1, capacity + chunk, latent]`` per mixer. The extra slot is
-    scratch for the padding rows of an extension batch; the extra chunk of
-    positions lets the last chunk of a full slot be written whole.
+    """The compiled serve path of a stack whose mixers keep a per-position
+    cache, all of one kind. Every kind has ``prefill`` (one chunk of one
+    session against its slot); beside it
 
-    Both programs take the parameters as arguments (nothing is baked in),
-    donate the cache, and return ``(cache, h_last, counters)``: the final-
-    normed hidden state of each session's last real position, and what the
-    expert layers counted. Which slot holds which session is the caller's
-    business (``models/sessionrec.LatentCache``)."""
+    * latent attention (``"mla"``): ``extend`` (a few new positions of
+      several sessions, absorbed form), over a cache of ``[n_slots + 1,
+      capacity + chunk, latent]`` per mixer;
+    * grouped-query attention under the block-causal mask (``"gqa"``):
+      ``block`` (one block of each of several sessions, denoised or
+      committed, with the head and the unmasking rule inside), over a cache
+      of ``[n_slots + 1, capacity + chunk, 2 * kv_heads * head_dim]`` (keys,
+      then values) per mixer.
+
+    The extra slot is scratch for the padding rows of a batch; the extra
+    chunk of positions lets the last chunk of a full slot be written whole.
+
+    The programs take the parameters as arguments (nothing is baked in),
+    donate the cache, and return ``(cache, result, counters)``: ``prefill``
+    and ``extend`` the final-normed hidden state of each session's last real
+    position, ``block`` what the rule decided; and what the expert layers
+    counted. Which slot holds which session is the caller's business
+    (``models/sessionrec.LatentCache``)."""
 
     def __init__(self, spec: StackSpec, params: Dict, shape: ServeShape):
-        if any(b.mixer != "mla" for b in spec.blocks):
-            raise ValueError("stepwise serving needs latent-attention mixers")
+        kinds = {b.mixer for b in spec.blocks}
+        if kinds not in ({"mla"}, {"gqa"}):
+            raise ValueError(
+                "stepwise serving needs mixers that keep a per-position "
+                "cache, all of one kind ('mla' or 'gqa'): got "
+                f"{sorted(kinds)}")
         from predictionio_tpu.obs import jaxmon
 
         self.spec, self.shape, self.params = spec, shape, params
+        self.kind = kinds.pop()
+        #: the one cached mixer's module and its sizes
+        self.mixer, self.dims = ((mla_ops, spec.mla) if self.kind == "mla"
+                                 else (gqa_ops, spec.gqa))
         dtype = params["item_embed"]["embedding"].dtype
         n_mixers = sum(2 if b.topology == "scmoe" else 1
                        for b in spec.blocks)
+        width = (mla_ops.cache_width(spec.mla) if self.kind == "mla"
+                 else spec.gqa.cache_width)
         self.cache = [jnp.zeros((shape.n_slots + 1,
-                                 shape.capacity + shape.chunk,
-                                 mla_ops.cache_width(spec.mla)), dtype)
+                                 shape.capacity + shape.chunk, width), dtype)
                       for _ in range(n_mixers)]
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
 
-        def struct(*dims):
-            return jax.ShapeDtypeStruct(dims, jnp.int32)
+        def struct(*dims, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(dims, dtype)
 
-        B, S = shape.extend_batch, shape.extend_len
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=1).lower(
             params, self.cache, struct(shape.chunk), i32, i32, i32).compile()
-        self._extend = jax.jit(self._extend_fn, donate_argnums=1).lower(
-            params, self.cache, struct(B, S), struct(B), struct(B),
-            struct(B), i32).compile()
-        for compiled in (self._prefill, self._extend):
-            jaxmon.record_scope_map(compiled)
+        compiled = [self._prefill]
+        if self.kind == "mla":
+            B, S = shape.extend_batch, shape.extend_len
+            self._extend = jax.jit(self._extend_fn, donate_argnums=1).lower(
+                params, self.cache, struct(B, S), struct(B), struct(B),
+                struct(B), i32).compile()
+            compiled.append(self._extend)
+        else:
+            gen = spec.generation
+            if gen is None or gen.block_len != spec.gqa.block_len:
+                raise ValueError(
+                    "a 'gqa' stack is served by block diffusion: its "
+                    "StackSpec.generation must be set, with the mask's "
+                    "block length")
+            if shape.chunk % gen.block_len or shape.capacity % gen.block_len:
+                raise ValueError("chunk and capacity must be multiples of "
+                                 "the block length")
+            B, S = shape.gen_batch, gen.block_len
+            self._block = jax.jit(self._block_fn, donate_argnums=1).lower(
+                params, self.cache, struct(B, S), struct(B), struct(B),
+                struct(B, dtype=jnp.bool_), struct(B), i32).compile()
+            compiled.append(self._block)
+        for program in compiled:
+            jaxmon.record_scope_map(program)
 
-    # -- the two programs -----------------------------------------------------
+    # -- the programs ---------------------------------------------------------
     def _run(self, params, x, valid, mix_with):
         """The blocks over tokens ``x`` [T, dim] (float32 residual stream);
         ``mix_with(i_mixer)`` gives block code its mixer."""
@@ -694,9 +798,8 @@ class StackPrograms:
         valid = jnp.arange(ids.shape[0]) < n_valid
 
         def mix_with(m, p, h):
-            out, cache[m] = mla_ops.prefill_chunk(
-                p, self.spec.mla, h, offset, cache[m], slot,
-                self.shape.chunk)
+            out, cache[m] = self.mixer.prefill_chunk(
+                p, self.dims, h, offset, cache[m], slot, self.shape.chunk)
             return out
 
         x, counters = self._run(params, self._embed(params, ids), valid,
@@ -721,6 +824,44 @@ class StackPrograms:
         last = x.reshape(B, S, -1)[jnp.arange(B), jnp.maximum(n_new - 1, 0)]
         return cache, self._final(params, last), counters
 
+    def _block_fn(self, params, cache, ids, slots, pos0, denoise, n_unmask,
+                  n_blocks):
+        """``ids`` [B, block_len]: one block a row, mask rows where masked.
+        Every row is a whole forward that writes its keys and values;
+        ``denoise`` rows are then unmasked by the rule (the head and the
+        rule run here because the decision is the next forward's input: a
+        round trip of [B * block_len, items] logits, or of a top-k that
+        lacks the softmax's normaliser, would stand between two forwards)."""
+        cache = list(cache)
+        gen = self.spec.generation
+        B, S = ids.shape
+        pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+        valid = jnp.repeat(slots != self.shape.n_slots, S)
+
+        def mix_with(m, p, h):
+            out, cache[m] = gqa_ops.block_step(
+                p, self.spec.gqa, h.reshape(B, S, -1), pos, cache[m], slots,
+                n_blocks, self.shape.chunk)
+            return out.reshape(B * S, -1)
+
+        x, counters = self._run(
+            params, self._embed(params, ids).reshape(B * S, -1), valid,
+            mix_with)
+        with jax.named_scope("seq.head"):
+            table = (params["item_embed"]["embedding"] if self.spec.tied_head
+                     else params["head"])
+            logits = mla_ops.mm(self._final(params, x), table.T)
+            # a mask is never an answer
+            logits = logits.at[:, gen.mask_row].set(-jnp.inf)
+            best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            top = logits.max(axis=-1)
+            conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1))
+            best, top, conf = (a.reshape(B, S) for a in (best, top, conf))
+        picked = unmask_by_rule(
+            gen, (ids == gen.mask_row) & denoise[:, None], conf, n_unmask)
+        return cache, {"ids": jnp.where(picked, best, ids), "picked": picked,
+                       "score": top, "confidence": conf}, counters
+
     # -- calls ----------------------------------------------------------------
     def prefill(self, ids: np.ndarray, slot: int, offset: int):
         """One chunk (``len(ids) <= chunk`` real positions) of the session in
@@ -732,6 +873,9 @@ class StackPrograms:
             self.params, self.cache, padded, np.int32(len(ids)),
             np.int32(slot), np.int32(offset))
         return h_last, counters
+
+    def _n_blocks(self, reach: int) -> np.int32:
+        return np.int32(-(-reach // self.shape.chunk))
 
     def extend(self, rows):
         """``rows``: [(ids, slot, position of ids[0])], at most
@@ -745,8 +889,29 @@ class StackPrograms:
         for b, (new, slot, at) in enumerate(rows):
             ids[b, :len(new)] = new
             n_new[b], slots[b], pos0[b] = len(new), slot, at
-        reach = int((pos0 + sh.extend_len).max())
         self.cache, h_last, counters = self._extend(
             self.params, self.cache, ids, n_new, slots, pos0,
-            np.int32(-(-reach // sh.chunk)))
+            self._n_blocks(int((pos0 + sh.extend_len).max())))
         return h_last, counters
+
+    def block(self, rows):
+        """``rows``: [(ids of one block, slot, position of ids[0], denoise,
+        n_unmask)], at most ``gen_batch``; a row that is not ``denoise``
+        commits its (finished or known) block. ``(decided, counters)``,
+        still on the device: ``decided["ids"]`` the blocks after the rule,
+        ``["picked"]`` the positions it unmasked, ``["score"]`` and
+        ``["confidence"]`` the best item's logit and probability at every
+        position, each ``[gen_batch, block_len]``."""
+        sh, S = self.shape, self.spec.generation.block_len
+        ids = np.zeros((sh.gen_batch, S), np.int32)
+        slots = np.full(sh.gen_batch, sh.n_slots, np.int32)      # scratch
+        pos0 = np.zeros(sh.gen_batch, np.int32)
+        denoise = np.zeros(sh.gen_batch, bool)
+        n_unmask = np.zeros(sh.gen_batch, np.int32)
+        for b, (block, slot, at, den, n) in enumerate(rows):
+            ids[b], slots[b], pos0[b] = block, slot, at
+            denoise[b], n_unmask[b] = den, n
+        self.cache, decided, counters = self._block(
+            self.params, self.cache, ids, slots, pos0, denoise, n_unmask,
+            self._n_blocks(int(pos0.max()) + S))
+        return decided, counters

@@ -209,8 +209,8 @@ def sessionrec_engine() -> Engine:
         data_source_classes=SeqDataSource,
         preparator_classes=SeqPreparator,
         algorithm_classes={"sessionrec": SessionRecAlgorithm,
-                           # a latent-attention stack served in steps over
-                           # a per-session cache (serve only)
+                           # a stack with cached mixers served in steps
+                           # over a per-session cache (serve only)
                            "seqstack": SeqStackAlgorithm},
         serving_classes=FirstServing,
     )

@@ -254,12 +254,15 @@ class HandedOverStack(PersistentModel):
         from predictionio_tpu.data.bimap import BiMap
         from predictionio_tpu.models.sessionrec import SeqStackModel
 
-        spec, weights = _HANDOVER[instance_id]
-        items = BiMap.from_vocab([f"i{r}" for r in range(N_ITEMS)])
+        spec, weights, n_items = _HANDOVER[instance_id]
+        items = BiMap.from_vocab([f"i{r}" for r in range(n_items)])
         return SeqStackModel(spec, weights, items, params.shape())
 
 
-def deploy_small(n_slots=2, capacity=128):
+def deploy_small(n_slots=2, capacity=128, stack=None, n_items=N_ITEMS,
+                 **shape):
+    """``stack``: another ``(spec, params)`` than this file's (tests/
+    test_seqgen.py deploys a block-diffusion stack the same way)."""
     import datetime as dt
     import json
     import pickle
@@ -273,8 +276,8 @@ def deploy_small(n_slots=2, capacity=128):
     from predictionio_tpu.templates.sessionrec import (
         SeqDataSourceParams, sessionrec_engine)
 
-    spec = small_spec()
-    params = seeded_params(spec)
+    spec, params = stack or (small_spec(), None)
+    params = seeded_params(spec) if params is None else params
     storage = Storage.from_env({
         "PIO_STORAGE_SOURCES_MEM_TYPE": "memory",
         **{f"PIO_STORAGE_REPOSITORIES_{r}_{k}": v
@@ -283,9 +286,9 @@ def deploy_small(n_slots=2, capacity=128):
     ep = EngineParams(
         data_source_params=("", SeqDataSourceParams(app_name="t")),
         preparator_params=("", None),
-        algorithm_params_list=[("seqstack", SeqStackParams(
-            n_slots=n_slots, capacity=capacity, chunk=16, extend_len=4,
-            extend_batch=2))],
+        algorithm_params_list=[("seqstack", SeqStackParams(**{
+            "n_slots": n_slots, "capacity": capacity, "chunk": 16,
+            "extend_len": 4, "extend_batch": 2, **shape}))],
         serving_params=("", None)).to_json_dict()
     now = dt.datetime.now(tz=dt.timezone.utc)
     instance = EngineInstance(
@@ -297,7 +300,7 @@ def deploy_small(n_slots=2, capacity=128):
         algorithms_params=json.dumps(ep["algorithmParamsList"]),
         serving_params=json.dumps(ep["servingParams"]))
     storage.engine_instances().insert(instance)
-    _HANDOVER[instance.id] = (spec, params)
+    _HANDOVER[instance.id] = (spec, params, n_items)
     manifest = PersistentModelManifest(
         class_name="HandedOverStack", module_name=__name__)
     storage.models().insert(Model(id=instance.id,

@@ -182,3 +182,37 @@ def test_a_scope_reaches_xlas_own_fusions_through_the_scope_map(
     scopes = jaxmon.scope_map_of(text)
     fusions = {k: v for k, v in scopes.items() if k.startswith("fusion")}
     assert fusions and set(fusions.values()) == {"twotower.adagrad_user"}
+
+
+def test_the_kv_cache_is_left_as_it_is_handed_over(one_chip,
+                                                   no_compile_cache):
+    """Grouped-query attention's block step at the benchmark's widths (32
+    query heads on 4 key/value heads of 128, 33 slots of 4,608 positions,
+    1,024 values a position): the compiler keeps the donated cache in the
+    layout it arrives in, positions on the sublanes, and copies none of it
+    (a cache of 576 values a position was copied in and out of every call:
+    ``ops/mla.cache_width``)."""
+    from predictionio_tpu.ops import gqa
+
+    dims = gqa.GQADims(dim=2048, heads=32, kv_heads=4, head_dim=128)
+    bf16 = jnp.bfloat16
+
+    def struct(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"w_q": struct(2048, 4096), "w_k": struct(2048, 512),
+         "w_v": struct(2048, 512), "w_o": struct(4096, 2048),
+         "q_norm": struct(128), "k_norm": struct(128)}
+
+    def step(p, x, pos, cache, slots, n_blocks):
+        return gqa.block_step(p, dims, x, pos, cache, slots, n_blocks, 512)
+
+    compiled = jax.jit(step, donate_argnums=3).lower(
+        p, struct(8, 4, 2048, dtype=jnp.float32),
+        struct(8, 4, dtype=jnp.int32), struct(33, 4608, dims.cache_width),
+        struct(8, dtype=jnp.int32), struct(dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    layouts = set(re.findall(r"bf16\[33,4608,1024\](\{[^}]*\})", text))
+    assert layouts == {"{2,1,0:T(8,128)(2,1)}"}, layouts
+    assert not re.search(r"= bf16\[33,4608,1024\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
