@@ -21,6 +21,7 @@ from predictionio_tpu.core import Algorithm, SanityCheck
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.obs import trace
+from predictionio_tpu.ops import moe as moe_ops
 from predictionio_tpu.ops.sessionrec import (
     SessionRecConfig,
     SessionRecModelState,
@@ -359,11 +360,13 @@ class SeqStackModel:
         # ("extend" / "prefill" / "block"): runs, real tokens, (token, pick)
         # pairs that reached a held expert, held experts that got any token
         # (per layer, summed), zero-compute picks; the cached positions the
-        # rows' attention read (extensions: latents; blocks: keys and values)
+        # rows' attention read (extensions: latents; blocks: keys and values);
+        # runs whose expert layers took the small forward's form (all of a
+        # kind's or none: the program's shape decides, ``ops/moe.small_forward``)
         self.counters = {
             f"{kind}_{what}": 0 for kind in ("extend", "prefill", "block")
             for what in ("runs", "tokens", "held_picks", "experts_touched",
-                         "zero_picks")}
+                         "zero_picks", "dense_expert_runs")}
         self.counters.update({
             "extend_rows": 0, "extend_latent_positions": 0,
             "extensions_waited": 0,
@@ -589,6 +592,8 @@ class SeqStackModel:
             load = np.asarray(counted["expert_load"], np.int64)
             c[f"{kind}_held_picks"] += int(load.sum())
             c[f"{kind}_experts_touched"] += int((load > 0).sum())
+            c[f"{kind}_dense_expert_runs"] += moe_ops.small_forward(
+                self._programs.tokens[kind])
             c[f"{kind}_zero_picks"] += int(
                 np.asarray(counted["zero_picks"]).sum())
             c["load_max_sum"] += float(load.max(axis=1).sum())

@@ -13,16 +13,41 @@ Routing: ``p = softmax(x W_r)`` in float32 at the highest matmul precision,
 probability), or, where the model says so (``norm_topk``), renormalised over
 the picks: ``g_i = scale * p_i / sum_{j in S} p_j``.
 
-The grouped product (:func:`experts_sorted`) runs over the (token, pick)
-pairs SORTED by expert. Each held expert's group is cut into tiles of
-``TILE`` rows; a loop with as many rounds as there are tiles takes one tile,
-gathers its tokens' rows, runs them through that expert's three matrices and
-adds the gated result back to the tokens. No capacity factor exists and no
-token is dropped: under any skew the loop just runs more tiles for the
-crowded expert (all of them, if every token picks it). The loop was measured
-on the chip against XLA's ``ragged_dot`` over the same sorted rows, and its
-tile against 128 and 256 rows (``benchmarks/tools/moe_grouped_probe.py``; the
-readings are in PERF.md, Findings, PR 27); the loop is the one form kept.
+What the held experts add is computed in one of two forms, chosen by the
+number of tokens ``T`` of the forward, a static shape (:func:`small_forward`;
+no option, model name or environment variable is asked):
+
+* ``T <= TILE`` (a block forward's 8 x 4 tokens, an extension batch's 8 x 8):
+  :func:`experts_streamed`. All ``T`` rows stay in place and run through each
+  TOUCHED expert, the gate of a row that did not pick it selecting its
+  product out; the experts' weights are streamed by one Pallas kernel
+  (``ops/pallas/expert_stream.py``) that reads expert ``e + 1`` while ``e``'s
+  products run. No sort, gather or scatter; an expert no token picked is not
+  read. The same picks and precision as the tile loop (product inputs in the
+  weights' type, float32 accumulation, gates and sum), summed in expert
+  order.
+* ``T > TILE`` (a prefill chunk's 512): :func:`experts_sorted`, the grouped
+  product over the (token, pick) pairs SORTED by expert. Each held expert's
+  group is cut into tiles of ``TILE`` rows; a loop with as many rounds as
+  there are tiles takes one tile, gathers its tokens' rows, runs them through
+  that expert's three matrices and adds the gated result back to the tokens.
+  Above one tile the dense form would waste products (at 512 tokens 16 times
+  the FLOPs: a layer bound by reading its weights would be bound by
+  arithmetic), so the tile loop is the form for more than one tile of tokens.
+
+No capacity factor exists and no token is dropped in either: under any skew
+the tile loop just runs more tiles for the crowded expert (all of them, if
+every token picks it), and the small forward's rows all run through it.
+
+Chip readings (one v5e). The tile loop against XLA's ``ragged_dot`` over the
+same sorted rows at 512 tokens, and its tile against 128 and 256 rows
+(``benchmarks/tools/moe_grouped_probe.py``; PERF.md, Findings, PRs 24-31): the
+loop is the one form kept there. At 32 tokens (SDAR's widths: 128 experts of
+2048 x 768, 9.44 MB and 11.5 us of reading each; 66 touched) a tile of the
+loop costs 31.9 us whether it holds two tokens or sixty, a plain-XLA loop
+over the touched experts 16.5 us (its products wait for their own weights),
+the kernel 12.8 us (``benchmarks/tools/moe_small_probe.py``; PERF.md,
+Findings, PR 33): the kernel is the one form kept here.
 """
 
 from __future__ import annotations
@@ -34,10 +59,14 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from predictionio_tpu.ops import pallas as pallas_ops
 from predictionio_tpu.ops.mla import mm
+from predictionio_tpu.ops.pallas import expert_stream
 
-#: rows of one expert tile (the measured best of 64, 128 and 256 at the
-#: widths this layer has been run at: PERF.md, Findings, PR 27)
+#: rows of one expert tile of the sorted-tile loop, the form for MORE than one
+#: tile of tokens (the measured best of 64, 128 and 256 at 512 tokens:
+#: PERF.md, Findings, PRs 24-31); a forward of up to one tile of tokens takes
+#: the streamed form instead (:func:`small_forward`)
 TILE = 64
 
 
@@ -123,6 +152,40 @@ def experts_sorted(p, dims: MoEDims, x, idx, gates, valid):
     return y, counts
 
 
+def small_forward(T: int) -> bool:
+    """Whether a forward of ``T`` tokens takes :func:`experts_streamed`: its
+    tokens fit one tile. A shape, known when the program is traced."""
+    return T <= TILE
+
+
+def held_gates(dims: MoEDims, idx, gates, valid):
+    """The picks as a matrix: ``gate [T, n]`` float32, the gate of token ``t``
+    on held expert ``e``, ``expert_stream.NOT_PICKED`` where it did not pick
+    it or is padding; and the tokens each held expert got, ``[n]`` int32."""
+    e0, n = dims.held
+    local = idx - e0
+    here = (local >= 0) & (local < n) & valid[:, None]
+    hit = here[:, :, None] & (local[:, :, None]
+                              == jnp.arange(n, dtype=jnp.int32))   # [T, k, n]
+    gate = jnp.where(hit.any(axis=1),
+                     jnp.where(hit, gates[:, :, None], 0.0).sum(axis=1),
+                     expert_stream.NOT_PICKED)
+    return gate, hit.sum(axis=(0, 1)).astype(jnp.int32)
+
+
+def experts_streamed(p, dims: MoEDims, x, idx, gates, valid):
+    """:func:`experts_sorted`'s answer for a forward whose tokens fit one
+    tile, summed in expert order: all ``T`` rows stay in place and run
+    through each TOUCHED held expert, the gate of a row that did not pick it
+    (or is padding) selecting its product out
+    (``ops/pallas/expert_stream.py``). No sort, gather or scatter."""
+    gate, counts = held_gates(dims, idx, gates, valid)
+    y = expert_stream.expert_stream(
+        x, gate, p["w_g"], p["w_u"], p["w_d"],
+        interpret=pallas_ops.interpret_mode())
+    return y, counts
+
+
 def moe(p, dims: MoEDims, x, valid, scope: str = "moe"):
     """The layer's output here for tokens ``x`` [T, dim]: ``(y [T, dim]
     float32, counters)``; ``counters`` = tokens per held expert ``[n]`` and
@@ -130,7 +193,9 @@ def moe(p, dims: MoEDims, x, valid, scope: str = "moe"):
     with jax.named_scope(scope + ".route"):
         idx, gates = route(p, dims, x)
     with jax.named_scope(scope + ".experts"):
-        routed, load = experts_sorted(p, dims, x, idx, gates, valid)
+        experts = (experts_streamed if small_forward(x.shape[0])
+                   else experts_sorted)
+        routed, load = experts(p, dims, x, idx, gates, valid)
     if not dims.n_zero:
         return routed, {"expert_load": load, "zero_picks": jnp.int32(0)}
     with jax.named_scope(scope + ".zero"):

@@ -32,6 +32,13 @@ top-k over the item table in ``[D, Ip]`` tiles of thousands of items,
 merging only a tile that can change the top-k — the exact retrieval
 index's hot path, selected per-index via ``index_kernel``).
 
+One kernel has no flag: ``expert_stream`` (the expert layer of a forward
+whose tokens fit one tile: every row through every touched expert, the
+experts' weights streamed) is the one form ``ops/moe.moe`` has for that
+shape, so it runs compiled on a TPU and under the interpreter everywhere
+else, tier-1 included; the tile loop it stands beside
+(``ops/moe.experts_sorted``) is its reference in the tests.
+
 Each flag (``flash_ce_kernel``, ``embed_update_kernel``,
 ``index_kernel``) takes ``on`` / ``off`` / ``auto``;
 ``PIO_PALLAS_INTERPRET=1`` forces interpret mode off the TPU.

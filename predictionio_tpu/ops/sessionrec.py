@@ -726,6 +726,9 @@ class StackPrograms:
                                  shape.capacity + shape.chunk, width), dtype)
                       for _ in range(n_mixers)]
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        #: tokens of a call of each program that this stack compiles: the
+        #: shape by which ``ops/moe.moe`` chooses its form
+        self.tokens = {"prefill": shape.chunk}
 
         def struct(*dims, dtype=jnp.int32):
             return jax.ShapeDtypeStruct(dims, dtype)
@@ -735,6 +738,7 @@ class StackPrograms:
         compiled = [self._prefill]
         if self.kind == "mla":
             B, S = shape.extend_batch, shape.extend_len
+            self.tokens["extend"] = B * S
             self._extend = jax.jit(self._extend_fn, donate_argnums=1).lower(
                 params, self.cache, struct(B, S), struct(B), struct(B),
                 struct(B), i32).compile()
@@ -750,6 +754,7 @@ class StackPrograms:
                 raise ValueError("chunk and capacity must be multiples of "
                                  "the block length")
             B, S = shape.gen_batch, gen.block_len
+            self.tokens["block"] = B * S
             self._block = jax.jit(self._block_fn, donate_argnums=1).lower(
                 params, self.cache, struct(B, S), struct(B), struct(B),
                 struct(B, dtype=jnp.bool_), struct(B), i32).compile()

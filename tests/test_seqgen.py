@@ -154,6 +154,45 @@ def test_renormalised_gates_match_the_reference(ref):
     close(g, 6.0 * jnp.take_along_axis(prob, idx, axis=-1))
 
 
+@pytest.mark.parametrize("T", [1, 32, 64])
+def test_a_small_forward_with_renormalised_gates_matches_the_reference(
+        ref, T):
+    """Every expert held, no zero-compute ones, gates renormalised over the
+    picks: a block forward's expert layer (tokens that fit one tile, every
+    row through each touched expert) against the tile loop and the float32
+    reference."""
+    p = seeded_params(small_spec())["blocks"][0]["moe"]
+    x = jnp.asarray(np.random.default_rng(30 + T).standard_normal((T, 64)),
+                    jnp.float32)
+    valid = jnp.ones(T, bool)
+    idx, gates = moe_ops.route(p, MOE, x)
+    y, counts = moe_ops.experts_streamed(p, MOE, x, idx, gates, valid)
+    y_tiles, counts_tiles = moe_ops.experts_sorted(p, MOE, x, idx, gates,
+                                                   valid)
+    close(y, y_tiles, 1e-6)
+    close(y, ref.experts(p, x, DM))
+    assert counts.tolist() == counts_tiles.tolist()
+    assert int(counts.sum()) == T * MOE.top_k
+    whole, counted = moe_ops.moe(p, MOE, x, valid)
+    close(whole, y, 1e-6)
+    assert int(counted["zero_picks"]) == 0
+
+
+@pytest.mark.parametrize("chunk", [16, 128])
+def test_every_block_forward_takes_the_small_forwards_form(chunk):
+    """``block_dense_expert_runs`` equals ``block_runs`` (4 rows x a block of
+    4 fit one tile); a chunk of 128 positions takes the tile loop, one of 16
+    does not."""
+    model, _ = small_model(STATIC, chunk=chunk, capacity=128)
+    model.answer(query(history(2, 22), 8))
+    stats = model.stats()
+    assert stats["block_runs"] > 0 and stats["prefill_runs"] > 0
+    assert stats["block_dense_expert_runs"] == stats["block_runs"]
+    assert stats["prefill_dense_expert_runs"] == (
+        stats["prefill_runs"] if chunk <= moe_ops.TILE else 0)
+    assert stats["extend_dense_expert_runs"] == stats["extend_runs"] == 0
+
+
 def test_programs_prefill_and_blocks_give_the_reference_logits(ref):
     """Scores, through the program's own head: a history of 22 (five whole
     blocks, two items left over) prefilled in two chunks, then the first

@@ -156,9 +156,11 @@ def test_expert_layer_matches_the_reference_and_drops_no_token(ref, skew):
         counted["expert_load"].sum()) or skew == "uniform"
 
 
-def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(ref):
+@pytest.mark.parametrize("form", ["experts_sorted", "experts_streamed"])
+def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(ref, form):
     """Four shares of the 16 routed experts, the zero-compute part counted
-    once: the whole layer, in the program and in the reference alike."""
+    once: the whole layer, in the program and in the reference alike, by
+    the tile loop and by the small forward's form (29 tokens fit a tile)."""
     whole = dataclasses.replace(MOE, held=(0, 16))
     p = moe_ops.init(jax.random.PRNGKey(4), whole, bias_std=2e-3)
     x = jnp.asarray(np.random.default_rng(4).standard_normal((29, 64)),
@@ -171,7 +173,7 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(ref):
     for e0 in range(0, 16, 4):
         share = dataclasses.replace(MOE, held=(e0, 4))
         ps = dict(p, **{k: p[k][e0:e0 + 4] for k in ("w_g", "w_u", "w_d")})
-        routed, _ = moe_ops.experts_sorted(ps, share, x, idx, gates, valid)
+        routed, _ = getattr(moe_ops, form)(ps, share, x, idx, gates, valid)
         ref_routed, ref_zero, _ = ref.moe_parts(ps, x, dm, (e0, 4))
         close(routed, ref_routed)
         close(ref_zero, uncut_z)
@@ -179,6 +181,112 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(ref):
     close(total, uncut_r)
     y, _ = moe_ops.moe(p, whole, x, valid)
     close(y, uncut_r + uncut_z)
+
+
+def small_forward_case(case, T, seed=6):
+    """One expert layer's weights, ``T`` tokens and their ``valid`` for a
+    named routing: ``uniform``; ``padding`` (the second half of the rows, all
+    of one row); ``one_expert`` (every token picks held expert 5);
+    ``none_held`` (no token picks a held expert: zero rounds)."""
+    p = dict(seeded_params(small_spec())["blocks"][0]["moe"])
+    if case == "one_expert":
+        p["bias"] = p["bias"].at[5].set(10.0)
+    if case == "none_held":
+        p["bias"] = p["bias"].at[4:8].set(-10.0)
+    x = jnp.asarray(np.random.default_rng(seed + T).standard_normal((T, 64)),
+                    jnp.float32)
+    valid = jnp.ones(T, bool)
+    if case == "padding":
+        valid = valid.at[T // 2:].set(False)
+    return p, x, valid
+
+
+@pytest.mark.parametrize("T", [1, 32, 64])
+@pytest.mark.parametrize("case", ["uniform", "padding", "one_expert",
+                                  "none_held"])
+def test_a_small_forward_runs_every_row_through_the_touched_experts(
+        ref, case, T):
+    """A forward whose tokens fit one tile (the kernel under the interpreter
+    here) against the tile loop and against the float32 reference, with
+    zero-compute experts and a strict share of the routed ones held."""
+    p, x, valid = small_forward_case(case, T)
+    assert moe_ops.small_forward(T)
+    idx, gates = moe_ops.route(p, MOE, x)
+    y, counts = moe_ops.experts_streamed(p, MOE, x, idx, gates, valid)
+    y_tiles, counts_tiles = moe_ops.experts_sorted(p, MOE, x, idx, gates,
+                                                   valid)
+    close(y, y_tiles, 1e-6)
+    assert counts.tolist() == counts_tiles.tolist()
+    routed, zero, _ = ref.moe_parts(p, x, ref_dims(small_spec()), MOE.held)
+    real = np.asarray(valid)[:, None]
+    # a padding row reaches no expert and counts nowhere
+    close(y, np.where(real, routed, 0.0))
+    assert int(counts.sum()) <= int(real.sum()) * MOE.top_k
+    whole, counted = moe_ops.moe(p, MOE, x, valid)
+    close(np.where(real, whole, 0.0), np.where(real, routed + zero, 0.0))
+    assert counted["expert_load"].tolist() == counts.tolist()
+    if case == "one_expert":
+        assert int(counts[1]) == int(real.sum())
+    if case == "none_held":
+        assert not counts.any() and not np.asarray(y).any()
+
+
+@pytest.mark.parametrize("T", [32, 64])
+def test_a_huge_row_that_picked_no_expert_reaches_no_sum(T):
+    """The rows that did not pick an expert are selected out, not multiplied
+    by 0: a padding row of 1e30 (its products overflow) reads 0 and leaves
+    every other row's output as it was."""
+    p, x, valid = small_forward_case("uniform", T)
+    idx, gates = moe_ops.route(p, MOE, x)
+    want, _ = moe_ops.experts_streamed(p, MOE, x, idx, gates, valid)
+    x = x.at[3].set(1e30)
+    valid = valid.at[3].set(False)
+    y, counts = moe_ops.experts_streamed(p, MOE, x, idx, gates, valid)
+    assert np.isfinite(np.asarray(y)).all()
+    assert not np.asarray(y)[3].any()
+    keep = np.arange(T) != 3
+    close(np.asarray(y)[keep], np.asarray(want)[keep], 1e-6)
+    e0, n = MOE.held
+    assert int(counts.sum()) == int(
+        ((idx[keep] >= e0) & (idx[keep] < e0 + n)).sum())
+
+
+@pytest.mark.parametrize("T", [1, 32, 64, 65, 512])
+def test_the_forwards_shape_chooses_the_expert_layers_form(T):
+    """Up to one tile of tokens the kernel and no sort; beyond it the
+    sorted-tile loop and no kernel. Nothing but the shape is asked."""
+    p, x, valid = small_forward_case("uniform", T)
+    text = str(jax.make_jaxpr(
+        lambda p, x, valid: moe_ops.moe(p, MOE, x, valid))(p, x, valid))
+    small = T <= moe_ops.TILE
+    assert moe_ops.small_forward(T) == small
+    assert ("pallas_call" in text) == small
+    assert (" sort[" in text) == (not small)
+    assert ("scatter-add" in text) == (not small)
+
+
+@pytest.mark.parametrize("chunk", [16, 128])
+def test_the_model_counts_the_runs_that_took_the_small_forwards_form(chunk):
+    """``<kind>_dense_expert_runs``: every run of a program whose tokens fit
+    one tile (the extension program's 2 x 4; a chunk of 16), none of a
+    program whose tokens do not (a chunk of 128)."""
+    from predictionio_tpu.data.bimap import BiMap
+    from predictionio_tpu.models.sessionrec import SeqStackModel
+
+    spec = small_spec()
+    model = SeqStackModel(
+        spec, seeded_params(spec),
+        BiMap.from_vocab([f"i{r}" for r in range(N_ITEMS)]),
+        dataclasses.replace(SHAPE, capacity=128, chunk=chunk))
+    rows = np.random.default_rng(8).integers(0, N_ITEMS, size=23).tolist()
+    for upto in (20, 23):
+        model.answer({"items": [f"i{r}" for r in rows[:upto]], "num": 5})
+    stats = model.stats()
+    assert stats["prefill_runs"] > 0 and stats["extend_runs"] > 0
+    assert stats["extend_dense_expert_runs"] == stats["extend_runs"]
+    assert stats["prefill_dense_expert_runs"] == (
+        stats["prefill_runs"] if chunk <= moe_ops.TILE else 0)
+    assert stats["block_dense_expert_runs"] == stats["block_runs"] == 0
 
 
 def test_double_layer_topology_matches_the_reference(ref):

@@ -83,6 +83,16 @@ def _embed_update(N, B, E):
                 ((B, E), f32)], 1
 
 
+def _expert_stream(T, dim, expert_dim, n):
+    from predictionio_tpu.ops.pallas import expert_stream
+
+    bf16 = jnp.bfloat16
+    return expert_stream.expert_stream, [
+        ((T, dim), jnp.float32), ((T, n), jnp.float32),
+        ((n, dim, expert_dim), bf16), ((n, dim, expert_dim), bf16),
+        ((n, expert_dim, dim), bf16)], 1
+
+
 @pytest.mark.parametrize("build,args", [
     (_flash_ce, (8192, 128)),
     (_flash_ce, (4096, 64)),
@@ -98,11 +108,18 @@ def _embed_update(N, B, E):
     (_topk_dot, (9_400_000, 64, 16, 16, 1)),
     (_topk_dot, (9_400_000, 64, 32, 16, 1)),
     (_topk_dot, (9_400_000, 64, 64, 16, 1)),
+    # a small forward's expert layer: SDAR's block forward (8 rows x a block
+    # of 4, 128 experts of 768: a whole expert a step, 18.9 MB of VMEM) and
+    # LongCat's extension batch (8 x 8, 16 experts of 2048: chunks of 512
+    # columns, 37.7 MB), each within the VMEM limit it asks for
+    (_expert_stream, (32, 2048, 768, 128)),
+    (_expert_stream, (64, 6144, 2048, 16)),
 ], ids=["flash_ce-8192x128", "flash_ce-4096x64", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
         "embed_update-1M-8192x128", "topk_dot-16384x6144-B1",
         "topk_dot-16384x6144-B8", "topk_dot-9400000x64-B16",
-        "topk_dot-9400000x64-B32", "topk_dot-9400000x64-B64"])
+        "topk_dot-9400000x64-B32", "topk_dot-9400000x64-B64",
+        "expert_stream-32x2048-128x768", "expert_stream-64x6144-16x2048"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
     text = _compiled_text(fn, shapes, one_chip)
@@ -125,7 +142,8 @@ def _kernel_instructions(text):
     (_topk_dot, (26_744, 64, 1), ["topk_dot"]),
     (_flash_ce, (4096, 64),
      ["flash_ce_fwd", "flash_ce_bwd_du", "flash_ce_bwd_dv"]),
-], ids=["topk_dot", "flash_ce"])
+    (_expert_stream, (32, 2048, 768, 128), ["expert_stream"]),
+], ids=["topk_dot", "flash_ce", "expert_stream"])
 def test_a_kernels_instruction_carries_its_name(one_chip, no_compile_cache,
                                                 build, args, names):
     """A device trace's events are named by the instruction's text: the
@@ -182,6 +200,53 @@ def test_a_scope_reaches_xlas_own_fusions_through_the_scope_map(
     scopes = jaxmon.scope_map_of(text)
     fusions = {k: v for k, v in scopes.items() if k.startswith("fusion")}
     assert fusions and set(fusions.values()) == {"twotower.adagrad_user"}
+
+
+@pytest.mark.parametrize("T,dim,expert_dim,n,chunk", [
+    (32, 2048, 768, 128, 768), (64, 6144, 2048, 16, 512)],
+    ids=["sdar-block", "longcat-extend"])
+def test_a_small_forwards_expert_layer_is_one_kernel_under_its_scope(
+        one_chip, no_compile_cache, monkeypatch, T, dim, expert_dim, n,
+        chunk):
+    """``ops/moe.moe`` at the two cells' small shapes: one ``expert_stream``
+    kernel, found under ``<scope>.experts`` through the scope map (a device
+    trace's readers count it to the expert layer), with no sort beside it
+    and its weights read as they are stored (no table-sized copy)."""
+    from predictionio_tpu.obs import jaxmon
+    from predictionio_tpu.ops import moe as moe_ops
+    from predictionio_tpu.ops.pallas import expert_stream
+
+    # the layer asks the backend, which is the CPU here: steer it to the
+    # compiled kernel, as it chooses for itself on a TPU
+    monkeypatch.setenv("PIO_PALLAS_INTERPRET", "0")
+    dims = moe_ops.MoEDims(dim=dim, expert_dim=expert_dim, n_routed=n,
+                           n_zero=0, top_k=8, scale=1.0, held=(0, n),
+                           norm_topk=True)
+    assert expert_stream.chunk_of(dim, expert_dim, 2) == chunk
+    bf16 = jnp.bfloat16
+
+    def struct(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"w_r": struct(dim, n), "bias": struct(n, dtype=jnp.float32),
+         "w_g": struct(n, dim, expert_dim), "w_u": struct(n, dim, expert_dim),
+         "w_d": struct(n, expert_dim, dim)}
+
+    def layer(p, x, valid):
+        return moe_ops.moe(p, dims, x, valid, scope="seq.layer0.moe")
+
+    text = jax.jit(layer).lower(
+        p, struct(T, dim, dtype=jnp.float32),
+        struct(T, dtype=jnp.bool_)).compile().as_text()
+    kernels = _kernel_instructions(text)
+    assert len(kernels) == 1 and "expert_stream" in kernels[0], kernels
+    scopes = jaxmon.scope_map_of(text)
+    assert scopes[kernels[0]] == "seq.layer0.moe.experts"
+    # the router's top-k may sort; nothing of the expert part does
+    assert not [i for i, scope in scopes.items()
+                if "sort" in i and scope.endswith(".experts")]
+    assert not re.search(
+        rf"= bf16\[{n},\d+,\d+\]\S* (copy|transpose)\(", text)
 
 
 def test_the_kv_cache_is_left_as_it_is_handed_over(one_chip,
