@@ -211,36 +211,79 @@ class SessionRecAlgorithm(Algorithm):
 # ---------------------------------------------------------------------------
 
 class LatentCache:
-    """Which slot holds which session's cached positions (latents, or keys
-    and values). Host bookkeeping only: the values themselves are the device
-    arrays of ``ops.sessionrec.StackPrograms``; a slot here is the list of
-    item rows whose positions it holds, in order.
+    """Which slot holds which session's cache, of whatever kinds the stack's
+    mixers keep (latents, keys and values, recurrent state). Host bookkeeping
+    only: the values themselves are the device arrays of ``ops.sessionrec
+    .StackPrograms``; a slot here is the list of item rows whose positions it
+    holds, in order. A slot is busy while a query is being answered from it.
 
-    A query's rows are matched against the free slots by LONGEST COMMON
-    PREFIX. A slot is a hit when the shared prefix is at least half of what
-    the slot holds (the session grew, went back a little, or diverged late):
-    the query then extends from the end of the prefix, and what the slot held
-    beyond it is simply written over. Anything less is a miss: the least
-    recently used free slot is taken and the history prefilled from its
-    start. A slot is busy while a query is being answered from it.
+    How a query's rows are matched against the free slots is the stack's to
+    say:
 
-    ``block``: under a block-causal mask a position's cached values depend
-    on every position of its block, so only whole shared blocks are
-    reusable: the match rounds DOWN to a block boundary."""
+    * every mixer keeps a PER-POSITION cache: by longest common prefix. A
+      slot is a hit when the shared prefix is at least half of what the slot
+      holds (the session grew, went back a little, or diverged late): the
+      query then extends from the end of the prefix, and what the slot held
+      beyond it is simply written over. ``block``: under a block-causal mask
+      a position's cached values depend on every position of its block, so
+      only whole shared blocks are reusable: the match rounds DOWN to a block
+      boundary;
+    * some mixer keeps a RECURRENT state (``recurrent``): the state stands at
+      one position, the end of the slot's rows, and cannot be rewound, so a
+      slot is a hit only when ALL of its rows are a proper prefix of the
+      query's (the session grew). "Went back a little", "diverged late" and
+      a repeated query (its last position is always left to compute, and the
+      state is already past it) are misses for every layer together, the
+      per-position ones too; where the other rule would have hit, a marker
+      (``seq.cache.rewind_miss``) says so, and the session starts over in
+      the slot it had.
 
-    def __init__(self, n_slots: int, block: int = 1):
+    A miss takes the least recently used free slot and the history is
+    prefilled from its start — from a ZERO state: the programs start any
+    call at position 0 so, whatever the slot held."""
+
+    def __init__(self, n_slots: int, block: int = 1, recurrent: bool = False):
         self.rows = [np.zeros(0, np.int32) for _ in range(n_slots)]
         self.busy = [False] * n_slots
         self.used = [0] * n_slots
-        self.block = block
+        self.block, self.recurrent = block, recurrent
         self._clock = 0
         self.hit_tokens = self.miss_tokens = self.evictions = 0
+        #: hits of a stack with recurrent state; queries a per-position
+        #: cache would have hit and the state could not, and the positions
+        #: it would have found
+        self.state_resumes = self.rewind_misses = self.rewind_miss_tokens = 0
 
     @staticmethod
     def common_prefix(a: np.ndarray, b: np.ndarray) -> int:
         n = min(len(a), len(b))
         differ = np.flatnonzero(a[:n] != b[:n])
         return int(differ[0]) if len(differ) else n
+
+    def _match(self, free: List[int], rows: np.ndarray) -> Tuple[int, int]:
+        """``(slot, positions of it that the query reuses)``; 0: a miss,
+        which takes that slot from its start."""
+        shared = [self.common_prefix(self.rows[s], rows) for s in free]
+        if self.recurrent:
+            whole = [f for f, n in zip(free, shared)
+                     if 0 < n == len(self.rows[f]) < len(rows)]
+            if whole:
+                self.state_resumes += 1
+                slot = max(whole, key=lambda f: len(self.rows[f]))
+                return slot, len(self.rows[slot])
+        best = max(range(len(free)), key=shared.__getitem__)
+        slot, cached = free[best], min(shared[best], len(rows) - 1)
+        cached -= cached % self.block
+        if cached < 1 or 2 * shared[best] < len(self.rows[slot]):
+            return min(free, key=self.used.__getitem__), 0
+        if not self.recurrent:
+            return slot, cached
+        self.rewind_misses += 1
+        self.rewind_miss_tokens += cached
+        with trace.device_span("seq.cache.rewind_miss", slot=slot,
+                               shared=shared[best]):
+            pass
+        return slot, 0
 
     def acquire(self, rows: np.ndarray) -> Optional[Tuple[int, int]]:
         """``(slot, positions already cached)`` for a query over ``rows``,
@@ -250,17 +293,11 @@ class LatentCache:
         if not free:
             return None
         with trace.device_span("seq.cache.lookup", rows=len(rows)):
-            shared = [self.common_prefix(self.rows[s], rows) for s in free]
-            best = max(range(len(free)), key=shared.__getitem__)
-            slot, cached = free[best], min(shared[best], len(rows) - 1)
-            cached -= cached % self.block
-            if cached < 1 or 2 * shared[best] < len(self.rows[slot]):
-                cached = 0
-                slot = min(free, key=self.used.__getitem__)
-                if len(self.rows[slot]):
-                    self.evictions += 1
-                    with trace.device_span("seq.cache.evict", slot=slot):
-                        self.rows[slot] = np.zeros(0, np.int32)
+            slot, cached = self._match(free, rows)
+            if not cached and len(self.rows[slot]):
+                self.evictions += 1
+                with trace.device_span("seq.cache.evict", slot=slot):
+                    self.rows[slot] = np.zeros(0, np.int32)
             # a span's attributes are set as it opens: the outcome rides on
             # a marker inside the lookup
             with trace.device_span("seq.cache.found", slot=slot,
@@ -273,7 +310,8 @@ class LatentCache:
         return slot, cached
 
     def release(self, slot: int, rows: np.ndarray) -> None:
-        """The slot now holds exactly ``rows``' positions."""
+        """The slot now holds exactly ``rows``' positions (a recurrent
+        state: it stands at their end)."""
         self._clock += 1
         self.rows[slot] = rows
         self.used[slot] = self._clock
@@ -324,7 +362,8 @@ class SeqTicket:
 
 
 class SeqStackModel:
-    """A block stack with cached mixers behind the ``items`` query, served in
+    """A block stack with cached mixers (per-position caches, recurrent
+    states, or both in one stack) behind the ``items`` query, served in
     STEPS: each step runs every pending extension (a few new positions each)
     as one batch, one block forward over the tickets that generate, and at
     most one prefill chunk of the oldest session that still has a history to
@@ -332,8 +371,8 @@ class SeqStackModel:
     :meth:`step` through :class:`SeqStackAlgorithm`; :meth:`recommend` drives
     them to the end for one query (``predict``).
 
-    A latent-attention stack answers ``{"items", "num"}`` once, from the last
-    position's hidden state through the head (the exact retrieval index over
+    A stack that does not generate answers ``{"items", "num"}`` once, from the
+    last position's hidden state through the head (the exact retrieval index over
     the output embedding, as for every other model's final projection). A
     block-diffusion stack (``StackSpec.generation``) answers ``{"items",
     "generate": n}`` with ``n`` items in position order, each with the logit
@@ -350,8 +389,11 @@ class SeqStackModel:
         self.spec, self.params, self.item_ids = spec, params, item_ids
         self.shape = shape or ServeShape()
         self.gen = spec.generation
+        #: the kinds of cache the stack's mixers keep
+        self.kinds = {b.mixer for b in spec.blocks}
         self.cache = LatentCache(self.shape.n_slots,
-                                 self.gen.block_len if self.gen else 1)
+                                 self.gen.block_len if self.gen else 1,
+                                 recurrent="mamba2" in self.kinds)
         self._programs = None
         self._index = None
         self._inverse = None
@@ -360,7 +402,9 @@ class SeqStackModel:
         # ("extend" / "prefill" / "block"): runs, real tokens, (token, pick)
         # pairs that reached a held expert, held experts that got any token
         # (per layer, summed), zero-compute picks; the cached positions the
-        # rows' attention read (extensions: latents; blocks: keys and values);
+        # rows' attention read (extensions: latents, or keys and values;
+        # blocks: keys and values) and the rows whose recurrent states an
+        # extension read and wrote, each where the stack has such a mixer;
         # runs whose expert layers took the small forward's form (all of a
         # kind's or none: the program's shape decides, ``ops/moe.small_forward``)
         self.counters = {
@@ -369,6 +413,7 @@ class SeqStackModel:
                          "zero_picks", "dense_expert_runs")}
         self.counters.update({
             "extend_rows": 0, "extend_latent_positions": 0,
+            "extend_kv_positions": 0, "extend_state_rows": 0,
             "extensions_waited": 0,
             # block forwards: rows by kind (a known block of a history is a
             # commit row), positions the rule unmasked, queries answered
@@ -404,7 +449,9 @@ class SeqStackModel:
         c = self.cache
         return {**self.counters, "steps": self.steps,
                 "hit_tokens": c.hit_tokens, "miss_tokens": c.miss_tokens,
-                "evictions": c.evictions,
+                "evictions": c.evictions, "state_resumes": c.state_resumes,
+                "rewind_misses": c.rewind_misses,
+                "rewind_miss_tokens": c.rewind_miss_tokens,
                 "index": self._index.stats() if self._index else None}
 
     # -- one query, in steps --------------------------------------------------
@@ -451,6 +498,8 @@ class SeqStackModel:
         return ticket
 
     def cancel(self, ticket: SeqTicket) -> None:
+        """The slot goes back holding what the ticket finished: ``done``
+        positions, which is also where a recurrent state stands."""
         if ticket.slot is not None:
             self.cache.release(ticket.slot, ticket.held())
 
@@ -487,8 +536,13 @@ class SeqStackModel:
                     h.block_until_ready()
                 self._count("extend", counted)
                 self.counters["extend_rows"] += len(ext)
-                self.counters["extend_latent_positions"] += sum(
-                    len(t.rows) for t in ext)
+                reach = sum(len(t.rows) for t in ext)
+                for kind, counter, n in (
+                        ("mla", "extend_latent_positions", reach),
+                        ("gqa", "extend_kv_positions", reach),
+                        ("mamba2", "extend_state_rows", len(ext))):
+                    if kind in self.kinds:
+                        self.counters[counter] += n
                 for t in ext:
                     # born at step b, first step it could join is b + 1
                     if self.steps - t.born > 1 and t.extension:
@@ -648,8 +702,9 @@ class SeqStackParams(Params):
 
 class SeqStackAlgorithm(Algorithm):
     """Serves a :class:`SeqStackModel`: a stack whose mixers keep a per-
-    session cache (latent attention, or grouped-query attention under a
-    block-causal mask). Training such a stack is not this system's yet
+    session cache (latent attention, grouped-query attention, state-space
+    mixers; the stack's ``StackSpec`` says which, block by block, and
+    whether it generates). Training such a stack is not this system's yet
     (``ROADMAP.md`` §2): its model arrives through ``core.persistent_model``
     from whoever holds its weights."""
 

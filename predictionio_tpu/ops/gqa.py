@@ -1,15 +1,20 @@
-"""Grouped-query attention under a BLOCK-CAUSAL mask, over a per-session
-cache of keys and values: the mixer of a block-diffusion stack.
+"""Grouped-query attention over a per-session cache of keys and values, as
+its ``GQADims`` say: the mixer of a block-diffusion stack (a block-causal
+mask, per-head norms, RoPE) and the attention layer of a stack of recurrent
+mixers (a causal mask, no position encoding, no norms, a score scale of the
+model's own).
 
 ``heads`` query heads share ``kv_heads`` key/value heads (``heads //
-kv_heads`` to one). Queries and keys are RMS-normed per head over the head's
-own dimensions (a learned weight of ``head_dim`` each) and turned by RoPE on
-all of them, pairs ``(i, i + head_dim / 2)`` (rotate-half), at absolute
-positions. Position ``i`` sees ``j`` iff ``j // block_len <= i // block_len``:
-causal from block to block, bidirectional inside one. Every key a position
-sees therefore lies at or before the LAST position of its own block, which
-is the position the causal loop of ``ops.attention.attend_over_blocks`` is
-given for it.
+kv_heads`` to one). Where the stack says so (``qk_norm``, ``rope``), queries
+and keys are RMS-normed per head over the head's own dimensions (a learned
+weight of ``head_dim`` each) and turned by RoPE on all of them, pairs ``(i,
+i + head_dim / 2)`` (rotate-half), at absolute positions. Scores are scaled
+by ``scale`` (``1 / sqrt(head_dim)`` where none is given). Position ``i``
+sees ``j`` iff ``j // block_len <= i // block_len``: causal from block to
+block, bidirectional inside one; a block length of 1 is the causal mask.
+Every key a position sees therefore lies at or before the LAST position of
+its own block, which is the position the causal loop of
+``ops.attention.attend_over_blocks`` is given for it.
 
 What a cache holds is a position's keys and values themselves, ``2 *
 kv_heads * head_dim`` values side by side (keys first), in the cache's type.
@@ -18,7 +23,8 @@ Two paths over one set of weights, as in ``ops/mla.py``:
 * :func:`prefill_chunk`: whole blocks of ONE session against its slot;
 * :func:`block_step`: one block (``block_len`` positions) of each of several
   sessions against their slots — a block being denoised or a finished block
-  being committed, the program is the same.
+  being committed, the program is the same. Under the causal mask the same
+  function is an EXTENSION (:data:`extend`): a few new positions a row.
 
 Both WRITE the keys and values of their positions and then attend over the
 slot up to the end of those positions. A block's keys and values depend on
@@ -36,6 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,9 +57,12 @@ class GQADims:
     heads: int
     kv_heads: int
     head_dim: int
-    block_len: int = 4          # positions of one block of the mask
+    block_len: int = 4          # positions of one block of the mask; 1: causal
     rope_theta: float = 1e6
     eps: float = 1e-6
+    rope: bool = True           # False: no position encoding
+    qk_norm: bool = True        # False: queries and keys as projected
+    scale: Optional[float] = None    # of the scores; None: head_dim ** -0.5
 
     @property
     def group(self) -> int:
@@ -75,8 +85,9 @@ def init(key, dims: GQADims, dtype=jnp.float32) -> dict:
                ).astype(dtype)
            for (n, s), k in zip(shapes.items(),
                                 jax.random.split(key, len(shapes)))}
-    out["q_norm"] = jnp.ones((d.head_dim,), dtype)
-    out["k_norm"] = jnp.ones((d.head_dim,), dtype)
+    if d.qk_norm:
+        out["q_norm"] = jnp.ones((d.head_dim,), dtype)
+        out["k_norm"] = jnp.ones((d.head_dim,), dtype)
     return out
 
 
@@ -95,14 +106,18 @@ def rope_half(x, pos, theta):
 def project(p, dims: GQADims, x, pos):
     """Queries, keys and values of the positions ``x`` [..., T, dim]:
     ``(q [..., T, heads, d], k [..., T, kv_heads, d], v alike)``, float32,
-    queries and keys normed and turned."""
+    queries and keys normed and turned where the stack's are."""
     d = dims
     lead = x.shape[:-1]
     q = mm(x, p["w_q"]).reshape(lead + (d.heads, d.head_dim))
     k = mm(x, p["w_k"]).reshape(lead + (d.kv_heads, d.head_dim))
     v = mm(x, p["w_v"]).reshape(lead + (d.kv_heads, d.head_dim))
-    q = rope_half(rms_norm(q, p["q_norm"], d.eps), pos, d.rope_theta)
-    k = rope_half(rms_norm(k, p["k_norm"], d.eps), pos, d.rope_theta)
+    if d.qk_norm:
+        q = rms_norm(q, p["q_norm"], d.eps)
+        k = rms_norm(k, p["k_norm"], d.eps)
+    if d.rope:
+        q = rope_half(q, pos, d.rope_theta)
+        k = rope_half(k, pos, d.rope_theta)
     return q, k, v
 
 
@@ -124,7 +139,8 @@ def attend_full(p, dims: GQADims, x, pos):
     q, k, v = project(p, d, x, pos)
     q = q.reshape(T, d.kv_heads, d.group, d.head_dim)
     s = jnp.einsum("tkgd,ukd->kgtu", q, k,
-                   precision=jax.lax.Precision.HIGHEST) / math.sqrt(d.head_dim)
+                   precision=jax.lax.Precision.HIGHEST)
+    s = s / math.sqrt(d.head_dim) if d.scale is None else s * d.scale
     sees = block_end(pos, d.block_len)[:, None] >= pos[None, :]
     prob = jax.nn.softmax(jnp.where(sees[None, None], s, -jnp.inf), axis=-1)
     o = jnp.einsum("kgtu,ukd->tkgd", prob, v,
@@ -160,7 +176,7 @@ def _attend(dims: GQADims, q, pos, cache, slots, n_blocks, block: int):
         return rows[..., :half].reshape(shape), rows[..., half:].reshape(shape)
 
     o = attend_over_blocks(q, q_pos, kv_block, n_blocks, block, d.head_dim,
-                           dtype=jnp.float32)
+                           dtype=jnp.float32, scale=d.scale)
     o = o.reshape(B, S, d.group, d.kv_heads, d.head_dim)
     return o.transpose(0, 1, 3, 2, 4).reshape(B, S, d.heads, d.head_dim)
 
@@ -193,3 +209,8 @@ def block_step(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int):
         cache = jax.lax.dynamic_update_slice(
             cache, rows[b][None], (slots[b], pos[b, 0], 0))
     return _out(p, _attend(dims, q, pos, cache, slots, n_blocks, block)), cache
+
+
+#: under the causal mask (``block_len`` 1) a row's positions are a few new
+#: positions of its session, each seeing the ones before it: an extension
+extend = block_step

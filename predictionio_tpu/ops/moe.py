@@ -6,7 +6,9 @@ what the routed experts it holds (``held = (e0, n)``) add for the tokens sent
 to them, plus the zero-compute experts' part (``g_i * x``: no weights, no
 matrix product). What the absent experts would add is left out: on one chip
 of a deployment that divides a layer's experts over many, that is the part
-the exchange would bring, and nothing here stands in for it.
+the exchange would bring, and nothing here stands in for it. A model may put
+a SHARED expert beside the routed ones (``shared_dim``): every token runs
+through it, ungated, and every chip of the deployment holds it.
 
 Routing: ``p = softmax(x W_r)`` in float32 at the highest matmul precision,
 ``S = top_k(p + bias)``, gate ``g_i = scale * p_i`` (the selected expert's own
@@ -80,6 +82,7 @@ class MoEDims:
     scale: float                     # routed_scaling_factor
     held: Tuple[int, int]            # (first routed expert held, how many)
     norm_topk: bool = False          # gates renormalised over the picks
+    shared_dim: int = 0              # the shared expert's width; 0: none
 
     @property
     def n_router(self) -> int:
@@ -94,12 +97,19 @@ def init(key, dims: MoEDims, dtype=jnp.float32, bias_std: float = 0.0) -> dict:
         return (jax.random.normal(k, shape, jnp.float32)
                 / math.sqrt(fan_in)).astype(dtype)
 
-    return {"w_r": normal(kr, (d.dim, d.n_router), d.dim),
-            "bias": bias_std * jax.random.normal(kb, (d.n_router,),
-                                                 jnp.float32),
-            "w_g": normal(kg, (n, d.dim, d.expert_dim), d.dim),
-            "w_u": normal(ku, (n, d.dim, d.expert_dim), d.dim),
-            "w_d": normal(kd, (n, d.expert_dim, d.dim), d.expert_dim)}
+    out = {"w_r": normal(kr, (d.dim, d.n_router), d.dim),
+           "bias": bias_std * jax.random.normal(kb, (d.n_router,),
+                                                jnp.float32),
+           "w_g": normal(kg, (n, d.dim, d.expert_dim), d.dim),
+           "w_u": normal(ku, (n, d.dim, d.expert_dim), d.dim),
+           "w_d": normal(kd, (n, d.expert_dim, d.dim), d.expert_dim)}
+    if d.shared_dim:
+        kg, ku, kd = jax.random.split(jax.random.fold_in(key, 5), 3)
+        out["shared"] = {
+            "w_g": normal(kg, (d.dim, d.shared_dim), d.dim),
+            "w_u": normal(ku, (d.dim, d.shared_dim), d.dim),
+            "w_d": normal(kd, (d.shared_dim, d.dim), d.shared_dim)}
+    return out
 
 
 def route(p, dims: MoEDims, x):
@@ -196,6 +206,9 @@ def moe(p, dims: MoEDims, x, valid, scope: str = "moe"):
         experts = (experts_streamed if small_forward(x.shape[0])
                    else experts_sorted)
         routed, load = experts(p, dims, x, idx, gates, valid)
+    if dims.shared_dim:
+        with jax.named_scope(scope + ".shared"):
+            routed = routed + swiglu(x, **p["shared"])
     if not dims.n_zero:
         return routed, {"expert_load": load, "zero_picks": jnp.int32(0)}
     with jax.named_scope(scope + ".zero"):

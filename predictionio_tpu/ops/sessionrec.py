@@ -37,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from predictionio_tpu.ops import gqa as gqa_ops
 from predictionio_tpu.ops import mla as mla_ops
 from predictionio_tpu.ops import moe as moe_ops
+from predictionio_tpu.ops import ssm as ssm_ops
 from predictionio_tpu.ops.attention import (
     blockwise_attention,
     mha_reference,
@@ -45,6 +46,7 @@ from predictionio_tpu.ops.attention import (
 from predictionio_tpu.ops.gqa import GQADims
 from predictionio_tpu.ops.mla import MLADims
 from predictionio_tpu.ops.moe import MoEDims
+from predictionio_tpu.ops.ssm import SSMDims
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,7 +84,8 @@ class SessionRecConfig:
 class BlockSpec:
     """One block of the stack, by the kinds of its parts."""
 
-    #: "mha" (q/k/v heads) | "mla" (ops/mla.py) | "gqa" (ops/gqa.py)
+    #: "mha" (q/k/v heads) | "mla" (ops/mla.py) | "gqa" (ops/gqa.py) |
+    #: "mamba2" (ops/ssm.py); the blocks of one stack may differ in it
     mixer: str = "mha"
     #: "gelu_mlp" | "swiglu" | "moe" (the expert layer, ops/moe.py, as the
     #: block's whole FFN: "pre_ln" only)
@@ -150,7 +153,9 @@ class StackSpec:
     """A stack of blocks over item embeddings. The toy next-item model is
     one such configuration (:meth:`SessionRecConfig.stack`), a latent-
     attention expert model another, a block-diffusion expert model with
-    grouped-query attention a third; all run through :func:`apply_block`."""
+    grouped-query attention a third, a stack of state-space mixers with an
+    attention layer among every few a fourth; all run through
+    :func:`apply_block`."""
 
     dim: int
     ffn_dim: int
@@ -159,10 +164,15 @@ class StackSpec:
     positions: str = "learned"           # "learned" (added) | "rope" (mixer's)
     max_len: int = 0                     # learned positions
     embed_scale: float = 1.0
+    #: what a mixer's and an FFN's output is multiplied by before it joins
+    #: the residual stream, and the final hidden state before the head
+    residual_scale: float = 1.0
+    logits_scale: float = 1.0
     eps: float = 1e-6
     tied_head: bool = True               # scores against the item embedding
     mla: Optional[MLADims] = None
     gqa: Optional[GQADims] = None
+    ssm: Optional[SSMDims] = None
     moe: Optional[MoEDims] = None
     #: how the stack generates, where it does (a "gqa" stack under the
     #: block-causal mask); None: a query is answered once, from the head
@@ -195,20 +205,26 @@ def apply_block(spec: StackSpec, block: BlockSpec, p, x, mix, *,
     chunked prefill and extension (positions, the cache). ``moe(params, h)``
     likewise, where the topology has an expert layer."""
     norm = functools.partial(NORMS[block.norm], eps=spec.eps)
+    r = spec.residual_scale
+
+    def scaled(out):
+        return out if r == 1.0 else r * out
 
     def scoped(name, fn, *args):
         with jax.named_scope(f"{scope}.{name}"):
-            return fn(*args)
+            return scaled(fn(*args))
 
     mixer = block.mixer
     h1 = x + drop(scoped(f"{mixer}_a", mix, "a", p["mixer_a"],
                          norm(p["norm_a"], x)))
     u = norm(p["norm_ffn_a"], h1)
     if block.topology == "pre_ln" and block.ffn == "moe":
-        return h1 + moe(p["moe"], u)
+        return h1 + scaled(moe(p["moe"], u))
     ffn = FFNS[block.ffn]
     if block.topology == "pre_ln":
         return h1 + drop(scoped("ffn_a", ffn, p["ffn_a"], u))
+    if r != 1.0:
+        raise ValueError("a residual scale is a 'pre_ln' block's")
     if block.topology != "scmoe":
         raise ValueError(f"unknown block topology {block.topology!r}")
     m = moe(p["moe"], u)
@@ -231,6 +247,8 @@ def _init_mixer(spec: StackSpec, block: BlockSpec, key, dtype):
         return mla_ops.init(key, spec.mla, dtype)
     if block.mixer == "gqa":
         return gqa_ops.init(key, spec.gqa, dtype)
+    if block.mixer == "mamba2":
+        return ssm_ops.init(key, spec.ssm, dtype)
     k1, k2 = jax.random.split(key)
     head_dim = spec.dim // spec.heads
     lecun = jax.nn.initializers.lecun_normal
@@ -680,18 +698,27 @@ class ServeShape:
 
 
 class StackPrograms:
-    """The compiled serve path of a stack whose mixers keep a per-position
-    cache, all of one kind. Every kind has ``prefill`` (one chunk of one
-    session against its slot); beside it
+    """The compiled serve path of a stack whose mixers each keep something
+    per session: a cache PER MIXER, of that mixer's kind, in one list.
 
-    * latent attention (``"mla"``): ``extend`` (a few new positions of
-      several sessions, absorbed form), over a cache of ``[n_slots + 1,
-      capacity + chunk, latent]`` per mixer;
-    * grouped-query attention under the block-causal mask (``"gqa"``):
-      ``block`` (one block of each of several sessions, denoised or
-      committed, with the head and the unmasking rule inside), over a cache
-      of ``[n_slots + 1, capacity + chunk, 2 * kv_heads * head_dim]`` (keys,
-      then values) per mixer.
+    * latent attention (``"mla"``): ``[n_slots + 1, capacity + chunk,
+      latent]``, a position's latent;
+    * grouped-query attention (``"gqa"``): ``[n_slots + 1, capacity + chunk,
+      2 * kv_heads * head_dim]``, a position's keys, then its values;
+    * a state-space mixer (``"mamba2"``): ``{conv [n_slots + 1, d_conv - 1,
+      conv_dim], ssm [n_slots + 1, heads, head_dim, d_state]}``, the
+      session's recurrent state at ONE position (the caller may then
+      resume a slot only from the end of what it holds).
+
+    The blocks of a stack may differ in their mixer. Every stack has
+    ``prefill`` (one chunk of one session against its slot); beside it a
+    stack that answers once has ``extend`` (a few new positions of several
+    sessions: absorbed latent attention, causal grouped-query attention, the
+    recurrence from each slot's state), and a stack that generates
+    (``StackSpec.generation``: grouped-query attention under the block-causal
+    mask in every block) has ``block`` (one block of each of several
+    sessions, denoised or committed, with the head and the unmasking rule
+    inside).
 
     The extra slot is scratch for the padding rows of a batch; the extra
     chunk of positions lets the last chunk of a full slot be written whole.
@@ -704,27 +731,35 @@ class StackPrograms:
     (``models/sessionrec.LatentCache``)."""
 
     def __init__(self, spec: StackSpec, params: Dict, shape: ServeShape):
-        kinds = {b.mixer for b in spec.blocks}
-        if kinds not in ({"mla"}, {"gqa"}):
+        #: the mixers' kinds, in the order of their caches
+        self.kinds = [b.mixer for b in spec.blocks
+                      for _ in range(2 if b.topology == "scmoe" else 1)]
+        if not set(self.kinds) <= {"mla", "gqa", "mamba2"}:
             raise ValueError(
-                "stepwise serving needs mixers that keep a per-position "
-                "cache, all of one kind ('mla' or 'gqa'): got "
-                f"{sorted(kinds)}")
+                "stepwise serving needs mixers that keep a per-session "
+                "cache ('mla', 'gqa' or 'mamba2'): got "
+                f"{sorted(set(self.kinds))}")
         from predictionio_tpu.obs import jaxmon
 
         self.spec, self.shape, self.params = spec, shape, params
-        self.kind = kinds.pop()
-        #: the one cached mixer's module and its sizes
-        self.mixer, self.dims = ((mla_ops, spec.mla) if self.kind == "mla"
-                                 else (gqa_ops, spec.gqa))
+        gen = spec.generation
+        if "gqa" in self.kinds and spec.gqa.block_len != (
+                gen.block_len if gen else 1):
+            raise ValueError(
+                "a 'gqa' stack under a block-causal mask is served by block "
+                "diffusion: its StackSpec.generation must be set, with the "
+                "mask's block length")
+        if gen is not None and set(self.kinds) != {"gqa"}:
+            raise ValueError("a stack that generates has 'gqa' mixers only")
         dtype = params["item_embed"]["embedding"].dtype
-        n_mixers = sum(2 if b.topology == "scmoe" else 1
-                       for b in spec.blocks)
-        width = (mla_ops.cache_width(spec.mla) if self.kind == "mla"
-                 else spec.gqa.cache_width)
-        self.cache = [jnp.zeros((shape.n_slots + 1,
-                                 shape.capacity + shape.chunk, width), dtype)
-                      for _ in range(n_mixers)]
+        positions = (shape.n_slots + 1, shape.capacity + shape.chunk)
+        self.cache = [
+            ssm_ops.init_state(spec.ssm, shape.n_slots + 1, dtype)
+            if kind == "mamba2" else
+            jnp.zeros(positions + (mla_ops.cache_width(spec.mla)
+                                   if kind == "mla"
+                                   else spec.gqa.cache_width,), dtype)
+            for kind in self.kinds]
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
         #: tokens of a call of each program that this stack compiles: the
         #: shape by which ``ops/moe.moe`` chooses its form
@@ -736,7 +771,7 @@ class StackPrograms:
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=1).lower(
             params, self.cache, struct(shape.chunk), i32, i32, i32).compile()
         compiled = [self._prefill]
-        if self.kind == "mla":
+        if gen is None:
             B, S = shape.extend_batch, shape.extend_len
             self.tokens["extend"] = B * S
             self._extend = jax.jit(self._extend_fn, donate_argnums=1).lower(
@@ -744,12 +779,6 @@ class StackPrograms:
                 struct(B), i32).compile()
             compiled.append(self._extend)
         else:
-            gen = spec.generation
-            if gen is None or gen.block_len != spec.gqa.block_len:
-                raise ValueError(
-                    "a 'gqa' stack is served by block diffusion: its "
-                    "StackSpec.generation must be set, with the mask's "
-                    "block length")
             if shape.chunk % gen.block_len or shape.capacity % gen.block_len:
                 raise ValueError("chunk and capacity must be multiples of "
                                  "the block length")
@@ -765,14 +794,16 @@ class StackPrograms:
     # -- the programs ---------------------------------------------------------
     def _run(self, params, x, valid, mix_with):
         """The blocks over tokens ``x`` [T, dim] (float32 residual stream);
-        ``mix_with(i_mixer)`` gives block code its mixer."""
+        ``mix_with(i_mixer, params, h, scope)`` gives block code its
+        mixer."""
         spec = self.spec
         loads, zeros, i_mixer = [], [], 0
         for i, block in enumerate(spec.blocks):
             mixers = {"a": i_mixer, "b": i_mixer + 1}
 
-            def mix(which, p, h, _m=mixers):
-                return mix_with(_m[which], p, h)
+            def mix(which, p, h, _m=mixers, _i=i, _kind=block.mixer):
+                return mix_with(_m[which], p, h,
+                                f"seq.layer{_i}.{_kind}_{which}")
 
             def moe(p, h, _i=i):
                 y, counted = moe_ops.moe(p, spec.moe, h, valid,
@@ -791,8 +822,10 @@ class StackPrograms:
         return x, counters
 
     def _final(self, params, h):
-        return NORMS[self.spec.blocks[-1].norm](
+        h = NORMS[self.spec.blocks[-1].norm](
             params["final_norm"], h, self.spec.eps)
+        scale = self.spec.logits_scale
+        return h if scale == 1.0 else scale * h
 
     def _embed(self, params, ids):
         return (params["item_embed"]["embedding"][ids].astype(jnp.float32)
@@ -800,11 +833,20 @@ class StackPrograms:
 
     def _prefill_fn(self, params, cache, ids, n_valid, slot, offset):
         cache = list(cache)
+        spec, chunk = self.spec, self.shape.chunk
         valid = jnp.arange(ids.shape[0]) < n_valid
 
-        def mix_with(m, p, h):
-            out, cache[m] = self.mixer.prefill_chunk(
-                p, self.dims, h, offset, cache[m], slot, self.shape.chunk)
+        def mix_with(m, p, h, scope):
+            kind = self.kinds[m]
+            if kind == "mamba2":
+                out, cache[m] = ssm_ops.prefill_chunk(
+                    p, spec.ssm, h, n_valid, offset, cache[m], slot, scope)
+            elif kind == "mla":
+                out, cache[m] = mla_ops.prefill_chunk(
+                    p, spec.mla, h, offset, cache[m], slot, chunk)
+            else:
+                out, cache[m] = gqa_ops.prefill_chunk(
+                    p, spec.gqa, h, offset, cache[m], slot, chunk)
             return out
 
         x, counters = self._run(params, self._embed(params, ids), valid,
@@ -813,14 +855,22 @@ class StackPrograms:
 
     def _extend_fn(self, params, cache, ids, n_new, slots, pos0, n_blocks):
         cache = list(cache)
+        spec, chunk = self.spec, self.shape.chunk
         B, S = ids.shape
         pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
         valid = (jnp.arange(S)[None] < n_new[:, None]).reshape(-1)
 
-        def mix_with(m, p, h):
-            out, cache[m] = mla_ops.extend(
-                p, self.spec.mla, h.reshape(B, S, -1), pos, cache[m], slots,
-                n_blocks, self.shape.chunk)
+        def mix_with(m, p, h, scope):
+            kind, h = self.kinds[m], h.reshape(B, S, -1)
+            if kind == "mamba2":
+                out, cache[m] = ssm_ops.extend(
+                    p, spec.ssm, h, n_new, pos0, cache[m], slots, scope)
+            elif kind == "mla":
+                out, cache[m] = mla_ops.extend(
+                    p, spec.mla, h, pos, cache[m], slots, n_blocks, chunk)
+            else:
+                out, cache[m] = gqa_ops.extend(
+                    p, spec.gqa, h, pos, cache[m], slots, n_blocks, chunk)
             return out.reshape(B * S, -1)
 
         x, counters = self._run(
@@ -843,7 +893,7 @@ class StackPrograms:
         pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
         valid = jnp.repeat(slots != self.shape.n_slots, S)
 
-        def mix_with(m, p, h):
+        def mix_with(m, p, h, scope):
             out, cache[m] = gqa_ops.block_step(
                 p, self.spec.gqa, h.reshape(B, S, -1), pos, cache[m], slots,
                 n_blocks, self.shape.chunk)
@@ -884,7 +934,9 @@ class StackPrograms:
 
     def extend(self, rows):
         """``rows``: [(ids, slot, position of ids[0])], at most
-        ``extend_batch`` of at most ``extend_len`` ids each.
+        ``extend_batch`` of at most ``extend_len`` ids each; in a stack with
+        recurrent state ``position of ids[0]`` is where the slot's state
+        stands.
         ``(h_last [extend_batch, dim], counters)``, still on the device."""
         sh = self.shape
         ids = np.zeros((sh.extend_batch, sh.extend_len), np.int32)

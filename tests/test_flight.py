@@ -185,6 +185,10 @@ def test_admin_flight_returns_recorded_requests(flight_server):
                                      {"mult": mult})
         assert status == 200 and json.loads(body) == {"result": 3.0 * mult}
         trace_ids.append(headers[trace.TRACE_HEADER])
+    # a record seals after its answer has left (``pio:http.finish``): the
+    # third may still be open when its client reads the ring
+    for trace_id in trace_ids:
+        _await_sealed(trace_id)
 
     status, _, body = http("GET", f"{base}/admin/flight")
     assert status == 200

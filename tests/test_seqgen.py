@@ -361,7 +361,7 @@ def test_stack_programs_refuse_mixed_or_uncached_mixers():
     params = seeded_params(spec)
     mixed = dataclasses.replace(spec, blocks=(
         spec.blocks[0], dataclasses.replace(spec.blocks[0], mixer="mha")))
-    with pytest.raises(ValueError, match="one kind"):
+    with pytest.raises(ValueError, match="per-session"):
         StackPrograms(mixed, params, SHAPE)
     with pytest.raises(ValueError, match="generation"):
         StackPrograms(dataclasses.replace(spec, generation=None), params,

@@ -281,3 +281,47 @@ def test_the_kv_cache_is_left_as_it_is_handed_over(one_chip,
     assert layouts == {"{2,1,0:T(8,128)(2,1)}"}, layouts
     assert not re.search(r"= bf16\[33,4608,1024\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("path", ["prefill_chunk", "extend"])
+def test_a_recurrent_state_is_stepped_in_place(one_chip, no_compile_cache,
+                                               path):
+    """One Mamba-2 mixer at the benchmark's widths (hidden 4096, 128 heads of
+    64, a state of 128; 33 slots): a chunk of 512 positions of one session,
+    and an extension of 16 rows of 4. The donated states keep the buffers
+    they arrive in — no copy of the 138 MB of ``S`` — and the chunked scan's
+    temporaries stay well under the chip's room."""
+    from predictionio_tpu.ops import ssm
+
+    dims = ssm.SSMDims(dim=4096, heads=128, head_dim=64, d_state=128)
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+    def struct(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"w_in": struct(4096, dims.in_width), "conv_w": struct(4, 8448),
+         "conv_b": struct(8448), "dt_bias": struct(128, dtype=f32),
+         "a_log": struct(128, dtype=f32), "d": struct(128, dtype=f32),
+         "norm": struct(8192), "w_out": struct(8192, 4096)}
+    state = {"conv": struct(33, 3, 8448),
+             "ssm": struct(33, 128, 64, 128, dtype=f32)}
+    if path == "prefill_chunk":
+        def step(p, a, n_valid, offset, state, slot):
+            return ssm.prefill_chunk(p, dims, a, n_valid, offset, state, slot,
+                                     "seq.layer0.mamba2_a")
+        args = (p, struct(512, 4096, dtype=f32), struct(dtype=i32),
+                struct(dtype=i32), state, struct(dtype=i32))
+    else:
+        def step(p, a, n_new, pos0, state, slots):
+            return ssm.extend(p, dims, a, n_new, pos0, state, slots,
+                              "seq.layer0.mamba2_a")
+        args = (p, struct(16, 4, 4096, dtype=f32), struct(16, dtype=i32),
+                struct(16, dtype=i32), state, struct(16, dtype=i32))
+    compiled = jax.jit(step, donate_argnums=4).lower(*args).compile()
+    text = compiled.as_text()
+    assert not re.search(r"= f32\[33,128,64,128\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+    from predictionio_tpu.obs import jaxmon
+    scopes = set(jaxmon.scope_map_of(text).values())
+    assert {f"seq.layer0.mamba2_a.ssm.{part}" for part in (
+        "in_proj", "conv", "scan", "out_proj")} <= scopes
