@@ -406,11 +406,15 @@ class SeqStackModel:
         # blocks: keys and values) and the rows whose recurrent states an
         # extension read and wrote, each where the stack has such a mixer;
         # runs whose expert layers took the small forward's form (all of a
-        # kind's or none: the program's shape decides, ``ops/moe.small_forward``)
+        # kind's or none: the program's shape decides,
+        # ``ops/moe.small_forward``) and, for the others, the products of
+        # sorted rows their grouped kernels ran (over ``experts_touched``:
+        # how many products shared one read of an expert)
         self.counters = {
             f"{kind}_{what}": 0 for kind in ("extend", "prefill", "block")
             for what in ("runs", "tokens", "held_picks", "experts_touched",
-                         "zero_picks", "dense_expert_runs")}
+                         "zero_picks", "dense_expert_runs",
+                         "expert_row_tiles")}
         self.counters.update({
             "extend_rows": 0, "extend_latent_positions": 0,
             "extend_kv_positions": 0, "extend_state_rows": 0,
@@ -646,8 +650,11 @@ class SeqStackModel:
             load = np.asarray(counted["expert_load"], np.int64)
             c[f"{kind}_held_picks"] += int(load.sum())
             c[f"{kind}_experts_touched"] += int((load > 0).sum())
-            c[f"{kind}_dense_expert_runs"] += moe_ops.small_forward(
-                self._programs.tokens[kind])
+            small = moe_ops.small_forward(self._programs.tokens[kind])
+            c[f"{kind}_dense_expert_runs"] += small
+            if not small:
+                c[f"{kind}_expert_row_tiles"] += int(
+                    moe_ops.row_tiles(load).sum())
             c[f"{kind}_zero_picks"] += int(
                 np.asarray(counted["zero_picks"]).sum())
             c["load_max_sum"] += float(load.max(axis=1).sum())

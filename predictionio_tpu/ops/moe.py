@@ -17,39 +17,51 @@ the picks: ``g_i = scale * p_i / sum_{j in S} p_j``.
 
 What the held experts add is computed in one of two forms, chosen by the
 number of tokens ``T`` of the forward, a static shape (:func:`small_forward`;
-no option, model name or environment variable is asked):
+no option, model name or environment variable is asked). Both are ONE Pallas
+kernel a layer whose grid walks the TOUCHED experts, reading expert ``e + 1``
+while ``e``'s products run; an expert no token picked is not read, and each
+touched expert's matrices cross HBM once a forward:
 
 * ``T <= TILE`` (a block forward's 8 x 4 tokens, an extension batch's 8 x 8):
   :func:`experts_streamed`. All ``T`` rows stay in place and run through each
-  TOUCHED expert, the gate of a row that did not pick it selecting its
-  product out; the experts' weights are streamed by one Pallas kernel
-  (``ops/pallas/expert_stream.py``) that reads expert ``e + 1`` while ``e``'s
-  products run. No sort, gather or scatter; an expert no token picked is not
-  read. The same picks and precision as the tile loop (product inputs in the
-  weights' type, float32 accumulation, gates and sum), summed in expert
-  order.
-* ``T > TILE`` (a prefill chunk's 512): :func:`experts_sorted`, the grouped
-  product over the (token, pick) pairs SORTED by expert. Each held expert's
-  group is cut into tiles of ``TILE`` rows; a loop with as many rounds as
-  there are tiles takes one tile, gathers its tokens' rows, runs them through
-  that expert's three matrices and adds the gated result back to the tokens.
-  Above one tile the dense form would waste products (at 512 tokens 16 times
-  the FLOPs: a layer bound by reading its weights would be bound by
-  arithmetic), so the tile loop is the form for more than one tile of tokens.
+  touched expert, the gate of a row that did not pick it selecting its
+  product out (``ops/pallas/expert_stream.py``, ``expert_stream``). No sort,
+  gather or scatter.
+* ``T > TILE`` (a prefill chunk's 512): :func:`experts_grouped`. The (token,
+  pick) pairs are SORTED by held expert (:func:`sorted_pairs`); inside an
+  expert's grid step a loop takes its group ``expert_stream.GROUP_ROWS`` rows
+  a product, gathering each row from ``x`` in VMEM and adding the gated
+  product back to the float32 sum in VMEM (``expert_groups`` in the same
+  file). The dense form would waste products here (at 512 tokens 16 times the
+  FLOPs: a layer bound by reading its weights would be bound by arithmetic);
+  the grouped one's work grows with the pairs that are here and nothing else.
 
-No capacity factor exists and no token is dropped in either: under any skew
-the tile loop just runs more tiles for the crowded expert (all of them, if
+The same picks and precision in both (product inputs in the weights' type,
+float32 accumulation, gates and sum), summed in expert order. No capacity
+factor exists and no token is dropped in either: under any skew the crowded
+expert's group just runs more products under its one read (all 512 rows, if
 every token picks it), and the small forward's rows all run through it.
 
-Chip readings (one v5e). The tile loop against XLA's ``ragged_dot`` over the
-same sorted rows at 512 tokens, and its tile against 128 and 256 rows
-(``benchmarks/tools/moe_grouped_probe.py``; PERF.md, Findings, PRs 24-31): the
-loop is the one form kept there. At 32 tokens (SDAR's widths: 128 experts of
-2048 x 768, 9.44 MB and 11.5 us of reading each; 66 touched) a tile of the
-loop costs 31.9 us whether it holds two tokens or sixty, a plain-XLA loop
-over the touched experts 16.5 us (its products wait for their own weights),
-the kernel 12.8 us (``benchmarks/tools/moe_small_probe.py``; PERF.md,
-Findings, PR 33): the kernel is the one form kept here.
+:func:`experts_sorted` is the form both replaced: a ``fori_loop`` with one
+round a tile of :data:`TILE` sorted rows. :func:`moe` calls it no more; the
+tests hold both kernels to it and the benchmark's probes time it as their
+baseline.
+
+Chip readings (one v5e; PERF.md, Findings). The tile loop beat XLA's
+``ragged_dot`` over the same sorted rows at 512 tokens, 2.34 to 6.35 ms
+(``benchmarks/tools/moe_grouped_probe.py``; PRs 24-31). At 32 tokens (SDAR's
+widths: 128 experts of 2048 x 768, 9.44 MB and 11.5 us of reading each; 66
+touched) a tile of the loop costs 31.9 us whether it holds two tokens or
+sixty, a plain-XLA loop over the touched experts 16.5 us (its products wait
+for their own weights), the streaming kernel 12.8 us
+(``benchmarks/tools/moe_small_probe.py``; PR 33). At 512 tokens, us a touched
+expert with the sort, tile loop | grouped kernel (to read one): SDAR 34.9 |
+13.4 (11.5); granite (36 held of 4096 x 768, ~71 rows each: two tiles of the
+loop, one product of the kernel) 90.1 | 29.4 (23.0), the kernel alone 25.9
+inside the cell's chunk program; LongCat (16 held of 6144 x 2048 in four
+column chunks, ~8 rows each) 144.3 | 111.1 (92.2); every token on one
+expert: 36.8 | 13.7, 91.6 | 30.7, 202.4 | 123.7. 64 and 128 rows a product
+read alike (PR 36).
 """
 
 from __future__ import annotations
@@ -65,10 +77,10 @@ from predictionio_tpu.ops import pallas as pallas_ops
 from predictionio_tpu.ops.mla import mm
 from predictionio_tpu.ops.pallas import expert_stream
 
-#: rows of one expert tile of the sorted-tile loop, the form for MORE than one
-#: tile of tokens (the measured best of 64, 128 and 256 at 512 tokens:
-#: PERF.md, Findings, PRs 24-31); a forward of up to one tile of tokens takes
-#: the streamed form instead (:func:`small_forward`)
+#: the tokens up to which a forward takes the streamed form
+#: (:func:`small_forward`), and the rows of one tile of the sorted-tile loop
+#: (:func:`experts_sorted`; its measured best of 64, 128 and 256 at 512
+#: tokens: PERF.md, Findings, PRs 24-31)
 TILE = 64
 
 
@@ -128,12 +140,14 @@ def swiglu(x, w_g, w_u, w_d):
     return mm(jax.nn.silu(mm(x, w_g)) * mm(x, w_u), w_d)
 
 
-def experts_sorted(p, dims: MoEDims, x, idx, gates, valid):
-    """What the held routed experts add, ``[T, dim]`` float32, and the tokens
-    each of them got, ``[n]`` int32. ``valid`` [T] masks padding tokens out:
-    they reach no expert."""
+def sorted_pairs(dims: MoEDims, idx, gates, valid):
+    """The (token, pick) pairs sorted by held expert, stably: ``token`` and
+    ``gate`` [T * top_k], each pair's row and gate; ``counts`` and ``start``
+    [n] int32, held expert ``e``'s group being pairs ``start[e] : start[e] +
+    counts[e]``. The pairs of absent experts and of padding tokens
+    (``valid`` [T] false) come last, outside every group."""
     e0, n = dims.held
-    T, k = idx.shape
+    k = idx.shape[1]
     local = idx - e0
     here = (local >= 0) & (local < n) & valid[:, None]
     flat = jnp.where(here, local, n).reshape(-1)             # n = "not here"
@@ -141,9 +155,23 @@ def experts_sorted(p, dims: MoEDims, x, idx, gates, valid):
     token = (order // k).astype(jnp.int32)
     gate = gates.reshape(-1)[order]
     counts = jnp.zeros((n + 1,), jnp.int32).at[flat].add(1)[:n]
+    return token, gate, counts, jnp.cumsum(counts) - counts
+
+
+def experts_sorted(p, dims: MoEDims, x, idx, gates, valid):
+    """What the held routed experts add, ``[T, dim]`` float32, and the tokens
+    each of them got, ``[n]`` int32, by a loop with one round a tile of
+    :data:`TILE` sorted rows: gather the tile's rows, three products, add
+    the gated result back. ``valid`` [T] masks padding tokens out: they
+    reach no expert. :func:`moe` calls it no more (an expert with two tiles
+    was read twice, and no round's weights could load before it began): it
+    is the form the tests hold both kernels to and the baseline that the
+    benchmark's probes time (``benchmarks/tools/moe_small_probe.py``,
+    ``hyb_probe.py``, ``moe_grouped_probe.py``)."""
+    T, k = idx.shape
+    token, gate, counts, group_start = sorted_pairs(dims, idx, gates, valid)
     tiles = (counts + TILE - 1) // TILE
     tile_end = jnp.cumsum(tiles)                             # [n]
-    group_start = jnp.cumsum(counts) - counts
     rows = jnp.arange(TILE, dtype=jnp.int32)
 
     def one_tile(t, y):
@@ -160,6 +188,26 @@ def experts_sorted(p, dims: MoEDims, x, idx, gates, valid):
     y = jax.lax.fori_loop(0, tile_end[-1], one_tile,
                           jnp.zeros((T, dims.dim), jnp.float32))
     return y, counts
+
+
+def experts_grouped(p, dims: MoEDims, x, idx, gates, valid):
+    """:func:`experts_sorted`'s answer for a forward of more than one tile
+    of tokens, summed in the same order: the same sorted pairs through ONE
+    kernel a layer that walks the touched experts, each expert's matrices
+    read once whatever its rows (``ops/pallas/expert_stream.py``,
+    ``expert_groups``)."""
+    token, gate, counts, start = sorted_pairs(dims, idx, gates, valid)
+    y = expert_stream.expert_groups(
+        x, token, gate, start, counts, p["w_g"], p["w_u"], p["w_d"],
+        interpret=pallas_ops.interpret_mode())
+    return y, counts
+
+
+def row_tiles(load):
+    """Products of sorted rows the grouped form runs for tokens-per-expert
+    ``load`` (any shape, numpy or jax): one for every
+    ``expert_stream.GROUP_ROWS`` rows of a group, begun."""
+    return -(-load // expert_stream.GROUP_ROWS)
 
 
 def small_forward(T: int) -> bool:
@@ -204,7 +252,7 @@ def moe(p, dims: MoEDims, x, valid, scope: str = "moe"):
         idx, gates = route(p, dims, x)
     with jax.named_scope(scope + ".experts"):
         experts = (experts_streamed if small_forward(x.shape[0])
-                   else experts_sorted)
+                   else experts_grouped)
         routed, load = experts(p, dims, x, idx, gates, valid)
     if dims.shared_dim:
         with jax.named_scope(scope + ".shared"):

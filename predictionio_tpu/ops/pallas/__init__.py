@@ -32,12 +32,15 @@ top-k over the item table in ``[D, Ip]`` tiles of thousands of items,
 merging only a tile that can change the top-k — the exact retrieval
 index's hot path, selected per-index via ``index_kernel``).
 
-One kernel has no flag: ``expert_stream`` (the expert layer of a forward
-whose tokens fit one tile: every row through every touched expert, the
-experts' weights streamed) is the one form ``ops/moe.moe`` has for that
-shape, so it runs compiled on a TPU and under the interpreter everywhere
-else, tier-1 included; the tile loop it stands beside
-(``ops/moe.experts_sorted``) is its reference in the tests.
+Two kernels have no flag: ``expert_stream`` (the expert layer of a forward
+whose tokens fit one tile: every row through every touched expert) and
+``expert_groups`` (of a larger forward, a prefill chunk: the rows sorted by
+expert, each expert's group gathered, multiplied and added back inside its
+grid step), both in ``expert_stream.py``, both streaming each touched
+expert's weights once. They are the two forms ``ops/moe.moe`` has, chosen by
+the forward's tokens, so they run compiled on a TPU and under the interpreter
+everywhere else, tier-1 included; the tile loop they replaced
+(``ops/moe.experts_sorted``) is their reference in the tests.
 
 Each flag (``flash_ce_kernel``, ``embed_update_kernel``,
 ``index_kernel``) takes ``on`` / ``off`` / ``auto``;

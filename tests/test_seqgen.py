@@ -178,6 +178,36 @@ def test_a_small_forward_with_renormalised_gates_matches_the_reference(
     assert int(counted["zero_picks"]) == 0
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("T", [65, 128, 512])
+def test_a_chunk_with_renormalised_gates_matches_the_reference(ref, T, dtype):
+    """Every expert held, gates renormalised over the picks, a forward of
+    more than one tile: the grouped kernel over the sorted rows against the
+    tile loop and the float32 reference; every pair is here, so a chunk of
+    512 tokens sends 2,048 rows through the 16 experts."""
+    p = dict(seeded_params(small_spec())["blocks"][0]["moe"])
+    p.update({k: p[k].astype(dtype) for k in ("w_g", "w_u", "w_d")})
+    x = jnp.asarray(np.random.default_rng(40 + T).standard_normal((T, 64)),
+                    jnp.float32)
+    valid = jnp.ones(T, bool)
+    idx, gates = moe_ops.route(p, MOE, x)
+    assert np.allclose(np.asarray(gates).sum(axis=-1), 1.0, atol=1e-6)
+    y, counts = moe_ops.experts_grouped(p, MOE, x, idx, gates, valid)
+    y_tiles, counts_tiles = moe_ops.experts_sorted(p, MOE, x, idx, gates,
+                                                   valid)
+    close(y, y_tiles, 1e-6)
+    as_f32 = {k: v.astype(jnp.float32) for k, v in p.items()}
+    close(y, ref.experts(as_f32, x, DM),
+          2e-4 if dtype == jnp.float32 else 2e-2)
+    assert counts.tolist() == counts_tiles.tolist()
+    assert int(counts.sum()) == T * MOE.top_k
+    whole, counted = moe_ops.moe(p, MOE, x, valid)
+    close(whole, y, 1e-6)
+    assert int(counted["zero_picks"]) == 0
+    assert int(moe_ops.row_tiles(counts).sum()) >= 16
+
+
 @pytest.mark.parametrize("chunk", [16, 128])
 def test_every_block_forward_takes_the_small_forwards_form(chunk):
     """``block_dense_expert_runs`` equals ``block_runs`` (4 rows x a block of
@@ -191,6 +221,12 @@ def test_every_block_forward_takes_the_small_forwards_form(chunk):
     assert stats["prefill_dense_expert_runs"] == (
         stats["prefill_runs"] if chunk <= moe_ops.TILE else 0)
     assert stats["extend_dense_expert_runs"] == stats["extend_runs"] == 0
+    # a chunk above a tile counts its products of sorted rows: one a touched
+    # expert here (22 tokens x 4 picks over 16 experts); a block forward none
+    assert stats["block_expert_row_tiles"] == 0
+    assert stats["prefill_expert_row_tiles"] == (
+        0 if chunk <= moe_ops.TILE else stats["prefill_experts_touched"])
+    assert (stats["prefill_experts_touched"] > 0)
 
 
 def test_programs_prefill_and_blocks_give_the_reference_logits(ref):

@@ -246,6 +246,41 @@ def test_two_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
     close(y, ref.moe_parts(half, x, ref_dims(), (0, 4))[0] + shared, 5e-4)
 
 
+@pytest.mark.parametrize("case", ["uniform", "padding", "one_expert"])
+@pytest.mark.parametrize("tokens", [65, 200])
+def test_a_chunks_grouped_experts_beside_the_shared_one(ref, tokens, case):
+    """A forward of more than one tile through the layer with a share of the
+    routed experts and the shared expert: the grouped kernel adds what the
+    tile loop adds, the shared expert runs beside it over every row, and
+    the sum is the reference's; the load counts the products."""
+    p = dict(seeded_params(small_spec())["blocks"][0]["moe"])
+    if case == "one_expert":        # every token picks held expert 2
+        p["bias"] = p["bias"].at[2].set(10.0)
+    x = jnp.asarray(np.random.default_rng(tokens).standard_normal(
+        (tokens, 64)), jnp.float32)
+    valid = (jnp.arange(tokens) < tokens - 9 if case == "padding"
+             else jnp.ones(tokens, bool))
+    idx, gates = moe_ops.route(p, MOE, x)
+    grouped, counts = moe_ops.experts_grouped(p, MOE, x, idx, gates, valid)
+    tiles, counts_tiles = moe_ops.experts_sorted(p, MOE, x, idx, gates, valid)
+    close(grouped, tiles, 1e-6)
+    assert counts.tolist() == counts_tiles.tolist()
+    y, counted = moe_ops.moe(p, MOE, x, valid)
+    shared = moe_ops.swiglu(x, **p["shared"])
+    close(y, grouped + shared, 1e-6)
+    real = np.asarray(valid)
+    if case != "one_expert":        # the reference's router has no bias
+        routed, ref_shared, _ = ref.moe_parts(p, x, ref_dims(), MOE.held)
+        close(np.asarray(y)[real], np.asarray(routed + ref_shared)[real],
+              5e-4)
+    # a padding row gets the shared expert's part alone
+    assert np.array_equal(np.asarray(y)[~real], np.asarray(shared)[~real])
+    assert counted["expert_load"].tolist() == counts.tolist()
+    if case == "one_expert":
+        assert int(counts[2]) == int(real.sum())
+        assert moe_ops.row_tiles(counts)[2] == -(-int(real.sum()) // 128)
+
+
 # -- the programs -------------------------------------------------------------
 
 def test_chunked_prefill_then_extensions_give_the_reference_logits(ref):

@@ -6,6 +6,7 @@ program), at a small size on the CPU: hidden 64, 4 heads, 2 double-layers,
 import dataclasses
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +15,7 @@ import pytest
 
 from predictionio_tpu.ops import mla as mla_ops
 from predictionio_tpu.ops import moe as moe_ops
+from predictionio_tpu.ops.pallas import expert_stream
 from predictionio_tpu.ops.sessionrec import (
     BlockSpec, ServeShape, StackPrograms, StackSpec, apply_block, init_stack)
 
@@ -251,18 +253,184 @@ def test_a_huge_row_that_picked_no_expert_reaches_no_sum(T):
         ((idx[keep] >= e0) & (idx[keep] < e0 + n)).sum())
 
 
+def plain_experts(p, dims, x, idx, gates, valid):
+    """What the held experts add, the plain way: for each held expert the
+    rows that picked it, through its three matrices in float32 numpy, times
+    their gates, added to their tokens. No sort, no tile, no kernel."""
+    e0, n = dims.held
+    x, gates = np.asarray(x, np.float32), np.asarray(gates, np.float32)
+    idx = np.asarray(idx)
+    w_g, w_u, w_d = (np.asarray(p[k], np.float32)
+                     for k in ("w_g", "w_u", "w_d"))
+    y = np.zeros((x.shape[0], dims.dim), np.float32)
+    counts = np.zeros(n, np.int64)
+    for t, k in zip(*np.nonzero((idx >= e0) & (idx < e0 + n)
+                                & np.asarray(valid)[:, None])):
+        e = idx[t, k] - e0
+        a = x[t] @ w_g[e]
+        y[t] += gates[t, k] * ((a / (1 + np.exp(-a)) * (x[t] @ w_u[e]))
+                               @ w_d[e])
+        counts[e] += 1
+    return y, counts
+
+
+def chunk_case(case, T, dtype, seed=9):
+    """:func:`small_forward_case` for a forward of more than one tile, its
+    expert weights in ``dtype``; ``padding`` pads the tail."""
+    p, x, valid = small_forward_case(case, T, seed)
+    p = dict(p, **{k: p[k].astype(dtype) for k in ("w_g", "w_u", "w_d")})
+    if case == "padding":
+        valid = jnp.arange(T) < T - T // 3
+    return p, x, valid
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("T", [65, 128, 512])
+@pytest.mark.parametrize("case", ["uniform", "padding", "one_expert",
+                                  "none_held"])
+def test_a_chunk_runs_its_sorted_rows_through_the_grouped_kernel(
+        case, T, dtype):
+    """A forward of more than one tile (the kernel under the interpreter
+    here) against the tile loop, the same products summed in the same order,
+    and against the plain per-expert sum in float32; no capacity: every
+    token on one expert is more products under one read."""
+    p, x, valid = chunk_case(case, T, dtype)
+    assert not moe_ops.small_forward(T)
+    idx, gates = moe_ops.route(p, MOE, x)
+    y, counts = moe_ops.experts_grouped(p, MOE, x, idx, gates, valid)
+    y_tiles, counts_tiles = moe_ops.experts_sorted(p, MOE, x, idx, gates,
+                                                   valid)
+    close(y, y_tiles, 1e-6)
+    assert counts.tolist() == counts_tiles.tolist()
+    want, n_rows = plain_experts(p, MOE, x, idx, gates, valid)
+    close(y, want, 2e-4 if dtype == jnp.float32 else 2e-2)
+    assert counts.tolist() == n_rows.tolist()
+    real = np.asarray(valid)
+    assert not np.asarray(y)[~real].any()
+    whole, counted = moe_ops.moe(p, MOE, x, valid)
+    assert counted["expert_load"].tolist() == counts.tolist()
+    if case == "one_expert":
+        assert int(counts[1]) == int(real.sum())
+        assert int(moe_ops.row_tiles(counts)[1]) == -(-int(real.sum()) // 128)
+    if case == "none_held":
+        assert not counts.any() and not np.asarray(y).any()
+        assert not moe_ops.row_tiles(counts).any()
+
+
+#: rows of each of 12 held experts' groups: exactly 1-4 products of 128 rows,
+#: one row over and one row under, one row, none
+GROUP_LENGTHS = [128, 256, 384, 512, 129, 127, 1, 0, 257, 255, 0, 383]
+
+
+def grouped_case(lengths, dtype=jnp.float32, seed=12):
+    """One pick a token, dealt so that held expert ``e`` gets ``lengths[e]``
+    tokens, the tokens in a shuffled order; two absent experts get some
+    too."""
+    n = len(lengths)
+    dims = dataclasses.replace(MOE, n_routed=n + 2, n_zero=0, top_k=1,
+                               held=(1, n), scale=1.0)
+    rng = np.random.default_rng(seed)
+    picks = np.concatenate([np.full(c, e + 1) for e, c in enumerate(lengths)]
+                           + [np.full(5, 0), np.full(7, n + 1)])
+    rng.shuffle(picks)
+    T = len(picks)
+    p = moe_ops.init(jax.random.PRNGKey(seed), dims, dtype)
+    x = jnp.asarray(rng.standard_normal((T, 64)), jnp.float32)
+    idx = jnp.asarray(picks[:, None], jnp.int32)
+    gates = jnp.asarray(rng.uniform(0.1, 1.0, (T, 1)), jnp.float32)
+    return dims, p, x, idx, gates, jnp.ones(T, bool)
+
+
+def test_groups_of_whole_products_and_of_a_row_more_or_less():
+    """Group lengths on both sides of a product's 128 rows, a group of one
+    row and groups of none, among pairs of absent experts: every row of
+    every group is summed once, by the products counted."""
+    assert expert_stream.GROUP_ROWS == 128
+    dims, p, x, idx, gates, valid = grouped_case(GROUP_LENGTHS)
+    y, counts = moe_ops.experts_grouped(p, dims, x, idx, gates, valid)
+    assert counts.tolist() == GROUP_LENGTHS
+    assert moe_ops.row_tiles(counts).tolist() == [
+        1, 2, 3, 4, 2, 1, 1, 0, 3, 2, 0, 3]
+    want, _ = plain_experts(p, dims, x, idx, gates, valid)
+    close(y, want)
+    y_tiles, _ = moe_ops.experts_sorted(p, dims, x, idx, gates, valid)
+    close(y, y_tiles, 1e-6)
+    # a token of an absent expert gets nothing here
+    absent = (np.asarray(idx)[:, 0] == 0) | (np.asarray(idx)[:, 0] == 13)
+    assert absent.sum() == 12 and not np.asarray(y)[absent].any()
+
+
+@pytest.mark.parametrize("rows", [8, 16])
+def test_the_rows_of_a_product_change_no_sum(monkeypatch, rows):
+    """The same forward with fewer rows a product (more products under one
+    read of an expert): the same sum."""
+    p, x, valid = chunk_case("uniform", 128, jnp.float32)
+    idx, gates = moe_ops.route(p, MOE, x)
+    want, counts = moe_ops.experts_grouped(p, MOE, x, idx, gates, valid)
+    monkeypatch.setattr(expert_stream, "GROUP_ROWS", rows)
+    y, _ = moe_ops.experts_grouped(p, MOE, x, idx, gates, valid)
+    close(y, want, 1e-6)
+    assert int(moe_ops.row_tiles(counts).sum()) == int(
+        (-(-np.asarray(counts) // rows)).sum()) > int((counts > 0).sum())
+
+
+@pytest.mark.parametrize("T", [65, 512])
+def test_an_experts_rows_pass_under_each_of_its_column_chunks(monkeypatch, T):
+    """``expert_dim`` cut into two column chunks (LongCat's is cut into
+    four): an expert's rows run under each chunk and the chunks' products
+    add up, in another order than the uncut one's."""
+    p, x, valid = chunk_case("padding", T, jnp.float32)
+    idx, gates = moe_ops.route(p, MOE, x)
+    want, _ = moe_ops.experts_sorted(p, MOE, x, idx, gates, valid)
+    monkeypatch.setattr(expert_stream, "chunk_of",
+                        lambda dim, expert_dim, itemsize: expert_dim // 2)
+    text = str(jax.make_jaxpr(lambda *a: moe_ops.experts_grouped(
+        p, MOE, *a))(x, idx, gates, valid))
+    assert "grid=(4, 2)" in text          # touched experts x column chunks
+    y, _ = moe_ops.experts_grouped(p, MOE, x, idx, gates, valid)
+    close(y, want, 1e-6)
+
+
+@pytest.mark.parametrize("T", [65, 512])
+def test_a_huge_padding_row_of_a_chunk_reaches_no_sum(T):
+    """Padding rows are in no group: a row of 1e30 among them (its products
+    overflow) is never gathered, reads 0 and leaves every other row's
+    output as it was."""
+    p, x, valid = chunk_case("padding", T, jnp.float32)
+    idx, gates = moe_ops.route(p, MOE, x)
+    want, _ = moe_ops.experts_grouped(p, MOE, x, idx, gates, valid)
+    x = x.at[T - 2].set(1e30)
+    assert not bool(valid[T - 2])
+    y, counts = moe_ops.experts_grouped(p, MOE, x, idx, gates, valid)
+    assert np.isfinite(np.asarray(y)).all()
+    assert np.array_equal(np.asarray(y), np.asarray(want))
+    e0, n = MOE.held
+    here = (np.asarray(idx) >= e0) & (np.asarray(idx) < e0 + n)
+    assert int(counts.sum()) == int(here[np.asarray(valid)].sum())
+
+
 @pytest.mark.parametrize("T", [1, 32, 64, 65, 512])
 def test_the_forwards_shape_chooses_the_expert_layers_form(T):
-    """Up to one tile of tokens the kernel and no sort; beyond it the
-    sorted-tile loop and no kernel. Nothing but the shape is asked."""
+    """Up to one tile of tokens the streaming kernel and no sort; beyond it
+    the sort and the grouped kernel over the sorted rows. ONE kernel either
+    way, no loop over tiles and no scatter-add left in the program, and
+    nothing but the shape is asked."""
     p, x, valid = small_forward_case("uniform", T)
     text = str(jax.make_jaxpr(
         lambda p, x, valid: moe_ops.moe(p, MOE, x, valid))(p, x, valid))
     small = T <= moe_ops.TILE
     assert moe_ops.small_forward(T) == small
-    assert ("pallas_call" in text) == small
+    assert text.count("pallas_call") == 1
+    assert ("name=expert_stream" in text) == small
+    assert ("name=expert_groups" in text) == (not small)
     assert (" sort[" in text) == (not small)
-    assert ("scatter-add" in text) == (not small)
+    # the kernel's own loops are inside the pallas_call's jaxpr: the text
+    # before it holds no while; the one scatter-add left counts the pairs
+    # of each expert ([n + 1] integers), no row of ``dim`` values
+    assert "while[" not in text[:text.index("pallas_call")]
+    assert text.count("scatter-add") == (0 if small else 1)
+    assert not re.search(r"f32\[\d+,64\] = scatter-add", text)
 
 
 @pytest.mark.parametrize("chunk", [16, 128])
@@ -287,6 +455,42 @@ def test_the_model_counts_the_runs_that_took_the_small_forwards_form(chunk):
     assert stats["prefill_dense_expert_runs"] == (
         stats["prefill_runs"] if chunk <= moe_ops.TILE else 0)
     assert stats["block_dense_expert_runs"] == stats["block_runs"] == 0
+    # products of sorted rows: none in a small forward; at these sizes no
+    # group passes a product's 128 rows, so one a touched expert
+    assert stats["extend_expert_row_tiles"] == 0
+    assert stats["prefill_expert_row_tiles"] == (
+        0 if chunk <= moe_ops.TILE else stats["prefill_experts_touched"])
+
+
+def test_the_model_counts_the_products_of_sorted_rows(monkeypatch):
+    """``<kind>_expert_row_tiles`` against the loads that ran: with 8 rows a
+    product (the programs are traced with it) a chunk of 128 positions runs
+    more products than it touches experts, one for every 8 rows of a group,
+    begun."""
+    from predictionio_tpu.data.bimap import BiMap
+    from predictionio_tpu.models.sessionrec import SeqStackModel
+    monkeypatch.setattr(expert_stream, "GROUP_ROWS", 8)
+    spec = small_spec()
+    model = SeqStackModel(
+        spec, seeded_params(spec),
+        BiMap.from_vocab([f"i{r}" for r in range(N_ITEMS)]),
+        dataclasses.replace(SHAPE, capacity=256, chunk=128))
+    loads = []
+    count = model._count
+    monkeypatch.setattr(model, "_count", lambda kind, counted: (
+        loads.append((kind, np.asarray(counted["expert_load"]))),
+        count(kind, counted))[1])
+    rows = np.random.default_rng(8).integers(0, N_ITEMS, size=203).tolist()
+    for upto in (200, 203):
+        model.answer({"items": [f"i{r}" for r in rows[:upto]], "num": 5})
+    stats = model.stats()
+    chunks = [load for kind, load in loads if kind == "prefill"]
+    assert len(chunks) == stats["prefill_runs"] == 2
+    want = sum(int((-(-load // 8)).sum()) for load in chunks)
+    assert stats["prefill_expert_row_tiles"] == want
+    assert want > stats["prefill_experts_touched"] == sum(
+        int((load > 0).sum()) for load in chunks)
+    assert stats["extend_runs"] > 0 and stats["extend_expert_row_tiles"] == 0
 
 
 def test_double_layer_topology_matches_the_reference(ref):

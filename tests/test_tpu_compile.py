@@ -93,6 +93,17 @@ def _expert_stream(T, dim, expert_dim, n):
         ((n, expert_dim, dim), bf16)], 1
 
 
+def _expert_groups(T, dim, expert_dim, n, top_k):
+    from predictionio_tpu.ops.pallas import expert_stream
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    return expert_stream.expert_groups, [
+        ((T, dim), jnp.float32), ((T * top_k,), i32),
+        ((T * top_k,), jnp.float32), ((n,), i32), ((n,), i32),
+        ((n, dim, expert_dim), bf16), ((n, dim, expert_dim), bf16),
+        ((n, expert_dim, dim), bf16)], 1
+
+
 @pytest.mark.parametrize("build,args", [
     (_flash_ce, (8192, 128)),
     (_flash_ce, (4096, 64)),
@@ -114,12 +125,22 @@ def _expert_stream(T, dim, expert_dim, n):
     # columns, 37.7 MB), each within the VMEM limit it asks for
     (_expert_stream, (32, 2048, 768, 128)),
     (_expert_stream, (64, 6144, 2048, 16)),
+    # a prefill chunk's expert layer, 512 tokens' sorted pairs: SDAR's (128
+    # experts of 768, top-8), granite's (36 held of 768 at hidden 4096,
+    # top-10) and LongCat's (16 held of 2048 at hidden 6144, top-12, four
+    # column chunks); x and the sum once each in VMEM beside two buffers of
+    # an expert's matrices
+    (_expert_groups, (512, 2048, 768, 128, 8)),
+    (_expert_groups, (512, 4096, 768, 36, 10)),
+    (_expert_groups, (512, 6144, 2048, 16, 12)),
 ], ids=["flash_ce-8192x128", "flash_ce-4096x64", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
         "embed_update-1M-8192x128", "topk_dot-16384x6144-B1",
         "topk_dot-16384x6144-B8", "topk_dot-9400000x64-B16",
         "topk_dot-9400000x64-B32", "topk_dot-9400000x64-B64",
-        "expert_stream-32x2048-128x768", "expert_stream-64x6144-16x2048"])
+        "expert_stream-32x2048-128x768", "expert_stream-64x6144-16x2048",
+        "expert_groups-512x2048-128x768", "expert_groups-512x4096-36x768",
+        "expert_groups-512x6144-16x2048"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
     text = _compiled_text(fn, shapes, one_chip)
@@ -143,7 +164,8 @@ def _kernel_instructions(text):
     (_flash_ce, (4096, 64),
      ["flash_ce_fwd", "flash_ce_bwd_du", "flash_ce_bwd_dv"]),
     (_expert_stream, (32, 2048, 768, 128), ["expert_stream"]),
-], ids=["topk_dot", "flash_ce", "expert_stream"])
+    (_expert_groups, (512, 2048, 768, 128, 8), ["expert_groups"]),
+], ids=["topk_dot", "flash_ce", "expert_stream", "expert_groups"])
 def test_a_kernels_instruction_carries_its_name(one_chip, no_compile_cache,
                                                 build, args, names):
     """A device trace's events are named by the instruction's text: the
@@ -247,6 +269,65 @@ def test_a_small_forwards_expert_layer_is_one_kernel_under_its_scope(
                 if "sort" in i and scope.endswith(".experts")]
     assert not re.search(
         rf"= bf16\[{n},\d+,\d+\]\S* (copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("dim,expert_dim,n_routed,n_zero,held,top_k,chunk", [
+    (2048, 768, 128, 0, 128, 8, 768), (4096, 768, 72, 0, 36, 10, 768),
+    (6144, 2048, 512, 256, 16, 12, 512)],
+    ids=["sdar-chunk", "granite-chunk", "longcat-chunk"])
+def test_a_chunks_expert_layer_is_one_grouped_kernel_under_its_scope(
+        one_chip, no_compile_cache, monkeypatch, dim, expert_dim, n_routed,
+        n_zero, held, top_k, chunk):
+    """``ops/moe.moe`` at a prefill chunk's 512 tokens and the three
+    configurations' widths: ONE ``expert_groups`` kernel, found under
+    ``<scope>.experts`` through the scope map, and no ``while`` left of the
+    tile loop in the program (each touched expert's matrices cross HBM once:
+    the kernel's grid walks them, the rows' loop is inside it); the weights
+    read as they are stored; the VMEM limit the kernel asks for, which the
+    compile stays under, is under 100 MiB (40.9 / 71.8 / 83.4 MB: two
+    buffers of an expert's column chunk, x and the sum once each, a
+    product's rows); the layer's temporaries outside it are small (0, 0 and
+    0.7 MB as read here: the sorted lists)."""
+    from predictionio_tpu.obs import jaxmon
+    from predictionio_tpu.ops import moe as moe_ops
+    from predictionio_tpu.ops.pallas import expert_stream
+
+    monkeypatch.setenv("PIO_PALLAS_INTERPRET", "0")
+    dims = moe_ops.MoEDims(dim=dim, expert_dim=expert_dim, n_routed=n_routed,
+                           n_zero=n_zero, top_k=top_k, scale=1.0,
+                           held=(0, held), norm_topk=True)
+    assert expert_stream.chunk_of(dim, expert_dim, 2) == chunk
+    T, n, bf16 = 512, held, jnp.bfloat16
+    assert not moe_ops.small_forward(T)
+
+    def struct(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"w_r": struct(dim, dims.n_router),
+         "bias": struct(dims.n_router, dtype=jnp.float32),
+         "w_g": struct(n, dim, expert_dim), "w_u": struct(n, dim, expert_dim),
+         "w_d": struct(n, expert_dim, dim)}
+
+    def layer(p, x, valid):
+        return moe_ops.moe(p, dims, x, valid, scope="seq.layer0.moe")
+
+    compiled = jax.jit(layer).lower(
+        p, struct(T, dim, dtype=jnp.float32),
+        struct(T, dtype=jnp.bool_)).compile()
+    text = compiled.as_text()
+    kernels = _kernel_instructions(text)
+    assert len(kernels) == 1 and "expert_groups" in kernels[0], kernels
+    scopes = jaxmon.scope_map_of(text)
+    assert scopes[kernels[0]] == "seq.layer0.moe.experts"
+    assert not re.search(r"\bwhile\(", text)
+    assert not re.search(
+        rf"= bf16\[{n},\d+,\d+\]\S* (copy|transpose)\(", text)
+    line = next(ln for ln in text.splitlines()
+                if f"%{kernels[0]} = " in ln)
+    limit = int(re.search(r'"memory_space":"1","offset":"0","size":"(\d+)"',
+                          line).group(1))
+    assert 32 << 20 < limit < 100 << 20, limit
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 def test_the_kv_cache_is_left_as_it_is_handed_over(one_chip,
