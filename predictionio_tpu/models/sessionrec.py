@@ -12,6 +12,7 @@ attention for histories past one device's HBM).
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -296,14 +297,7 @@ class LatentCache:
             slot, cached = self._match(free, rows)
             if not cached and len(self.rows[slot]):
                 self.evictions += 1
-                with trace.device_span("seq.cache.evict", slot=slot):
-                    self.rows[slot] = np.zeros(0, np.int32)
-            # a span's attributes are set as it opens: the outcome rides on
-            # a marker inside the lookup
-            with trace.device_span("seq.cache.found", slot=slot,
-                                   hit_tokens=cached,
-                                   miss_tokens=len(rows) - cached):
-                pass
+                self.rows[slot] = np.zeros(0, np.int32)
         self.hit_tokens += cached
         self.miss_tokens += len(rows) - cached
         self.busy[slot] = True
@@ -325,13 +319,17 @@ class SeqTicket:
     after block is denoised and committed, ``forwards`` block forwards in
     all, until ``done`` reaches ``end``."""
 
-    __slots__ = ("rows", "num", "slot", "done", "born", "result",
+    __slots__ = ("rows", "num", "slot", "done", "result",
                  "extension", "generate", "known", "end", "block",
-                 "denoised", "forwards", "found", "tail")
+                 "denoised", "forwards", "found", "tail", "admitted_ns",
+                 "launched_ns")
 
-    def __init__(self, rows, num, slot, done, born):
-        self.rows, self.num, self.slot = rows, num, slot
-        self.done, self.born = done, born
+    def __init__(self, rows, num, slot, done):
+        self.rows, self.num, self.slot, self.done = rows, num, slot, done
+        #: the host's clock at admission, and where the first program that
+        #: carries rows of this ticket was launched (None until then)
+        self.admitted_ns = time.perf_counter_ns()
+        self.launched_ns: Optional[int] = None
         #: whether the query arrived as an extension (a few new positions
         #: beyond what its slot held), as opposed to a history to prefill
         self.extension = False
@@ -359,6 +357,16 @@ class SeqTicket:
         beyond = [self.found[p][0] for p in range(len(self.rows), self.done)]
         return np.concatenate(
             [self.rows, np.asarray(beyond, np.int32)])[:self.done]
+
+
+#: the span names that make up the step worker's cycle, as
+#: ``SeqStackModel.stats()`` reports them (benchmarks/WORKER_PHASES.md)
+STEP_PHASES = (
+    "batch.idle", "batch.collect", "seq.cache.lookup", "seq.step",
+    "seq.extend", "seq.prefill_chunk", "seq.block_step", "seq.launch",
+    "seq.wait", "seq.count", "seq.decide", "seq.head", "index.search",
+    "index.enqueue", "index.fetch", "batch.deliver", "engine.decode", "gc",
+    trace.UNSPANNED)
 
 
 class SeqStackModel:
@@ -397,6 +405,9 @@ class SeqStackModel:
         self._programs = None
         self._index = None
         self._inverse = None
+        #: the span account of the thread that steps (obs/trace
+        #: .ThreadAccount; the engine server's step worker keeps one)
+        self._account = None
         self.steps = 0
         # what the steps did, summed since deploy. Per program kind
         # ("extend" / "prefill" / "block"): runs, real tokens, (token, pick)
@@ -418,7 +429,13 @@ class SeqStackModel:
         self.counters.update({
             "extend_rows": 0, "extend_latent_positions": 0,
             "extend_kv_positions": 0, "extend_state_rows": 0,
-            "extensions_waited": 0,
+            # from a ticket's admission to the launch of the first program
+            # that carries rows of it, summed, and the tickets summed over:
+            # by that program, a prefill chunk (a first query in the FIFO
+            # of whole prefills) or an extension (a block row counts as an
+            # extension of its slate)
+            "prefill_queue_ns": 0, "prefill_tickets": 0,
+            "extend_queue_ns": 0, "extend_tickets": 0,
             # block forwards: rows by kind (a known block of a history is a
             # commit row), positions the rule unmasked, queries answered
             "denoise_rows": 0, "commit_rows": 0, "positions_unmasked": 0,
@@ -450,8 +467,27 @@ class SeqStackModel:
         return self._programs
 
     def stats(self) -> Dict[str, Any]:
+        """The counters, the cache's, and where the stepping thread's time
+        went: ``phase_<span name>_n`` / ``_wall_ns`` / ``_cpu_ns`` (self
+        time on two clocks, obs/trace.ThreadAccount; ``_cpu_ns`` None where
+        the host's CPU clock is too dear to read), always every name of
+        ``STEP_PHASES`` (two snapshots subtract key by key) and what other
+        spans that thread opened under ``other``; they add up to the
+        thread's time."""
         c = self.cache
+        phases = {name: [0, 0, 0] for name in STEP_PHASES + ("other",)}
+        if self._account is not None:
+            for name, got in self._account.snapshot().items():
+                into = phases[name if name in phases else "other"]
+                for i, v in enumerate(got):
+                    into[i] += v or 0
+            if self._account.cpu_clock is None:     # not read: not measured
+                for got in phases.values():
+                    got[2] = None
         return {**self.counters, "steps": self.steps,
+                **{f"phase_{name}_{what}": v
+                   for name, got in phases.items()
+                   for what, v in zip(("n", "wall_ns", "cpu_ns"), got)},
                 "hit_tokens": c.hit_tokens, "miss_tokens": c.miss_tokens,
                 "evictions": c.evictions, "state_resumes": c.state_resumes,
                 "rewind_misses": c.rewind_misses,
@@ -486,13 +522,13 @@ class SeqStackModel:
         rows = self.resolve(query, room)
         num = int(query.get("num", 10))
         if len(rows) == 0:
-            ticket = SeqTicket(rows, num, None, 0, self.steps)
+            ticket = SeqTicket(rows, num, None, 0)
             ticket.result = []
             return ticket
         got = self.cache.acquire(rows)
         if got is None:
             return None
-        ticket = SeqTicket(rows, num, got[0], got[1], self.steps)
+        ticket = SeqTicket(rows, num, got[0], got[1])
         if self.gen is not None:
             B = self.gen.block_len
             ticket.generate = generate
@@ -529,15 +565,19 @@ class SeqStackModel:
         rows = self._block_rows(short) if self.gen else []
         pre = next((t for t in pending if t.remaining > sh.extend_len), None)
         self.steps += 1
+        self._account = trace.thread_account() or self._account
         with trace.device_span(
                 "seq.step", n_extend=len(ext), n_block=len(rows),
                 seq=self.steps,
                 prefill_tokens=(min(pre.remaining, sh.chunk) if pre else 0)):
             if ext:
+                self._launching(ext, "extend")
                 with trace.device_span("seq.extend", rows=len(ext)):
-                    h, counted = programs.extend(
-                        [(t.rows[t.done:], t.slot, t.done) for t in ext])
-                    h.block_until_ready()
+                    with trace.device_span("seq.launch", program="extend"):
+                        h, counted = programs.extend(
+                            [(t.rows[t.done:], t.slot, t.done) for t in ext])
+                    with trace.device_span("seq.wait", program="extend"):
+                        h.block_until_ready()
                 self._count("extend", counted)
                 self.counters["extend_rows"] += len(ext)
                 reach = sum(len(t.rows) for t in ext)
@@ -548,9 +588,6 @@ class SeqStackModel:
                     if kind in self.kinds:
                         self.counters[counter] += n
                 for t in ext:
-                    # born at step b, first step it could join is b + 1
-                    if self.steps - t.born > 1 and t.extension:
-                        self.counters["extensions_waited"] += 1
                     t.done = len(t.rows)
                 self._answer(ext, h, done)
                 finished += ext
@@ -558,17 +595,31 @@ class SeqStackModel:
                 finished += self._block_forward(rows, done)
             if pre is not None:
                 n = min(pre.remaining, sh.chunk)
+                self._launching([pre], "prefill")
                 with trace.device_span("seq.prefill_chunk", slot=pre.slot,
                                        offset=pre.done, tokens=n):
-                    h, counted = programs.prefill(
-                        pre.rows[pre.done:pre.done + n], pre.slot, pre.done)
-                    h.block_until_ready()
+                    with trace.device_span("seq.launch", program="prefill"):
+                        h, counted = programs.prefill(
+                            pre.rows[pre.done:pre.done + n], pre.slot,
+                            pre.done)
+                    with trace.device_span("seq.wait", program="prefill"):
+                        h.block_until_ready()
                 self._count("prefill", counted)
                 pre.done += n
                 if pre.remaining == 0 and not self.gen:
                     self._answer([pre], h, done)
                     finished.append(pre)
         return finished
+
+    def _launching(self, tickets: List[SeqTicket], queue: str) -> None:
+        """A program that carries rows of ``tickets`` is about to be
+        launched: those it is the first for have waited until now."""
+        now, c = time.perf_counter_ns(), self.counters
+        for t in tickets:
+            if t.launched_ns is None:
+                t.launched_ns = now
+                c[f"{queue}_queue_ns"] += now - t.admitted_ns
+                c[f"{queue}_tickets"] += 1
 
     # -- block diffusion ------------------------------------------------------
     def _block_rows(self, tickets: List[SeqTicket]) -> list:
@@ -601,36 +652,41 @@ class SeqStackModel:
 
         gen, B = self.gen, self.gen.block_len
         n_denoise = sum(1 for r in rows if r[1] == "denoise")
+        self._launching([r[0] for r in rows], "extend")
         with trace.device_span("seq.block_step", rows=len(rows),
                                denoise_rows=n_denoise,
                                commit_rows=len(rows) - n_denoise):
-            decided, counted = jax.device_get(self._programs.block(
-                [(ids, t.slot, at, kind == "denoise", n)
-                 for t, kind, ids, at, n in rows]))
+            with trace.device_span("seq.launch", program="block"):
+                launched = self._programs.block(
+                    [(ids, t.slot, at, kind == "denoise", n)
+                     for t, kind, ids, at, n in rows])
+            with trace.device_span("seq.wait", program="block"):
+                decided, counted = jax.device_get(launched)
         self._count("block", counted)
-        c = self.counters
-        c["denoise_rows"] += n_denoise
-        c["commit_rows"] += len(rows) - n_denoise
-        c["block_kv_positions"] += sum(at + B for _, _, _, at, _ in rows)
         finished = []
-        for b, (t, kind, _, at, _) in enumerate(rows):
-            if kind == "known":
-                t.done = at + B
-                continue
-            if kind == "denoise":
-                t.block = decided["ids"][b]
-                for i in np.flatnonzero(decided["picked"][b]):
-                    t.found[at + int(i)] = (
-                        int(t.block[i]), float(decided["score"][b, i]),
-                        float(decided["confidence"][b, i]), t.forwards)
-                    c["positions_unmasked"] += 1
-                t.denoised += 1
-            else:
-                t.done, t.block = at + B, None
-            t.forwards += 1
-            if t.done == t.end:
-                self._finish_slate(t, done)
-                finished.append(t)
+        with trace.device_span("seq.decide", rows=len(rows)):
+            c = self.counters
+            c["denoise_rows"] += n_denoise
+            c["commit_rows"] += len(rows) - n_denoise
+            c["block_kv_positions"] += sum(at + B for _, _, _, at, _ in rows)
+            for b, (t, kind, _, at, _) in enumerate(rows):
+                if kind == "known":
+                    t.done = at + B
+                    continue
+                if kind == "denoise":
+                    t.block = decided["ids"][b]
+                    for i in np.flatnonzero(decided["picked"][b]):
+                        t.found[at + int(i)] = (
+                            int(t.block[i]), float(decided["score"][b, i]),
+                            float(decided["confidence"][b, i]), t.forwards)
+                        c["positions_unmasked"] += 1
+                    t.denoised += 1
+                else:
+                    t.done, t.block = at + B, None
+                t.forwards += 1
+                if t.done == t.end:
+                    self._finish_slate(t, done)
+                    finished.append(t)
         return finished
 
     def _finish_slate(self, t: SeqTicket, done) -> None:
@@ -643,22 +699,26 @@ class SeqStackModel:
         done(t)
 
     def _count(self, kind: str, counted) -> None:
-        c = self.counters
-        c[f"{kind}_runs"] += 1
-        c[f"{kind}_tokens"] += int(counted["tokens"])
-        if "expert_load" in counted:
-            load = np.asarray(counted["expert_load"], np.int64)
-            c[f"{kind}_held_picks"] += int(load.sum())
-            c[f"{kind}_experts_touched"] += int((load > 0).sum())
-            small = moe_ops.small_forward(self._programs.tokens[kind])
-            c[f"{kind}_dense_expert_runs"] += small
-            if not small:
-                c[f"{kind}_expert_row_tiles"] += int(
-                    moe_ops.row_tiles(load).sum())
-            c[f"{kind}_zero_picks"] += int(
-                np.asarray(counted["zero_picks"]).sum())
-            c["load_max_sum"] += float(load.max(axis=1).sum())
-            c["load_mean_sum"] += float(load.mean(axis=1).sum())
+        """What the program's expert layers counted (device arrays, each
+        fetched here, but a block program's: they came with its
+        decision)."""
+        with trace.device_span("seq.count", program=kind):
+            c = self.counters
+            c[f"{kind}_runs"] += 1
+            c[f"{kind}_tokens"] += int(counted["tokens"])
+            if "expert_load" in counted:
+                load = np.asarray(counted["expert_load"], np.int64)
+                c[f"{kind}_held_picks"] += int(load.sum())
+                c[f"{kind}_experts_touched"] += int((load > 0).sum())
+                small = moe_ops.small_forward(self._programs.tokens[kind])
+                c[f"{kind}_dense_expert_runs"] += small
+                if not small:
+                    c[f"{kind}_expert_row_tiles"] += int(
+                        moe_ops.row_tiles(load).sum())
+                c[f"{kind}_zero_picks"] += int(
+                    np.asarray(counted["zero_picks"]).sum())
+                c["load_max_sum"] += float(load.max(axis=1).sum())
+                c["load_mean_sum"] += float(load.mean(axis=1).sum())
 
     def _answer(self, tickets: List[SeqTicket], h_last, done) -> None:
         """``h_last``: a program's whole output (its rows beyond the

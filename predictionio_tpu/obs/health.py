@@ -370,6 +370,8 @@ class _Watch:
     trace_id: Optional[str]
     fired: bool = False
     deadman: bool = False
+    #: the watched thread's span account, where it keeps one
+    account: Optional[trace.ThreadAccount] = None
 
 
 class _Monitor:
@@ -488,7 +490,8 @@ class Watchdog:
             now = time.monotonic()
             key = _MONITOR.arm(_Watch(
                 watchdog=self, deadline=now + deadline, armed_at=now,
-                trace_id=trace.current_trace_id()))
+                trace_id=trace.current_trace_id(),
+                account=trace.thread_account()))
         t0 = time.perf_counter()
         try:
             yield
@@ -564,6 +567,10 @@ class Watchdog:
         }
         if watch.trace_id:
             payload["trace"] = watch.trace_id
+        # where an accounted thread (the serving workers) stands
+        span = watch.account.innermost() if watch.account else None
+        if span:
+            payload["span"] = trace.DEVICE_SPAN_PREFIX + span
         dump_path = None
         if self.dump_stacks:
             dump_path = self._dump_stacks(payload)
@@ -577,7 +584,7 @@ class Watchdog:
         )
         journal.emit("watchdog_stall", watchdog=self.name,
                      waited_sec=payload["waited_sec"],
-                     stall_trace=watch.trace_id,
+                     stall_trace=watch.trace_id, span=payload.get("span"),
                      stack_dump=dump_path)
         # the counter is the LAST effect: anything observing it (tests,
         # alert rules sampling right after a stall) sees the log line,

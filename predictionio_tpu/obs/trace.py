@@ -40,6 +40,20 @@ none), costs well under a microsecond while no profiler session runs,
 and is a null context in a process that never imported JAX (the event
 and storage servers). ``span()`` opens one too, so a boundary that
 already records is annotated from the same call site.
+
+A WORKER thread may ask, once (``account_thread()``), that every span it
+opens be accounted on two clocks: per span name a count, its SELF wall
+nanoseconds and its SELF thread-CPU nanoseconds (its own less what its child
+spans covered; the time between its outermost spans goes under
+``unspanned``), so that the names add up to the thread's time. Wall less CPU
+is the time the thread was not running: inside a name of ``WAIT_PHASES`` it
+was meant to wait, anywhere else it wanted the interpreter (or the CPU, or a
+transfer) and did not have it. The same call site gives the annotation, the
+record and the accounting; a thread that did not ask pays one thread-local
+read a span. Where a read of the thread's CPU clock costs more than
+``CPU_READ_LIMIT_NS`` (a sandboxed kernel answers it in 6 us, in steps of 10
+ms, and charges a waiting thread: PERF.md §6, PR 37) the account keeps the
+wall clock alone and reports every CPU figure as None: not measured.
 """
 
 from __future__ import annotations
@@ -326,6 +340,162 @@ def traced_headers(headers: Optional[Dict[str, str]] = None
     return out
 
 
+#: accounted names inside which a worker thread is MEANT to be off the CPU:
+#: nothing queued, or the device at work. Every other accounted name is host
+#: work, and off the CPU there is contention (benchmarks/WORKER_PHASES.md)
+WAIT_PHASES = frozenset({"batch.idle", "index.fetch", "seq.wait"})
+
+#: the accounted time a thread spent between its outermost spans
+UNSPANNED = "unspanned"
+
+#: a read of the thread's CPU clock (``time.thread_time_ns``) that costs more
+#: than this is not worth making four times a span
+CPU_READ_LIMIT_NS = 1000
+
+_thread = threading.local()
+
+
+def _cpu_clock_is_cheap() -> bool:
+    """Whether this host answers ``time.thread_time_ns`` within the limit
+    (the least of five reads: a vDSO or a plain syscall does, a sandbox's
+    emulated kernel does not)."""
+    def read_ns() -> int:
+        t0 = time.perf_counter_ns()
+        time.thread_time_ns()
+        return time.perf_counter_ns() - t0
+
+    return min(read_ns() for _ in range(5)) <= CPU_READ_LIMIT_NS
+
+
+class ThreadAccount:
+    """One thread's spans on two clocks (module docstring). Only its own
+    thread writes; ``snapshot`` and ``innermost`` may be read from any."""
+
+    __slots__ = ("totals", "busy", "cpu_clock", "_lock", "_open", "_wall",
+                 "_cpu")
+
+    def __init__(self):
+        #: the thread's CPU clock; None where it is too dear to read (the
+        #: CPU figures are then None too)
+        self.cpu_clock = (time.thread_time_ns if _cpu_clock_is_cheap()
+                          else None)
+        #: name -> [count, self wall ns, self CPU ns] of the spans closed
+        self.totals: Dict[str, list] = {}
+        #: the open spans, outermost first: [name, wall at entry, CPU at
+        #: entry, wall its closed children covered, CPU they covered]
+        self._open: List[list] = []
+        # the books are written under it, a few hundred ns a span, so that
+        # a snapshot from another thread finds them whole
+        self._lock = threading.Lock()
+        #: inside ``enter`` / ``exit``: a collection that starts there (they
+        #: allocate) is annotated but not accounted, it would find the lock
+        #: taken
+        self.busy = False
+        # where the last outermost span ended
+        self._wall, self._cpu = self._now()
+
+    def _now(self):
+        return (time.perf_counter_ns(),
+                self.cpu_clock() if self.cpu_clock is not None else 0)
+
+    def _add(self, name: str, wall: int, cpu: int) -> None:
+        total = self.totals.get(name)
+        if total is None:
+            total = self.totals[name] = [0, 0, 0]
+        total[0] += 1
+        total[1] += wall
+        total[2] += cpu
+
+    def enter(self, name: str) -> None:
+        self.busy = True
+        with self._lock:
+            wall, cpu = self._now()
+            if not self._open:
+                self._add(UNSPANNED, wall - self._wall, cpu - self._cpu)
+            self._open.append([name, wall, cpu, 0, 0])
+        self.busy = False
+
+    def exit(self) -> None:
+        self.busy = True
+        with self._lock:
+            wall, cpu = self._now()
+            name, wall0, cpu0, child_wall, child_cpu = self._open.pop()
+            wall_all, cpu_all = wall - wall0, cpu - cpu0
+            self._add(name, wall_all - child_wall, cpu_all - child_cpu)
+            if self._open:
+                self._open[-1][3] += wall_all
+                self._open[-1][4] += cpu_all
+            else:
+                self._wall, self._cpu = wall, cpu
+        self.busy = False
+
+    def snapshot(self) -> Dict[str, list]:
+        """``{name: [count, self wall ns, self CPU ns]}``: the spans closed
+        so far (the counts are theirs) and, in wall time, what the spans
+        still open have had of their own: two snapshots differ by the
+        thread's time between them (another thread's CPU clock is not read:
+        a span's CPU is booked as it closes; None where this host's CPU
+        clock is too dear to read)."""
+        # asked by the account's own thread, a collection that starts in
+        # here must not come for the lock again
+        own = thread_account() is self
+        self.busy = self.busy or own
+        with self._lock:
+            out = {name: list(t) for name, t in self.totals.items()}
+            until = time.perf_counter_ns()
+            for name, wall0, _, child_wall, _ in reversed(self._open):
+                out.setdefault(name, [0, 0, 0])[1] += (
+                    until - wall0 - child_wall)
+                until = wall0
+            if not self._open:
+                out.setdefault(UNSPANNED, [0, 0, 0])[1] += until - self._wall
+        if own:
+            self.busy = False
+        if self.cpu_clock is None:
+            for total in out.values():
+                total[2] = None
+        return out
+
+    def innermost(self) -> Optional[str]:
+        """The name of the innermost span open now, if any."""
+        try:
+            return self._open[-1][0]
+        except IndexError:
+            return None
+
+
+def account_thread() -> ThreadAccount:
+    """From now on every span THIS thread opens is accounted; the account
+    is the thread's one, however often it asks."""
+    account = getattr(_thread, "account", None)
+    if account is None:
+        account = _thread.account = ThreadAccount()
+    return account
+
+
+def thread_account() -> Optional[ThreadAccount]:
+    """This thread's account, None where it never asked for one."""
+    return getattr(_thread, "account", None)
+
+
+class _Accounted:
+    """An annotation that also enters and leaves the thread's account."""
+
+    __slots__ = ("_account", "_name", "_annotation")
+
+    def __init__(self, account, name, annotation):
+        self._account, self._name = account, name
+        self._annotation = annotation
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._account.enter(self._name)
+
+    def __exit__(self, *exc):
+        self._account.exit()
+        return self._annotation.__exit__(*exc)
+
+
 #: every annotation this module writes into a profiler trace starts so
 DEVICE_SPAN_PREFIX = "pio:"
 
@@ -339,7 +509,15 @@ def device_span(name: str, **attrs: Any):
     ``pio:index.fetch`` span of a profiler capture, on the device
     trace's clock (module docstring). Attributes are scalars; the
     active request's trace id rides along as ``trace``, which is how
-    the spans of one request are told from another's."""
+    the spans of one request are told from another's. On a thread that
+    asked (:func:`account_thread`) the block is accounted too."""
+    account = getattr(_thread, "account", None)
+    if account is not None and not account.busy:
+        return _Accounted(account, name, _annotate(name, attrs))
+    return _annotate(name, attrs)
+
+
+def _annotate(name: str, attrs: Dict[str, Any]):
     global _annotation
     if _annotation is None:
         if "jax" not in sys.modules:
@@ -351,6 +529,32 @@ def device_span(name: str, **attrs: Any):
     if ctx is not None:
         attrs["trace"] = ctx.trace_id
     return _annotation(DEVICE_SPAN_PREFIX + name, **attrs)
+
+
+def _gc_span(phase: str, info: Dict[str, Any]) -> None:
+    """``gc.callbacks``: a FULL collection as a ``pio:gc`` span on the thread
+    that collects (both calls of one collection come on that thread). The
+    young generations' collections are many and short, and a span round each
+    cost ``slates-c8`` 2% of its rate (PERF.md §6, PR 37)."""
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        _thread.gc = device_span("gc", generation=2)
+        _thread.gc.__enter__()
+    else:
+        opened = _thread.__dict__.pop("gc", None)
+        if opened is not None:
+            opened.__exit__(None, None, None)
+
+
+def span_collections() -> None:
+    """From now on every full garbage collection of this process (the
+    oldest generation's: the one that walks the id maps) is a ``pio:gc``
+    span, accounted where its thread is."""
+    import gc
+
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 @contextlib.contextmanager

@@ -155,6 +155,10 @@ class ServingStats:
         }
 
 
+def _phases(account: Optional[trace.ThreadAccount]) -> dict:
+    return account.snapshot() if account is not None else {}
+
+
 class _Pending:
     __slots__ = ("payload", "event", "result", "error", "abandoned",
                  "t_submit", "trace_ctx")
@@ -247,6 +251,9 @@ class MicroBatcher:
         # dispatches so far; only the worker writes it. Rides on each
         # pio:batch.dispatch span so a trace can tell them apart
         self._seq = 0
+        # the worker's spans on two clocks (obs/trace.account_thread);
+        # the worker sets it as its loop starts
+        self._account: Optional[trace.ThreadAccount] = None
         self._stop = False
         # orders submit()'s stop-check+enqueue against stop()'s flag+wake,
         # so nothing can be enqueued after the worker's shutdown drain
@@ -288,9 +295,11 @@ class MicroBatcher:
     def _loop(self) -> None:
         import queue as _queue
 
+        self._account = trace.account_thread()
         leftover: List[_Pending] = []
         while True:
-            first = self._queue.get()
+            with trace.device_span("batch.idle"):   # nothing to do
+                first = self._queue.get()
             if self._stop:
                 leftover.append(first)
                 break
@@ -337,7 +346,9 @@ class MicroBatcher:
     def histogram(self) -> dict:
         """Dispatch-size distribution since start: {"1": lone requests,
         "2": two-query dispatches, ...}. Sizes > 1 are queries that
-        shared one device dispatch."""
+        shared one device dispatch. ``phases``: where the worker thread's
+        time went since start, ``{span name: [count, self wall ns, self
+        CPU ns]}`` (obs/trace.ThreadAccount); they add up to its time."""
         with self._hist_lock:
             hist = {str(k): v for k, v in sorted(self._hist.items())}
             abandoned = self._abandoned
@@ -347,6 +358,7 @@ class MicroBatcher:
             "batchSizeHistogram": hist,
             # timed-out submitters, kept OUT of the latency splits
             "abandonedRequests": abandoned,
+            "phases": _phases(self._account),
         }
 
     def _answer(self, batch) -> None:
@@ -490,6 +502,7 @@ class StepWorker:
         self._hist: dict = {}               # finished per step -> steps
         self._splits = deque(maxlen=50_000)
         self._abandoned = 0
+        self._account: Optional[trace.ThreadAccount] = None
         self._stop = False
         self._stop_lock = threading.Lock()
         self._worker = threading.Thread(target=self._loop, daemon=True,
@@ -527,7 +540,8 @@ class StepWorker:
         return {"stepwise": True, "dispatches": sum(hist.values()),
                 "batchSizeHistogram": hist,
                 "answered": sum(int(k) * v for k, v in hist.items()),
-                "abandonedRequests": abandoned}
+                "abandonedRequests": abandoned,
+                "phases": _phases(self._account)}
 
     def recent_splits(self, n: int):
         """Last ``n`` answered requests' (seconds from submit to admission —
@@ -544,7 +558,10 @@ class StepWorker:
         """Whatever is queued; with nothing in hand, wait for the first."""
         import queue as _queue
 
-        got = [self._queue.get()] if block else []
+        got = []
+        if block:
+            with trace.device_span("batch.idle"):   # nothing to do
+                got.append(self._queue.get())
         try:
             for _ in range(self._queue.qsize() + 1):
                 got.append(self._queue.get_nowait())
@@ -553,6 +570,7 @@ class StepWorker:
         return got
 
     def _loop(self) -> None:
+        self._account = trace.account_thread()
         waiting: List[_Pending] = []     # admitted, no ticket yet
         # (pending, deployment, ticket, t_first): a ticket is stepped by
         # the deployment that began it, through a reload too
@@ -697,6 +715,10 @@ class EngineServer(HTTPServerBase):
         # ONE replica of a fleet; a standalone server stays untagged
         self.chaos_tag = chaos_tag or os.environ.get("PIO_CHAOS_TAG") or None
         self._batcher = None
+        if micro_batch:
+            # a full collection is a span like any other: on the worker it
+            # is accounted, on a handler it shows in a capture
+            trace.span_collections()
         if micro_batch and self.deployment.stepwise:
             # an algorithm that answers in steps gets the step worker; every
             # other engine keeps the batcher as it was

@@ -7,6 +7,7 @@ import dataclasses
 import importlib.util
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -689,11 +690,19 @@ def test_an_extension_is_served_within_one_step_of_a_long_prefill():
         t_ext = model.begin({"items": [f"i{r}" for r in short + [1, 2]],
                              "num": 5})
         assert t_ext.extension and t_ext.remaining == 2
+        queued0 = {k: model.counters[k] for k in ("extend_queue_ns",
+                                                  "extend_tickets")}
         done = model.step([t_long, t_ext])            # its first step
+        step_ended_ns = time.perf_counter_ns()
         assert done == [t_ext] and len(t_ext.result) == 5
         assert t_long.done == 32 and t_long.result is None
         assert model.counters["prefill_runs"] - chunks0 == 2
-        assert model.counters["extensions_waited"] == 0
+        # it waited for no step: its program was launched inside the first
+        # step after its admission, and the counters say how long after
+        assert model.counters["extend_tickets"] - queued0["extend_tickets"] == 1
+        waited = model.counters["extend_queue_ns"] - queued0["extend_queue_ns"]
+        assert waited == t_ext.launched_ns - t_ext.admitted_ns
+        assert 0 <= waited < step_ended_ns - t_ext.admitted_ns
         while t_long.result is None:
             model.step([t_long])
         assert model.counters["prefill_runs"] - chunks0 == 5
