@@ -429,10 +429,14 @@ class DataObs:
         self._changes: collections.deque = collections.deque(maxlen=128)
         self._changes_seen: set = set()
         self._changes_total = 0
-        # unknown-entity coverage window: (refs, unknown) pairs
+        # unknown-entity coverage window: (refs, unknown) pairs, and the
+        # window's two sums kept beside it (integers: no drift), so that a
+        # query costs the same under the lock whether the window is full
         self._queries: collections.deque = collections.deque(
             maxlen=max(16, metrics.env_int("PIO_DATAOBS_QUERY_WINDOW",
                                            1024)))
+        self._query_refs = 0
+        self._query_unknown = 0
         self._breach_active: Dict[str, bool] = {}
         self._last_breach_check = 0.0
 
@@ -671,17 +675,24 @@ class DataObs:
         named, how many the served model had never seen."""
         if not self.enabled() or refs <= 0:
             return
+        refs, unknown = int(refs), int(unknown)
         with self._lock:
-            self._queries.append((int(refs), int(unknown)))
+            window = self._queries
+            if len(window) == window.maxlen:
+                old_refs, old_unknown = window[0]  # the append drops it
+                self._query_refs -= old_refs
+                self._query_unknown -= old_unknown
+            window.append((refs, unknown))
+            self._query_refs += refs
+            self._query_unknown += unknown
             ratio = self._unknown_ratio_locked()
         _UNKNOWN_RATIO.set(ratio)
         self._maybe_check_breach()
 
     def _unknown_ratio_locked(self) -> float:
-        seen = sum(r for r, _ in self._queries)
-        if not seen:
+        if not self._query_refs:
             return 0.0
-        return sum(u for _, u in self._queries) / float(seen)
+        return self._query_unknown / float(self._query_refs)
 
     def unknown_ratio(self) -> float:
         with self._lock:
@@ -971,7 +982,7 @@ class DataObs:
                 "events_total": self._events_total,
                 "tail_events_total": self._tail_total,
                 "bytes_total": self._bytes_total,
-                "queries_seen": sum(r for r, _ in self._queries),
+                "queries_seen": self._query_refs,
                 "quantiles": {
                     "value": self._value_q.summary(),
                     "payload_bytes": self._bytes_q.summary(),

@@ -64,11 +64,11 @@ import contextvars
 import json
 import logging
 import os
+import random
 import re
 import sys
 import threading
 import time
-import uuid
 from typing import Any, Dict, List, NamedTuple, Optional
 
 from predictionio_tpu.obs import metrics
@@ -208,12 +208,27 @@ def _write_log_line(line: str) -> None:
                     path, e)
 
 
+#: the process's one source of ids, seeded once from the system (a forked
+#: child seeds it again, a spawned one imports it anew). ``uuid4`` reads
+#: ``os.urandom`` a call: a system call made with the interpreter released,
+#: which a thread gets back only behind whoever else wants it (3.7 ms a
+#: dispatch for the batcher's worker beside 14 woken handlers: PERF.md §6,
+#: PRs 37-38). These are correlation ids, not secrets: an id that must not be
+#: guessed (a feedback ``prId``, a scan id) stays on ``uuid4``.
+#: ``getrandbits`` is one C call, whole under the interpreter's lock.
+_ids = random.Random(os.urandom(32))
+_id_bits = _ids.getrandbits
+
+os.register_at_fork(after_in_child=lambda: _ids.seed(os.urandom(32)))
+
+
 def new_trace_id() -> str:
-    return uuid.uuid4().hex
+    """32 lower-case hex digits, as ``uuid4().hex`` gave."""
+    return "%032x" % _id_bits(128)
 
 
 def _new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return "%016x" % _id_bits(64)
 
 
 def current_context() -> Optional[SpanContext]:

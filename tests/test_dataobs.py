@@ -369,6 +369,94 @@ class TestUnknownEntityCoverage:
             server.stop()
         assert DATAOBS.report()["queries_seen"] == 0
 
+    # the window's two sums are kept beside it (PERF.md §6, PR 38): a
+    # query costs O(1) under the lock and the ratio is what the sums over
+    # the whole window gave, to the last bit (integers: nothing drifts)
+    @pytest.mark.parametrize("window", [16, 1024])
+    def test_running_sums_read_what_the_whole_window_sums_to(
+            self, monkeypatch, window):
+        import fractions
+
+        from predictionio_tpu.obs.dataobs import _UNKNOWN_RATIO
+
+        monkeypatch.setenv("PIO_DATAOBS_QUERY_WINDOW", str(window))
+        # breach checks read the ratio too; keep them out of the loop
+        monkeypatch.setenv("PIO_DATAOBS_BREACH_INTERVAL_SEC", "3600")
+        DATAOBS.reset()
+        rng = np.random.default_rng(window)
+        refs = rng.integers(1, 40, 5_000)
+        # stretches with no unknown entity, with a few, with all unknown
+        share = rng.choice([0.0, 0.1, 1.0], 5_000)
+        unknown = np.minimum(refs, (refs * share * rng.random(5_000) * 2)
+                             .astype(np.int64))
+        pairs = list(zip(refs.tolist(), unknown.tolist()))
+        for n, (r, u) in enumerate(pairs, 1):
+            DATAOBS.note_query(r, u)
+            tail = pairs[max(0, n - window):n]
+            seen = sum(r for r, _ in tail)
+            missed = sum(u for _, u in tail)
+            ratio = missed / float(seen)
+            assert DATAOBS.unknown_ratio() == ratio == _UNKNOWN_RATIO.value
+            assert fractions.Fraction(
+                DATAOBS._query_unknown, DATAOBS._query_refs) == \
+                fractions.Fraction(missed, seen)
+        assert len(DATAOBS._queries) == window
+        assert DATAOBS.report()["queries_seen"] == seen
+
+    def test_reset_and_a_new_window_start_from_zero_sums(self, monkeypatch):
+        for _ in range(40):
+            DATAOBS.note_query(5, 5)
+        assert DATAOBS.unknown_ratio() == 1.0
+        DATAOBS.reset()
+        assert (DATAOBS._query_refs, DATAOBS._query_unknown) == (0, 0)
+        assert DATAOBS.unknown_ratio() == 0.0
+        assert DATAOBS.report()["queries_seen"] == 0
+        DATAOBS.note_query(4, 1)
+        assert DATAOBS.unknown_ratio() == 0.25
+        # the knob keeps its meaning: read at reset, floor of 16 pairs
+        monkeypatch.setenv("PIO_DATAOBS_QUERY_WINDOW", "20")
+        DATAOBS.reset()
+        assert DATAOBS._queries.maxlen == 20
+        assert (DATAOBS._query_refs, DATAOBS._query_unknown) == (0, 0)
+        for _ in range(20):
+            DATAOBS.note_query(2, 2)
+        for _ in range(20):
+            DATAOBS.note_query(2, 0)
+        assert DATAOBS.unknown_ratio() == 0.0
+        assert DATAOBS.report()["queries_seen"] == 40
+        monkeypatch.setenv("PIO_DATAOBS_QUERY_WINDOW", "3")
+        DATAOBS.reset()
+        assert DATAOBS._queries.maxlen == 16
+
+    def test_a_query_costs_the_same_at_a_full_window(self, monkeypatch):
+        """Summing the window a query cost 70 us at 1,024 pairs and grew
+        with the knob; a ratio of best times, not a wall-clock limit."""
+        import time
+
+        monkeypatch.setenv("PIO_DATAOBS_QUERY_WINDOW", "16384")
+        monkeypatch.setenv("PIO_DATAOBS_BREACH_INTERVAL_SEC", "3600")
+
+        def best_of_five(prepare):
+            best = float("inf")
+            for _ in range(5):
+                prepare()
+                t0 = time.perf_counter()
+                for _ in range(200):
+                    DATAOBS.note_query(3, 1)
+                best = min(best, time.perf_counter() - t0)
+            return best
+
+        def full():
+            if len(DATAOBS._queries) < 16384:
+                for _ in range(16384):
+                    DATAOBS.note_query(3, 1)
+
+        empty = best_of_five(DATAOBS.reset)
+        at_full = best_of_five(full)
+        assert len(DATAOBS._queries) == DATAOBS._queries.maxlen == 16384
+        # the old sums read 16,384 pairs a query here: some 80 times
+        assert at_full < 5 * empty, (at_full, empty)
+
 
 # ---------------------------------------------------------------------------
 # pio top ingest row

@@ -434,6 +434,132 @@ def test_span_records_error_and_nesting(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# ids: one process-wide generator, no system call an id (PERF.md §6, PR 38)
+# ---------------------------------------------------------------------------
+
+TRACE_ID_FORM = re.compile(r"[0-9a-f]{32}")
+SPAN_ID_FORM = re.compile(r"[0-9a-f]{16}")
+
+
+def test_ids_are_minted_without_a_system_call(monkeypatch):
+    """``uuid4`` read ``os.urandom`` an id: ``getrandom`` with the
+    interpreter released, five times a request and dispatch. The
+    generator is seeded as the module is imported and asks nothing
+    afterwards."""
+    import os
+    import uuid
+
+    def refused(*args, **kwargs):
+        raise AssertionError("an id asked the system for randomness")
+
+    monkeypatch.setattr(os, "urandom", refused)
+    monkeypatch.setattr(uuid, "uuid4", refused)
+    for _ in range(100):
+        trace_id, span_id = trace.new_trace_id(), trace._new_span_id()
+        assert TRACE_ID_FORM.fullmatch(trace_id), trace_id
+        assert SPAN_ID_FORM.fullmatch(span_id), span_id
+        assert trace.valid_trace_id(trace_id)
+        assert trace.valid_span_id(span_id)
+    with trace.new_trace() as trace_id:
+        with trace.span("ids.no_syscall") as span_id:
+            pass
+    record, = trace.recent_spans(trace_id=trace_id)
+    assert (record["trace"], record["span"]) == (trace_id, span_id)
+
+
+@pytest.mark.parametrize("mint", ["new_trace_id", "_new_span_id"])
+def test_200000_ids_from_8_threads_are_distinct(mint):
+    import threading
+
+    draw = getattr(trace, mint)
+    drawn = [None] * 8
+    start = threading.Barrier(8)
+
+    def work(k):
+        start.wait()
+        drawn[k] = [draw() for _ in range(25_000)]
+
+    threads = [threading.Thread(target=work, args=(k,)) for k in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    ids = [i for part in drawn for i in part]
+    assert len(ids) == 200_000 == len(set(ids))
+    form = TRACE_ID_FORM if mint == "new_trace_id" else SPAN_ID_FORM
+    assert all(form.fullmatch(i) for i in ids)
+
+
+def _next_ids(n=8):
+    return ([trace.new_trace_id() for _ in range(n)]
+            + [trace._new_span_id() for _ in range(n)])
+
+
+def test_a_forked_child_does_not_repeat_its_parents_next_ids():
+    """A fork copies the generator's state: unseeded, the child's next
+    ids ARE the parent's next ids, and two replicas of a fleet would
+    label different requests alike."""
+    import os
+    import warnings
+
+    read_end, write_end = os.pipe()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # forking beside other threads
+        pid = os.fork()
+    if pid == 0:  # the child: report and leave, running nothing else
+        try:
+            os.write(write_end, " ".join(_next_ids()).encode())
+        finally:
+            os._exit(0)
+    os.close(write_end)
+    ours = _next_ids()
+    with os.fdopen(read_end, "rb") as pipe:
+        theirs = pipe.read().decode().split()
+    assert os.waitpid(pid, 0)[1] == 0
+    assert len(theirs) == len(ours) == 16
+    assert not set(theirs) & set(ours)
+    assert all(trace.valid_span_id(i) for i in theirs)
+
+
+def test_a_spawned_child_does_not_repeat_its_parents_next_ids():
+    import concurrent.futures
+    import multiprocessing
+
+    spawn = multiprocessing.get_context("spawn")
+    with concurrent.futures.ProcessPoolExecutor(1, mp_context=spawn) as pool:
+        theirs = [pool.submit(trace.new_trace_id).result(timeout=120)
+                  for _ in range(8)]
+    ours = [trace.new_trace_id() for _ in range(8)]
+    assert len(set(theirs)) == 8 and not set(theirs) & set(ours)
+    assert all(TRACE_ID_FORM.fullmatch(i) for i in theirs)
+
+
+def test_span_records_keep_their_ids_and_parent_chain():
+    """What a record, the ``X-PIO-Trace-Id`` echo and obs/collect.py's
+    stitching see: the trace's 32 hex digits on every record, a fresh
+    16 hex digits a span, each child naming its parent's."""
+    trace.clear_recent()
+    with trace.new_trace() as trace_id:
+        assert TRACE_ID_FORM.fullmatch(trace_id)
+        assert trace.traced_headers() == {trace.TRACE_HEADER: trace_id}
+        with trace.span("chain.a") as a:
+            assert trace.traced_headers()[trace.PARENT_HEADER] == a
+            with trace.span("chain.b") as b:
+                with trace.span("chain.c") as c:
+                    pass
+            with trace.span("chain.d") as d:
+                pass
+    assert trace.current_trace_id() is None
+    by_name = {r["name"]: r for r in trace.recent_spans(trace_id=trace_id)}
+    records = [by_name[n] for n in ("chain.a", "chain.b", "chain.c", "chain.d")]
+    assert [r["span"] for r in records] == [a, b, c, d]
+    assert len({a, b, c, d}) == 4
+    assert all(SPAN_ID_FORM.fullmatch(i) for i in (a, b, c, d))
+    assert [r["parent"] for r in records] == [None, a, b, a]
+    assert {r["trace"] for r in records} == {trace_id}
+
+
+# ---------------------------------------------------------------------------
 # JAX runtime instrumentation
 # ---------------------------------------------------------------------------
 
