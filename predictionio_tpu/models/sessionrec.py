@@ -420,14 +420,21 @@ class SeqStackModel:
         # kind's or none: the program's shape decides,
         # ``ops/moe.small_forward``) and, for the others, the products of
         # sorted rows their grouped kernels ran (over ``experts_touched``:
-        # how many products shared one read of an expert)
+        # how many products shared one read of an expert); where the
+        # router picks groups before experts, the (token, expert layer)
+        # pairs that kept a group with experts held here
         self.counters = {
             f"{kind}_{what}": 0 for kind in ("extend", "prefill", "block")
             for what in ("runs", "tokens", "held_picks", "experts_touched",
                          "zero_picks", "dense_expert_runs",
-                         "expert_row_tiles")}
+                         "expert_row_tiles", "group_hit_tokens")}
         self.counters.update({
             "extend_rows": 0, "extend_latent_positions": 0,
+            # blocks of cached latents a layer's attention walked for the
+            # extended rows (every row of a batch as far as its longest),
+            # and what each row's own reach would have taken
+            "extend_latent_blocks_attended": 0,
+            "extend_latent_blocks_own": 0,
             "extend_kv_positions": 0, "extend_state_rows": 0,
             # from a ticket's admission to the launch of the first program
             # that carries rows of it, summed, and the tickets summed over:
@@ -587,6 +594,12 @@ class SeqStackModel:
                         ("mamba2", "extend_state_rows", len(ext))):
                     if kind in self.kinds:
                         self.counters[counter] += n
+                if "mla" in self.kinds:
+                    own = [int(programs.n_blocks(t.done + sh.extend_len))
+                           for t in ext]
+                    self.counters["extend_latent_blocks_own"] += sum(own)
+                    self.counters["extend_latent_blocks_attended"] += (
+                        max(own) * len(ext))
                 for t in ext:
                     t.done = len(t.rows)
                 self._answer(ext, h, done)
@@ -719,6 +732,9 @@ class SeqStackModel:
                     np.asarray(counted["zero_picks"]).sum())
                 c["load_max_sum"] += float(load.max(axis=1).sum())
                 c["load_mean_sum"] += float(load.mean(axis=1).sum())
+            if "group_hits" in counted:
+                c[f"{kind}_group_hit_tokens"] += int(
+                    np.asarray(counted["group_hits"]).sum())
 
     def _answer(self, tickets: List[SeqTicket], h_last, done) -> None:
         """``h_last``: a program's whole output (its rows beyond the

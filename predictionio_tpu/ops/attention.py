@@ -44,11 +44,12 @@ _NEG = -0.7 * jnp.finfo(jnp.float32).max
 
 
 def mha_reference(
-    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True
+    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
+    scale: Optional[float] = None,   # None: q's head width ** -0.5
 ) -> jax.Array:
     """Materialized-softmax attention, the correctness oracle for the
     blockwise/ring paths (and fine for short sequences)."""
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
     s = s * scale
     if causal:

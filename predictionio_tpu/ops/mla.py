@@ -22,6 +22,15 @@ Matrix products take their inputs in the weights' type and accumulate in
 float32; norms, RoPE and softmax are float32. Padding positions of a chunk or
 of an extension write latents beyond the session's real length: a later
 position is written before anything attends to it, so they are never read.
+
+Positions past the length a model was trained at (YaRN, ``rope_factor`` > 1):
+pair ``i`` of the RoPE dimensions turns at ``f_i (1 - r_i) + f_i / factor *
+r_i``, ``f_i = theta^(-2i/d)``, where ``r_i`` ramps from 0 at the pair that
+makes ``beta_fast`` turns over the original length to 1 at the pair that
+makes ``beta_slow`` (fast pairs keep their frequency, slow ones are
+interpolated); cos and sin are multiplied by ``m(mscale) / m(mscale_all_dim)``
+and the softmax scale by ``m(mscale_all_dim)^2``, ``m(s) = 0.1 s ln(factor)
++ 1``. One scale (:attr:`MLADims.softmax_scale`) reaches all three paths.
 """
 
 from __future__ import annotations
@@ -48,6 +57,12 @@ class MLADims:
     eps: float = 1e-5
     scale_q: bool = True        # cQ * sqrt(dim / q_rank)
     scale_kv: bool = True       # cKV * sqrt(dim / kv_rank)
+    rope_factor: float = 1.0    # YaRN: positions stretched this many times
+    rope_original_max: int = 0  # over the length the model was trained at
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     @property
     def latent(self) -> int:
@@ -56,6 +71,41 @@ class MLADims:
     @property
     def d_qk(self) -> int:
         return self.d_nope + self.d_rope
+
+    def _yarn_m(self, s: float) -> float:
+        return (0.1 * s * math.log(self.rope_factor) + 1.0
+                if self.rope_factor > 1 else 1.0)
+
+    @property
+    def softmax_scale(self) -> float:
+        """What a query-key product is multiplied by before the softmax."""
+        m = self._yarn_m(self.rope_mscale_all_dim)
+        return self.d_qk ** -0.5 * m * m
+
+    @property
+    def rope_amplitude(self) -> float:
+        """What cos and sin are multiplied by."""
+        return (self._yarn_m(self.rope_mscale)
+                / self._yarn_m(self.rope_mscale_all_dim))
+
+    def rope_freqs(self):
+        """The angle a position adds to each pair of the RoPE dimensions,
+        ``[d_rope / 2]`` float32."""
+        d = self.d_rope
+        f = self.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        if self.rope_factor <= 1:
+            return f
+
+        def pair_of(turns):     # the pair that makes ``turns`` turns over
+            return (d * math.log(self.rope_original_max     # the original
+                                 / (turns * 2 * math.pi))   # length
+                    / (2 * math.log(self.rope_theta)))
+
+        lo = max(math.floor(pair_of(self.rope_beta_fast)), 0)
+        hi = min(math.ceil(pair_of(self.rope_beta_slow)), d - 1)
+        r = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - lo)
+                     / max(hi - lo, 1e-3), 0.0, 1.0)
+        return f * (1.0 - r) + f / self.rope_factor * r
 
 
 def init(key, dims: MLADims, dtype=jnp.float32) -> dict:
@@ -86,15 +136,16 @@ def rms_norm(x, w, eps):
             * w.astype(jnp.float32))
 
 
-def rope(x, pos, theta):
+def rope(x, pos, freqs, amplitude: float = 1.0):
     """``x`` [..., T, H, d] or [..., T, d] (float32), ``pos`` [..., T]:
-    dimensions (2i, 2i+1) turned by ``pos * theta^(-2i/d)``."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos.astype(jnp.float32)[..., None] * inv           # [..., T, d/2]
+    dimensions (2i, 2i+1) turned by ``pos * freqs[i]`` (``freqs`` [d / 2]:
+    :meth:`MLADims.rope_freqs`), cos and sin times ``amplitude``."""
+    ang = pos.astype(jnp.float32)[..., None] * freqs         # [..., T, d/2]
     if x.ndim == pos.ndim + 2:                               # a head axis
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if amplitude != 1.0:
+        cos, sin = amplitude * cos, amplitude * sin
     x1, x2 = x[..., 0::2], x[..., 1::2]
     return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                      axis=-1).reshape(x.shape)
@@ -109,12 +160,13 @@ def project(p, dims: MLADims, x, pos):
     if d.scale_q:
         cq = cq * math.sqrt(d.dim / d.q_rank)
     q = mm(cq, p["w_uq"]).reshape(x.shape[:-1] + (d.heads, d.d_qk))
-    qn, qr = q[..., :d.d_nope], rope(q[..., d.d_nope:], pos, d.rope_theta)
+    freqs, amp = d.rope_freqs(), d.rope_amplitude
+    qn, qr = q[..., :d.d_nope], rope(q[..., d.d_nope:], pos, freqs, amp)
     down = mm(x, p["w_dkv"])
     ckv = rms_norm(down[..., :d.kv_rank], p["kv_norm"], d.eps)
     if d.scale_kv:
         ckv = ckv * math.sqrt(d.dim / d.kv_rank)
-    kr = rope(down[..., d.kv_rank:], pos, d.rope_theta)
+    kr = rope(down[..., d.kv_rank:], pos, freqs, amp)
     return qn, qr, jnp.concatenate([ckv, kr], axis=-1)
 
 
@@ -158,7 +210,8 @@ def attend_full(p, dims: MLADims, x, pos):
     qn, qr, latent = project(p, dims, x, pos)
     k, v = expand(p, dims, latent)
     q = jnp.concatenate([qn, qr], axis=-1)
-    return _out(p, dims, mha_reference(q, k, v, causal=True))
+    return _out(p, dims, mha_reference(q, k, v, causal=True,
+                                       scale=dims.softmax_scale))
 
 
 def prefill_chunk(p, dims: MLADims, x, offset, cache, slot, block: int):
@@ -181,7 +234,7 @@ def prefill_chunk(p, dims: MLADims, x, offset, cache, slot, block: int):
 
     n_blocks = (offset + C + block - 1) // block
     o = attend_over_blocks(q, pos, kv_block, n_blocks, block, d.d_v,
-                           dtype=jnp.float32)[0]
+                           dtype=jnp.float32, scale=d.softmax_scale)[0]
     return _out(p, d, o), cache
 
 
@@ -213,7 +266,7 @@ def extend(p, dims: MLADims, x, pos, cache, slots, n_blocks, block: int):
         return lat[:, :, None, :d.latent], lat[:, :, None, :d.kv_rank]
 
     o = attend_over_blocks(q, q_pos, kv_block, n_blocks, block, d.kv_rank,
-                           dtype=jnp.float32, scale=d.d_qk ** -0.5)
+                           dtype=jnp.float32, scale=d.softmax_scale)
     o = o.reshape(B, S, d.heads, d.kv_rank)
     o = jnp.einsum("bshc,chd->bshd", o.astype(w.dtype), w[..., d.d_nope:],
                    preferred_element_type=jnp.float32)

@@ -13,7 +13,12 @@ through it, ungated, and every chip of the deployment holds it.
 Routing: ``p = softmax(x W_r)`` in float32 at the highest matmul precision,
 ``S = top_k(p + bias)``, gate ``g_i = scale * p_i`` (the selected expert's own
 probability), or, where the model says so (``norm_topk``), renormalised over
-the picks: ``g_i = scale * p_i / sum_{j in S} p_j``.
+the picks: ``g_i = scale * p_i / sum_{j in S} p_j``. A model may score its
+experts one by one instead (``scoring="sigmoid"``: ``p = sigmoid(x W_r)``),
+and may pick GROUPS before experts (``n_group``, ``topk_group``): the routed
+experts lie in ``n_group`` equal runs, a group's score is the sum of its two
+largest ``p + bias``, and ``S`` is the ``top_k`` of the ``topk_group`` best
+groups' experts alone. One group (the default) is no selection by groups.
 
 What the held experts add is computed in one of two forms, chosen by the
 number of tokens ``T`` of the forward, a static shape (:func:`small_forward`;
@@ -95,6 +100,18 @@ class MoEDims:
     held: Tuple[int, int]            # (first routed expert held, how many)
     norm_topk: bool = False          # gates renormalised over the picks
     shared_dim: int = 0              # the shared expert's width; 0: none
+    scoring: str = "softmax"         # "softmax" over all | "sigmoid" of each
+    n_group: int = 1                 # equal runs of routed experts, of which
+    topk_group: int = 1              # this many are kept before the top_k
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown expert scoring {self.scoring!r}")
+        if self.n_group > 1 and (self.n_zero or self.n_routed % self.n_group
+                                 or not 0 < self.topk_group <= self.n_group):
+            raise ValueError(
+                "groups divide the routed experts evenly, keep 1..n_group "
+                "of them, and know no zero-compute experts")
 
     @property
     def n_router(self) -> int:
@@ -124,16 +141,46 @@ def init(key, dims: MoEDims, dtype=jnp.float32, bias_std: float = 0.0) -> dict:
     return out
 
 
-def route(p, dims: MoEDims, x):
-    """``(idx [T, top_k] int32, gates [T, top_k] float32)``."""
+def kept_groups(dims: MoEDims, choice):
+    """Which groups a token's pick is made from, ``[T, n_group]`` bool: the
+    ``topk_group`` whose two largest ``choice`` [T, n_routed] add up
+    highest (ties: the earlier group)."""
+    per = choice.reshape(choice.shape[0], dims.n_group, -1)
+    score = jax.lax.top_k(per, 2)[0].sum(axis=-1)
+    _, best = jax.lax.top_k(score, dims.topk_group)
+    return (best[:, :, None] == jnp.arange(dims.n_group)).any(axis=1)
+
+
+def route_kept(p, dims: MoEDims, x):
+    """:func:`route`'s answer and the groups it picked from (None where the
+    model selects by no groups)."""
     logits = jnp.dot(x.astype(jnp.float32), p["w_r"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    prob = jax.nn.softmax(logits, axis=-1)
-    _, idx = jax.lax.top_k(prob + p["bias"].astype(jnp.float32), dims.top_k)
+    prob = (jax.nn.softmax(logits, axis=-1) if dims.scoring == "softmax"
+            else jax.nn.sigmoid(logits))
+    choice, kept = prob + p["bias"].astype(jnp.float32), None
+    if dims.n_group > 1:
+        kept = kept_groups(dims, choice)
+        choice = jnp.where(
+            jnp.repeat(kept, dims.n_routed // dims.n_group, axis=1),
+            choice, -jnp.inf)
+    _, idx = jax.lax.top_k(choice, dims.top_k)
     gates = jnp.take_along_axis(prob, idx, axis=-1)
     if dims.norm_topk:
         gates = gates / gates.sum(axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), dims.scale * gates
+    return idx.astype(jnp.int32), dims.scale * gates, kept
+
+
+def route(p, dims: MoEDims, x):
+    """``(idx [T, top_k] int32, gates [T, top_k] float32)``."""
+    return route_kept(p, dims, x)[:2]
+
+
+def held_groups(dims: MoEDims) -> slice:
+    """The groups that hold any expert held here."""
+    e0, n = dims.held
+    per = dims.n_routed // dims.n_group
+    return slice(e0 // per, (e0 + n - 1) // per + 1)
 
 
 def swiglu(x, w_g, w_u, w_d):
@@ -247,9 +294,11 @@ def experts_streamed(p, dims: MoEDims, x, idx, gates, valid):
 def moe(p, dims: MoEDims, x, valid, scope: str = "moe"):
     """The layer's output here for tokens ``x`` [T, dim]: ``(y [T, dim]
     float32, counters)``; ``counters`` = tokens per held expert ``[n]`` and
-    the zero-compute picks of the valid tokens (a scalar), int32."""
+    the zero-compute picks of the valid tokens (a scalar), int32; where the
+    model picks groups first, also ``group_hits``: the valid tokens that
+    kept a group with experts held here."""
     with jax.named_scope(scope + ".route"):
-        idx, gates = route(p, dims, x)
+        idx, gates, kept = route_kept(p, dims, x)
     with jax.named_scope(scope + ".experts"):
         experts = (experts_streamed if small_forward(x.shape[0])
                    else experts_grouped)
@@ -257,11 +306,15 @@ def moe(p, dims: MoEDims, x, valid, scope: str = "moe"):
     if dims.shared_dim:
         with jax.named_scope(scope + ".shared"):
             routed = routed + swiglu(x, **p["shared"])
+    counters = {"expert_load": load, "zero_picks": jnp.int32(0)}
+    if kept is not None:
+        hits = kept[:, held_groups(dims)].any(axis=1) & valid
+        counters["group_hits"] = hits.sum().astype(jnp.int32)
     if not dims.n_zero:
-        return routed, {"expert_load": load, "zero_picks": jnp.int32(0)}
+        return routed, counters
     with jax.named_scope(scope + ".zero"):
         is_zero = idx >= dims.n_routed
         zero_gate = jnp.where(is_zero, gates, 0.0).sum(axis=-1)
         zero = zero_gate[:, None] * x.astype(jnp.float32)
         zero_picks = (is_zero & valid[:, None]).sum().astype(jnp.int32)
-    return routed + zero, {"expert_load": load, "zero_picks": zero_picks}
+    return routed + zero, {**counters, "zero_picks": zero_picks}

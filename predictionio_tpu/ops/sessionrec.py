@@ -797,7 +797,7 @@ class StackPrograms:
         ``mix_with(i_mixer, params, h, scope)`` gives block code its
         mixer."""
         spec = self.spec
-        loads, zeros, i_mixer = [], [], 0
+        loads, zeros, hits, i_mixer = [], [], [], 0
         for i, block in enumerate(spec.blocks):
             mixers = {"a": i_mixer, "b": i_mixer + 1}
 
@@ -810,6 +810,8 @@ class StackPrograms:
                                          scope=f"seq.layer{_i}.moe")
                 loads.append(counted["expert_load"])
                 zeros.append(counted["zero_picks"])
+                if "group_hits" in counted:
+                    hits.append(counted["group_hits"])
                 return y
 
             x = apply_block(spec, block, params["blocks"][i], x, mix,
@@ -819,6 +821,8 @@ class StackPrograms:
         if loads:
             counters.update(expert_load=jnp.stack(loads),
                             zero_picks=jnp.stack(zeros))
+        if hits:
+            counters["group_hits"] = jnp.stack(hits)
         return x, counters
 
     def _final(self, params, h):
@@ -929,7 +933,8 @@ class StackPrograms:
             np.int32(slot), np.int32(offset))
         return h_last, counters
 
-    def _n_blocks(self, reach: int) -> np.int32:
+    def n_blocks(self, reach: int) -> np.int32:
+        """The cached blocks attention walks to reach position ``reach``."""
         return np.int32(-(-reach // self.shape.chunk))
 
     def extend(self, rows):
@@ -948,7 +953,7 @@ class StackPrograms:
             n_new[b], slots[b], pos0[b] = len(new), slot, at
         self.cache, h_last, counters = self._extend(
             self.params, self.cache, ids, n_new, slots, pos0,
-            self._n_blocks(int((pos0 + sh.extend_len).max())))
+            self.n_blocks(int((pos0 + sh.extend_len).max())))
         return h_last, counters
 
     def block(self, rows):
@@ -970,5 +975,5 @@ class StackPrograms:
             denoise[b], n_unmask[b] = den, n
         self.cache, decided, counters = self._block(
             self.params, self.cache, ids, slots, pos0, denoise, n_unmask,
-            self._n_blocks(int(pos0.max()) + S))
+            self.n_blocks(int(pos0.max()) + S))
         return decided, counters

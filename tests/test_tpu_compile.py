@@ -133,6 +133,13 @@ def _expert_groups(T, dim, expert_dim, n, top_k):
     (_expert_groups, (512, 2048, 768, 128, 8)),
     (_expert_groups, (512, 4096, 768, 36, 10)),
     (_expert_groups, (512, 6144, 2048, 16, 12)),
+    # A.X-K1's (12 held of 2048 at hidden 7168, top-8: the widest expert the
+    # kernels are asked for, eight column chunks of 256), an extension
+    # batch's 4 x 4 tokens and a chunk's 512; its head over 20,480 rows
+    (_expert_stream, (16, 7168, 2048, 12)),
+    (_expert_groups, (512, 7168, 2048, 12, 8)),
+    (_topk_dot, (20_480, 7168, 1, 16, 1)),
+    (_topk_dot, (20_480, 7168, 4, 16, 1)),
 ], ids=["flash_ce-8192x128", "flash_ce-4096x64", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
         "embed_update-1M-8192x128", "topk_dot-16384x6144-B1",
@@ -140,7 +147,9 @@ def _expert_groups(T, dim, expert_dim, n, top_k):
         "topk_dot-9400000x64-B32", "topk_dot-9400000x64-B64",
         "expert_stream-32x2048-128x768", "expert_stream-64x6144-16x2048",
         "expert_groups-512x2048-128x768", "expert_groups-512x4096-36x768",
-        "expert_groups-512x6144-16x2048"])
+        "expert_groups-512x6144-16x2048", "expert_stream-16x7168-12x2048",
+        "expert_groups-512x7168-12x2048", "topk_dot-20480x7168-B1",
+        "topk_dot-20480x7168-B4"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
     text = _compiled_text(fn, shapes, one_chip)
@@ -328,6 +337,64 @@ def test_a_chunks_expert_layer_is_one_grouped_kernel_under_its_scope(
                           line).group(1))
     assert 32 << 20 < limit < 100 << 20, limit
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("T,kernel", [(16, "expert_stream"),
+                                      (512, "expert_groups")],
+                         ids=["axk-extend", "axk-chunk"])
+def test_an_expert_layer_that_picks_groups_first_is_one_kernel_under_its_scope(
+        one_chip, no_compile_cache, monkeypatch, T, kernel):
+    """``ops/moe.moe`` at A.X-K1's widths (hidden 7168, 12 of 192 experts of
+    2048 held, a sigmoid router that keeps 4 of 8 groups before its top-8, a
+    shared expert): the choice of groups adds no kernel and no loop, the one
+    expert kernel lies under ``<scope>.experts`` in eight column chunks of
+    256 and asks for under 100 MiB of VMEM, the shared expert's products
+    under ``<scope>.shared``."""
+    from predictionio_tpu.obs import jaxmon
+    from predictionio_tpu.ops import moe as moe_ops
+    from predictionio_tpu.ops.pallas import expert_stream
+
+    monkeypatch.setenv("PIO_PALLAS_INTERPRET", "0")
+    dim, expert_dim, n = 7168, 2048, 12
+    dims = moe_ops.MoEDims(
+        dim=dim, expert_dim=expert_dim, n_routed=192, n_zero=0, top_k=8,
+        scale=2.5, held=(0, n), norm_topk=True, shared_dim=expert_dim,
+        scoring="sigmoid", n_group=8, topk_group=4)
+    assert expert_stream.chunk_of(dim, expert_dim, 2) == 256
+    assert moe_ops.small_forward(T) == (kernel == "expert_stream")
+    bf16 = jnp.bfloat16
+
+    def struct(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"w_r": struct(dim, 192), "bias": struct(192, dtype=jnp.float32),
+         "w_g": struct(n, dim, expert_dim), "w_u": struct(n, dim, expert_dim),
+         "w_d": struct(n, expert_dim, dim),
+         "shared": {"w_g": struct(dim, expert_dim),
+                    "w_u": struct(dim, expert_dim),
+                    "w_d": struct(expert_dim, dim)}}
+
+    def layer(p, x, valid):
+        return moe_ops.moe(p, dims, x, valid, scope="seq.layer1.moe")
+
+    text = jax.jit(layer).lower(
+        p, struct(T, dim, dtype=jnp.float32),
+        struct(T, dtype=jnp.bool_)).compile().as_text()
+    kernels = _kernel_instructions(text)
+    assert len(kernels) == 1 and kernel in kernels[0], kernels
+    scopes = jaxmon.scope_map_of(text)
+    assert scopes[kernels[0]] == "seq.layer1.moe.experts"
+    assert "seq.layer1.moe.shared" in set(scopes.values())
+    assert not re.search(r"\bwhile\(", text)
+    assert not re.search(
+        rf"= bf16\[{n},\d+,\d+\]\S* (copy|transpose)\(", text)
+    line = next(ln for ln in text.splitlines()
+                if f"%{kernels[0]} = " in ln)
+    # the kernel's own scope (beside the shared expert's products XLA puts
+    # it at an offset; the two together stay under the chip's 128 MiB)
+    limit = int(re.search(r'"memory_space":"1","offset":"\d+","size":"(\d+)"',
+                          line).group(1))
+    assert 16 << 20 < limit < 100 << 20, limit
 
 
 def test_the_kv_cache_is_left_as_it_is_handed_over(one_chip,
