@@ -12,6 +12,7 @@ attention for histories past one device's HBM).
 
 from __future__ import annotations
 
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
@@ -22,12 +23,12 @@ from predictionio_tpu.core import Algorithm, SanityCheck
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.obs import trace
-from predictionio_tpu.ops import moe as moe_ops
 from predictionio_tpu.ops.sessionrec import (
     SessionRecConfig,
     SessionRecModelState,
     SessionRecTrainer,
     SessionScorer,
+    StackPrograms,
 )
 from predictionio_tpu.parallel.mesh import MeshContext
 
@@ -409,26 +410,14 @@ class SeqStackModel:
         #: .ThreadAccount; the engine server's step worker keeps one)
         self._account = None
         self.steps = 0
-        # what the steps did, summed since deploy. Per program kind
-        # ("extend" / "prefill" / "block"): runs, real tokens, (token, pick)
-        # pairs that reached a held expert, held experts that got any token
-        # (per layer, summed), zero-compute picks; the cached positions the
-        # rows' attention read (extensions: latents, or keys and values;
-        # blocks: keys and values) and the rows whose recurrent states an
-        # extension read and wrote, each where the stack has such a mixer;
-        # runs whose expert layers took the small forward's form (all of a
-        # kind's or none: the program's shape decides,
-        # ``ops/moe.small_forward``) and, for the others, the products of
-        # sorted rows their grouped kernels ran (over ``experts_touched``:
-        # how many products shared one read of an expert); where the
-        # router picks groups before experts, the (token, expert layer)
-        # pairs that kept a group with experts held here
+        # what the steps did, summed since deploy, as the HOST knows it:
+        # the cached positions the rows' attention read (extensions:
+        # latents, or keys and values; blocks: keys and values) and the
+        # rows whose recurrent states an extension read and wrote, each
+        # where the stack has such a mixer. What the programs counted
+        # (runs, tokens, where the picks went) stays on the device until
+        # ``stats()`` is read: ``StackPrograms.totals``
         self.counters = {
-            f"{kind}_{what}": 0 for kind in ("extend", "prefill", "block")
-            for what in ("runs", "tokens", "held_picks", "experts_touched",
-                         "zero_picks", "dense_expert_runs",
-                         "expert_row_tiles", "group_hit_tokens")}
-        self.counters.update({
             "extend_rows": 0, "extend_latent_positions": 0,
             # blocks of cached latents a layer's attention walked for the
             # extended rows (every row of a batch as far as its longest),
@@ -447,9 +436,20 @@ class SeqStackModel:
             # commit row), positions the rule unmasked, queries answered
             "denoise_rows": 0, "commit_rows": 0, "positions_unmasked": 0,
             "slates_done": 0, "block_kv_positions": 0,
-            # per program run and layer: the fullest held expert's tokens,
-            # and the held experts' mean, summed
-            "load_max_sum": 0.0, "load_mean_sum": 0.0})
+            # device-to-host fetches of the programs' totals: reads of
+            # ``stats()`` and drains
+            "count_fetches": 0}
+        #: what the programs counted (``StackPrograms.TOTAL_KINDS`` x
+        #: ``TOTAL_FIELDS``): ``_drained`` of it in Python ints, the rest in
+        #: ``_live``, the device's totals as the last counted program left
+        #: them (None before the first), over ``_undrained`` runs. They and
+        #: ``counters`` change together under the lock, so a reader on
+        #: another thread sees all of them after the same runs
+        self._drained = np.zeros((len(StackPrograms.TOTAL_KINDS),
+                                  len(StackPrograms.TOTAL_FIELDS)), object)
+        self._live = None
+        self._undrained = 0
+        self._lock = threading.Lock()
 
     def __getstate__(self):
         raise TypeError(
@@ -461,7 +461,6 @@ class SeqStackModel:
     def programs(self):
         if self._programs is None:
             from predictionio_tpu.index.exact import ExactIndex
-            from predictionio_tpu.ops.sessionrec import StackPrograms
 
             self._programs = StackPrograms(self.spec, self.params, self.shape)
             self._inverse = self.item_ids.inverse()
@@ -480,7 +479,33 @@ class SeqStackModel:
         the host's CPU clock is too dear to read), always every name of
         ``STEP_PHASES`` (two snapshots subtract key by key) and what other
         spans that thread opened under ``other``; they add up to the
-        thread's time."""
+        thread's time.
+
+        ``extend_*`` / ``prefill_*`` / ``block_*`` of ``StackPrograms
+        .TOTAL_FIELDS`` are what that kind's programs counted on the device
+        (with ``load_mean_sum``, the held experts' mean load a run and
+        layer, summed as ``load_max_sum`` is): reading them is ONE fetch
+        (``count_fetches``), from any thread, of what the last counted
+        program left, so they and the host's counters are after the same
+        runs."""
+        with self._lock:
+            live, totals = self._live, self._drained.copy()
+            if live is not None:
+                self.counters["count_fetches"] += 1
+            counters = dict(self.counters)
+        if live is not None:
+            totals += np.asarray(live).tolist()
+        counted = {kind: dict(zip(StackPrograms.TOTAL_FIELDS, row))
+                   for kind, row in zip(StackPrograms.TOTAL_KINDS,
+                                        totals.tolist())}
+        picks = sum(got["held_picks"] for got in counted.values())
+        counters.update(
+            load_max_sum=float(sum(got.pop("load_max_sum")
+                                   for got in counted.values())),
+            load_mean_sum=(picks / self.spec.moe.held[1] if picks else 0.0))
+        counters.update((f"{kind}_{field}", n)
+                        for kind, got in counted.items()
+                        for field, n in got.items())
         c = self.cache
         phases = {name: [0, 0, 0] for name in STEP_PHASES + ("other",)}
         if self._account is not None:
@@ -491,7 +516,7 @@ class SeqStackModel:
             if self._account.cpu_clock is None:     # not read: not measured
                 for got in phases.values():
                     got[2] = None
-        return {**self.counters, "steps": self.steps,
+        return {**counters, "steps": self.steps,
                 **{f"phase_{name}_{what}": v
                    for name, got in phases.items()
                    for what, v in zip(("n", "wall_ns", "cpu_ns"), got)},
@@ -581,25 +606,24 @@ class SeqStackModel:
                 self._launching(ext, "extend")
                 with trace.device_span("seq.extend", rows=len(ext)):
                     with trace.device_span("seq.launch", program="extend"):
-                        h, counted = programs.extend(
+                        h, _ = programs.extend(
                             [(t.rows[t.done:], t.slot, t.done) for t in ext])
                     with trace.device_span("seq.wait", program="extend"):
                         h.block_until_ready()
-                self._count("extend", counted)
-                self.counters["extend_rows"] += len(ext)
                 reach = sum(len(t.rows) for t in ext)
-                for kind, counter, n in (
-                        ("mla", "extend_latent_positions", reach),
-                        ("gqa", "extend_kv_positions", reach),
-                        ("mamba2", "extend_state_rows", len(ext))):
-                    if kind in self.kinds:
-                        self.counters[counter] += n
+                did = {"extend_rows": len(ext)}
+                did.update((counter, n) for kind, counter, n in (
+                    ("mla", "extend_latent_positions", reach),
+                    ("gqa", "extend_kv_positions", reach),
+                    ("mamba2", "extend_state_rows", len(ext)))
+                    if kind in self.kinds)
                 if "mla" in self.kinds:
                     own = [int(programs.n_blocks(t.done + sh.extend_len))
                            for t in ext]
-                    self.counters["extend_latent_blocks_own"] += sum(own)
-                    self.counters["extend_latent_blocks_attended"] += (
-                        max(own) * len(ext))
+                    did.update(
+                        extend_latent_blocks_own=sum(own),
+                        extend_latent_blocks_attended=max(own) * len(ext))
+                self._count("extend", did)
                 for t in ext:
                     t.done = len(t.rows)
                 self._answer(ext, h, done)
@@ -612,12 +636,12 @@ class SeqStackModel:
                 with trace.device_span("seq.prefill_chunk", slot=pre.slot,
                                        offset=pre.done, tokens=n):
                     with trace.device_span("seq.launch", program="prefill"):
-                        h, counted = programs.prefill(
+                        h, _ = programs.prefill(
                             pre.rows[pre.done:pre.done + n], pre.slot,
                             pre.done)
                     with trace.device_span("seq.wait", program="prefill"):
                         h.block_until_ready()
-                self._count("prefill", counted)
+                self._count("prefill")
                 pre.done += n
                 if pre.remaining == 0 and not self.gen:
                     self._answer([pre], h, done)
@@ -670,18 +694,17 @@ class SeqStackModel:
                                denoise_rows=n_denoise,
                                commit_rows=len(rows) - n_denoise):
             with trace.device_span("seq.launch", program="block"):
-                launched = self._programs.block(
+                launched, _ = self._programs.block(
                     [(ids, t.slot, at, kind == "denoise", n)
                      for t, kind, ids, at, n in rows])
             with trace.device_span("seq.wait", program="block"):
-                decided, counted = jax.device_get(launched)
-        self._count("block", counted)
+                decided = jax.device_get(launched)
+        self._count("block", {
+            "denoise_rows": n_denoise, "commit_rows": len(rows) - n_denoise,
+            "block_kv_positions": sum(at + B for _, _, _, at, _ in rows)})
         finished = []
         with trace.device_span("seq.decide", rows=len(rows)):
             c = self.counters
-            c["denoise_rows"] += n_denoise
-            c["commit_rows"] += len(rows) - n_denoise
-            c["block_kv_positions"] += sum(at + B for _, _, _, at, _ in rows)
             for b, (t, kind, _, at, _) in enumerate(rows):
                 if kind == "known":
                     t.done = at + B
@@ -711,30 +734,26 @@ class SeqStackModel:
         self.cache.release(t.slot, t.held())
         done(t)
 
-    def _count(self, kind: str, counted) -> None:
-        """What the program's expert layers counted (device arrays, each
-        fetched here, but a block program's: they came with its
-        decision)."""
+    def _count(self, kind: str, did: Optional[Dict[str, int]] = None
+               ) -> None:
+        """A program of ``kind`` has run: what the host knows it ``did``
+        joins ``counters``, and the device's totals as that program left
+        them are kept, unfetched. Nothing here waits for the device but the
+        drain, every ``StackPrograms.drain_every`` runs: the totals go into
+        Python ints and start again from zero, exact over any run length."""
         with trace.device_span("seq.count", program=kind):
-            c = self.counters
-            c[f"{kind}_runs"] += 1
-            c[f"{kind}_tokens"] += int(counted["tokens"])
-            if "expert_load" in counted:
-                load = np.asarray(counted["expert_load"], np.int64)
-                c[f"{kind}_held_picks"] += int(load.sum())
-                c[f"{kind}_experts_touched"] += int((load > 0).sum())
-                small = moe_ops.small_forward(self._programs.tokens[kind])
-                c[f"{kind}_dense_expert_runs"] += small
-                if not small:
-                    c[f"{kind}_expert_row_tiles"] += int(
-                        moe_ops.row_tiles(load).sum())
-                c[f"{kind}_zero_picks"] += int(
-                    np.asarray(counted["zero_picks"]).sum())
-                c["load_max_sum"] += float(load.max(axis=1).sum())
-                c["load_mean_sum"] += float(load.mean(axis=1).sum())
-            if "group_hits" in counted:
-                c[f"{kind}_group_hit_tokens"] += int(
-                    np.asarray(counted["group_hits"]).sum())
+            programs, drained = self._programs, None
+            self._undrained += 1
+            if self._undrained >= programs.drain_every:
+                drained = np.asarray(programs.take_totals()).tolist()
+                self._undrained = 0
+            with self._lock:
+                for counter, n in (did or {}).items():
+                    self.counters[counter] += n
+                if drained is not None:
+                    self._drained += drained
+                    self.counters["count_fetches"] += 1
+                self._live = programs.totals
 
     def _answer(self, tickets: List[SeqTicket], h_last, done) -> None:
         """``h_last``: a program's whole output (its rows beyond the
@@ -815,11 +834,12 @@ class SeqStackAlgorithm(Algorithm):
             programs.prefill(np.zeros(B, np.int32), sh.n_slots, 0)
             jax.block_until_ready(programs.block(
                 [(np.zeros(B, np.int32), sh.n_slots, 0, True, 1)]))
-            return
-        h, _ = programs.prefill(np.zeros(1, np.int32), sh.n_slots, 0)
-        hs, _ = programs.extend([(np.zeros(1, np.int32), sh.n_slots, 1)])
-        for h_last in (h, hs):
-            model._index.search(h_last, 10)
+        else:
+            h, _ = programs.prefill(np.zeros(1, np.int32), sh.n_slots, 0)
+            hs, _ = programs.extend([(np.zeros(1, np.int32), sh.n_slots, 1)])
+            for h_last in (h, hs):
+                model._index.search(h_last, 10)
+        programs.take_totals()              # a warm-up run is not counted
 
     @classmethod
     def _prediction(cls, ticket: SeqTicket) -> Dict[str, Any]:

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -697,6 +698,18 @@ class ServeShape:
     gen_batch: int = 8           # blocks of one block forward, at most
 
 
+def _cut(args, shapes):
+    """Flat ``args`` as the arrays of ``shapes``, one after the other: views
+    of a numpy array (the host writes a call's arguments through them),
+    static slices of a traced one (the program reads them)."""
+    parts, at = [], 0
+    for shape in shapes:
+        n = math.prod(shape)
+        parts.append(args[at:at + n].reshape(shape))
+        at += n
+    return parts
+
+
 class StackPrograms:
     """The compiled serve path of a stack whose mixers each keep something
     per session: a cache PER MIXER, of that mixer's kind, in one list.
@@ -724,11 +737,39 @@ class StackPrograms:
     chunk of positions lets the last chunk of a full slot be written whole.
 
     The programs take the parameters as arguments (nothing is baked in),
-    donate the cache, and return ``(cache, result, counters)``: ``prefill``
-    and ``extend`` the final-normed hidden state of each session's last real
-    position, ``block`` what the rule decided; and what the expert layers
-    counted. Which slot holds which session is the caller's business
-    (``models/sessionrec.LatentCache``)."""
+    donate the cache, and return ``(cache, result, counters, totals)``:
+    ``prefill`` and ``extend`` the final-normed hidden state of each
+    session's last real position, ``block`` what the rule decided; and what
+    the expert layers counted. Which slot holds which session is the
+    caller's business (``models/sessionrec.LatentCache``).
+
+    A call crosses to the device ONCE: what the host knows of it (ids,
+    lengths, slots, positions) goes in as one int32 array, cut up again by
+    static slices inside the program (:func:`_cut`, the same on both
+    sides). And nothing of a call need come back but its result: what the
+    expert layers counted is also summed ON THE DEVICE, into ``totals``
+    (``[TOTAL_KINDS, TOTAL_FIELDS]`` int32: an argument in, the sum out,
+    never donated, so whoever keeps an older one keeps a live buffer
+    whatever runs meanwhile). The caller fetches ``totals`` when somebody
+    asks, and takes them (:meth:`take_totals`) before ``drain_every`` runs
+    could wrap an int32."""
+
+    #: the rows of ``totals``: a program's kind
+    TOTAL_KINDS = ("extend", "prefill", "block")
+    #: its columns, per kind: program runs, real tokens, (token, pick) pairs
+    #: that reached a held expert, held experts that got any token (per
+    #: layer, summed), zero-compute picks, runs whose expert layers took the
+    #: small forward's form (all of a kind's or none: the program's shape
+    #: decides, ``ops/moe.small_forward``) and, for the others, the products
+    #: of sorted rows their grouped kernels ran (over ``experts_touched``:
+    #: how many products shared one read of an expert), the fullest held
+    #: expert's tokens (per layer, summed), (token, expert layer) pairs that
+    #: kept a group with experts held here. A stack without expert layers,
+    #: or whose router picks no groups, leaves those columns at 0: the keys
+    #: of a call's ``counters`` decide, when the program is traced
+    TOTAL_FIELDS = ("runs", "tokens", "held_picks", "experts_touched",
+                    "zero_picks", "dense_expert_runs", "expert_row_tiles",
+                    "load_max_sum", "group_hit_tokens")
 
     def __init__(self, spec: StackSpec, params: Dict, shape: ServeShape):
         #: the mixers' kinds, in the order of their caches
@@ -760,36 +801,43 @@ class StackPrograms:
                                    if kind == "mla"
                                    else spec.gqa.cache_width,), dtype)
             for kind in self.kinds]
-        i32 = jax.ShapeDtypeStruct((), jnp.int32)
         #: tokens of a call of each program that this stack compiles: the
         #: shape by which ``ops/moe.moe`` chooses its form
         self.tokens = {"prefill": shape.chunk}
-
-        def struct(*dims, dtype=jnp.int32):
-            return jax.ShapeDtypeStruct(dims, dtype)
-
-        self._prefill = jax.jit(self._prefill_fn, donate_argnums=1).lower(
-            params, self.cache, struct(shape.chunk), i32, i32, i32).compile()
-        compiled = [self._prefill]
+        #: the shapes of what the host knows of a call, in the order the
+        #: program's function takes them: the layout of its one array
+        self._shapes = {"prefill": ((shape.chunk,), (), (), ())}
+        self._zeros = self.totals = jnp.zeros(
+            (len(self.TOTAL_KINDS), len(self.TOTAL_FIELDS)), jnp.int32)
+        fns = {"prefill": self._prefill_fn}
         if gen is None:
             B, S = shape.extend_batch, shape.extend_len
             self.tokens["extend"] = B * S
-            self._extend = jax.jit(self._extend_fn, donate_argnums=1).lower(
-                params, self.cache, struct(B, S), struct(B), struct(B),
-                struct(B), i32).compile()
-            compiled.append(self._extend)
+            self._shapes["extend"] = ((B, S), (B,), (B,), (B,), ())
+            fns["extend"] = self._extend_fn
         else:
             if shape.chunk % gen.block_len or shape.capacity % gen.block_len:
                 raise ValueError("chunk and capacity must be multiples of "
                                  "the block length")
             B, S = shape.gen_batch, gen.block_len
             self.tokens["block"] = B * S
-            self._block = jax.jit(self._block_fn, donate_argnums=1).lower(
-                params, self.cache, struct(B, S), struct(B), struct(B),
-                struct(B, dtype=jnp.bool_), struct(B), i32).compile()
-            compiled.append(self._block)
-        for program in compiled:
+            self._shapes["block"] = ((B, S), (B,), (B,), (B,), (B,), ())
+            fns["block"] = self._block_fn
+        self._compiled = {
+            kind: jax.jit(self._packed(kind, fn), donate_argnums=1).lower(
+                params, self.cache, self.totals,
+                self._args(kind)[0]).compile()
+            for kind, fn in fns.items()}
+        for program in self._compiled.values():
             jaxmon.record_scope_map(program)
+        # in one run no column grows by more than every token's every pick
+        # and every held expert, in every expert layer (fewer than the
+        # mixers): so many runs an int32 holds
+        moe = spec.moe
+        #: runs after which the caller takes the totals, at the latest
+        self.drain_every = (2 ** 31 - 1) // (len(self.kinds) * (
+            max(self.tokens.values()) * (moe.top_k if moe else 1)
+            + (moe.held[1] if moe else 0)))
 
     # -- the programs ---------------------------------------------------------
     def _run(self, params, x, valid, mix_with):
@@ -834,6 +882,36 @@ class StackPrograms:
     def _embed(self, params, ids):
         return (params["item_embed"]["embedding"][ids].astype(jnp.float32)
                 * self.spec.embed_scale)
+
+    def _packed(self, kind, fn):
+        """Program ``kind`` as it is compiled: ``fn`` with what the host
+        knows of a call cut from its one array, and the call's counters
+        added to row ``kind`` of ``totals``, in ``TOTAL_FIELDS``."""
+        shapes = self._shapes[kind]
+
+        def program(params, cache, totals, args):
+            cache, result, counters = fn(params, cache, *_cut(args, shapes))
+            add = {"runs": 1, "tokens": counters["tokens"]}
+            if "expert_load" in counters:
+                load = counters["expert_load"]          # [layers, held]
+                add.update(held_picks=load.sum(),
+                           experts_touched=(load > 0).sum(),
+                           zero_picks=counters["zero_picks"].sum(),
+                           load_max_sum=load.max(axis=1).sum())
+                if moe_ops.small_forward(self.tokens[kind]):
+                    add["dense_expert_runs"] = 1
+                else:
+                    add["expert_row_tiles"] = moe_ops.row_tiles(load).sum()
+            if "group_hits" in counters:
+                add["group_hit_tokens"] = counters["group_hits"].sum()
+            row = jnp.stack([jnp.asarray(add.get(f, 0), jnp.int32)
+                             for f in self.TOTAL_FIELDS])
+            return (cache, result, counters,
+                    totals.at[self.TOTAL_KINDS.index(kind)].add(row))
+
+        # the compiled module's name, by which a trace's readers know it
+        program.__name__ = fn.__name__
+        return program
 
     def _prefill_fn(self, params, cache, ids, n_valid, slot, offset):
         cache = list(cache)
@@ -917,21 +995,39 @@ class StackPrograms:
             conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1))
             best, top, conf = (a.reshape(B, S) for a in (best, top, conf))
         picked = unmask_by_rule(
-            gen, (ids == gen.mask_row) & denoise[:, None], conf, n_unmask)
+            gen, (ids == gen.mask_row) & (denoise != 0)[:, None], conf,
+            n_unmask)
         return cache, {"ids": jnp.where(picked, best, ids), "picked": picked,
                        "score": top, "confidence": conf}, counters
 
     # -- calls ----------------------------------------------------------------
+    def _args(self, kind: str):
+        """A call's one array, zeroed, and its parts to fill in."""
+        shapes = self._shapes[kind]
+        args = np.zeros(sum(map(math.prod, shapes)), np.int32)
+        return args, _cut(args, shapes)
+
+    def _call(self, kind: str, args: np.ndarray):
+        """One crossing to the device: the compiled call with the host's one
+        array. The result and the call's own counters, still there."""
+        self.cache, result, counters, self.totals = self._compiled[kind](
+            self.params, self.cache, self.totals, args)
+        return result, counters
+
+    def take_totals(self):
+        """What the programs counted since this was last called (the array,
+        still on the device); they count on from zero."""
+        taken, self.totals = self.totals, self._zeros
+        return taken
+
     def prefill(self, ids: np.ndarray, slot: int, offset: int):
         """One chunk (``len(ids) <= chunk`` real positions) of the session in
         ``slot``, from position ``offset`` on. ``(h_last [1, dim],
         counters)``, still on the device."""
-        padded = np.zeros(self.shape.chunk, np.int32)
+        args, (padded, n_valid, at_slot, at) = self._args("prefill")
         padded[:len(ids)] = ids
-        self.cache, h_last, counters = self._prefill(
-            self.params, self.cache, padded, np.int32(len(ids)),
-            np.int32(slot), np.int32(offset))
-        return h_last, counters
+        n_valid[()], at_slot[()], at[()] = len(ids), slot, offset
+        return self._call("prefill", args)
 
     def n_blocks(self, reach: int) -> np.int32:
         """The cached blocks attention walks to reach position ``reach``."""
@@ -944,17 +1040,13 @@ class StackPrograms:
         stands.
         ``(h_last [extend_batch, dim], counters)``, still on the device."""
         sh = self.shape
-        ids = np.zeros((sh.extend_batch, sh.extend_len), np.int32)
-        n_new = np.zeros(sh.extend_batch, np.int32)
-        slots = np.full(sh.extend_batch, sh.n_slots, np.int32)   # scratch
-        pos0 = np.zeros(sh.extend_batch, np.int32)
+        args, (ids, n_new, slots, pos0, n_blocks) = self._args("extend")
+        slots[:] = sh.n_slots                                    # scratch
         for b, (new, slot, at) in enumerate(rows):
             ids[b, :len(new)] = new
             n_new[b], slots[b], pos0[b] = len(new), slot, at
-        self.cache, h_last, counters = self._extend(
-            self.params, self.cache, ids, n_new, slots, pos0,
-            self.n_blocks(int((pos0 + sh.extend_len).max())))
-        return h_last, counters
+        n_blocks[()] = self.n_blocks(int((pos0 + sh.extend_len).max()))
+        return self._call("extend", args)
 
     def block(self, rows):
         """``rows``: [(ids of one block, slot, position of ids[0], denoise,
@@ -965,15 +1057,11 @@ class StackPrograms:
         ``["confidence"]`` the best item's logit and probability at every
         position, each ``[gen_batch, block_len]``."""
         sh, S = self.shape, self.spec.generation.block_len
-        ids = np.zeros((sh.gen_batch, S), np.int32)
-        slots = np.full(sh.gen_batch, sh.n_slots, np.int32)      # scratch
-        pos0 = np.zeros(sh.gen_batch, np.int32)
-        denoise = np.zeros(sh.gen_batch, bool)
-        n_unmask = np.zeros(sh.gen_batch, np.int32)
+        args, (ids, slots, pos0, denoise, n_unmask, n_blocks) = self._args(
+            "block")
+        slots[:] = sh.n_slots                                    # scratch
         for b, (block, slot, at, den, n) in enumerate(rows):
             ids[b], slots[b], pos0[b] = block, slot, at
             denoise[b], n_unmask[b] = den, n
-        self.cache, decided, counters = self._block(
-            self.params, self.cache, ids, slots, pos0, denoise, n_unmask,
-            self.n_blocks(int(pos0.max()) + S))
-        return decided, counters
+        n_blocks[()] = self.n_blocks(int(pos0.max()) + S)
+        return self._call("block", args)

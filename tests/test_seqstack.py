@@ -463,6 +463,19 @@ def test_the_model_counts_the_runs_that_took_the_small_forwards_form(chunk):
         0 if chunk <= moe_ops.TILE else stats["prefill_experts_touched"])
 
 
+def recording(model, monkeypatch):
+    """Every program call's own counters, as the programs return them:
+    ``[(kind, counters)]``, filled in as the model runs."""
+    calls, programs = [], model.programs()
+    for kind in programs.tokens:
+        def call(*args, _kind=kind, _call=getattr(programs, kind)):
+            result, counted = _call(*args)
+            calls.append((_kind, counted))
+            return result, counted
+        monkeypatch.setattr(programs, kind, call)
+    return calls
+
+
 def test_the_model_counts_the_products_of_sorted_rows(monkeypatch):
     """``<kind>_expert_row_tiles`` against the loads that ran: with 8 rows a
     product (the programs are traced with it) a chunk of 128 positions runs
@@ -476,16 +489,13 @@ def test_the_model_counts_the_products_of_sorted_rows(monkeypatch):
         spec, seeded_params(spec),
         BiMap.from_vocab([f"i{r}" for r in range(N_ITEMS)]),
         dataclasses.replace(SHAPE, capacity=256, chunk=128))
-    loads = []
-    count = model._count
-    monkeypatch.setattr(model, "_count", lambda kind, counted: (
-        loads.append((kind, np.asarray(counted["expert_load"]))),
-        count(kind, counted))[1])
+    calls = recording(model, monkeypatch)
     rows = np.random.default_rng(8).integers(0, N_ITEMS, size=203).tolist()
     for upto in (200, 203):
         model.answer({"items": [f"i{r}" for r in rows[:upto]], "num": 5})
     stats = model.stats()
-    chunks = [load for kind, load in loads if kind == "prefill"]
+    chunks = [np.asarray(counted["expert_load"]) for kind, counted in calls
+              if kind == "prefill"]
     assert len(chunks) == stats["prefill_runs"] == 2
     want = sum(int((-(-load // 8)).sum()) for load in chunks)
     assert stats["prefill_expert_row_tiles"] == want
@@ -683,7 +693,7 @@ def test_an_extension_is_served_within_one_step_of_a_long_prefill():
         rng = np.random.default_rng(8)
         short = rng.integers(0, N_ITEMS, size=10).tolist()
         post(server, short)                           # its slot is warm
-        chunks0 = model.counters["prefill_runs"]
+        chunks0 = model.stats()["prefill_runs"]
         long = rng.integers(0, N_ITEMS, size=80).tolist()   # 5 chunks of 16
         t_long = model.begin({"items": [f"i{r}" for r in long], "num": 5})
         model.step([t_long])                          # chunk 1 of 5
@@ -696,7 +706,7 @@ def test_an_extension_is_served_within_one_step_of_a_long_prefill():
         step_ended_ns = time.perf_counter_ns()
         assert done == [t_ext] and len(t_ext.result) == 5
         assert t_long.done == 32 and t_long.result is None
-        assert model.counters["prefill_runs"] - chunks0 == 2
+        assert model.stats()["prefill_runs"] - chunks0 == 2
         # it waited for no step: its program was launched inside the first
         # step after its admission, and the counters say how long after
         assert model.counters["extend_tickets"] - queued0["extend_tickets"] == 1
@@ -705,7 +715,7 @@ def test_an_extension_is_served_within_one_step_of_a_long_prefill():
         assert 0 <= waited < step_ended_ns - t_ext.admitted_ns
         while t_long.result is None:
             model.step([t_long])
-        assert model.counters["prefill_runs"] - chunks0 == 5
+        assert model.stats()["prefill_runs"] - chunks0 == 5
     finally:
         server.stop()
 
