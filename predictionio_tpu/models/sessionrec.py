@@ -424,6 +424,10 @@ class SeqStackModel:
             # and what each row's own reach would have taken
             "extend_latent_blocks_attended": 0,
             "extend_latent_blocks_own": 0,
+            # under a learned index an extension walks no latent block: the
+            # latents its rows' attention GATHERED, a layer (each new
+            # position its ``min(index_topk, reach)`` selected ones)
+            "extend_latents_gathered": 0,
             "extend_kv_positions": 0, "extend_state_rows": 0,
             # from a ticket's admission to the launch of the first program
             # that carries rows of it, summed, and the tickets summed over:
@@ -617,7 +621,12 @@ class SeqStackModel:
                     ("gqa", "extend_kv_positions", reach),
                     ("mamba2", "extend_state_rows", len(ext)))
                     if kind in self.kinds)
-                if "mla" in self.kinds:
+                if programs.indexed:
+                    topk = self.spec.mla.index_topk
+                    did["extend_latents_gathered"] = sum(
+                        min(at + 1, topk) for t in ext
+                        for at in range(t.done, len(t.rows)))
+                elif "mla" in self.kinds:
                     own = [int(programs.n_blocks(t.done + sh.extend_len))
                            for t in ext]
                     did.update(

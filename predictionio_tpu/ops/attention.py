@@ -46,6 +46,7 @@ _NEG = -0.7 * jnp.finfo(jnp.float32).max
 def mha_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     scale: Optional[float] = None,   # None: q's head width ** -0.5
+    keep: Optional[jax.Array] = None,  # [B, Lq, Lk]: each row's own keys
 ) -> jax.Array:
     """Materialized-softmax attention, the correctness oracle for the
     blockwise/ring paths (and fine for short sequences)."""
@@ -58,6 +59,8 @@ def mha_reference(
         q_pos = jnp.arange(L_q) + (L_k - L_q)
         mask = q_pos[:, None] >= jnp.arange(L_k)[None, :]
         s = jnp.where(mask[None, None], s, _NEG)
+    if keep is not None:
+        s = jnp.where(keep[:, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
 
@@ -73,6 +76,8 @@ def _accum_block(
     k_pos: jax.Array,    # [Lk] global positions
     causal: bool,
     scale: Optional[float] = None,   # None: q's head width ** -0.5
+    keep: Optional[jax.Array] = None,  # [Lq, Lk] or [B, Lq, Lk]: each
+                                       # row's own set of key positions
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One online-softmax update: fold the (q, k/v-block) partial into
     the (m, l, o) accumulators. The rescaling trick is the standard
@@ -87,6 +92,8 @@ def _accum_block(
         # ``q_pos``/``k_pos`` are [L] (one set of positions for the batch)
         # or [B, L] (each row its own: sessions of different lengths)
         mask = q_pos[..., :, None] >= k_pos[..., None, :]
+        if keep is not None:
+            mask = mask & keep
         s = jnp.where(mask[None, None] if mask.ndim == 2 else mask[:, None],
                       s, _NEG)
     m_new = jnp.maximum(m, s.max(axis=-1))                   # [B, H, Lq]
@@ -149,14 +156,22 @@ def blockwise_attention(
 
 def attend_over_blocks(q, q_pos, kv_block, n_blocks, block_size: int,
                        v_dim: int, dtype=None,
-                       scale: Optional[float] = None) -> jax.Array:
+                       scale: Optional[float] = None,
+                       keep_block=None) -> jax.Array:
     """Causal attention of ``q`` [B, Lq, H, Dk] (positions ``q_pos``, [Lq]
     or [B, Lq]) over keys and values that ``kv_block(j)`` produces one block
     at a time — ``(k [B, block, H, Dk], v [B, block, H, Dv])`` for the key
     positions ``j * block_size + arange(block_size)`` — so the caller can
     read them from a cache, or expand them from latents, only as far as the
     history reaches. ``n_blocks`` may be traced: the loop runs that many
-    times in ONE compiled program for every history length."""
+    times in ONE compiled program for every history length.
+
+    ``keep_block(j)``, where given, is each query row's OWN set of key
+    positions inside block ``j`` (bool ``[Lq, block]`` or ``[B, Lq,
+    block]``: a learned index's selection), on top of the causal mask. A row
+    that keeps nothing of its first blocks carries a running maximum of
+    ``_NEG`` through them, and the first kept key's ``alpha`` (``exp(_NEG -
+    m)``, exactly 0) wipes what they added: every row must keep some key."""
     B, Lq, H, _ = q.shape
     carry = (jnp.full((B, H, Lq), _NEG, jnp.float32),
              jnp.zeros((B, H, Lq), jnp.float32),
@@ -165,7 +180,8 @@ def attend_over_blocks(q, q_pos, kv_block, n_blocks, block_size: int,
     def body(j, carry):
         k, v = kv_block(j)
         k_pos = j * block_size + jnp.arange(block_size)
-        return _accum_block(q, k, v, *carry, q_pos, k_pos, True, scale)
+        keep = None if keep_block is None else keep_block(j)
+        return _accum_block(q, k, v, *carry, q_pos, k_pos, True, scale, keep)
 
     m, l, o = jax.lax.fori_loop(0, n_blocks, body, carry)
     return _finish(m, l, o, dtype or q.dtype)

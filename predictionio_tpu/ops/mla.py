@@ -31,6 +31,28 @@ makes ``beta_slow`` (fast pairs keep their frequency, slow ones are
 interpolated); cos and sin are multiplied by ``m(mscale) / m(mscale_all_dim)``
 and the softmax scale by ``m(mscale_all_dim)^2``, ``m(s) = 0.1 s ln(factor)
 + 1``. One scale (:attr:`MLADims.softmax_scale`) reaches all three paths.
+
+**A learned index** (``MLADims.index_heads`` > 0; DeepSeek-V3.2's "lightning
+indexer"): beside its latent every position keeps an index key ``kI =
+LayerNorm(x W_kI)`` (``index_dim`` values, a SECOND cached array), and a query
+row scores every earlier position with ``I[t, s] = sum_j w[t, j] ReLU(qI[t, j]
+. kI[s])`` (``qI = cQ W_qI`` as ``index_heads`` heads, ``w = x W_w`` times
+``index_heads^-0.5 index_dim^-0.5``; the first ``d_rope`` values of every
+``qI`` head and of ``kI`` under the same RoPE) and attends ONLY the
+``min(index_topk, t + 1)`` positions of largest ``I[t, .]`` (ties: the earlier
+position, ``jax.lax.top_k``'s rule). The cache of such a mixer is ``{"latent",
+"index_k"}``; :func:`prefill_chunk_indexed` and :func:`extend_indexed` are the
+two cached paths and :func:`attend_full` the plain form of both. A chunk
+whose reach is at most ``index_topk`` attends all of it and scores nothing
+(it still writes its index keys); past that it scores the slot's cached index
+keys block by block, finds each row's exact set (:func:`topk_mask`: the k-th
+largest score by bisection over the scores' bits, no sort) and walks the
+blocks under that per-row mask: exact, and no saving over attending all
+(512 rows' sets together cover every block; gathering 512 x 2,048 latents
+instead measured 44.5 ms a layer against 3.0-19.5 at offsets of 2,048-32,256:
+PERF.md section 5). An extension gathers: it reads a position's index key
+(256 B at 128 bfloat16 values) for every cached position and a latent only
+for its rows' selected ones.
 """
 
 from __future__ import annotations
@@ -41,7 +63,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from predictionio_tpu.ops.attention import attend_over_blocks, mha_reference
+from predictionio_tpu.ops.attention import (_NEG, attend_over_blocks,
+                                            mha_reference)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +86,24 @@ class MLADims:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    #: the learned index that picks the cached positions a row attends
+    #: (0 heads: none, and every path is the one it was): heads and width of
+    #: its queries and keys, positions a row keeps, its LayerNorm's epsilon
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    index_eps: float = 1e-6
+
+    def __post_init__(self):
+        if self.index_heads and not (
+                self.index_topk > 0 and self.d_rope <= self.index_dim):
+            raise ValueError(
+                "an index needs index_topk > 0 and index_dim >= d_rope "
+                "(its first d_rope values are under RoPE)")
+
+    @property
+    def has_index(self) -> bool:
+        return self.index_heads > 0
 
     @property
     def latent(self) -> int:
@@ -122,6 +163,16 @@ def init(key, dims: MLADims, dtype=jnp.float32) -> dict:
                                 jax.random.split(key, len(shapes)))}
     out["q_norm"] = jnp.ones((d.q_rank,), dtype)
     out["kv_norm"] = jnp.ones((d.kv_rank,), dtype)
+    if d.has_index:
+        shapes = {"w_qi": (d.q_rank, d.index_heads * d.index_dim),
+                  "w_ki": (d.dim, d.index_dim), "w_w": (d.dim, d.index_heads)}
+        out.update({n: (jax.random.normal(k, s, jnp.float32)
+                        / math.sqrt(s[0])).astype(dtype)
+                    for (n, s), k in zip(
+                        shapes.items(),
+                        jax.random.split(jax.random.fold_in(key, 1), 3))})
+        out["ki_norm"] = {"scale": jnp.ones((d.index_dim,), dtype),
+                          "bias": jnp.zeros((d.index_dim,), dtype)}
     return out
 
 
@@ -151,14 +202,23 @@ def rope(x, pos, freqs, amplitude: float = 1.0):
                      axis=-1).reshape(x.shape)
 
 
-def project(p, dims: MLADims, x, pos):
+def compress_q(p, dims: MLADims, x):
+    """The queries' latent ``cQ`` [..., T, q_rank] (float32), from which the
+    heads' queries and an index's are expanded."""
+    cq = rms_norm(mm(x, p["w_dq"]), p["q_norm"], dims.eps)
+    if dims.scale_q:
+        cq = cq * math.sqrt(dims.dim / dims.q_rank)
+    return cq
+
+
+def project(p, dims: MLADims, x, pos, cq=None):
     """Queries and latents of the positions ``x`` [..., T, dim]:
     ``(qN [..., T, H, d_nope], qR [..., T, H, d_rope] after RoPE,
-    latent [..., T, kv_rank + d_rope])``, float32."""
+    latent [..., T, kv_rank + d_rope])``, float32. ``cq``: the queries'
+    latent where the caller has it (:func:`compress_q`)."""
     d = dims
-    cq = rms_norm(mm(x, p["w_dq"]), p["q_norm"], d.eps)
-    if d.scale_q:
-        cq = cq * math.sqrt(d.dim / d.q_rank)
+    if cq is None:
+        cq = compress_q(p, d, x)
     q = mm(cq, p["w_uq"]).reshape(x.shape[:-1] + (d.heads, d.d_qk))
     freqs, amp = d.rope_freqs(), d.rope_amplitude
     qn, qr = q[..., :d.d_nope], rope(q[..., d.d_nope:], pos, freqs, amp)
@@ -207,11 +267,16 @@ def _out(p, dims: MLADims, o):
 def attend_full(p, dims: MLADims, x, pos):
     """Every position of ``x`` [B, T, dim] against every earlier one, keys
     and values expanded in full, scores materialised: the plain form."""
-    qn, qr, latent = project(p, dims, x, pos)
+    cq = compress_q(p, dims, x)
+    qn, qr, latent = project(p, dims, x, pos, cq)
     k, v = expand(p, dims, latent)
     q = jnp.concatenate([qn, qr], axis=-1)
+    keep = None
+    if dims.has_index:
+        qi, ki, w = project_index(p, dims, x, cq, pos)
+        keep = selected_full(dims, index_scores(qi, w, ki), pos)
     return _out(p, dims, mha_reference(q, k, v, causal=True,
-                                       scale=dims.softmax_scale))
+                                       scale=dims.softmax_scale, keep=keep))
 
 
 def prefill_chunk(p, dims: MLADims, x, offset, cache, slot, block: int):
@@ -271,3 +336,219 @@ def extend(p, dims: MLADims, x, pos, cache, slots, n_blocks, block: int):
     o = jnp.einsum("bshc,chd->bshd", o.astype(w.dtype), w[..., d.d_nope:],
                    preferred_element_type=jnp.float32)
     return _out(p, d, o), cache
+
+
+# ---------------------------------------------------------------------------
+# The learned index
+# ---------------------------------------------------------------------------
+
+def layer_norm(x, p, eps):
+    x = x.astype(jnp.float32)
+    mean = x.mean(axis=-1, keepdims=True)
+    var = jnp.square(x - mean).mean(axis=-1, keepdims=True)
+    return ((x - mean) * jax.lax.rsqrt(var + eps)
+            * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32))
+
+
+def project_index(p, dims: MLADims, x, cq, pos):
+    """The index's view of the positions ``x`` [..., T, dim] (``cq``: their
+    :func:`compress_q`): ``(qI [..., T, Hi, di], kI [..., T, di], w [..., T,
+    Hi])``, float32; the first ``d_rope`` values of every ``qI`` head and of
+    ``kI`` under the latents' RoPE, ``w`` with both of the score's scales."""
+    d = dims
+    freqs, amp = d.rope_freqs(), d.rope_amplitude
+
+    def turned(v):
+        return jnp.concatenate(
+            [rope(v[..., :d.d_rope], pos, freqs, amp), v[..., d.d_rope:]],
+            axis=-1)
+
+    qi = turned(mm(cq, p["w_qi"]).reshape(
+        x.shape[:-1] + (d.index_heads, d.index_dim)))
+    ki = turned(layer_norm(mm(x, p["w_ki"]), p["ki_norm"], d.index_eps))
+    w = mm(x, p["w_w"]) * (d.index_heads ** -0.5 * d.index_dim ** -0.5)
+    return qi, ki, w
+
+
+def index_scores(qi, w, k):
+    """``I[t, s] = sum_j w[t, j] ReLU(qI[t, j] . kI[s])``: ``qi`` [..., T,
+    Hi, di] and ``k`` [..., Lk, di] in one type (the cached keys'), ``w``
+    [..., T, Hi] float32 -> [..., T, Lk] float32. The heads are summed on
+    the vector unit: a second matrix product would round ``ReLU(.)`` and
+    ``w`` to the chip's default precision, and move the cut."""
+    s = jnp.einsum("...thd,...kd->...thk", qi, k,
+                   preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * w[..., None]).sum(axis=-2)
+
+
+def selected_full(dims: MLADims, scores, pos):
+    """Every row's set as a mask [..., T, T] over all positions, from the
+    full ``scores`` [..., T, T] (the plain form: ``jax.lax.top_k`` itself)."""
+    T = scores.shape[-1]
+    causal = pos[..., :, None] >= pos[..., None, :]
+    idx = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf),
+                        min(dims.index_topk, T))[1]
+    return jnp.any(idx[..., None] == jnp.arange(T), axis=-2) & causal
+
+
+def topk_mask(scores, k: int):
+    """The ``k`` largest of each row of ``scores`` [R, W] (float32, ``-inf``
+    where a position is out of reach) as a mask [R, W], ties to the earlier
+    position: what ``jax.lax.top_k`` selects, without a sort. A float's bits
+    order as the floats do once the negatives' are flipped; the k-th largest
+    key of a row is then found bit by bit, from the top (32 counts over the
+    row). Only where a tie straddles the cut are the tied positions ranked
+    (one running count more)."""
+    bits = jax.lax.bitcast_convert_type(scores + 0.0, jnp.int32)  # no -0.0
+    key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    top = jnp.uint32(0x80000000)
+
+    def signed(t):                  # the keys' order, from the unsigned one
+        return jax.lax.bitcast_convert_type(t ^ top, jnp.int32)
+
+    def bit(i, t):
+        cand = t | (top >> i.astype(jnp.uint32))
+        enough = (key >= signed(cand)[:, None]).sum(axis=1) >= k
+        return jnp.where(enough, cand, t)
+
+    kth = signed(jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros(scores.shape[0], jnp.uint32)))[:, None]
+    above, tie = key > kth, key == kth
+    room = k - above.sum(axis=1)                # of the ties, at least one
+    return jax.lax.cond(
+        (tie.sum(axis=1) > room).any(),
+        lambda: above | (tie & (jnp.cumsum(tie, axis=1) <= room[:, None])),
+        lambda: above | tie)
+
+
+def prefill_chunk_indexed(p, dims: MLADims, x, offset, cache, slot,
+                          block: int, scope: str = "mla"):
+    """:func:`prefill_chunk` of a mixer with an index: ``cache`` is
+    ``{"latent" [slots, P, width], "index_k" [slots, P, index_dim]}``, ``P`` a
+    whole number of blocks. Writes the chunk's latents and index keys; a
+    chunk that reaches past ``index_topk`` positions then scores its rows
+    against the slot's index keys up to its own end, finds each row's exact
+    set and walks the blocks under that mask. ``(out [C, dim] float32,
+    cache, blocks of index keys scanned)``."""
+    d = dims
+    C, P = x.shape[0], cache["latent"].shape[1]
+    if P % block:
+        raise ValueError("a slot of an indexed cache holds whole blocks")
+    offset = jnp.asarray(offset, jnp.int32)
+    pos = offset + jnp.arange(C, dtype=jnp.int32)
+    cq = compress_q(p, d, x)
+    with jax.named_scope(scope + ".index"):
+        qi, ki, w = project_index(p, d, x, cq, pos)
+    qn, qr, latent = project(p, d, x, pos, cq)
+    lat_c = jax.lax.dynamic_update_slice(
+        cache["latent"], _to_cache(latent, cache["latent"])[None],
+        (slot, offset, 0))
+    idx_c = jax.lax.dynamic_update_slice(
+        cache["index_k"], ki.astype(cache["index_k"].dtype)[None],
+        (slot, offset, 0))
+    wdt = p["w_ukv"].dtype
+    reach = offset + C
+    n_blocks = (reach + block - 1) // block
+
+    def kv_block(j):
+        lat = jax.lax.dynamic_slice(
+            lat_c, (slot, j * block, 0), (1, block, lat_c.shape[-1]))
+        return expand(p, d, lat[..., :d.latent])
+
+    def walk(keep_block=None):
+        q = jnp.concatenate([qn, qr], axis=-1).astype(wdt)[None]
+        return attend_over_blocks(
+            q, pos, kv_block, n_blocks, block, d.d_v, dtype=jnp.float32,
+            scale=d.softmax_scale, keep_block=keep_block)[0]
+
+    def all_in_reach():
+        with jax.named_scope(scope + ".attend"):
+            return _out(p, d, walk())
+
+    def under_the_mask():
+        qi_c = qi.astype(idx_c.dtype)
+
+        def score_block(j, scores):
+            kb = jax.lax.dynamic_slice(
+                idx_c, (slot, j * block, 0), (1, block, idx_c.shape[-1]))[0]
+            k_pos = j * block + jnp.arange(block)
+            s = jnp.where(pos[:, None] >= k_pos[None],
+                          index_scores(qi_c, w, kb), -jnp.inf)
+            return jax.lax.dynamic_update_slice(scores, s, (0, j * block))
+
+        with jax.named_scope(scope + ".index"):
+            scores = jax.lax.fori_loop(
+                0, n_blocks, score_block,
+                jnp.full((C, P), -jnp.inf, jnp.float32))
+        with jax.named_scope(scope + ".select"):
+            keep = topk_mask(scores, d.index_topk)
+        with jax.named_scope(scope + ".attend"):
+            return _out(p, d, walk(lambda j: jax.lax.dynamic_slice(
+                keep, (0, j * block), (C, block))))
+
+    sparse = reach > d.index_topk
+    out = jax.lax.cond(sparse, under_the_mask, all_in_reach)
+    return (out, {"latent": lat_c, "index_k": idx_c},
+            jnp.where(sparse, n_blocks, 0).astype(jnp.int32))
+
+
+def extend_indexed(p, dims: MLADims, x, pos, cache, slots, n_blocks,
+                   block: int, scope: str = "mla"):
+    """:func:`extend` of a mixer with an index: every row scores its slot's
+    cached index keys (``n_blocks`` blocks: the batch's longest reach),
+    keeps its ``index_topk`` best positions, GATHERS their latents and
+    attends those in the absorbed form: no row reads a session's whole
+    latent cache. ``(out [B, S, dim] float32, cache, blocks of index keys
+    scanned a row)``."""
+    d = dims
+    B, S, _ = x.shape
+    P = cache["latent"].shape[1]
+    if P % block:
+        raise ValueError("a slot of an indexed cache holds whole blocks")
+    cq = compress_q(p, d, x)
+    with jax.named_scope(scope + ".index"):
+        qi, ki, w = project_index(p, d, x, cq, pos)
+    qn, qr, latent = project(p, d, x, pos, cq)
+    lat_c, idx_c = cache["latent"], cache["index_k"]
+    latent, ki = _to_cache(latent, lat_c), ki.astype(idx_c.dtype)
+    for b in range(B):
+        lat_c = jax.lax.dynamic_update_slice(
+            lat_c, latent[b][None], (slots[b], pos[b, 0], 0))
+        idx_c = jax.lax.dynamic_update_slice(
+            idx_c, ki[b][None], (slots[b], pos[b, 0], 0))
+    qi_c = qi.astype(idx_c.dtype)
+
+    def score_block(j, scores):
+        kb = jax.vmap(lambda s: jax.lax.dynamic_slice(
+            idx_c, (s, j * block, 0), (1, block, idx_c.shape[-1]))[0])(slots)
+        k_pos = j * block + jnp.arange(block)
+        s = jnp.where(pos[..., None] >= k_pos, index_scores(qi_c, w, kb),
+                      -jnp.inf)
+        return jax.lax.dynamic_update_slice(scores, s, (0, 0, j * block))
+
+    with jax.named_scope(scope + ".index"):
+        scores = jax.lax.fori_loop(
+            0, n_blocks, score_block,
+            jnp.full((B, S, P), -jnp.inf, jnp.float32))
+    with jax.named_scope(scope + ".select"):
+        vals, idx = jax.lax.top_k(scores, min(d.index_topk, P))
+    with jax.named_scope(scope + ".attend"):
+        # the absorbed form of :func:`extend`, each row over ITS OWN latents
+        w_ukv = p["w_ukv"].reshape(d.kv_rank, d.heads, d.d_nope + d.d_v)
+        q_abs = jnp.einsum("bshd,chd->bshc", qn.astype(w_ukv.dtype),
+                           w_ukv[..., :d.d_nope],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_abs, qr], axis=-1).astype(lat_c.dtype)
+        lat = lat_c[slots[:, None, None], idx]              # [B, S, K, width]
+        s = jnp.einsum("bshc,bskc->bshk", q, lat[..., :d.latent],
+                       preferred_element_type=jnp.float32) * d.softmax_scale
+        prob = jax.nn.softmax(
+            jnp.where((vals > -jnp.inf)[:, :, None], s, _NEG), axis=-1)
+        o = jnp.einsum("bshk,bskc->bshc", prob.astype(lat.dtype),
+                       lat[..., :d.kv_rank],
+                       preferred_element_type=jnp.float32)
+        o = jnp.einsum("bshc,chd->bshd", o.astype(w_ukv.dtype),
+                       w_ukv[..., d.d_nope:],
+                       preferred_element_type=jnp.float32)
+        out = _out(p, d, o)
+    return out, {"latent": lat_c, "index_k": idx_c}, n_blocks

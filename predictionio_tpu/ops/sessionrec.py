@@ -715,7 +715,11 @@ class StackPrograms:
     per session: a cache PER MIXER, of that mixer's kind, in one list.
 
     * latent attention (``"mla"``): ``[n_slots + 1, capacity + chunk,
-      latent]``, a position's latent;
+      latent]``, a position's latent; with a learned index
+      (``MLADims.index_heads``) ``{latent [n_slots + 1, capacity + chunk,
+      latent], index_k [n_slots + 1, capacity + chunk, index_dim]}``: the
+      index key stands and falls with the latent, position by position, and
+      is a second array because the scorer reads keys alone;
     * grouped-query attention (``"gqa"``): ``[n_slots + 1, capacity + chunk,
       2 * kv_heads * head_dim]``, a position's keys, then its values;
     * a state-space mixer (``"mamba2"``): ``{conv [n_slots + 1, d_conv - 1,
@@ -764,12 +768,19 @@ class StackPrograms:
     #: of sorted rows their grouped kernels ran (over ``experts_touched``:
     #: how many products shared one read of an expert), the fullest held
     #: expert's tokens (per layer, summed), (token, expert layer) pairs that
-    #: kept a group with experts held here. A stack without expert layers,
-    #: or whose router picks no groups, leaves those columns at 0: the keys
-    #: of a call's ``counters`` decide, when the program is traced
+    #: kept a group with experts held here; under a learned index the BLOCKS
+    #: of index keys scanned (per layer, summed: a chunk's for its rows, an
+    #: extension batch's once a real session) and the real query rows whose
+    #: reach exceeded ``index_topk`` (once a run), both in units an int32
+    #: holds for long (the positions in reach of one chunk at 32k over five
+    #: layers are 84 M and would wrap in 25 runs). A stack without expert
+    #: layers, whose router picks no groups, or without an index leaves
+    #: those columns at 0: the keys of a call's ``counters`` decide, when
+    #: the program is traced
     TOTAL_FIELDS = ("runs", "tokens", "held_picks", "experts_touched",
                     "zero_picks", "dense_expert_runs", "expert_row_tiles",
-                    "load_max_sum", "group_hit_tokens")
+                    "load_max_sum", "group_hit_tokens", "index_blocks",
+                    "index_sparse_rows")
 
     def __init__(self, spec: StackSpec, params: Dict, shape: ServeShape):
         #: the mixers' kinds, in the order of their caches
@@ -794,12 +805,22 @@ class StackPrograms:
             raise ValueError("a stack that generates has 'gqa' mixers only")
         dtype = params["item_embed"]["embedding"].dtype
         positions = (shape.n_slots + 1, shape.capacity + shape.chunk)
+        #: whether the latent mixers select their positions by a learned index
+        self.indexed = "mla" in self.kinds and spec.mla.has_index
+
+        def per_position(kind):
+            if kind != "mla":
+                return jnp.zeros(positions + (spec.gqa.cache_width,), dtype)
+            latent = jnp.zeros(
+                positions + (mla_ops.cache_width(spec.mla),), dtype)
+            if not self.indexed:
+                return latent
+            return {"latent": latent, "index_k": jnp.zeros(
+                positions + (spec.mla.index_dim,), dtype)}
+
         self.cache = [
             ssm_ops.init_state(spec.ssm, shape.n_slots + 1, dtype)
-            if kind == "mamba2" else
-            jnp.zeros(positions + (mla_ops.cache_width(spec.mla)
-                                   if kind == "mla"
-                                   else spec.gqa.cache_width,), dtype)
+            if kind == "mamba2" else per_position(kind)
             for kind in self.kinds]
         #: tokens of a call of each program that this stack compiles: the
         #: shape by which ``ops/moe.moe`` chooses its form
@@ -832,12 +853,17 @@ class StackPrograms:
             jaxmon.record_scope_map(program)
         # in one run no column grows by more than every token's every pick
         # and every held expert, in every expert layer (fewer than the
-        # mixers): so many runs an int32 holds
+        # mixers), or than every block of a slot's index keys for every row
+        # of an extension batch, in every mixer: the widest column says how
+        # many runs an int32 holds
         moe = spec.moe
+        widest = (max(self.tokens.values()) * (moe.top_k if moe else 1)
+                  + (moe.held[1] if moe else 0))
+        if self.indexed:
+            widest = max(widest, -(-positions[1] // shape.chunk)
+                         * shape.extend_batch)
         #: runs after which the caller takes the totals, at the latest
-        self.drain_every = (2 ** 31 - 1) // (len(self.kinds) * (
-            max(self.tokens.values()) * (moe.top_k if moe else 1)
-            + (moe.held[1] if moe else 0)))
+        self.drain_every = (2 ** 31 - 1) // (len(self.kinds) * widest)
 
     # -- the programs ---------------------------------------------------------
     def _run(self, params, x, valid, mix_with):
@@ -904,6 +930,9 @@ class StackPrograms:
                     add["expert_row_tiles"] = moe_ops.row_tiles(load).sum()
             if "group_hits" in counters:
                 add["group_hit_tokens"] = counters["group_hits"].sum()
+            if "index_blocks" in counters:
+                add.update(index_blocks=counters["index_blocks"],
+                           index_sparse_rows=counters["index_sparse_rows"])
             row = jnp.stack([jnp.asarray(add.get(f, 0), jnp.int32)
                              for f in self.TOTAL_FIELDS])
             return (cache, result, counters,
@@ -917,12 +946,17 @@ class StackPrograms:
         cache = list(cache)
         spec, chunk = self.spec, self.shape.chunk
         valid = jnp.arange(ids.shape[0]) < n_valid
+        scanned = []
 
         def mix_with(m, p, h, scope):
             kind = self.kinds[m]
             if kind == "mamba2":
                 out, cache[m] = ssm_ops.prefill_chunk(
                     p, spec.ssm, h, n_valid, offset, cache[m], slot, scope)
+            elif kind == "mla" and self.indexed:
+                out, cache[m], blocks = mla_ops.prefill_chunk_indexed(
+                    p, spec.mla, h, offset, cache[m], slot, chunk, scope)
+                scanned.append(blocks)
             elif kind == "mla":
                 out, cache[m] = mla_ops.prefill_chunk(
                     p, spec.mla, h, offset, cache[m], slot, chunk)
@@ -933,7 +967,19 @@ class StackPrograms:
 
         x, counters = self._run(params, self._embed(params, ids), valid,
                                 mix_with)
+        if scanned:
+            pos = offset + jnp.arange(ids.shape[0], dtype=jnp.int32)
+            counters.update(self._index_counts(sum(scanned), valid, pos))
         return cache, self._final(params, x[n_valid - 1])[None], counters
+
+    def _index_counts(self, blocks, valid, pos):
+        """A run's two counts under a learned index: blocks of index keys
+        scanned, and real query rows that reach past ``index_topk``
+        positions (those the index selects for)."""
+        return {"index_blocks": blocks.astype(jnp.int32),
+                "index_sparse_rows": (
+                    valid & (pos >= self.spec.mla.index_topk)).sum().astype(
+                        jnp.int32)}
 
     def _extend_fn(self, params, cache, ids, n_new, slots, pos0, n_blocks):
         cache = list(cache)
@@ -941,12 +987,18 @@ class StackPrograms:
         B, S = ids.shape
         pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
         valid = (jnp.arange(S)[None] < n_new[:, None]).reshape(-1)
+        scanned = []
 
         def mix_with(m, p, h, scope):
             kind, h = self.kinds[m], h.reshape(B, S, -1)
             if kind == "mamba2":
                 out, cache[m] = ssm_ops.extend(
                     p, spec.ssm, h, n_new, pos0, cache[m], slots, scope)
+            elif kind == "mla" and self.indexed:
+                out, cache[m], blocks = mla_ops.extend_indexed(
+                    p, spec.mla, h, pos, cache[m], slots, n_blocks, chunk,
+                    scope)
+                scanned.append(blocks)
             elif kind == "mla":
                 out, cache[m] = mla_ops.extend(
                     p, spec.mla, h, pos, cache[m], slots, n_blocks, chunk)
@@ -958,6 +1010,9 @@ class StackPrograms:
         x, counters = self._run(
             params, self._embed(params, ids).reshape(B * S, -1), valid,
             mix_with)
+        if scanned:         # every real session's rows scan them
+            counters.update(self._index_counts(
+                sum(scanned) * (n_new > 0).sum(), valid, pos.reshape(-1)))
         last = x.reshape(B, S, -1)[jnp.arange(B), jnp.maximum(n_new - 1, 0)]
         return cache, self._final(params, last), counters
 

@@ -1,0 +1,562 @@
+"""GLM-5's cell (ISSUE 43), rehearsed on the CPU at a tiny size
+(tests/benchmarks/tiny_glm: new files and one entry, found by name), its
+control, runs with the selection broken underneath, the configuration
+against the published one, ``glm_counts`` at the published widths, and each
+new per-layer reader on hand-built events. A CPU run is a control-flow check,
+never a device number."""
+
+import dataclasses
+import json
+import os
+
+import pytest
+
+from tests.benchmarks import repo_spec
+from tests.benchmarks.test_program_spans import (BENCHMARKS, FakeBench, HERE,
+                                                 load_file, make_trace)
+from tests.benchmarks.test_seq_cell import OLD_FIXTURE, harness  # noqa: F401
+
+TINY = os.path.join(HERE, "tiny_glm")
+CELL = "glm-tiny.lifelong-c2"
+REAL_CELL = "glm-5.lifelong32k-c4"
+CONFIG = "glm-5"
+
+
+def entry(name, unit, better, source, layer, moves):
+    return {"name": name, "unit": unit, "better": better, "source": source,
+            "layer": layer, "moves": moves, "workloads": [REAL_CELL]}
+
+
+ENTRIES = [
+    entry("extend_step_ms.glm", "ms", "lower", "program_span",
+          "sequence engine", "query_p50_ms"),
+    entry("prefill_chunk_ms.glm", "ms", "lower", "program_span",
+          "sequence engine", "query_p95_ms"),
+    entry("cache_hit_tokens_pct.glm", "%", "higher", "program_counter",
+          "latent cache", "query_rate"),
+    entry("mla_device_share_pct.glm", "%", "lower", "device_trace",
+          "sequence programs", "query_rate"),
+    entry("indexer_device_share_pct.glm", "%", "lower", "device_trace",
+          "sequence programs", "query_rate"),
+    entry("moe_device_share_pct.glm", "%", "lower", "device_trace",
+          "sequence programs", "query_rate"),
+    entry("prefill_roofline_pct.glm", "%", "higher", "device_trace",
+          "sequence programs", "query_p95_ms"),
+    entry("extend_roofline_pct.glm", "%", "higher", "device_trace",
+          "sequence programs", "query_p50_ms"),
+    entry("index_score_roofline_pct.glm", "%", "higher", "device_trace",
+          "sequence programs", "query_p95_ms"),
+    entry("sparse_attend_roofline_pct.glm", "%", "higher", "device_trace",
+          "sequence programs", "query_p50_ms"),
+    entry("sparse_rows_pct.glm", "%", "higher", "program_counter",
+          "sequence programs", "query_rate"),
+    entry("device_idle_pct.lifelong32k-c4", "%", "lower", "device_trace",
+          "device", "query_rate"),
+]
+NEW_METRICS = [e["name"] for e in ENTRIES]
+#: the readers that need nothing of the device
+ON_THE_CPU = {"extend_step_ms.glm", "prefill_chunk_ms.glm",
+              "cache_hit_tokens_pct.glm", "sparse_rows_pct.glm"}
+
+
+def run_cell(harness, capsys, *extra, seed=5000000011):
+    code = harness.main(["--bench-root", TINY, "--rehearse-cpu",
+                         "--workload", CELL, "--seed", str(seed),
+                         "--seconds", "1", *extra])
+    lines = capsys.readouterr().out.strip().splitlines()
+    return code, json.loads(lines[-1]), lines
+
+
+def make_bench(harness, seed=7):
+    import argparse
+
+    import jax
+
+    with open(os.path.join(TINY, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    cell = next(w for w in spec["workloads"] if w["name"] == CELL)
+    bench = harness.Bench(TINY, spec, cell, argparse.Namespace(
+        seed=seed, seconds=1, trace=0))
+    bench.devices = jax.devices()[:1]
+    return bench
+
+
+def real_config():
+    with open(os.path.join(BENCHMARKS, "configs", CONFIG + ".json")) as f:
+        return json.load(f)
+
+
+def tiny_config():
+    with open(os.path.join(TINY, "bench", "configs", "glm-tiny.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("trace", ["0", "1"])
+def test_the_cell_runs_from_new_files_and_prints_the_contracts_line(
+        harness, capsys, trace):
+    code, line, log = run_cell(harness, capsys, "--trace", trace)
+    assert code == 0
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    assert any("compilations inside the window: 0" in l for l in log)
+    assert sum(1 for l in log if l.startswith("# check ")) == 4
+    if trace == "0":
+        assert {"query_p50_ms", "query_p95_ms", "query_rate",
+                "setup_s"} <= set(line["metrics"])
+    else:
+        assert {"busy_s", "window_s"} <= set(line["device"])
+        # the tiny tree lists the cell's own per-layer entries: what needs
+        # no device is read on the CPU too, the rest is left out
+        assert set(line["metrics"]) == ON_THE_CPU
+        # histories of 20-200 against 16 positions a row
+        assert 50 < line["metrics"]["sparse_rows_pct.glm"]["value"] < 100
+    assert any("reference: 8 answers compared" in l
+               and "(4 first queries" in l for l in log)
+    assert any("longest history served 20" in l for l in log)
+    assert any(l.startswith("# reference: of the 8 compared histories")
+               for l in log)
+    counted = next(l for l in log if "engine counters over the window" in l)
+    for name in ("prefill_index_blocks", "extend_index_blocks",
+                 "prefill_index_sparse_rows", "extend_latents_gathered"):
+        assert f"'{name}': 0" not in counted and f"'{name}'" in counted
+    assert "'extend_latent_blocks_attended': 0" in counted
+
+
+def test_the_tiny_tree_lists_the_cells_own_entries_under_its_own_cell():
+    with open(os.path.join(TINY, "BENCHMARK.json")) as f:
+        tiny = json.load(f)
+    assert [dict(m, workloads=[REAL_CELL]) for m in tiny["per_layer"]] \
+        == ENTRIES
+
+
+def test_the_traffic_holds_the_parameters_the_issue_names():
+    traffic = load_file(os.path.join(BENCHMARKS, "session_traffic.py"))
+    with open(os.path.join(BENCHMARKS, "traffic",
+                           "lifelong32k-c4.json")) as f:
+        mix = json.load(f)
+    named = {"connections": 4, "num": 10, "queries_per_session": 10,
+             "grow_max": 3, "history_quantiles": 32, "history_median": 8192,
+             "history_sigma": 0.8, "history_min": 2048,
+             "history_max": 32768, "topics": 64, "own_topic": 0.8,
+             "zipf_exponent": 1.0, "sessions_seed": 43,
+             "warmup_sessions_per_connection": 2,
+             "prepared_sessions_per_connection": 32, "check_sample": 12,
+             "trace_seconds": 3.0}
+    assert {k: mix[k] for k in named} == named
+    # ISSUE 43 named 4.0 here on its reckoning of a 35-60 ms chunk; at the
+    # 100 ms measured, seconds 4 to 7 after GO lie inside the window's first
+    # prefill (a 32,768-event session) and hold NO extension: the three
+    # readers of the extension program found nothing (PERF.md section 6)
+    assert mix["trace_after_go_s"] == 8.0
+    # what the accepted driver and load generator need beside them
+    assert set(mix) - set(named) == {"driver", "loop", "start", "start_why",
+                                     "trace_after_go_s"}
+    assert mix["driver"] == "session_queries"
+    assert [w.get("delay_s", 0.0) for w in mix["start"]] == [0, .05, .05, .05]
+    assert sum(w["connections"] for w in mix["start"]) == 4
+    lengths = traffic.history_lengths(mix)
+    assert lengths[:3] + lengths[-3:] == [2048, 2143, 2635, 25467, 31309,
+                                          32768]
+    assert sum(lengths) / 32 == pytest.approx(10687, abs=1)
+    assert sum(1 for h in lengths if h > 2048) == 31
+    assert sum(1 for h in lengths if h > 8192) == 16
+    assert sum(-(-h // 512) for h in lengths) / 32 == pytest.approx(21.3,
+                                                                    abs=0.05)
+    # over all prefill rows the selected positions are 22% of those in reach
+    k = real_config()["index_topk"]
+    reach = sum(h * (h + 1) // 2 for h in lengths)
+    kept = sum(k * (k + 1) // 2 + (h - k) * k for h in lengths)
+    assert kept / reach == pytest.approx(0.222, abs=0.001)
+    # the control's six shortest histories all select
+    assert sorted(lengths)[:6] == [2048, 2143, 2635, 3063, 3460, 3841]
+    cfg = real_config()
+    sessions = traffic.Sessions(mix, cfg["vocab_size"])
+    order = sessions.order(3)
+    queries = sessions.session(3, order.index(32768))
+    assert len(queries) == 10 and len(queries[0]) == 32768
+    for before, after in zip(queries, queries[1:]):
+        assert after[:len(before)] == before
+        assert 1 <= len(after) - len(before) <= 3 <= cfg["serve"][
+            "extend_len"]
+    # the longest session, grown nine times, fits its slot
+    assert len(queries[-1]) <= 32795 <= cfg["serve"]["capacity"]
+    assert max(queries[-1]) < cfg["vocab_size"]
+
+
+def test_the_controls_histories_are_the_mixs_shortest(harness):
+    bench = make_bench(harness)
+    builder = bench.load_module("models", bench.config["engine"])
+    histories = builder.control_histories(bench)
+    assert len(histories) == 3
+    lengths = bench.lib("session_traffic").history_lengths(bench.traffic)
+    for want, got in zip(sorted(lengths)[:3], histories):
+        assert want <= len(got) <= want + 3 * 3
+        assert len(got) > bench.config["index_topk"]     # each one selects
+
+
+def test_the_control_in_a_lower_precision_fails_a_limit(harness):
+    bench = make_bench(harness)
+    reference = bench.load_module("reference", bench.config["reference"])
+    readings = reference.control(bench)
+    limits = bench.config["limits"]
+    assert set(readings) == {"bfloat16", "float8_e4m3fn"}
+    for name, got in readings.items():
+        assert got["compared"] == 3
+        assert (got["score_err"] > limits["score_err"]
+                or got["rank_gap"] > limits["rank_gap"]), (name, got)
+    assert (readings["float8_e4m3fn"]["score_err"]
+            > readings["bfloat16"]["score_err"])
+
+
+@pytest.mark.parametrize("what", ["no_index", "last_positions", "no_rope"])
+def test_a_program_that_selects_otherwise_makes_the_run_incorrect(
+        harness, capsys, monkeypatch, what):
+    """What the builder shows once on the chip (``benchmarks/tools/
+    glm_ablation.py``): dense attention, the last ``index_topk`` positions in
+    the index's place, or an index without its RoPE, and the comparison must
+    notice."""
+    ablation = load_file(os.path.join(BENCHMARKS, "tools",
+                                      "glm_ablation.py"))
+    run_builder = harness.load_file(
+        os.path.join(BENCHMARKS, "models", "glmrec.py"))
+    with ablation.broken(what, run_builder):
+        code, line, log = run_cell(harness, capsys, "--trace", "0")
+    assert code == 0
+    assert line["correct"] is False
+    assert any(l.startswith("# check score_err") and "FAILED" in l
+               for l in log)
+    # and the sound program is back
+    builder = load_file(os.path.join(BENCHMARKS, "models", "glmrec.py"))
+    assert dataclasses.asdict(builder.stack_spec(tiny_config())) \
+        == dataclasses.asdict(run_builder.stack_spec(tiny_config()))
+
+
+def test_a_program_that_lacks_the_index_fails_before_any_weight(
+        harness, monkeypatch):
+    """The parent's program under this PR's benchmark files: the builder
+    hands ``MLADims`` fields it lacks, before 8 GB of weights are made."""
+    from predictionio_tpu.ops import mla
+
+    old_fields = [f for f in dataclasses.fields(mla.MLADims)
+                  if not f.name.startswith("index_")]
+    OldMLADims = dataclasses.make_dataclass(
+        "OldMLADims", [(f.name, f.type, f) for f in old_fields], frozen=True)
+    monkeypatch.setattr(mla, "MLADims", OldMLADims)
+    bench = make_bench(harness)
+    builder = bench.load_module("models", bench.config["engine"])
+    made = []
+    monkeypatch.setattr(builder, "make_weights",
+                        lambda bench: made.append(1))
+    with pytest.raises(TypeError):
+        builder.deploy(bench)
+    assert not made
+
+
+def test_glm_counts_at_the_published_widths():
+    counts = load_file(os.path.join(BENCHMARKS, "glm_counts.py"))
+    cfg = real_config()
+    # ISSUE 43's figures (matrices only)
+    assert counts.mla_params(cfg) == 165_019_648
+    assert counts.indexer_params(cfg) == 9_371_648
+    assert counts.expert_params(cfg) == 37_748_736
+    assert counts.router_params(cfg) == 1_572_864
+    assert counts.expert_layer_params(cfg) == 213_712_896
+    assert counts.dense_layer_params(cfg) == 400_883_712
+    assert counts.expert_layers(cfg) == 4
+    assert counts.nonexpert_params(cfg) == 400_883_712 + 4 * 213_712_896
+    published = cfg["parameters_published"]
+    assert (published["mla"], published["indexer"], published["expert"],
+            published["router"],
+            published["expert_layer_outside_routed_experts"],
+            published["dense_layer"]) == (
+        165_019_648, 9_371_648, 37_748_736, 1_572_864, 213_712_896,
+        400_883_712)
+    whole = (3 * 400_883_712 + 75 * (213_712_896 + 256 * 37_748_736)
+             + 2 * 154_880 * 6144)
+    assert whole == published["model"] and abs(whole / 1e9 - 744) < 0.2
+    held = cfg["parameters_held"]
+    assert held["expert_layer"] == 213_712_896 + 16 * 37_748_736 \
+        == 817_692_672
+    assert held["embedding_and_head"] == 2 * 19_360 * 6144
+    assert held["all"] == (counts.nonexpert_params(cfg)
+                           + 4 * 16 * 37_748_736 + 2 * 19_360 * 6144)
+    assert abs(held["all"] * 2 / 1e9 - 7.82) < 0.01
+    # 13 slots x 33,792 positions x 5 layers x (640 + 128) bfloat16 values
+    assert abs(13 * 33_792 * 5 * 768 * 2 / 1e9 - 3.37) < 0.01
+    assert counts.latent_bytes_per_position(cfg) == 5 * 576 * 2
+    assert counts.index_key_bytes_per_position(cfg) == 5 * 128 * 2
+    # a chunk of 512 from 1,800 on: 248 rows keep all they reach, 264 keep
+    # 2,048 each
+    assert counts.selected_pairs(cfg, 1800, 512) == (
+        248 * 1800 + 248 * 249 // 2 + 264 * 2048)
+    assert counts.selected_pairs(cfg, 0, 512) == 512 * 513 // 2
+    assert counts.selected_pairs(cfg, 32256, 512) == 512 * 2048
+    assert counts.attention_flops(cfg, 32256, 1) == 2048 * 1024 * 64 * 5
+    # ISSUE 43's 137 GFLOP a layer for a chunk's scores at offset 32,256
+    # (64 blocks of 512 keys against 512 rows)
+    assert counts.index_score_flops(cfg, 64, 0) / 1e9 == pytest.approx(
+        137.4, abs=0.1)
+    assert counts.index_score_flops(cfg, 0, 10) == (
+        10 * 512 * 4 * 2 * 32 * 128)
+    assert counts.index_projection_flops(cfg, 3) == 2 * 3 * 9_371_648 * 5
+    one = counts.prefill_flops(cfg, [(0, 512)], held_picks=256)
+    assert one == pytest.approx(
+        2 * (512 * 1_255_735_296 + 256 * 37_748_736)
+        + 512 * 513 / 2 * 1024 * 64 * 5)
+    far = counts.prefill_flops(cfg, [(8192, 512)], 0) - 2 * 512 * \
+        1_255_735_296
+    scored = 8704 * 8705 // 2 - 8192 * 8193 // 2
+    assert far == pytest.approx(scored * 2 * 32 * 128 * 5
+                                + 512 * 2048 * 1024 * 64 * 5)
+    assert counts.prefill_bytes(cfg, [(1024, 512), (4096, 512)], 60) == \
+        pytest.approx(2 * 1_255_735_296 * 2 + 60 * 75_497_472
+                      + (1536 + 4608) * 5760 + 4608 * 1280)
+    step = counts.extend_bytes(cfg, runs=1, experts_touched=20,
+                               index_blocks=130, latents_gathered=4096)
+    assert step == pytest.approx(1_255_735_296 * 2 + 20 * 75_497_472
+                                 + 130 * 512 * 256 + 4096 * 5760)
+    assert counts.extend_flops(cfg, 8, 3, 130, 4096) == pytest.approx(
+        2 * (8 * 1_255_735_296 + 3 * 37_748_736)
+        + 130 * 512 * 4 * 2 * 32 * 128 + 4096 * 2176 * 64 * 5)
+
+
+@pytest.mark.parametrize("case", repo_spec.CASES)
+def test_benchmark_json_names_the_configuration_the_cell_and_each_reader(
+        case):
+    spec = repo_spec.load(case)
+    cell = repo_spec.by_name(spec["workloads"], REAL_CELL)
+    assert cell == {"name": REAL_CELL, "config": CONFIG,
+                    "traffic": "lifelong32k-c4", "chips": 1,
+                    "why": cell["why"]}
+    assert len(cell["why"]) <= 200 and "32,768" in cell["why"]
+    config = repo_spec.by_name(spec["configs"], CONFIG)
+    assert config["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                                 "vocab_size"]
+    assert config["file"] == f"benchmarks/configs/{CONFIG}.json"
+    assert config["source"] == real_config()["source"].split(" ")[0]
+    assert len(config["why"]) <= 200
+    for e in ENTRIES:
+        repo_spec.assert_names_the_reader(spec, e)
+    for name in ("query_p50_ms", "query_p95_ms", "query_rate"):
+        assert REAL_CELL in repo_spec.by_name(
+            spec["end_to_end"], name)["workloads"]
+    # the cell joins no accepted per-layer metric's list (GLM_SPANS.md), and
+    # none of four chips came with it
+    for m in spec["per_layer"]:
+        if m["name"] not in NEW_METRICS:
+            assert REAL_CELL not in m.get("workloads", ()), m["name"]
+    assert all(w["chips"] == 1 for w in spec["workloads"])
+    # appended: the configuration, the cell and its entries stand after
+    # everything the benchmark had
+    names = [m["name"] for m in spec["per_layer"]]
+    assert max(names.index(n) for n in names
+               if n.endswith((".axk", ".lifelong-c4"))) < min(
+        names.index(n) for n in NEW_METRICS)
+
+
+def test_the_configuration_keeps_every_published_number():
+    catalog = {
+        "attention_bias": False, "ep_size": 1, "first_k_dense_replace": 3,
+        "hidden_act": "silu", "head_dim": 64, "hidden_size": 6144,
+        "index_head_dim": 128, "index_n_heads": 32, "index_topk": 2048,
+        "indexer_rope_interleave": True, "intermediate_size": 12288,
+        "kv_lora_rank": 512, "max_position_embeddings": 202752,
+        "moe_intermediate_size": 2048, "moe_layer_freq": 1,
+        "model_type": "glm_moe_dsa", "n_group": 1, "n_routed_experts": 256,
+        "n_shared_experts": 1, "norm_topk_prob": True,
+        "num_attention_heads": 64, "num_experts_per_tok": 8,
+        "num_hidden_layers": 78, "num_key_value_heads": 64,
+        "num_nextn_predict_layers": 1, "q_lora_rank": 2048,
+        "qk_head_dim": 256, "qk_nope_head_dim": 192, "qk_rope_head_dim": 64,
+        "rms_norm_eps": 1e-05, "rope_interleave": True,
+        "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+        "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+        "tie_word_embeddings": False, "topk_group": 1,
+        "topk_method": "noaux_tc", "v_head_dim": 256, "vocab_size": 154880}
+    cfg = real_config()
+    differ = {k for k, v in catalog.items() if cfg[k] != v}
+    assert differ == {"num_hidden_layers", "n_routed_experts",
+                      "vocab_size"} == set(cfg["reduced"])
+    assert (cfg["num_hidden_layers"], cfg["n_routed_experts"],
+            cfg["vocab_size"]) == (5, 16, 19360)
+    assert (cfg["num_hidden_layers_published"],
+            cfg["n_routed_experts_published"],
+            cfg["vocab_size_published"]) == (78, 256, 154880)
+    assert cfg["experts_held"] == [0, 16]
+    # the floors: ONE dense layer (the three count once) + at least four
+    # expert layers, at least eight routed experts, at least an eighth of
+    # the vocabulary
+    assert cfg["first_k_dense_replace_held"] == 1
+    assert cfg["num_hidden_layers"] - cfg["first_k_dense_replace_held"] >= 4
+    assert cfg["n_routed_experts"] >= 8
+    assert cfg["vocab_size"] * 8 >= cfg["vocab_size_published"]
+    assert cfg["limits"]["score_err"] > 0 and cfg["limits"]["rank_gap"] > 0
+    assert {"indexer", "indexer_rotation_and_fp8", "mtp", "selection_bias",
+            "hidden_act", "rope_pairing", "weights", "sessions", "cache",
+            "slo"} <= set(cfg["assumed"])
+    assert "NOT run" in cfg["assumed"]["mtp"]
+    for key in ("source", "deployment", "precision", "equations",
+                "limits_set_from"):
+        assert cfg[key], key
+    assert "16 chips" in cfg["deployment"] or "16-chip" in cfg["deployment"]
+    assert cfg["serve"] == {"n_slots": 12, "capacity": 33280, "chunk": 512,
+                            "extend_len": 4, "extend_batch": 4}
+    # the program's stack follows from these keys alone
+    builder = load_file(os.path.join(BENCHMARKS, "models", "glmrec.py"))
+    spec = builder.stack_spec(cfg)
+    assert [b.ffn for b in spec.blocks] == ["swiglu"] + ["moe"] * 4
+    assert spec.ffn_dim == 12288
+    assert spec.mla.softmax_scale == 256 ** -0.5
+    assert spec.mla.rope_factor == 1.0 and spec.mla.rope_theta == 1e6
+    assert (spec.mla.d_nope, spec.mla.d_v, spec.mla.q_rank) == (192, 256,
+                                                                2048)
+    assert (spec.mla.index_heads, spec.mla.index_dim,
+            spec.mla.index_topk) == (32, 128, 2048)
+    assert (spec.moe.scoring, spec.moe.n_group, spec.moe.topk_group,
+            spec.moe.n_router, spec.moe.held, spec.moe.norm_topk,
+            spec.moe.scale) == ("sigmoid", 1, 1, 256, (0, 16), True, 2.5)
+    assert not builder.stack_spec(cfg, no_index=True).mla.has_index
+
+
+# -- the readers -----------------------------------------------------------------
+
+def read(metric, trace, config=None, **ctx):
+    reader = load_file(os.path.join(BENCHMARKS, "layer_metrics",
+                                    metric + ".py"))
+    return reader.read({"bench": FakeBench(config or real_config()),
+                        "_program_spans": trace, **ctx})
+
+
+@pytest.fixture(scope="module")
+def ps():
+    return load_file(os.path.join(BENCHMARKS, "program_spans.py"))
+
+
+def stats(**kw):
+    return {"stats0": {k: 0 for k in kw}, "stats1": kw,
+            "window_stats0": {k: 0 for k in kw}, "window_stats1": kw}
+
+
+def test_the_span_and_counter_readers_on_hand_built_events(ps):
+    spans = [("pio:seq.step", 0, 70, 1),
+             ("pio:seq.extend", 1, 17, 1, {"rows": 3}),
+             ("pio:seq.prefill_chunk", 18, 69, 1,
+              {"slot": 4, "offset": 512, "tokens": 512}),
+             ("pio:seq.step", 80, 150, 1), ("pio:seq.extend", 81, 95, 1),
+             ("pio:seq.prefill_chunk", 96, 149, 1),
+             ("pio:seq.step", 160, 180, 1), ("pio:seq.extend", 161, 179, 1)]
+    trace = make_trace(ps, spans)
+    assert read("extend_step_ms.glm", trace) == pytest.approx(16.0)
+    assert read("prefill_chunk_ms.glm", trace) == pytest.approx(52.0)
+    ctx = stats(hit_tokens=900, miss_tokens=100)
+    assert read("cache_hit_tokens_pct.glm", None, **ctx) == pytest.approx(90)
+    ctx = stats(prefill_index_sparse_rows=700, extend_index_sparse_rows=150,
+                prefill_tokens=800, extend_tokens=200)
+    assert read("sparse_rows_pct.glm", None, **ctx) == pytest.approx(85)
+    assert read("device_idle_pct.lifelong32k-c4", None,
+                traced={"busy_s": 2.4, "window_s": 3.0}) == pytest.approx(
+                    20.0)
+
+
+def test_the_device_readers_on_hand_built_operations(ps):
+    """Two extension programs of 10 ms and one chunk program of 60 ms on the
+    device. An extension: 2 ms of index (projections and scores), 1 of
+    selection, 2 of attention, 1 of the mixer's rest, 1 + 1 of router and
+    experts, 2 outside any scope. The chunk: 8 of index, 2 of selection, 20
+    of attention, 4 of the mixer's rest, 2 + 8 + 4 of router, experts and the
+    shared expert, 12 outside. Both programs number their fusions alike."""
+    ops, modules = [], []
+    for t in (0, 20):
+        ops += [("%fusion.1", t, t + 2, "seq.layer0.mla_a.index"),
+                ("%sort.2", t + 2, t + 3, "seq.layer0.mla_a.select"),
+                ("%fusion.3", t + 3, t + 5, "seq.layer0.mla_a.attend"),
+                ("%fusion.4", t + 5, t + 6, "seq.layer0.mla_a"),
+                ("%fusion.5", t + 6, t + 7, "seq.layer1.moe.route"),
+                ("%expert_stream.6", t + 7, t + 8, "seq.layer1.moe.experts"),
+                ("%copy.7", t + 8, t + 10, None)]
+        modules += ["jit__extend_fn"] * 7
+    ops += [("%fusion.1", 40, 48, "seq.layer3.mla_a.index"),
+            ("%while.2", 48, 50, "seq.layer3.mla_a.select"),
+            ("%fusion.3", 50, 70, "seq.layer3.mla_a.attend"),
+            ("%fusion.4", 70, 74, "seq.layer3.mla_a"),
+            ("%fusion.5", 74, 76, "seq.layer3.moe.route"),
+            ("%expert_groups.6", 76, 84, "seq.layer3.moe.experts"),
+            ("%fusion.8", 84, 88, "seq.layer3.moe.shared"),
+            ("%copy.7", 88, 100, None)]
+    modules += ["jit__prefill_fn"] * 8
+    trace = make_trace(ps, [("pio:seq.prefill_chunk", 39, 101, 1,
+                             {"offset": 8192, "tokens": 512})], ops)
+    for dev in trace.ops.values():
+        dev[:] = [o._replace(module=m) for o, m in zip(dev, modules)]
+    traced = {"busy_s": 0.080, "window_s": 0.101}
+    counts = load_file(os.path.join(BENCHMARKS, "glm_counts.py"))
+    # by program AND instruction: the chunk's %fusion.3 is not the
+    # extension's
+    assert counts.scope_self_ns(ps, trace, ".mla_a.attend") == \
+        pytest.approx(24e6)
+    assert counts.scope_self_ns(ps, trace, ".mla_a.attend",
+                                "extend_fn") == pytest.approx(4e6)
+    assert counts.scope_self_ns(ps, trace, ".mla_a.index") == \
+        pytest.approx(12e6)
+    cfg = real_config()
+    ctx = stats(extend_runs=2, extend_tokens=12, extend_held_picks=5,
+                extend_experts_touched=9, extend_index_blocks=600,
+                extend_latents_gathered=24_000,
+                prefill_tokens=512, prefill_held_picks=260,
+                prefill_experts_touched=40, prefill_index_blocks=85)
+    flops = (counts.index_score_flops(cfg, 85, 600)
+             + counts.index_projection_flops(cfg, 524))
+    assert read("index_score_roofline_pct.glm", trace, traced=traced,
+                **ctx) == pytest.approx(100.0 * flops / 197e12 / 0.012)
+    assert read("sparse_attend_roofline_pct.glm", trace, traced=traced,
+                **ctx) == pytest.approx(
+                    100.0 * 24_000 * 5760 / 819e9 / 0.004)
+    need = counts.extend_bytes(cfg, 2, 9, 600, 24_000)
+    assert need / 819e9 > counts.extend_flops(cfg, 12, 5, 600,
+                                              24_000) / 197e12
+    assert read("extend_roofline_pct.glm", trace, traced=traced, **ctx) == \
+        pytest.approx(100.0 * need / 819e9 / 0.020)
+    # the chunk at offset 8,192: its operations bound it, not its bytes
+    flop_s = counts.prefill_flops(cfg, [(8192, 512)], 260) / 197e12
+    byte_s = counts.prefill_bytes(cfg, [(8192, 512)], 40) / 819e9
+    assert flop_s > byte_s
+    assert read("prefill_roofline_pct.glm", trace, traced=traced, **ctx) == \
+        pytest.approx(100.0 * flop_s / 0.060)
+    # counted too high, or part of the time left out: no reading
+    far = stats(**dict(ctx["stats1"], extend_runs=20))
+    assert read("extend_roofline_pct.glm", trace, traced=traced,
+                **far) is None
+    # the shares go through the accepted reduction (by instruction)
+    for name in ("mla_device_share_pct.glm", "indexer_device_share_pct.glm",
+                 "moe_device_share_pct.glm"):
+        assert 0 < read(name, trace, traced=traced) < 100, name
+    assert read("indexer_device_share_pct.glm", trace, traced=traced) < \
+        read("mla_device_share_pct.glm", trace, traced=traced)
+
+
+def test_a_reader_returns_none_where_there_is_nothing_to_read(ps):
+    """A trace of a program without this engine (PR 25's fixture), counters
+    of a program that lacks what this PR counts (the PARENT's, under this
+    PR's benchmark files), and a run that was not traced: no number, no
+    error."""
+    old = ps.load(OLD_FIXTURE, {})
+    old_stats = stats(hit_tokens=0, miss_tokens=0, extend_runs=0)
+    for name in NEW_METRICS:
+        if name != "device_idle_pct.lifelong32k-c4":
+            assert read(name, old, traced={"busy_s": 1.0, "window_s": 2.0},
+                        **old_stats) is None, name
+        assert read(name, None) is None, name
+    # the parent's A.X-K1 trace (PR 39's fixture, recorded on the chip): both
+    # serve programs, latent-attention scopes, and none of the index's
+    # scopes or counters
+    with open(os.path.join(HERE, "fixtures", "axk_small.scopes.json")) as f:
+        axk = ps.load(os.path.join(HERE, "fixtures", "axk_small.xplane.pb"),
+                      json.load(f))
+    with open(os.path.join(HERE, "fixtures", "axk_small.ctx.json")) as f:
+        ctx = json.load(f)
+    ctx.update(window_stats0=ctx["stats0"], window_stats1=ctx["stats1"])
+    for name in ("prefill_roofline_pct.glm", "extend_roofline_pct.glm",
+                 "index_score_roofline_pct.glm",
+                 "sparse_attend_roofline_pct.glm", "sparse_rows_pct.glm",
+                 "indexer_device_share_pct.glm"):
+        assert read(name, axk, **ctx) is None, name
