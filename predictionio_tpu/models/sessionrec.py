@@ -424,9 +424,12 @@ class SeqStackModel:
             # and what each row's own reach would have taken
             "extend_latent_blocks_attended": 0,
             "extend_latent_blocks_own": 0,
-            # under a learned index an extension walks no latent block: the
-            # latents its rows' attention GATHERED, a layer (each new
-            # position its ``min(index_topk, reach)`` selected ones)
+            # under a learned index the two above stay 0: the walk reads
+            # the blocks the scorer scans (``extend_index_blocks``, on the
+            # device) and a row attends only what it SELECTED of them: the
+            # latents its new positions selected, a layer
+            # (``min(index_topk, reach)`` each; the name is from when an
+            # extension gathered them)
             "extend_latents_gathered": 0,
             "extend_kv_positions": 0, "extend_state_rows": 0,
             # from a ticket's admission to the launch of the first program

@@ -50,9 +50,12 @@ largest score by bisection over the scores' bits, no sort) and walks the
 blocks under that per-row mask: exact, and no saving over attending all
 (512 rows' sets together cover every block; gathering 512 x 2,048 latents
 instead measured 44.5 ms a layer against 3.0-19.5 at offsets of 2,048-32,256:
-PERF.md section 5). An extension gathers: it reads a position's index key
-(256 B at 128 bfloat16 values) for every cached position and a latent only
-for its rows' selected ones.
+PERF.md section 5). An extension does the same in the absorbed form: its rows
+score their slots' index keys (256 B a position at 128 bfloat16 values), find
+their sets by the same bisection and walk the slots' latents in place, each
+row under its own mask (sorting 16 rows of 33,792 scores and gathering 16 x
+2,048 latents instead took 2.3-2.8 ms a layer where this takes 0.8-1.7 and the
+walk without an index 0.7-1.5: PERF.md section 5).
 """
 
 from __future__ import annotations
@@ -63,8 +66,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from predictionio_tpu.ops.attention import (_NEG, attend_over_blocks,
-                                            mha_reference)
+from predictionio_tpu.ops.attention import attend_over_blocks, mha_reference
 
 
 @dataclasses.dataclass(frozen=True)
@@ -492,14 +494,72 @@ def prefill_chunk_indexed(p, dims: MLADims, x, offset, cache, slot,
             jnp.where(sparse, n_blocks, 0).astype(jnp.int32))
 
 
+#: the float32 scores one step of an indexed extension's walk may hold, every
+#: query row and head against one run of cached positions: 6 MiB is 16 rows x
+#: 64 heads against 1,536 positions. Steps of 512 | 1,024 | 1,536 | 3,072 |
+#: 5,632 positions read 1.92 | 1.70 | 1.65 | 1.73 | 1.85 ms a mixer at a reach
+#: of 32,256 and 0.77 | 0.78 | 0.79 | 0.81 | 0.97 at 2,048 (PERF.md section
+#: 5): fewer steps take their latency off the loops, wider ones walk further
+#: past a short reach, and the scores cross HBM as often either way.
+_WALK_SCORE_BYTES = 6 << 20
+
+
+def _walk_block(P: int, block: int, rows: int) -> int:
+    """Positions a step of an indexed extension's loops takes: the widest
+    whole number of ``block``s that divides a slot's ``P`` positions (no step
+    reaches past the slot's end) and whose float32 scores against ``rows``
+    query rows fit :data:`_WALK_SCORE_BYTES`; ``block`` where none does."""
+    n = P // block
+    most = max(_WALK_SCORE_BYTES // (4 * rows * block), 1)
+    return block * max(m for m in range(1, min(n, most) + 1) if n % m == 0)
+
+
+def _of_slots(held, slots, j, wide: int):
+    """Step ``j`` (``wide`` positions) of each of ``slots`` [B] of a cached
+    array ``held`` [slots, P, width]: [B, wide, width]."""
+    return jax.vmap(lambda s: jax.lax.dynamic_slice(
+        held, (s, j * wide, 0), (1, wide, held.shape[-1]))[0])(slots)
+
+
+def extension_sets(dims: MLADims, qi, w, index_k, slots, pos, n_wide,
+                   wide: int, scope: str = "mla"):
+    """Each extension row's set as a mask [B, S, P] over ITS slot of
+    ``index_k`` [slots, P, index_dim]: its index's queries ``qi`` [B, S, Hi,
+    di] (in the keys' type) and head weights ``w`` [B, S, Hi] against the
+    slot's keys, ``n_wide`` steps of ``wide`` positions, out of the row's
+    reach ``-inf``; then the row's exact ``index_topk`` (:func:`topk_mask`).
+    A row that reaches no further than its set keeps all in reach, and what
+    fills the set up lies past its reach: the walk's causal mask drops it."""
+    B, S = pos.shape
+    P = index_k.shape[1]
+
+    def score_block(j, scores):
+        k_pos = j * wide + jnp.arange(wide)
+        s = jnp.where(
+            pos[..., None] >= k_pos,
+            index_scores(qi, w, _of_slots(index_k, slots, j, wide)), -jnp.inf)
+        return jax.lax.dynamic_update_slice(scores, s, (0, 0, j * wide))
+
+    with jax.named_scope(scope + ".index"):
+        scores = jax.lax.fori_loop(
+            0, n_wide, score_block,
+            jnp.full((B, S, P), -jnp.inf, jnp.float32))
+    with jax.named_scope(scope + ".select"):
+        return topk_mask(scores.reshape(B * S, P),
+                         min(dims.index_topk, P)).reshape(B, S, P)
+
+
 def extend_indexed(p, dims: MLADims, x, pos, cache, slots, n_blocks,
                    block: int, scope: str = "mla"):
     """:func:`extend` of a mixer with an index: every row scores its slot's
-    cached index keys (``n_blocks`` blocks: the batch's longest reach),
-    keeps its ``index_topk`` best positions, GATHERS their latents and
-    attends those in the absorbed form: no row reads a session's whole
-    latent cache. ``(out [B, S, dim] float32, cache, blocks of index keys
-    scanned a row)``."""
+    cached index keys as far as the batch's longest reach (``n_blocks``
+    blocks) and finds its exact ``index_topk`` best positions as a MASK
+    (:func:`extension_sets`); then the absorbed walk of :func:`extend` reads
+    the slots' cached latents once, in place, each row attending under its
+    own mask: nothing is sorted and nothing gathered. Scorer and walk step
+    :func:`_walk_block` positions at a time (what a step reads past the
+    batch's reach is past every row's). ``(out [B, S, dim] float32, cache,
+    blocks of index keys in the batch's reach: what a row scans)``."""
     d = dims
     B, S, _ = x.shape
     P = cache["latent"].shape[1]
@@ -516,37 +576,31 @@ def extend_indexed(p, dims: MLADims, x, pos, cache, slots, n_blocks,
             lat_c, latent[b][None], (slots[b], pos[b, 0], 0))
         idx_c = jax.lax.dynamic_update_slice(
             idx_c, ki[b][None], (slots[b], pos[b, 0], 0))
-    qi_c = qi.astype(idx_c.dtype)
-
-    def score_block(j, scores):
-        kb = jax.vmap(lambda s: jax.lax.dynamic_slice(
-            idx_c, (s, j * block, 0), (1, block, idx_c.shape[-1]))[0])(slots)
-        k_pos = j * block + jnp.arange(block)
-        s = jnp.where(pos[..., None] >= k_pos, index_scores(qi_c, w, kb),
-                      -jnp.inf)
-        return jax.lax.dynamic_update_slice(scores, s, (0, 0, j * block))
-
-    with jax.named_scope(scope + ".index"):
-        scores = jax.lax.fori_loop(
-            0, n_blocks, score_block,
-            jnp.full((B, S, P), -jnp.inf, jnp.float32))
-    with jax.named_scope(scope + ".select"):
-        vals, idx = jax.lax.top_k(scores, min(d.index_topk, P))
+    wide = _walk_block(P, block, B * S * d.heads)
+    n_wide = (n_blocks * block + wide - 1) // wide
+    keep = extension_sets(d, qi.astype(idx_c.dtype), w, idx_c, slots, pos,
+                          n_wide, wide, scope)
     with jax.named_scope(scope + ".attend"):
-        # the absorbed form of :func:`extend`, each row over ITS OWN latents
+        # the absorbed form of :func:`extend`, each row under ITS OWN mask
         w_ukv = p["w_ukv"].reshape(d.kv_rank, d.heads, d.d_nope + d.d_v)
         q_abs = jnp.einsum("bshd,chd->bshc", qn.astype(w_ukv.dtype),
                            w_ukv[..., :d.d_nope],
                            preferred_element_type=jnp.float32)
         q = jnp.concatenate([q_abs, qr], axis=-1).astype(lat_c.dtype)
-        lat = lat_c[slots[:, None, None], idx]              # [B, S, K, width]
-        s = jnp.einsum("bshc,bskc->bshk", q, lat[..., :d.latent],
-                       preferred_element_type=jnp.float32) * d.softmax_scale
-        prob = jax.nn.softmax(
-            jnp.where((vals > -jnp.inf)[:, :, None], s, _NEG), axis=-1)
-        o = jnp.einsum("bshk,bskc->bshc", prob.astype(lat.dtype),
-                       lat[..., :d.kv_rank],
-                       preferred_element_type=jnp.float32)
+
+        def kv_block(j):
+            lat = _of_slots(lat_c, slots, j, wide)
+            return lat[:, :, None, :d.latent], lat[:, :, None, :d.kv_rank]
+
+        def keep_block(j):              # a row's set, for each of its heads
+            return jnp.repeat(jax.lax.dynamic_slice(
+                keep, (0, 0, j * wide), (B, S, wide)), d.heads, axis=1)
+
+        o = attend_over_blocks(
+            q.reshape(B, S * d.heads, 1, d.latent),
+            jnp.repeat(pos, d.heads, axis=1), kv_block, n_wide, wide,
+            d.kv_rank, dtype=jnp.float32, scale=d.softmax_scale,
+            keep_block=keep_block).reshape(B, S, d.heads, d.kv_rank)
         o = jnp.einsum("bshc,chd->bshd", o.astype(w_ukv.dtype),
                        w_ukv[..., d.d_nope:],
                        preferred_element_type=jnp.float32)

@@ -198,8 +198,9 @@ def test_the_programs_sets_are_the_references_position_for_position(ref):
 def test_chunks_and_extensions_through_the_cache_equal_the_plain_form(ref):
     """Prefill across nine chunk boundaries (blocks of 8, the first chunk
     starting mid-block) under each row's mask, then extensions of 4, 4 and 2
-    positions over gathered latents, beside a short session in the same
-    batch: ``attend_full`` with the index and the reference, row for row."""
+    positions walking the slot's latents under each row's mask, beside a
+    short session in the same batch: ``attend_full`` with the index and the
+    reference, row for row."""
     dims = MLA
     p = mixer()
     T = 150
@@ -243,6 +244,115 @@ def test_chunks_and_extensions_through_the_cache_equal_the_plain_form(ref):
             close(out[1, :2], want_short[7:])
         assert int(blocks) == -(-(at + 4) // 8)
         at += n
+
+
+SCRATCH = 3        # the slot a batch's padding sessions write to
+
+#: an extension batch through a cache of four slots of 176 positions (22
+#: blocks of 8): ``(each session's (slot, first new position, new
+#: positions), positions of the slot that hold ONE index key)``
+EXTENSIONS = {
+    "reach_within_the_set": ([(1, 5, 4), (2, 3, 4)], None),
+    "reach_one_past_the_set": ([(1, 9, 4), (2, 8, 4)], None),
+    "reach_over_many_blocks": ([(1, 150, 4), (2, 97, 4)], None),
+    "a_tie_straddling_the_cut": ([(1, 100, 4), (2, 60, 4)], (3, 100)),
+    "one_real_session_of_four": ([(2, 120, 4)] + [(SCRATCH, 0, 0)] * 3, None),
+    "one_new_position_of_four": ([(1, 77, 1), (2, 130, 1)], None),
+    "reaches_far_apart": ([(1, 6, 4), (2, 160, 4)], None),
+}
+
+
+def plain_extension(p, x, pos, cache, slots):
+    """Each row alone, the plain form: its scores over its WHOLE reach from
+    the slot's index keys, ``jax.lax.top_k``, and one softmax over the keys
+    and values expanded from the latents of that set. ``(out [B, S, dim],
+    sets [B, S, P])``."""
+    B, S, _ = x.shape
+    cq = mla_ops.compress_q(p, MLA, x)
+    qi, _, w = mla_ops.project_index(p, MLA, x, cq, pos)
+    qn, qr, _ = mla_ops.project(p, MLA, x, pos, cq)
+    q = jnp.concatenate([qn, qr], axis=-1)
+    out = np.zeros(x.shape, np.float32)
+    sets = np.zeros((B, S, cache["latent"].shape[1]), bool)
+    for b in range(B):
+        for i in range(S):
+            reach = int(pos[b, i]) + 1
+            row = mla_ops.index_scores(
+                qi[b, i][None], w[b, i][None],
+                cache["index_k"][slots[b], :reach])[0]
+            idx = jax.lax.top_k(row, min(TOPK, reach))[1]
+            sets[b, i, np.asarray(idx)] = True
+            k, v = mla_ops.expand(p, MLA, cache["latent"][slots[b], idx])
+            prob = jax.nn.softmax(jnp.einsum(
+                "hd,khd->hk", q[b, i], k) * MLA.softmax_scale, axis=-1)
+            out[b, i] = mla_ops._out(
+                p, MLA, jnp.einsum("hk,khd->hd", prob, v))
+    return out, sets
+
+
+@pytest.mark.parametrize("steps", ["one_step_a_slot", "steps_of_two_blocks"])
+@pytest.mark.parametrize("case", sorted(EXTENSIONS))
+def test_an_extensions_sets_and_outputs_are_the_plain_forms(
+        case, steps, monkeypatch):
+    """What the hazards name: a reach within the set (all in reach, whatever
+    fills the set up is past it), one position past it, many steps of the
+    walk, ties across the cut (the earlier position), padding sessions and
+    padding positions (every row must keep some key), and two reaches far
+    apart in one batch (each row's mask inside ITS slot and ITS reach)."""
+    sessions, tied = EXTENSIONS[case]
+    B = len(sessions)
+    rows = B * 4 * MLA.heads
+    if steps == "steps_of_two_blocks":  # float32 scores of 16 positions
+        monkeypatch.setattr(mla_ops, "_WALK_SCORE_BYTES", 4 * rows * 16)
+    wide = mla_ops._walk_block(176, 8, rows)
+    assert wide == (176 if steps == "one_step_a_slot" else 16)
+    p = mixer()
+    cache = {"latent": normal(21, 4, 176, MLA.latent),
+             "index_k": normal(22, 4, 176, MLA.index_dim)}
+    if tied:        # all of a slot's keys but a few are one key
+        cache["index_k"] = cache["index_k"].at[1:3, tied[0]:tied[1]].set(
+            cache["index_k"][1, tied[0]])
+    slots = jnp.array([s for s, _, _ in sessions], jnp.int32)
+    pos = jnp.array([[at + i for i in range(4)] for _, at, _ in sessions],
+                    jnp.int32)
+    x = normal(23, B, 4, 64)
+    longest = max(at + 4 for _, at, _ in sessions)
+    out, after, blocks = mla_ops.extend_indexed(
+        p, MLA, x, pos, cache, slots, jnp.int32(-(-longest // 8)), 8)
+    assert int(blocks) == -(-longest // 8)
+    # the new positions' latents and keys are written where they belong
+    cq = mla_ops.compress_q(p, MLA, x)
+    qi, ki, w = mla_ops.project_index(p, MLA, x, cq, pos)
+    latent = mla_ops.project(p, MLA, x, pos, cq)[2]
+    real = [b for b, (slot, _, _) in enumerate(sessions) if slot != SCRATCH]
+    for b in real:
+        at = sessions[b][1]
+        close(after["index_k"][slots[b], at:at + 4], ki[b], 1e-6)
+        close(after["latent"][slots[b], at:at + 4], latent[b], 1e-6)
+    want, want_sets = plain_extension(p, x, pos, after, slots)
+    got_sets = np.asarray(mla_ops.extension_sets(
+        MLA, qi, w, after["index_k"], slots, pos, -(-longest // wide), wide))
+    in_reach = np.asarray(pos)[..., None] >= np.arange(176)
+    for b in real:
+        assert (got_sets[b] & in_reach[b] == want_sets[b]).all(), b
+        assert (want_sets[b].sum(axis=1)
+                == np.minimum(np.asarray(pos[b]) + 1, TOPK)).all()
+        close(out[b], want[b])
+    if tied:        # the cut falls among the tied positions of every row
+        tied_kept = want_sets[0, :, tied[0]:tied[1]].sum(axis=1)
+        assert ((tied_kept > 0) & (tied_kept < tied[1] - tied[0])).all()
+        first = want_sets[0, 0, tied[0]:tied[1]]
+        assert first[:int(first.sum())].all()       # the earliest of them
+
+
+def test_an_extensions_loops_step_as_wide_as_the_slot_divides():
+    """The cell's 66 blocks of 512 against 16 rows x 64 heads: three blocks
+    a step; a slot of a prime number of blocks, or rows whose scores against
+    one block already pass the budget: a block a step."""
+    assert mla_ops._walk_block(33792, 512, 16 * 64) == 1536
+    assert mla_ops._walk_block(13 * 16, 16, 32) == 13 * 16
+    assert mla_ops._walk_block(13 * 512, 512, 16 * 64) == 512
+    assert mla_ops._walk_block(33792, 512, 1 << 20) == 512
 
 
 def test_a_chunk_whose_reach_is_within_the_set_scores_nothing_and_still_writes_its_keys(ref):
@@ -536,6 +646,8 @@ def test_a_program_that_selects_otherwise_misses_the_reference(
 
 
 def test_the_model_counts_what_the_index_scanned_and_gathered():
+    """``extend_latents_gathered`` keeps its name from the form that
+    gathered: the latents each new position SELECTED, a layer."""
     spec = small_spec()
     items = BiMap.from_vocab([f"i{r}" for r in range(N_ITEMS)])
     model = SeqStackModel(spec, seeded_params(spec), items, SHAPE)
@@ -567,6 +679,9 @@ def test_the_model_counts_what_the_index_scanned_and_gathered():
     # positions 100, 101 (12 each) and 10, 11 (11 and 12 in reach)
     assert new["extend_index_sparse_rows"] == 2
     assert new["extend_latents_gathered"] == 12 + 12 + 11 + 12
-    # an indexed stack walks no block of latents
+    # the blocks of latents the walk read are the blocks of index keys the
+    # scorer scanned (above): an indexed stack counts them once, there
+    # (tests/benchmarks/test_glm_cell.py holds this one to 0)
     assert new["extend_latent_blocks_attended"] == 0
+    assert new["extend_latent_blocks_own"] == 0
     assert after["block_index_blocks"] == 0

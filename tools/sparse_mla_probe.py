@@ -14,8 +14,15 @@ noise), called alone, no server, no stack around it:
 each at the chunk offsets given (``--offsets``, default 2048,8192,32256), the
 device's time a call (fifty calls follow each other unwaited). Beside them the
 two selections alone on ``[512, W]`` float32 scores (``topk_mask``'s bisection
-against ``jax.lax.top_k``) and the extension batch of 4 x 4 rows, absorbed
-walk (no index) against scored and gathered, at the same reaches.
+against ``jax.lax.top_k``) and the extension batch of 4 x 4 rows at the same
+reaches: ``walk`` (:func:`ops.mla.extend`, no index: every cached latent of
+the slot), ``indexed`` (:func:`ops.mla.extend_indexed`: scored, each row's set
+by bisection, the same walk under each row's mask), ``gathered`` (the form
+``indexed`` replaced at PR 44, kept HERE as its baseline: ``jax.lax.top_k``
+and a gather of each row's 2,048 latents), and ``indexed`` with its loops'
+step set by hand (``--steps``, in blocks of the chunk: what
+``ops.mla._walk_block`` was chosen from) beside ``sets`` (the scorer and the
+bisection alone, ``ops.mla.extension_sets``).
 
 One JSON line a reading, the log in ``chiprun_out/sparse_mla_probe.log``:
 
@@ -23,6 +30,7 @@ One JSON line a reading, the log in ``chiprun_out/sparse_mla_probe.log``:
 """
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -35,10 +43,60 @@ sys.path.insert(0, CHECKOUT)
 ROUNDS = 50
 
 
+def extend_gathered(mla, p, d, x, pos, cache, slots, n_blocks, block):
+    """``ops.mla.extend_indexed`` as PR 43 had it, this probe's baseline
+    only: scores block by block, ``jax.lax.top_k``, each row's selected
+    latents GATHERED, one softmax over them in the absorbed form (no cache
+    write: the probe times reads)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, S, _ = x.shape
+    P = cache["latent"].shape[1]
+    cq = mla.compress_q(p, d, x)
+    qi, _, w = mla.project_index(p, d, x, cq, pos)
+    qn, qr, _ = mla.project(p, d, x, pos, cq)
+    lat_c, idx_c = cache["latent"], cache["index_k"]
+    qi_c = qi.astype(idx_c.dtype)
+
+    def score_block(j, scores):
+        kb = jax.vmap(lambda s: jax.lax.dynamic_slice(
+            idx_c, (s, j * block, 0), (1, block, idx_c.shape[-1]))[0])(slots)
+        k_pos = j * block + jnp.arange(block)
+        s = jnp.where(pos[..., None] >= k_pos,
+                      mla.index_scores(qi_c, w, kb), -jnp.inf)
+        return jax.lax.dynamic_update_slice(scores, s, (0, 0, j * block))
+
+    scores = jax.lax.fori_loop(0, n_blocks, score_block,
+                               jnp.full((B, S, P), -jnp.inf, jnp.float32))
+    vals, idx = jax.lax.top_k(scores, min(d.index_topk, P))
+    w_ukv = p["w_ukv"].reshape(d.kv_rank, d.heads, d.d_nope + d.d_v)
+    q_abs = jnp.einsum("bshd,chd->bshc", qn.astype(w_ukv.dtype),
+                       w_ukv[..., :d.d_nope],
+                       preferred_element_type=jnp.float32)
+    q = jnp.concatenate([q_abs, qr], axis=-1).astype(lat_c.dtype)
+    lat = lat_c[slots[:, None, None], idx]                  # [B, S, K, width]
+    s = jnp.einsum("bshc,bskc->bshk", q, lat[..., :d.latent],
+                   preferred_element_type=jnp.float32) * d.softmax_scale
+    prob = jax.nn.softmax(
+        jnp.where((vals > -jnp.inf)[:, :, None], s, -1e30), axis=-1)
+    o = jnp.einsum("bshk,bskc->bshc", prob.astype(lat.dtype),
+                   lat[..., :d.kv_rank], preferred_element_type=jnp.float32)
+    o = jnp.einsum("bshc,chd->bshd", o.astype(w_ukv.dtype),
+                   w_ukv[..., d.d_nope:], preferred_element_type=jnp.float32)
+    return mla._out(p, d, o)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--offsets", default="")
+    ap.add_argument("--what", default="prefill_chunk,select,extend",
+                    help="the parts to time (all three take nine minutes "
+                         "on the chip, the extension's alone three)")
+    ap.add_argument("--steps", default="",
+                    help="blocks a step of the indexed extension's loops, "
+                         "comma-separated: each timed beside the default")
     args = ap.parse_args()
     if args.tiny:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -104,7 +162,8 @@ def main() -> int:
     forms = {
         "dense": dataclasses.replace(dims, index_heads=0, index_topk=0),
         "masked": dims}
-    for name, d in forms.items():
+    parts = set(args.what.split(","))
+    for name, d in forms.items() if "prefill_chunk" in parts else ():
         if d.has_index:
             fn = jax.jit(lambda x, at, c, d=d: mla.prefill_chunk_indexed(
                 p, d, x, at, c, 1, chunk)[0])
@@ -122,27 +181,68 @@ def main() -> int:
               "lax.top_k": jax.jit(
         lambda s: jax.lax.top_k(s, dims.index_topk)[1])}
     for W in sorted({min(P, -(-P // f // chunk) * chunk)
-                     for f in (8, 4, 2, 1)}):
+                     for f in (8, 4, 2, 1)} if "select" in parts else ()):
         scores = jax.random.normal(jax.random.fold_in(key, W), (chunk, W),
                                    jnp.float32)
         for name, fn in select.items():
             say(what="select", form=name, width=W, ms=timed(fn, scores))
 
+    if "extend" not in parts:
+        return 0
     B, S = 4, 4
     xs = noise(jax.random.fold_in(key, 4), (B, S, dims.dim)).astype(
         jnp.float32)
     slots = jnp.array([0, 1, 0, 1], jnp.int32)
-    walk = jax.jit(lambda x, pos, nb: mla.extend(
-        p, forms["dense"], x, pos, cache["latent"], slots, nb, chunk)[0])
-    pick = jax.jit(lambda x, pos, nb: mla.extend_indexed(
-        p, dims, x, pos, cache, slots, nb, chunk)[0])
+    rows = B * S * dims.heads
+
+    @contextlib.contextmanager
+    def allowed(budget):
+        """``budget`` bytes of scores a step, while a form is traced."""
+        held, mla._WALK_SCORE_BYTES = mla._WALK_SCORE_BYTES, budget
+        try:
+            yield
+        finally:
+            mla._WALK_SCORE_BYTES = held
+
+    def stepping(budget):
+        """The step ``budget`` allows, the indexed extension under it and
+        its sets alone (the scorer and the bisection)."""
+        with allowed(budget):
+            wide = mla._walk_block(P, chunk, rows)
+
+        def indexed(x, pos, nb):
+            with allowed(budget):
+                return mla.extend_indexed(
+                    p, dims, x, pos, cache, slots, nb, chunk)[0]
+
+        def sets(x, pos, nb):
+            cq = mla.compress_q(p, dims, x)
+            qi, _, w = mla.project_index(p, dims, x, cq, pos)
+            return mla.extension_sets(
+                dims, qi.astype(dtype), w, cache["index_k"], slots, pos,
+                (nb * chunk + wide - 1) // wide, wide)
+
+        return wide, jax.jit(indexed), jax.jit(sets)
+
+    wide, indexed, sets = stepping(mla._WALK_SCORE_BYTES)
+    extensions = {
+        "walk": (chunk, jax.jit(lambda x, pos, nb: mla.extend(
+            p, forms["dense"], x, pos, cache["latent"], slots, nb,
+            chunk)[0])),
+        "gathered": (chunk, jax.jit(lambda x, pos, nb: extend_gathered(
+            mla, p, dims, x, pos, cache, slots, nb, chunk))),
+        "indexed": (wide, indexed), "sets": (wide, sets)}
+    for m in (int(m) for m in args.steps.split(",") if m):
+        wide, indexed, sets = stepping(4 * rows * chunk * m)
+        extensions.update({f"indexed@{wide}": (wide, indexed),
+                           f"sets@{wide}": (wide, sets)})
     for at in offsets:
         pos = at + jnp.arange(S, dtype=jnp.int32)[None] + jnp.zeros(
             (B, 1), jnp.int32)
         nb = jnp.int32(-(-(at + S) // chunk))
-        say(what="extend", form="walk", reach=at, ms=timed(walk, xs, pos, nb))
-        say(what="extend", form="indexed", reach=at,
-            ms=timed(pick, xs, pos, nb))
+        for name, (step, fn) in extensions.items():
+            say(what="extend", form=name, reach=at, step=step,
+                ms=timed(fn, xs, pos, nb))
     return 0
 
 
