@@ -27,11 +27,17 @@ Semantics are pinned to the XLA reference (tests/test_pallas_kernels.py,
         recomputing tile logits with the SAME cdt rounding as fwd
         (bf16 divide before the f32 cast — a different rounding here
         would reconstruct probabilities inconsistent with the saved
-        LSEs, the r5-review grad-bias hazard). Two grid passes: du
-        accumulates over column tiles (inner axis), dv over row tiles
-        — the standard flash split, costing one extra tile-logits
-        recompute (2*B^2*D flops) instead of non-consecutive output
-        revisits.
+        LSEs, the r5-review grad-bias hazard). ONE grid pass, rows
+        outer (``flash_ce_bwd``): a tile's logits, masks, two
+        exponentials and cdt cast are made once and feed both products;
+        du accumulates over column tiles in its (br, D) block, dv in an
+        output that stays RESIDENT in VMEM for the whole grid
+        ([Sc, bc, D], slice j) and is written back once. Where that
+        array does not fit (``backward_form``: a rule of the shapes
+        alone) the standard flash split stands in: two passes
+        (``flash_ce_bwd_du``, ``flash_ce_bwd_dv``), each rebuilding the
+        tile, dv's with columns outer. Both give the same du and dv
+        bit for bit: a block receives the same tiles in the same order.
 
 NON-DIFFERENTIABLE BY CONSTRUCTION: ``u_idx`` / ``i_idx`` / ``weight``
 are closed over by the factory, not traced arguments of the returned
@@ -60,6 +66,16 @@ from jax.experimental.pallas import tpu as pltpu
 #: shapes degenerate — selection falls back
 MIN_BATCH = 128
 
+#: the share of a core's VMEM the one-pass backward may fill (the rest is
+#: the compiler's: spills, semaphores, what it keeps of its own). On a v5e
+#: the one-pass form took 0.63-0.67 of the two-pass form's time at every
+#: shape tried up to this share (tools/flash_ce_probe.py; PERF.md section 6,
+#: PR 45), so the rule asks only whether dv fits
+_VMEM_SHARE = 0.75
+#: on top of what the one-pass backward counts for itself, as the other
+#: kernels of this package leave the compiler
+_VMEM_HEADROOM = 8 << 20
+
 
 def pick_block(B: int) -> int:
     """Largest square tile (rows == cols) that keeps a few grid steps:
@@ -68,6 +84,37 @@ def pick_block(B: int) -> int:
         if B >= t:
             return t
     return 8
+
+
+def _vmem_bytes() -> int:
+    """One core's VMEM: the chip's own where this process has one, a
+    v5e's elsewhere (the interpreter's stand-in, and what a compile for a
+    described v5e is held to)."""
+    if jax.default_backend() == "tpu":
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    return 128 << 20
+
+
+def one_pass_vmem_bytes(B: int, D: int, block=None) -> int:
+    """What the one-pass backward holds in VMEM at batch ``B`` and tower
+    width ``D``: the resident dv ONCE (an output block under a constant
+    index map is given one buffer), two buffers each of the u and v tiles,
+    of du's block and of the four (b, 1) row operands (a whole lane tile
+    wide in VMEM), and the tile's float32 intermediates (logits, two
+    exponentials, coefficient, its cast, its transpose). The compiler's
+    own count at 8192 x 128, without the intermediates: 7.64 MiB."""
+    b = int(block or pick_block(B))
+    Bp = -(-B // b) * b
+    lanes = -(-D // 128) * 128
+    return ((Bp + 2 * 3 * b) * lanes + 2 * 4 * b * 128 + 6 * b * b) * 4
+
+
+def backward_form(B: int, D: int, block=None) -> str:
+    """``"one_pass"`` where the whole dv can stay in VMEM beside the
+    tile's working set, ``"two_pass"`` beyond: a rule of the shapes and
+    the chip's VMEM, of which the kernel may fill ``_VMEM_SHARE``."""
+    fits = one_pass_vmem_bytes(B, D, block) <= _VMEM_SHARE * _vmem_bytes()
+    return "one_pass" if fits else "two_pass"
 
 
 def _pad_rows(a, Bp: int):
@@ -137,44 +184,75 @@ def _bwd_coef(i, j, br, bc, L, lse_ui, lse_iu, uir, uic, iir, iic, wr, wc,
     return (wr * (p_ui - isdiag) + wc * (p_iu - isdiag)) * scale
 
 
-def _bwd_du_kernel(scale_ref, u_ref, v_ref, uir_ref, uic_ref, iir_ref,
-                   iic_ref, wr_ref, wc_ref, lse_ui_ref, lse_iu_ref, du_ref,
-                   *, temp, cdt, br, bc):
-    i, j = pl.program_id(0), pl.program_id(1)
+def _tile_cc(i, j, scale_ref, u_ref, v_ref, masks, temp, cdt, br, bc):
+    """Tile (i, j)'s coefficient as both gradient products take it:
+    logits on the MXU, the reconstruction, the cdt cast. ``masks``: the
+    eight refs after v in the backward calls' argument order."""
+    uir, uic, iir, iic, wr, wc, lse_ui, lse_iu = (r[...] for r in masks)
     L = _tile_logits(u_ref, v_ref, temp, cdt)
-    coef = _bwd_coef(i, j, br, bc, L, lse_ui_ref[...], lse_iu_ref[...],
-                     uir_ref[...], uic_ref[...], iir_ref[...], iic_ref[...],
-                     wr_ref[...], wc_ref[...], scale_ref[0, 0])
-    cc = coef.astype(cdt)
+    coef = _bwd_coef(i, j, br, bc, L, lse_ui, lse_iu, uir, uic, iir, iic,
+                     wr, wc, scale_ref[0, 0])
+    return coef.astype(cdt)
+
+
+def _du_tile(cc, v_ref, cdt):
+    return jax.lax.dot_general(
+        cc, v_ref[...].astype(cdt), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _dv_tile(cc, u_ref, cdt):
+    return jax.lax.dot_general(
+        cc, u_ref[...].astype(cdt), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _bwd_kernel(scale_ref, u_ref, v_ref, *rest, temp, cdt, br, bc):
+    """One pass, rows outer: the tile's coefficient once, both products.
+    ``dv_ref`` is the WHOLE [Sc, bc, D] gradient, resident over the grid:
+    slice j takes its row tiles in the order i = 0 .. Sr-1, which is the
+    order ``_bwd_dv_kernel``'s inner axis gives them."""
+    *masks, du_ref, dv_ref = rest
+    i, j = pl.program_id(0), pl.program_id(1)
+    cc = _tile_cc(i, j, scale_ref, u_ref, v_ref, masks, temp, cdt, br, bc)
 
     @pl.when(j == 0)
     def _():
         du_ref[...] = jnp.zeros_like(du_ref)
 
-    du_ref[...] += jax.lax.dot_general(
-        cc, v_ref[...].astype(cdt), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    du_ref[...] += _du_tile(cc, v_ref, cdt)
+
+    @pl.when(i == 0)
+    def _():
+        dv_ref[j] = jnp.zeros(dv_ref.shape[1:], dv_ref.dtype)
+
+    dv_ref[j] += _dv_tile(cc, u_ref, cdt)
 
 
-def _bwd_dv_kernel(scale_ref, u_ref, v_ref, uir_ref, uic_ref, iir_ref,
-                   iic_ref, wr_ref, wc_ref, lse_ui_ref, lse_iu_ref, dv_ref,
-                   *, temp, cdt, br, bc):
+def _bwd_du_kernel(scale_ref, u_ref, v_ref, *rest, temp, cdt, br, bc):
+    *masks, du_ref = rest
+    i, j = pl.program_id(0), pl.program_id(1)
+    cc = _tile_cc(i, j, scale_ref, u_ref, v_ref, masks, temp, cdt, br, bc)
+
+    @pl.when(j == 0)
+    def _():
+        du_ref[...] = jnp.zeros_like(du_ref)
+
+    du_ref[...] += _du_tile(cc, v_ref, cdt)
+
+
+def _bwd_dv_kernel(scale_ref, u_ref, v_ref, *rest, temp, cdt, br, bc):
     # transposed grid: columns outer, rows inner, so dv's block is
     # constant over the inner axis and accumulates in VMEM
+    *masks, dv_ref = rest
     j, i = pl.program_id(0), pl.program_id(1)
-    L = _tile_logits(u_ref, v_ref, temp, cdt)
-    coef = _bwd_coef(i, j, br, bc, L, lse_ui_ref[...], lse_iu_ref[...],
-                     uir_ref[...], uic_ref[...], iir_ref[...], iic_ref[...],
-                     wr_ref[...], wc_ref[...], scale_ref[0, 0])
-    cc = coef.astype(cdt)
+    cc = _tile_cc(i, j, scale_ref, u_ref, v_ref, masks, temp, cdt, br, bc)
 
     @pl.when(i == 0)
     def _():
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    dv_ref[...] += jax.lax.dot_general(
-        cc, u_ref[...].astype(cdt), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    dv_ref[...] += _dv_tile(cc, u_ref, cdt)
 
 
 def _row_spec(br, rowmajor=True):
@@ -257,21 +335,20 @@ def make_flash_ce(u_idx, i_idx, weight, temp, cdt, B,
                       + jnp.sum((lse_iu - d) * w_pad)) / wsum
         return loss, lse_ui, lse_iu
 
-    def _bwd_call(kernel_fn, rowmajor, out_len, scale, up, vp, lse_ui2,
-                  lse_iu2, D):
-        kernel = functools.partial(kernel_fn, temp=temp, cdt=cdt,
-                                   br=br, bc=bc)
+    def _bwd_call(kernel_fn, name, rowmajor, outs, args, vmem_limit=None):
+        """One backward kernel over the tile grid (rows outer where
+        ``rowmajor``). ``outs``: (block, index map, array shape) an
+        output."""
+        D = args[1].shape[1]
         vm = pltpu.VMEM
         if rowmajor:
             u_map, v_map = (lambda i, j: (i, 0)), (lambda i, j: (j, 0))
-            out_map = lambda i, j: (i, 0)
             grid = (Sr, Sc)
         else:
             u_map, v_map = (lambda j, i: (i, 0)), (lambda j, i: (j, 0))
-            out_map = lambda j, i: (j, 0)
             grid = (Sc, Sr)
         return pl.pallas_call(
-            kernel,
+            functools.partial(kernel_fn, temp=temp, cdt=cdt, br=br, bc=bc),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1), lambda *_: (0, 0),
@@ -281,11 +358,15 @@ def make_flash_ce(u_idx, i_idx, weight, temp, cdt, B,
                 *_mask_specs(rowmajor),
                 _row_spec(br, rowmajor), _col_spec(bc, rowmajor),
             ],
-            out_specs=pl.BlockSpec((out_len, D), out_map, memory_space=vm),
-            out_shape=jax.ShapeDtypeStruct((Bp, D), f32),
+            out_specs=[pl.BlockSpec(block, index_map, memory_space=vm)
+                       for block, index_map, _ in outs],
+            out_shape=[jax.ShapeDtypeStruct(shape, f32)
+                       for _, _, shape in outs],
+            compiler_params=(pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit) if vmem_limit else None),
             interpret=interpret,
-            name="flash_ce_bwd_du" if rowmajor else "flash_ce_bwd_dv",
-        )(scale, up, vp, uir, uic, iir, iic, wr, wc, lse_ui2, lse_iu2)
+            name=name,
+        )(*args)
 
     @jax.custom_vjp
     def ce(u, v):
@@ -298,14 +379,24 @@ def make_flash_ce(u_idx, i_idx, weight, temp, cdt, B,
     def bwd(res, ct):
         u, v, lse_ui, lse_iu = res
         D = u.shape[1]
-        up, vp = _pad_rows(u, Bp), _pad_rows(v, Bp)
-        lse_ui2 = lse_ui.reshape(Bp, 1)
-        lse_iu2 = lse_iu.reshape(1, Bp)
         scale = (ct / (2.0 * wsum * temp)).astype(f32).reshape(1, 1)
-        du = _bwd_call(_bwd_du_kernel, True, br, scale, up, vp,
-                       lse_ui2, lse_iu2, D)
-        dv = _bwd_call(_bwd_dv_kernel, False, bc, scale, up, vp,
-                       lse_ui2, lse_iu2, D)
+        args = (scale, _pad_rows(u, Bp), _pad_rows(v, Bp), uir, uic, iir,
+                iic, wr, wc, lse_ui.reshape(Bp, 1), lse_iu.reshape(1, Bp))
+        du_out = ((br, D), lambda i, j: (i, 0), (Bp, D))
+        if backward_form(B, D, br) == "one_pass":
+            du, dv = _bwd_call(
+                _bwd_kernel, "flash_ce_bwd", True,
+                # dv whole under a constant index map: it stays in VMEM
+                # for the grid and is written back once
+                [du_out, ((Sc, bc, D), lambda i, j: (0, 0, 0), (Sc, bc, D))],
+                args,
+                vmem_limit=one_pass_vmem_bytes(B, D, br) + _VMEM_HEADROOM)
+            dv = dv.reshape(Bp, D)
+        else:
+            du, = _bwd_call(_bwd_du_kernel, "flash_ce_bwd_du", True,
+                            [du_out], args)
+            dv, = _bwd_call(_bwd_dv_kernel, "flash_ce_bwd_dv", False,
+                            [((bc, D), lambda j, i: (j, 0), (Bp, D))], args)
         return du[:B], dv[:B]
 
     ce.defvjp(fwd, bwd)

@@ -616,6 +616,11 @@ class TwoTowerTrainer:
         #                          discipline, ops/pallas/embed_update.py
 
         plan.update({"flash_ce": ce_on, "flash_ce_reason": ce_why,
+                     # which backward the kernel's own shape rule takes
+                     # ("one_pass" / "two_pass"); None where it is off
+                     "flash_ce_backward": (
+                         _pl_flash.backward_form(self.batch, cfg.dim)
+                         if ce_on else None),
                      "embed_update": emb_on, "embed_update_reason": emb_why})
         jaxmon.record_kernel_plan(plan)
         return plan

@@ -539,13 +539,21 @@ def test_exemplar_carries_served_request_trace_id(memory_storage):
                              "entityId": "u1"}).encode(),
         )
         assert status == 201
-        status, text, headers = get(
-            f"{base}/metrics",
-            headers={"Accept": "application/openmetrics-text"})
-        assert status == 200
-        assert "application/openmetrics-text" in headers["Content-Type"]
-        exemplar_lines = [l for l in text.splitlines()
-                          if f'trace_id="{trace_id}"' in l]
+        # a request's latency is observed AFTER its answer is written
+        # (serving/http.py _instrument's finally), on another thread than
+        # the one that serves the next connection: wait for it, bounded
+        deadline = time.monotonic() + 5.0
+        while True:
+            status, text, headers = get(
+                f"{base}/metrics",
+                headers={"Accept": "application/openmetrics-text"})
+            assert status == 200
+            assert "application/openmetrics-text" in headers["Content-Type"]
+            exemplar_lines = [l for l in text.splitlines()
+                              if f'trace_id="{trace_id}"' in l]
+            if exemplar_lines or time.monotonic() > deadline:
+                break
+            time.sleep(0.02)
         assert exemplar_lines, "no exemplar carrying the request trace id"
         assert all(" # {" in l for l in exemplar_lines)
         # content negotiation: default Accept still gets Prometheus text

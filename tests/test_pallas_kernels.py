@@ -18,7 +18,9 @@ import jax.numpy as jnp
 
 from predictionio_tpu.ops import pallas as plk
 from predictionio_tpu.ops.pallas.embed_update import pallas_rowwise_adagrad
+from predictionio_tpu.ops.pallas import flash_ce as flash_ce_mod
 from predictionio_tpu.ops.pallas.flash_ce import (
+    backward_form,
     make_flash_ce,
     pallas_blockwise_ce,
 )
@@ -108,6 +110,161 @@ def test_flash_ce_ragged_last_tile(B):
     np.testing.assert_allclose(np.asarray(gfv), np.asarray(gdv),
                                rtol=1e-4, atol=1e-6)
     assert gfu.shape == (B, D) and gfv.shape == (B, D)
+
+
+def _tilewise_grads(u, v, u_idx, i_idx, w, temp, cdt, b):
+    """Flash-CE's gradients as plain ``jax.numpy``, a Python loop over the
+    tiles: the kernels' roundings (cdt logits, cdt divide, cdt coefficient,
+    float32 accumulation) and their order (du[i] over j, dv[j] over i),
+    none of their code."""
+    f32 = jnp.float32
+    B = u.shape[0]
+    Bp = -(-B // b) * b
+    S = Bp // b
+    u, v, ui, ii, w = (jnp.pad(a, [(0, Bp - B)] + [(0, 0)] * (a.ndim - 1))
+                       .reshape(S, b, *a.shape[1:])
+                       for a in (u, v, u_idx, i_idx, w))
+    rows = jnp.arange(Bp).reshape(S, b)
+
+    def tile(i, j):
+        L = jax.lax.dot_general(
+            u[i].astype(cdt), v[j].astype(cdt), (((1,), (1,)), ((), ())),
+            preferred_element_type=f32).astype(cdt)
+        L = (L / temp).astype(f32)
+        off = rows[i][:, None] != rows[j][None, :]
+        ban_ui = ((ii[j][None, :] == ii[i][:, None])
+                  | (w[j][None, :] <= 0.0)) & off
+        ban_iu = ((ui[i][:, None] == ui[j][None, :])
+                  | (w[i][:, None] <= 0.0)) & off
+        return L, off, ban_ui, ban_iu
+
+    # each tile's step under jit, as the interpreter runs a kernel's body:
+    # XLA on the CPU may keep more than cdt between two fused operations,
+    # and an eager loop would round where it does not
+    @jax.jit
+    def sums(i, j, sum_ui_i):
+        L, _, ban_ui, ban_iu = tile(i, j)
+        e = jnp.exp(L)
+        return (sum_ui_i + jnp.sum(jnp.where(ban_ui, 0.0, e), axis=1),
+                jnp.sum(jnp.where(ban_iu, 0.0, e), axis=0))
+
+    @jax.jit
+    def step(i, j, lse_ui_i, lse_iu_j, du_i, dv_j):
+        L, off, ban_ui, ban_iu = tile(i, j)
+        p_ui = jnp.where(ban_ui, 0.0, jnp.exp(L - lse_ui_i[:, None]))
+        p_iu = jnp.where(ban_iu, 0.0, jnp.exp(L - lse_iu_j[None, :]))
+        d = jnp.where(off, 0.0, 1.0)
+        cc = ((w[i][:, None] * (p_ui - d) + w[j][None, :] * (p_iu - d))
+              * scale).astype(cdt)
+        du_i = du_i + jax.lax.dot_general(
+            cc, v[j].astype(cdt), (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        dv_j = dv_j + jax.lax.dot_general(
+            cc, u[i].astype(cdt), (((0,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        real = (w[i][:, None] > 0) & (w[j][None, :] > 0)
+        return du_i, dv_j, (ban_ui & real).any() & (ban_iu & real).any()
+
+    sum_ui = [jnp.zeros((b,), f32) for _ in range(S)]
+    iu = [[None] * S for _ in range(S)]
+    for i in range(S):
+        for j in range(S):
+            sum_ui[i], iu[i][j] = sums(i, j, sum_ui[i])
+    lse_ui = [jnp.log(s_) for s_ in sum_ui]
+    lse_iu = [jnp.log(jnp.sum(jnp.stack([iu[i][j] for i in range(S)]),
+                              axis=0)) for j in range(S)]
+    scale = (1.0 / (2.0 * jnp.maximum(w.sum(), 1e-8) * temp)).astype(f32)
+    du = [jnp.zeros(u.shape[1:], f32) for _ in range(S)]
+    dv = [jnp.zeros(v.shape[1:], f32) for _ in range(S)]
+    banned = {"diagonal": False, "off": False}
+    for i in range(S):
+        for j in range(S):
+            du[i], dv[j], any_banned = step(i, j, lse_ui[i], lse_iu[j],
+                                            du[i], dv[j])
+            banned["diagonal" if i == j else "off"] |= bool(any_banned)
+    return (np.concatenate(du)[:B], np.concatenate(dv)[:B], banned)
+
+
+@pytest.mark.parametrize("B", [256, 200, 130])
+@pytest.mark.parametrize("uniform_w", [True, False],
+                         ids=["uniform", "ragged_w"])
+@pytest.mark.parametrize("cdt_name", ["bfloat16", "float32"])
+def test_flash_ce_one_pass_backward_is_the_tilewise_form(cdt_name, uniform_w,
+                                                         B):
+    """The one-pass backward's du and dv against the tile-by-tile form:
+    same roundings, same accumulation order. Duplicate users and items
+    ban pairs inside diagonal tiles and off them; 200 and 130 leave a
+    ragged last tile."""
+    D, block = 16, 64
+    u, v, u_idx, i_idx, w = _batch(B, D, seed=B, uniform_w=uniform_w)
+    cdt = jnp.dtype(cdt_name)
+    assert backward_form(B, D, block) == "one_pass"
+    flash = make_flash_ce(u_idx, i_idx, w, 0.07, cdt, B,
+                          interpret=True, block=block)
+    du, dv = jax.grad(flash, argnums=(0, 1))(u, v)
+    ref_du, ref_dv, banned = _tilewise_grads(u, v, u_idx, i_idx, w, 0.07,
+                                             cdt, block)
+    assert banned == {"diagonal": True, "off": True}
+    # the interpreter may reassociate a reduction: 1e-6 of the largest entry
+    for got, ref in ((du, ref_du), (dv, ref_dv)):
+        np.testing.assert_allclose(np.asarray(got), ref, rtol=0,
+                                   atol=1e-6 * np.abs(ref).max())
+
+
+def _pallas_calls(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _pallas_calls(sub)
+    return n
+
+
+@pytest.mark.parametrize("B", [256, 200])
+@pytest.mark.parametrize("cdt_name", ["bfloat16", "float32"])
+def test_flash_ce_backward_form_follows_the_shape(monkeypatch, cdt_name, B):
+    """Two kernels a step where the whole dv fits the VMEM budget, three
+    past it; the choice moves neither gradient."""
+    D, block = 16, 64
+    u, v, u_idx, i_idx, w = _batch(B, D, seed=6, uniform_w=False)
+    cdt = jnp.dtype(cdt_name)
+
+    def grads():
+        flash = make_flash_ce(u_idx, i_idx, w, 0.07, cdt, B,
+                              interpret=True, block=block)
+        vg = jax.value_and_grad(flash, argnums=(0, 1))
+        return _pallas_calls(jax.make_jaxpr(vg)(u, v).jaxpr), vg(u, v)
+
+    n_one, (l_one, g_one) = grads()
+    # a chip too small to hold this dv beside a tile's working set
+    monkeypatch.setattr(flash_ce_mod, "_vmem_bytes", lambda: 1 << 16)
+    assert backward_form(B, D, block) == "two_pass"
+    n_two, (l_two, g_two) = grads()
+    assert (n_one, n_two) == (2, 3)
+    assert float(l_one) == float(l_two)
+    for one, two in zip(g_one, g_two):
+        one, two = np.asarray(one), np.asarray(two)
+        if cdt == jnp.float32:
+            assert np.array_equal(one, two)
+        else:
+            # XLA on the CPU keeps more than bfloat16 where it fuses, and
+            # the two bodies fuse apart: a few entries an ulp of float32
+            # off under the interpreter (the chip's kernels round as
+            # written: tools/flash_ce_probe.py compares them bit for bit)
+            np.testing.assert_allclose(one, two, rtol=0,
+                                       atol=1e-6 * np.abs(two).max())
+
+
+@pytest.mark.parametrize("B,D,form", [
+    (8192, 128, "one_pass"),     # the stretch cell: dv is 4 MiB
+    (4096, 64, "one_pass"),
+    (65536, 256, "one_pass"),    # 64 MiB resident of a v5e's 128
+    (81920, 256, "one_pass"),    # 80 MiB: the largest the probe ran
+    (98304, 256, "two_pass"),    # 96 MiB and the tiles: past three quarters
+    (262144, 128, "two_pass"),
+])
+def test_flash_ce_backward_form_at_real_shapes(B, D, form):
+    assert backward_form(B, D) == form
 
 
 def test_flash_ce_one_call_form_jits():
@@ -218,6 +375,32 @@ def test_trainer_kernel_plan_ineligible_falls_back():
                        flash_ce_kernel="on"))
     assert small.kernel_plan["flash_ce"] is False
     assert "batch" in small.kernel_plan["flash_ce_reason"]
+
+
+@pytest.mark.parametrize("flag,batch,tight,form", [
+    ("on", 256, False, "one_pass"),
+    ("on", 256, True, "two_pass"),     # a chip too small for this dv
+    ("off", 256, False, None),
+    ("on", 64, False, None),           # ineligible: below MIN_BATCH
+], ids=["on", "on_past_the_budget", "off", "ineligible"])
+def test_kernel_plan_and_train_report_name_the_backward(monkeypatch, flag,
+                                                        batch, tight, form):
+    """``flash_ce_backward`` says which backward a capture came from, by
+    the kernel's own shape rule; the train report carries the plan."""
+    from predictionio_tpu.obs import jaxmon
+
+    if tight:
+        monkeypatch.setattr(flash_ce_mod, "_vmem_bytes", lambda: 1 << 16)
+    u, i, n_users, n_items = _positives(n=300)
+    cfg = TwoTowerConfig(dim=8, epochs=1, batch_size=batch, seed=3,
+                         compute_dtype="float32", flash_ce_kernel=flag)
+    tr = TwoTowerTrainer((u, i, None), n_users, n_items, cfg)
+    assert tr.kernel_plan["flash_ce"] is (form is not None)
+    assert tr.kernel_plan["flash_ce_backward"] == form
+    monkeypatch.setattr(jaxmon, "TRAINER_REPORTS", {})
+    tr.run()
+    report = jaxmon.TRAINER_REPORTS["twotower"]
+    assert report["kernel_plan"]["flash_ce_backward"] == form
 
 
 def test_trainer_kernels_end_to_end_match_xla():

@@ -60,8 +60,10 @@ def _flash_ce(B, D):
         return jax.value_and_grad(ce, argnums=(0, 1))(u, v)
 
     f32, i32 = jnp.float32, jnp.int32
+    # forward and ONE backward where dv stays in VMEM, two past that
+    n_kernels = 2 if flash_ce.backward_form(B, D) == "one_pass" else 3
     return fwd_bwd, [((B, D), f32), ((B, D), f32), ((B,), i32), ((B,), i32),
-                     ((B,), f32)], 3
+                     ((B,), f32)], n_kernels
 
 
 def _topk_dot(n_items, D, B, k=16, n_excl=8):
@@ -107,6 +109,10 @@ def _expert_groups(T, dim, expert_dim, n, top_k):
 @pytest.mark.parametrize("build,args", [
     (_flash_ce, (8192, 128)),
     (_flash_ce, (4096, 64)),
+    # a dv of 64 MiB that the one-pass backward keeps resident, and a
+    # batch past the budget: the two-pass split
+    (_flash_ce, (65_536, 256)),
+    (_flash_ce, (131_072, 256)),
     (_topk_dot, (26_744, 64, 1)),
     (_topk_dot, (26_744, 64, 32)),
     (_topk_dot, (1_000_000, 128, 1)),
@@ -140,7 +146,8 @@ def _expert_groups(T, dim, expert_dim, n, top_k):
     (_expert_groups, (512, 7168, 2048, 12, 8)),
     (_topk_dot, (20_480, 7168, 1, 16, 1)),
     (_topk_dot, (20_480, 7168, 4, 16, 1)),
-], ids=["flash_ce-8192x128", "flash_ce-4096x64", "topk_dot-26744x64-B1",
+], ids=["flash_ce-8192x128", "flash_ce-4096x64", "flash_ce-65536x256",
+        "flash_ce-131072x256", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
         "embed_update-1M-8192x128", "topk_dot-16384x6144-B1",
         "topk_dot-16384x6144-B8", "topk_dot-9400000x64-B16",
@@ -170,11 +177,14 @@ def _kernel_instructions(text):
 
 @pytest.mark.parametrize("build,args,names", [
     (_topk_dot, (26_744, 64, 1), ["topk_dot"]),
-    (_flash_ce, (4096, 64),
+    # the stretch cell's shape: exactly two kernels a step
+    (_flash_ce, (8192, 128), ["flash_ce_fwd", "flash_ce_bwd"]),
+    (_flash_ce, (131_072, 256),
      ["flash_ce_fwd", "flash_ce_bwd_du", "flash_ce_bwd_dv"]),
     (_expert_stream, (32, 2048, 768, 128), ["expert_stream"]),
     (_expert_groups, (512, 2048, 768, 128, 8), ["expert_groups"]),
-], ids=["topk_dot", "flash_ce", "expert_stream", "expert_groups"])
+], ids=["topk_dot", "flash_ce", "flash_ce_two_pass", "expert_stream",
+        "expert_groups"])
 def test_a_kernels_instruction_carries_its_name(one_chip, no_compile_cache,
                                                 build, args, names):
     """A device trace's events are named by the instruction's text: the
