@@ -14,8 +14,8 @@ from __future__ import annotations
 
 import threading
 import time
-from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from dataclasses import asdict, dataclass, field
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -24,6 +24,7 @@ from predictionio_tpu.core.params import Params
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.obs import trace
 from predictionio_tpu.ops.sessionrec import (
+    ServeShape,
     SessionRecConfig,
     SessionRecModelState,
     SessionRecTrainer,
@@ -370,15 +371,61 @@ STEP_PHASES = (
     trace.UNSPANNED)
 
 
+class StepPlan(NamedTuple):
+    """What one step runs, as :func:`plan_step` chose it."""
+
+    answered: List[SeqTicket]       # at admission: delivered, nothing to run
+    extend: List[SeqTicket]         # the one extension batch
+    #: the one block forward's rows ``(ticket, kind, position, n_unmask)``:
+    #: ``"known"`` (a whole block of the history, committed as it is),
+    #: ``"denoise"`` (the block it generates, masked or to open), ``"commit"``
+    block: List[Tuple[SeqTicket, str, int, int]]
+    prefill: Optional[SeqTicket]    # who gets the one chunk, and its tokens
+    prefill_tokens: int
+
+
+def plan_step(tickets: List[SeqTicket], shape, gen=None) -> StepPlan:
+    """A step over the pending ``tickets`` (oldest first), chosen from them,
+    the ``ServeShape`` and the stack's ``Generation`` (None: it answers
+    once) alone: no ticket changes and no device is touched, so the ORDER of
+    a stack's work is decided, and tested, here. Every ticket with at most
+    ``extend_len`` positions left extends, oldest first, ``extend_batch`` a
+    step; in a stack that generates it gives the block forward (``gen_batch``
+    rows) the whole blocks its history still lacks and, behind them, the
+    block it generates. The OLDEST ticket with more left gets the step's one
+    chunk (``chunk`` positions at most): a FIFO of whole prefills."""
+    pending = [t for t in tickets if t.result is None]
+    short = [t for t in pending if t.remaining <= shape.extend_len]
+    pre = next((t for t in pending if t.remaining > shape.extend_len), None)
+    rows = []
+    for t in short if gen else ():
+        at = t.done
+        while at < t.known and len(rows) < shape.gen_batch:
+            rows.append((t, "known", at, 0))
+            at += gen.block_len
+        if at < t.known or len(rows) == shape.gen_batch:
+            continue
+        # a block still to open is all masks behind the history's left-over
+        # items (fewer than a block), and no forward has denoised it
+        denoised = 0 if t.block is None else t.denoised
+        if t.block is None or (t.block == gen.mask_row).any():
+            rows.append((t, "denoise", at, gen.unmask_at_least(denoised)))
+        else:
+            rows.append((t, "commit", at, 0))
+    return StepPlan(
+        [t for t in tickets if t.result is not None],
+        [] if gen else short[:shape.extend_batch], rows,
+        pre, min(pre.remaining, shape.chunk) if pre else 0)
+
+
 class SeqStackModel:
     """A block stack with cached mixers (per-position caches, recurrent
     states, or both in one stack) behind the ``items`` query, served in
-    STEPS: each step runs every pending extension (a few new positions each)
-    as one batch, one block forward over the tickets that generate, and at
-    most one prefill chunk of the oldest session that still has a history to
-    encode. The engine server's step worker drives :meth:`begin` /
-    :meth:`step` through :class:`SeqStackAlgorithm`; :meth:`recommend` drives
-    them to the end for one query (``predict``).
+    STEPS: :func:`plan_step` chooses a step (an extension batch or a block
+    forward, and one prefill chunk) and :meth:`step` runs it. The engine
+    server's step worker drives :meth:`begin` / :meth:`step` through
+    :class:`SeqStackAlgorithm`; :meth:`recommend` drives them to the end for
+    one query (``predict``).
 
     A stack that does not generate answers ``{"items", "num"}`` once, from the
     last position's hidden state through the head (the exact retrieval index over
@@ -393,8 +440,6 @@ class SeqStackModel:
     .init_stack``)."""
 
     def __init__(self, spec, params, item_ids: BiMap, shape=None):
-        from predictionio_tpu.ops.sessionrec import ServeShape
-
         self.spec, self.params, self.item_ids = spec, params, item_ids
         self.shape = shape or ServeShape()
         self.gen = spec.generation
@@ -584,81 +629,83 @@ class SeqStackModel:
 
     def step(self, tickets: List[SeqTicket],
              done=lambda ticket: None) -> List[SeqTicket]:
-        """One step over the pending ``tickets`` (oldest first): every
-        extension (at most ``extend_batch``) or, in a block-diffusion stack,
-        one block forward over the tickets that generate (``gen_batch`` rows
-        at most); then one prefill chunk. Returns the tickets it finished,
-        their ``result`` set (a ticket that was answered at admission among
-        them), possibly none: a slate takes many steps. ``done`` is called
-        with each as soon as it is, so that an answer leaves before the
-        step's prefill chunk begins."""
-        programs, sh = self.programs(), self.shape
-        finished = [t for t in tickets if t.result is not None]
-        for t in finished:                  # answered at admission
+        """One step over the pending ``tickets``: what :func:`plan_step`
+        chose, in this order: extensions, block forward, prefill chunk.
+        Returns the tickets it finished, their ``result`` set (a ticket that
+        was answered at admission among them), possibly none: a slate takes
+        many steps. ``done`` is called with each as soon as it is, so that
+        an answer leaves before the step's prefill chunk begins."""
+        self.programs()
+        plan = plan_step(tickets, self.shape, self.gen)
+        finished = list(plan.answered)
+        for t in finished:
             done(t)
-        pending = [t for t in tickets if t.result is None]
-        if not pending:
+        if not (plan.extend or plan.block or plan.prefill):
             return finished
-        short = [t for t in pending if t.remaining <= sh.extend_len]
-        ext = [] if self.gen else short[:sh.extend_batch]
-        rows = self._block_rows(short) if self.gen else []
-        pre = next((t for t in pending if t.remaining > sh.extend_len), None)
         self.steps += 1
         self._account = trace.thread_account() or self._account
         with trace.device_span(
-                "seq.step", n_extend=len(ext), n_block=len(rows),
-                seq=self.steps,
-                prefill_tokens=(min(pre.remaining, sh.chunk) if pre else 0)):
-            if ext:
-                self._launching(ext, "extend")
-                with trace.device_span("seq.extend", rows=len(ext)):
-                    with trace.device_span("seq.launch", program="extend"):
-                        h, _ = programs.extend(
-                            [(t.rows[t.done:], t.slot, t.done) for t in ext])
-                    with trace.device_span("seq.wait", program="extend"):
-                        h.block_until_ready()
-                reach = sum(len(t.rows) for t in ext)
-                did = {"extend_rows": len(ext)}
-                did.update((counter, n) for kind, counter, n in (
-                    ("mla", "extend_latent_positions", reach),
-                    ("gqa", "extend_kv_positions", reach),
-                    ("mamba2", "extend_state_rows", len(ext)))
-                    if kind in self.kinds)
-                if programs.indexed:
-                    topk = self.spec.mla.index_topk
-                    did["extend_latents_gathered"] = sum(
-                        min(at + 1, topk) for t in ext
-                        for at in range(t.done, len(t.rows)))
-                elif "mla" in self.kinds:
-                    own = [int(programs.n_blocks(t.done + sh.extend_len))
-                           for t in ext]
-                    did.update(
-                        extend_latent_blocks_own=sum(own),
-                        extend_latent_blocks_attended=max(own) * len(ext))
-                self._count("extend", did)
-                for t in ext:
-                    t.done = len(t.rows)
-                self._answer(ext, h, done)
-                finished += ext
-            if rows:
-                finished += self._block_forward(rows, done)
-            if pre is not None:
-                n = min(pre.remaining, sh.chunk)
-                self._launching([pre], "prefill")
-                with trace.device_span("seq.prefill_chunk", slot=pre.slot,
-                                       offset=pre.done, tokens=n):
-                    with trace.device_span("seq.launch", program="prefill"):
-                        h, _ = programs.prefill(
-                            pre.rows[pre.done:pre.done + n], pre.slot,
-                            pre.done)
-                    with trace.device_span("seq.wait", program="prefill"):
-                        h.block_until_ready()
-                self._count("prefill")
-                pre.done += n
-                if pre.remaining == 0 and not self.gen:
-                    self._answer([pre], h, done)
-                    finished.append(pre)
+                "seq.step", n_extend=len(plan.extend),
+                n_block=len(plan.block), seq=self.steps,
+                prefill_tokens=plan.prefill_tokens):
+            if plan.extend:
+                finished += self._run_extend(plan.extend, done)
+            if plan.block:
+                finished += self._block_forward(plan.block, done)
+            if plan.prefill is not None:
+                finished += self._run_prefill(
+                    plan.prefill, plan.prefill_tokens, done)
         return finished
+
+    def _run_extend(self, ext: List[SeqTicket], done) -> List[SeqTicket]:
+        """The new positions of ``ext``, one batch; every one is answered."""
+        programs, sh = self._programs, self.shape
+        self._launching(ext, "extend")
+        with trace.device_span("seq.extend", rows=len(ext)):
+            with trace.device_span("seq.launch", program="extend"):
+                h, _ = programs.extend(
+                    [(t.rows[t.done:], t.slot, t.done) for t in ext])
+            with trace.device_span("seq.wait", program="extend"):
+                h.block_until_ready()
+        reach = sum(len(t.rows) for t in ext)
+        did = {"extend_rows": len(ext)}
+        did.update((counter, n) for kind, counter, n in (
+            ("mla", "extend_latent_positions", reach),
+            ("gqa", "extend_kv_positions", reach),
+            ("mamba2", "extend_state_rows", len(ext)))
+            if kind in self.kinds)
+        if programs.indexed:
+            topk = self.spec.mla.index_topk
+            did["extend_latents_gathered"] = sum(
+                min(at + 1, topk) for t in ext
+                for at in range(t.done, len(t.rows)))
+        elif "mla" in self.kinds:
+            own = [int(programs.n_blocks(t.done + sh.extend_len))
+                   for t in ext]
+            did.update(extend_latent_blocks_own=sum(own),
+                       extend_latent_blocks_attended=max(own) * len(ext))
+        self._count("extend", did)
+        for t in ext:
+            t.done = len(t.rows)
+        self._answer(ext, h, done)
+        return ext
+
+    def _run_prefill(self, pre: SeqTicket, n: int, done) -> List[SeqTicket]:
+        """One chunk: the next ``n`` positions of ``pre``'s history."""
+        self._launching([pre], "prefill")
+        with trace.device_span("seq.prefill_chunk", slot=pre.slot,
+                               offset=pre.done, tokens=n):
+            with trace.device_span("seq.launch", program="prefill"):
+                h, _ = self._programs.prefill(
+                    pre.rows[pre.done:pre.done + n], pre.slot, pre.done)
+            with trace.device_span("seq.wait", program="prefill"):
+                h.block_until_ready()
+        self._count("prefill")
+        pre.done += n
+        if pre.remaining or self.gen:
+            return []
+        self._answer([pre], h, done)
+        return [pre]
 
     def _launching(self, tickets: List[SeqTicket], queue: str) -> None:
         """A program that carries rows of ``tickets`` is about to be
@@ -671,35 +718,18 @@ class SeqStackModel:
                 c[f"{queue}_tickets"] += 1
 
     # -- block diffusion ------------------------------------------------------
-    def _block_rows(self, tickets: List[SeqTicket]) -> list:
-        """The rows of this step's block forward, oldest ticket first:
-        ``(ticket, kind, block ids, position, n_unmask)``. A ticket gives
-        the whole blocks its history still lacks (``"known"``: committed as
-        they are) and, behind them, the block it generates: ``"denoise"``
-        while it holds a mask, ``"commit"`` once."""
-        gen, B = self.gen, self.gen.block_len
-        rows = []
-        for t in tickets:
-            at = t.done
-            while at < t.known and len(rows) < self.shape.gen_batch:
-                rows.append((t, "known", t.rows[at:at + B], at, 0))
-                at += B
-            if at < t.known or len(rows) == self.shape.gen_batch:
-                continue
-            if t.block is None:     # a new block: the history's left-over
-                t.block = np.full(B, gen.mask_row, np.int32)    # items open
-                if at == t.known:                               # the first
-                    t.block[:len(t.rows) - at] = t.rows[at:]
-                t.denoised = 0
-            masked = bool((t.block == gen.mask_row).any())
-            rows.append((t, "denoise" if masked else "commit", t.block, at,
-                         gen.unmask_at_least(t.denoised) if masked else 0))
-        return rows
-
     def _block_forward(self, rows: list, done) -> List[SeqTicket]:
         import jax
 
         gen, B = self.gen, self.gen.block_len
+        for t, kind, at, _ in rows:
+            if kind != "known" and t.block is None:     # a new block: masks,
+                t.block = np.full(B, gen.mask_row, np.int32)
+                if at == t.known:       # behind the history's left-over items
+                    t.block[:len(t.rows) - at] = t.rows[at:]
+                t.denoised = 0
+        rows = [(t, kind, t.rows[at:at + B] if kind == "known" else t.block,
+                 at, n) for t, kind, at, n in rows]
         n_denoise = sum(1 for r in rows if r[1] == "denoise")
         self._launching([r[0] for r in rows], "extend")
         with trace.device_span("seq.block_step", rows=len(rows),
@@ -806,12 +836,8 @@ class SeqStackParams(Params):
     extend_batch: int = 8
     gen_batch: int = 8
 
-    def shape(self):
-        import dataclasses
-
-        from predictionio_tpu.ops.sessionrec import ServeShape
-
-        return ServeShape(**dataclasses.asdict(self))
+    def shape(self) -> ServeShape:
+        return ServeShape(**asdict(self))
 
 
 class SeqStackAlgorithm(Algorithm):

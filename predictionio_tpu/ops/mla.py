@@ -41,8 +41,8 @@ row scores every earlier position with ``I[t, s] = sum_j w[t, j] ReLU(qI[t, j]
 ``qI`` head and of ``kI`` under the same RoPE) and attends ONLY the
 ``min(index_topk, t + 1)`` positions of largest ``I[t, .]`` (ties: the earlier
 position, ``jax.lax.top_k``'s rule). The cache of such a mixer is ``{"latent",
-"index_k"}``; :func:`prefill_chunk_indexed` and :func:`extend_indexed` are the
-two cached paths and :func:`attend_full` the plain form of both. A chunk
+"index_k"}`` (:func:`init_cache`); the same two cached paths serve it, the
+index inside them, and :func:`attend_full` is the plain form of both. A chunk
 whose reach is at most ``index_topk`` attends all of it and scores nothing
 (it still writes its index keys); past that it scores the slot's cached index
 keys block by block, finds each row's exact set (:func:`topk_mask`: the k-th
@@ -52,14 +52,16 @@ blocks under that per-row mask: exact, and no saving over attending all
 instead measured 44.5 ms a layer against 3.0-19.5 at offsets of 2,048-32,256:
 PERF.md section 5). An extension does the same in the absorbed form: its rows
 score their slots' index keys (256 B a position at 128 bfloat16 values), find
-their sets by the same bisection and walk the slots' latents in place, each
-row under its own mask (sorting 16 rows of 33,792 scores and gathering 16 x
-2,048 latents instead took 2.3-2.8 ms a layer where this takes 0.8-1.7 and the
-walk without an index 0.7-1.5: PERF.md section 5).
+their sets by the same bisection (:func:`extension_sets`) and walk the slots'
+latents in place, each row under its own mask, nothing sorted and nothing
+gathered (sorting 16 rows of 33,792 scores and gathering 16 x 2,048 latents
+instead took 2.3-2.8 ms a layer where this takes 0.8-1.7 and the walk
+without an index 0.7-1.5: PERF.md section 5).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 
@@ -213,14 +215,11 @@ def compress_q(p, dims: MLADims, x):
     return cq
 
 
-def project(p, dims: MLADims, x, pos, cq=None):
-    """Queries and latents of the positions ``x`` [..., T, dim]:
-    ``(qN [..., T, H, d_nope], qR [..., T, H, d_rope] after RoPE,
-    latent [..., T, kv_rank + d_rope])``, float32. ``cq``: the queries'
-    latent where the caller has it (:func:`compress_q`)."""
+def project(p, dims: MLADims, x, pos, cq):
+    """Queries and latents of the positions ``x`` [..., T, dim] (``cq``:
+    their :func:`compress_q`): ``(qN [..., T, H, d_nope], qR [..., T, H,
+    d_rope] after RoPE, latent [..., T, kv_rank + d_rope])``, float32."""
     d = dims
-    if cq is None:
-        cq = compress_q(p, d, x)
     q = mm(cq, p["w_uq"]).reshape(x.shape[:-1] + (d.heads, d.d_qk))
     freqs, amp = d.rope_freqs(), d.rope_amplitude
     qn, qr = q[..., :d.d_nope], rope(q[..., d.d_nope:], pos, freqs, amp)
@@ -279,65 +278,6 @@ def attend_full(p, dims: MLADims, x, pos):
         keep = selected_full(dims, index_scores(qi, w, ki), pos)
     return _out(p, dims, mha_reference(q, k, v, causal=True,
                                        scale=dims.softmax_scale, keep=keep))
-
-
-def prefill_chunk(p, dims: MLADims, x, offset, cache, slot, block: int):
-    """A chunk ``x`` [C, dim] of ONE session, at positions ``offset +
-    arange(C)``, against that session's slot of ``cache`` [slots, P, latent].
-    Writes the chunk's latents into the slot, then attends over the slot's
-    blocks up to the chunk's end. ``(out [C, dim] float32, cache)``."""
-    d = dims
-    C = x.shape[0]
-    pos = offset + jnp.arange(C, dtype=jnp.int32)
-    qn, qr, latent = project(p, d, x, pos)
-    cache = jax.lax.dynamic_update_slice(
-        cache, _to_cache(latent, cache)[None], (slot, offset, 0))
-    q = jnp.concatenate([qn, qr], axis=-1).astype(p["w_ukv"].dtype)[None]
-
-    def kv_block(j):
-        lat = jax.lax.dynamic_slice(
-            cache, (slot, j * block, 0), (1, block, cache.shape[-1]))
-        return expand(p, d, lat[..., :d.latent])
-
-    n_blocks = (offset + C + block - 1) // block
-    o = attend_over_blocks(q, pos, kv_block, n_blocks, block, d.d_v,
-                           dtype=jnp.float32, scale=d.softmax_scale)[0]
-    return _out(p, d, o), cache
-
-
-def extend(p, dims: MLADims, x, pos, cache, slots, n_blocks, block: int):
-    """A few new positions of several sessions, absorbed form: ``x``
-    [B, S, dim] at positions ``pos`` [B, S] of the slots ``slots`` [B].
-    Writes their latents, then attends over the cached LATENTS themselves
-    (one shared key/value "head" of ``latent`` / ``kv_rank`` values; the
-    heads are folded into the query axis). ``n_blocks`` (traced) covers the
-    longest session of the batch. ``(out [B, S, dim] float32, cache)``."""
-    d = dims
-    B, S, _ = x.shape
-    qn, qr, latent = project(p, d, x, pos)
-    latent = _to_cache(latent, cache)
-    for b in range(B):
-        cache = jax.lax.dynamic_update_slice(
-            cache, latent[b][None], (slots[b], pos[b, 0], 0))
-    w = p["w_ukv"].reshape(d.kv_rank, d.heads, d.d_nope + d.d_v)
-    q_abs = jnp.einsum("bshd,chd->bshc", qn.astype(w.dtype),
-                       w[..., :d.d_nope],
-                       preferred_element_type=jnp.float32)
-    q = jnp.concatenate([q_abs, qr], axis=-1).astype(cache.dtype)
-    q = q.reshape(B, S * d.heads, 1, d.latent)
-    q_pos = jnp.repeat(pos, d.heads, axis=1)                 # [B, S*H]
-
-    def kv_block(j):
-        lat = jax.vmap(lambda s: jax.lax.dynamic_slice(
-            cache, (s, j * block, 0), (1, block, cache.shape[-1]))[0])(slots)
-        return lat[:, :, None, :d.latent], lat[:, :, None, :d.kv_rank]
-
-    o = attend_over_blocks(q, q_pos, kv_block, n_blocks, block, d.kv_rank,
-                           dtype=jnp.float32, scale=d.softmax_scale)
-    o = o.reshape(B, S, d.heads, d.kv_rank)
-    o = jnp.einsum("bshc,chd->bshd", o.astype(w.dtype), w[..., d.d_nope:],
-                   preferred_element_type=jnp.float32)
-    return _out(p, d, o), cache
 
 
 # ---------------------------------------------------------------------------
@@ -423,32 +363,63 @@ def topk_mask(scores, k: int):
         lambda: above | tie)
 
 
-def prefill_chunk_indexed(p, dims: MLADims, x, offset, cache, slot,
-                          block: int, scope: str = "mla"):
-    """:func:`prefill_chunk` of a mixer with an index: ``cache`` is
-    ``{"latent" [slots, P, width], "index_k" [slots, P, index_dim]}``, ``P`` a
-    whole number of blocks. Writes the chunk's latents and index keys; a
-    chunk that reaches past ``index_topk`` positions then scores its rows
-    against the slot's index keys up to its own end, finds each row's exact
-    set and walks the blocks under that mask. ``(out [C, dim] float32,
-    cache, blocks of index keys scanned)``."""
-    d = dims
-    C, P = x.shape[0], cache["latent"].shape[1]
-    if P % block:
+# ---------------------------------------------------------------------------
+# The cached paths
+# ---------------------------------------------------------------------------
+
+def init_cache(dims: MLADims, slots: int, positions: int, dtype):
+    """What one mixer keeps: ``[slots, positions, cache_width]`` latents, a
+    bare array; under an index ``{"latent": that, "index_k": [slots,
+    positions, index_dim]}`` (the key stands and falls with the latent,
+    position by position, and is a second array because the scorer reads
+    keys alone), ``positions`` then a whole number of attention blocks."""
+    latent = jnp.zeros((slots, positions, cache_width(dims)), dtype)
+    if not dims.has_index:
+        return latent
+    return {"latent": latent,
+            "index_k": jnp.zeros((slots, positions, dims.index_dim), dtype)}
+
+
+def _held(dims: MLADims, cache, block: int):
+    """``(latents, index keys or None)`` of a mixer's cache."""
+    if not dims.has_index:
+        return cache, None
+    if cache["latent"].shape[1] % block:
         raise ValueError("a slot of an indexed cache holds whole blocks")
+    return cache["latent"], cache["index_k"]
+
+
+def _part(dims: MLADims, name: str):
+    """Under an index a mixer's parts have scopes of their own (a trace's
+    readers tell the scorer's time from the attention's); else its caller's."""
+    if dims.has_index:
+        return jax.named_scope(name)
+    return contextlib.nullcontext()
+
+
+def prefill_chunk(p, dims: MLADims, x, offset, cache, slot, block: int,
+                  scope: str = "mla"):
+    """A chunk ``x`` [C, dim] of ONE session, at positions ``offset +
+    arange(C)``, against that session's slot of ``cache`` (:func:`init_cache`).
+    Writes the chunk's latents (and index keys) into the slot, then attends
+    over the slot's blocks up to the chunk's end, under each row's mask where
+    an index's chunk reaches past ``index_topk`` positions. ``(out [C, dim]
+    float32, cache, blocks of index keys scanned: 0 without an index)``."""
+    d = dims
+    C = x.shape[0]
+    lat_c, idx_c = _held(d, cache, block)
     offset = jnp.asarray(offset, jnp.int32)
     pos = offset + jnp.arange(C, dtype=jnp.int32)
     cq = compress_q(p, d, x)
-    with jax.named_scope(scope + ".index"):
-        qi, ki, w = project_index(p, d, x, cq, pos)
+    if d.has_index:
+        with jax.named_scope(scope + ".index"):
+            qi, ki, w = project_index(p, d, x, cq, pos)
     qn, qr, latent = project(p, d, x, pos, cq)
     lat_c = jax.lax.dynamic_update_slice(
-        cache["latent"], _to_cache(latent, cache["latent"])[None],
-        (slot, offset, 0))
-    idx_c = jax.lax.dynamic_update_slice(
-        cache["index_k"], ki.astype(cache["index_k"].dtype)[None],
-        (slot, offset, 0))
-    wdt = p["w_ukv"].dtype
+        lat_c, _to_cache(latent, lat_c)[None], (slot, offset, 0))
+    if d.has_index:
+        idx_c = jax.lax.dynamic_update_slice(
+            idx_c, ki.astype(idx_c.dtype)[None], (slot, offset, 0))
     reach = offset + C
     n_blocks = (reach + block - 1) // block
 
@@ -457,17 +428,19 @@ def prefill_chunk_indexed(p, dims: MLADims, x, offset, cache, slot,
             lat_c, (slot, j * block, 0), (1, block, lat_c.shape[-1]))
         return expand(p, d, lat[..., :d.latent])
 
-    def walk(keep_block=None):
-        q = jnp.concatenate([qn, qr], axis=-1).astype(wdt)[None]
-        return attend_over_blocks(
-            q, pos, kv_block, n_blocks, block, d.d_v, dtype=jnp.float32,
-            scale=d.softmax_scale, keep_block=keep_block)[0]
+    def attend(keep_block=None):
+        with _part(d, scope + ".attend"):
+            q = jnp.concatenate([qn, qr], axis=-1).astype(p["w_ukv"].dtype)
+            return _out(p, d, attend_over_blocks(
+                q[None], pos, kv_block, n_blocks, block, d.d_v,
+                dtype=jnp.float32, scale=d.softmax_scale,
+                keep_block=keep_block)[0])
 
-    def all_in_reach():
-        with jax.named_scope(scope + ".attend"):
-            return _out(p, d, walk())
+    if not d.has_index:
+        return attend(), lat_c, 0
 
     def under_the_mask():
+        P = idx_c.shape[1]
         qi_c = qi.astype(idx_c.dtype)
 
         def score_block(j, scores):
@@ -484,12 +457,11 @@ def prefill_chunk_indexed(p, dims: MLADims, x, offset, cache, slot,
                 jnp.full((C, P), -jnp.inf, jnp.float32))
         with jax.named_scope(scope + ".select"):
             keep = topk_mask(scores, d.index_topk)
-        with jax.named_scope(scope + ".attend"):
-            return _out(p, d, walk(lambda j: jax.lax.dynamic_slice(
-                keep, (0, j * block), (C, block))))
+        return attend(lambda j: jax.lax.dynamic_slice(
+            keep, (0, j * block), (C, block)))
 
     sparse = reach > d.index_topk
-    out = jax.lax.cond(sparse, under_the_mask, all_in_reach)
+    out = jax.lax.cond(sparse, under_the_mask, attend)
     return (out, {"latent": lat_c, "index_k": idx_c},
             jnp.where(sparse, n_blocks, 0).astype(jnp.int32))
 
@@ -549,39 +521,48 @@ def extension_sets(dims: MLADims, qi, w, index_k, slots, pos, n_wide,
                          min(dims.index_topk, P)).reshape(B, S, P)
 
 
-def extend_indexed(p, dims: MLADims, x, pos, cache, slots, n_blocks,
-                   block: int, scope: str = "mla"):
-    """:func:`extend` of a mixer with an index: every row scores its slot's
-    cached index keys as far as the batch's longest reach (``n_blocks``
-    blocks) and finds its exact ``index_topk`` best positions as a MASK
-    (:func:`extension_sets`); then the absorbed walk of :func:`extend` reads
-    the slots' cached latents once, in place, each row attending under its
-    own mask: nothing is sorted and nothing gathered. Scorer and walk step
-    :func:`_walk_block` positions at a time (what a step reads past the
-    batch's reach is past every row's). ``(out [B, S, dim] float32, cache,
-    blocks of index keys in the batch's reach: what a row scans)``."""
+def extend(p, dims: MLADims, x, pos, cache, slots, n_blocks, block: int,
+           scope: str = "mla"):
+    """A few new positions of several sessions, absorbed form: ``x``
+    [B, S, dim] at positions ``pos`` [B, S] of the slots ``slots`` [B] of
+    ``cache`` (:func:`init_cache`). Writes their latents (and index keys),
+    then attends over the cached LATENTS themselves (one shared key/value
+    "head" of ``latent`` / ``kv_rank`` values; the heads are folded into the
+    query axis), ``block`` positions a step; ``n_blocks`` (traced) covers
+    the longest session of the batch. Under an index each row attends under
+    the mask of ITS set, and scorer and walk step :func:`_walk_block`
+    positions (what a step reads past the batch's reach is past every
+    row's). ``(out [B, S, dim] float32, cache, blocks of index keys in the
+    batch's reach, what a row scans: 0 without an index)``."""
     d = dims
     B, S, _ = x.shape
-    P = cache["latent"].shape[1]
-    if P % block:
-        raise ValueError("a slot of an indexed cache holds whole blocks")
+    lat_c, idx_c = _held(d, cache, block)
     cq = compress_q(p, d, x)
-    with jax.named_scope(scope + ".index"):
-        qi, ki, w = project_index(p, d, x, cq, pos)
+    if d.has_index:
+        with jax.named_scope(scope + ".index"):
+            qi, ki, w = project_index(p, d, x, cq, pos)
     qn, qr, latent = project(p, d, x, pos, cq)
-    lat_c, idx_c = cache["latent"], cache["index_k"]
-    latent, ki = _to_cache(latent, lat_c), ki.astype(idx_c.dtype)
+    latent = _to_cache(latent, lat_c)
+    if d.has_index:
+        ki = ki.astype(idx_c.dtype)
     for b in range(B):
         lat_c = jax.lax.dynamic_update_slice(
             lat_c, latent[b][None], (slots[b], pos[b, 0], 0))
-        idx_c = jax.lax.dynamic_update_slice(
-            idx_c, ki[b][None], (slots[b], pos[b, 0], 0))
-    wide = _walk_block(P, block, B * S * d.heads)
-    n_wide = (n_blocks * block + wide - 1) // wide
-    keep = extension_sets(d, qi.astype(idx_c.dtype), w, idx_c, slots, pos,
-                          n_wide, wide, scope)
-    with jax.named_scope(scope + ".attend"):
-        # the absorbed form of :func:`extend`, each row under ITS OWN mask
+        if d.has_index:
+            idx_c = jax.lax.dynamic_update_slice(
+                idx_c, ki[b][None], (slots[b], pos[b, 0], 0))
+    wide, n_wide, keep_block = block, n_blocks, None
+    if d.has_index:
+        wide = _walk_block(idx_c.shape[1], block, B * S * d.heads)
+        n_wide = (n_blocks * block + wide - 1) // wide
+        keep = extension_sets(d, qi.astype(idx_c.dtype), w, idx_c, slots, pos,
+                              n_wide, wide, scope)
+
+        def keep_block(j):              # a row's set, for each of its heads
+            return jnp.repeat(jax.lax.dynamic_slice(
+                keep, (0, 0, j * wide), (B, S, wide)), d.heads, axis=1)
+
+    with _part(d, scope + ".attend"):
         w_ukv = p["w_ukv"].reshape(d.kv_rank, d.heads, d.d_nope + d.d_v)
         q_abs = jnp.einsum("bshd,chd->bshc", qn.astype(w_ukv.dtype),
                            w_ukv[..., :d.d_nope],
@@ -592,10 +573,6 @@ def extend_indexed(p, dims: MLADims, x, pos, cache, slots, n_blocks,
             lat = _of_slots(lat_c, slots, j, wide)
             return lat[:, :, None, :d.latent], lat[:, :, None, :d.kv_rank]
 
-        def keep_block(j):              # a row's set, for each of its heads
-            return jnp.repeat(jax.lax.dynamic_slice(
-                keep, (0, 0, j * wide), (B, S, wide)), d.heads, axis=1)
-
         o = attend_over_blocks(
             q.reshape(B, S * d.heads, 1, d.latent),
             jnp.repeat(pos, d.heads, axis=1), kv_block, n_wide, wide,
@@ -605,4 +582,6 @@ def extend_indexed(p, dims: MLADims, x, pos, cache, slots, n_blocks,
                        w_ukv[..., d.d_nope:],
                        preferred_element_type=jnp.float32)
         out = _out(p, d, o)
+    if not d.has_index:
+        return out, lat_c, 0
     return out, {"latent": lat_c, "index_k": idx_c}, n_blocks

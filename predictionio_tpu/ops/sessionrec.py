@@ -716,10 +716,8 @@ class StackPrograms:
 
     * latent attention (``"mla"``): ``[n_slots + 1, capacity + chunk,
       latent]``, a position's latent; with a learned index
-      (``MLADims.index_heads``) ``{latent [n_slots + 1, capacity + chunk,
-      latent], index_k [n_slots + 1, capacity + chunk, index_dim]}``: the
-      index key stands and falls with the latent, position by position, and
-      is a second array because the scorer reads keys alone;
+      (``MLADims.index_heads``) ``{latent: that, index_k [n_slots + 1,
+      capacity + chunk, index_dim]}`` (``ops/mla.init_cache`` says which);
     * grouped-query attention (``"gqa"``): ``[n_slots + 1, capacity + chunk,
       2 * kv_heads * head_dim]``, a position's keys, then its values;
     * a state-space mixer (``"mamba2"``): ``{conv [n_slots + 1, d_conv - 1,
@@ -808,20 +806,13 @@ class StackPrograms:
         #: whether the latent mixers select their positions by a learned index
         self.indexed = "mla" in self.kinds and spec.mla.has_index
 
-        def per_position(kind):
-            if kind != "mla":
-                return jnp.zeros(positions + (spec.gqa.cache_width,), dtype)
-            latent = jnp.zeros(
-                positions + (mla_ops.cache_width(spec.mla),), dtype)
-            if not self.indexed:
-                return latent
-            return {"latent": latent, "index_k": jnp.zeros(
-                positions + (spec.mla.index_dim,), dtype)}
-
-        self.cache = [
-            ssm_ops.init_state(spec.ssm, shape.n_slots + 1, dtype)
-            if kind == "mamba2" else per_position(kind)
-            for kind in self.kinds]
+        held = {
+            "mla": lambda: mla_ops.init_cache(spec.mla, *positions, dtype),
+            "gqa": lambda: jnp.zeros(
+                positions + (spec.gqa.cache_width,), dtype),
+            "mamba2": lambda: ssm_ops.init_state(
+                spec.ssm, shape.n_slots + 1, dtype)}
+        self.cache = [held[kind]() for kind in self.kinds]
         #: tokens of a call of each program that this stack compiles: the
         #: shape by which ``ops/moe.moe`` chooses its form
         self.tokens = {"prefill": shape.chunk}
@@ -953,13 +944,10 @@ class StackPrograms:
             if kind == "mamba2":
                 out, cache[m] = ssm_ops.prefill_chunk(
                     p, spec.ssm, h, n_valid, offset, cache[m], slot, scope)
-            elif kind == "mla" and self.indexed:
-                out, cache[m], blocks = mla_ops.prefill_chunk_indexed(
+            elif kind == "mla":
+                out, cache[m], blocks = mla_ops.prefill_chunk(
                     p, spec.mla, h, offset, cache[m], slot, chunk, scope)
                 scanned.append(blocks)
-            elif kind == "mla":
-                out, cache[m] = mla_ops.prefill_chunk(
-                    p, spec.mla, h, offset, cache[m], slot, chunk)
             else:
                 out, cache[m] = gqa_ops.prefill_chunk(
                     p, spec.gqa, h, offset, cache[m], slot, chunk)
@@ -967,7 +955,7 @@ class StackPrograms:
 
         x, counters = self._run(params, self._embed(params, ids), valid,
                                 mix_with)
-        if scanned:
+        if self.indexed:
             pos = offset + jnp.arange(ids.shape[0], dtype=jnp.int32)
             counters.update(self._index_counts(sum(scanned), valid, pos))
         return cache, self._final(params, x[n_valid - 1])[None], counters
@@ -994,14 +982,11 @@ class StackPrograms:
             if kind == "mamba2":
                 out, cache[m] = ssm_ops.extend(
                     p, spec.ssm, h, n_new, pos0, cache[m], slots, scope)
-            elif kind == "mla" and self.indexed:
-                out, cache[m], blocks = mla_ops.extend_indexed(
+            elif kind == "mla":
+                out, cache[m], blocks = mla_ops.extend(
                     p, spec.mla, h, pos, cache[m], slots, n_blocks, chunk,
                     scope)
                 scanned.append(blocks)
-            elif kind == "mla":
-                out, cache[m] = mla_ops.extend(
-                    p, spec.mla, h, pos, cache[m], slots, n_blocks, chunk)
             else:
                 out, cache[m] = gqa_ops.extend(
                     p, spec.gqa, h, pos, cache[m], slots, n_blocks, chunk)
@@ -1010,7 +995,7 @@ class StackPrograms:
         x, counters = self._run(
             params, self._embed(params, ids).reshape(B * S, -1), valid,
             mix_with)
-        if scanned:         # every real session's rows scan them
+        if self.indexed:    # every real session's rows scan them
             counters.update(self._index_counts(
                 sum(scanned) * (n_new > 0).sum(), valid, pos.reshape(-1)))
         last = x.reshape(B, S, -1)[jnp.arange(B), jnp.maximum(n_new - 1, 0)]

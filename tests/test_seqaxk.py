@@ -146,7 +146,7 @@ def test_mla_full_and_chunked_prefill_match_the_reference_past_the_original_leng
     while at < 150:
         n = min(12 if at == 0 else 16, 150 - at)
         chunk = jnp.zeros((16, 64), jnp.float32).at[:n].set(x[at:at + n])
-        out, cache = mla_ops.prefill_chunk(p, MLA, chunk, at, cache, 1, 8)
+        out, cache, _ = mla_ops.prefill_chunk(p, MLA, chunk, at, cache, 1, 8)
         outs.append(out[:n])
         at += n
     close(jnp.concatenate(outs), want)
@@ -166,7 +166,7 @@ def test_an_extension_batch_of_a_short_and_a_long_session_equals_each_alone(ref)
     for slot, (x, n) in enumerate(zip(xs, new)):
         head = len(x) - n
         chunk = jnp.zeros((144, 64), jnp.float32).at[:head].set(x[:head])
-        _, cache = mla_ops.prefill_chunk(p, MLA, chunk, 0, cache, slot, 8)
+        _, cache, _ = mla_ops.prefill_chunk(p, MLA, chunk, 0, cache, slot, 8)
     rows = [jnp.zeros((4, 64)).at[:n].set(x[len(x) - n:])
             for x, n in zip(xs, new)]
     pos0 = [len(x) - n for x, n in zip(xs, new)]

@@ -22,7 +22,6 @@ from predictionio_tpu.ops import mla as mla_ops
 from predictionio_tpu.ops import moe as moe_ops
 from predictionio_tpu.ops.sessionrec import (
     BlockSpec, ServeShape, StackPrograms, StackSpec, init_stack)
-from tests import parent_mla
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -124,14 +123,12 @@ def normal(seed, *shape):
                        jnp.float32)
 
 
-def mixer(seed=0):
-    return seeded_params(small_spec(), seed)["blocks"][0]["mixer_a"]
+def mixer(seed=0, mla=MLA):
+    return seeded_params(small_spec(mla), seed)["blocks"][0]["mixer_a"]
 
 
-def empty_cache(slots, positions, dims=MLA):
-    return {"latent": jnp.zeros((slots, positions, dims.latent), jnp.float32),
-            "index_k": jnp.zeros((slots, positions, dims.index_dim),
-                                 jnp.float32)}
+def empty_cache(slots, positions):
+    return mla_ops.init_cache(MLA, slots, positions, jnp.float32)
 
 
 # -- the selection ------------------------------------------------------------
@@ -181,7 +178,7 @@ def test_the_programs_sets_are_the_references_position_for_position(ref):
     for at in range(0, T, 16):
         n = min(16, T - at)
         chunk = jnp.zeros((16, 64), jnp.float32).at[:n].set(x[at:at + n])
-        _, cache, _ = mla_ops.prefill_chunk_indexed(
+        _, cache, _ = mla_ops.prefill_chunk(
             p, MLA, chunk, at, cache, 1, 8)
     keys = cache["index_k"][1, :T]
     close(keys, ki, 1e-6)
@@ -212,7 +209,7 @@ def test_chunks_and_extensions_through_the_cache_equal_the_plain_form(ref):
     while at < 140:
         n = min(12 if at == 0 else 16, 140 - at)
         chunk = jnp.zeros((16, 64), jnp.float32).at[:n].set(x[at:at + n])
-        out, cache, blocks = mla_ops.prefill_chunk_indexed(
+        out, cache, blocks = mla_ops.prefill_chunk(
             p, dims, chunk, at, cache, 1, 8)
         outs.append(out[:n])
         scanned.append(int(blocks))
@@ -224,7 +221,7 @@ def test_chunks_and_extensions_through_the_cache_equal_the_plain_form(ref):
     short = normal(2, 9, 64)
     want_short = ref.mla(p, short, jnp.arange(9), ref_dims(small_spec()))
     chunk = jnp.zeros((16, 64), jnp.float32).at[:7].set(short[:7])
-    _, cache, blocks = mla_ops.prefill_chunk_indexed(
+    _, cache, blocks = mla_ops.prefill_chunk(
         p, dims, chunk, 0, cache, 0, 8)
     assert int(blocks) == 2                  # 16 rows reach past 12
     for n in (4, 4, 2):
@@ -235,7 +232,7 @@ def test_chunks_and_extensions_through_the_cache_equal_the_plain_form(ref):
             rows = rows.at[1, :2].set(short[7:])
         ext_pos = jnp.array([[at + i for i in range(4)],
                              [7 + i for i in range(4)]], jnp.int32)
-        out, cache, blocks = mla_ops.extend_indexed(
+        out, cache, blocks = mla_ops.extend(
             p, dims, rows, ext_pos, cache,
             jnp.array([1, 0 if both else 2]), jnp.int32(-(-(at + 4) // 8)),
             8)
@@ -317,7 +314,7 @@ def test_an_extensions_sets_and_outputs_are_the_plain_forms(
                     jnp.int32)
     x = normal(23, B, 4, 64)
     longest = max(at + 4 for _, at, _ in sessions)
-    out, after, blocks = mla_ops.extend_indexed(
+    out, after, blocks = mla_ops.extend(
         p, MLA, x, pos, cache, slots, jnp.int32(-(-longest // 8)), 8)
     assert int(blocks) == -(-longest // 8)
     # the new positions' latents and keys are written where they belong
@@ -359,7 +356,7 @@ def test_a_chunk_whose_reach_is_within_the_set_scores_nothing_and_still_writes_i
     p = mixer()
     x = normal(3, 8, 64)
     cache = empty_cache(2, 32)
-    out, cache, blocks = mla_ops.prefill_chunk_indexed(
+    out, cache, blocks = mla_ops.prefill_chunk(
         p, MLA, x, 0, cache, 1, 8)
     assert int(blocks) == 0
     want = ref.mla(p, x, jnp.arange(8), ref_dims(small_spec()))
@@ -376,46 +373,60 @@ def test_an_index_needs_its_sizes_and_whole_blocks():
     with pytest.raises(ValueError):
         dataclasses.replace(MLA, index_dim=8)
     with pytest.raises(ValueError):
-        mla_ops.prefill_chunk_indexed(
+        mla_ops.prefill_chunk(
             mixer(), MLA, normal(4, 16, 64), 0, empty_cache(2, 20), 0, 8)
 
 
-# -- a stack without an index is the parent's ---------------------------------
+# -- one cached path of each kind, the index inside ----------------------------
 
-def test_without_an_index_both_cached_paths_are_the_parents_bit_for_bit():
-    """``ops/mla.prefill_chunk`` and ``extend`` against their copies from
-    the commit before the index (tests/parent_mla.py): the same primitives
-    in the same order, and the same bits."""
-    p = mla_ops.init(jax.random.PRNGKey(5), PLAIN)
-    assert set(p) == {"w_dq", "w_uq", "w_dkv", "w_ukv", "w_o", "q_norm",
-                      "kv_norm"}
-    x = normal(5, 16, 64)
-    cache = normal(6, 3, 48, PLAIN.latent)
-
-    def chunk(fn):
-        return lambda x, at, c: fn(p, PLAIN, x, at, c, 1, 8)
-
-    args = (x, jnp.int32(24), cache)
-    assert str(jax.make_jaxpr(chunk(mla_ops.prefill_chunk))(*args)) == str(
-        jax.make_jaxpr(chunk(parent_mla.prefill_chunk))(*args))
-    new, old = (jax.jit(chunk(f))(*args) for f in (
-        mla_ops.prefill_chunk, parent_mla.prefill_chunk))
-    assert all((np.asarray(a) == np.asarray(b)).all()
-               for a, b in zip(new, old))
-    rows = normal(7, 2, 4, 64)
-    pos = jnp.array([[40, 41, 42, 43], [5, 6, 7, 8]], jnp.int32)
-
-    def ext(fn):
-        return lambda x, pos, c: fn(p, PLAIN, x, pos, c, jnp.array([1, 2]),
-                                    jnp.int32(6), 8)
-
-    args = (rows, pos, cache)
-    assert str(jax.make_jaxpr(ext(mla_ops.extend))(*args)) == str(
-        jax.make_jaxpr(ext(parent_mla.extend))(*args))
-    new, old = (jax.jit(ext(f))(*args) for f in (mla_ops.extend,
-                                                 parent_mla.extend))
-    assert all((np.asarray(a) == np.asarray(b)).all()
-               for a, b in zip(new, old))
+@pytest.mark.parametrize("path", ["chunk", "extension"])
+@pytest.mark.parametrize("dims", [PLAIN, MLA], ids=["no_index", "index"])
+def test_a_cached_path_gives_the_plain_forms_numbers_and_writes_its_rows(
+        dims, path):
+    """``ops/mla.prefill_chunk`` and ``extend`` over what ``init_cache``
+    gives their mixer (a bare array, or two under an index): 40 positions in
+    chunks of 16, or 36 and then an extension of 4 beside a padding session,
+    against ``attend_full``; every latent (and index key) where it belongs,
+    the next slot untouched, no block of index keys scanned without one."""
+    p = mixer(mla=dims)
+    T = 40
+    x, pos = normal(5, T, 64), jnp.arange(T, dtype=jnp.int32)
+    want = mla_ops.attend_full(p, dims, x[None], pos[None])[0]
+    cache = mla_ops.init_cache(dims, 4, 48, jnp.float32)
+    assert isinstance(cache, dict if dims.has_index else jax.Array)
+    chunked = T if path == "chunk" else T - 4
+    outs, scanned = [], []
+    for at in range(0, chunked, 16):
+        n = min(16, chunked - at)
+        chunk = jnp.zeros((16, 64), jnp.float32).at[:n].set(x[at:at + n])
+        out, cache, blocks = mla_ops.prefill_chunk(
+            p, dims, chunk, at, cache, 1, 8)
+        outs.append(out[:n])
+        scanned.append(int(blocks))
+    if path == "extension":
+        rows = jnp.zeros((2, 4, 64), jnp.float32).at[0].set(x[chunked:])
+        ext_pos = jnp.array([[chunked + i for i in range(4)], [0, 1, 2, 3]],
+                            jnp.int32)
+        out, cache, blocks = mla_ops.extend(
+            p, dims, rows, ext_pos, cache, jnp.array([1, SCRATCH]),
+            jnp.int32(5), 8)
+        outs.append(out[0])
+        scanned.append(int(blocks))
+    close(jnp.concatenate(outs), want)
+    # every chunk reaches past 12 positions: scored as far as its own end
+    assert scanned == ([2, 4, 6, 5][:len(scanned)] if dims.has_index
+                       else [0] * len(scanned))
+    cq = mla_ops.compress_q(p, dims, x)
+    latents = cache["latent"] if dims.has_index else cache
+    assert latents.shape == (4, 48, 128)        # 40 values, whole lane rows
+    close(latents[1, :T, :dims.latent],
+          mla_ops.project(p, dims, x, pos, cq)[2], 1e-6)
+    assert not np.asarray(latents[1, :T, dims.latent:]).any()
+    assert not np.asarray(latents[0]).any()
+    if dims.has_index:
+        close(cache["index_k"][1, :T],
+              mla_ops.project_index(p, dims, x, cq, pos)[1], 1e-6)
+        assert not np.asarray(cache["index_k"][0]).any()
 
 
 def test_a_stack_without_an_index_compiles_the_programs_it_had():

@@ -107,7 +107,7 @@ def test_mla_full_and_chunked_prefill_match_the_reference(ref):
     outs, at = [], 0
     for n in (12, 16, 12):
         chunk = jnp.zeros((16, 64), jnp.float32).at[:n].set(x[at:at + n])
-        out, cache = mla_ops.prefill_chunk(p, MLA, chunk, at, cache, 1, 8)
+        out, cache, _ = mla_ops.prefill_chunk(p, MLA, chunk, at, cache, 1, 8)
         outs.append(out[:n])
         at += n
     close(jnp.concatenate(outs), want)
@@ -126,13 +126,13 @@ def test_mla_extension_over_cached_latents_matches_the_reference(ref):
     for slot, (x, n) in enumerate(zip(xs, new)):
         head = len(x) - n
         chunk = jnp.zeros((24, 64), jnp.float32).at[:head].set(x[:head])
-        _, cache = mla_ops.prefill_chunk(p, MLA, chunk, 0, cache, slot, 8)
+        _, cache, _ = mla_ops.prefill_chunk(p, MLA, chunk, 0, cache, slot, 8)
     batch = jnp.stack([jnp.zeros((4, 64)).at[:n].set(x[len(x) - n:])
                        for x, n in zip(xs, new)])
     pos0 = jnp.array([len(x) - n for x, n in zip(xs, new)], jnp.int32)
     pos = pos0[:, None] + jnp.arange(4)[None]
-    out, _ = mla_ops.extend(p, MLA, batch, pos, cache, jnp.array([0, 1]),
-                            jnp.int32(4), 8)
+    out, _, _ = mla_ops.extend(p, MLA, batch, pos, cache,
+                               jnp.array([0, 1]), jnp.int32(4), 8)
     for b, (w, n) in enumerate(zip(want, new)):
         close(out[b, :n], w[len(w) - n:])
 
