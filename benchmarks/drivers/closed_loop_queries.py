@@ -67,6 +67,7 @@ def run(bench) -> dict:
     batcher = deployed.batcher
     if bench.trace and batcher is not None:
         _wrap_dispatch(batcher)
+    bench.mark("deploy")
 
     k = int(mix["num"])
     # every batch size the window can form, once, before the window: the
@@ -104,6 +105,8 @@ def run(bench) -> dict:
             raise RuntimeError(f"load generator warm-up failed: {said!r}")
         compiles0 = bench.compiles.count
         setup_s = time.time() - bench.t_start
+        bench.mark("batch sizes, warm-up queries"
+                   + (", traced stretch" if traced else ""))
         child.stdin.write("GO\n")
         child.stdin.flush()
         out, _ = child.communicate(timeout=bench.seconds + 600)
@@ -115,6 +118,7 @@ def run(bench) -> dict:
     load = json.loads(out.strip().splitlines()[-1])
     if "fatal" in load:
         raise RuntimeError(f"load generator: {load['fatal']}")
+    bench.mark("window and drain")
 
     lat = sorted(load["latencies_s"])
     answered = len(lat)
@@ -124,7 +128,10 @@ def run(bench) -> dict:
         "query_p95_ms": percentile(lat, 0.95) * 1e3,
         "query_rate": answered / load["window_s"],
     }
-    layer_ctx = {"bench": bench, "traced": traced, **(traced or {})}
+    # ``window_end_to_end``: the window's own numbers, for a cell that
+    # reports one of them per layer (a traced run measures the same window)
+    layer_ctx = {"bench": bench, "traced": traced, **(traced or {}),
+                 "window_end_to_end": end_to_end}
     layer = bench.read_layer_metrics(layer_ctx) if bench.trace else {}
     peak = bench.memory_peak()
     notes = [
@@ -136,6 +143,10 @@ def run(bench) -> dict:
         f"{end_to_end['query_p95_ms']:.3f} p99 "
         f"{percentile(lat, 0.99) * 1e3:.3f} max {lat[-1] * 1e3:.3f} "
         f"mean {statistics.fmean(lat) * 1e3:.3f}",
+        f"latency ms by the second a request ended in, p50/p95: "
+        f"{_by_second(load)}",
+        f"load generator's own collections [n, s, longest s] by generation:"
+        f" {load.get('gc_pauses')}",
         f"batcher histogram over the run: "
         f"{batcher.histogram() if batcher is not None else None}",
         f"index: {_index_stats(deployed)}",
@@ -157,8 +168,10 @@ def run(bench) -> dict:
                                        float(s["score"])) for s in scores]))
         except (ValueError, KeyError, TypeError):
             unparsable += 1
+    bench.mark("readers, server stopped")
     t_ref = time.perf_counter()
     got = reference.compare(X, Y, sample, k)
+    bench.mark("comparison")
     notes.append(f"reference: {len(sample)} answers compared in "
                  f"{time.perf_counter() - t_ref:.2f} s")
     limits = cfg["limits"]
@@ -179,6 +192,18 @@ def run(bench) -> dict:
         "end_to_end": end_to_end, "layer_metrics": layer,
         "memory_peak_bytes": peak, "notes": notes, "traced": traced,
     }
+
+
+def _by_second(load) -> str:
+    """The regimes a saturated closed loop passes through, second by second
+    (a note for the log: no metric reads it)."""
+    rows = {}
+    for t, d in zip(load.get("done_s", ()), load["latencies_s"]):
+        rows.setdefault(int(t), []).append(d)
+    return " ".join(
+        f"{percentile(sorted(v), 0.5) * 1e3:.1f}/"
+        f"{percentile(sorted(v), 0.95) * 1e3:.1f}"
+        for _, v in sorted(rows.items()))
 
 
 def _index_stats(deployed):

@@ -8,8 +8,12 @@ builds its request bodies, connects and plays its warm-up sessions. The
 window: each connection plays sessions back to back for ``--seconds``
 seconds. After the window the server is stopped, the engine's cache and
 programs are freed, and a seeded sample of the window's own answers — half
-first queries, half extensions, the longest history served among them — is
-held against the reference's full forward over the history each carried.
+first queries, half extensions, the longest history served and the longest
+later query among them — is held against the reference's full forward over
+the history each carried, in the order and under the budget of
+``check_budget.py`` (the traffic file's ``check_budget_s`` and
+``check_floor``). ``bench.mark`` notes where each phase ends, for the ``#
+run:`` line of the log.
 
 A traced run starts the profiler when the warm-up sessions are done and
 every connection stands at its next session's boundary: the server is idle
@@ -46,6 +50,7 @@ def run(bench) -> dict:
     model = deployed.model
     say(f"# device memory after deploy: {bench.memory_peak()} peak bytes",
         flush=True)
+    bench.mark("deploy")
     child_cfg = {"port": deployed.port, "seed": bench.seed,
                  "seconds": bench.seconds, "mix": mix,
                  "n_items": int(cfg["vocab_size"])}
@@ -63,6 +68,7 @@ def run(bench) -> dict:
         compiles0 = bench.compiles.count
         stats0 = model.stats()
         setup_s = time.time() - bench.t_start
+        bench.mark("warm-up sessions")
         if bench.trace:
             trace_dir = os.path.join(bench.scratch, "trace")
             bench.lib("trace_reduce").start_trace(trace_dir)
@@ -81,6 +87,7 @@ def run(bench) -> dict:
     load = json.loads(out.strip().splitlines()[-1])
     if "fatal" in load:
         raise RuntimeError(f"load generator: {load['fatal']}")
+    mark_window(bench, traced)
 
     lat = sorted(load["latencies_s"])
     answered = len(lat)
@@ -129,22 +136,29 @@ def run(bench) -> dict:
     model._programs = model._index = None
     del deployed, model, layer_ctx
     gc.collect()
+    bench.mark("readers, server stopped")
 
     reference = bench.load_module("reference", cfg["reference"])
     k = int(mix["num"])
-    sample, unparsable = [], 0
+    entries, unparsable = [], 0
     for entry in load["sample"]:
         try:
             scores = json.loads(entry["body"])["itemScores"]
-            sample.append((entry["rows"], [(builder.item_row(s["item"]),
-                                            float(s["score"]))
-                                           for s in scores]))
+            entries.append({**entry, "answer": [
+                (builder.item_row(s["item"]), float(s["score"]))
+                for s in scores]})
         except (ValueError, KeyError, TypeError):
             unparsable += 1
+    budget = bench.lib("check_budget").Budget(
+        mix, entries, answered, max(load["history_lengths"], default=0))
     t_ref = time.perf_counter()
-    got = reference.compare(weights, sample, k, reference.dims_of(cfg))
+    got = reference.compare(
+        weights, [(e["rows"], e["answer"]) for e in budget.entries], k,
+        reference.dims_of(cfg), reach=reach_of(mix), stop=budget.stop)
+    bench.mark("comparison")
+    notes.append(budget.note(got["compared"]))
     notes.append(
-        f"reference: {len(sample)} answers compared in "
+        f"reference: {got['compared']} answers compared in "
         f"{time.perf_counter() - t_ref:.2f} s "
         f"({sum(1 for e in load['sample'] if e['first'])} first queries, "
         f"longest history {got['longest_history']}); router near ties "
@@ -156,16 +170,28 @@ def run(bench) -> dict:
     bad = got["malformed"] + unparsable + load["malformed"]
     checks.append({"name": "malformed_answers", "value": bad, "limit": 0,
                    "ok": bad == 0})
-    want = min(int(mix["check_sample"]), answered)
-    checks.append({"name": "answers_compared", "value": got["compared"],
-                   "limit": f">= {want}",
-                   "ok": got["compared"] >= want > 0})
+    checks.append(budget.check(got["compared"]))
     return {
         "attempted": load["sent"], "failed": load["n_errors"] + bad,
         "checks": checks, "window_compiles": window_compiles,
         "end_to_end": end_to_end, "layer_metrics": layer,
         "memory_peak_bytes": peak, "notes": notes, "traced": traced,
     }
+
+
+def mark_window(bench, traced) -> None:
+    """The window's phase ends; a traced run's trace was stopped and reduced
+    on this thread inside it."""
+    bench.mark("window and drain"
+               + (f" (trace stopped in {traced['stop_s']:.1f} and reduced in "
+                  f"{traced['reduce_s']:.1f} of them)" if traced else ""))
+
+
+def reach_of(mix: dict) -> int:
+    """The longest history the mix can send: its longest first query grown
+    by every later one. The reference pads every history to it."""
+    return int(mix["history_max"]) + (int(mix["queries_per_session"]) - 1) \
+        * int(mix["grow_max"])
 
 
 def _trace_stretch(bench, model, batcher, mix, trace_dir) -> dict:
@@ -180,7 +206,10 @@ def _trace_stretch(bench, model, batcher, mix, trace_dir) -> dict:
         time.sleep(float(mix.get("trace_seconds", 3.0)))
         stats1, h1 = model.stats(), batcher.histogram()
     splits = batcher.recent_splits(max(1, h1["answered"] - h0["answered"]))
+    t0 = time.perf_counter()
     jax.profiler.stop_trace()
+    t1 = time.perf_counter()
     out = trace_reduce.reduce_trace(trace_dir)
-    out.update(stats0=stats0, stats1=stats1, splits=splits)
+    out.update(stats0=stats0, stats1=stats1, splits=splits, stop_s=t1 - t0,
+               reduce_s=time.perf_counter() - t1)
     return out

@@ -11,8 +11,10 @@ seconds; a traced run's stretch lies inside the window.
 
 ``correct`` is decided by the timed path's own answers. After the window the
 engine's cache and programs are freed and a seeded sample of the window's
-answers (half first queries, the longest history served among them, half
-follow-ups, which read reused blocks) is taken apart: an answer's ``step``s
+answers (half first queries, the longest history served and the longest
+follow-up's among them, half follow-ups, which read reused blocks; compared in
+the order and under the budget of ``check_budget.py``) is taken apart: an
+answer's ``step``s
 must describe a well-formed run of forwards (``reference.rebuild``: exact),
 and for ``check_forwards`` of its denoise forwards — always the slate's first
 and the last block's last, the others by seed — the plain reference computes
@@ -43,6 +45,7 @@ def run(bench) -> dict:
     model = deployed.model
     say(f"# device memory after deploy: {bench.memory_peak()} peak bytes",
         flush=True)
+    bench.mark("deploy")
     child_cfg = {"port": deployed.port, "seed": bench.seed,
                  "seconds": bench.seconds, "mix": mix,
                  "n_items": builder.n_traffic_items(cfg)}
@@ -60,6 +63,7 @@ def run(bench) -> dict:
         compiles0 = bench.compiles.count
         stats0 = model.stats()
         setup_s = time.time() - bench.t_start
+        bench.mark("warm-up sessions")
         if bench.trace:
             trace_dir = os.path.join(bench.scratch, "trace")
             bench.lib("trace_reduce").start_trace(trace_dir)
@@ -78,6 +82,7 @@ def run(bench) -> dict:
     load = json.loads(out.strip().splitlines()[-1])
     if "fatal" in load:
         raise RuntimeError(f"load generator: {load['fatal']}")
+    sessions_driver.mark_window(bench, traced)
 
     lat = sorted(load["latencies_s"])
     answered = len(lat)
@@ -125,19 +130,22 @@ def run(bench) -> dict:
     model._programs = None
     del deployed, model, layer_ctx
     gc.collect()
+    bench.mark("readers, server stopped")
 
     reference = bench.load_module("reference", cfg["reference"])
     generate, gen = int(mix["generate"]), cfg["generation"]
-    sample, unparsable = [], 0
+    entries, unparsable = [], 0
     for entry in load["sample"]:
         try:
             body = json.loads(entry["body"])
-            sample.append((entry["rows"], [
+            entries.append({**entry, "answer": [
                 (builder.item_row(e["item"]), float(e["score"]),
                  float(e["confidence"]), int(e["step"]))
-                for e in body["itemScores"] + body.get("blockTail", [])]))
+                for e in body["itemScores"] + body.get("blockTail", [])]})
         except (ValueError, KeyError, TypeError):
             unparsable += 1
+    budget = bench.lib("check_budget").Budget(
+        mix, entries, answered, max(load["history_lengths"], default=0))
     rng = bench.lib("seeded").rng(bench.seed, 97)
     n_check = int(mix["check_forwards"])
 
@@ -150,8 +158,16 @@ def run(bench) -> dict:
         return {0, n_denoise - 1, *(int(i) for i in more)}
 
     t_ref = time.perf_counter()
-    got = reference.compare(weights, sample, generate, gen,
-                            reference.dims_of(cfg), pick)
+    # a slate's last forward runs over its history, what it generated and
+    # the whole of its last block
+    reach = (sessions_driver.reach_of(mix) + generate
+             + int(gen["block_len"]))
+    got = reference.compare(
+        weights, [(e["rows"], e["answer"]) for e in budget.entries],
+        generate, gen, reference.dims_of(cfg), pick, reach=reach,
+        stop=budget.stop)
+    bench.mark("comparison")
+    notes.append(budget.note(got["compared"]))
     notes.append(
         f"reference: {got['compared']} answers compared "
         f"({sum(1 for e in load['sample'] if e['first'])} first queries, "
@@ -166,10 +182,7 @@ def run(bench) -> dict:
     bad = got["malformed"] + unparsable + load["malformed"]
     checks.append({"name": "malformed_answers", "value": bad, "limit": 0,
                    "ok": bad == 0})
-    want = min(int(mix["check_sample"]), answered)
-    checks.append({"name": "answers_compared", "value": got["compared"],
-                   "limit": f">= {want}",
-                   "ok": got["compared"] >= want > 0})
+    checks.append(budget.check(got["compared"]))
     return {
         "attempted": load["sent"], "failed": load["n_errors"] + bad,
         "checks": checks, "window_compiles": window_compiles,

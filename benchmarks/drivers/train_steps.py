@@ -28,6 +28,7 @@ def run(bench) -> dict:
     say(f"# first call: {first}", flush=True)
     compiles0 = bench.compiles.count
     setup_s = time.time() - bench.t_start
+    bench.mark("build and first call")
 
     trace_reduce = bench.lib("trace_reduce") if bench.trace else None
     trace_from = int(mix.get("trace_from_call", 1))
@@ -66,6 +67,8 @@ def run(bench) -> dict:
     if tracing:                      # the window ended inside the trace
         traced = stop_tracing()
     window_compiles = bench.compiles.count - compiles0
+    bench.mark("window" + (", trace stopped and reduced in it"
+                           if traced else ""))
 
     examples = calls * built.examples_per_call
     steps = calls * built.steps_per_call
@@ -83,10 +86,12 @@ def run(bench) -> dict:
     built.free()
     del built, layer_ctx
     gc.collect()
+    bench.mark("readers, state freed")
 
     reference = bench.load_module("reference", cfg["reference"])
     t_ref = time.perf_counter()
     got = reference.compare(bench, first)
+    bench.mark("comparison")
     notes.append(f"reference: first call followed in "
                  f"{time.perf_counter() - t_ref:.2f} s")
     limits = cfg["limits"]

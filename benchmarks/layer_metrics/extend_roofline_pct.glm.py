@@ -2,7 +2,9 @@
 %: the larger of the time its runs' needed bytes take at the peak memory rate
 (``glm_counts.extend_bytes``: the non-expert weights once a run, every held
 expert that got a token, the index keys AS SCANNED, 256 B a position a layer,
-and the latents AS GATHERED, each new position's own 2,048) and the time
+and the latents each new position SELECTED, its own 2,048: since PR 44 the
+program walks the slot's latents in place under each row's mask and gathers
+nothing, so this is a lower bound of the bytes it reads) and the time
 their operations take at the bf16 peak (``glm_counts.extend_flops``), over
 the device time of the program's own operations. From the engine's counters;
 None where the program counts no ``extend_index_blocks`` (the parent)."""

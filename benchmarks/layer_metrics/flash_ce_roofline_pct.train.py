@@ -1,8 +1,10 @@
 """The flash-CE kernels' share of their roofline, in %: the least time the
 chip could take for one step's cross-entropy (``kernel_counts
 .flash_ce_flops_per_step`` at the peak bf16 rate) over the device time the
-three kernels (instructions ``flash_ce_fwd``, ``flash_ce_bwd_du``,
-``flash_ce_bwd_dv``) took per traced step."""
+kernels took per traced step: every device operation whose instruction holds
+``flash_ce``. Two since PR 45 (``flash_ce_fwd`` and the one ``flash_ce_bwd``
+that feeds both gradients; three before it, and past the widths where the
+whole ``dv`` stays in VMEM: ``flash_ce_bwd_du``, ``flash_ce_bwd_dv``)."""
 
 
 def read(ctx):

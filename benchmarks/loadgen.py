@@ -24,6 +24,7 @@ Standard library only.
 
 from __future__ import annotations
 
+import gc
 import json
 import random
 import socket
@@ -127,6 +128,20 @@ def main() -> int:
                 warmed[c] += 1
         sock.close()
 
+    # this process's own collector: every pause of it holds all the
+    # connections at once ([collections, seconds in all, longest] by
+    # generation; printed by the driver, read by no metric)
+    pauses = {g: [0, 0.0, 0.0] for g in (0, 1, 2)}
+    began = [0.0]
+
+    def on_gc(phase, info):
+        if phase == "start":
+            began[0] = time.perf_counter()
+        else:
+            p, d = pauses[info["generation"]], time.perf_counter() - began[0]
+            p[0], p[1], p[2] = p[0] + 1, p[1] + d, max(p[2], d)
+
+    gc.callbacks.append(on_gc)
     threads = [threading.Thread(target=worker, args=(c,), daemon=True)
                for c in range(n_conn)]
     for t in threads:
@@ -171,6 +186,8 @@ def main() -> int:
         "malformed": malformed,
         "window_s": t_end - t_go,
         "latencies_s": [d for _, d in done],
+        "done_s": [t - t_go for t, _ in done],
+        "gc_pauses": pauses,
         "sample": sample,
     }), flush=True)
     return 0

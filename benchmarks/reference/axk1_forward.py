@@ -38,14 +38,19 @@ the configuration's file: ``topk_method`` reads ``"none"`` there, taken as
 DeepSeek-V3's group-limited selection WITHOUT its correction bias (``s``
 itself chooses); RoPE pairs neighbouring dimensions; weights are seeded.
 
-So that 24,576 positions fit beside 8 GB of weights, attention runs a group
-of heads and a block of :data:`BLOCK` QUERIES at a time against every key
-(masked), and position-wise parts a block of rows at a time: each number is
-the one the unblocked form gives. A history is padded to one of a few
-lengths (:func:`padded_length`; causal: positions after the last real one
-change nothing before them) so that a sample compiles a handful of shapes.
-Weights arrive as the benchmark's seeded arrays (bfloat16-valued) and are
-widened inside each jitted part.
+So that 24,576 positions fit beside 8 GB of weights, attention runs a group of
+heads and a block of :data:`BLOCK` QUERIES at a time against the blocks of
+keys up to its own (causal: a later block holds nothing it may attend), the
+softmax carried from block to block as its running maximum and sum, and
+position-wise parts a block of rows at a time, a routed expert over the rows
+routed to it alone (:func:`gated`): each number is the one the unblocked form
+gives, to float32 rounding. A history is padded to ONE length a cell
+(:func:`shapes`: what the traffic's longest history can reach; causal:
+positions after the last real one change nothing before them) and its REAL
+length goes in as an argument that bounds every loop over blocks: the work
+follows the real length, and each layer kind compiles once. Weights arrive as
+the benchmark's seeded arrays (bfloat16-valued) and are widened inside each
+jitted part.
 
 ``hold``: the control. ``(exponent_bits, mantissa_bits)`` rounds every weight
 and every matrix product's input to that format (``lax.reduce_precision``);
@@ -62,8 +67,13 @@ import numpy as np
 FORMATS = {"bfloat16": (8, 7), "float8_e4m3fn": (4, 3)}
 #: queries of one attention block, rows of one position-wise block
 BLOCK = 1024
-#: [heads of a group, BLOCK, T] float32 scores held at once
+#: [heads of a group, BLOCK, BLOCK] float32 scores held at once
 SCORE_BYTES = 1 << 29
+#: [positions, heads of a group, head width] float32 queries, keys and values
+#: of one group held at once
+HEAD_BYTES = 1 << 30
+#: routed rows of a block that one expert's matrices see at once
+ROUTED_ROWS = 128
 #: a history past this is reported (below it YaRN's angles barely differ
 #: from plain RoPE's)
 LONG = 4096
@@ -107,15 +117,40 @@ def _mm(x, w, hold):
     return jnp.dot(_hold(x, hold), _hold(w.astype(jnp.float32), hold))
 
 
-def _rows(fn, x):
+def _blocked(T: int) -> bool:
+    return T > BLOCK and T % BLOCK == 0
+
+
+def blocks_of(n):
+    """How many blocks of :data:`BLOCK` the first ``n`` positions lie in
+    (``n``: an int or a traced scalar); None, every block, for None."""
+    return None if n is None else (n + BLOCK - 1) // BLOCK
+
+
+def _rows(fn, x, nb=None):
     """``fn`` over ``x`` [T, ...] a block of rows at a time (each row's
-    result, an array or several, is its own)."""
+    result, an array or several, is its own): the first ``nb`` blocks only
+    (a traced count; None: all), the rows of the others left zero."""
     import jax
+    import jax.numpy as jnp
 
     T = x.shape[0]
-    if T <= BLOCK or T % BLOCK:
+    if not _blocked(T):
         return fn(x)
-    out = jax.lax.map(fn, x.reshape((T // BLOCK, BLOCK) + x.shape[1:]))
+    blocks = T // BLOCK
+    xb = x.reshape((blocks, BLOCK) + x.shape[1:])
+    like = jax.eval_shape(fn, jax.ShapeDtypeStruct(xb.shape[1:], xb.dtype))
+
+    def body(i, acc):
+        got = fn(jax.lax.dynamic_index_in_dim(xb, i, keepdims=False))
+        return jax.tree_util.tree_map(
+            lambda a, v: jax.lax.dynamic_update_index_in_dim(a, v, i, 0),
+            acc, got)
+
+    out = jax.lax.fori_loop(
+        0, blocks if nb is None else jnp.minimum(nb, blocks), body,
+        jax.tree_util.tree_map(
+            lambda s: jnp.zeros((blocks,) + s.shape, s.dtype), like))
     return jax.tree_util.tree_map(
         lambda a: a.reshape((T,) + a.shape[2:]), out)
 
@@ -164,63 +199,130 @@ def rope(x, pos, freqs, amplitude):
                      axis=-1).reshape(x.shape)
 
 
-def mla(p, x, pos, dm, hold=None):
-    """Full causal latent attention over one sequence ``x`` [T, D]."""
+def mla(p, x, pos, dm, hold=None, n=None):
+    """Full causal latent attention over one sequence ``x`` [T, D]. ``n``:
+    the real length (a traced scalar; None: all of ``T``): the blocks past it
+    are not computed. A block of queries walks the blocks of keys up to its
+    own, the softmax carried from block to block as its running maximum and
+    sum."""
     import jax
     import jax.numpy as jnp
 
     T = x.shape[0]
     H, dn, dr, dv, rkv = dm["H"], dm["dn"], dm["dr"], dm["dv"], dm["rkv"]
     freqs, amplitude, scale = yarn(dm)
+    nb = blocks_of(n)
     cq = _rows(lambda r: rms(_mm(r, p["w_dq"], hold), p["q_norm"],
-                             dm["eps"]), x)
-    down = _rows(lambda r: _mm(r, p["w_dkv"], hold), x)
+                             dm["eps"]), x, nb)
+    down = _rows(lambda r: _mm(r, p["w_dkv"], hold), x, nb)
     ckv = rms(down[:, :rkv], p["kv_norm"], dm["eps"])
     kr = rope(down[:, rkv:], pos, freqs, amplitude)          # [T, dr]
-    blocks = max(1, T // BLOCK) if T % BLOCK == 0 else 1
-    Tq = T // blocks
-    group = max(1, min(H, SCORE_BYTES // (4 * Tq * T)))
+    Tq = BLOCK if _blocked(T) else T
+    blocks = T // Tq
+    nb = blocks if nb is None else jnp.minimum(nb, blocks)
+    group = max(1, min(H, SCORE_BYTES // (4 * Tq * Tq),
+                       HEAD_BYTES // (8 * T * (dn + max(dr, dv)))))
     while H % group:
         group -= 1
     w_uq = p["w_uq"].reshape(dm["rq"], H // group, group, dn + dr)
     w_ukv = p["w_ukv"].reshape(rkv, H // group, group, dn + dv)
     w_o = p["w_o"].reshape(H // group, group * dv, dm["D"])
+    pos_b, kr_b = pos.reshape(blocks, Tq), kr.reshape(blocks, Tq, dr)
 
-    def heads(out, args):
+    def block(a, i):
+        return jax.lax.dynamic_index_in_dim(a, i, keepdims=False)
+
+    def heads(out, args):                                   # [blocks, Tq, D]
         uq, ukv, wo = args                                  # one group's
-        q = _mm(cq, uq.reshape(dm["rq"], -1), hold).reshape(
-            T, group, dn + dr)
-        qn, qr = q[..., :dn], rope(q[..., dn:], pos, freqs, amplitude)
-        kv = _mm(ckv, ukv.reshape(rkv, -1), hold).reshape(T, group, dn + dv)
-        kn, v = kv[..., :dn], kv[..., dn:]
+        q = _rows(lambda r: _mm(r, uq.reshape(dm["rq"], -1), hold), cq,
+                  nb).reshape(blocks, Tq, group, dn + dr)
+        kv = _rows(lambda r: _mm(r, ukv.reshape(rkv, -1), hold), ckv,
+                   nb).reshape(blocks, Tq, group, dn + dv)
 
-        def queries(args):
-            qn_b, qr_b, pos_b = args                        # [Tq, g, d]
-            s = (jnp.einsum("tgd,ugd->gtu", _hold(qn_b, hold),
-                            _hold(kn, hold))
-                 + jnp.einsum("tgd,ud->gtu", _hold(qr_b, hold),
-                              _hold(kr, hold)))
-            s = jnp.where((pos_b[:, None] >= pos[None, :])[None],
-                          s * scale, -jnp.inf)
-            prob = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum("gtu,ugd->tgd", _hold(prob, hold),
-                              _hold(v, hold))
+        def queries(b, out):
+            # a block's own slices: nothing of the whole length is copied
+            q_q, pos_q = block(q, b), block(pos_b, b)
+            qn_q, qr_q = q_q[..., :dn], rope(q_q[..., dn:], pos_q, freqs,
+                                             amplitude)
 
-        o = jax.lax.map(queries, (
-            qn.reshape(blocks, Tq, group, dn),
-            qr.reshape(blocks, Tq, group, dr), pos.reshape(blocks, Tq)))
-        return out + _mm(o.reshape(T, group * dv), wo, hold), None
+            def keys_block(c, carry):
+                m, l, acc = carry               # [g, Tq] twice, [g, Tq, dv]
+                kv_c = block(kv, c)
+                s = (jnp.einsum("tgd,ugd->gtu", _hold(qn_q, hold),
+                                _hold(kv_c[..., :dn], hold))
+                     + jnp.einsum("tgd,ud->gtu", _hold(qr_q, hold),
+                                  _hold(block(kr_b, c), hold)))
+                s = jnp.where(
+                    (pos_q[:, None] >= block(pos_b, c)[None, :])[None],
+                    s * scale, -jnp.inf)
+                top = jnp.maximum(m, s.max(axis=-1))
+                at = jnp.where(jnp.isfinite(top), top, 0.0)
+                e = jnp.exp(s - at[..., None])
+                shrink = jnp.exp(m - at)         # 0 while nothing was seen
+                return (top, shrink * l + e.sum(axis=-1),
+                        shrink[..., None] * acc + jnp.einsum(
+                            "gtu,ugd->gtd", _hold(e, hold),
+                            _hold(kv_c[..., dn:], hold)))
 
-    return jax.lax.scan(heads, jnp.zeros_like(x), (
-        w_uq.transpose(1, 0, 2, 3), w_ukv.transpose(1, 0, 2, 3), w_o))[0]
+            _, l, acc = jax.lax.fori_loop(0, b + 1, keys_block, (
+                jnp.full((group, Tq), -jnp.inf), jnp.zeros((group, Tq)),
+                jnp.zeros((group, Tq, dv))))
+            o_q = (acc / l[..., None]).transpose(1, 0, 2).reshape(
+                Tq, group * dv)
+            return jax.lax.dynamic_update_index_in_dim(
+                out, block(out, b) + _mm(o_q, wo, hold), b, 0)
+
+        return jax.lax.fori_loop(0, nb, queries, out), None
+
+    return jax.lax.scan(heads, jnp.zeros((blocks, Tq, dm["D"])), (
+        w_uq.transpose(1, 0, 2, 3), w_ukv.transpose(1, 0, 2, 3),
+        w_o))[0].reshape(T, dm["D"])
 
 
-def ffn(p, x, hold=None):
+def ffn(p, x, hold=None, nb=None):
     import jax
 
     return _rows(lambda r: _mm(
         jax.nn.silu(_mm(r, p["w_g"], hold)) * _mm(r, p["w_u"], hold),
-        p["w_d"], hold), x)
+        p["w_d"], hold), x, nb)
+
+
+def gated(p, x, gates, hold=None, nb=None):
+    """``sum_e gates[:, e, None] * ffn(p_e, x)`` over the experts whose
+    matrices ``p`` stacks, each computed for the rows whose gate is not 0
+    alone (an expert held here sees one token in twenty-four): a block of rows
+    at a time, an expert at a time, its routed rows :data:`ROUTED_ROWS` at a
+    time in position order, as many times as it takes. Each row's number is
+    the one the whole block's product gives it."""
+    import jax
+    import jax.numpy as jnp
+
+    D = x.shape[1]
+
+    def rows(xg):                                          # [B, D + n]
+        xb = xg[:, :D]
+        B = xb.shape[0]
+        C = min(ROUTED_ROWS, B)
+
+        def one(acc, args):
+            w_g, w_u, w_d, gb = args                       # gb [B]
+            routed = gb != 0
+            n = jnp.sum(routed)
+            order = jnp.pad(jnp.argsort(~routed, stable=True), (0, -B % C))
+
+            def some(j, out):
+                at = jax.lax.dynamic_slice_in_dim(order, j * C, C)
+                live = j * C + jnp.arange(C) < n
+                y = ffn({"w_g": w_g, "w_u": w_u, "w_d": w_d}, xb[at], hold)
+                return out.at[at].add(
+                    jnp.where(live, gb[at], 0.0)[:, None] * y)
+
+            return jax.lax.fori_loop(0, (n + C - 1) // C, some, acc), None
+
+        return jax.lax.scan(one, jnp.zeros_like(xb), (
+            p["w_g"], p["w_u"], p["w_d"], xg[:, D:].T))[0]
+
+    return _rows(rows, jnp.concatenate([x, gates], axis=-1), nb)
 
 
 def route(p, x, dm, hold=None):
@@ -250,87 +352,104 @@ def route(p, x, dm, hold=None):
     return gates, near
 
 
-def moe_parts(p, x, dm, held, hold=None):
+def moe_parts(p, x, dm, held, hold=None, nb=None):
     """(what the routed experts ``held = (e0, n)`` add, what the shared
     expert adds, near ties). ``p["w_g"|"w_u"|"w_d"]`` hold those ``n``
-    experts' matrices, in order."""
+    experts' matrices, in order. ``nb``: the blocks of rows in use."""
     import jax
     import jax.numpy as jnp
 
-    gates, near = _rows(lambda r: route(p, r, dm, hold), x)
+    gates, near = _rows(lambda r: route(p, r, dm, hold), x, nb)
     e0, n = held
 
-    def one(acc, args):
-        w_g, w_u, w_d, g = args
-        y = ffn({"w_g": w_g, "w_u": w_u, "w_d": w_d}, x, hold)
-        return acc + g[:, None] * y, None
-
-    routed, _ = jax.lax.scan(
-        one, jnp.zeros_like(x),
-        (p["w_g"], p["w_u"], p["w_d"], gates[:, e0:e0 + n].T))
-    return routed, ffn(p["shared"], x, hold), near
+    routed = gated(p, x, gates[:, e0:e0 + n], hold, nb)
+    return routed, ffn(p["shared"], x, hold, nb), near
 
 
-def layer(p, x, pos, dm, held, hold=None):
-    """One layer over ``x`` [T, D], dense or expert by what ``p`` holds;
-    (out, near ties [T])."""
+def _fed(p, h, dm, held, hold=None, nb=None):
+    """What a layer's FFN adds to ``h`` [T, D], dense or expert by what
+    ``p`` holds: (out, near ties [T])."""
     import jax.numpy as jnp
 
-    eps = dm["eps"]
-    h = x + mla(p["mixer_a"], rms(x, p["norm_a"], eps), pos, dm, hold)
-    u = rms(h, p["norm_ffn_a"], eps)
+    u = rms(h, p["norm_ffn_a"], dm["eps"])
     if "moe" not in p:
-        return h + ffn(p["ffn_a"], u, hold), jnp.zeros(x.shape[0], bool)
-    routed, shared, near = moe_parts(p["moe"], u, dm, held, hold)
+        return h + ffn(p["ffn_a"], u, hold, nb), jnp.zeros(h.shape[0], bool)
+    routed, shared, near = moe_parts(p["moe"], u, dm, held, hold, nb)
     return h + routed + shared, near
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted(dm_items, hold):
+    """The jitted parts: the mixer and the FFN of a layer apart, so that the
+    mixer (the same in a dense and in an expert layer) compiles once."""
     import jax
+    import jax.numpy as jnp
 
     dm = dict(dm_items)
 
-    def one_layer(p, x, pos):
-        with jax.default_matmul_precision("highest"):
-            return layer(p, x, pos, dm, dm["held"], hold)
+    def highest(fn):
+        def run(*args):
+            with jax.default_matmul_precision("highest"):
+                return fn(*args)
+        return jax.jit(run)
 
-    def head(final_norm, table, h):
-        with jax.default_matmul_precision("highest"):
-            return _mm(rms(h, final_norm, dm["eps"])[None], table.T, hold)[0]
+    mixed = highest(lambda norm, p, x, n: x + mla(
+        p, rms(x, norm, dm["eps"]), jnp.arange(x.shape[0], dtype=jnp.int32),
+        dm, hold, n))
 
-    return jax.jit(one_layer), jax.jit(head)
+    def fed(p, h, n):
+        out, near = _fed(p, h, dm, dm["held"], hold, blocks_of(n))
+        return out, jnp.sum(near & (jnp.arange(h.shape[0]) < n))
+
+    fed = highest(fed)
+
+    def one_layer(p, x, n):
+        return fed({k: v for k, v in p.items()
+                    if k not in ("norm_a", "mixer_a")},
+                   mixed(p["norm_a"], p["mixer_a"], x, n), n)
+
+    return (jax.jit(lambda table, rows: table[rows].astype(jnp.float32)),
+            one_layer,
+            highest(lambda final_norm, table, h: _mm(
+                rms(h, final_norm, dm["eps"])[None], table.T, hold)[0]))
 
 
-def padded_length(n: int) -> int:
-    """Whole blocks up to four, then whole fours of blocks: at most nine
-    shapes up to 24,576 positions."""
-    step = BLOCK if n <= 4 * BLOCK else 4 * BLOCK
-    return -(-n // step) * step
+def shapes(reach: int) -> tuple:
+    """The padded lengths of a cell whose histories reach ``reach``
+    positions, shortest first: ONE, whole fours of blocks (a block where one
+    holds it)."""
+    step = BLOCK if reach <= BLOCK else 4 * BLOCK
+    return (-(-reach // step) * step,)
 
 
-def forward(weights, ids, dm, hold=None):
+def padded_length(n: int, reach: int | None = None) -> int:
+    """The first of :func:`shapes` that holds ``n`` positions (``reach``:
+    None, the history's own length)."""
+    return next(s for s in shapes(max(n, reach or n)) if s >= n)
+
+
+def forward(weights, ids, dm, hold=None, reach=None):
     """Logits [V] after the history ``ids`` (rows of the item table, oldest
     first), and how many of its (position, expert layer) pairs had a near tie
     at one of the router's two cuts: each is a place where a rounding can
-    send a token to another expert than the reference's."""
+    send a token to another expert than the reference's. ``reach``:
+    :func:`padded_length`'s."""
     import jax.numpy as jnp
 
-    one_layer, head = _jitted(tuple(sorted(dm.items())), hold)
+    embed, one_layer, head = _jitted(tuple(sorted(dm.items())), hold)
     n = len(ids)
-    padded = padded_length(n)
-    rows = np.zeros(padded, np.int32)
+    rows = np.zeros(padded_length(n, reach), np.int32)
     rows[:n] = np.asarray(ids, np.int32)
-    x = weights["embed"][jnp.asarray(rows)].astype(jnp.float32)
-    pos = jnp.arange(padded, dtype=jnp.int32)
+    x = embed(weights["embed"], jnp.asarray(rows))
     near_ties = 0
     for i, p in enumerate(weights["layers"]):
         if ("moe" in p) != (i >= dm["first_dense"]):
             raise ValueError(f"layer {i} is not of the kind the "
                              "configuration gives it")
-        x, near = one_layer(p, x, pos)
-        near_ties += int(near[:n].sum())
-    logits = head(weights["final_norm"], weights["head"], x[n - 1])
+        x, near = one_layer(p, x, jnp.int32(n))
+        near_ties += int(near)
+    logits = head(weights["final_norm"], weights["head"],
+                  x[jnp.int32(n - 1)])
     return np.asarray(logits, np.float32), near_ties
 
 
@@ -357,15 +476,22 @@ def measure(logits: np.ndarray, answer, k: int):
             float(max(0.0, kth - ref.min())) / span)
 
 
-def compare(weights, sample, k: int, dm: dict) -> dict:
+def compare(weights, sample, k: int, dm: dict, reach=None,
+            stop=None) -> dict:
     """``sample``: [(ids, [(item_row, served_score), ...]), ...]: the widest
     :func:`measure` of each answer against the reference's full forward over
-    its ``ids``."""
+    its ``ids``, in the sample's order. ``reach``: the longest history the
+    cell's traffic can send (:func:`shapes`; None: the sample's longest).
+    ``stop(compared so far)``: asked before each answer, true where no
+    further one is to be started (the driver's budget)."""
     score_err = rank_gap = 0.0
     malformed = compared = longest = near_ties = positions = n_long = 0
     expert_layers = sum(1 for p in weights["layers"] if "moe" in p)
+    reach = reach or max((len(ids) for ids, _ in sample), default=1)
     for ids, answer in sample:
-        logits, near = forward(weights, ids, dm)
+        if stop is not None and stop(compared + malformed):
+            break
+        logits, near = forward(weights, ids, dm, reach=reach)
         near_ties += near
         positions += len(ids) * expert_layers
         got = measure(logits, answer, k)

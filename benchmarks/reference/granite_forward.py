@@ -42,9 +42,9 @@ import functools
 import numpy as np
 
 FORMATS = {"bfloat16": (8, 7), "float8_e4m3fn": (4, 3)}
-#: a history is padded to a multiple of this before the jitted parts see it
-#: (causal and recurrent: positions after the last real one change nothing
-#: before them), so that 32 sampled histories compile a handful of shapes
+#: a history is padded to one of :func:`shapes`' whole multiples of this
+#: before the jitted parts see it (causal and recurrent: positions after the
+#: last real one change nothing before them)
 PAD_TO = 1024
 #: [heads, T, T] float32 scores held at once
 SCORE_BYTES = 1 << 30
@@ -215,47 +215,71 @@ def layer(p, x, kind, dm, held, hold=None, state_hold=None):
 @functools.lru_cache(maxsize=None)
 def _jitted(dm_items, hold, state_hold):
     import jax
+    import jax.numpy as jnp
 
     dm = dict(dm_items)
 
+    def embed(table, rows):
+        return table[rows].astype(jnp.float32) * dm["embed_mult"]
+
     def one_layer(kind):
-        def run(p, x):
+        def run(p, x, n):
             with jax.default_matmul_precision("highest"):
-                return layer(p, x, kind, dm, dm["held"], hold, state_hold)
+                out, near = layer(p, x, kind, dm, dm["held"], hold,
+                                  state_hold)
+                return out, jnp.sum(near & (jnp.arange(x.shape[0]) < n))
         return jax.jit(run)
 
-    def head(final_norm, table, h):
+    def head(final_norm, table, x, n):
         with jax.default_matmul_precision("highest"):
-            return _mm(rms(h, final_norm, dm["eps"])[None], table.T,
+            return _mm(rms(x[n - 1], final_norm, dm["eps"])[None], table.T,
                        hold)[0] / dm["logits_scaling"]
 
-    return ({kind: one_layer(kind) for kind in set(dm["layer_types"])},
+    return (jax.jit(embed),
+            {kind: one_layer(kind) for kind in set(dm["layer_types"])},
             jax.jit(head))
 
 
-def forward(weights, ids, dm, hold=None, state_hold=None):
+def shapes(reach: int) -> tuple:
+    """The padded lengths of a cell whose histories reach ``reach``
+    positions, shortest first: a third, two thirds and the whole of it, in
+    whole multiples of :data:`PAD_TO` (at most three shapes a layer kind
+    where every multiple had its own; the real length goes in as an
+    argument, so no length compiles anything of its own)."""
+    top = -(-reach // PAD_TO)
+    return tuple(sorted({-(-top * i // 3) * PAD_TO for i in (1, 2, 3)}))
+
+
+def padded_length(n: int, reach: int | None = None) -> int:
+    """The first of :func:`shapes` that holds ``n`` positions (``reach``:
+    None, the history's own length)."""
+    return next(s for s in shapes(max(n, reach or n)) if s >= n)
+
+
+def forward(weights, ids, dm, hold=None, state_hold=None, reach=None):
     """Logits [V] after the history ``ids`` (rows of the item table, oldest
     first), and how many of its (position, layer) pairs had a near tie at
     the router's cut: each is a place where a rounding can send a token to
-    another expert than the reference's."""
+    another expert than the reference's. ``reach``:
+    :func:`padded_length`'s."""
     import jax.numpy as jnp
 
-    layers, head = _jitted(tuple(sorted(dm.items())), hold, state_hold)
+    embed, layers, head = _jitted(tuple(sorted(dm.items())), hold,
+                                  state_hold)
     n = len(ids)
-    padded = -(-n // PAD_TO) * PAD_TO
-    rows = np.zeros(padded, np.int32)
+    rows = np.zeros(padded_length(n, reach), np.int32)
     rows[:n] = np.asarray(ids, np.int32)
-    x = (weights["embed"][jnp.asarray(rows)].astype(jnp.float32)
-         * dm["embed_mult"])
+    x = embed(weights["embed"], jnp.asarray(rows))
     near_ties = 0
     for kind, p in zip(dm["layer_types"], weights["layers"]):
-        x, near = layers[kind](p, x)
-        near_ties += int(near[:n].sum())
-    logits = head(weights["final_norm"], weights["embed"], x[n - 1])
+        x, near = layers[kind](p, x, jnp.int32(n))
+        near_ties += int(near)
+    logits = head(weights["final_norm"], weights["embed"], x, jnp.int32(n))
     return np.asarray(logits, np.float32), near_ties
 
 
-def compare(weights, sample, k: int, dm: dict) -> dict:
+def compare(weights, sample, k: int, dm: dict, reach=None,
+            stop=None) -> dict:
     """``sample``: [(ids, [(item_row, served_score), ...]), ...]. For each,
     the reference's full forward over ``ids``:
 
@@ -264,12 +288,20 @@ def compare(weights, sample, k: int, dm: dict) -> dict:
       the catalogue);
     * ``rank_gap``: the widest gap by which a served item's reference logit
       lies below the reference's k-th best, relative to the same range.
+
+    In the sample's order. ``reach``: the longest history the cell's traffic
+    can send (:func:`shapes`; None: the sample's longest). ``stop(compared
+    so far)``: asked before each answer, true where no further one is to be
+    started (the driver's budget).
     """
     score_err = rank_gap = 0.0
     malformed = compared = longest = near_ties = positions = 0
+    reach = reach or max((len(ids) for ids, _ in sample), default=1)
     for ids, answer in sample:
+        if stop is not None and stop(compared + malformed):
+            break
         items = [i for i, _ in answer]
-        logits, near = forward(weights, ids, dm)
+        logits, near = forward(weights, ids, dm, reach=reach)
         near_ties += near
         positions += len(ids) * len(weights["layers"])
         if (len(items) != k or len(set(items)) != k or min(items) < 0

@@ -56,9 +56,9 @@ import math
 import numpy as np
 
 FORMATS = {"bfloat16": (8, 7), "float8_e4m3fn": (4, 3)}
-#: a sequence is padded to a multiple of this before the jitted parts see it
-#: (block-causal: whole blocks after the last real one change nothing before
-#: them), so that the sampled forwards compile a handful of shapes
+#: a sequence is padded to one of :func:`shapes`' whole multiples of this
+#: before the jitted parts see it (block-causal: whole blocks after the last
+#: real one change nothing before them)
 PAD_TO = 512
 #: experts whose matrices are widened to float32 at once
 EXPERT_GROUP = 8
@@ -183,6 +183,7 @@ def layer(p, x, pos, dm, hold=None):
 @functools.lru_cache(maxsize=None)
 def _jitted(dm_items, hold):
     import jax
+    import jax.numpy as jnp
 
     dm = dict(dm_items)
 
@@ -190,30 +191,53 @@ def _jitted(dm_items, hold):
         with jax.default_matmul_precision("highest"):
             return layer(p, x, pos, dm, hold)
 
-    def head(final_norm, table, h):
+    def embed(table, rows):
+        return table[rows].astype(jnp.float32)
+
+    def head(final_norm, table, x, n, last):
         with jax.default_matmul_precision("highest"):
+            h = jax.lax.dynamic_slice_in_dim(x, n - last, last)
             return _mm(rms(h, final_norm, dm["eps"]), table.T, hold)
 
-    return jax.jit(one_layer), jax.jit(head)
+    return (jax.jit(embed), jax.jit(one_layer),
+            jax.jit(head, static_argnums=4))
 
 
-def forward(weights, ids, dm, hold=None, last=None):
+def shapes(reach: int) -> tuple:
+    """The padded lengths of a cell whose sequences reach ``reach``
+    positions, shortest first: a third, two thirds and the whole of it, in
+    whole multiples of :data:`PAD_TO` (at most three shapes where every
+    multiple had its own; the real length goes in as an argument, so no
+    length compiles anything of its own)."""
+    top = -(-reach // PAD_TO)
+    return tuple(sorted({-(-top * i // 3) * PAD_TO for i in (1, 2, 3)}))
+
+
+def padded_length(n: int, reach: int | None = None) -> int:
+    """The first of :func:`shapes` that holds ``n`` positions (``reach``:
+    None, the sequence's own length)."""
+    return next(s for s in shapes(max(n, reach or n)) if s >= n)
+
+
+def forward(weights, ids, dm, hold=None, last=None, reach=None):
     """Logits [last, V] at the last ``last`` positions (default: one block)
-    of the full forward over ``ids`` (rows of the item table; whole blocks)."""
+    of the full forward over ``ids`` (rows of the item table; whole blocks).
+    ``reach``: :func:`padded_length`'s."""
     import jax.numpy as jnp
 
-    one_layer, head = _jitted(tuple(sorted(dm.items())), hold)
+    embed, one_layer, head = _jitted(tuple(sorted(dm.items())), hold)
     n, last = len(ids), last or dm["bl"]
     if n % dm["bl"]:
         raise ValueError("a forward runs over whole blocks")
-    padded = -(-n // PAD_TO) * PAD_TO
+    padded = padded_length(n, reach)
     rows = np.zeros(padded, np.int32)
     rows[:n] = np.asarray(ids, np.int32)
-    x = weights["embed"][jnp.asarray(rows)].astype(jnp.float32)
+    x = embed(weights["embed"], jnp.asarray(rows))
     pos = jnp.arange(padded, dtype=jnp.int32)
     for p in weights["layers"]:
         x = one_layer(p, x, pos)
-    logits = head(weights["final_norm"], weights["head"], x[n - last:n])
+    logits = head(weights["final_norm"], weights["head"], x, jnp.int32(n),
+                  int(last))
     return np.asarray(logits, np.float32)
 
 
@@ -323,9 +347,14 @@ def state_before(history, items, forward_index: int, block: int, gen: dict):
     return x
 
 
-def compare(weights, sample, generate: int, gen: dict, dm: dict, pick) -> dict:
-    """``sample``: [(history rows, items)]; ``pick(n_denoise)`` chooses which
-    of an answer's denoise forwards are recomputed. For each chosen forward
+def compare(weights, sample, generate: int, gen: dict, dm: dict, pick,
+            reach=None, stop=None) -> dict:
+    """``sample``: [(history rows, items)], compared in its order;
+    ``pick(n_denoise)`` chooses which of an answer's denoise forwards are
+    recomputed. ``reach``: the longest sequence the cell's traffic can make
+    (:func:`shapes`; None: each forward's own). ``stop(compared so far)``:
+    asked before each answer, true where no further one is to be started (the
+    driver's budget). For each chosen forward
     the reference's full forward over the sequence as it stood gives the
     logits at the block's positions; over the positions it unmasked:
 
@@ -344,6 +373,8 @@ def compare(weights, sample, generate: int, gen: dict, dm: dict, pick) -> dict:
            "longest_history": 0, "why_malformed": []}
     B, mask = int(gen["block_len"]), int(gen["mask_row"])
     for history, items in sample:
+        if stop is not None and stop(out["compared"] + out["malformed"]):
+            break
         H = len(history)
         try:
             forwards = rebuild(H, generate, items, gen)
@@ -357,7 +388,8 @@ def compare(weights, sample, generate: int, gen: dict, dm: dict, pick) -> dict:
             fw = forwards[f]
             lo = fw["block"] * B
             logits = forward(weights, state_before(history, items, f,
-                                                   fw["block"], gen), dm)
+                                                   fw["block"], gen), dm,
+                             reach=reach)
             logits = logits.astype(np.float64)
             logits[:, mask] = -np.inf
             finite = np.where(np.isfinite(logits), logits, np.nan)

@@ -72,6 +72,10 @@ class Bench:
             self.config = json.load(f)
         self.traffic = self.load_json("traffic", cell["traffic"])
         self.compiles = CompileCounter()
+        #: where the run's seconds went: [(phase, seconds, seconds of them
+        #: compiling, compilations)], each phase ending at its ``mark``
+        self.phases = []
+        self._marked = (T_PROCESS_START, 0.0, 0)
         #: a scratch directory inside the checkout (traces, child output)
         self.scratch = os.path.join(CHECKOUT, ".pio_run", "bench",
                                     self.name)
@@ -100,6 +104,29 @@ class Bench:
         """A shared module of the benchmark (``seeded``, ``trace_reduce``,
         ...): ``<path>/<name>.py``."""
         return self.load_module(".", name)
+
+    # -- the run's own account ------------------------------------------
+    def mark(self, phase: str) -> None:
+        """The phase that ends now (it began at the last mark, the first at
+        the process's start). For the ``# run:`` line only: no metric reads
+        it."""
+        t, seconds, count = self._marked
+        self._marked = (time.time(), self.compiles.seconds,
+                        self.compiles.count)
+        self.phases.append((phase, self._marked[0] - t,
+                            self._marked[1] - seconds,
+                            self._marked[2] - count))
+
+    def account(self) -> str:
+        """``# run:``'s line: every marked phase, what it spent compiling
+        apart, then what is left and the whole process."""
+        self.mark("rest")
+        parts = [f"{name} {s:.1f}" + (f" ({c:.1f} compiling, {n} programs)"
+                                      if n else "")
+                 for name, s, c, n in self.phases
+                 if name != "rest" or s > 0.05]
+        return ("run: seconds by phase: " + ", ".join(parts)
+                + f"; whole process {time.time() - T_PROCESS_START:.1f}")
 
     # -- metrics --------------------------------------------------------
     def metrics_for(self, group: str):
@@ -148,6 +175,7 @@ class CompileCounter:
 
     def __init__(self):
         self.count = 0
+        self.seconds = 0.0        # the backend's own, for the account
         self._installed = False
 
     def install(self) -> None:
@@ -158,6 +186,7 @@ class CompileCounter:
         def on_duration(event, duration, **kw):
             if event.endswith("backend_compile_duration"):
                 self.count += 1
+                self.seconds += float(duration)
 
         monitoring.register_event_duration_secs_listener(on_duration)
         self._installed = True
@@ -255,6 +284,7 @@ def main(argv=None) -> int:
     say(f"# compilations inside the window: {result['window_compiles']}")
     for line in result.get("notes", ()):
         say(f"# {line}")
+    say(f"# {bench.account()}")
 
     d0 = devices[0]
     device = {"platform": d0.platform, "kind": d0.device_kind,

@@ -11,7 +11,8 @@ sessions, then ``GO`` on standard input; the requests that START in the
 ``seconds`` after ``GO`` are the window's. The last line is one JSON object
 with every latency (tagged first query / extension) and a seeded sample of
 the window's answers: half first queries, half extensions, the longest
-history served among them, each with the item rows its query carried.
+history served and the longest later query among them, each with the item
+rows its query carried.
 """
 
 from __future__ import annotations
@@ -170,6 +171,12 @@ def main() -> int:
         longest = max(every, key=lambda cj: len(rows_of(*cj)))
         if longest not in pick:
             pick[0 if longest in firsts or not later else -1] = longest
+        # ... and the longest later query (the same answer unless a session
+        # of the longest history had only begun): what ``check_floor`` names
+        longest_later = max(later, key=lambda cj: len(rows_of(*cj)),
+                            default=longest)
+        if longest_later not in pick:
+            pick[-1] = longest_later
     sample = [{"rows": rows_of(c, j),
                "first": is_first(c, j),
                "body": answers[c][j][1].decode("utf-8", "replace")}

@@ -172,6 +172,12 @@ def main() -> int:
         longest = max(every, key=lambda cj: len(rows_of(*cj)))
         if longest not in pick:
             pick[0 if longest in firsts or not later else -1] = longest
+        # ... and the longest later query (the same answer unless a session
+        # of the longest history had only begun): what ``check_floor`` names
+        longest_later = max(later, key=lambda cj: len(rows_of(*cj)),
+                            default=longest)
+        if longest_later not in pick:
+            pick[-1] = longest_later
     sample = [{"rows": rows_of(c, j),
                "first": is_first(c, j),
                "body": answers[c][j][1].decode("utf-8", "replace")}

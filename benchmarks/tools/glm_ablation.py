@@ -179,9 +179,7 @@ def sets_at_the_cut(bench, rows: int) -> None:
     @jax.jit
     def plain(u):
         with jax.default_matmul_precision("highest"):
-            cq = reference.rms(reference._mm(u, p["w_dq"], None),
-                               p["q_norm"], dm["eps"])
-            qi, ki, w = reference.index_parts(p, u, cq, pos, dm)
+            _, (_, _, ki), (qi, w) = reference.latents(p, u, pos, dm)
             return reference.selected(qi[last], w[last], pos[last], ki, pos,
                                       dm)
 

@@ -143,10 +143,12 @@ def test_the_traffic_holds_the_parameters_the_issue_names():
              "sessions_seed": 39, "warmup_sessions_per_connection": 2,
              "check_sample": 16, "trace_seconds": 3.0}
     assert {k: mix[k] for k in named} == named
-    # what the accepted driver and load generator need beside them
+    # what the accepted driver and load generator need beside them, and
+    # the comparison's budget (ISSUE 48: test_check_budget.py pins the two)
     assert set(mix) - set(named) == {
         "driver", "loop", "num", "prepared_sessions_per_connection",
-        "trace_after_go_s", "start", "start_why"}
+        "trace_after_go_s", "start", "start_why", "check_budget_s",
+        "check_floor"}
     assert (mix["driver"], mix["num"]) == ("session_queries", 10)
     assert [w.get("delay_s", 0.0) for w in mix["start"]] == [0, .05, .05, .05]
     assert sum(w["connections"] for w in mix["start"]) == 4

@@ -148,9 +148,11 @@ def test_the_traffic_holds_the_parameters_the_issue_names():
     # prefill (a 32,768-event session) and hold NO extension: the three
     # readers of the extension program found nothing (PERF.md section 6)
     assert mix["trace_after_go_s"] == 8.0
-    # what the accepted driver and load generator need beside them
+    # what the accepted driver and load generator need beside them, and
+    # the comparison's budget (ISSUE 48: test_check_budget.py pins the two)
     assert set(mix) - set(named) == {"driver", "loop", "start", "start_why",
-                                     "trace_after_go_s"}
+                                     "trace_after_go_s", "check_budget_s",
+                                     "check_floor"}
     assert mix["driver"] == "session_queries"
     assert [w.get("delay_s", 0.0) for w in mix["start"]] == [0, .05, .05, .05]
     assert sum(w["connections"] for w in mix["start"]) == 4
