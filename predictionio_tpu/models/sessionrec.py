@@ -23,6 +23,7 @@ from predictionio_tpu.core import Algorithm, SanityCheck
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.obs import trace
+from predictionio_tpu.ops.gqa import ring_len
 from predictionio_tpu.ops.sessionrec import (
     ServeShape,
     SessionRecConfig,
@@ -239,17 +240,35 @@ class LatentCache:
       state is already past it) are misses for every layer together, the
       per-position ones too; where the other rule would have hit, a marker
       (``seq.cache.rewind_miss``) says so, and the session starts over in
-      the slot it had.
+      the slot it had;
+    * some mixer keeps a RING (``ring`` rows for a ``window`` of positions:
+      a sliding-window layer beside layers whose spans grow): the slot holds
+      its last ``ring`` positions and no earlier one, and a query at position
+      ``p`` attends ``p - (window - 1) .. p``. The prefix rule stands, but a
+      slot can resume at ``p`` only while its rings still hold ``p - (window
+      - 1) .. p - 1`` (``floor``: the earliest position every ring of the
+      slot still holds): "grew" always can, "went back a little" can by up
+      to ``ring - window`` positions, and past that it is a miss for every
+      layer together, the spans too (``seq.cache.ring_miss``), from position
+      0 in the slot the session had. A slot's rings and spans are evicted
+      and reused together: they are one slot.
 
     A miss takes the least recently used free slot and the history is
     prefilled from its start — from a ZERO state: the programs start any
     call at position 0 so, whatever the slot held."""
 
-    def __init__(self, n_slots: int, block: int = 1, recurrent: bool = False):
+    def __init__(self, n_slots: int, block: int = 1, recurrent: bool = False,
+                 ring: int = 0, window: int = 0):
         self.rows = [np.zeros(0, np.int32) for _ in range(n_slots)]
         self.busy = [False] * n_slots
         self.used = [0] * n_slots
         self.block, self.recurrent = block, recurrent
+        self.ring, self.window = ring, window
+        #: the earliest position each slot's rings still hold
+        self.floor = [0] * n_slots
+        #: queries the prefix rule would have resumed and the rings could
+        #: not, and the positions it would have found
+        self.ring_misses = self.ring_miss_tokens = 0
         self._clock = 0
         self.hit_tokens = self.miss_tokens = self.evictions = 0
         #: hits of a stack with recurrent state; queries a per-position
@@ -279,6 +298,14 @@ class LatentCache:
         cached -= cached % self.block
         if cached < 1 or 2 * shared[best] < len(self.rows[slot]):
             return min(free, key=self.used.__getitem__), 0
+        if self.ring and max(cached - (self.window - 1), 0) < self.floor[slot]:
+            self.ring_misses += 1
+            self.ring_miss_tokens += cached
+            with trace.device_span("seq.cache.ring_miss", slot=slot,
+                                   shared=shared[best],
+                                   floor=self.floor[slot]):
+                pass
+            return slot, 0
         if not self.recurrent:
             return slot, cached
         self.rewind_misses += 1
@@ -297,9 +324,11 @@ class LatentCache:
             return None
         with trace.device_span("seq.cache.lookup", rows=len(rows)):
             slot, cached = self._match(free, rows)
-            if not cached and len(self.rows[slot]):
-                self.evictions += 1
-                self.rows[slot] = np.zeros(0, np.int32)
+            if not cached:      # written anew from position 0: all is held
+                self.floor[slot] = 0
+                if len(self.rows[slot]):
+                    self.evictions += 1
+                    self.rows[slot] = np.zeros(0, np.int32)
         self.hit_tokens += cached
         self.miss_tokens += len(rows) - cached
         self.busy[slot] = True
@@ -310,6 +339,8 @@ class LatentCache:
         state: it stands at their end)."""
         self._clock += 1
         self.rows[slot] = rows
+        if self.ring:       # what was written pushed the earliest rows out
+            self.floor[slot] = max(self.floor[slot], len(rows) - self.ring)
         self.used[slot] = self._clock
         self.busy[slot] = False
 
@@ -445,9 +476,12 @@ class SeqStackModel:
         self.gen = spec.generation
         #: the kinds of cache the stack's mixers keep
         self.kinds = {b.mixer for b in spec.blocks}
-        self.cache = LatentCache(self.shape.n_slots,
-                                 self.gen.block_len if self.gen else 1,
-                                 recurrent="mamba2" in self.kinds)
+        window = (spec.gqa_window.window if "gqa_window" in self.kinds
+                  else 0)
+        self.cache = LatentCache(
+            self.shape.n_slots, self.gen.block_len if self.gen else 1,
+            recurrent="mamba2" in self.kinds, window=window,
+            ring=ring_len(window, self.shape.chunk) if window else 0)
         self._programs = None
         self._index = None
         self._inverse = None
@@ -477,6 +511,9 @@ class SeqStackModel:
             # extension gathered them)
             "extend_latents_gathered": 0,
             "extend_kv_positions": 0, "extend_state_rows": 0,
+            # the cached positions the rows' attention read in a WINDOW
+            # layer: each row's new positions and the window before them
+            "extend_window_positions": 0,
             # from a ticket's admission to the launch of the first program
             # that carries rows of it, summed, and the tickets summed over:
             # by that program, a prefill chunk (a first query in the FIFO
@@ -576,6 +613,8 @@ class SeqStackModel:
                 "evictions": c.evictions, "state_resumes": c.state_resumes,
                 "rewind_misses": c.rewind_misses,
                 "rewind_miss_tokens": c.rewind_miss_tokens,
+                "ring_misses": c.ring_misses,
+                "ring_miss_tokens": c.ring_miss_tokens,
                 "index": self._index.stats() if self._index else None}
 
     # -- one query, in steps --------------------------------------------------
@@ -672,6 +711,9 @@ class SeqStackModel:
         did.update((counter, n) for kind, counter, n in (
             ("mla", "extend_latent_positions", reach),
             ("gqa", "extend_kv_positions", reach),
+            ("gqa_window", "extend_window_positions", sum(
+                len(t.rows) - max(0, t.done - self.cache.window + 1)
+                for t in ext)),
             ("mamba2", "extend_state_rows", len(ext)))
             if kind in self.kinds)
         if programs.indexed:

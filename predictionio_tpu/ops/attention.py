@@ -78,6 +78,7 @@ def _accum_block(
     scale: Optional[float] = None,   # None: q's head width ** -0.5
     keep: Optional[jax.Array] = None,  # [Lq, Lk] or [B, Lq, Lk]: each
                                        # row's own set of key positions
+    window: int = 0,     # > 0: a query sees itself and the window - 1 before
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One online-softmax update: fold the (q, k/v-block) partial into
     the (m, l, o) accumulators. The rescaling trick is the standard
@@ -92,6 +93,8 @@ def _accum_block(
         # ``q_pos``/``k_pos`` are [L] (one set of positions for the batch)
         # or [B, L] (each row its own: sessions of different lengths)
         mask = q_pos[..., :, None] >= k_pos[..., None, :]
+        if window:
+            mask = mask & (q_pos[..., :, None] - k_pos[..., None, :] < window)
         if keep is not None:
             mask = mask & keep
         s = jnp.where(mask[None, None] if mask.ndim == 2 else mask[:, None],
@@ -157,7 +160,8 @@ def blockwise_attention(
 def attend_over_blocks(q, q_pos, kv_block, n_blocks, block_size: int,
                        v_dim: int, dtype=None,
                        scale: Optional[float] = None,
-                       keep_block=None) -> jax.Array:
+                       keep_block=None, first_block=None, window: int = 0,
+                       sink=None) -> jax.Array:
     """Causal attention of ``q`` [B, Lq, H, Dk] (positions ``q_pos``, [Lq]
     or [B, Lq]) over keys and values that ``kv_block(j)`` produces one block
     at a time — ``(k [B, block, H, Dk], v [B, block, H, Dv])`` for the key
@@ -171,17 +175,32 @@ def attend_over_blocks(q, q_pos, kv_block, n_blocks, block_size: int,
     block]``: a learned index's selection), on top of the causal mask. A row
     that keeps nothing of its first blocks carries a running maximum of
     ``_NEG`` through them, and the first kept key's ``alpha`` (``exp(_NEG -
-    m)``, exactly 0) wipes what they added: every row must keep some key."""
+    m)``, exactly 0) wipes what they added: every row must keep some key.
+
+    A WINDOW (``window`` > 0: a query sees its own position and the ``window
+    - 1`` before it) needs no walk from 0: ``first_block`` (a scalar, or [B]:
+    each row of the batch its own) is the block of keys that round 0 holds,
+    ``kv_block(j)`` gives round ``j``'s, and ``n_blocks`` counts rounds. A
+    SINK (``sink`` [H, Lq] float32: a learned logit that joins each head's
+    normaliser and nothing else) is where the running maximum starts, with
+    the normaliser at ``exp(sink - sink)``."""
     B, Lq, H, _ = q.shape
-    carry = (jnp.full((B, H, Lq), _NEG, jnp.float32),
-             jnp.zeros((B, H, Lq), jnp.float32),
-             jnp.zeros((B, Lq, H, v_dim), jnp.float32))
+    if sink is None:
+        carry = (jnp.full((B, H, Lq), _NEG, jnp.float32),
+                 jnp.zeros((B, H, Lq), jnp.float32))
+    else:
+        carry = (jnp.broadcast_to(sink, (B, H, Lq)),
+                 jnp.ones((B, H, Lq), jnp.float32))
+    carry += (jnp.zeros((B, Lq, H, v_dim), jnp.float32),)
 
     def body(j, carry):
         k, v = kv_block(j)
-        k_pos = j * block_size + jnp.arange(block_size)
+        at = j if first_block is None else jnp.asarray(
+            first_block + j)[..., None]
+        k_pos = at * block_size + jnp.arange(block_size)
         keep = None if keep_block is None else keep_block(j)
-        return _accum_block(q, k, v, *carry, q_pos, k_pos, True, scale, keep)
+        return _accum_block(q, k, v, *carry, q_pos, k_pos, True, scale, keep,
+                            window)
 
     m, l, o = jax.lax.fori_loop(0, n_blocks, body, carry)
     return _finish(m, l, o, dtype or q.dtype)

@@ -1,26 +1,49 @@
 """Grouped-query attention over a per-session cache of keys and values, as
-its ``GQADims`` say: the mixer of a block-diffusion stack (a block-causal
-mask, per-head norms, RoPE) and the attention layer of a stack of recurrent
-mixers (a causal mask, no position encoding, no norms, a score scale of the
-model's own).
+its ``GQADims`` say. Three stacks run it: a block-diffusion stack (a
+block-causal mask, per-head norms, RoPE on every dimension), the attention
+layer of a stack of recurrent mixers (a causal mask, no position encoding, no
+norms, a score scale of the model's own), and a stack that MIXES full and
+sliding-window layers under two dims of its own (heads of 192 for queries and
+keys beside values of 128, RoPE on the head's leading 64 dimensions at two
+bases, the values scaled, 4 and 8 key/value heads, a window of 128 positions
+with a learned sink a head).
 
 ``heads`` query heads share ``kv_heads`` key/value heads (``heads //
-kv_heads`` to one). Where the stack says so (``qk_norm``, ``rope``), queries
-and keys are RMS-normed per head over the head's own dimensions (a learned
-weight of ``head_dim`` each) and turned by RoPE on all of them, pairs ``(i,
-i + head_dim / 2)`` (rotate-half), at absolute positions. Scores are scaled
-by ``scale`` (``1 / sqrt(head_dim)`` where none is given). Position ``i``
-sees ``j`` iff ``j // block_len <= i // block_len``: causal from block to
-block, bidirectional inside one; a block length of 1 is the causal mask.
-Every key a position sees therefore lies at or before the LAST position of
-its own block, which is the position the causal loop of
-``ops.attention.attend_over_blocks`` is given for it.
+kv_heads`` to one). Keys and queries are ``head_dim`` wide, values ``v_dim``
+(``v_head_dim``, or ``head_dim`` where none is given), multiplied by
+``value_scale`` where they are made. Where the stack says so (``qk_norm``,
+``rope``), queries and keys are RMS-normed per head over the head's own
+dimensions (a learned weight of ``head_dim`` each) and turned by RoPE on their
+leading ``rope_dims`` (all of them where none is given), pairs ``(i, i +
+rope_dims / 2)`` (rotate-half), at absolute positions; the other dimensions go
+as projected. Scores are scaled by ``scale`` (``1 / sqrt(head_dim)`` where
+none is given). Position ``i`` sees ``j`` iff ``j // block_len <= i //
+block_len``: causal from block to block, bidirectional inside one; a block
+length of 1 is the causal mask, and under it a ``window`` (> 0) narrows the
+sight to ``0 <= i - j < window``. Every key a position sees lies at or before
+the LAST position of its own block, which is the position the causal loop of
+``ops.attention.attend_over_blocks`` is given for it. A ``sink`` is a learned
+logit a query head (the parameter leaf ``sink [heads]``, there only where the
+dims say so) that joins the softmax's normaliser and nothing else: ``P_ij =
+exp(s_ij - m) / (sum_j exp(s_ij - m) + exp(b_h - m))``.
 
-What a cache holds is a position's keys and values themselves, ``2 *
-kv_heads * head_dim`` values side by side (keys first), in the cache's type.
+What a cache ROW holds is a position's keys and values themselves,
+``kv_heads * (head_dim + v_dim)`` values side by side (keys first), in the
+cache's type. Two layouts:
+
+* a SPAN ``[slots, P, width]``: position ``t`` at row ``t``, as long as the
+  longest session; attention walks its blocks from 0 to the reach
+  (:func:`prefill_chunk`, :func:`block_step`, :data:`extend`);
+* a RING ``[slots, R, width]`` for a WINDOW layer (:func:`ring_len`: the
+  window and one chunk, in whole blocks of ``window`` positions): position
+  ``t`` at row ``t mod R``, real positions only, so a slot holds its last ``R``
+  positions; attention walks the blocks that hold the queries' windows and no
+  other, at most ``ceil((C + window - 1) / window) + 1`` rounds whatever the
+  reach (:func:`window_prefill_chunk`, :func:`window_extend`).
+
 Two paths over one set of weights, as in ``ops/mla.py``:
 
-* :func:`prefill_chunk`: whole blocks of ONE session against its slot;
+* a chunk: whole blocks of ONE session against its slot;
 * :func:`block_step`: one block (``block_len`` positions) of each of several
   sessions against their slots — a block being denoised or a finished block
   being committed, the program is the same. Under the causal mask the same
@@ -31,11 +54,12 @@ slot up to the end of those positions. A block's keys and values depend on
 every position of the block, so what a forward over an unfinished block
 writes is provisional: it lies beyond what the slot's owner counts as held,
 and the forward over the finished block writes over it before anything
-later attends to it. Both give the numbers of :func:`attend_full` (scores
-materialised, no cache), which is the plain form the tests hold them to.
+later attends to it. All give the numbers of :func:`attend_full` (scores
+materialised, no cache, the window a mask), which is the plain form the
+tests hold them to.
 
 Matrix products take their inputs in the weights' type and accumulate in
-float32; norms, RoPE and softmax are float32.
+float32; norms, RoPE, softmax and sinks are float32.
 """
 
 from __future__ import annotations
@@ -63,24 +87,37 @@ class GQADims:
     rope: bool = True           # False: no position encoding
     qk_norm: bool = True        # False: queries and keys as projected
     scale: Optional[float] = None    # of the scores; None: head_dim ** -0.5
+    v_head_dim: Optional[int] = None     # of a value head; None: head_dim
+    rope_dims: Optional[int] = None  # leading dims RoPE turns; None: all
+    window: int = 0             # positions a query sees, itself among them; 0: all
+    sink: bool = False          # a learned logit a head in the normaliser
+    value_scale: float = 1.0    # what the values are multiplied by
+
+    def __post_init__(self):
+        if self.window and self.block_len != 1:
+            raise ValueError("a window is a causal mask's: block_len 1")
 
     @property
     def group(self) -> int:
         return self.heads // self.kv_heads
 
     @property
+    def v_dim(self) -> int:
+        return self.head_dim if self.v_head_dim is None else self.v_head_dim
+
+    @property
     def cache_width(self) -> int:
         """Values a cached position takes: keys, then values."""
-        return 2 * self.kv_heads * self.head_dim
+        return self.kv_heads * (self.head_dim + self.v_dim)
 
 
 def init(key, dims: GQADims, dtype=jnp.float32) -> dict:
-    """N(0, 1 / fan_in) matrices, unit norms."""
+    """N(0, 1 / fan_in) matrices, unit norms, N(0, 1) sinks."""
     d = dims
     shapes = {"w_q": (d.dim, d.heads * d.head_dim),
               "w_k": (d.dim, d.kv_heads * d.head_dim),
-              "w_v": (d.dim, d.kv_heads * d.head_dim),
-              "w_o": (d.heads * d.head_dim, d.dim)}
+              "w_v": (d.dim, d.kv_heads * d.v_dim),
+              "w_o": (d.heads * d.v_dim, d.dim)}
     out = {n: (jax.random.normal(k, s, jnp.float32) / math.sqrt(s[0])
                ).astype(dtype)
            for (n, s), k in zip(shapes.items(),
@@ -88,6 +125,10 @@ def init(key, dims: GQADims, dtype=jnp.float32) -> dict:
     if d.qk_norm:
         out["q_norm"] = jnp.ones((d.head_dim,), dtype)
         out["k_norm"] = jnp.ones((d.head_dim,), dtype)
+    if d.sink:
+        out["sink"] = jax.random.normal(
+            jax.random.fold_in(key, len(shapes)), (d.heads,),
+            jnp.float32).astype(dtype)
     return out
 
 
@@ -111,14 +152,26 @@ def project(p, dims: GQADims, x, pos):
     lead = x.shape[:-1]
     q = mm(x, p["w_q"]).reshape(lead + (d.heads, d.head_dim))
     k = mm(x, p["w_k"]).reshape(lead + (d.kv_heads, d.head_dim))
-    v = mm(x, p["w_v"]).reshape(lead + (d.kv_heads, d.head_dim))
+    v = mm(x, p["w_v"]).reshape(lead + (d.kv_heads, d.v_dim))
+    if d.value_scale != 1.0:
+        v = d.value_scale * v
     if d.qk_norm:
         q = rms_norm(q, p["q_norm"], d.eps)
         k = rms_norm(k, p["k_norm"], d.eps)
     if d.rope:
-        q = rope_half(q, pos, d.rope_theta)
-        k = rope_half(k, pos, d.rope_theta)
+        q = _turned(d, q, pos)
+        k = _turned(d, k, pos)
     return q, k, v
+
+
+def _turned(dims: GQADims, x, pos):
+    """RoPE on the head's leading ``rope_dims`` dimensions (all of them
+    where none is given), the others as projected."""
+    r = dims.rope_dims
+    if r is None or r == dims.head_dim:
+        return rope_half(x, pos, dims.rope_theta)
+    return jnp.concatenate(
+        [rope_half(x[..., :r], pos, dims.rope_theta), x[..., r:]], axis=-1)
 
 
 def block_end(pos, block_len: int):
@@ -131,9 +184,21 @@ def _out(p, o):
     return mm(o.reshape(o.shape[:-2] + (-1,)), p["w_o"])
 
 
+def sees(dims: GQADims, q_pos, k_pos):
+    """Whether the query at ``q_pos`` [T] sees the key at ``k_pos`` [U],
+    ``[T, U]``: the block-causal mask, and inside a window the query's own
+    position and the ``window - 1`` before it."""
+    out = block_end(q_pos, dims.block_len)[:, None] >= k_pos[None, :]
+    if dims.window:
+        out = out & (q_pos[:, None] - k_pos[None, :] < dims.window)
+    return out
+
+
 def attend_full(p, dims: GQADims, x, pos):
-    """Every position of ``x`` [T, dim] against every one its block may
-    see, scores materialised: the plain form."""
+    """Every position of ``x`` [T, dim] against every one its mask lets it
+    see, scores materialised: the plain form. A sink joins the softmax's
+    normaliser and nothing else: ``P_ij = exp(s_ij - m) / (sum_j exp(s_ij -
+    m) + exp(b_h - m))``."""
     d = dims
     T = x.shape[0]
     q, k, v = project(p, d, x, pos)
@@ -141,11 +206,16 @@ def attend_full(p, dims: GQADims, x, pos):
     s = jnp.einsum("tkgd,ukd->kgtu", q, k,
                    precision=jax.lax.Precision.HIGHEST)
     s = s / math.sqrt(d.head_dim) if d.scale is None else s * d.scale
-    sees = block_end(pos, d.block_len)[:, None] >= pos[None, :]
-    prob = jax.nn.softmax(jnp.where(sees[None, None], s, -jnp.inf), axis=-1)
+    s = jnp.where(sees(d, pos, pos)[None, None], s, -jnp.inf)
+    if d.sink:
+        b = p["sink"].astype(jnp.float32).reshape(d.kv_heads, d.group)
+        s = jnp.concatenate(
+            [s, jnp.broadcast_to(b[:, :, None, None], s.shape[:3] + (1,))],
+            axis=-1)
+    prob = jax.nn.softmax(s, axis=-1)[..., :T]
     o = jnp.einsum("kgtu,ukd->tkgd", prob, v,
                    precision=jax.lax.Precision.HIGHEST)
-    return _out(p, o.reshape(T, d.heads, d.head_dim))
+    return _out(p, o.reshape(T, d.heads, d.v_dim))
 
 
 def _to_cache(k, v, cache):
@@ -163,22 +233,41 @@ def _attend(dims: GQADims, q, pos, cache, slots, n_blocks, block: int):
     key/value head. ``[B, S, heads, d]`` float32."""
     d = dims
     B, S = pos.shape
-    half = d.kv_heads * d.head_dim
-    q = q.reshape(B, S, d.kv_heads, d.group, d.head_dim)
-    q = q.transpose(0, 1, 3, 2, 4).reshape(B, S * d.group, d.kv_heads,
-                                           d.head_dim).astype(cache.dtype)
+    q = _folded(d, q, cache.dtype)
     q_pos = jnp.repeat(block_end(pos, d.block_len), d.group, axis=1)
 
     def kv_block(j):
         rows = jax.vmap(lambda s: jax.lax.dynamic_slice(
             cache, (s, j * block, 0), (1, block, cache.shape[-1]))[0])(slots)
-        shape = (B, block, d.kv_heads, d.head_dim)
-        return rows[..., :half].reshape(shape), rows[..., half:].reshape(shape)
+        return _split(d, rows)
 
-    o = attend_over_blocks(q, q_pos, kv_block, n_blocks, block, d.head_dim,
+    o = attend_over_blocks(q, q_pos, kv_block, n_blocks, block, d.v_dim,
                            dtype=jnp.float32, scale=d.scale)
-    o = o.reshape(B, S, d.group, d.kv_heads, d.head_dim)
-    return o.transpose(0, 1, 3, 2, 4).reshape(B, S, d.heads, d.head_dim)
+    return _unfolded(d, o, B, S)
+
+
+def _split(dims: GQADims, rows):
+    """Cache rows [B, block, width] as ``(k [B, block, kv_heads, d], v [B,
+    block, kv_heads, v_dim])``."""
+    d, (B, block) = dims, rows.shape[:2]
+    half = d.kv_heads * d.head_dim
+    return (rows[..., :half].reshape(B, block, d.kv_heads, d.head_dim),
+            rows[..., half:].reshape(B, block, d.kv_heads, d.v_dim))
+
+
+def _folded(dims: GQADims, q, dtype):
+    """``q`` [B, S, heads, d] with each key/value head's group of queries
+    folded into the query axis: ``[B, S * group, kv_heads, d]``."""
+    d, (B, S) = dims, q.shape[:2]
+    q = q.reshape(B, S, d.kv_heads, d.group, d.head_dim)
+    return q.transpose(0, 1, 3, 2, 4).reshape(
+        B, S * d.group, d.kv_heads, d.head_dim).astype(dtype)
+
+
+def _unfolded(dims: GQADims, o, B: int, S: int):
+    d = dims
+    o = o.reshape(B, S, d.group, d.kv_heads, d.v_dim)
+    return o.transpose(0, 1, 3, 2, 4).reshape(B, S, d.heads, d.v_dim)
 
 
 def prefill_chunk(p, dims: GQADims, x, offset, cache, slot, block: int):
@@ -214,3 +303,101 @@ def block_step(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int):
 #: under the causal mask (``block_len`` 1) a row's positions are a few new
 #: positions of its session, each seeing the ones before it: an extension
 extend = block_step
+
+
+# -- a window layer's ring -----------------------------------------------------
+
+def ring_len(window: int, chunk: int) -> int:
+    """Rows of a window layer's ring: the window and one chunk, in whole
+    blocks of ``window`` positions (the blocks its walk takes)."""
+    return -(-(window + chunk) // window) * window
+
+
+def window_rounds(dims: GQADims, first, last):
+    """``(first block, rounds)`` of the walk of queries at positions
+    ``first`` .. ``last`` (traced, any equal shapes): the blocks of ``window``
+    positions that hold ``[first - (window - 1), last]``. A walk from 0
+    would take ``last // window + 1`` rounds."""
+    w = dims.window
+    at = jnp.maximum(first - (w - 1), 0) // w
+    return at, last // w - at + 1
+
+
+def _attend_ring(p, dims: GQADims, q, pos, last, ring, slots):
+    """``q`` [B, S, heads, d] at ``pos`` [B, S] over the slots ``slots`` [B]
+    of ``ring`` [slots, R, width]: position ``t`` lies at row ``t mod R``, so
+    block ``j`` of ``window`` positions lies at the ring's block ``j mod (R /
+    window)``, and each row of the batch walks the blocks that hold its own
+    ``[pos[b, 0] - (window - 1), last[b]]`` and no other: a row read under a
+    position it does not hold (an older lap's, or one not yet written) lies
+    outside every query's window and is masked. ``([B, S, heads, v_dim]
+    float32, the rounds walked)``."""
+    d, block = dims, dims.window
+    B, S = pos.shape
+    n_ring = ring.shape[1] // block
+    first, rounds = window_rounds(d, pos[:, 0], last)
+    rounds = rounds.max()
+
+    def kv_block(t):
+        at = ((first + t) % n_ring) * block
+        rows = jax.vmap(lambda s, a: jax.lax.dynamic_slice(
+            ring, (s, a, 0), (1, block, ring.shape[-1]))[0])(slots, at)
+        return _split(d, rows)
+
+    sink = None
+    if d.sink:      # a query head's logit, at its place in the folded axis
+        b = p["sink"].astype(jnp.float32).reshape(d.kv_heads, 1, d.group)
+        sink = jnp.broadcast_to(b, (d.kv_heads, S, d.group)).reshape(
+            d.kv_heads, S * d.group)
+    o = attend_over_blocks(
+        _folded(d, q, ring.dtype), jnp.repeat(pos, d.group, axis=1),
+        kv_block, rounds, block, d.v_dim, dtype=jnp.float32, scale=d.scale,
+        first_block=first, window=d.window, sink=sink)
+    return _unfolded(d, o, B, S), rounds
+
+
+def window_prefill_chunk(p, dims: GQADims, x, n_valid, offset, ring, slot,
+                         scope: str = "gqa_window"):
+    """:func:`prefill_chunk` of a WINDOW layer: ``x`` [C, dim] of one session
+    at positions ``offset + arange(C)``, the first ``n_valid`` of them real,
+    against that session's slot of ``ring`` [slots, R, width] (``R >= window
+    + C``). Only the real positions are written (position ``t`` at row ``t
+    mod R``), so a slot always holds the last ``R`` real positions written to
+    it. The walk lies under the device scope ``<scope>.attend``. ``(out [C,
+    dim] float32, ring, rounds walked)``."""
+    C, R = x.shape[0], ring.shape[1]
+    pos = offset + jnp.arange(C, dtype=jnp.int32)
+    q, k, v = project(p, dims, x, pos)
+    # the chunk's rows in the ring's order: row r takes position offset + i,
+    # i = (r - offset) mod R, where that one is real
+    new = jnp.pad(_to_cache(k, v, ring), ((0, R - C), (0, 0)))
+    i = (jnp.arange(R, dtype=jnp.int32) - offset) % R
+    held = jax.lax.dynamic_slice(ring, (slot, 0, 0), (1,) + ring.shape[1:])
+    held = jnp.where((i < n_valid)[None, :, None],
+                     jnp.roll(new, offset % R, axis=0)[None], held)
+    ring = jax.lax.dynamic_update_slice(ring, held, (slot, 0, 0))
+    with jax.named_scope(scope + ".attend"):
+        o, rounds = _attend_ring(
+            p, dims, q[None], pos[None],
+            jnp.reshape(offset + n_valid - 1, (1,)), ring,
+            jnp.reshape(slot, (1,)))
+    return _out(p, o[0]), ring, rounds
+
+
+def window_extend(p, dims: GQADims, x, n_new, pos, ring, slots,
+                  scope: str = "gqa_window"):
+    """:data:`extend` of a WINDOW layer: ``x`` [B, S, dim] at positions
+    ``pos`` [B, S] of the slots ``slots`` [B], the first ``n_new`` [B] of
+    each row real and written. ``(out [B, S, dim] float32, ring, rounds
+    walked)``: every row walks as many rounds as the row that needs most."""
+    B, S = pos.shape
+    R = ring.shape[1]
+    q, k, v = project(p, dims, x, pos)
+    real = jnp.arange(S, dtype=jnp.int32)[None] < n_new[:, None]
+    ring = ring.at[slots[:, None], jnp.where(real, pos % R, R)].set(
+        _to_cache(k, v, ring), mode="drop")
+    with jax.named_scope(scope + ".attend"):
+        o, rounds = _attend_ring(
+            p, dims, q, pos, pos[:, 0] + jnp.maximum(n_new, 1) - 1, ring,
+            slots)
+    return _out(p, o), ring, rounds

@@ -86,7 +86,9 @@ class BlockSpec:
     """One block of the stack, by the kinds of its parts."""
 
     #: "mha" (q/k/v heads) | "mla" (ops/mla.py) | "gqa" (ops/gqa.py) |
-    #: "mamba2" (ops/ssm.py); the blocks of one stack may differ in it
+    #: "gqa_window" (ops/gqa.py under the stack's second dims, a sliding
+    #: window's) | "mamba2" (ops/ssm.py); the blocks of one stack may differ
+    #: in it
     mixer: str = "mha"
     #: "gelu_mlp" | "swiglu" | "moe" (the expert layer, ops/moe.py, as the
     #: block's whole FFN: "pre_ln" only)
@@ -155,8 +157,9 @@ class StackSpec:
     one such configuration (:meth:`SessionRecConfig.stack`), a latent-
     attention expert model another, a block-diffusion expert model with
     grouped-query attention a third, a stack of state-space mixers with an
-    attention layer among every few a fourth; all run through
-    :func:`apply_block`."""
+    attention layer among every few a fourth, window and full grouped-query
+    attention layers mixed (two ``GQADims``: heads, RoPE base and mask
+    differ) a fifth; all run through :func:`apply_block`."""
 
     dim: int
     ffn_dim: int
@@ -173,6 +176,8 @@ class StackSpec:
     tied_head: bool = True               # scores against the item embedding
     mla: Optional[MLADims] = None
     gqa: Optional[GQADims] = None
+    #: the dims of the "gqa_window" blocks (``window`` > 0), beside ``gqa``'s
+    gqa_window: Optional[GQADims] = None
     ssm: Optional[SSMDims] = None
     moe: Optional[MoEDims] = None
     #: how the stack generates, where it does (a "gqa" stack under the
@@ -248,6 +253,8 @@ def _init_mixer(spec: StackSpec, block: BlockSpec, key, dtype):
         return mla_ops.init(key, spec.mla, dtype)
     if block.mixer == "gqa":
         return gqa_ops.init(key, spec.gqa, dtype)
+    if block.mixer == "gqa_window":
+        return gqa_ops.init(key, spec.gqa_window, dtype)
     if block.mixer == "mamba2":
         return ssm_ops.init(key, spec.ssm, dtype)
     k1, k2 = jax.random.split(key)
@@ -719,7 +726,17 @@ class StackPrograms:
       (``MLADims.index_heads``) ``{latent: that, index_k [n_slots + 1,
       capacity + chunk, index_dim]}`` (``ops/mla.init_cache`` says which);
     * grouped-query attention (``"gqa"``): ``[n_slots + 1, capacity + chunk,
-      2 * kv_heads * head_dim]``, a position's keys, then its values;
+      kv_heads * (head_dim + v_dim)]``, a position's keys, then its values:
+      a SPAN that grows with the session;
+    * grouped-query attention under a sliding window (``"gqa_window"``,
+      ``StackSpec.gqa_window``): ``[n_slots + 1, ring, kv_heads * (head_dim +
+      v_dim)]``, a RING of ``ops/gqa.ring_len(window, chunk)`` rows (the
+      window and one chunk, in whole blocks of ``window`` positions):
+      position ``t`` lies at row ``t mod ring``, only real positions are
+      written, and a query walks the blocks that hold its window and no
+      other, whatever its reach. A slot's rings hold the last ``ring``
+      positions written to it, which is what its owner may resume from
+      (``models/sessionrec.LatentCache``: the ring's rule);
     * a state-space mixer (``"mamba2"``): ``{conv [n_slots + 1, d_conv - 1,
       conv_dim], ssm [n_slots + 1, heads, head_dim, d_state]}``, the
       session's recurrent state at ONE position (the caller may then
@@ -771,23 +788,28 @@ class StackPrograms:
     #: extension batch's once a real session) and the real query rows whose
     #: reach exceeded ``index_topk`` (once a run), both in units an int32
     #: holds for long (the positions in reach of one chunk at 32k over five
-    #: layers are 84 M and would wrap in 25 runs). A stack without expert
-    #: layers, whose router picks no groups, or without an index leaves
-    #: those columns at 0: the keys of a call's ``counters`` decide, when
-    #: the program is traced
+    #: layers are 84 M and would wrap in 25 runs); in a stack with window
+    #: layers the blocks of ``window`` cached positions those layers WALKED
+    #: (per layer, summed: a chunk's rounds, an extension batch's once a real
+    #: session), the blocks a walk from 0 to the same reach would have taken,
+    #: and the blocks of ``chunk`` positions its full layers walked (counted
+    #: alike). A stack without expert layers, whose router picks no groups,
+    #: without an index or without window layers leaves those columns at 0:
+    #: the keys of a call's ``counters`` decide, when the program is traced
     TOTAL_FIELDS = ("runs", "tokens", "held_picks", "experts_touched",
                     "zero_picks", "dense_expert_runs", "expert_row_tiles",
                     "load_max_sum", "group_hit_tokens", "index_blocks",
-                    "index_sparse_rows")
+                    "index_sparse_rows", "window_blocks",
+                    "window_blocks_from0", "full_blocks")
 
     def __init__(self, spec: StackSpec, params: Dict, shape: ServeShape):
         #: the mixers' kinds, in the order of their caches
         self.kinds = [b.mixer for b in spec.blocks
                       for _ in range(2 if b.topology == "scmoe" else 1)]
-        if not set(self.kinds) <= {"mla", "gqa", "mamba2"}:
+        if not set(self.kinds) <= {"mla", "gqa", "gqa_window", "mamba2"}:
             raise ValueError(
                 "stepwise serving needs mixers that keep a per-session "
-                "cache ('mla', 'gqa' or 'mamba2'): got "
+                "cache ('mla', 'gqa', 'gqa_window' or 'mamba2'): got "
                 f"{sorted(set(self.kinds))}")
         from predictionio_tpu.obs import jaxmon
 
@@ -801,6 +823,10 @@ class StackPrograms:
                 "mask's block length")
         if gen is not None and set(self.kinds) != {"gqa"}:
             raise ValueError("a stack that generates has 'gqa' mixers only")
+        #: whether some mixers attend a sliding window, over rings
+        self.windowed = "gqa_window" in self.kinds
+        if self.windowed and not spec.gqa_window.window:
+            raise ValueError("a 'gqa_window' block's dims give its window")
         dtype = params["item_embed"]["embedding"].dtype
         positions = (shape.n_slots + 1, shape.capacity + shape.chunk)
         #: whether the latent mixers select their positions by a learned index
@@ -810,6 +836,10 @@ class StackPrograms:
             "mla": lambda: mla_ops.init_cache(spec.mla, *positions, dtype),
             "gqa": lambda: jnp.zeros(
                 positions + (spec.gqa.cache_width,), dtype),
+            "gqa_window": lambda: jnp.zeros(
+                (positions[0],
+                 gqa_ops.ring_len(spec.gqa_window.window, shape.chunk),
+                 spec.gqa_window.cache_width), dtype),
             "mamba2": lambda: ssm_ops.init_state(
                 spec.ssm, shape.n_slots + 1, dtype)}
         self.cache = [held[kind]() for kind in self.kinds]
@@ -852,6 +882,9 @@ class StackPrograms:
                   + (moe.held[1] if moe else 0))
         if self.indexed:
             widest = max(widest, -(-positions[1] // shape.chunk)
+                         * shape.extend_batch)
+        if self.windowed:
+            widest = max(widest, -(-positions[1] // spec.gqa_window.window)
                          * shape.extend_batch)
         #: runs after which the caller takes the totals, at the latest
         self.drain_every = (2 ** 31 - 1) // (len(self.kinds) * widest)
@@ -924,6 +957,9 @@ class StackPrograms:
             if "index_blocks" in counters:
                 add.update(index_blocks=counters["index_blocks"],
                            index_sparse_rows=counters["index_sparse_rows"])
+            if "window_blocks" in counters:
+                add.update((f, counters[f]) for f in (
+                    "window_blocks", "window_blocks_from0", "full_blocks"))
             row = jnp.stack([jnp.asarray(add.get(f, 0), jnp.int32)
                              for f in self.TOTAL_FIELDS])
             return (cache, result, counters,
@@ -937,7 +973,7 @@ class StackPrograms:
         cache = list(cache)
         spec, chunk = self.spec, self.shape.chunk
         valid = jnp.arange(ids.shape[0]) < n_valid
-        scanned = []
+        scanned, walked = [], []
 
         def mix_with(m, p, h, scope):
             kind = self.kinds[m]
@@ -948,6 +984,11 @@ class StackPrograms:
                 out, cache[m], blocks = mla_ops.prefill_chunk(
                     p, spec.mla, h, offset, cache[m], slot, chunk, scope)
                 scanned.append(blocks)
+            elif kind == "gqa_window":
+                out, cache[m], rounds = gqa_ops.window_prefill_chunk(
+                    p, spec.gqa_window, h, n_valid, offset, cache[m], slot,
+                    scope)
+                walked.append(rounds)
             else:
                 out, cache[m] = gqa_ops.prefill_chunk(
                     p, spec.gqa, h, offset, cache[m], slot, chunk)
@@ -958,7 +999,29 @@ class StackPrograms:
         if self.indexed:
             pos = offset + jnp.arange(ids.shape[0], dtype=jnp.int32)
             counters.update(self._index_counts(sum(scanned), valid, pos))
+        if self.windowed:
+            counters.update(self._walk_counts(
+                sum(walked), (offset + n_valid - 1)[None],
+                (offset + ids.shape[0] + chunk - 1) // chunk, 1))
         return cache, self._final(params, x[n_valid - 1])[None], counters
+
+    def _walk_counts(self, rounds, last, n_blocks, rows):
+        """A run's three counts in a stack with window layers: the blocks of
+        ``window`` positions those layers walked (``rounds``: summed over the
+        window layers; every one of the run's ``rows`` real sessions walks
+        them), what walks from 0 to each session's last position
+        ``last`` [rows, or more with padding at -1] would have taken, and
+        the blocks of ``chunk`` positions the full layers walked (``n_blocks``
+        each, every real session as far as the longest)."""
+        window = self.spec.gqa_window.window
+        n_window, n_full = (self.kinds.count(k)
+                            for k in ("gqa_window", "gqa"))
+        return {"window_blocks": (rounds * rows).astype(jnp.int32),
+                "window_blocks_from0": (
+                    n_window * ((last + window) // window).sum()).astype(
+                        jnp.int32),
+                "full_blocks": jnp.asarray(n_full * n_blocks * rows,
+                                           jnp.int32)}
 
     def _index_counts(self, blocks, valid, pos):
         """A run's two counts under a learned index: blocks of index keys
@@ -975,7 +1038,7 @@ class StackPrograms:
         B, S = ids.shape
         pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
         valid = (jnp.arange(S)[None] < n_new[:, None]).reshape(-1)
-        scanned = []
+        scanned, walked = [], []
 
         def mix_with(m, p, h, scope):
             kind, h = self.kinds[m], h.reshape(B, S, -1)
@@ -987,6 +1050,11 @@ class StackPrograms:
                     p, spec.mla, h, pos, cache[m], slots, n_blocks, chunk,
                     scope)
                 scanned.append(blocks)
+            elif kind == "gqa_window":
+                out, cache[m], rounds = gqa_ops.window_extend(
+                    p, spec.gqa_window, h, n_new, pos, cache[m], slots,
+                    scope)
+                walked.append(rounds)
             else:
                 out, cache[m] = gqa_ops.extend(
                     p, spec.gqa, h, pos, cache[m], slots, n_blocks, chunk)
@@ -998,6 +1066,10 @@ class StackPrograms:
         if self.indexed:    # every real session's rows scan them
             counters.update(self._index_counts(
                 sum(scanned) * (n_new > 0).sum(), valid, pos.reshape(-1)))
+        if self.windowed:   # a padding row's last position is -1: no block
+            counters.update(self._walk_counts(
+                sum(walked), jnp.where(n_new > 0, pos0 + n_new - 1, -1),
+                n_blocks, (n_new > 0).sum()))
         last = x.reshape(B, S, -1)[jnp.arange(B), jnp.maximum(n_new - 1, 0)]
         return cache, self._final(params, last), counters
 

@@ -12,9 +12,10 @@ documents a reviewed exception.
 from __future__ import annotations
 
 import ast
+import functools
 import os
 import re
-from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Set, Tuple
 
 from predictionio_tpu.tools.lint.engine import (
     FileContext,
@@ -105,11 +106,24 @@ def _jit_static_params(dec: ast.AST, fn: ast.FunctionDef) -> Optional[Set[str]]:
     return static
 
 
+@functools.lru_cache(maxsize=2)
+def _module_nodes(tree: ast.Module) -> Tuple[ast.AST, ...]:
+    return tuple(ast.walk(tree))
+
+
+def _walk(root: ast.AST) -> Iterable[ast.AST]:
+    """``ast.walk``; a whole module's nodes are listed once and kept while
+    the rules take their turns over it (two dozen of them walk all of it)."""
+    if isinstance(root, ast.Module):
+        return _module_nodes(root)
+    return ast.walk(root)
+
+
 def iter_jit_functions(
     tree: ast.AST,
 ) -> Iterator[Tuple[ast.FunctionDef, Set[str], Set[str]]]:
     """Yield (function, traced-params, static-params) per jit'd def."""
-    for node in ast.walk(tree):
+    for node in _walk(tree):
         if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
         for dec in node.decorator_list:
@@ -128,12 +142,12 @@ def iter_jit_functions(
 def _walk_body(fn: ast.FunctionDef) -> Iterator[ast.AST]:
     """Walk a function's body, skipping its decorators and signature."""
     for stmt in fn.body:
-        yield from ast.walk(stmt)
+        yield from _walk(stmt)
 
 
 def _parent_map(root: ast.AST) -> Dict[ast.AST, ast.AST]:
     parents: Dict[ast.AST, ast.AST] = {}
-    for node in ast.walk(root):
+    for node in _walk(root):
         for child in ast.iter_child_nodes(node):
             parents[child] = node
     return parents
@@ -197,7 +211,7 @@ def _is_low_prec_cast(node: ast.AST) -> bool:
 
 
 def _contains_low_prec(node: ast.AST, tainted: Set[str]) -> bool:
-    for sub in ast.walk(node):
+    for sub in _walk(node):
         if _is_low_prec_cast(sub):
             return True
         if (isinstance(sub, ast.Name) and isinstance(sub.ctx, ast.Load)
@@ -263,7 +277,7 @@ class HostSyncInJit(Rule):
         # redundant double conversion anywhere (the serving-path cost):
         # asarray(asarray(x)) round-trips through a host buffer that a
         # single asarray(x, dtype=...) never allocates
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if node in in_jit or not isinstance(node, ast.Call):
                 continue
             d = dotted(node.func)
@@ -297,7 +311,7 @@ class PythonBranchOnTracer(Rule):
 
     def _exposed_name(self, test: ast.AST, traced: Set[str]) -> Optional[str]:
         parents = _parent_map(test)
-        for node in ast.walk(test):
+        for node in _walk(test):
             if not (isinstance(node, ast.Name)
                     and isinstance(node.ctx, ast.Load)
                     and node.id in traced):
@@ -368,7 +382,7 @@ class LowPrecisionAccumulation(Rule):
         # cast are tainted (no reassignment clearing — a linter
         # over-approximates; suppress with justification where reviewed)
         tainted: Set[str] = set()
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if isinstance(node, ast.Assign) and _is_low_prec_cast(node.value):
                 for tgt in node.targets:
                     if isinstance(tgt, ast.Name):
@@ -377,7 +391,7 @@ class LowPrecisionAccumulation(Rule):
                     and _is_low_prec_cast(node.value):
                 if isinstance(node.target, ast.Name):
                     tainted.add(node.target.id)
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if isinstance(node, ast.Call):
                 tail = dotted(node.func).rsplit(".", 1)[-1] or (
                     node.func.attr if isinstance(node.func, ast.Attribute)
@@ -440,7 +454,7 @@ class SilentBroadExcept(Rule):
         )
 
     def _handles(self, handler: ast.ExceptHandler) -> bool:
-        for node in ast.walk(handler):
+        for node in _walk(handler):
             if isinstance(node, ast.Raise):
                 return True
             if isinstance(node, ast.Call) and isinstance(
@@ -458,7 +472,7 @@ class SilentBroadExcept(Rule):
         return False
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not isinstance(node, ast.Try):
                 continue
             for handler in node.handlers:
@@ -523,7 +537,7 @@ class MeshAxisConsistency(Rule):
                 tree = ast.parse(f.read(), filename=mesh_py)
         except (OSError, SyntaxError):
             return self._FALLBACK_AXES
-        for node in ast.walk(tree):
+        for node in _walk(tree):
             targets: List[ast.AST] = []
             if isinstance(node, ast.Assign):
                 targets, value = node.targets, node.value
@@ -540,7 +554,7 @@ class MeshAxisConsistency(Rule):
 
     def _spec_aliases(self, tree: ast.AST) -> Set[str]:
         aliases: Set[str] = {"PartitionSpec"}
-        for node in ast.walk(tree):
+        for node in _walk(tree):
             if isinstance(node, ast.ImportFrom):
                 for a in node.names:
                     if a.name == "PartitionSpec":
@@ -550,7 +564,7 @@ class MeshAxisConsistency(Rule):
     def check(self, ctx: FileContext) -> Iterator[Finding]:
         axes = self._declared_axes(ctx.abspath)
         aliases = self._spec_aliases(ctx.tree)
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not isinstance(node, ast.Call):
                 continue
             d = dotted(node.func)
@@ -589,7 +603,7 @@ class BlockingTransferInHandler(Rule):
         return "/serving/" in abspath and abspath.endswith("_server.py")
 
     def _handler_classes(self, tree: ast.AST) -> Iterator[ast.ClassDef]:
-        for node in ast.walk(tree):
+        for node in _walk(tree):
             if isinstance(node, ast.ClassDef) and (
                 "Handler" in node.name
                 or any("Handler" in dotted(b) for b in node.bases)
@@ -598,7 +612,7 @@ class BlockingTransferInHandler(Rule):
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
         for cls in self._handler_classes(ctx.tree):
-            for node in ast.walk(cls):
+            for node in _walk(cls):
                 if not isinstance(node, ast.Call):
                     continue
                 d = dotted(node.func)
@@ -650,7 +664,7 @@ class MissingBufferDonation(Rule):
         function visible file-locally: decorated defs and
         ``x = jax.jit(f, ...)`` bindings (incl. ``self._step = ...``)."""
         donates: Dict[str, bool] = {}
-        for node in ast.walk(tree):
+        for node in _walk(tree):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 for dec in node.decorator_list:
                     if _is_jit_callable(dec):
@@ -676,7 +690,7 @@ class MissingBufferDonation(Rule):
         donates = self._jit_targets(ctx.tree)
         if not donates:
             return
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not (isinstance(node, ast.Assign)
                     and isinstance(node.value, ast.Call)):
                 continue
@@ -759,7 +773,7 @@ class CompileCacheKeyInstability(Rule):
         loads: Set[str] = set()
         stores: Set[str] = set(self._fn_params(fn))
         for stmt in body:
-            for node in ast.walk(stmt):
+            for node in _walk(stmt):
                 if isinstance(node, ast.Name):
                     if isinstance(node.ctx, ast.Load):
                         loads.add(node.id)
@@ -829,7 +843,7 @@ class CompileCacheKeyInstability(Rule):
         # (2) jit-wrapped closures capturing unstable enclosing state;
         # each function is analyzed as ITS OWN scope (ast.walk visits
         # nested defs separately), so bindings never leak across scopes
-        for outer in ast.walk(ctx.tree):
+        for outer in _walk(ctx.tree):
             if not isinstance(outer, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
             local_defs: Dict[str, ast.AST] = {}
@@ -890,7 +904,7 @@ class UnsupervisedDaemonThread(Rule):
         ``threading.Thread(target=...)`` whose target is resolvable
         file-locally (a bare name or attribute chain — external
         callables like ``server.serve_forever`` resolve to nothing)."""
-        for node in ast.walk(tree):
+        for node in _walk(tree):
             if not isinstance(node, ast.Call):
                 continue
             if dotted(node.func) not in self._THREAD_NAMES:
@@ -929,7 +943,7 @@ class UnsupervisedDaemonThread(Rule):
             )
             if not broad:
                 continue
-            for sub in ast.walk(handler):
+            for sub in _walk(handler):
                 if isinstance(sub, ast.Call) and isinstance(
                     sub.func, ast.Attribute
                 ) and sub.func.attr in SilentBroadExcept._LOG_ATTRS:
@@ -940,7 +954,7 @@ class UnsupervisedDaemonThread(Rule):
                          parents: Dict[ast.AST, ast.AST],
                          fn: ast.AST) -> bool:
         # supervised inside: any broad-logging try within the loop body
-        for sub in ast.walk(loop):
+        for sub in _walk(loop):
             if sub is not loop and self._broad_logging_try(sub):
                 return True
         # supervised outside: a broad-logging try wrapping the loop
@@ -954,7 +968,7 @@ class UnsupervisedDaemonThread(Rule):
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
         defs: Dict[str, List[ast.AST]] = {}
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 defs.setdefault(node.name, []).append(node)
         seen: Set[ast.AST] = set()
@@ -1027,7 +1041,7 @@ class OutboundCallWithoutTimeout(Rule):
     }
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not isinstance(node, ast.Call):
                 continue
             name = dotted(node.func).rsplit(".", 1)[-1]
@@ -1107,7 +1121,7 @@ class UnboundedMetricLabelCardinality(Rule):
         return None
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not isinstance(node, ast.Call):
                 continue
             if not (isinstance(node.func, ast.Attribute)
@@ -1159,7 +1173,7 @@ class JoinWaitWithoutTimeout(Rule):
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
         _is_none = self._is_none
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not isinstance(node, ast.Call):
                 continue
             func = node.func
@@ -1270,7 +1284,7 @@ class CopyInducingDeviceTransfer(Rule):
         return None
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not isinstance(node, ast.Call) or not node.args:
                 continue
             if not self._is_transfer(node.func):
@@ -1335,7 +1349,7 @@ class FullSortForTopK(Rule):
         return False
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not isinstance(node, ast.Subscript):
                 continue
             if not (isinstance(node.value, ast.Call)
@@ -1418,7 +1432,7 @@ class NonMonotonicDurationClock(Rule):
         justification where the wall clock is the reviewed intent."""
         tainted: Set[str] = set()
         for _ in range(2):
-            for node in ast.walk(tree):
+            for node in _walk(tree):
                 if isinstance(node, ast.Assign):
                     targets, value = node.targets, node.value
                 elif isinstance(node, ast.AnnAssign) and (
@@ -1435,7 +1449,7 @@ class NonMonotonicDurationClock(Rule):
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
         tainted = self._tainted_names(ctx.tree)
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not (isinstance(node, ast.BinOp)
                     and isinstance(node.op, ast.Sub)):
                 continue
@@ -1496,7 +1510,7 @@ class UnledgeredDeviceResidency(Rule):
     def _contains_transfer(node: ast.AST) -> bool:
         return any(isinstance(n, ast.Call)
                    and _is_device_transfer_call(n.func)
-                   for n in ast.walk(node))
+                   for n in _walk(node))
 
     @staticmethod
     def _body_walk(fn: ast.AST) -> Iterator[ast.AST]:
@@ -1512,7 +1526,7 @@ class UnledgeredDeviceResidency(Rule):
             stack.extend(ast.iter_child_nodes(node))
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for fn in ast.walk(ctx.tree):
+        for fn in _walk(ctx.tree):
             if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
             body = list(self._body_walk(fn))
@@ -1622,7 +1636,7 @@ class UntracedIntraFleetCall(Rule):
         return None
 
     def _has_marker(self, scope: ast.AST) -> bool:
-        for sub in ast.walk(scope):
+        for sub in _walk(scope):
             if isinstance(sub, (ast.Name, ast.Attribute)):
                 if dotted(sub).rsplit(".", 1)[-1] in self._MARKER_NAMES:
                     return True
@@ -1638,7 +1652,7 @@ class UntracedIntraFleetCall(Rule):
         ``req = Request(...)`` / ``req = self._build(...)`` shapes
         whose urlopen use defers to the construction site."""
         out: Set[str] = set()
-        for sub in ast.walk(scope):
+        for sub in _walk(scope):
             value = None
             targets: List[ast.AST] = []
             if isinstance(sub, ast.Assign):
@@ -1656,7 +1670,7 @@ class UntracedIntraFleetCall(Rule):
         parents = _parent_map(ctx.tree)
         marker_cache: Dict[ast.AST, bool] = {}
         assigned_cache: Dict[ast.AST, Set[str]] = {}
-        for node in ast.walk(ctx.tree):
+        for node in _walk(ctx.tree):
             if not isinstance(node, ast.Call):
                 continue
             tail = dotted(node.func).rsplit(".", 1)[-1]
@@ -1763,7 +1777,7 @@ class UnjournaledStateTransition(Rule):
             stack.extend(ast.iter_child_nodes(node))
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for fn in ast.walk(ctx.tree):
+        for fn in _walk(ctx.tree):
             if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
             if fn.name == "__init__":
@@ -1909,7 +1923,7 @@ class UnboundedPerKeyDictGrowth(Rule):
         return False
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for fn in ast.walk(ctx.tree):
+        for fn in _walk(ctx.tree):
             if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
             body = list(UnjournaledStateTransition._body_walk(fn))

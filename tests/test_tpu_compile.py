@@ -244,8 +244,9 @@ def test_a_scope_reaches_xlas_own_fusions_through_the_scope_map(
 
 
 @pytest.mark.parametrize("T,dim,expert_dim,n,chunk", [
-    (32, 2048, 768, 128, 768), (64, 6144, 2048, 16, 512)],
-    ids=["sdar-block", "longcat-extend"])
+    (32, 2048, 768, 128, 768), (64, 6144, 2048, 16, 512),
+    (32, 4096, 2048, 16, 512)],
+    ids=["sdar-block", "longcat-extend", "mimo-extend"])
 def test_a_small_forwards_expert_layer_is_one_kernel_under_its_scope(
         one_chip, no_compile_cache, monkeypatch, T, dim, expert_dim, n,
         chunk):
@@ -292,8 +293,9 @@ def test_a_small_forwards_expert_layer_is_one_kernel_under_its_scope(
 
 @pytest.mark.parametrize("dim,expert_dim,n_routed,n_zero,held,top_k,chunk", [
     (2048, 768, 128, 0, 128, 8, 768), (4096, 768, 72, 0, 36, 10, 768),
-    (6144, 2048, 512, 256, 16, 12, 512)],
-    ids=["sdar-chunk", "granite-chunk", "longcat-chunk"])
+    (6144, 2048, 512, 256, 16, 12, 512),
+    (4096, 2048, 256, 0, 16, 8, 512)],
+    ids=["sdar-chunk", "granite-chunk", "longcat-chunk", "mimo-chunk"])
 def test_a_chunks_expert_layer_is_one_grouped_kernel_under_its_scope(
         one_chip, no_compile_cache, monkeypatch, dim, expert_dim, n_routed,
         n_zero, held, top_k, chunk):
@@ -439,6 +441,51 @@ def test_the_kv_cache_is_left_as_it_is_handed_over(one_chip,
     assert layouts == {"{2,1,0:T(8,128)(2,1)}"}, layouts
     assert not re.search(r"= bf16\[33,4608,1024\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("path", ["prefill_chunk", "extend"])
+def test_a_window_layers_ring_is_left_as_it_is_handed_over(
+        one_chip, no_compile_cache, path):
+    """A sliding-window layer at MiMo-V2.5's widths (64 query heads of 192 on
+    8 key/value heads, values of 128, a window of 128 with a sink; 25 slots,
+    rings of 640 rows of 2,560 values): a chunk of 512 positions (the slot's
+    rows re-made by a roll and a select) and an extension batch of 8 x 4 (a
+    scatter of its real rows). The donated rings keep the layout they arrive
+    in and none is copied; the walk's rounds are counted, not unrolled."""
+    from predictionio_tpu.ops import gqa
+
+    dims = gqa.GQADims(dim=4096, heads=64, kv_heads=8, head_dim=192,
+                       block_len=1, rope_theta=1e4, eps=1e-5, qk_norm=False,
+                       v_head_dim=128, rope_dims=64, window=128, sink=True,
+                       value_scale=0.707)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+
+    def struct(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"w_q": struct(4096, 12288), "w_k": struct(4096, 1536),
+         "w_v": struct(4096, 1024), "w_o": struct(8192, 4096),
+         "sink": struct(64)}
+    assert gqa.ring_len(128, 512) == 640 and dims.cache_width == 2560
+    ring = struct(25, 640, 2560)
+    if path == "prefill_chunk":
+        compiled = jax.jit(
+            lambda p, x, n, at, ring, slot: gqa.window_prefill_chunk(
+                p, dims, x, n, at, ring, slot), donate_argnums=4).lower(
+            p, struct(512, 4096, dtype=jnp.float32), struct(dtype=i32),
+            struct(dtype=i32), ring, struct(dtype=i32)).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, x, n, pos, ring, slots: gqa.window_extend(
+                p, dims, x, n, pos, ring, slots), donate_argnums=4).lower(
+            p, struct(8, 4, 4096, dtype=jnp.float32), struct(8, dtype=i32),
+            struct(8, 4, dtype=i32), ring, struct(8, dtype=i32)).compile()
+    text = compiled.as_text()
+    layouts = set(re.findall(r"bf16\[25,640,2560\](\{[^}]*\})", text))
+    assert layouts == {"{2,1,0:T(8,128)(2,1)}"}, layouts
+    assert not re.search(r"= bf16\[25,640,2560\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert " while(" in text
 
 
 @pytest.mark.parametrize("path", ["prefill_chunk", "extend"])
