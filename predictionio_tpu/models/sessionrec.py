@@ -521,6 +521,10 @@ class SeqStackModel:
             # extension of its slate)
             "prefill_queue_ns": 0, "prefill_tickets": 0,
             "extend_queue_ns": 0, "extend_tickets": 0,
+            # chunks whose attention ran in the ``chunk_attend`` kernel: the
+            # device's ``prefill_runs`` in a stack of latent mixers, 0 in
+            # every other (``StackPrograms.attend_kernel``)
+            "prefill_attend_kernel_chunks": 0,
             # block forwards: rows by kind (a known block of a history is a
             # commit row), positions the rule unmasked, queries answered
             "denoise_rows": 0, "commit_rows": 0, "positions_unmasked": 0,
@@ -742,7 +746,8 @@ class SeqStackModel:
                     pre.rows[pre.done:pre.done + n], pre.slot, pre.done)
             with trace.device_span("seq.wait", program="prefill"):
                 h.block_until_ready()
-        self._count("prefill")
+        self._count("prefill", {"prefill_attend_kernel_chunks": int(
+            self._programs.attend_kernel)})
         pre.done += n
         if pre.remaining or self.gen:
             return []

@@ -7,8 +7,12 @@ by all heads (``d_rope`` values). That latent — not the heads' keys and values
 
 * **prefill** (:func:`prefill_chunk`): a chunk of new positions against the
   slot's cache, with keys and values EXPANDED from the cached latents one
-  block at a time (``ops.attention.attend_over_blocks``: 192-wide keys beside
-  128-wide values, online softmax, only as many blocks as the history has);
+  block at a time (192-wide keys beside 128-wide values, online softmax, only
+  as many blocks as the history has), in ONE kernel a mixer
+  (``ops/pallas/chunk_attend.py``: a head a grid step, the blocks in a loop
+  inside it, scores and probabilities in VMEM only; :func:`attend_blocks` is
+  the same walk as XLA's own fusions, ``ops.attention.attend_over_blocks``
+  over :func:`expand`: the tests' reference and the probe's baseline);
 * **extension** (:func:`extend`): a few new positions of several sessions in
   the ABSORBED form, straight over the cached latents: the key expansion is
   folded into the query (``q~_h = qN_h W_uk,h^T``) and the value expansion is
@@ -68,7 +72,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+from predictionio_tpu.ops import pallas as pallas_ops
 from predictionio_tpu.ops.attention import attend_over_blocks, mha_reference
+from predictionio_tpu.ops.pallas import chunk_attend
 
 
 @dataclasses.dataclass(frozen=True)
@@ -397,6 +403,53 @@ def _part(dims: MLADims, name: str):
     return contextlib.nullcontext()
 
 
+def attend_blocks(p, dims: MLADims, q, offset, latents, slot, n_blocks,
+                  block: int, keep=None):
+    """A chunk's queries ``q`` [C, H, d_qk] (the weights' type, positions
+    ``offset + arange(C)``) over ``n_blocks`` blocks of slot ``slot`` of the
+    cached ``latents``, each expanded (:func:`expand`) and folded into the
+    running softmax by XLA's own fusions; ``keep`` [C, P] bool: each row's
+    own set, or None. ``[C, H, d_v]`` float32. The plain form of
+    :func:`attend_kernel`, which is what :func:`prefill_chunk` runs."""
+    d = dims
+    C = q.shape[0]
+
+    def kv_block(j):
+        lat = jax.lax.dynamic_slice(
+            latents, (slot, j * block, 0), (1, block, latents.shape[-1]))
+        return expand(p, d, lat[..., :d.latent])
+
+    return attend_over_blocks(
+        q[None], offset + jnp.arange(C, dtype=jnp.int32), kv_block, n_blocks,
+        block, d.d_v, dtype=jnp.float32, scale=d.softmax_scale,
+        keep_block=None if keep is None else lambda j: jax.lax.dynamic_slice(
+            keep, (0, j * block), (C, block)))[0]
+
+
+def attend_kernel(p, dims: MLADims, q, offset, latents, slot, n_blocks,
+                  block: int, keep=None):
+    """:func:`attend_blocks` as ONE kernel (``ops/pallas/chunk_attend.py``):
+    queries and the two halves of ``w_ukv`` a head at a time, the RoPE part
+    of a key where the kernel's layout has it; compiled on a TPU, under the
+    Pallas interpreter elsewhere."""
+    d = dims
+    rope_at, wide = chunk_attend.key_layout(d.d_nope, d.d_rope)
+    w = jnp.moveaxis(
+        p["w_ukv"].reshape(d.kv_rank, d.heads, d.d_nope + d.d_v), 1, 0)
+    o = chunk_attend.chunk_attend(
+        jnp.moveaxis(chunk_attend.laid_out(
+            q[..., :d.d_nope], q[..., d.d_nope:], rope_at), 1, 0),
+        chunk_attend.laid_out(      # zero columns where the layout asks
+            w[..., :d.d_nope],
+            jnp.zeros(w.shape[:-1] + (wide - d.d_nope,), w.dtype), rope_at),
+        w[..., d.d_nope:], latents, slot, offset, n_blocks, block=block,
+        kv_rank=d.kv_rank, d_rope=d.d_rope, rope_at=rope_at,
+        scale=d.softmax_scale,
+        keep=None if keep is None else keep.astype(jnp.int8),
+        interpret=pallas_ops.interpret_mode())
+    return jnp.moveaxis(o, 0, 1)
+
+
 def prefill_chunk(p, dims: MLADims, x, offset, cache, slot, block: int,
                   scope: str = "mla"):
     """A chunk ``x`` [C, dim] of ONE session, at positions ``offset +
@@ -423,18 +476,11 @@ def prefill_chunk(p, dims: MLADims, x, offset, cache, slot, block: int,
     reach = offset + C
     n_blocks = (reach + block - 1) // block
 
-    def kv_block(j):
-        lat = jax.lax.dynamic_slice(
-            lat_c, (slot, j * block, 0), (1, block, lat_c.shape[-1]))
-        return expand(p, d, lat[..., :d.latent])
-
-    def attend(keep_block=None):
+    def attend(keep=None):
         with _part(d, scope + ".attend"):
             q = jnp.concatenate([qn, qr], axis=-1).astype(p["w_ukv"].dtype)
-            return _out(p, d, attend_over_blocks(
-                q[None], pos, kv_block, n_blocks, block, d.d_v,
-                dtype=jnp.float32, scale=d.softmax_scale,
-                keep_block=keep_block)[0])
+            return _out(p, d, attend_kernel(
+                p, d, q, offset, lat_c, slot, n_blocks, block, keep))
 
     if not d.has_index:
         return attend(), lat_c, 0
@@ -457,8 +503,7 @@ def prefill_chunk(p, dims: MLADims, x, offset, cache, slot, block: int,
                 jnp.full((C, P), -jnp.inf, jnp.float32))
         with jax.named_scope(scope + ".select"):
             keep = topk_mask(scores, d.index_topk)
-        return attend(lambda j: jax.lax.dynamic_slice(
-            keep, (0, j * block), (C, block)))
+        return attend(keep)
 
     sparse = reach > d.index_topk
     out = jax.lax.cond(sparse, under_the_mask, attend)

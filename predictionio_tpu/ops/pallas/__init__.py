@@ -32,15 +32,23 @@ top-k over the item table in ``[D, Ip]`` tiles of thousands of items,
 merging only a tile that can change the top-k — the exact retrieval
 index's hot path, selected per-index via ``index_kernel``).
 
-Two kernels have no flag: ``expert_stream`` (the expert layer of a forward
+Three kernels have no flag. ``expert_stream`` (the expert layer of a forward
 whose tokens fit one tile: every row through every touched expert) and
 ``expert_groups`` (of a larger forward, a prefill chunk: the rows sorted by
 expert, each expert's group gathered, multiplied and added back inside its
 grid step), both in ``expert_stream.py``, both streaming each touched
-expert's weights once. They are the two forms ``ops/moe.moe`` has, chosen by
-the forward's tokens, so they run compiled on a TPU and under the interpreter
-everywhere else, tier-1 included; the tile loop they replaced
+expert's weights once, are the two forms ``ops/moe.moe`` has, chosen by
+the forward's tokens; the tile loop they replaced
 (``ops/moe.experts_sorted``) is their reference in the tests.
+``chunk_attend`` (``chunk_attend.py``: a prefill chunk's latent attention,
+the walk over a slot's cached blocks with their expansion, a head a grid
+step, scores and probabilities in VMEM only) is the one form
+``ops/mla.prefill_chunk`` has, with an index and without; the loop of XLA's
+fusions it replaced (``ops/mla.attend_blocks``: ``ops/attention
+.attend_over_blocks`` over ``expand``) is its reference in the tests and the
+path of every other walk (the absorbed extension, grouped-query attention,
+the window layers). A path that has one form runs it compiled on a TPU and
+under the interpreter everywhere else, tier-1 included.
 
 Each flag (``flash_ce_kernel``, ``embed_update_kernel``,
 ``index_kernel``) takes ``on`` / ``off`` / ``auto``;

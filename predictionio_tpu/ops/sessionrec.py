@@ -831,6 +831,9 @@ class StackPrograms:
         positions = (shape.n_slots + 1, shape.capacity + shape.chunk)
         #: whether the latent mixers select their positions by a learned index
         self.indexed = "mla" in self.kinds and spec.mla.has_index
+        #: whether a chunk's attention runs in the ``chunk_attend`` kernel
+        #: (``ops/mla.prefill_chunk``'s walk: every latent mixer's, no other)
+        self.attend_kernel = "mla" in self.kinds
 
         held = {
             "mla": lambda: mla_ops.init_cache(spec.mla, *positions, dtype),

@@ -479,3 +479,203 @@ def test_missing_pallas_with_flag_on_raises(monkeypatch):
                          flash_ce_kernel="on", embed_update_kernel="on")
     with pytest.raises(AttributeError):
         tt.TwoTowerTrainer((u, i, None), n_users, n_items, cfg)
+
+
+# -- chunk_attend: a prefill chunk's latent attention, the walk in one kernel --
+
+from predictionio_tpu.ops import mla as mla_ops     # noqa: E402
+from predictionio_tpu.ops.attention import mha_reference     # noqa: E402
+from predictionio_tpu.ops.pallas import chunk_attend as chunk_attend_mod  # noqa: E402
+
+#: (d_nope, d_rope, d_v) of the three configurations' mixers, an eighth of
+#: them (GLM-5's 192 + 64 | 256; A.X-K1's and LongCat's 128 + 64 | 128), and
+#: GLM-5's own: the one whose RoPE key lies inside ``d_nope``'s last lanes
+#: (``chunk_attend.key_layout``: zero columns in ``w_uk``)
+ATTEND_WIDTHS = {"glm": (24, 8, 32), "axk_longcat": (16, 8, 16),
+                 "glm_full_width": (192, 64, 256)}
+ATTEND_CHUNK = ATTEND_BLOCK = 16
+ATTEND_SLOT = 6 * ATTEND_BLOCK         # positions a slot holds
+
+
+def attend_case(widths, dtype, heads=4, seed=0):
+    """One mixer's weights, a cache of three slots of noise, a chunk's
+    queries: ``(dims, p, latents, q)``."""
+    dn, dr, dv = widths
+    d = mla_ops.MLADims(dim=64, heads=heads, d_nope=dn, d_rope=dr, d_v=dv,
+                        q_rank=32, kv_rank=32)
+    key = jax.random.PRNGKey(seed)
+    p = mla_ops.init(key, d, dtype)
+    latents = jax.random.normal(
+        jax.random.fold_in(key, 1),
+        (3, ATTEND_SLOT, mla_ops.cache_width(d))).astype(dtype)
+    q = jax.random.normal(jax.random.fold_in(key, 2),
+                          (ATTEND_CHUNK, heads, dn + dr)).astype(dtype)
+    return d, p, latents, q
+
+
+def some_keep(seed, kept=0.4):
+    """A row's own set over a slot, ``[chunk, slot]`` bool: position 0
+    always (every row must keep some key it can see)."""
+    keep = jax.random.bernoulli(jax.random.PRNGKey(seed), kept,
+                                (ATTEND_CHUNK, ATTEND_SLOT))
+    return keep.at[:, 0].set(True)
+
+
+def both_walks(d, p, slot=1):
+    """``(kernel, XLA loop)`` of one chunk, each jitted ONCE for every
+    offset: ``walk(q, latents, offset, keep) -> [C, H, d_v]`` numpy."""
+    def jitted(walk):
+        fn = jax.jit(lambda q, lat, at, nb, keep: walk(
+            p, d, q, at, lat, slot, nb, ATTEND_BLOCK, keep))
+        return lambda q, lat, offset, keep=None: np.asarray(fn(
+            q, lat, jnp.int32(offset),
+            jnp.int32(-(-(offset + ATTEND_CHUNK) // ATTEND_BLOCK)), keep))
+
+    return jitted(mla_ops.attend_kernel), jitted(mla_ops.attend_blocks)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "keep"])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-3)])
+@pytest.mark.parametrize("widths", sorted(ATTEND_WIDTHS))
+def test_chunk_attend_is_the_accum_block_loop(widths, dtype, tol, masked):
+    """The kernel against ``attend_over_blocks`` over ``expand``
+    (``ops/mla.attend_blocks``) at <= 1e-5 in float32; in bfloat16 both
+    round keys, values and probabilities at the same places, so what is left
+    between them is a float32 sum's order tipping one of those roundings (a
+    quarter of a bfloat16 step of the result allowed; three results in a
+    thousand differ at all, by 3e-5): with and without a row's own ``keep``,
+    at offsets inside a block, on a block's edge and at the slot's last
+    chunk."""
+    d, p, latents, q = attend_case(
+        ATTEND_WIDTHS[widths], dtype,
+        heads=2 if widths == "glm_full_width" else 4)
+    keep = some_keep(3) if masked else None
+    kernel, loop = both_walks(d, p)
+    for offset in (0, 7, ATTEND_BLOCK, 43, ATTEND_SLOT - ATTEND_CHUNK):
+        got, want = (walk(q, latents, offset, keep) for walk in (kernel, loop))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+def test_chunk_attend_wipes_what_a_row_that_kept_nothing_carried():
+    """Rows that keep NOTHING of their first two blocks carry a running
+    maximum of ``_NEG`` through them (every masked key then weighs ``exp(0)``);
+    the first kept key's ``alpha`` is exactly 0 and wipes it, as
+    ``attend_over_blocks`` states: the loop's numbers, and the plain
+    softmax's over the kept keys alone."""
+    d, p, latents, q = attend_case(ATTEND_WIDTHS["glm"], "float32")
+    offset = 4 * ATTEND_BLOCK
+    keep = some_keep(5)
+    keep = keep.at[::2, :2 * ATTEND_BLOCK].set(False)
+    got, want = (walk(q, latents, offset, keep) for walk in both_walks(d, p))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    reach = offset + ATTEND_CHUNK
+    k, v = mla_ops.expand(p, d, latents[1:2, :reach, :d.latent])
+    plain = mha_reference(q[None], k, v, causal=True, scale=d.softmax_scale,
+                          keep=keep[None, :, :reach])[0]
+    np.testing.assert_allclose(got, np.asarray(plain), rtol=2e-5, atol=2e-5)
+
+
+def test_chunk_attend_is_one_program_for_every_reach():
+    """``n_blocks``, the offset and the slot are traced: ONE compiled program
+    walks 1 block, 2, and the slot's whole length, each the loop's numbers
+    (and the next slot's cache, full of huge values, is never read)."""
+    d, p, latents, q = attend_case(ATTEND_WIDTHS["axk_longcat"], "float32")
+    latents = latents.at[2].set(1e30)
+    keep = some_keep(7)
+
+    @jax.jit
+    def kernel(q, at, lat, slot, nb, keep):
+        return mla_ops.attend_kernel(p, d, q, at, lat, slot, nb,
+                                     ATTEND_BLOCK, keep)
+
+    for offset in (0, ATTEND_BLOCK, ATTEND_SLOT - ATTEND_CHUNK):
+        nb = -(-(offset + ATTEND_CHUNK) // ATTEND_BLOCK)
+        got = kernel(q, jnp.int32(offset), latents, jnp.int32(1),
+                     jnp.int32(nb), keep)
+        want = mla_ops.attend_blocks(p, d, q, offset, latents, 1, nb,
+                                     ATTEND_BLOCK, keep)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    assert kernel._cache_size() == 1
+
+
+INDEXED = mla_ops.MLADims(
+    dim=64, heads=4, d_nope=24, d_rope=8, d_v=32, q_rank=32, kv_rank=32,
+    scale_q=False, scale_kv=False, index_heads=4, index_dim=32, index_topk=24)
+
+
+@pytest.mark.parametrize("dims", [
+    INDEXED, mla_ops.MLADims(dim=64, heads=4, d_nope=16, d_rope=8, d_v=16,
+                             q_rank=32, kv_rank=32)],
+    ids=["index", "no_index"])
+def test_prefill_chunk_through_the_kernel_is_the_plain_form(dims):
+    """A history prefilled chunk by chunk through the kernel against
+    ``attend_full``, every position against every earlier one with its scores
+    materialised: below ``index_topk`` (the first chunk: all in reach
+    attended, nothing scored), above it (each row under its own set), and a
+    LAST chunk whose rows past the history's end are padding full of huge
+    values, which no real row reads."""
+    chunk, n = 16, 56           # three and a half chunks
+    key = jax.random.PRNGKey(11)
+    p = mla_ops.init(key, dims, jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (n, 64), jnp.float32)
+    want = mla_ops.attend_full(p, dims, x[None], jnp.arange(n)[None])[0]
+    padded = jnp.concatenate([x, jnp.full((8, 64), 1e4, jnp.float32)])
+    cache = mla_ops.init_cache(dims, 2, 64, jnp.float32)
+    step = jax.jit(lambda x, at, cache: mla_ops.prefill_chunk(
+        p, dims, x, at, cache, 1, chunk))
+    scanned = []
+    for at in range(0, n, chunk):
+        out, cache, blocks = step(padded[at:at + chunk], jnp.int32(at),
+                                  cache)
+        real = min(chunk, n - at)
+        assert np.isfinite(np.asarray(out[:real])).all()
+        np.testing.assert_allclose(np.asarray(out[:real]),
+                                   np.asarray(want[at:at + real]),
+                                   rtol=2e-4, atol=2e-4)
+        scanned.append(int(blocks))
+    assert step._cache_size() == 1
+    assert scanned == ([0, 2, 3, 4] if dims.has_index else [0] * 4)
+
+
+@pytest.mark.parametrize("d_nope,d_rope,rope_at,wide", [
+    (192, 64, 128, 256),     # GLM-5: zero columns at lanes 128-191 of w_uk
+    (128, 64, 128, 128),     # A.X-K1, LongCat: behind d_nope, none needed
+    (24, 8, 24, 24)])        # under one row of lanes: behind d_nope
+def test_a_keys_rope_part_lies_at_a_whole_row_of_lanes(d_nope, d_rope,
+                                                       rope_at, wide):
+    assert chunk_attend_mod.key_layout(d_nope, d_rope) == (rope_at, wide)
+    x = jnp.arange(d_nope, dtype=jnp.float32)[None]
+    r = -jnp.ones((1, d_rope), jnp.float32)
+    laid = chunk_attend_mod.laid_out(x, r, rope_at)[0]
+    assert (laid[rope_at:rope_at + d_rope] == -1).all()
+    assert sorted(laid[laid >= 0].tolist()) == list(range(d_nope))
+
+
+#: SHA-256 of each path's jaxpr text at PR 50's parent (tests/other_walks.py)
+PARENTS_WALKS = {
+    "mla.extend": "0b359426918a157f15a9c65f75c8809f3599bbfcdb30927e5344051d742d859c",
+    "mla.extend.index": "45fca96ff67e1f26c11f8ad643b93c76c9da03f5063cae1d3ceec80afeda2045",
+    "gqa.prefill_chunk": "ab1236eca7a46f8086370d687b2b69bd3dcd9976d71902098f2b92e4502ab021",
+    "gqa.block_step": "743fa703403ea772e1fe76af250d899a6500345a7b8eb350657d09c54082f634",
+    "gqa.window_prefill_chunk": "ae86742bcf15b1c7dbc14ca7383e34a7b30c6ae5a5b7d3d9f59cdd0713e1f0e6",
+    "gqa.window_extend": "c451dba88c8a22edb921048e63f47c577a3f33ae804c246115d618e3d04b8348",
+}
+
+
+@pytest.fixture(scope="module")
+def walks_digests():
+    from tests import other_walks
+
+    return other_walks.digests()
+
+
+@pytest.mark.parametrize("path", sorted(PARENTS_WALKS))
+def test_the_walks_the_kernel_does_not_serve_trace_to_the_parents_jaxpr(
+        walks_digests, path):
+    """The absorbed extension, grouped-query attention's chunks and block
+    steps and the window walk still fold their blocks with ``_accum_block``:
+    the same primitives in the same order as at the commit before the
+    kernel, letter for letter, and not one ``pallas_call`` among them."""
+    assert walks_digests[path] == PARENTS_WALKS[path]

@@ -139,6 +139,23 @@ def test_stats_read_what_the_programs_arrays_sum_to(stack, drain_every,
 
 
 @pytest.mark.parametrize("stack", STACKS)
+def test_the_chunks_whose_attention_ran_in_the_kernel_are_counted(stack):
+    """``prefill_attend_kernel_chunks``: every chunk of a stack of latent
+    mixers (``ops/mla.prefill_chunk`` walks in the ``chunk_attend`` kernel,
+    with an index or without), none of a stack of grouped-query or recurrent
+    mixers, whose walks are XLA's own."""
+    model = small_model(stack)
+    latent = stack in ("scmoe", "grouped-router")
+    assert model.programs().attend_kernel is latent
+    assert model.stats()["prefill_attend_kernel_chunks"] == 0
+    mixed_run(model)
+    after = model.stats()
+    assert after["prefill_runs"] > 0
+    assert after["prefill_attend_kernel_chunks"] == (
+        after["prefill_runs"] if latent else 0)
+
+
+@pytest.mark.parametrize("stack", STACKS)
 def test_the_drain_comes_before_an_int32_could_wrap(stack):
     model = small_model(stack)
     programs, moe = model.programs(), model.spec.moe

@@ -678,6 +678,9 @@ def test_the_model_counts_what_the_index_scanned_and_gathered():
     assert before["extend_index_blocks"] == 3 * 7
     assert before["extend_index_sparse_rows"] == 4
     assert before["extend_latents_gathered"] == 4 * 12
+    # every chunk's attention ran in the kernel, under a mask or not
+    assert before["prefill_attend_kernel_chunks"] == before["prefill_runs"] \
+        == 7
     # both sessions grow by two items and are extended in ONE batch
     tickets = [model.begin({"items": h + ["i1", "i2"], "num": 5})
                for h in (long, short)]

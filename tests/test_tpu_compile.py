@@ -106,6 +106,28 @@ def _expert_groups(T, dim, expert_dim, n, top_k):
         ((n, expert_dim, dim), bf16)], 1
 
 
+def _chunk_attend(C, H, d_qk, d_v, masked, slots=2, positions=33_792):
+    """One mixer's walk at a configuration's widths: a chunk of ``C`` rows,
+    ``H`` heads, keys ``d_qk`` wide (64 of them the shared RoPE key) beside
+    values ``d_v``, latents of rank 512 cached 640 wide, with or without
+    the rows' own sets."""
+    from predictionio_tpu.ops.pallas import chunk_attend
+
+    d_rope, kv_rank = 64, 512
+    rope_at, wide = chunk_attend.key_layout(d_qk - d_rope, d_rope)
+    fn = functools.partial(
+        chunk_attend.chunk_attend, block=512, kv_rank=kv_rank, d_rope=d_rope,
+        rope_at=rope_at, scale=d_qk ** -0.5)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    shapes = [((H, C, d_qk), bf16), ((H, kv_rank, wide), bf16),
+              ((H, kv_rank, d_v), bf16), ((slots, positions, 640), bf16),
+              ((), i32), ((), i32), ((), i32)]
+    if masked:
+        return (lambda *a: fn(*a[:-1], keep=a[-1])), shapes + [
+            ((C, positions), jnp.int8)], 1
+    return fn, shapes, 1
+
+
 @pytest.mark.parametrize("build,args", [
     (_flash_ce, (8192, 128)),
     (_flash_ce, (4096, 64)),
@@ -146,6 +168,12 @@ def _expert_groups(T, dim, expert_dim, n, top_k):
     (_expert_groups, (512, 7168, 2048, 12, 8)),
     (_topk_dot, (20_480, 7168, 1, 16, 1)),
     (_topk_dot, (20_480, 7168, 4, 16, 1)),
+    # a prefill chunk's latent attention, the walk in one kernel: GLM-5's
+    # widths (256-wide keys and values, under each row's own set and
+    # without) and A.X-K1's and LongCat's (192 beside 128)
+    (_chunk_attend, (512, 64, 256, 256, True)),
+    (_chunk_attend, (512, 64, 256, 256, False)),
+    (_chunk_attend, (512, 64, 192, 128, False)),
 ], ids=["flash_ce-8192x128", "flash_ce-4096x64", "flash_ce-65536x256",
         "flash_ce-131072x256", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
@@ -156,7 +184,8 @@ def _expert_groups(T, dim, expert_dim, n, top_k):
         "expert_groups-512x2048-128x768", "expert_groups-512x4096-36x768",
         "expert_groups-512x6144-16x2048", "expert_stream-16x7168-12x2048",
         "expert_groups-512x7168-12x2048", "topk_dot-20480x7168-B1",
-        "topk_dot-20480x7168-B4"])
+        "topk_dot-20480x7168-B4", "chunk_attend-512x64x256x256-keep",
+        "chunk_attend-512x64x256x256", "chunk_attend-512x64x192x128"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
     text = _compiled_text(fn, shapes, one_chip)
@@ -183,8 +212,9 @@ def _kernel_instructions(text):
      ["flash_ce_fwd", "flash_ce_bwd_du", "flash_ce_bwd_dv"]),
     (_expert_stream, (32, 2048, 768, 128), ["expert_stream"]),
     (_expert_groups, (512, 2048, 768, 128, 8), ["expert_groups"]),
+    (_chunk_attend, (512, 64, 256, 256, True), ["chunk_attend"]),
 ], ids=["topk_dot", "flash_ce", "flash_ce_two_pass", "expert_stream",
-        "expert_groups"])
+        "expert_groups", "chunk_attend"])
 def test_a_kernels_instruction_carries_its_name(one_chip, no_compile_cache,
                                                 build, args, names):
     """A device trace's events are named by the instruction's text: the
@@ -407,6 +437,77 @@ def test_an_expert_layer_that_picks_groups_first_is_one_kernel_under_its_scope(
     limit = int(re.search(r'"memory_space":"1","offset":"\d+","size":"(\d+)"',
                           line).group(1))
     assert 16 << 20 < limit < 100 << 20, limit
+
+
+GLM_MIXER = dict(dim=6144, heads=64, d_nope=192, d_rope=64, d_v=256,
+                 q_rank=2048, kv_rank=512, scale_q=False, scale_kv=False)
+MIXERS = {
+    "glm-5": dict(GLM_MIXER, index_heads=32, index_dim=128, index_topk=2048),
+    "longcat": dict(dim=6144, heads=64, d_nope=128, d_rope=64, d_v=128,
+                    q_rank=1536, kv_rank=512)}
+
+
+@pytest.mark.parametrize("name,positions", [("glm-5", 33_280),
+                                            ("longcat", 8_704)])
+def test_a_chunks_latent_attention_is_one_kernel_under_the_mixers_scope(
+        one_chip, no_compile_cache, monkeypatch, name, positions):
+    """``ops/mla.prefill_chunk`` at GLM-5's widths (an index beside the
+    latents: the walk under each row's set in one branch, over all in reach
+    in the other) and at LongCat's: ONE ``chunk_attend`` instruction a walk,
+    found under the mixer's scope through the scope map (``.attend`` under
+    an index: where a trace's readers found the loop's fusions), no ``while``
+    left of the walk over blocks in the attention's scope, no float32 scores
+    ``[64, 512, 512]`` anywhere in the program; the donated latent cache is
+    left in the layout it arrives in and none of it is copied; the kernel's
+    VMEM limit is well under the chip's 128 MiB."""
+    from predictionio_tpu.obs import jaxmon
+    from predictionio_tpu.ops import mla
+
+    monkeypatch.setenv("PIO_PALLAS_INTERPRET", "0")
+    d = mla.MLADims(**MIXERS[name])
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    p = placed(jax.eval_shape(
+        lambda: mla.init(jax.random.PRNGKey(0), d, jnp.bfloat16)))
+    cache = placed(jax.eval_shape(
+        lambda: mla.init_cache(d, 5, positions, jnp.bfloat16)))
+    scope = "seq.layer0.mla_a"
+
+    def chunk(p, x, offset, cache):
+        with jax.named_scope(scope):
+            return mla.prefill_chunk(p, d, x, offset, cache, 1, 512, scope)
+
+    compiled = jax.jit(chunk, donate_argnums=3).lower(
+        p, jax.ShapeDtypeStruct((512, d.dim), jnp.float32,
+                                sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        cache).compile()
+    text = compiled.as_text()
+    kernels = _kernel_instructions(text)
+    scopes = jaxmon.scope_map_of(text)
+    attend = scope + (".attend" if d.has_index else "")
+    assert len(kernels) == (2 if d.has_index else 1), kernels
+    assert all("chunk_attend" in k and scopes[k] == attend
+               for k in kernels), [(k, scopes[k]) for k in kernels]
+    walks = [i for i, s in scopes.items()
+             if s == attend and i.startswith("while")]
+    assert not walks, walks
+    assert "f32[64,512,512]" not in text and "f32[1,64,512,512]" not in text
+    held = rf"bf16\[5,{positions},640\]"
+    # (a kernel's operand constraints name the logical order alone)
+    layouts = set(re.findall(held + r"(\{[^}]*\})", re.sub(
+        r"operand_layout_constraints=\{[^=]*\}, ", "", text)))
+    assert layouts == {"{2,1,0:T(8,128)(2,1)}"}, layouts
+    assert not re.search(rf"= {held}\S* copy\(", text)
+    for k in kernels:
+        line = next(ln for ln in text.splitlines() if f"%{k} = " in ln)
+        limit = int(re.search(
+            r'"memory_space":"1","offset":"\d+","size":"(\d+)"',
+            line).group(1))
+        assert 8 << 20 < limit < 64 << 20, limit
 
 
 def test_the_kv_cache_is_left_as_it_is_handed_over(one_chip,
