@@ -251,7 +251,11 @@ class LatentCache:
       to ``ring - window`` positions, and past that it is a miss for every
       layer together, the spans too (``seq.cache.ring_miss``), from position
       0 in the slot the session had. A slot's rings and spans are evicted
-      and reused together: they are one slot.
+      and reused together: they are one slot. In a stack that ALSO keeps a
+      recurrent state the whole-prefix rule decides alone: a slot resumes
+      only from the end of its rows, where its rings hold the whole window
+      by construction (``ring >= window``), so the rings' floor is never
+      asked and every would-be hit is a ``rewind_miss``.
 
     A miss takes the least recently used free slot and the history is
     prefilled from its start — from a ZERO state: the programs start any
@@ -298,7 +302,8 @@ class LatentCache:
         cached -= cached % self.block
         if cached < 1 or 2 * shared[best] < len(self.rows[slot]):
             return min(free, key=self.used.__getitem__), 0
-        if self.ring and max(cached - (self.window - 1), 0) < self.floor[slot]:
+        if (self.ring and not self.recurrent
+                and max(cached - (self.window - 1), 0) < self.floor[slot]):
             self.ring_misses += 1
             self.ring_miss_tokens += cached
             with trace.device_span("seq.cache.ring_miss", slot=slot,
@@ -413,6 +418,9 @@ class StepPlan(NamedTuple):
     block: List[Tuple[SeqTicket, str, int, int]]
     prefill: Optional[SeqTicket]    # who gets the one chunk, and its tokens
     prefill_tokens: int
+    #: whether that chunk ENDS its ticket's history: in a stack with a
+    #: cross-decoder only such a chunk runs it (for the row that is answered)
+    prefill_last: bool = False
 
 
 def plan_step(tickets: List[SeqTicket], shape, gen=None) -> StepPlan:
@@ -424,7 +432,9 @@ def plan_step(tickets: List[SeqTicket], shape, gen=None) -> StepPlan:
     step; in a stack that generates it gives the block forward (``gen_batch``
     rows) the whole blocks its history still lacks and, behind them, the
     block it generates. The OLDEST ticket with more left gets the step's one
-    chunk (``chunk`` positions at most): a FIFO of whole prefills."""
+    chunk (``chunk`` positions at most): a FIFO of whole prefills; whether
+    that chunk ends its history (``prefill_last``) says which chunk program a
+    stack with a cross-decoder runs for it."""
     pending = [t for t in tickets if t.result is None]
     short = [t for t in pending if t.remaining <= shape.extend_len]
     pre = next((t for t in pending if t.remaining > shape.extend_len), None)
@@ -446,7 +456,8 @@ def plan_step(tickets: List[SeqTicket], shape, gen=None) -> StepPlan:
     return StepPlan(
         [t for t in tickets if t.result is not None],
         [] if gen else short[:shape.extend_batch], rows,
-        pre, min(pre.remaining, shape.chunk) if pre else 0)
+        pre, min(pre.remaining, shape.chunk) if pre else 0,
+        pre is not None and pre.remaining <= shape.chunk)
 
 
 class SeqStackModel:
@@ -480,7 +491,7 @@ class SeqStackModel:
                   else 0)
         self.cache = LatentCache(
             self.shape.n_slots, self.gen.block_len if self.gen else 1,
-            recurrent="mamba2" in self.kinds, window=window,
+            recurrent=bool(self.kinds & {"mamba2", "mamba1"}), window=window,
             ring=ring_len(window, self.shape.chunk) if window else 0)
         self._programs = None
         self._index = None
@@ -697,7 +708,8 @@ class SeqStackModel:
                 finished += self._block_forward(plan.block, done)
             if plan.prefill is not None:
                 finished += self._run_prefill(
-                    plan.prefill, plan.prefill_tokens, done)
+                    plan.prefill, plan.prefill_tokens, done,
+                    plan.prefill_last)
         return finished
 
     def _run_extend(self, ext: List[SeqTicket], done) -> List[SeqTicket]:
@@ -718,7 +730,8 @@ class SeqStackModel:
             ("gqa_window", "extend_window_positions", sum(
                 len(t.rows) - max(0, t.done - self.cache.window + 1)
                 for t in ext)),
-            ("mamba2", "extend_state_rows", len(ext)))
+            ("mamba2", "extend_state_rows", len(ext)),
+            ("mamba1", "extend_state_rows", len(ext)))
             if kind in self.kinds)
         if programs.indexed:
             topk = self.spec.mla.index_topk
@@ -736,14 +749,17 @@ class SeqStackModel:
         self._answer(ext, h, done)
         return ext
 
-    def _run_prefill(self, pre: SeqTicket, n: int, done) -> List[SeqTicket]:
-        """One chunk: the next ``n`` positions of ``pre``'s history."""
+    def _run_prefill(self, pre: SeqTicket, n: int, done,
+                     last: bool = True) -> List[SeqTicket]:
+        """One chunk: the next ``n`` positions of ``pre``'s history;
+        ``last``: the chunk ends it (``plan_step``'s ``prefill_last``)."""
         self._launching([pre], "prefill")
         with trace.device_span("seq.prefill_chunk", slot=pre.slot,
-                               offset=pre.done, tokens=n):
+                               offset=pre.done, tokens=n, last=int(last)):
             with trace.device_span("seq.launch", program="prefill"):
                 h, _ = self._programs.prefill(
-                    pre.rows[pre.done:pre.done + n], pre.slot, pre.done)
+                    pre.rows[pre.done:pre.done + n], pre.slot, pre.done,
+                    last)
             with trace.device_span("seq.wait", program="prefill"):
                 h.block_until_ready()
         self._count("prefill", {"prefill_attend_kernel_chunks": int(
@@ -920,6 +936,8 @@ class SeqStackAlgorithm(Algorithm):
             jax.block_until_ready(programs.block(
                 [(np.zeros(B, np.int32), sh.n_slots, 0, True, 1)]))
         else:
+            if programs.cross_from is not None:    # the chunk that ends none
+                programs.prefill(np.zeros(1, np.int32), sh.n_slots, 0, False)
             h, _ = programs.prefill(np.zeros(1, np.int32), sh.n_slots, 0)
             hs, _ = programs.extend([(np.zeros(1, np.int32), sh.n_slots, 1)])
             for h_last in (h, hs):

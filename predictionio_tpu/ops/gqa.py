@@ -58,8 +58,26 @@ later attends to it. All give the numbers of :func:`attend_full` (scores
 materialised, no cache, the window a mask), which is the plain form the
 tests hold them to.
 
+DIFFERENTIAL attention (``diff``; a fourth stack's, with projection biases
+``bias``, no position encoding, a window of 512): the query, key and value
+heads pair up ``(2i, 2i + 1)`` into ``q1, q2``, ``k1, k2``, ``v1, v2``
+(grouped-query between the PAIRS: ``heads / kv_heads`` query pairs to a
+key/value pair); ``A_a = softmax(q_a k_a^T * scale)`` under the mask, ``o_a =
+[A_a v1, A_a v2]`` (``2 * v_dim`` wide); ``lambda = exp(l_q1 . l_k1) -
+exp(l_q2 . l_k2) + lambda_init`` (four learned vectors of ``head_dim`` a
+layer), ``lambda_init = 0.8 - 0.6 exp(-0.3 depth)`` (``depth``: the layer's
+place in the stack, from 0); ``o = RMS(o_1 - lambda o_2) w * (1 -
+lambda_init)`` (``subln`` [2 * v_dim]); the ``heads / 2`` double heads side by
+side, then ``W_o``. On the walks a key head ``2p + a`` is read with BOTH of
+its pair's values behind it (:func:`_split`), so one walk gives ``o_1`` and
+``o_2``.
+
+A CROSS mixer (``cross``: ``W_q`` and ``W_o`` alone, with its own ``lambda``s
+and sub-norm) writes nothing: :func:`cross_rows` walks the span ANOTHER mixer
+of the same head shapes wrote, for one position a session.
+
 Matrix products take their inputs in the weights' type and accumulate in
-float32; norms, RoPE, softmax and sinks are float32.
+float32; norms, RoPE, softmax, sinks and ``lambda`` are float32.
 """
 
 from __future__ import annotations
@@ -92,10 +110,16 @@ class GQADims:
     window: int = 0             # positions a query sees, itself among them; 0: all
     sink: bool = False          # a learned logit a head in the normaliser
     value_scale: float = 1.0    # what the values are multiplied by
+    bias: bool = False          # the four projections add a learned bias
+    diff: bool = False          # differential attention over pairs of heads
+    cross: bool = False         # W_q and W_o alone: another mixer's span
 
     def __post_init__(self):
         if self.window and self.block_len != 1:
             raise ValueError("a window is a causal mask's: block_len 1")
+        if self.diff and (self.kv_heads % 2 or self.heads % 2 or self.sink):
+            raise ValueError("differential attention pairs its heads and "
+                             "knows no sink")
 
     @property
     def group(self) -> int:
@@ -110,18 +134,35 @@ class GQADims:
         """Values a cached position takes: keys, then values."""
         return self.kv_heads * (self.head_dim + self.v_dim)
 
+    @property
+    def walk_v_dim(self) -> int:
+        """What a walk's key head carries behind it: its value, or under
+        differential attention its pair's two."""
+        return 2 * self.v_dim if self.diff else self.v_dim
+
 
 def init(key, dims: GQADims, dtype=jnp.float32) -> dict:
-    """N(0, 1 / fan_in) matrices, unit norms, N(0, 1) sinks."""
+    """N(0, 1 / fan_in) matrices, unit norms, N(0, 1) sinks, zero biases,
+    N(0, 0.1^2) ``lambda`` vectors; a cross mixer has no ``w_k``, ``w_v``."""
     d = dims
     shapes = {"w_q": (d.dim, d.heads * d.head_dim),
               "w_k": (d.dim, d.kv_heads * d.head_dim),
               "w_v": (d.dim, d.kv_heads * d.v_dim),
               "w_o": (d.heads * d.v_dim, d.dim)}
+    if d.cross:
+        del shapes["w_k"], shapes["w_v"]
     out = {n: (jax.random.normal(k, s, jnp.float32) / math.sqrt(s[0])
                ).astype(dtype)
            for (n, s), k in zip(shapes.items(),
                                 jax.random.split(key, len(shapes)))}
+    if d.bias:
+        out.update(("b" + n[1:], jnp.zeros((s[1],), dtype))
+                   for n, s in shapes.items())
+    if d.diff:
+        for i, n in enumerate(LAMBDAS):
+            out[n] = 0.1 * jax.random.normal(
+                jax.random.fold_in(key, 16 + i), (d.head_dim,), jnp.float32)
+        out["subln"] = jnp.ones((2 * d.v_dim,), dtype)
     if d.qk_norm:
         out["q_norm"] = jnp.ones((d.head_dim,), dtype)
         out["k_norm"] = jnp.ones((d.head_dim,), dtype)
@@ -144,15 +185,25 @@ def rope_half(x, pos, theta):
                            axis=-1)
 
 
+#: the four learned vectors of a differential layer's ``lambda``
+LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+
+
+def _mmb(p, dims: GQADims, x, name: str):
+    """``x W_<name>``, and the projection's bias where the dims say so."""
+    out = mm(x, p["w_" + name])
+    return out + p["b_" + name].astype(jnp.float32) if dims.bias else out
+
+
 def project(p, dims: GQADims, x, pos):
     """Queries, keys and values of the positions ``x`` [..., T, dim]:
     ``(q [..., T, heads, d], k [..., T, kv_heads, d], v alike)``, float32,
     queries and keys normed and turned where the stack's are."""
     d = dims
     lead = x.shape[:-1]
-    q = mm(x, p["w_q"]).reshape(lead + (d.heads, d.head_dim))
-    k = mm(x, p["w_k"]).reshape(lead + (d.kv_heads, d.head_dim))
-    v = mm(x, p["w_v"]).reshape(lead + (d.kv_heads, d.v_dim))
+    q = _mmb(p, d, x, "q").reshape(lead + (d.heads, d.head_dim))
+    k = _mmb(p, d, x, "k").reshape(lead + (d.kv_heads, d.head_dim))
+    v = _mmb(p, d, x, "v").reshape(lead + (d.kv_heads, d.v_dim))
     if d.value_scale != 1.0:
         v = d.value_scale * v
     if d.qk_norm:
@@ -180,8 +231,26 @@ def block_end(pos, block_len: int):
     return (pos // block_len) * block_len + (block_len - 1)
 
 
-def _out(p, o):
-    return mm(o.reshape(o.shape[:-2] + (-1,)), p["w_o"])
+def lambda_init(depth: int) -> float:
+    """A differential layer's ``lambda_init``, by its place in the stack."""
+    return 0.8 - 0.6 * math.exp(-0.3 * depth)
+
+
+def diff_lambda(p, depth: int):
+    """``lambda = exp(l_q1 . l_k1) - exp(l_q2 . l_k2) + lambda_init``."""
+    l_q1, l_k1, l_q2, l_k2 = (p[n].astype(jnp.float32) for n in LAMBDAS)
+    return (jnp.exp(jnp.sum(l_q1 * l_k1)) - jnp.exp(jnp.sum(l_q2 * l_k2))
+            + lambda_init(depth))
+
+
+def _out(p, dims: GQADims, o, depth: int = 0):
+    """The heads' outputs side by side through ``W_o``: ``o`` [..., heads,
+    v_dim], or under differential attention [..., heads / 2, 2, 2 * v_dim]
+    (a double head's ``o_1`` and ``o_2``), combined here."""
+    if dims.diff:
+        o = rms_norm(o[..., 0, :] - diff_lambda(p, depth) * o[..., 1, :],
+                     p["subln"], dims.eps) * (1.0 - lambda_init(depth))
+    return _mmb(p, dims, o.reshape(o.shape[:-2] + (-1,)), "o")
 
 
 def sees(dims: GQADims, q_pos, k_pos):
@@ -194,14 +263,21 @@ def sees(dims: GQADims, q_pos, k_pos):
     return out
 
 
-def attend_full(p, dims: GQADims, x, pos):
+def attend_full(p, dims: GQADims, x, pos, depth: int = 0, kv=None):
     """Every position of ``x`` [T, dim] against every one its mask lets it
     see, scores materialised: the plain form. A sink joins the softmax's
     normaliser and nothing else: ``P_ij = exp(s_ij - m) / (sum_j exp(s_ij -
-    m) + exp(b_h - m))``."""
+    m) + exp(b_h - m))``. ``kv``: the ``(k, v)`` of another mixer over the
+    same positions, for a cross mixer (:func:`project`'s)."""
     d = dims
     T = x.shape[0]
-    q, k, v = project(p, d, x, pos)
+    if d.cross:
+        q = _mmb(p, d, x, "q").reshape(T, d.heads, d.head_dim)
+        k, v = kv
+    else:
+        q, k, v = project(p, d, x, pos)
+    if d.diff:
+        return _out(p, d, _diff_full(d, q, k, v, pos), depth)
     q = q.reshape(T, d.kv_heads, d.group, d.head_dim)
     s = jnp.einsum("tkgd,ukd->kgtu", q, k,
                    precision=jax.lax.Precision.HIGHEST)
@@ -215,7 +291,25 @@ def attend_full(p, dims: GQADims, x, pos):
     prob = jax.nn.softmax(s, axis=-1)[..., :T]
     o = jnp.einsum("kgtu,ukd->tkgd", prob, v,
                    precision=jax.lax.Precision.HIGHEST)
-    return _out(p, o.reshape(T, d.heads, d.v_dim))
+    return _out(p, d, o.reshape(T, d.heads, d.v_dim))
+
+
+def _diff_full(dims: GQADims, q, k, v, pos):
+    """The equations of differential attention as they stand, scores
+    materialised: ``[T, heads / 2, 2, 2 * v_dim]``."""
+    d, T, hi = dims, q.shape[0], jax.lax.Precision.HIGHEST
+    pairs = d.kv_heads // 2
+    v12 = jnp.concatenate([v[:, 0::2], v[:, 1::2]], axis=-1)  # [T, pairs, 2v]
+    seen = sees(d, pos, pos)[None, None]
+    o = []
+    for a in (0, 1):
+        q_a = q[:, a::2].reshape(T, pairs, d.group, d.head_dim)
+        s = jnp.einsum("tpgd,upd->pgtu", q_a, k[:, a::2], precision=hi)
+        s = s / math.sqrt(d.head_dim) if d.scale is None else s * d.scale
+        prob = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        o.append(jnp.einsum("pgtu,upd->tpgd", prob, v12, precision=hi
+                            ).reshape(T, d.heads // 2, 2 * d.v_dim))
+    return jnp.stack(o, axis=-2)
 
 
 def _to_cache(k, v, cache):
@@ -241,52 +335,73 @@ def _attend(dims: GQADims, q, pos, cache, slots, n_blocks, block: int):
             cache, (s, j * block, 0), (1, block, cache.shape[-1]))[0])(slots)
         return _split(d, rows)
 
-    o = attend_over_blocks(q, q_pos, kv_block, n_blocks, block, d.v_dim,
+    o = attend_over_blocks(q, q_pos, kv_block, n_blocks, block, d.walk_v_dim,
                            dtype=jnp.float32, scale=d.scale)
     return _unfolded(d, o, B, S)
 
 
 def _split(dims: GQADims, rows):
     """Cache rows [B, block, width] as ``(k [B, block, kv_heads, d], v [B,
-    block, kv_heads, v_dim])``."""
+    block, kv_heads, v_dim])``; under differential attention ``v [B, block,
+    kv_heads, 2 * v_dim]``: behind key head ``2p + a`` both values of pair
+    ``p``."""
     d, (B, block) = dims, rows.shape[:2]
     half = d.kv_heads * d.head_dim
-    return (rows[..., :half].reshape(B, block, d.kv_heads, d.head_dim),
-            rows[..., half:].reshape(B, block, d.kv_heads, d.v_dim))
+    k = rows[..., :half].reshape(B, block, d.kv_heads, d.head_dim)
+    if not d.diff:
+        return k, rows[..., half:].reshape(B, block, d.kv_heads, d.v_dim)
+    # key head 2p + a with its pair's two values behind it
+    v = rows[..., half:].reshape(B, block, d.kv_heads // 2, 1, 2 * d.v_dim)
+    return k, jnp.broadcast_to(
+        v, (B, block, d.kv_heads // 2, 2, 2 * d.v_dim)).reshape(
+            B, block, d.kv_heads, 2 * d.v_dim)
 
 
 def _folded(dims: GQADims, q, dtype):
     """``q`` [B, S, heads, d] with each key/value head's group of queries
     folded into the query axis: ``[B, S * group, kv_heads, d]``."""
     d, (B, S) = dims, q.shape[:2]
+    if d.diff:      # query head (p * group + g) * 2 + a reads key head 2p + a
+        q = q.reshape(B, S, d.kv_heads // 2, d.group, 2, d.head_dim)
+        return q.transpose(0, 1, 3, 2, 4, 5).reshape(
+            B, S * d.group, d.kv_heads, d.head_dim).astype(dtype)
     q = q.reshape(B, S, d.kv_heads, d.group, d.head_dim)
     return q.transpose(0, 1, 3, 2, 4).reshape(
         B, S * d.group, d.kv_heads, d.head_dim).astype(dtype)
 
 
 def _unfolded(dims: GQADims, o, B: int, S: int):
+    """A walk's output with the heads back in their places: ``[B, S, heads,
+    v_dim]``, or ``[B, S, heads / 2, 2, 2 * v_dim]`` (:func:`_out`'s)."""
     d = dims
+    if d.diff:
+        o = o.reshape(B, S, d.group, d.kv_heads // 2, 2, 2 * d.v_dim)
+        return o.transpose(0, 1, 3, 2, 4, 5).reshape(
+            B, S, d.heads // 2, 2, 2 * d.v_dim)
     o = o.reshape(B, S, d.group, d.kv_heads, d.v_dim)
     return o.transpose(0, 1, 3, 2, 4).reshape(B, S, d.heads, d.v_dim)
 
 
-def prefill_chunk(p, dims: GQADims, x, offset, cache, slot, block: int):
+def prefill_chunk(p, dims: GQADims, x, offset, cache, slot, block: int,
+                  scope: str = "gqa", depth: int = 0):
     """Whole blocks ``x`` [C, dim] of ONE session, at positions ``offset +
     arange(C)`` (``offset`` and ``C`` multiples of the block length), against
-    that session's slot of ``cache`` [slots, P, width]. ``(out [C, dim]
-    float32, cache)``."""
+    that session's slot of ``cache`` [slots, P, width]. The walk lies under
+    the device scope ``<scope>.attend``. ``(out [C, dim] float32, cache)``."""
     C = x.shape[0]
     pos = offset + jnp.arange(C, dtype=jnp.int32)
     q, k, v = project(p, dims, x, pos)
     cache = jax.lax.dynamic_update_slice(
         cache, _to_cache(k, v, cache)[None], (slot, offset, 0))
     n_blocks = (offset + C + block - 1) // block
-    o = _attend(dims, q[None], pos[None], cache, jnp.reshape(slot, (1,)),
-                n_blocks, block)[0]
-    return _out(p, o), cache
+    with jax.named_scope(scope + ".attend"):
+        o = _attend(dims, q[None], pos[None], cache, jnp.reshape(slot, (1,)),
+                    n_blocks, block)[0]
+    return _out(p, dims, o, depth), cache
 
 
-def block_step(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int):
+def block_step(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int,
+               scope: str = "gqa", depth: int = 0):
     """One block of each of several sessions: ``x`` [B, block_len, dim] at
     positions ``pos`` [B, block_len] of the slots ``slots`` [B] (two rows may
     name one slot, at consecutive blocks: every row's keys are written
@@ -297,12 +412,36 @@ def block_step(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int):
     for b in range(x.shape[0]):
         cache = jax.lax.dynamic_update_slice(
             cache, rows[b][None], (slots[b], pos[b, 0], 0))
-    return _out(p, _attend(dims, q, pos, cache, slots, n_blocks, block)), cache
+    with jax.named_scope(scope + ".attend"):
+        o = _attend(dims, q, pos, cache, slots, n_blocks, block)
+    return _out(p, dims, o, depth), cache
 
 
 #: under the causal mask (``block_len`` 1) a row's positions are a few new
 #: positions of its session, each seeing the ones before it: an extension
 extend = block_step
+
+
+def cross_rows(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int,
+               scope: str = "gqa_cross", depth: int = 0):
+    """A CROSS mixer for ONE position a session: ``x`` [B, dim] at positions
+    ``pos`` [B] against the slots ``slots`` [B] of ``cache`` [slots, P,
+    width], the span ANOTHER mixer wrote (its keys and values at positions
+    ``<= pos`` are there already); nothing is written. ``n_blocks`` (traced)
+    covers the longest session of the batch. ``out [B, dim]`` float32.
+
+    A batch of ONE is walked as two of the same row: for a single slot XLA
+    reads the cache by a plain slice and re-lays the WHOLE span out for the
+    walk's matrix-vector products (a 3 GB copy a call at 17 slots of 33,792
+    positions: a compile for a described v5e, PR 53); two rows are gathered,
+    as a batch's are, and the span stays as it lies."""
+    d, B = dims, x.shape[0]
+    q = _mmb(p, d, x, "q").reshape(B, 1, d.heads, d.head_dim)
+    if B == 1:
+        q, pos, slots = (jnp.concatenate([a, a]) for a in (q, pos, slots))
+    with jax.named_scope(scope + ".attend"):
+        o = _attend(d, q, pos[:, None], cache, slots, n_blocks, block)
+    return _out(p, d, o[:B, 0], depth)
 
 
 # -- a window layer's ring -----------------------------------------------------
@@ -351,13 +490,13 @@ def _attend_ring(p, dims: GQADims, q, pos, last, ring, slots):
             d.kv_heads, S * d.group)
     o = attend_over_blocks(
         _folded(d, q, ring.dtype), jnp.repeat(pos, d.group, axis=1),
-        kv_block, rounds, block, d.v_dim, dtype=jnp.float32, scale=d.scale,
-        first_block=first, window=d.window, sink=sink)
+        kv_block, rounds, block, d.walk_v_dim, dtype=jnp.float32,
+        scale=d.scale, first_block=first, window=d.window, sink=sink)
     return _unfolded(d, o, B, S), rounds
 
 
 def window_prefill_chunk(p, dims: GQADims, x, n_valid, offset, ring, slot,
-                         scope: str = "gqa_window"):
+                         scope: str = "gqa_window", depth: int = 0):
     """:func:`prefill_chunk` of a WINDOW layer: ``x`` [C, dim] of one session
     at positions ``offset + arange(C)``, the first ``n_valid`` of them real,
     against that session's slot of ``ring`` [slots, R, width] (``R >= window
@@ -381,11 +520,11 @@ def window_prefill_chunk(p, dims: GQADims, x, n_valid, offset, ring, slot,
             p, dims, q[None], pos[None],
             jnp.reshape(offset + n_valid - 1, (1,)), ring,
             jnp.reshape(slot, (1,)))
-    return _out(p, o[0]), ring, rounds
+    return _out(p, dims, o[0], depth), ring, rounds
 
 
 def window_extend(p, dims: GQADims, x, n_new, pos, ring, slots,
-                  scope: str = "gqa_window"):
+                  scope: str = "gqa_window", depth: int = 0):
     """:data:`extend` of a WINDOW layer: ``x`` [B, S, dim] at positions
     ``pos`` [B, S] of the slots ``slots`` [B], the first ``n_new`` [B] of
     each row real and written. ``(out [B, S, dim] float32, ring, rounds
@@ -400,4 +539,4 @@ def window_extend(p, dims: GQADims, x, n_new, pos, ring, slots,
         o, rounds = _attend_ring(
             p, dims, q, pos, pos[:, 0] + jnp.maximum(n_new, 1) - 1, ring,
             slots)
-    return _out(p, o), ring, rounds
+    return _out(p, dims, o, depth), ring, rounds

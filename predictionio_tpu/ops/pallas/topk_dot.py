@@ -288,9 +288,10 @@ def _put_rows(table, rows, at):
     return jax.lax.dynamic_update_slice(table, rows.T, (0, at))
 
 
-#: rows per transfer of :func:`to_kernel_layout`: what the build holds
-#: on the device beside the table itself (64 MB at D = 64)
-_BUILD_ROWS = 1 << 18
+#: bytes per transfer of :func:`to_kernel_layout`: what the build holds
+#: on the device beside the table itself (1 << 18 rows at D = 64; at D =
+#: 2,560 a slab of 1 << 18 rows was the whole 2 GB table a second time)
+_BUILD_BYTES = 64 << 20
 
 
 def to_kernel_layout(items, block_items=None):
@@ -302,11 +303,13 @@ def to_kernel_layout(items, block_items=None):
     either side."""
     n, D = items.shape
     table = _empty_table(*table_shape(D, n, block_items))
-    for lo in range(0, n, _BUILD_ROWS):
-        rows = items[lo:lo + _BUILD_ROWS]
-        if len(rows) < _BUILD_ROWS and lo:
+    # whole lanes' worth of rows: a slab lands at an aligned column
+    slab = max(128, _BUILD_BYTES // (4 * D) // 128 * 128)
+    for lo in range(0, n, slab):
+        rows = items[lo:lo + slab]
+        if len(rows) < slab and lo:
             # a ragged last slab would compile a second program
-            lo = n - _BUILD_ROWS
+            lo = n - slab
             rows = items[lo:]
         table = _put_rows(table, jnp.asarray(rows, jnp.float32), lo)
         # one slab in flight: the host would otherwise queue every

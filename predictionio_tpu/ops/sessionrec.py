@@ -36,6 +36,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.ops import gqa as gqa_ops
+from predictionio_tpu.ops import mamba1 as mamba1_ops
 from predictionio_tpu.ops import mla as mla_ops
 from predictionio_tpu.ops import moe as moe_ops
 from predictionio_tpu.ops import ssm as ssm_ops
@@ -45,6 +46,7 @@ from predictionio_tpu.ops.attention import (
     ring_attention_sharded,
 )
 from predictionio_tpu.ops.gqa import GQADims
+from predictionio_tpu.ops.mamba1 import Mamba1Dims
 from predictionio_tpu.ops.mla import MLADims
 from predictionio_tpu.ops.moe import MoEDims
 from predictionio_tpu.ops.ssm import SSMDims
@@ -87,8 +89,11 @@ class BlockSpec:
 
     #: "mha" (q/k/v heads) | "mla" (ops/mla.py) | "gqa" (ops/gqa.py) |
     #: "gqa_window" (ops/gqa.py under the stack's second dims, a sliding
-    #: window's) | "mamba2" (ops/ssm.py); the blocks of one stack may differ
-    #: in it
+    #: window's) | "mamba2" (ops/ssm.py) | "mamba1" (ops/mamba1.py) | "gmu"
+    #: (ops/mamba1.gmu: it gates the stack's ``memory_block``'s scan output)
+    #: | "gqa_cross" (ops/gqa.cross_rows over the span of the stack's LAST
+    #: "gqa" block); the blocks of one stack may differ in it, and the last
+    #: two keep nothing per session
     mixer: str = "mha"
     #: "gelu_mlp" | "swiglu" | "moe" (the expert layer, ops/moe.py, as the
     #: block's whole FFN: "pre_ln" only)
@@ -183,6 +188,34 @@ class StackSpec:
     #: how the stack generates, where it does (a "gqa" stack under the
     #: block-causal mask); None: a query is answered once, from the head
     generation: Optional[Generation] = None
+    mamba1: Optional[Mamba1Dims] = None
+    #: the dims of the "gqa_cross" blocks (``cross``), which read the span of
+    #: the stack's last "gqa" block
+    gqa_cross: Optional[GQADims] = None
+    #: the "mamba1" block whose scan output (before its gate) the "gmu"
+    #: blocks gate: the stack's memory
+    memory_block: Optional[int] = None
+
+    @property
+    def cross_from(self) -> Optional[int]:
+        """The first block of the CROSS-DECODER: the blocks behind the last
+        one that keeps something per session, all "gmu" or "gqa_cross" (row
+        ``t`` of them reads row ``t`` of the block before, row ``t`` of the
+        memory and another block's keys and values up to ``t``, so they run
+        for the rows that are answered and no other). None: the stack has no
+        such blocks."""
+        stateless = [b.mixer in ("gmu", "gqa_cross") for b in self.blocks]
+        if not any(stateless):
+            return None
+        first = len(stateless) - stateless[::-1].index(False)
+        if any(stateless[:first]):
+            raise ValueError("'gmu' and 'gqa_cross' blocks come last")
+        if "gqa" not in [b.mixer for b in self.blocks[:first]] \
+                or self.memory_block is None \
+                or self.blocks[self.memory_block].mixer != "mamba1":
+            raise ValueError("a cross-decoder needs a 'gqa' block's span "
+                             "and a 'mamba1' memory_block before it")
+        return first
 
 
 def _layernorm(p, x, eps):
@@ -257,6 +290,12 @@ def _init_mixer(spec: StackSpec, block: BlockSpec, key, dtype):
         return gqa_ops.init(key, spec.gqa_window, dtype)
     if block.mixer == "mamba2":
         return ssm_ops.init(key, spec.ssm, dtype)
+    if block.mixer == "mamba1":
+        return mamba1_ops.init(key, spec.mamba1, dtype)
+    if block.mixer == "gmu":
+        return mamba1_ops.init_gmu(key, spec.dim, spec.mamba1.d_inner, dtype)
+    if block.mixer == "gqa_cross":
+        return gqa_ops.init(key, spec.gqa_cross, dtype)
     k1, k2 = jax.random.split(key)
     head_dim = spec.dim // spec.heads
     lecun = jax.nn.initializers.lecun_normal
@@ -740,7 +779,18 @@ class StackPrograms:
     * a state-space mixer (``"mamba2"``): ``{conv [n_slots + 1, d_conv - 1,
       conv_dim], ssm [n_slots + 1, heads, head_dim, d_state]}``, the
       session's recurrent state at ONE position (the caller may then
-      resume a slot only from the end of what it holds).
+      resume a slot only from the end of what it holds); ``"mamba1"``: ``{conv
+      [n_slots + 1, d_conv - 1, d_inner], ssm [n_slots + 1, d_state,
+      d_inner]}``, alike;
+    * a gated memory unit (``"gmu"``) and a cross-attention mixer
+      (``"gqa_cross"``): NOTHING (``None`` in the list). They read what other
+      blocks made: the memory block's scan output at the same position, and
+      the span of the stack's last ``"gqa"`` block. A stack that ends in such
+      blocks (``StackSpec.cross_from``: a CROSS-DECODER) runs them for the
+      rows that are answered and no other: ``prefill`` then runs the blocks
+      before them over its chunk and returns nothing, ``prefill_last`` (the
+      chunk that ends a history) also carries the chunk's last real row
+      through them, and ``extend`` carries each session's last real row.
 
     The blocks of a stack may differ in their mixer. Every stack has
     ``prefill`` (one chunk of one session against its slot); beside it a
@@ -800,17 +850,33 @@ class StackPrograms:
                     "zero_picks", "dense_expert_runs", "expert_row_tiles",
                     "load_max_sum", "group_hit_tokens", "index_blocks",
                     "index_sparse_rows", "window_blocks",
-                    "window_blocks_from0", "full_blocks")
+                    "window_blocks_from0", "full_blocks",
+                    # a stack with a cross-decoder: the rows carried through
+                    # it, and the blocks of ``chunk`` positions of the ONE
+                    # span its readers (the block that writes it and every
+                    # cross mixer) walked for an extension batch's rows,
+                    # beside what each row's own reach would have taken
+                    "cross_rows", "span_blocks_walked", "span_blocks_own")
 
     def __init__(self, spec: StackSpec, params: Dict, shape: ServeShape):
         #: the mixers' kinds, in the order of their caches
         self.kinds = [b.mixer for b in spec.blocks
                       for _ in range(2 if b.topology == "scmoe" else 1)]
-        if not set(self.kinds) <= {"mla", "gqa", "gqa_window", "mamba2"}:
+        #: the kinds that keep nothing per session: they read other blocks'
+        stateless = {"gmu", "gqa_cross"}
+        if not set(self.kinds) <= {"mla", "gqa", "gqa_window", "mamba2",
+                                   "mamba1"} | stateless:
             raise ValueError(
                 "stepwise serving needs mixers that keep a per-session "
-                "cache ('mla', 'gqa', 'gqa_window' or 'mamba2'): got "
+                "cache ('mla', 'gqa', 'gqa_window', 'mamba2' or 'mamba1') "
+                "or read another block's ('gmu', 'gqa_cross'): got "
                 f"{sorted(set(self.kinds))}")
+        #: the first block of the cross-decoder (None: the stack has none),
+        #: and the mixer whose span its cross mixers read
+        self.cross_from = spec.cross_from
+        if self.cross_from is not None:
+            self._span = max(i for i in range(self.cross_from)
+                             if self.kinds[i] == "gqa")
         from predictionio_tpu.obs import jaxmon
 
         self.spec, self.shape, self.params = spec, shape, params
@@ -844,8 +910,11 @@ class StackPrograms:
                  gqa_ops.ring_len(spec.gqa_window.window, shape.chunk),
                  spec.gqa_window.cache_width), dtype),
             "mamba2": lambda: ssm_ops.init_state(
-                spec.ssm, shape.n_slots + 1, dtype)}
-        self.cache = [held[kind]() for kind in self.kinds]
+                spec.ssm, shape.n_slots + 1, dtype),
+            "mamba1": lambda: mamba1_ops.init_state(
+                spec.mamba1, shape.n_slots + 1, dtype)}
+        self.cache = [None if kind in stateless else held[kind]()
+                      for kind in self.kinds]
         #: tokens of a call of each program that this stack compiles: the
         #: shape by which ``ops/moe.moe`` chooses its form
         self.tokens = {"prefill": shape.chunk}
@@ -854,12 +923,17 @@ class StackPrograms:
         self._shapes = {"prefill": ((shape.chunk,), (), (), ())}
         self._zeros = self.totals = jnp.zeros(
             (len(self.TOTAL_KINDS), len(self.TOTAL_FIELDS)), jnp.int32)
-        fns = {"prefill": self._prefill_fn}
+        # a program's name: (the row of ``totals`` it counts in, its function)
+        fns = {"prefill": ("prefill", self._prefill_fn)}
+        if self.cross_from is not None:
+            self.tokens["prefill_last"] = shape.chunk
+            self._shapes["prefill_last"] = self._shapes["prefill"]
+            fns["prefill_last"] = ("prefill", self._prefill_last_fn)
         if gen is None:
             B, S = shape.extend_batch, shape.extend_len
             self.tokens["extend"] = B * S
             self._shapes["extend"] = ((B, S), (B,), (B,), (B,), ())
-            fns["extend"] = self._extend_fn
+            fns["extend"] = ("extend", self._extend_fn)
         else:
             if shape.chunk % gen.block_len or shape.capacity % gen.block_len:
                 raise ValueError("chunk and capacity must be multiples of "
@@ -867,12 +941,13 @@ class StackPrograms:
             B, S = shape.gen_batch, gen.block_len
             self.tokens["block"] = B * S
             self._shapes["block"] = ((B, S), (B,), (B,), (B,), (B,), ())
-            fns["block"] = self._block_fn
+            fns["block"] = ("block", self._block_fn)
         self._compiled = {
-            kind: jax.jit(self._packed(kind, fn), donate_argnums=1).lower(
+            kind: jax.jit(self._packed(kind, fn, counted),
+                          donate_argnums=1).lower(
                 params, self.cache, self.totals,
                 self._args(kind)[0]).compile()
-            for kind, fn in fns.items()}
+            for kind, (counted, fn) in fns.items()}
         for program in self._compiled.values():
             jaxmon.record_scope_map(program)
         # in one run no column grows by more than every token's every pick
@@ -893,13 +968,16 @@ class StackPrograms:
         self.drain_every = (2 ** 31 - 1) // (len(self.kinds) * widest)
 
     # -- the programs ---------------------------------------------------------
-    def _run(self, params, x, valid, mix_with):
+    def _run(self, params, x, valid, mix_with, blocks=None):
         """The blocks over tokens ``x`` [T, dim] (float32 residual stream);
-        ``mix_with(i_mixer, params, h, scope)`` gives block code its
-        mixer."""
+        ``mix_with(i_mixer, params, h, scope)`` gives block code its mixer.
+        ``blocks``: the (start, stop) of the blocks to run, where not all (a
+        stack with a cross-decoder, whose topology is "pre_ln" throughout: a
+        block's mixer has the block's own index)."""
         spec = self.spec
-        loads, zeros, hits, i_mixer = [], [], [], 0
-        for i, block in enumerate(spec.blocks):
+        start, stop = blocks or (0, len(spec.blocks))
+        loads, zeros, hits, i_mixer = [], [], [], start
+        for i, block in enumerate(spec.blocks[start:stop], start):
             mixers = {"a": i_mixer, "b": i_mixer + 1}
 
             def mix(which, p, h, _m=mixers, _i=i, _kind=block.mixer):
@@ -936,11 +1014,13 @@ class StackPrograms:
         return (params["item_embed"]["embedding"][ids].astype(jnp.float32)
                 * self.spec.embed_scale)
 
-    def _packed(self, kind, fn):
+    def _packed(self, kind, fn, counted):
         """Program ``kind`` as it is compiled: ``fn`` with what the host
         knows of a call cut from its one array, and the call's counters
-        added to row ``kind`` of ``totals``, in ``TOTAL_FIELDS``."""
+        added to row ``counted`` of ``totals`` (one of ``TOTAL_KINDS``), in
+        ``TOTAL_FIELDS``."""
         shapes = self._shapes[kind]
+        row_of = self.TOTAL_KINDS.index(counted)
 
         def program(params, cache, totals, args):
             cache, result, counters = fn(params, cache, *_cut(args, shapes))
@@ -963,26 +1043,64 @@ class StackPrograms:
             if "window_blocks" in counters:
                 add.update((f, counters[f]) for f in (
                     "window_blocks", "window_blocks_from0", "full_blocks"))
+            add.update((f, counters[f]) for f in (
+                "cross_rows", "span_blocks_walked", "span_blocks_own")
+                if f in counters)
             row = jnp.stack([jnp.asarray(add.get(f, 0), jnp.int32)
                              for f in self.TOTAL_FIELDS])
-            return (cache, result, counters,
-                    totals.at[self.TOTAL_KINDS.index(kind)].add(row))
+            return cache, result, counters, totals.at[row_of].add(row)
 
         # the compiled module's name, by which a trace's readers know it
         program.__name__ = fn.__name__
         return program
 
     def _prefill_fn(self, params, cache, ids, n_valid, slot, offset):
+        """A chunk through every block and its last real row's final-normed
+        hidden state; in a stack with a cross-decoder, a chunk that does NOT
+        end its history: the blocks before the cross-decoder, and no
+        result (zeros)."""
+        cache, x, counters, _ = self._chunk(params, cache, ids, n_valid,
+                                            slot, offset)
+        if self.cross_from is not None:
+            return cache, jnp.zeros((1, self.spec.dim), jnp.float32), counters
+        return cache, self._final(params, x[n_valid - 1])[None], counters
+
+    def _prefill_last_fn(self, params, cache, ids, n_valid, slot, offset):
+        """The chunk that ENDS a history, in a stack with a cross-decoder:
+        as :meth:`_prefill_fn`, and the chunk's last real row carried through
+        the cross-decoder to the head."""
+        cache, x, counters, memory = self._chunk(params, cache, ids, n_valid,
+                                                 slot, offset)
+        last = n_valid - 1
+        n_blocks = (offset + n_valid + self.shape.chunk - 1) // self.shape.chunk
+        row = self._cross(params, cache, x[last][None], memory[last][None],
+                          jnp.reshape(offset + last, (1,)),
+                          jnp.reshape(slot, (1,)), n_blocks,
+                          jnp.ones((1,), bool))
+        counters["cross_rows"] = jnp.int32(1)
+        return cache, self._final(params, row), counters
+
+    def _chunk(self, params, cache, ids, n_valid, slot, offset):
+        """One chunk of one session through the blocks that keep something
+        per session (all of them, or those before the cross-decoder):
+        ``(cache, x [chunk, dim], counters, the memory block's scan output
+        [chunk, d_mem] or None)``."""
         cache = list(cache)
         spec, chunk = self.spec, self.shape.chunk
         valid = jnp.arange(ids.shape[0]) < n_valid
-        scanned, walked = [], []
+        scanned, walked, memory = [], [], [None]
 
         def mix_with(m, p, h, scope):
             kind = self.kinds[m]
             if kind == "mamba2":
                 out, cache[m] = ssm_ops.prefill_chunk(
                     p, spec.ssm, h, n_valid, offset, cache[m], slot, scope)
+            elif kind == "mamba1":
+                out, cache[m], y = mamba1_ops.prefill_chunk(
+                    p, spec.mamba1, h, n_valid, offset, cache[m], slot,
+                    scope)
+                if m == spec.memory_block:
+                    memory[0] = y
             elif kind == "mla":
                 out, cache[m], blocks = mla_ops.prefill_chunk(
                     p, spec.mla, h, offset, cache[m], slot, chunk, scope)
@@ -990,15 +1108,16 @@ class StackPrograms:
             elif kind == "gqa_window":
                 out, cache[m], rounds = gqa_ops.window_prefill_chunk(
                     p, spec.gqa_window, h, n_valid, offset, cache[m], slot,
-                    scope)
+                    scope, m)
                 walked.append(rounds)
             else:
                 out, cache[m] = gqa_ops.prefill_chunk(
-                    p, spec.gqa, h, offset, cache[m], slot, chunk)
+                    p, spec.gqa, h, offset, cache[m], slot, chunk, scope, m)
             return out
 
-        x, counters = self._run(params, self._embed(params, ids), valid,
-                                mix_with)
+        x, counters = self._run(
+            params, self._embed(params, ids), valid, mix_with,
+            self.cross_from and (0, self.cross_from))
         if self.indexed:
             pos = offset + jnp.arange(ids.shape[0], dtype=jnp.int32)
             counters.update(self._index_counts(sum(scanned), valid, pos))
@@ -1006,7 +1125,24 @@ class StackPrograms:
             counters.update(self._walk_counts(
                 sum(walked), (offset + n_valid - 1)[None],
                 (offset + ids.shape[0] + chunk - 1) // chunk, 1))
-        return cache, self._final(params, x[n_valid - 1])[None], counters
+        return cache, x, counters, memory[0]
+
+    def _cross(self, params, cache, x, memory, pos, slots, n_blocks, valid):
+        """The cross-decoder over ONE row a session: ``x`` [B, dim] (the
+        blocks before it at positions ``pos`` [B] of the slots ``slots``),
+        ``memory`` [B, d_mem] the same positions' memory; each cross mixer
+        walks the one span as far as ``n_blocks`` (traced). ``[B, dim]``."""
+        spec, chunk = self.spec, self.shape.chunk
+
+        def mix_with(m, p, h, scope):
+            if self.kinds[m] == "gmu":
+                return mamba1_ops.gmu(p, h, memory)
+            return gqa_ops.cross_rows(
+                p, spec.gqa_cross, h, pos, cache[self._span], slots,
+                n_blocks, chunk, scope, m)
+
+        return self._run(params, x, valid, mix_with,
+                         (self.cross_from, len(spec.blocks)))[0]
 
     def _walk_counts(self, rounds, last, n_blocks, rows):
         """A run's three counts in a stack with window layers: the blocks of
@@ -1041,13 +1177,18 @@ class StackPrograms:
         B, S = ids.shape
         pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
         valid = (jnp.arange(S)[None] < n_new[:, None]).reshape(-1)
-        scanned, walked = [], []
+        scanned, walked, memory = [], [], [None]
 
         def mix_with(m, p, h, scope):
             kind, h = self.kinds[m], h.reshape(B, S, -1)
             if kind == "mamba2":
                 out, cache[m] = ssm_ops.extend(
                     p, spec.ssm, h, n_new, pos0, cache[m], slots, scope)
+            elif kind == "mamba1":
+                out, cache[m], y = mamba1_ops.extend(
+                    p, spec.mamba1, h, n_new, pos0, cache[m], slots, scope)
+                if m == spec.memory_block:
+                    memory[0] = y
             elif kind == "mla":
                 out, cache[m], blocks = mla_ops.extend(
                     p, spec.mla, h, pos, cache[m], slots, n_blocks, chunk,
@@ -1056,16 +1197,17 @@ class StackPrograms:
             elif kind == "gqa_window":
                 out, cache[m], rounds = gqa_ops.window_extend(
                     p, spec.gqa_window, h, n_new, pos, cache[m], slots,
-                    scope)
+                    scope, m)
                 walked.append(rounds)
             else:
                 out, cache[m] = gqa_ops.extend(
-                    p, spec.gqa, h, pos, cache[m], slots, n_blocks, chunk)
+                    p, spec.gqa, h, pos, cache[m], slots, n_blocks, chunk,
+                    scope, m)
             return out.reshape(B * S, -1)
 
         x, counters = self._run(
             params, self._embed(params, ids).reshape(B * S, -1), valid,
-            mix_with)
+            mix_with, self.cross_from and (0, self.cross_from))
         if self.indexed:    # every real session's rows scan them
             counters.update(self._index_counts(
                 sum(scanned) * (n_new > 0).sum(), valid, pos.reshape(-1)))
@@ -1073,7 +1215,22 @@ class StackPrograms:
             counters.update(self._walk_counts(
                 sum(walked), jnp.where(n_new > 0, pos0 + n_new - 1, -1),
                 n_blocks, (n_new > 0).sum()))
-        last = x.reshape(B, S, -1)[jnp.arange(B), jnp.maximum(n_new - 1, 0)]
+        at = jnp.maximum(n_new - 1, 0)
+        last = x.reshape(B, S, -1)[jnp.arange(B), at]
+        if self.cross_from is not None:
+            real = n_new > 0
+            last = self._cross(params, cache, last,
+                               memory[0][jnp.arange(B), at], pos0 + at, slots,
+                               n_blocks, real)
+            # the span's readers: the block that writes it and the cross
+            # mixers; every real row walks as far as the batch's longest
+            readers = 1 + self.kinds.count("gqa_cross")
+            own = jnp.where(real, (pos0 + S + chunk - 1) // chunk, 0).sum()
+            counters.update(
+                cross_rows=real.sum().astype(jnp.int32),
+                span_blocks_walked=(readers * n_blocks * real.sum()).astype(
+                    jnp.int32),
+                span_blocks_own=(readers * own).astype(jnp.int32))
         return cache, self._final(params, last), counters
 
     def _block_fn(self, params, cache, ids, slots, pos0, denoise, n_unmask,
@@ -1093,7 +1250,7 @@ class StackPrograms:
         def mix_with(m, p, h, scope):
             out, cache[m] = gqa_ops.block_step(
                 p, self.spec.gqa, h.reshape(B, S, -1), pos, cache[m], slots,
-                n_blocks, self.shape.chunk)
+                n_blocks, self.shape.chunk, scope, m)
             return out.reshape(B * S, -1)
 
         x, counters = self._run(
@@ -1135,14 +1292,22 @@ class StackPrograms:
         taken, self.totals = self.totals, self._zeros
         return taken
 
-    def prefill(self, ids: np.ndarray, slot: int, offset: int):
+    def prefill(self, ids: np.ndarray, slot: int, offset: int,
+                last: bool = True):
         """One chunk (``len(ids) <= chunk`` real positions) of the session in
         ``slot``, from position ``offset`` on. ``(h_last [1, dim],
-        counters)``, still on the device."""
+        counters)``, still on the device. ``last``: whether the chunk ends
+        its history; in a stack with a cross-decoder a chunk that does not
+        runs the shorter program, and its ``h_last`` is zeros."""
         args, (padded, n_valid, at_slot, at) = self._args("prefill")
         padded[:len(ids)] = ids
         n_valid[()], at_slot[()], at[()] = len(ids), slot, offset
-        return self._call("prefill", args)
+        return self._call(self.prefill_program(last), args)
+
+    def prefill_program(self, last: bool) -> str:
+        """The chunk program a chunk runs, by whether it ends its history."""
+        return ("prefill_last" if last and self.cross_from is not None
+                else "prefill")
 
     def n_blocks(self, reach: int) -> np.int32:
         """The cached blocks attention walks to reach position ``reach``."""

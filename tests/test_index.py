@@ -91,6 +91,33 @@ class TestTopkDotKernel:
         np.testing.assert_allclose(np.asarray(s), bs, rtol=1e-5, atol=1e-5)
         assert np.array_equal(np.asarray(i), bi)
 
+    @pytest.mark.parametrize("n,D", [(1000, 40), (640, 40), (90, 8)])
+    def test_the_table_built_in_slabs_is_the_transposed_table(
+            self, monkeypatch, n, D):
+        """``to_kernel_layout`` writes slabs of whole lanes' worth of rows
+        (a multiple of 128: 6,528 rows at D = 2,560, not 6,553) into the
+        one buffer; the ragged last slab overlaps the one before it."""
+        from predictionio_tpu.ops.pallas import topk_dot as tkd
+
+        monkeypatch.setattr(tkd, "_BUILD_BYTES", 4 * D * 300)
+        puts = []
+        put = tkd._put_rows
+        monkeypatch.setattr(tkd, "_put_rows", lambda table, rows, at: (
+            puts.append((len(rows), at)), put(table, rows, at))[1])
+        items = np.random.default_rng(n).normal(size=(n, D)).astype(
+            np.float32)
+        table = np.asarray(tkd.to_kernel_layout(items, 512))
+        assert np.array_equal(table[:D, :n], items.T)
+        assert not table[:, n:].any() and not table[D:].any()
+        # 300 rows' worth of bytes: slabs of 256 rows
+        if n > 256:
+            assert {rows for rows, _ in puts} == {256}
+            assert [at for _, at in puts][:-1] == list(
+                range(0, n - 256, 256)) and puts[-1][1] == n - 256
+        else:
+            assert puts == [(n, 0)]
+        assert (64 << 20) // (4 * 2560) // 128 * 128 == 6528
+
     def test_item_ids_beyond_uint16(self):
         """>2^16 items: the winning global id must survive the int32
         iota/merge path (a uint16 anywhere would alias it)."""

@@ -682,8 +682,11 @@ def test_the_accepted_stacks_serve_programs_trace_to_the_parents_jaxpr(
     tree = traced()
     assert set(tree) == ({"_prefill_fn", "_block_fn"} if name == "sdar"
                          else {"_prefill_fn", "_extend_fn"})
-    for fn in ("prefill_chunk", "block_step", "extend"):
-        monkeypatch.setattr(gqa_ops, fn, getattr(parent_gqa, fn))
+    # (PR 53: the tree's take the block's place in the stack and its scope
+    # behind the parent's arguments: 7 and 8 of them)
+    for fn, n in (("prefill_chunk", 7), ("block_step", 8), ("extend", 8)):
+        monkeypatch.setattr(gqa_ops, fn, lambda *a, _f=getattr(
+            parent_gqa, fn), _n=n: _f(*a[:_n]))
     parents = traced()
     assert tree == parents
     assert not any("gqa_window" in text for text in tree.values())

@@ -128,6 +128,18 @@ def _chunk_attend(C, H, d_qk, d_v, masked, slots=2, positions=33_792):
     return fn, shapes, 1
 
 
+def _selective_scan(T, d_inner, d_state):
+    """One Mamba-1 layer's scan over a chunk: ``x`` and ``delta`` rows,
+    ``A``, ``B``, ``C`` and the incoming state, float32."""
+    from predictionio_tpu.ops.pallas import selective_scan
+
+    f32 = jnp.float32
+    return selective_scan.selective_scan, [
+        ((T, d_inner), f32), ((T, d_inner), f32), ((d_state, d_inner), f32),
+        ((T, d_state), f32), ((T, d_state), f32),
+        ((d_state, d_inner), f32)], 1
+
+
 @pytest.mark.parametrize("build,args", [
     (_flash_ce, (8192, 128)),
     (_flash_ce, (4096, 64)),
@@ -174,6 +186,9 @@ def _chunk_attend(C, H, d_qk, d_v, masked, slots=2, positions=33_792):
     (_chunk_attend, (512, 64, 256, 256, True)),
     (_chunk_attend, (512, 64, 256, 256, False)),
     (_chunk_attend, (512, 64, 192, 128, False)),
+    # a chunk's selective scan at Phi-4-mini-flash's widths: five groups of
+    # 1,024 channels, sixteen states a channel in registers
+    (_selective_scan, (512, 5120, 16)),
 ], ids=["flash_ce-8192x128", "flash_ce-4096x64", "flash_ce-65536x256",
         "flash_ce-131072x256", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
@@ -185,7 +200,8 @@ def _chunk_attend(C, H, d_qk, d_v, masked, slots=2, positions=33_792):
         "expert_groups-512x6144-16x2048", "expert_stream-16x7168-12x2048",
         "expert_groups-512x7168-12x2048", "topk_dot-20480x7168-B1",
         "topk_dot-20480x7168-B4", "chunk_attend-512x64x256x256-keep",
-        "chunk_attend-512x64x256x256", "chunk_attend-512x64x192x128"])
+        "chunk_attend-512x64x256x256", "chunk_attend-512x64x192x128",
+        "selective_scan-512x5120x16"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
     text = _compiled_text(fn, shapes, one_chip)
@@ -213,8 +229,9 @@ def _kernel_instructions(text):
     (_expert_stream, (32, 2048, 768, 128), ["expert_stream"]),
     (_expert_groups, (512, 2048, 768, 128, 8), ["expert_groups"]),
     (_chunk_attend, (512, 64, 256, 256, True), ["chunk_attend"]),
+    (_selective_scan, (512, 5120, 16), ["selective_scan"]),
 ], ids=["topk_dot", "flash_ce", "flash_ce_two_pass", "expert_stream",
-        "expert_groups", "chunk_attend"])
+        "expert_groups", "chunk_attend", "selective_scan"])
 def test_a_kernels_instruction_carries_its_name(one_chip, no_compile_cache,
                                                 build, args, names):
     """A device trace's events are named by the instruction's text: the
