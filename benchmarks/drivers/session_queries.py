@@ -89,22 +89,16 @@ def run(bench) -> dict:
         raise RuntimeError(f"load generator: {load['fatal']}")
     mark_window(bench, traced)
 
-    lat = sorted(load["latencies_s"])
+    end_to_end, lat, firsts, later = window_numbers(load, setup_s,
+                                                    percentile)
     answered = len(lat)
-    end_to_end = {
-        "setup_s": setup_s,
-        "query_p50_ms": percentile(lat, 0.50) * 1e3,
-        "query_p95_ms": percentile(lat, 0.95) * 1e3,
-        "query_rate": answered / load["window_s"],
-    }
+    # ``window_end_to_end``: as ``closed_loop_queries`` hands it over, for a
+    # cell that keeps one of the window's own numbers in sight per layer
     layer_ctx = {"bench": bench, "traced": traced, **(traced or {}),
-                 "window_stats0": stats0, "window_stats1": stats1}
+                 "window_stats0": stats0, "window_stats1": stats1,
+                 "window_end_to_end": end_to_end}
     layer = bench.read_layer_metrics(layer_ctx) if bench.trace else {}
     peak = bench.memory_peak()
-    firsts = sorted(d for d, f in zip(load["latencies_s"],
-                                      load["first_query"]) if f)
-    later = sorted(d for d, f in zip(load["latencies_s"],
-                                     load["first_query"]) if not f)
     counted = {k: stats1[k] - stats0[k] for k in stats1
                if isinstance(stats1[k], (int, float))}
     notes = [
@@ -177,6 +171,30 @@ def run(bench) -> dict:
         "end_to_end": end_to_end, "layer_metrics": layer,
         "memory_peak_bytes": peak, "notes": notes, "traced": traced,
     }
+
+
+def window_numbers(load: dict, setup_s: float, percentile) -> tuple:
+    """The window's own end-to-end numbers from what the load generator
+    said, and the sorted latencies behind them: all, the first queries',
+    the extensions'."""
+    lat = sorted(load["latencies_s"])
+    firsts = sorted(d for d, f in zip(load["latencies_s"],
+                                      load["first_query"]) if f)
+    later = sorted(d for d, f in zip(load["latencies_s"],
+                                     load["first_query"]) if not f)
+    end_to_end = {
+        "setup_s": setup_s,
+        "query_p50_ms": percentile(lat, 0.50) * 1e3,
+        "query_p95_ms": percentile(lat, 0.95) * 1e3,
+        "query_rate": len(lat) / load["window_s"],
+    }
+    if firsts:
+        # what a returning user waits for the first page, prefill and FIFO
+        # included: the first queries' OWN median, whose rank does not move
+        # with the window's count of extensions (PERF.md section 2); a window
+        # without a first query has no such number, never 0
+        end_to_end["first_query_p50_ms"] = percentile(firsts, 0.5) * 1e3
+    return end_to_end, lat, firsts, later
 
 
 def mark_window(bench, traced) -> None:

@@ -22,6 +22,12 @@ REAL_CELL = "glm-5.lifelong32k-c4"
 CONFIG = "glm-5"
 
 
+#: the cell's own end-to-end metrics beside ``setup_s`` since PR 54: its
+#: tail is the first queries' own median, and ``query_p95_ms`` is the
+#: per-layer ``window_p95_ms.lifelong32k-c4`` (``PERF.md`` section 2)
+END_TO_END = ("query_p50_ms", "query_rate", "first_query_p50_ms")
+
+
 def entry(name, unit, better, source, layer, moves):
     return {"name": name, "unit": unit, "better": better, "source": source,
             "layer": layer, "moves": moves, "workloads": [REAL_CELL]}
@@ -31,7 +37,7 @@ ENTRIES = [
     entry("extend_step_ms.glm", "ms", "lower", "program_span",
           "sequence engine", "query_p50_ms"),
     entry("prefill_chunk_ms.glm", "ms", "lower", "program_span",
-          "sequence engine", "query_p95_ms"),
+          "sequence engine", "first_query_p50_ms"),
     entry("cache_hit_tokens_pct.glm", "%", "higher", "program_counter",
           "latent cache", "query_rate"),
     entry("mla_device_share_pct.glm", "%", "lower", "device_trace",
@@ -41,22 +47,25 @@ ENTRIES = [
     entry("moe_device_share_pct.glm", "%", "lower", "device_trace",
           "sequence programs", "query_rate"),
     entry("prefill_roofline_pct.glm", "%", "higher", "device_trace",
-          "sequence programs", "query_p95_ms"),
+          "sequence programs", "first_query_p50_ms"),
     entry("extend_roofline_pct.glm", "%", "higher", "device_trace",
           "sequence programs", "query_p50_ms"),
     entry("index_score_roofline_pct.glm", "%", "higher", "device_trace",
-          "sequence programs", "query_p95_ms"),
+          "sequence programs", "first_query_p50_ms"),
     entry("sparse_attend_roofline_pct.glm", "%", "higher", "device_trace",
           "sequence programs", "query_p50_ms"),
     entry("sparse_rows_pct.glm", "%", "higher", "program_counter",
           "sequence programs", "query_rate"),
     entry("device_idle_pct.lifelong32k-c4", "%", "lower", "device_trace",
           "device", "query_rate"),
+    entry("window_p95_ms.lifelong32k-c4", "ms", "lower", "host_clock",
+          "sequence engine", "first_query_p50_ms"),
 ]
 NEW_METRICS = [e["name"] for e in ENTRIES]
 #: the readers that need nothing of the device
 ON_THE_CPU = {"extend_step_ms.glm", "prefill_chunk_ms.glm",
-              "cache_hit_tokens_pct.glm", "sparse_rows_pct.glm"}
+              "cache_hit_tokens_pct.glm", "sparse_rows_pct.glm",
+              "window_p95_ms.lifelong32k-c4"}
 
 
 def run_cell(harness, capsys, *extra, seed=5000000011):
@@ -101,8 +110,8 @@ def test_the_cell_runs_from_new_files_and_prints_the_contracts_line(
     assert any("compilations inside the window: 0" in l for l in log)
     assert sum(1 for l in log if l.startswith("# check ")) == 4
     if trace == "0":
-        assert {"query_p50_ms", "query_p95_ms", "query_rate",
-                "setup_s"} <= set(line["metrics"])
+        # the tiny copy's specification lists what the real cell reports
+        assert set(line["metrics"]) == {*END_TO_END, "setup_s"}
     else:
         assert {"busy_s", "window_s"} <= set(line["device"])
         # the tiny tree lists the cell's own per-layer entries: what needs
@@ -110,6 +119,9 @@ def test_the_cell_runs_from_new_files_and_prints_the_contracts_line(
         assert set(line["metrics"]) == ON_THE_CPU
         # histories of 20-200 against 16 positions a row
         assert 50 < line["metrics"]["sparse_rows_pct.glm"]["value"] < 100
+        said = next(l for l in log if l.startswith("# latency ms: "))
+        tail = line["metrics"]["window_p95_ms.lifelong32k-c4"]["value"]
+        assert f" p95 {tail:.3f} " in said
     assert any("reference: 8 answers compared" in l
                and "(4 first queries" in l for l in log)
     assert any("longest history served 20" in l for l in log)
@@ -127,6 +139,12 @@ def test_the_tiny_tree_lists_the_cells_own_entries_under_its_own_cell():
         tiny = json.load(f)
     assert [dict(m, workloads=[REAL_CELL]) for m in tiny["per_layer"]] \
         == ENTRIES
+    assert {m["name"] for m in tiny["end_to_end"]} == {*END_TO_END,
+                                                       "setup_s"}
+    real = repo_spec.by_name(repo_spec.load()["end_to_end"],
+                             "first_query_p50_ms")
+    assert repo_spec.by_name(tiny["end_to_end"], "first_query_p50_ms") == {
+        k: v for k, v in real.items() if k != "workloads"}
 
 
 def test_the_traffic_holds_the_parameters_the_issue_names():
@@ -143,15 +161,24 @@ def test_the_traffic_holds_the_parameters_the_issue_names():
              "prepared_sessions_per_connection": 32, "check_sample": 12,
              "trace_seconds": 3.0}
     assert {k: mix[k] for k in named} == named
-    # ISSUE 43 named 4.0 here on its reckoning of a 35-60 ms chunk; at the
-    # 100 ms measured, seconds 4 to 7 after GO lie inside the window's first
-    # prefill (a 32,768-event session) and hold NO extension: the three
-    # readers of the extension program found nothing (PERF.md section 6)
-    assert mix["trace_after_go_s"] == 8.0
+    # where the traced stretch lies: ISSUE 43 named 4.0 on its reckoning of a
+    # 35-60 ms chunk (at the 100 ms measured, seconds 4 to 7 lay inside the
+    # 32,768-event prefill); PR 43 set 8.0; PR 50 took a quarter off the
+    # chunk and seconds 8 to 11 then lay BETWEEN two bursts of extensions,
+    # the three readers of the extension program reading nothing (ledger,
+    # PRs 50-53); PR 54 set 5.0 from the model and the window's own timeline
+    # (tests/benchmarks/test_closed_loop_model.py, PERF.md section 6), and
+    # the file says in one key how the place was chosen and what moves it
+    assert mix["trace_after_go_s"] == 5.0
+    why = mix["trace_after_go_why"]
+    for word in ("extensions AND chunks", "bursts", "closed_loop_model.py",
+                 "window_timeline.py", "three seeds"):
+        assert word in why, word
     # what the accepted driver and load generator need beside them, and
     # the comparison's budget (ISSUE 48: test_check_budget.py pins the two)
     assert set(mix) - set(named) == {"driver", "loop", "start", "start_why",
-                                     "trace_after_go_s", "check_budget_s",
+                                     "trace_after_go_s",
+                                     "trace_after_go_why", "check_budget_s",
                                      "check_floor"}
     assert mix["driver"] == "session_queries"
     assert [w.get("delay_s", 0.0) for w in mix["start"]] == [0, .05, .05, .05]
@@ -339,9 +366,16 @@ def test_benchmark_json_names_the_configuration_the_cell_and_each_reader(
     assert len(config["why"]) <= 200
     for e in ENTRIES:
         repo_spec.assert_names_the_reader(spec, e)
-    for name in ("query_p50_ms", "query_p95_ms", "query_rate"):
+    for name in END_TO_END:
         assert REAL_CELL in repo_spec.by_name(
             spec["end_to_end"], name)["workloads"]
+    # PR 54: the cell's tail is not the window's 95th percentile, and every
+    # per-layer entry that lists the cell moves a metric the cell reports
+    assert REAL_CELL not in repo_spec.by_name(
+        spec["end_to_end"], "query_p95_ms")["workloads"]
+    for m in spec["per_layer"]:
+        if REAL_CELL in m.get("workloads", ()):
+            assert m["moves"] in END_TO_END, m["name"]
     # the cell joins no accepted per-layer metric's list (GLM_SPANS.md), and
     # none of four chips came with it
     for m in spec["per_layer"]:

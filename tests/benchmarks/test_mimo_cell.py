@@ -367,10 +367,11 @@ def test_benchmark_json_names_the_configuration_the_cell_and_each_reader(
             assert REAL_CELL not in m.get("workloads", ()), m["name"]
     assert all(w["chips"] == 1 for w in spec["workloads"])
     # appended: the cell's entries stand after everything the benchmark had
+    # (``window_p95_ms.lifelong32k-c4`` came later, at PR 54, behind them)
     names = [m["name"] for m in spec["per_layer"]]
     assert max(names.index(n) for n in names
-               if n.endswith((".glm", ".lifelong32k-c4"))) < min(
-        names.index(n) for n in NEW_METRICS)
+               if n.endswith(".glm") or n == "device_idle_pct.lifelong32k-c4"
+               ) < min(names.index(n) for n in NEW_METRICS)
 
 
 def test_the_configuration_keeps_every_published_number():
