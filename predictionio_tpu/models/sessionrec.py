@@ -536,6 +536,11 @@ class SeqStackModel:
             # device's ``prefill_runs`` in a stack of latent mixers, 0 in
             # every other (``StackPrograms.attend_kernel``)
             "prefill_attend_kernel_chunks": 0,
+            # extension batches whose rows walked their span of keys and
+            # values in the ``span_walk`` kernel: ``extend_runs`` in a
+            # causal stack with a ``gqa`` layer, 0 in every other
+            # (``StackPrograms.walk_kernel``)
+            "extend_walk_kernel_batches": 0,
             # block forwards: rows by kind (a known block of a history is a
             # commit row), positions the rule unmasked, queries answered
             "denoise_rows": 0, "commit_rows": 0, "positions_unmasked": 0,
@@ -723,7 +728,8 @@ class SeqStackModel:
             with trace.device_span("seq.wait", program="extend"):
                 h.block_until_ready()
         reach = sum(len(t.rows) for t in ext)
-        did = {"extend_rows": len(ext)}
+        did = {"extend_rows": len(ext),
+               "extend_walk_kernel_batches": int(programs.walk_kernel)}
         did.update((counter, n) for kind, counter, n in (
             ("mla", "extend_latent_positions", reach),
             ("gqa", "extend_kv_positions", reach),

@@ -33,7 +33,7 @@ cache's type. Two layouts:
 
 * a SPAN ``[slots, P, width]``: position ``t`` at row ``t``, as long as the
   longest session; attention walks its blocks from 0 to the reach
-  (:func:`prefill_chunk`, :func:`block_step`, :data:`extend`);
+  (:func:`prefill_chunk`, :func:`block_step`, :func:`extend`);
 * a RING ``[slots, R, width]`` for a WINDOW layer (:func:`ring_len`: the
   window and one chunk, in whole blocks of ``window`` positions): position
   ``t`` at row ``t mod R``, real positions only, so a slot holds its last ``R``
@@ -46,8 +46,12 @@ Two paths over one set of weights, as in ``ops/mla.py``:
 * a chunk: whole blocks of ONE session against its slot;
 * :func:`block_step`: one block (``block_len`` positions) of each of several
   sessions against their slots — a block being denoised or a finished block
-  being committed, the program is the same. Under the causal mask the same
-  function is an EXTENSION (:data:`extend`): a few new positions a row.
+  being committed, the program is the same: every row walks as far as the
+  batch's longest (``ops.attention.attend_over_blocks``);
+* :func:`extend`, under the causal mask: a few new positions of each of
+  several sessions, written as a block step writes them; then each row walks
+  the blocks of ITS OWN reach, in place, in one kernel
+  (``ops/pallas/span_walk.py``), and a padding row walks none.
 
 Both WRITE the keys and values of their positions and then attend over the
 slot up to the end of those positions. A block's keys and values depend on
@@ -74,7 +78,8 @@ its pair's values behind it (:func:`_split`), so one walk gives ``o_1`` and
 
 A CROSS mixer (``cross``: ``W_q`` and ``W_o`` alone, with its own ``lambda``s
 and sub-norm) writes nothing: :func:`cross_rows` walks the span ANOTHER mixer
-of the same head shapes wrote, for one position a session.
+of the same head shapes wrote, for one position a session, by the same kernel
+as :func:`extend`.
 
 Matrix products take their inputs in the weights' type and accumulate in
 float32; norms, RoPE, softmax, sinks and ``lambda`` are float32.
@@ -89,8 +94,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from predictionio_tpu.ops import pallas
 from predictionio_tpu.ops.attention import attend_over_blocks
 from predictionio_tpu.ops.mla import mm, rms_norm
+from predictionio_tpu.ops.pallas import span_walk
 
 
 @dataclasses.dataclass(frozen=True)
@@ -407,19 +414,37 @@ def block_step(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int,
     name one slot, at consecutive blocks: every row's keys are written
     before any row attends). ``n_blocks`` (traced) covers the longest
     session of the batch. ``(out [B, block_len, dim] float32, cache)``."""
-    q, k, v = project(p, dims, x, pos)
-    rows = _to_cache(k, v, cache)
-    for b in range(x.shape[0]):
-        cache = jax.lax.dynamic_update_slice(
-            cache, rows[b][None], (slots[b], pos[b, 0], 0))
+    q, cache = _written(p, dims, x, pos, cache, slots)
     with jax.named_scope(scope + ".attend"):
         o = _attend(dims, q, pos, cache, slots, n_blocks, block)
     return _out(p, dims, o, depth), cache
 
 
-#: under the causal mask (``block_len`` 1) a row's positions are a few new
-#: positions of its session, each seeing the ones before it: an extension
-extend = block_step
+def _written(p, dims: GQADims, x, pos, cache, slots):
+    """The queries of ``x`` [B, S, dim], and ``cache`` with every row's keys
+    and values written at its slot's positions ``pos`` [B, S]."""
+    q, k, v = project(p, dims, x, pos)
+    rows = _to_cache(k, v, cache)
+    for b in range(x.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[b][None], (slots[b], pos[b, 0], 0))
+    return q, cache
+
+
+def extend(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int,
+           scope: str = "gqa", depth: int = 0):
+    """An EXTENSION, under the causal mask (``block_len`` 1): ``x`` [B, S,
+    dim], a few new positions of each of several sessions at ``pos`` [B, S]
+    of the slots ``slots`` [B], each seeing the ones before it. The rows'
+    keys and values are written as :func:`block_step` writes them; then row
+    ``b`` walks ``n_blocks[b]`` blocks of its slot ([B] traced: the blocks
+    that hold the row's own reach, 0 for a padding row, whose output is
+    zeros; a scalar: that many for every row). ``(out [B, S, dim] float32,
+    cache)``."""
+    q, cache = _written(p, dims, x, pos, cache, slots)
+    with jax.named_scope(scope + ".attend"):
+        o = _walk(dims, q, pos, cache, slots, n_blocks, block)
+    return _out(p, dims, o, depth), cache
 
 
 def cross_rows(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int,
@@ -427,21 +452,60 @@ def cross_rows(p, dims: GQADims, x, pos, cache, slots, n_blocks, block: int,
     """A CROSS mixer for ONE position a session: ``x`` [B, dim] at positions
     ``pos`` [B] against the slots ``slots`` [B] of ``cache`` [slots, P,
     width], the span ANOTHER mixer wrote (its keys and values at positions
-    ``<= pos`` are there already); nothing is written. ``n_blocks`` (traced)
-    covers the longest session of the batch. ``out [B, dim]`` float32.
-
-    A batch of ONE is walked as two of the same row: for a single slot XLA
-    reads the cache by a plain slice and re-lays the WHOLE span out for the
-    walk's matrix-vector products (a 3 GB copy a call at 17 slots of 33,792
-    positions: a compile for a described v5e, PR 53); two rows are gathered,
-    as a batch's are, and the span stays as it lies."""
+    ``<= pos`` are there already); nothing is written. Row ``b`` walks
+    ``n_blocks[b]`` blocks of its slot, as :func:`extend`'s rows do. ``out
+    [B, dim]`` float32."""
     d, B = dims, x.shape[0]
     q = _mmb(p, d, x, "q").reshape(B, 1, d.heads, d.head_dim)
-    if B == 1:
-        q, pos, slots = (jnp.concatenate([a, a]) for a in (q, pos, slots))
     with jax.named_scope(scope + ".attend"):
-        o = _attend(d, q, pos[:, None], cache, slots, n_blocks, block)
-    return _out(p, d, o[:B, 0], depth)
+        o = _walk(d, q, pos[:, None], cache, slots, n_blocks, block)
+    return _out(p, d, o[:, 0], depth)
+
+
+def walk_groups(dims: GQADims):
+    """The groups of key heads ``span_walk`` folds, as the lanes of a cached
+    row: a key head and its value; under differential attention a PAIR's two
+    key heads (side by side in the row) and the pair's two values, which
+    both of them read."""
+    d = dims
+    n = 2 if d.diff else 1
+    half = d.kv_heads * d.head_dim
+    return tuple((g * n * d.head_dim, n * d.head_dim,
+                  half + g * n * d.v_dim, n * d.v_dim)
+                 for g in range(d.kv_heads // n))
+
+
+def _walk(dims: GQADims, q, pos, cache, slots, n_blocks, block: int):
+    """:func:`_attend` under the causal mask with a round count A ROW
+    (``n_blocks`` [B], or one for all), in the ``span_walk`` kernel: the
+    folded queries a group of key heads at a time, ``[B, groups, rows,
+    lanes of the group's keys]``. A differential pair is one group: its two
+    heads' queries lie block-diagonally (head ``2p``'s rows over the pair's
+    first ``head_dim`` lanes, head ``2p + 1``'s over the last, zeros
+    elsewhere), so one product scores both against their own keys and one
+    gives both the pair's two values. ``[B, S, heads, d]`` float32."""
+    d = dims
+    if d.block_len != 1:
+        raise ValueError("rows that walk their own reach are causal rows: "
+                         "a block-causal stack's block forward is "
+                         "block_step")
+    B, S = pos.shape
+    groups = walk_groups(d)
+    M, G = S * d.group, len(groups)
+    n = d.kv_heads // G                             # key heads a group
+    q = _folded(d, q, cache.dtype).reshape(B, M, G, n, d.head_dim)
+    q = q.transpose(0, 2, 3, 1, 4)                  # [B, G, n, M, d]
+    if n > 1:
+        q = q[:, :, :, :, None] * jnp.eye(n, dtype=q.dtype)[:, None, :, None]
+    q = q.reshape(B, G, n * M, n * d.head_dim)
+    q_pos = jnp.tile(jnp.repeat(pos, d.group, axis=1), (1, n))[..., None]
+    o = span_walk.span_walk(
+        q, q_pos.astype(jnp.int32), cache, slots,
+        jnp.broadcast_to(n_blocks, (B,)), groups=groups, block=block,
+        scale=d.head_dim ** -0.5 if d.scale is None else d.scale,
+        interpret=pallas.interpret_mode())
+    o = o.reshape(B, G, n, M, d.walk_v_dim).transpose(0, 3, 1, 2, 4)
+    return _unfolded(d, o.reshape(B, M, d.kv_heads, d.walk_v_dim), B, S)
 
 
 # -- a window layer's ring -----------------------------------------------------

@@ -32,7 +32,7 @@ top-k over the item table in ``[D, Ip]`` tiles of thousands of items,
 merging only a tile that can change the top-k — the exact retrieval
 index's hot path, selected per-index via ``index_kernel``).
 
-Three kernels have no flag. ``expert_stream`` (the expert layer of a forward
+Four kernels have no flag. ``expert_stream`` (the expert layer of a forward
 whose tokens fit one tile: every row through every touched expert) and
 ``expert_groups`` (of a larger forward, a prefill chunk: the rows sorted by
 expert, each expert's group gathered, multiplied and added back inside its
@@ -46,9 +46,16 @@ step, scores and probabilities in VMEM only) is the one form
 ``ops/mla.prefill_chunk`` has, with an index and without; the loop of XLA's
 fusions it replaced (``ops/mla.attend_blocks``: ``ops/attention
 .attend_over_blocks`` over ``expand``) is its reference in the tests and the
-path of every other walk (the absorbed extension, grouped-query attention,
-the window layers). A path that has one form runs it compiled on a TPU and
-under the interpreter everywhere else, tier-1 included.
+path of every other walk (the absorbed extension, grouped-query attention's
+chunks and block-diffusion forwards, the window layers). ``span_walk``
+(``span_walk.py``: a causal stack's EXTENSION over a span of keys and values,
+each real row of the batch over the blocks of its own reach, read where the
+cache holds them, a padding row over none; ``chunk_attend.fold`` a group of
+key heads) is the one form ``ops/gqa.extend`` and ``ops/gqa.cross_rows`` have;
+``ops/gqa._attend`` (``attend_over_blocks`` over slices of the span, every
+row as far as the batch's longest) is its reference in the tests. A path
+that has one form runs it compiled on a TPU and under the interpreter
+everywhere else, tier-1 included.
 
 Each flag (``flash_ce_kernel``, ``embed_update_kernel``,
 ``index_kernel``) takes ``on`` / ``off`` / ``auto``;

@@ -842,8 +842,9 @@ class StackPrograms:
     #: layers the blocks of ``window`` cached positions those layers WALKED
     #: (per layer, summed: a chunk's rounds, an extension batch's once a real
     #: session), the blocks a walk from 0 to the same reach would have taken,
-    #: and the blocks of ``chunk`` positions its full layers walked (counted
-    #: alike). A stack without expert layers, whose router picks no groups,
+    #: and the blocks of ``chunk`` positions its full layers walked (a
+    #: chunk's as far as it reaches, an extension batch's real rows each as
+    #: far as its own reach). A stack without expert layers, whose router picks no groups,
     #: without an index or without window layers leaves those columns at 0:
     #: the keys of a call's ``counters`` decide, when the program is traced
     TOTAL_FIELDS = ("runs", "tokens", "held_picks", "experts_touched",
@@ -855,7 +856,8 @@ class StackPrograms:
                     # it, and the blocks of ``chunk`` positions of the ONE
                     # span its readers (the block that writes it and every
                     # cross mixer) walked for an extension batch's rows,
-                    # beside what each row's own reach would have taken
+                    # beside what each row's own reach takes (the same,
+                    # since the rows walk in ``span_walk``)
                     "cross_rows", "span_blocks_walked", "span_blocks_own")
 
     def __init__(self, spec: StackSpec, params: Dict, shape: ServeShape):
@@ -900,6 +902,10 @@ class StackPrograms:
         #: whether a chunk's attention runs in the ``chunk_attend`` kernel
         #: (``ops/mla.prefill_chunk``'s walk: every latent mixer's, no other)
         self.attend_kernel = "mla" in self.kinds
+        #: whether an extension's rows walk a span of keys and values in the
+        #: ``span_walk`` kernel, each as far as its own reach
+        #: (``ops/gqa.extend`` and ``cross_rows``: a causal stack's)
+        self.walk_kernel = "gqa" in self.kinds and gen is None
 
         held = {
             "mla": lambda: mla_ops.init_cache(spec.mla, *positions, dtype),
@@ -1075,8 +1081,8 @@ class StackPrograms:
         n_blocks = (offset + n_valid + self.shape.chunk - 1) // self.shape.chunk
         row = self._cross(params, cache, x[last][None], memory[last][None],
                           jnp.reshape(offset + last, (1,)),
-                          jnp.reshape(slot, (1,)), n_blocks,
-                          jnp.ones((1,), bool))
+                          jnp.reshape(slot, (1,)),
+                          jnp.reshape(n_blocks, (1,)), jnp.ones((1,), bool))
         counters["cross_rows"] = jnp.int32(1)
         return cache, self._final(params, row), counters
 
@@ -1131,7 +1137,8 @@ class StackPrograms:
         """The cross-decoder over ONE row a session: ``x`` [B, dim] (the
         blocks before it at positions ``pos`` [B] of the slots ``slots``),
         ``memory`` [B, d_mem] the same positions' memory; each cross mixer
-        walks the one span as far as ``n_blocks`` (traced). ``[B, dim]``."""
+        walks the one span, row ``b`` as far as ``n_blocks[b]`` (traced).
+        ``[B, dim]``."""
         spec, chunk = self.spec, self.shape.chunk
 
         def mix_with(m, p, h, scope):
@@ -1150,8 +1157,8 @@ class StackPrograms:
         window layers; every one of the run's ``rows`` real sessions walks
         them), what walks from 0 to each session's last position
         ``last`` [rows, or more with padding at -1] would have taken, and
-        the blocks of ``chunk`` positions the full layers walked (``n_blocks``
-        each, every real session as far as the longest)."""
+        the blocks of ``chunk`` positions the full layers walked
+        (``n_blocks``: what a full layer's rows walked, summed over them)."""
         window = self.spec.gqa_window.window
         n_window, n_full = (self.kinds.count(k)
                             for k in ("gqa_window", "gqa"))
@@ -1159,8 +1166,7 @@ class StackPrograms:
                 "window_blocks_from0": (
                     n_window * ((last + window) // window).sum()).astype(
                         jnp.int32),
-                "full_blocks": jnp.asarray(n_full * n_blocks * rows,
-                                           jnp.int32)}
+                "full_blocks": jnp.asarray(n_full * n_blocks, jnp.int32)}
 
     def _index_counts(self, blocks, valid, pos):
         """A run's two counts under a learned index: blocks of index keys
@@ -1172,11 +1178,18 @@ class StackPrograms:
                         jnp.int32)}
 
     def _extend_fn(self, params, cache, ids, n_new, slots, pos0, n_blocks):
+        """``n_blocks``: the batch's longest reach, in blocks (what a latent
+        mixer's rows all walk). A span of keys and values is walked by each
+        real row as far as ITS OWN reach (``own`` [B]: the blocks that hold
+        the row's ``extend_len`` positions, 0 for a padding row), by the
+        block that writes it and by every cross mixer."""
         cache = list(cache)
         spec, chunk = self.spec, self.shape.chunk
         B, S = ids.shape
         pos = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
         valid = (jnp.arange(S)[None] < n_new[:, None]).reshape(-1)
+        real = n_new > 0
+        own = jnp.where(real, (pos0 + S + chunk - 1) // chunk, 0)
         scanned, walked, memory = [], [], [None]
 
         def mix_with(m, p, h, scope):
@@ -1201,8 +1214,8 @@ class StackPrograms:
                 walked.append(rounds)
             else:
                 out, cache[m] = gqa_ops.extend(
-                    p, spec.gqa, h, pos, cache[m], slots, n_blocks, chunk,
-                    scope, m)
+                    p, spec.gqa, h, pos, cache[m], slots, own, chunk, scope,
+                    m)
             return out.reshape(B * S, -1)
 
         x, counters = self._run(
@@ -1210,27 +1223,24 @@ class StackPrograms:
             mix_with, self.cross_from and (0, self.cross_from))
         if self.indexed:    # every real session's rows scan them
             counters.update(self._index_counts(
-                sum(scanned) * (n_new > 0).sum(), valid, pos.reshape(-1)))
+                sum(scanned) * real.sum(), valid, pos.reshape(-1)))
         if self.windowed:   # a padding row's last position is -1: no block
             counters.update(self._walk_counts(
-                sum(walked), jnp.where(n_new > 0, pos0 + n_new - 1, -1),
-                n_blocks, (n_new > 0).sum()))
+                sum(walked), jnp.where(real, pos0 + n_new - 1, -1),
+                own.sum(), real.sum()))
         at = jnp.maximum(n_new - 1, 0)
         last = x.reshape(B, S, -1)[jnp.arange(B), at]
         if self.cross_from is not None:
-            real = n_new > 0
             last = self._cross(params, cache, last,
                                memory[0][jnp.arange(B), at], pos0 + at, slots,
-                               n_blocks, real)
+                               own, real)
             # the span's readers: the block that writes it and the cross
-            # mixers; every real row walks as far as the batch's longest
-            readers = 1 + self.kinds.count("gqa_cross")
-            own = jnp.where(real, (pos0 + S + chunk - 1) // chunk, 0).sum()
-            counters.update(
-                cross_rows=real.sum().astype(jnp.int32),
-                span_blocks_walked=(readers * n_blocks * real.sum()).astype(
-                    jnp.int32),
-                span_blocks_own=(readers * own).astype(jnp.int32))
+            # mixers; each walks every real row's own blocks and no other
+            walked_own = ((1 + self.kinds.count("gqa_cross"))
+                          * own.sum()).astype(jnp.int32)
+            counters.update(cross_rows=real.sum().astype(jnp.int32),
+                            span_blocks_walked=walked_own,
+                            span_blocks_own=walked_own)
         return cache, self._final(params, last), counters
 
     def _block_fn(self, params, cache, ids, slots, pos0, denoise, n_unmask,

@@ -1,10 +1,13 @@
 """The attention paths that ``ops/pallas/chunk_attend.py`` does NOT serve, each
 traced at a small size: ``ops/mla.extend`` (absorbed form, with and without
-an index), ``ops/gqa._attend`` (a chunk's and a block step's walk over keys
-and values) and the window walk (a chunk's and an extension's over a ring).
-``jaxprs()`` gives each one's jaxpr as text; ``tests/test_pallas_kernels.py``
-holds them to the text they had at the commit before the kernel came (PR 50's
-parent, ``1340c6b``), by its SHA-256: their compiled programs do not change.
+an index), ``ops/gqa._attend`` (a chunk's and a block-diffusion forward's walk
+over keys and values) and the window walk (a chunk's and an extension's over a
+ring). ``jaxprs()`` gives each one's jaxpr as text;
+``tests/test_pallas_kernels.py`` holds them to the text they had at the commit
+before the kernel came (PR 50's parent, ``1340c6b``; the block-diffusion
+forward's at PR 56's, ``1c1a441``, when ``ops/pallas/span_walk.py`` took a
+CAUSAL stack's extension out of that walk), by its SHA-256: their compiled
+programs do not change.
 
 To print a tree's own: ``PYTHONPATH=<checkout> python3 tests/other_walks.py``.
 """
@@ -40,10 +43,15 @@ def jaxprs() -> dict:
         lambda p, x, at, cache: gqa.prefill_chunk(
             p, full, x, at, cache, 1, 16))(
                 p, x[0].repeat(4, axis=0), jnp.int32(24), cache)
+    # the block-diffusion forward: rows of a whole block that see each other
+    blocks = gqa.GQADims(dim=64, heads=8, kv_heads=2, head_dim=16,
+                         block_len=4, rope_theta=1e6, eps=1e-6)
     out["gqa.block_step"] = jax.make_jaxpr(
         lambda p, x, pos, cache, slots, nb: gqa.block_step(
-            p, full, x, pos, cache, slots, nb, 16))(
-                p, x, pos, cache, slots, jnp.int32(3))
+            p, blocks, x, pos, cache, slots, nb, 16))(
+                gqa.init(jax.random.PRNGKey(0), blocks), x, pos,
+                jnp.zeros((3, 64, blocks.cache_width), jnp.float32), slots,
+                jnp.int32(3))
     win = gqa.GQADims(kv_heads=4, rope_theta=1e4, window=8, sink=True, **same)
     p = gqa.init(jax.random.PRNGKey(0), win)
     ring = jnp.zeros((3, gqa.ring_len(8, 16), win.cache_width), jnp.float32)

@@ -653,12 +653,15 @@ def test_a_keys_rope_part_lies_at_a_whole_row_of_lanes(d_nope, d_rope,
     assert sorted(laid[laid >= 0].tolist()) == list(range(d_nope))
 
 
-#: SHA-256 of each path's jaxpr text at PR 50's parent (tests/other_walks.py)
+#: SHA-256 of each path's jaxpr text at PR 50's parent (tests/other_walks.py);
+#: ``gqa.block_step`` since PR 56 the BLOCK-DIFFUSION forward's (a block length
+#: of 4), at PR 56's parent: a causal stack's extension left that walk then
+#: (``gqa.extend`` below, against ``attend_full``)
 PARENTS_WALKS = {
     "mla.extend": "0b359426918a157f15a9c65f75c8809f3599bbfcdb30927e5344051d742d859c",
     "mla.extend.index": "45fca96ff67e1f26c11f8ad643b93c76c9da03f5063cae1d3ceec80afeda2045",
     "gqa.prefill_chunk": "ab1236eca7a46f8086370d687b2b69bd3dcd9976d71902098f2b92e4502ab021",
-    "gqa.block_step": "743fa703403ea772e1fe76af250d899a6500345a7b8eb350657d09c54082f634",
+    "gqa.block_step": "ccafb0c553bae6edd088fbcd1ea39e16239d93b68750dffea9dec57028b79b65",
     "gqa.window_prefill_chunk": "ae86742bcf15b1c7dbc14ca7383e34a7b30c6ae5a5b7d3d9f59cdd0713e1f0e6",
     "gqa.window_extend": "c451dba88c8a22edb921048e63f47c577a3f33ae804c246115d618e3d04b8348",
 }
@@ -674,8 +677,195 @@ def walks_digests():
 @pytest.mark.parametrize("path", sorted(PARENTS_WALKS))
 def test_the_walks_the_kernel_does_not_serve_trace_to_the_parents_jaxpr(
         walks_digests, path):
-    """The absorbed extension, grouped-query attention's chunks and block
-    steps and the window walk still fold their blocks with ``_accum_block``:
-    the same primitives in the same order as at the commit before the
-    kernel, letter for letter, and not one ``pallas_call`` among them."""
+    """The absorbed extension, grouped-query attention's chunks and
+    block-diffusion forwards and the window walk still fold their blocks with
+    ``_accum_block``: the same primitives in the same order as at the commit
+    before the kernel that might have taken them, letter for letter, and not
+    one ``pallas_call`` among them."""
     assert walks_digests[path] == PARENTS_WALKS[path]
+
+
+def test_no_walk_the_kernels_leave_alone_holds_a_pallas_call():
+    from tests import other_walks
+
+    assert not [name for name, text in other_walks.jaxprs().items()
+                if "pallas_call" in text]
+
+
+# -- span_walk: an extension's rows, each over its own blocks (PR 56) ----------
+
+from predictionio_tpu.ops import gqa as gqa_ops     # noqa: E402
+
+WALK_BLOCK, WALK_SLOT = 8, 64
+#: the three cells' head shapes at a model width of 64 (differential pairs of
+#: 64-wide heads two to a key head; 128-wide heads four to one; keys of 192
+#: beside values of 128 sixteen to one), the pairs at another width, plain
+#: heads of 64, and the tests' own small odd widths
+WALK_DIMS = {
+    "phi": dict(heads=8, kv_heads=4, head_dim=64, diff=True, bias=True,
+                rope=False, qk_norm=False),
+    "granite": dict(heads=8, kv_heads=2, head_dim=128, rope=False,
+                    qk_norm=False, scale=0.0078125),
+    "mimo": dict(heads=32, kv_heads=2, head_dim=192, v_head_dim=128,
+                 rope_dims=64, qk_norm=False, value_scale=0.707),
+    "pairs_of_128": dict(heads=16, kv_heads=4, head_dim=128, v_head_dim=64,
+                         diff=True, rope=False),
+    "plain_64": dict(heads=4, kv_heads=2, head_dim=64, v_head_dim=128),
+    "small": dict(heads=8, kv_heads=2, head_dim=24, v_head_dim=16,
+                  rope_dims=8, qk_norm=False),
+}
+
+
+def walk_case(name, dtype, seed=0, **more):
+    """``(dims, p, a span of four slots of noise)``; slot 3 is a batch's
+    scratch slot and holds huge values."""
+    d = gqa_ops.GQADims(dim=64, block_len=1, **dict(WALK_DIMS[name], **more))
+    key = jax.random.PRNGKey(seed)
+    span = jax.random.normal(jax.random.fold_in(key, 1),
+                             (4, WALK_SLOT, d.cache_width)).astype(dtype)
+    return d, gqa_ops.init(key, d, dtype), span.at[3].set(1e30)
+
+
+def own_blocks(pos0, S, real=True):
+    """A row's own rounds, by ``StackPrograms._extend_fn``'s rule."""
+    return -(-(pos0 + S) // WALK_BLOCK) if real else 0
+
+
+@pytest.mark.parametrize("S", [1, 4], ids=["cross_row", "extend_len"])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-3)])
+@pytest.mark.parametrize("name", sorted(WALK_DIMS))
+def test_span_walk_is_the_accum_block_loop_over_each_rows_own_blocks(
+        name, dtype, tol, S):
+    """The kernel against ``attend_over_blocks`` (``ops/gqa._attend``, every
+    row as far as the batch's longest): rows of unequal reach in one batch,
+    one whose reach ends ON a block's edge, one that starts its slot, and a
+    padding row on the scratch slot (no rounds: zeros, whatever the slot
+    holds). Each real row walks ITS OWN blocks: whole blocks past them hold
+    NaN here, which the loop over the longest row's would multiply."""
+    d, p, span = walk_case(name, dtype)
+    starts = [37, 2 * WALK_BLOCK - S, 0, 5]
+    slots = jnp.array([1, 2, 0, 3])
+    counts = [own_blocks(at, S) for at in starts[:3]] + [0]
+    pos = jnp.array(starts)[:, None] + jnp.arange(S)[None]
+    q = jax.random.normal(jax.random.PRNGKey(7), (4, S, d.heads, d.head_dim))
+    want = jax.jit(lambda q, span: gqa_ops._attend(
+        d, q, pos, span, slots, jnp.int32(max(counts)), WALK_BLOCK))(q, span)
+    for b, n in enumerate(counts[:3]):      # never read: nothing to poison
+        span = span.at[int(slots[b]), n * WALK_BLOCK:].set(jnp.nan)
+    got = jax.jit(lambda q, span, n: gqa_ops._walk(
+        d, q, pos, span, slots, n, WALK_BLOCK))(q, span, jnp.array(counts))
+    assert got.shape == want.shape and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got[:3]), np.asarray(want[:3]),
+                               rtol=tol, atol=tol)
+    assert not np.asarray(got[3]).any()
+
+
+@pytest.mark.parametrize("name", ["phi", "mimo"])
+def test_span_walk_is_one_program_for_every_batch(name):
+    """Slots, positions and round counts are traced: ONE compiled program
+    serves one real row alone beside two padding rows, then three rows, then
+    the same rows with one count for all (the batch's longest: the blocks
+    past a row's reach are masked, the numbers the same)."""
+    d, p, span = walk_case(name, "float32")
+    S = 4
+    q = jax.random.normal(jax.random.PRNGKey(9), (3, S, d.heads, d.head_dim))
+
+    @jax.jit
+    def walk(q, pos, span, slots, counts):
+        return gqa_ops._walk(d, q, pos, span, slots, counts, WALK_BLOCK)
+
+    def loop(pos, slots, n):
+        return gqa_ops._attend(d, q, pos, span, slots, jnp.int32(n),
+                               WALK_BLOCK)
+
+    def at(starts):
+        return jnp.array(starts)[:, None] + jnp.arange(S)[None]
+
+    alone = walk(q, at([43, 0, 0]), span, jnp.array([2, 3, 3]),
+                 jnp.array([own_blocks(43, S), 0, 0]))
+    np.testing.assert_allclose(
+        np.asarray(alone[0]), np.asarray(loop(at([43, 0, 0]), jnp.array(
+            [2, 3, 3]), 6)[0]), rtol=1e-5, atol=1e-5)
+    assert not np.asarray(alone[1:]).any()
+    starts, slots = [43, 9, 20], jnp.array([2, 0, 1])
+    want = loop(at(starts), slots, 6)
+    for counts in ([own_blocks(a, S) for a in starts], [6, 6, 6]):
+        got = walk(q, at(starts), span, slots, jnp.array(counts))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    assert walk._cache_size() == 1
+
+
+def test_a_span_that_is_not_whole_rows_of_lanes_is_refused_on_a_tpu():
+    """No ``GQADims`` falls back to the loop: every causal stack's extension
+    walks in the kernel, under the interpreter at any width. Compiled, the
+    copy of a block takes whole 128-lane rows of the span (Mosaic's slice of
+    a tiled array): the three configurations' rows are 2,560, 1,280 and 2,048
+    wide; a test-sized row of 80 names the reason instead of the compiler."""
+    from predictionio_tpu.ops.pallas import span_walk as span_walk_mod
+
+    d, p, span = walk_case("small", "float32")
+    assert d.cache_width == 80
+    q = jnp.zeros((1, len(gqa_ops.walk_groups(d)), 4, d.head_dim))
+    with pytest.raises(ValueError, match="128"):
+        span_walk_mod.span_walk(
+            q, jnp.zeros((1, 4, 1), jnp.int32), span, jnp.array([0]),
+            jnp.array([1]), groups=gqa_ops.walk_groups(d), block=WALK_BLOCK,
+            scale=1.0, interpret=False)
+
+
+@pytest.mark.parametrize("name", ["phi", "mimo", "small"])
+def test_gqa_extend_is_the_plain_form(name):
+    """What ``PARENTS_WALKS["gqa.block_step"]`` pinned until PR 56, held to
+    what it computes: a history prefilled chunk by chunk, then EXTENDED in
+    batches (two sessions of unequal reach and a padding row; 4, 3 and 1 new
+    positions a row) against ``attend_full``, every position against every
+    earlier one with its scores materialised; under the differential dims a
+    cross mixer's rows over the same span as well (``cross_rows``)."""
+    d, p, _ = walk_case(name, "float32")
+    C, n = 16, 45
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, n, 64), jnp.float32)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    want = [gqa_ops.attend_full(p, d, x[i], pos) for i in (0, 1)]
+    cache = jnp.zeros((4, WALK_SLOT, d.cache_width), jnp.float32)
+    held = (32, 16)     # whole chunks: session 0 in slot 1, session 1 in 2
+    for i, slot in ((0, 1), (1, 2)):
+        for at in range(0, held[i], C):
+            out, cache = gqa_ops.prefill_chunk(
+                p, d, x[i, at:at + C], jnp.int32(at), cache, slot, WALK_BLOCK)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(
+                want[i][at:at + C]), rtol=2e-4, atol=2e-4)
+    step = jax.jit(lambda rows, pos, cache, slots, counts: gqa_ops.extend(
+        p, d, rows, pos, cache, slots, counts, WALK_BLOCK))
+    at, S = list(held), 4
+    for new in ((4, 3), (1, 4), (3, 2)):
+        rows = jnp.zeros((3, S, 64), jnp.float32)
+        for i in (0, 1):
+            rows = rows.at[i, :new[i]].set(x[i, at[i]:at[i] + new[i]])
+        out, cache = step(
+            rows, jnp.array(at + [0])[:, None] + jnp.arange(S)[None], cache,
+            jnp.array([1, 2, 3]),
+            jnp.array([own_blocks(a, S) for a in at] + [0]))
+        for i in (0, 1):
+            np.testing.assert_allclose(
+                np.asarray(out[i, :new[i]]),
+                np.asarray(want[i][at[i]:at[i] + new[i]]),
+                rtol=2e-4, atol=2e-4)
+            at[i] += new[i]
+    assert step._cache_size() == 1
+    if not d.diff:
+        return
+    cross = gqa_ops.GQADims(dim=64, block_len=1, cross=True,
+                            **WALK_DIMS[name])
+    pc = gqa_ops.init(jax.random.PRNGKey(5), cross)
+    last = jnp.array([a - 1 for a in at])
+    got = gqa_ops.cross_rows(
+        pc, cross, x[jnp.arange(2), last], last, cache, jnp.array([1, 2]),
+        jnp.array([own_blocks(int(a), 1) for a in last]), WALK_BLOCK,
+        depth=3)
+    for i in (0, 1):
+        whole = gqa_ops.attend_full(
+            pc, cross, x[i, :at[i]], pos[:at[i]], 3,
+            kv=gqa_ops.project(p, d, x[i, :at[i]], pos[:at[i]])[1:])
+        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(whole[-1]),
+                                   rtol=2e-4, atol=2e-4)

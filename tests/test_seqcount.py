@@ -156,6 +156,22 @@ def test_the_chunks_whose_attention_ran_in_the_kernel_are_counted(stack):
 
 
 @pytest.mark.parametrize("stack", STACKS)
+def test_the_batches_whose_rows_walked_in_the_kernel_are_counted(stack):
+    """``extend_walk_kernel_batches``: once an extension batch of a causal
+    stack with a ``gqa`` layer (its rows walk the span in ``span_walk``, each
+    as far as its own reach), none of a stack of latent mixers (the absorbed
+    walk is XLA's own) or of a block-diffusion stack (no extension at all)."""
+    model = small_model(stack)
+    assert model.programs().walk_kernel is (stack == "hybrid")
+    assert model.stats()["extend_walk_kernel_batches"] == 0
+    mixed_run(model)
+    after = model.stats()
+    assert after["extend_walk_kernel_batches"] == (
+        after["extend_runs"] if stack == "hybrid" else 0)
+    assert (after["extend_runs"] > 0) == (stack != "generation")
+
+
+@pytest.mark.parametrize("stack", STACKS)
 def test_the_drain_comes_before_an_int32_could_wrap(stack):
     model = small_model(stack)
     programs, moe = model.programs(), model.spec.moe

@@ -428,7 +428,8 @@ def test_a_batch_of_extensions_equals_each_alone_and_counts_each(served, ref):
     # [31, 39] and [3, 12]: 2 rounds each; both rows walk the longer one
     assert ext[f("window_blocks")] == 5 * 2 * 2
     assert ext[f("window_blocks_from0")] == 5 * (5 + 2)
-    assert ext[f("full_blocks")] == 2 * 3 * 2
+    # a full layer's rows walk their own reach: 3 blocks of 16 and 1
+    assert ext[f("full_blocks")] == 2 * (3 + 1)
 
 
 def test_the_sixteen_shares_add_up_to_the_uncut_layer(ref):
@@ -617,7 +618,11 @@ def test_the_accepted_stacks_cached_paths_trace_to_the_parents_jaxpr(fields):
         (gqa_ops.block_step, new), (parent_gqa.block_step, old)))
     assert all((np.asarray(a) == np.asarray(b)).all()
                for a, b in zip(got, want))
-    assert gqa_ops.extend is gqa_ops.block_step
+    if new.block_len == 1:
+        # an extension is a program of its own (each row walks its own reach
+        # in ``span_walk``), with the block step's numbers
+        for a, b in zip(jax.jit(step(gqa_ops.extend, new))(*args), got):
+            close(a, b, 1e-6)
 
 
 def accepted_stack(name):
@@ -679,18 +684,41 @@ def test_the_accepted_stacks_serve_programs_trace_to_the_parents_jaxpr(
         monkeypatch.undo()
         return made
 
+    def answers():
+        """Two sessions prefilled, then extended in one batch."""
+        programs = StackPrograms(spec, params, shape)
+        programs.prefill(np.arange(1, 9), 0, 0)
+        programs.prefill(np.arange(9, 15), 0, 8)
+        programs.prefill(np.arange(20, 23), 1, 0)
+        h, _ = programs.extend([(np.arange(30, 33), 0, 14),
+                                (np.arange(40, 44), 1, 3)])
+        return programs, np.asarray(h)
+
     tree = traced()
     assert set(tree) == ({"_prefill_fn", "_block_fn"} if name == "sdar"
                          else {"_prefill_fn", "_extend_fn"})
+    mine = None if name == "sdar" else answers()[1]
     # (PR 53: the tree's take the block's place in the stack and its scope
     # behind the parent's arguments: 7 and 8 of them)
-    for fn, n in (("prefill_chunk", 7), ("block_step", 8), ("extend", 8)):
+    for fn, n in (("prefill_chunk", 7), ("block_step", 8)):
         monkeypatch.setattr(gqa_ops, fn, lambda *a, _f=getattr(
             parent_gqa, fn), _n=n: _f(*a[:_n]))
+    # the parent's extension is its block step: one count for the batch,
+    # every row as far as the longest
+    monkeypatch.setattr(gqa_ops, "extend", lambda *a: parent_gqa.extend(
+        *a[:6], jnp.max(a[6]), a[7]))
     parents = traced()
+    # an extension's walk is the ``span_walk`` kernel (PR 56); every other
+    # program is the parent's, letter for letter
+    walk = tree.pop("_extend_fn", ""), parents.pop("_extend_fn", "")
     assert tree == parents
     assert not any("gqa_window" in text for text in tree.values())
+    if name == "granite":
+        assert "span_walk" in walk[0] and "span_walk" not in walk[1]
+        # and an extension batch's answers are the parent walk's
+        close(mine, answers()[1], 1e-5)
     # no column of the walks moves in a stack without window layers
+    monkeypatch.undo()
     programs = StackPrograms(spec, params, shape)
     assert not programs.windowed
     programs.prefill(np.arange(1, 9), 0, 0)

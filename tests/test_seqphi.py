@@ -509,6 +509,29 @@ def test_two_sessions_extend_in_one_batch_each_from_its_own_slot(stack, ref):
     close(got[1], ref.forward(weights, b, DM))
 
 
+def test_an_extension_batch_in_the_kernel_answers_as_the_parents_walk(
+        stack, monkeypatch):
+    """The span's two readers (layer 5's ``extend``, layer 7's
+    ``cross_rows``) with each row over its OWN blocks in ``span_walk``,
+    against the walk until PR 56: every row as far as the batch's longest
+    through ``attend_over_blocks``; and the last chunk's one row through the
+    cross-decoder alike (``prefill_last``)."""
+    spec, params, programs = stack
+
+    def answers(programs):
+        a, b = history(27, 40), history(28, 21)
+        first = [prefill(programs, a[:37], 0), prefill(programs, b[:19], 2)]
+        hs, _ = programs.extend([(a[37:40], 0, 37), (b[19:21], 2, 19)])
+        return [np.asarray(h) for h in first] + [np.asarray(hs)]
+
+    mine = answers(programs)
+    monkeypatch.setattr(
+        gqa_ops, "_walk", lambda d, q, pos, cache, slots, n, block:
+        gqa_ops._attend(d, q, pos, cache, slots, jnp.max(n), block))
+    for got, want in zip(mine, answers(StackPrograms(spec, params, SHAPE))):
+        close(got, want, 1e-5)
+
+
 def test_the_programs_count_the_rows_they_carry_and_the_blocks_they_walk(
         stack):
     _, _, programs = stack
@@ -523,9 +546,9 @@ def test_the_programs_count_the_rows_they_carry_and_the_blocks_they_walk(
     assert got["prefill"]["runs"] == 5 and got["prefill"]["tokens"] == 55
     assert got["prefill"]["cross_rows"] == 2
     assert got["extend"]["cross_rows"] == 2
-    # two readers (layer 5 and the one cross layer); both rows walk the 3
-    # blocks of the longer; their own reaches take 3 and 2
-    assert got["extend"]["span_blocks_walked"] == 2 * 3 * 2
+    # two readers (layer 5 and the one cross layer); each row walks the
+    # blocks of its own reach, 3 and 2, and not the 3 of the longer
+    assert got["extend"]["span_blocks_walked"] == 2 * (3 + 2)
     assert got["extend"]["span_blocks_own"] == 2 * (3 + 2)
     assert got["prefill"]["span_blocks_walked"] == 0
 

@@ -128,6 +128,26 @@ def _chunk_attend(C, H, d_qk, d_v, masked, slots=2, positions=33_792):
     return fn, shapes, 1
 
 
+def _span_walk(B, S, kv_heads, group, d_k, d_v, diff, slots, positions):
+    """An extension batch's walk at a configuration's widths: ``B`` rows of
+    ``S`` positions, ``group`` query heads a key/value head, keys ``d_k``
+    beside values ``d_v``, pairs of heads under ``diff``."""
+    from predictionio_tpu.ops import gqa
+    from predictionio_tpu.ops.pallas import span_walk
+
+    dims = gqa.GQADims(dim=64, heads=kv_heads * group, kv_heads=kv_heads,
+                       head_dim=d_k, v_head_dim=d_v, block_len=1, diff=diff)
+    groups = gqa.walk_groups(dims)
+    n = kv_heads // len(groups)
+    fn = functools.partial(span_walk.span_walk, groups=groups, block=512,
+                           scale=d_k ** -0.5)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    M = n * S * group
+    return fn, [((B, len(groups), M, n * d_k), bf16), ((B, M, 1), i32),
+                ((slots, positions, dims.cache_width), bf16), ((B,), i32),
+                ((B,), i32)], 1
+
+
 def _selective_scan(T, d_inner, d_state):
     """One Mamba-1 layer's scan over a chunk: ``x`` and ``delta`` rows,
     ``A``, ``B``, ``C`` and the incoming state, float32."""
@@ -189,6 +209,15 @@ def _selective_scan(T, d_inner, d_state):
     # a chunk's selective scan at Phi-4-mini-flash's widths: five groups of
     # 1,024 channels, sixteen states a channel in registers
     (_selective_scan, (512, 5120, 16)),
+    # an extension batch's walk over a span of keys and values, each row its
+    # own blocks: Phi-4-mini-flash's (ten differential pairs of 64-wide
+    # heads, an extension's 4 positions and a cross mixer's one), MiMo-V2.5's
+    # full layers (keys of 192 beside values of 128, groups of 16: a key
+    # head's lanes start inside a 128-lane row) and granite's one
+    (_span_walk, (8, 4, 20, 2, 64, 64, True, 17, 33_792)),
+    (_span_walk, (8, 1, 20, 2, 64, 64, True, 17, 33_792)),
+    (_span_walk, (8, 4, 4, 16, 192, 128, False, 25, 25_600)),
+    (_span_walk, (16, 4, 8, 4, 128, 128, False, 33, 8_704)),
 ], ids=["flash_ce-8192x128", "flash_ce-4096x64", "flash_ce-65536x256",
         "flash_ce-131072x256", "topk_dot-26744x64-B1",
         "topk_dot-26744x64-B32", "topk_dot-1Mx128-B1",
@@ -201,7 +230,8 @@ def _selective_scan(T, d_inner, d_state):
         "expert_groups-512x7168-12x2048", "topk_dot-20480x7168-B1",
         "topk_dot-20480x7168-B4", "chunk_attend-512x64x256x256-keep",
         "chunk_attend-512x64x256x256", "chunk_attend-512x64x192x128",
-        "selective_scan-512x5120x16"])
+        "selective_scan-512x5120x16", "span_walk-phi-extend",
+        "span_walk-phi-cross", "span_walk-mimo", "span_walk-granite"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build, args):
     fn, shapes, n_kernels = build(*args)
     text = _compiled_text(fn, shapes, one_chip)
@@ -230,8 +260,9 @@ def _kernel_instructions(text):
     (_expert_groups, (512, 2048, 768, 128, 8), ["expert_groups"]),
     (_chunk_attend, (512, 64, 256, 256, True), ["chunk_attend"]),
     (_selective_scan, (512, 5120, 16), ["selective_scan"]),
+    (_span_walk, (8, 4, 20, 2, 64, 64, True, 17, 33_792), ["span_walk"]),
 ], ids=["topk_dot", "flash_ce", "flash_ce_two_pass", "expert_stream",
-        "expert_groups", "chunk_attend", "selective_scan"])
+        "expert_groups", "chunk_attend", "selective_scan", "span_walk"])
 def test_a_kernels_instruction_carries_its_name(one_chip, no_compile_cache,
                                                 build, args, names):
     """A device trace's events are named by the instruction's text: the
@@ -558,6 +589,65 @@ def test_the_kv_cache_is_left_as_it_is_handed_over(one_chip,
     layouts = set(re.findall(r"bf16\[33,4608,1024\](\{[^}]*\})", text))
     assert layouts == {"{2,1,0:T(8,128)(2,1)}"}, layouts
     assert not re.search(r"= bf16\[33,4608,1024\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_an_extensions_rows_walk_the_span_where_it_lies(
+        one_chip, no_compile_cache, monkeypatch):
+    """The span's two kinds of reader at Phi-4-mini-flash's widths (40 query
+    heads on 20 key/value heads of 64, differential pairs, biases; 17 slots
+    of 33,792 positions of 2,560 values): layer 17's ``extend`` (a batch of
+    8 x 4: the rows written, then walked) and a cross mixer's ``cross_rows``
+    (8 x 1), in one program as the extension program holds them. ONE
+    ``span_walk`` instruction a reader, under the mixer's ``.attend`` scope;
+    no loop left there, no block of a row sliced out of the span (the
+    parent's eight ``dynamic-slice`` fusions of ``bf16[1,1,512,2560]``); the
+    donated span keeps the layout it arrives in and none of it is copied."""
+    from predictionio_tpu.obs import jaxmon
+    from predictionio_tpu.ops import gqa
+
+    monkeypatch.setenv("PIO_PALLAS_INTERPRET", "0")
+    same = dict(dim=2560, heads=40, kv_heads=20, head_dim=64, block_len=1,
+                eps=1e-5, rope=False, qk_norm=False, bias=True, diff=True)
+    full, cross = gqa.GQADims(**same), gqa.GQADims(cross=True, **same)
+    assert full.cache_width == 2560
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    p = placed(jax.eval_shape(lambda: (
+        gqa.init(jax.random.PRNGKey(0), full, jnp.bfloat16),
+        gqa.init(jax.random.PRNGKey(1), cross, jnp.bfloat16))))
+    i32 = jnp.int32
+
+    def step(p, x, pos, span, slots, own):
+        out, span = gqa.extend(p[0], full, x, pos, span, slots, own, 512,
+                               "seq.layer17.gqa_a", 17)
+        row = gqa.cross_rows(p[1], cross, out[:, -1], pos[:, -1], span,
+                             slots, own, 512, "seq.layer19.gqa_cross_a", 19)
+        return row, span
+
+    compiled = jax.jit(step, donate_argnums=3).lower(
+        p, *placed((jax.ShapeDtypeStruct((8, 4, 2560), jnp.float32),
+                    jax.ShapeDtypeStruct((8, 4), i32),
+                    jax.ShapeDtypeStruct((17, 33_792, 2560), jnp.bfloat16),
+                    jax.ShapeDtypeStruct((8,), i32),
+                    jax.ShapeDtypeStruct((8,), i32)))).compile()
+    text = compiled.as_text()
+    kernels = _kernel_instructions(text)
+    scopes = jaxmon.scope_map_of(text)
+    assert sorted(scopes[k] for k in kernels) == [
+        "seq.layer17.gqa_a.attend", "seq.layer19.gqa_cross_a.attend"]
+    assert all("span_walk" in k for k in kernels), kernels
+    assert not [i for i, s in scopes.items()
+                if s.endswith(".attend") and i.startswith("while")]
+    assert "bf16[1,1,512,2560]" not in text
+    held = r"bf16\[17,33792,2560\]"
+    layouts = set(re.findall(held + r"(\{[^}]*\})", re.sub(
+        r"operand_layout_constraints=\{[^=]*\}, ", "", text)))
+    assert layouts == {"{2,1,0:T(8,128)(2,1)}"}, layouts
+    assert not re.search(rf"= {held}\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
